@@ -347,8 +347,8 @@ def main() -> int:
                   flush=True)
             continue
         if not prop["onchip"] and not allow_session:
-            # the tunnel-backlog honesty gate: session-only costs may
-            # not mint a "learned" ladder a cold boot silently trusts
+            # the honesty gate: costs never measured on a chip may not
+            # mint a "learned" ladder a cold boot silently trusts
             print(json.dumps({"key": key, "refused":
                               "builder-session-only samples; pass "
                               "--allow-session to install anyway",

@@ -3,10 +3,11 @@
 Reference analog: the distributed benchmarks HPX runs per-locality-count
 (partitioned_vector STREAM triad, collectives all_reduce, distributed
 Jacobi — SURVEY.md §6 configs #3/#4/#5). Here a locality = a mesh
-device; the same harness takes real multi-chip hardware unchanged (it
-meshes over however many devices jax exposes) and falls back to a
-virtual CPU mesh for development, where the numbers measure SCALING
-SHAPE (collective/halo overhead vs device count), not absolute GB/s.
+device; the harness meshes over however many devices jax exposes and
+fails when they are too few. Under JAX_PLATFORMS=cpu the launcher
+gives the host platform N virtual devices for development, where the
+numbers measure SCALING SHAPE (collective/halo overhead vs device
+count), not absolute GB/s. Every line carries the platform it ran on.
 
 One command:  python -m hpx_tpu.run --bench-mesh 8
 prints one JSON line per (config, device-count):
@@ -25,7 +26,9 @@ import time
 
 
 def _emit(**kv) -> None:
-    print(json.dumps(kv), flush=True)
+    import jax
+    print(json.dumps({**kv, "platform": jax.devices()[0].platform}),
+          flush=True)
 
 
 def _time_loop(fn, iters: int, warm: int = 2) -> float:
@@ -250,11 +253,11 @@ def bench_paged_serving(ndev: int, devices) -> None:
 def sweep(max_devices: int) -> None:
     import jax
     devs = jax.devices()
-    assert len(devs) >= max_devices, (
-        f"need {max_devices} devices, have {len(devs)} — launch via "
-        f"`python -m hpx_tpu.run --bench-mesh N` (it provisions a "
-        f"virtual CPU mesh when hardware is short)")
-    _emit(metric="mesh_info", platform=devs[0].platform,
+    if len(devs) < max_devices:
+        raise RuntimeError(
+            f"need {max_devices} devices, jax exposes {len(devs)} on "
+            f"platform {devs[0].platform!r}")
+    _emit(metric="mesh_info", device_kind=devs[0].device_kind,
           n_available=len(devs))
     counts = []
     k = 1
@@ -272,6 +275,13 @@ def sweep(max_devices: int) -> None:
         bench_paged_serving(k, devs)
 
 
+def main(max_devices: int) -> None:
+    """The entry point (`__main__`, `hpx_tpu.run --bench-mesh`)."""
+    from hpx_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    sweep(max_devices)
+
+
 if __name__ == "__main__":
     import argparse
     import os
@@ -282,11 +292,4 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=8)
     args = ap.parse_args()
-    import jax
-    if os.environ.get("HPX_TPU_FORCE_PLATFORM"):
-        try:
-            jax.config.update(
-                "jax_platforms", os.environ["HPX_TPU_FORCE_PLATFORM"])
-        except Exception:  # noqa: BLE001
-            pass
-    sweep(args.devices)
+    main(args.devices)

@@ -223,9 +223,18 @@ def write_metrics_artifact(path, doc):
 
 
 def main() -> int:
-    import jax
     if "--cpu" in sys.argv:
-        jax.config.update("jax_platforms", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"     # before jax is imported
+    import jax
+    from hpx_tpu.utils.compile_cache import enable_compile_cache
+    dev = jax.devices()[0]
+    print(f"# device: platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())}", file=sys.stderr, flush=True)
+    if dev.platform != "tpu" and "--cpu" not in sys.argv:
+        print("serving_bench measures on a TPU; --cpu is the only way "
+              "onto the CPU", file=sys.stderr)
+        return 1
+    enable_compile_cache()
     import jax.numpy as jnp
     import numpy as np
     from hpx_tpu.models import transformer as tfm

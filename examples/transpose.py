@@ -22,7 +22,7 @@ argv = setup_platform()
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from hpx_tpu.utils.jaxcompat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 import hpx_tpu as hpx  # noqa: E402

@@ -118,7 +118,7 @@ def _program(key, build):
 
 def _shard_prog(mesh, spec, body):
     import jax
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
     if isinstance(spec, str):
         from jax.sharding import PartitionSpec as P
         spec = P(spec)

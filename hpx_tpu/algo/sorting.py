@@ -61,7 +61,7 @@ def _build_odd_even(mesh, axis: str):
     p (cheap rounds, no capacity padding), wrong shape at pod scale."""
     import jax
     import jax.numpy as jnp
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     p = mesh.shape[axis]
@@ -194,7 +194,7 @@ def _build_sample_sort(mesh, axis: str, with_payload: bool = False):
     """
     import jax
     import jax.numpy as jnp
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     p = mesh.shape[axis]

@@ -454,9 +454,7 @@ _DEVICE_PREFIXES = ("jax.numpy.", "jax.lax.", "jax.random.", "jax.nn.",
                     "jax.scipy.", "jax.ops.")
 _DEVICE_CALLS = {"jax.device_put", "jax.tree_util.tree_map"}
 _JIT_FUNCS = {"jax.jit", "jax.pjit", "jax.experimental.pjit.pjit"}
-_PROGRAM_FACTORIES = _JIT_FUNCS | {
-    "jax.shard_map", "jax.experimental.shard_map.shard_map",
-    "shard_map", "hpx_tpu.utils.jaxcompat.shard_map"}
+_PROGRAM_FACTORIES = _JIT_FUNCS | {"jax.shard_map", "shard_map"}
 # array methods that preserve the host/device-ness of their receiver
 _ARRAY_METHODS = {"sum", "mean", "max", "min", "astype", "reshape",
                   "copy", "ravel", "any", "all", "dot", "transpose",

@@ -3,7 +3,7 @@
 Reference analog: `containers/partitioned_vector.py` stores data at
 rest as fixed-size segments behind an address map; this module is the
 same discipline for data in flight — decode-time K/V lives in ONE
-preallocated pool of `[num_blocks, block_size, n_kv, head_dim]` rows
+preallocated pool of `[num_blocks, n_kv, block_size, head_dim]` rows
 per layer, and requests hold *block ids*, never rows. The allocator is
 pure host-side bookkeeping (free list + ref counts) so it is testable
 without jax; the device pools it indexes live with their owner
@@ -184,7 +184,7 @@ class BlockAllocator:
 
     def pool_pspec(self, tp_axis: Optional[str] = None) -> tuple:
         """PartitionSpec entries (as a plain tuple — this module stays
-        jax-free) for the `[num_blocks, block_size, n_kv, head_dim]`
+        jax-free) for the `[num_blocks, n_kv, block_size, head_dim]`
         pools this allocator's ids index on a (dp, tp) mesh: kv-heads
         shard over `tp_axis`, the BLOCK AXIS never shards. Replicating
         blocks over dp is the sharded-serving invariant that keeps
@@ -192,7 +192,7 @@ class BlockAllocator:
         per-shard table gather never crosses shards (the HPX010
         fence); tp slices only the head dim, which block ids never
         address."""
-        return (None, None, tp_axis, None)
+        return (None, tp_axis, None, None)
 
     def scale_pspec(self, tp_axis: Optional[str] = None) -> tuple:
         """PartitionSpec entries for the `[num_blocks, n_kv]` int8/fp8

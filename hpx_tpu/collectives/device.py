@@ -67,7 +67,7 @@ def _program(mesh, axis: str, key: Tuple, build: Callable) -> Any:
 
 def _shard_map(body, mesh, in_spec, out_spec):
     import jax
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
     # check_vma stays ON (the default): with it off, jax falls back to
     # the legacy psum transpose and silently produces WRONG gradients
     # for differentiated collectives. Each verb below is written so its

@@ -89,6 +89,7 @@ from ..svc import faultinject, flight, tracing
 from ..svc.resiliency import sync_replay
 from ..ops.attention_pallas import resolve_paged_block_src
 from ..ops.paged_attention import (
+    block_rows,
     gather_block_kv,
     paged_decode_attention,
     paged_window_attention,
@@ -332,6 +333,30 @@ def _moe_fold(sink):
     return jnp.concatenate([s[:2], s[2:] / len(sink)])
 
 
+def _dp_rows(x, dp):
+    """Every dp shard's slot rows, concatenated in slot order along
+    axis 0 and TYPED replicated over dp (`dp` = (axis name, size), or
+    None off the mesh: identity). Each shard places its rows in a zero
+    buffer and a psum closes it — shard_map's replication check proves
+    a psum's result replicated, not an all_gather's (collectives/
+    device.py) — over the value's BITS, so the copy is exact down to
+    the sign of zero."""
+    if dp is None:
+        return x
+    name, size = dp
+    n = x.shape[0]
+    bits = x
+    if not jnp.issubdtype(x.dtype, jnp.integer):
+        bits = jax.lax.bitcast_convert_type(
+            x, {2: jnp.uint16, 4: jnp.uint32}[x.dtype.itemsize])
+    buf = jnp.zeros((size * n,) + x.shape[1:], bits.dtype)
+    buf = jax.lax.dynamic_update_slice_in_dim(
+        buf, bits, jax.lax.axis_index(name) * n, 0)
+    out = jax.lax.psum(buf, name)
+    return (out if out.dtype == x.dtype
+            else jax.lax.bitcast_convert_type(out, x.dtype))
+
+
 def _block_decode_rows(x, lp, kv, pos, cfg: TransformerConfig,
                        moe_cf=None, moe_ep=None, moe_sink=None,
                        moe_ms=None):
@@ -395,10 +420,10 @@ def _decode_rows(params, caches, tok, pos, cfg, moe_cf=None,
 def _paged_block_rows(x, lp, pools, scales, table, pos,
                       cfg: TransformerConfig, fused=False,
                       tp_axis=None, moe_cf=None, moe_ep=None,
-                      moe_sink=None):
+                      moe_sink=None, dp=None, write=None):
     """_block_decode_rows with the K/V rows living in a shared BLOCK
     POOL instead of per-slot dense buffers. x: [B, 1, D]; pools:
-    (k_pool, v_pool) each [num_blocks, block_size, Nkv, H]; scales:
+    (k_pool, v_pool) each [num_blocks, Nkv, block_size, H]; scales:
     (k_scale, v_scale) [num_blocks, Nkv] f32 sidecars for int8 pools,
     or None; table: [B, max_blocks] int32 logical->physical block map;
     pos: [B] int32. Projections/rope/ffn are byte-identical to the
@@ -411,7 +436,10 @@ def _paged_block_rows(x, lp, pools, scales, table, pos,
     tensor-parallel axis: every shard sees its LOCAL kv-head slice of
     the pools (block axis replicated over dp) and the partial attention
     / ffn outputs close with explicit psums — the same two reduction
-    points `_block_decode` uses."""
+    points `_block_decode` uses. `dp` = (axis, size) with `write` =
+    the all-slot (table, pos): every dp shard then writes EVERY slot's
+    new rows into its copy of the pools, which is what keeps the
+    copies equal — the replication the pool spec declares."""
     kp, vp = pools
     b = x.shape[0]
     h = _ln(x, lp["ln1"])
@@ -419,15 +447,16 @@ def _paged_block_rows(x, lp, pools, scales, table, pos,
     if cfg.rope:
         q = _rope_rows(q, pos, cfg)
         k = _rope_rows(k, pos, cfg)
+    kn, vn = _dp_rows(k[:, 0], dp), _dp_rows(v[:, 0], dp)
     if scales is None:
-        att, kp, vp = paged_decode_attention(q, k[:, 0], v[:, 0], kp,
-                                             vp, table, pos,
-                                             fused=fused)
+        att, kp, vp = paged_decode_attention(q, kn, vn, kp, vp, table,
+                                             pos, fused=fused,
+                                             write=write)
     else:
         ks, vs = scales
         att, kp, vp, ks, vs = paged_decode_attention(
-            q, k[:, 0], v[:, 0], kp, vp, table, pos,
-            k_scale=ks, v_scale=vs, fused=fused)
+            q, kn, vn, kp, vp, table, pos,
+            k_scale=ks, v_scale=vs, fused=fused, write=write)
         scales = (ks, vs)
     o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
     if tp_axis is not None:
@@ -447,7 +476,7 @@ def _paged_block_rows(x, lp, pools, scales, table, pos,
 
 def _paged_decode_rows(params, pools, scales, tok, table, pos, cfg,
                        fused=False, tp_axis=None, moe_cf=None,
-                       moe_ep=None):
+                       moe_ep=None, dp=None):
     """One token per slot through every block over paged pools;
     returns (pools, scales, f32 logits [B, V], mstats) — the
     _decode_rows analog. `scales` is the per-layer list of
@@ -456,11 +485,13 @@ def _paged_decode_rows(params, pools, scales, tok, table, pos, cfg,
     x = params["emb"][tok][:, None, :]
     new_pools, new_scales = [], []
     sink = []
+    write = None if dp is None else (_dp_rows(table, dp),
+                                     _dp_rows(pos, dp))
     for i, (lp, pl) in enumerate(zip(params["layers"], pools)):
         sc = None if scales is None else scales[i]
         x, pl, sc = _paged_block_rows(x, lp, pl, sc, table, pos, cfg,
                                       fused, tp_axis, moe_cf, moe_ep,
-                                      sink)
+                                      sink, dp, write)
         new_pools.append(pl)
         new_scales.append(sc)
     x = _ln(x, params["ln_f"])
@@ -539,13 +570,14 @@ def _decode_window_rows(params, caches, toks, pos0, cfg, moe_cf=None,
 def _paged_window_rows(x, lp, pools, scales, table, pos0,
                        cfg: TransformerConfig, fused=False,
                        tp_axis=None, moe_cf=None, moe_ep=None,
-                       moe_sink=None):
+                       moe_sink=None, dp=None, write=None):
     """`_window_rows` over paged pools: the scatter/gather and the
     per-query horizon live in `ops.paged_attention.
     paged_window_attention`; projections/rope/ffn are byte-identical
     to the dense window, which keeps paged == dense token-exact under
     speculation too. `tp_axis` closes the per-shard partial sums under
-    shard_map exactly as in `_paged_block_rows`."""
+    shard_map, and `dp` / `write` keep the pool copies equal, exactly
+    as in `_paged_block_rows`."""
     kp, vp = pools
     b, w = x.shape[0], x.shape[1]
     h = _ln(x, lp["ln1"])
@@ -554,14 +586,16 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
     if cfg.rope:
         q = _rope_win(q, posw, cfg)
         k = _rope_win(k, posw, cfg)
+    kn, vn = _dp_rows(k, dp), _dp_rows(v, dp)
     if scales is None:
-        att, kp, vp = paged_window_attention(q, k, v, kp, vp, table,
-                                             pos0, fused=fused)
+        att, kp, vp = paged_window_attention(q, kn, vn, kp, vp, table,
+                                             pos0, fused=fused,
+                                             write=write)
     else:
         ks, vs = scales
         att, kp, vp, ks, vs = paged_window_attention(
-            q, k, v, kp, vp, table, pos0,
-            k_scale=ks, v_scale=vs, fused=fused)
+            q, kn, vn, kp, vp, table, pos0,
+            k_scale=ks, v_scale=vs, fused=fused, write=write)
         scales = (ks, vs)
     o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
     if tp_axis is not None:
@@ -581,17 +615,19 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
 
 def _paged_decode_window_rows(params, pools, scales, toks, table, pos0,
                               cfg, fused=False, tp_axis=None,
-                              moe_cf=None, moe_ep=None):
+                              moe_cf=None, moe_ep=None, dp=None):
     """W tokens per slot over paged pools; returns (pools, scales, f32
     logits [B, W, V], mstats) — the `_decode_window_rows` analog."""
     x = params["emb"][toks]
     new_pools, new_scales = [], []
     sink = []
+    write = None if dp is None else (_dp_rows(table, dp),
+                                     _dp_rows(pos0, dp))
     for i, (lp, pl) in enumerate(zip(params["layers"], pools)):
         sc = None if scales is None else scales[i]
         x, pl, sc = _paged_window_rows(x, lp, pl, sc, table, pos0, cfg,
                                        fused, tp_axis, moe_cf, moe_ep,
-                                       sink)
+                                       sink, dp, write)
         new_pools.append(pl)
         new_scales.append(sc)
     x = _ln(x, params["ln_f"])
@@ -1223,9 +1259,9 @@ class ContinuousServer:
                   "fp8": jnp.float8_e4m3fn}.get(self._kv_dtype,
                                                 cfg.dtype)
             if self._pool_sh is not None:
-                return jnp.zeros((num_blocks, bs, nkv, hd), dt,
+                return jnp.zeros((num_blocks, nkv, bs, hd), dt,
                                  device=self._pool_sh)
-            return jnp.zeros((num_blocks, bs, nkv, hd), dt)
+            return jnp.zeros((num_blocks, nkv, bs, hd), dt)
         self._pools = [(pzeros(), pzeros())
                        for _ in range(cfg.n_layers)]
         if self._kv_dtype in ("int8", "fp8"):
@@ -1268,6 +1304,12 @@ class ContinuousServer:
         if self.cfg.n_experts <= 0:
             return None
         return self._moe_capacity_pct / 100.0
+
+    def _dp(self):
+        """(axis, size) of the data-parallel axis for the shard_map
+        paged bodies (`_dp_rows`); None on a single device."""
+        return None if self.mesh is None else ("dp",
+                                               self.mesh.shape["dp"])
 
     def _moe_ep(self):
         """(axis, size) for expert-parallel routing inside the
@@ -1391,6 +1433,7 @@ class ContinuousServer:
         def build():
             fused = self._paged_fused
             tp_axis = None if self.mesh is None else "tp"
+            dp = self._dp()
             moe_cf = self._moe_cf()
             moe_ep = self._moe_ep()
 
@@ -1398,7 +1441,7 @@ class ContinuousServer:
                      keys):
                 pools, scales, logits, ms = _paged_decode_rows(
                     params, pools, scales, tok, tables, pos, cfg,
-                    fused, tp_axis, moe_cf, moe_ep)
+                    fused, tp_axis, moe_cf, moe_ep, dp)
                 nxt = jax.vmap(_pick_row)(logits, keys, temp, pos)
                 if ms is not None and tp_axis is not None:
                     # fold the per-dp-group stats into one replicated
@@ -1414,16 +1457,17 @@ class ContinuousServer:
             # GSPMD: each dp shard steps ITS slots against its LOCAL
             # pool replica (block tables are per-shard int32 into a
             # dp-replicated block axis — the gather can never cross
-            # shards), tp shards the kv-head axis with explicit psums
-            # in _paged_block_rows, and MoE layers route tokens over
-            # the expert axis via moe_ffn_decode's tiled all_to_all.
-            # Per-slot sampling (keys fold per slot, row 0) is
-            # shard-local, so emitted tokens match the single-device
+            # shards; every shard writes every slot's new rows, so the
+            # replicas stay equal and the replication check, left ON,
+            # can prove the pool spec), tp shards the kv-head axis with
+            # explicit psums in _paged_block_rows, and MoE layers route
+            # tokens over the expert axis via moe_ffn_decode's tiled
+            # all_to_all. Per-slot sampling (keys fold per slot, row 0)
+            # is shard-local, so emitted tokens match the single-device
             # server exactly.
             from jax.sharding import PartitionSpec as P
-            from ..utils.jaxcompat import shard_map
             pspecs, pool_sp, scale_sp = self._paged_shard_specs()
-            return self._jit_step(shard_map(
+            return self._jit_step(jax.shard_map(
                 step, mesh=self.mesh,
                 in_specs=(pspecs, pool_sp, scale_sp, P("dp"),
                           P("dp"), P("dp", None), P("dp"),
@@ -1598,6 +1642,7 @@ class ContinuousServer:
             pool_sh, scale_sh = self._pool_sh, self._scale_sh
 
             def restore(pools, scales, bid, rows, scs):
+                rows = block_rows(rows)     # token rows -> pool blocks
                 pools = [(kp.at[bid].set(rows[li, 0].astype(kp.dtype)),
                           vp.at[bid].set(rows[li, 1].astype(vp.dtype)))
                          for li, (kp, vp) in enumerate(pools)]
@@ -1658,6 +1703,7 @@ class ContinuousServer:
         def build():
             fused = self._paged_fused
             tp_axis = None if self.mesh is None else "tp"
+            dp = self._dp()
             moe_cf = self._moe_cf()
             moe_ep = self._moe_ep()
 
@@ -1665,7 +1711,7 @@ class ContinuousServer:
                        kvec, temp, keys):
                 pools, scales, logits, ms = _paged_decode_window_rows(
                     params, pools, scales, toks, tables, pos0, cfg,
-                    fused, tp_axis, moe_cf, moe_ep)
+                    fused, tp_axis, moe_cf, moe_ep, dp)
                 if ms is not None and tp_axis is not None:
                     ms = jnp.concatenate(
                         [jax.lax.psum(ms[:2], "dp"),
@@ -1680,9 +1726,8 @@ class ContinuousServer:
             # _verify_tail pick is per-slot (shard-local) so spec
             # acceptance matches the single-device server exactly.
             from jax.sharding import PartitionSpec as P
-            from ..utils.jaxcompat import shard_map
             pspecs, pool_sp, scale_sp = self._paged_shard_specs()
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 verify, mesh=self.mesh,
                 in_specs=(pspecs, pool_sp, scale_sp, P("dp", None),
                           P("dp"), P("dp", None), P("dp"), P("dp"),
@@ -1849,8 +1894,8 @@ class ContinuousServer:
         layers = []
         scl = [] if self._scales is not None else None
         for li, (kp, vp) in enumerate(self._pools):
-            layers.append(np.stack((np.asarray(kp[bid]),
-                                    np.asarray(vp[bid]))))
+            layers.append(np.stack((np.asarray(block_rows(kp[bid])),
+                                    np.asarray(block_rows(vp[bid])))))
             if scl is not None:
                 ks, vs = self._scales[li]
                 scl.append(np.stack((np.asarray(ks[bid]),
@@ -2017,6 +2062,8 @@ class ContinuousServer:
             # env | learned (perfdb) | seed (paged_blocks.json) |
             # default — the satellite audit hook for learned ladders
             "block_size_source": self._block_size_src,
+            # what `auto` resolved to: gather | fused | fused_online
+            "paged_kernel": self._paged_kernel,
         }
 
     def spec_stats(self) -> Dict[str, float]:
@@ -2179,12 +2226,12 @@ class ContinuousServer:
                     # hpxlint: disable-next=HPX010 — host-side export
                     # of a few matched blocks (once per fleet
                     # placement hit), not the decode attention loop
-                    g = pool[idx]                 # [nblk, bs, nkv, hd]
+                    g = pool[idx]                 # [nblk, nkv, bs, hd]
                     if self._scales is not None:
                         sc = self._scales[li][side][idx]
                         g = (g.astype(jnp.float32)
-                             * sc[:, None, :, None])
-                    g = g.astype(self.cfg.dtype)
+                             * sc[:, :, None, None])
+                    g = block_rows(g.astype(self.cfg.dtype))
                     sides.append(np.asarray(g).reshape(
                         matched, nkv, hd))
                 layers.append(np.stack(sides))

@@ -29,7 +29,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import auto_attention, ring_attention_sharded

@@ -86,8 +86,10 @@ def native_lib() -> Optional[ctypes.CDLL]:
         _lib_tried = True
         # Always invoke make (it is incremental): a stale prebuilt .so —
         # the .so is gitignored, sources are not — would otherwise be
-        # loaded and fail symbol binding after a source update.
-        if not _build() and not os.path.exists(_SO):
+        # loaded and fail symbol binding after a source update. Where
+        # make FAILS, whatever .so lies there was not built from these
+        # sources: run the pure-Python scheduler instead of loading it.
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO)

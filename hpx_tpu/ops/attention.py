@@ -23,7 +23,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = [
@@ -37,8 +37,7 @@ def auto_attention(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = False) -> jax.Array:
     """Best-available single-device attention: the pallas flash kernel
     on TPU (bf16 MXU tiles with fp32 accumulation, VMEM-resident online
-    softmax; driver-measured 35% MFU at B2/S4096/N8/H128 causal —
-    BENCH_r03.json — higher at longer S), XLA blockwise elsewhere.
+    softmax), XLA blockwise elsewhere.
     Differentiable on both paths (flash carries a custom_vjp)."""
     if jax.default_backend() == "tpu":
         from .attention_pallas import flash_attention
@@ -51,14 +50,9 @@ def _scale(q: jax.Array) -> jax.Array:
 
 
 def _pvary(x: jax.Array, axis) -> jax.Array:
-    """Mark a constant as device-varying over shard_map axis/axes (newer
-    jax tracks varying manual axes; older versions don't need it)."""
+    """Mark a constant as device-varying over shard_map axis/axes."""
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    return x
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 # ---------------------------------------------------------------------------
@@ -407,8 +401,8 @@ def _ring_flash_fwd_impl(qc, kc, vc, axis, nshards, causal,
         d = ring_offset(idx, src, sq, striped)
         acc, m, l = flash_attention_chunk(qt, kc_, vc_, acc, m, l, d,
                                           causal=causal, block_q=blk,
-                                          block_k=blk, q_heads=n,
-                                          kv_heads=nkv)
+                                          block_k=_ring_blk(sq, 512),
+                                          q_heads=n, kv_heads=nkv)
         kc_ = jax.lax.ppermute(kc_, axis, perm)
         vc_ = jax.lax.ppermute(vc_, axis, perm)
         return (acc, m, l, kc_, vc_), None

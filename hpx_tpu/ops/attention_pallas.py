@@ -37,11 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5;
-# accept either so the kernels run on both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 __all__ = ["flash_attention", "flash_attention_chunk",
            "flash_attention_bwd", "fused_paged_attention",
            "fused_paged_online_attention",
@@ -99,11 +94,8 @@ def _sds(shape, dtype, *operands):
     """ShapeDtypeStruct whose varying-mesh-axes type is the union of the
     operands' — required when a pallas_call runs INSIDE a vma-checked
     shard_map (the kernel output varies over whatever its inputs do)."""
-    try:
-        vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 _NEG_INF = -1e30     # large-negative instead of -inf: exp() stays exact,
                      # and (m_prev - m_new) never produces inf - inf
@@ -277,7 +269,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
@@ -561,7 +553,7 @@ def flash_attention_bwd(q, k, v, do, delta, lse, d,
             ],
         ),
         out_shape=[_sds((bn, sq, h), f32, q, k, v, do, delta, lse)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(darr, q, k, v, do, delta, lse)[0]
@@ -596,7 +588,7 @@ def flash_attention_bwd(q, k, v, do, delta, lse, d,
         ),
         out_shape=[_sds((bn, sk, h), f32, q, k, v, do, delta, lse),
                    _sds((bn, sk, h), f32, q, k, v, do, delta, lse)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(darr, q, k, v, do, delta, lse)
@@ -693,7 +685,7 @@ def _flash_chunk_kernel(d_ref, q_ref, k_ref, v_ref, acc_in, m_in, l_in,
 
 def flash_attention_chunk(q, k, v, acc, m, l, d,
                           causal: bool = False, block_q: int = 1024,
-                          block_k: int = 1024,
+                          block_k: int = 512,
                           interpret: Optional[bool] = None,
                           q_heads: int = 1, kv_heads: int = 1):
     """Fold one K/V chunk into an online-softmax carry (pallas).
@@ -711,7 +703,9 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
     volume at the kv-head size.
 
     sq and sk must be multiples of the (clamped) block sizes — ring
-    chunks are equal by construction.
+    chunks are equal by construction. 1024 x 1024 tiles need 19.4 MB
+    of the chip's 16 MB scoped VMEM (the carry streams in AND out), so
+    block_k defaults to 512.
     """
     import math as _math
     bn, sq, h = q.shape
@@ -778,7 +772,7 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
             _sds((bn, sq, 128), f32, q, k, v, acc, m, l),
             _sds((bn, sq, 128), f32, q, k, v, acc, m, l),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray([d], jnp.int32).reshape(1), q, k, v, acc, m, l)
@@ -798,11 +792,20 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
 # (table_ref[b, i]), so each (block_size, head_dim) tile streams
 # HBM -> VMEM exactly once and no logical view ever touches HBM.
 #
+# Pools are laid out [num_blocks, n_kv, block_size, head_dim] — heads
+# AHEAD of rows — so the streamed tile's last two dimensions are the
+# pool's own (block_size, head_dim): the TPU lowering takes a block
+# only when its last two dimensions are multiples of (8, 128) or the
+# array's full extents, and one head of a rows-ahead-of-heads pool is
+# neither for n_kv > 1.
+#
 # Quantized (int8/fp8) pools dequantize AT THE VMEM BOUNDARY:
 # per-(block, kv-head) absmax scales ride a sibling [num_blocks, n_kv]
-# f32 array whose BlockSpec follows the same table indirection, and
-# (q * scale).astype(q.dtype) happens on the freshly-landed tile —
-# HBM moves 1 byte/elem instead of 2 (bf16) or 4 (f32).
+# f32 array; the kernel streams the 8-row group holding the table's
+# block (the same lowering rule: 8 rows x all heads) and selects its
+# (block, head) entry by mask, and (q * scale).astype(q.dtype) happens
+# on the freshly-landed tile — HBM moves 1 byte/elem instead of 2
+# (bf16) or 4 (f32).
 #
 # TWO kernels share that walk, trading VMEM for exactness differently:
 #
@@ -810,11 +813,13 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
 # be able to emit the SAME TOKENS as the gather oracle and the dense
 # server with bitwise-equal scores and softmax (tests pin dense ==
 # gather-paged == fused-paged greedy/sampled/speculative), so it
-# spends VMEM on exactness: per-block score tiles are stashed into an
-# (W*g, S) f32 scratch and dequantized V rows into an (S, hd) scratch
-# along the sequential block axis, and the LAST block step applies the
-# oracle's op order verbatim — mask to -inf, f32 softmax over the full
-# row, cast to q.dtype, one (W*g, S) x (S, hd) dot. Scores and
+# spends VMEM on exactness: the dequantized K and V rows are banked
+# into two (S, hd) scratches along the sequential block axis (row
+# offsets are block multiples, which the sublane axis takes; a lane
+# offset of one block would not lower), and the LAST block step applies
+# the oracle's op order verbatim — one (W*g, hd) x (hd, S) score dot,
+# mask to -inf, f32 softmax over the full row, cast to q.dtype, one
+# (W*g, S) x (S, hd) dot. Scores and
 # softmax are bitwise-equal to the oracle's; the final PV contraction
 # is the same f32 math but XLA schedules a batched einsum's reduction
 # differently from a 2-D dot, so logits agree to ~1 ulp rather than
@@ -822,9 +827,10 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
 # its own programs (the oracle's eager and jitted logits differ by the
 # same amount, as do its W=1 decode and W-window verify gemms), and
 # the reason every serving equivalence contract here is pinned at
-# exact TOKENS plus ulp-tight logits. VMEM cost is O(S*(W*g + hd))
-# per (slot, head) step, which is what CAPS the usable context: S
-# rides the scratch, so smax can't outgrow VMEM.
+# exact TOKENS plus ulp-tight logits. VMEM cost is O(S * hd) per
+# (slot, head) step plus the (W*g, S) score row at the last step, which
+# is what CAPS the usable context: S rides the scratch, so smax can't
+# outgrow VMEM.
 #
 # `fused_online` (_paged_online_kernel) — the O(block) roofline leg.
 # The classic flash-attention move applied to the paged walk: the
@@ -905,55 +911,71 @@ def resolve_paged_block(head_dim: int, kv_dtype: str = "bf16",
     return resolve_paged_block_src(head_dim, kv_dtype, default)[0]
 
 
+def _dequant_tile(x, sc_ref, blk, head, dtype):
+    """Dequantize one freshly-landed (block_size, head_dim) tile:
+    sc_ref holds the 8-block group of [num_blocks, n_kv] scales around
+    physical block `blk`; its (blk % 8, head) entry is selected by mask
+    (a dynamic scalar read from VMEM does not lower) — elementwise-
+    identical to the oracle's (pool.astype(f32) * scale).astype(dtype)."""
+    sc = sc_ref[...]                               # (8, n_kv) f32
+    row = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    pick = jnp.logical_and(row == blk % 8, col == head)
+    scale = jnp.sum(jnp.where(pick, sc, 0.0))
+    return (x.astype(jnp.float32) * scale).astype(dtype)
+
+
 def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                   block_size: int, nblk: int, group: int,
                   quantized: bool):
     """One (slot b, kv-head h, logical block i) grid step.
 
-    q_ref: (1, 1, Wg, hd) the slot's query rows for this kv head
-    (window row w, group lane j flattened as r = w*group + j);
-    k_ref/v_ref: (1, block_size, 1, hd) the PHYSICAL pool block the
-    table maps logical block i to (the index_map did the gather);
-    quantized adds ks_ref/vs_ref (1, 1) per-(block, head) scales.
-    s_s/v_s scratch accumulate the full logical row along the
-    sequential i axis; the last step runs the oracle-order softmax."""
+    q_ref: (Wg, hd) the slot's query rows for this kv head (window row
+    w, group lane j flattened as r = w*group + j); k_ref/v_ref:
+    (block_size, hd) the PHYSICAL pool block the table maps logical
+    block i to (the index_map did the gather); quantized adds
+    ks_ref/vs_ref (8, n_kv) scale groups. k_s/v_s scratch bank the
+    full logical K/V rows along the sequential i axis; the last step
+    runs the oracle-order attention over them."""
     if quantized:
-        ks_ref, vs_ref, o_ref, s_s, v_s = rest
+        ks_ref, vs_ref, o_ref, k_s, v_s = rest
     else:
-        o_ref, s_s, v_s = rest
+        o_ref, k_s, v_s = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     i = pl.program_id(2)
 
-    q = q_ref[0, 0]                                # (Wg, hd)
-    k = k_ref[0, :, 0, :]                          # (bs, hd)
-    v = v_ref[0, :, 0, :]
+    k = k_ref[...]                                 # (bs, hd)
+    v = v_ref[...]
     if quantized:
-        # dequantize at the VMEM boundary — elementwise-identical to
-        # the oracle's (pool.astype(f32) * scale).astype(q.dtype)
-        k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(q.dtype)
-        v = (v.astype(jnp.float32) * vs_ref[0, 0]).astype(q.dtype)
-
-    # same dtype semantics as the oracle's einsum (no forced f32
-    # accumulation — byte-identity beats MXU rate here; the f32 upcast
-    # below is exact for bf16/f32 scores)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
-    s = s / math.sqrt(q.shape[-1])
-    s_s[:, pl.ds(i * block_size, block_size)] = s.astype(jnp.float32)
-    v_s[pl.ds(i * block_size, block_size), :] = v.astype(jnp.float32)
+        blk = table_ref[b, i]
+        k = _dequant_tile(k, ks_ref, blk, h, k_s.dtype)
+        v = _dequant_tile(v, vs_ref, blk, h, v_s.dtype)
+    rows = pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
+    k_s[rows, :] = k
+    v_s[rows, :] = v
 
     @pl.when(i == nblk - 1)
     def _finish():
         pos0 = pos_ref[b]
-        sf = s_s[...]                              # (Wg, S) f32
+        q = q_ref[...]                             # (Wg, hd)
+        # the oracle's einsum: operands in q.dtype, result rounded to
+        # q.dtype (the MXU accumulates in f32 either way; Mosaic wants
+        # the accumulator spelled), scaled, then upcast for the softmax
+        s = jax.lax.dot_general(
+            q, k_s[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(q.dtype)
+        sf = (s / math.sqrt(q.shape[-1])).astype(jnp.float32)
         kpos = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 1)
         wrow = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 0) // group
         live = kpos <= pos0 + wrow                 # per-window-row horizon
         sf = jnp.where(live, sf, -jnp.inf)
         p = jax.nn.softmax(sf, axis=-1)            # oracle op order
         att = jax.lax.dot_general(
-            p.astype(o_ref.dtype), v_s[...].astype(o_ref.dtype),
-            (((1,), (0,)), ((), ())))
-        o_ref[0, 0] = att.astype(o_ref.dtype)
+            p.astype(o_ref.dtype), v_s[...],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[...] = att.astype(o_ref.dtype)
 
 
 def paged_online_scratch_shapes(wg_pad: int, head_dim: int) -> list:
@@ -989,6 +1011,7 @@ def _paged_online_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
     else:
         o_ref, acc_s, m_s, l_s = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     i = pl.program_id(2)
 
     @pl.when(i == 0)
@@ -997,14 +1020,13 @@ def _paged_online_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    q = q_ref[0, 0]                                # (Wg, hd)
-    k = k_ref[0, :, 0, :]                          # (bs, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]                                 # (Wg, hd)
+    k = k_ref[...]                                 # (bs, hd)
+    v = v_ref[...]
     if quantized:
-        # dequantize at the VMEM boundary — elementwise-identical to
-        # the oracle's (pool * scale).astype(q.dtype)
-        k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(q.dtype)
-        v = (v.astype(jnp.float32) * vs_ref[0, 0]).astype(q.dtype)
+        blk = table_ref[b, i]
+        k = _dequant_tile(k, ks_ref, blk, h, q.dtype)
+        v = _dequant_tile(v, vs_ref, blk, h, q.dtype)
 
     # f32 score accumulation (the flash numerics contract) — this
     # kernel's gate is tolerance-budgeted, so MXU-rate operands with
@@ -1047,7 +1069,7 @@ def _paged_online_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
         # guard covers only the 8-sublane pad rows (sliced off outside)
         l = l_s[:, :1]
         den = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = (acc_s[:] / den).astype(o_ref.dtype)
+        o_ref[...] = (acc_s[:] / den).astype(o_ref.dtype)
 
 
 def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
@@ -1060,7 +1082,7 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
 
     q: [B, W, n_q, head_dim] post-rope queries (W = 1 for plain decode,
     W = window width for speculative verify); k_pool/v_pool:
-    [num_blocks, block_size, n_kv, head_dim] with this step's rows
+    [num_blocks, n_kv, block_size, head_dim] with this step's rows
     ALREADY scattered (write precedes attention, exactly like the
     gather oracle); table: [B, max_blocks] int32; pos0: [B] int32 —
     window row w attends logical positions <= pos0 + w (W = 1: the
@@ -1077,11 +1099,9 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
     reshape, so n_q % n_kv == 0.
 
     Falls back to interpret mode off-TPU (CPU tier-1 stays green).
-    Real-TPU int8 pools want block_size >= 32 (the int8 sublane tile);
-    interpret mode takes any block size.
 
-    Runs unchanged inside shard_map on the serving (dp, tp) mesh
-    (via utils/jaxcompat): n_q/n_kv here are then the PER-SHARD head
+    Runs unchanged inside shard_map on the serving (dp, tp) mesh:
+    n_q/n_kv here are then the PER-SHARD head
     counts (tp slices the kv-head axis, so the GQA group n_q // n_kv
     is unchanged), the block axis is dp-replicated so the
     scalar-prefetched table's global block ids index the local pool
@@ -1130,8 +1150,8 @@ def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, w, nq, hd = q.shape
-    bs = k_pool.shape[1]
-    nkv = k_pool.shape[2]
+    nkv = k_pool.shape[1]
+    bs = k_pool.shape[2]
     maxb = table.shape[1]
     if nq % nkv:
         raise ValueError(f"q heads ({nq}) not a multiple of kv heads "
@@ -1155,22 +1175,22 @@ def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
         # the flash carry — O(block), no sequence extent anywhere
         scratch = paged_online_scratch_shapes(wg_pad, hd)
     else:
-        # the bitwise kernel banks full rows: O(S * (W*g + hd))
-        scratch = [pltpu.VMEM((wg_pad, seq), jnp.float32),
-                   pltpu.VMEM((seq, hd), jnp.float32)]
+        # the bitwise kernel banks full K/V rows: O(S * hd)
+        scratch = [pltpu.VMEM((seq, hd), q.dtype),
+                   pltpu.VMEM((seq, hd), q.dtype)]
 
-    q_spec = pl.BlockSpec((1, 1, wg_pad, hd),
+    q_spec = pl.BlockSpec((None, None, wg_pad, hd),
                           lambda bb, hh, ii, *_: (bb, hh, 0, 0))
     # THE fusion: logical block ii of slot bb reads physical pool
     # block table[bb, ii] straight from the scalar-prefetched table
     kv_spec = pl.BlockSpec(
-        (1, bs, 1, hd),
-        lambda bb, hh, ii, tref, pref: (tref[bb, ii], 0, hh, 0))
+        (None, None, bs, hd),
+        lambda bb, hh, ii, tref, pref: (tref[bb, ii], hh, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qk, k_pool, v_pool]
     if quantized:
         sc_spec = pl.BlockSpec(
-            (1, 1), lambda bb, hh, ii, tref, pref: (tref[bb, ii], hh))
+            (8, nkv), lambda bb, hh, ii, tref, pref: (tref[bb, ii] // 8, 0))
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale, v_scale]
 
@@ -1185,7 +1205,7 @@ def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
         ),
         out_shape=[_sds((b, nkv, wg_pad, hd), q.dtype, q, k_pool,
                         v_pool)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table.astype(jnp.int32), pos0.astype(jnp.int32), *operands)[0]

@@ -2,7 +2,9 @@
 
 The device side of the `hpx_tpu/cache` subsystem: K/V for every
 request lives in one preallocated per-layer pool of fixed-size blocks
-(`[num_blocks, block_size, n_kv, head_dim]`), and a per-step int32
+(`[num_blocks, n_kv, block_size, head_dim]` — heads ahead of rows, so
+one head of one block is a contiguous (block_size, head_dim) tile, the
+shape the fused kernels stream), and a per-step int32
 block table (`cache/page_table.py`) maps each slot's logical positions
 to physical blocks. This module is pure jit-safe array plumbing — no
 host state, no syncs — so the serving layer can compose it with its
@@ -56,7 +58,10 @@ serving layer shards pools/scales over tp on the kv-head axis and
 REPLICATES the block axis over dp (`BlockAllocator.pool_pspec`), which
 is exactly what keeps each shard's `pool[table]` gather shard-local:
 tables carry global block ids, and every id resolves on every dp
-shard. Nothing in this module may introduce a cross-shard collective.
+shard. The replicas stay equal because every shard applies every
+slot's write (the attention entry points' `write=`; the serving layer
+gathers the rows). Nothing in this module may introduce a cross-shard
+collective.
 """
 
 from __future__ import annotations
@@ -71,6 +76,7 @@ from .attention_pallas import (fused_paged_attention,
                                fused_paged_online_attention)
 
 __all__ = [
+    "block_rows",
     "gather_block_kv",
     "paged_decode_attention",
     "paged_window_attention",
@@ -86,12 +92,20 @@ __all__ = [
 ]
 
 
+def block_rows(x: jax.Array) -> jax.Array:
+    """Pool blocks `[..., n_kv, block_size, head_dim]` <-> token rows
+    `[..., block_size, n_kv, head_dim]` (its own inverse). Everything
+    outside the pools — scratch caches, KV segments, host-tier entries
+    — keeps token-row order; this is the one crossing."""
+    return jnp.swapaxes(x, -3, -2)
+
+
 def gather_block_kv(pool: jax.Array, table: jax.Array,
                     scale: jax.Array = None,
                     out_dtype=None) -> jax.Array:
     """Materialize logical K or V rows from a block pool.
 
-    pool: [num_blocks, block_size, n_kv, head_dim]; table: [B,
+    pool: [num_blocks, n_kv, block_size, head_dim]; table: [B,
     max_blocks] int32. Returns [B, max_blocks * block_size, n_kv,
     head_dim] — slot b's logical row p at index p (pad blocks yield
     garbage rows the causal mask must exclude).
@@ -101,58 +115,59 @@ def gather_block_kv(pool: jax.Array, table: jax.Array,
     elementwise ops the fused kernels apply at their VMEM boundary
     ((q * scale).astype(out_dtype)), keeping the quantized paths
     exactly comparable."""
-    g = pool[table]                       # [B, maxb, bs, nkv, hd]
-    b, m, s, n, h = g.shape
+    g = pool[table]                       # [B, maxb, nkv, bs, hd]
+    b, m, n, s, h = g.shape
     if scale is not None:
         sc = scale[table]                 # [B, maxb, nkv]
-        g = (g.astype(jnp.float32) * sc[:, :, None, :, None]).astype(
+        g = (g.astype(jnp.float32) * sc[:, :, :, None, None]).astype(
             out_dtype if out_dtype is not None else jnp.bfloat16)
-    return g.reshape(b, m * s, n, h)
+    return block_rows(g).reshape(b, m * s, n, h)
 
 
 def quantize_blocks(rows: jax.Array, dtype=jnp.int8):
-    """Symmetric-absmax quantization per (block, kv-head): rows [...,
-    block_size, n_kv, head_dim] -> (quantized rows, scales [..., n_kv]
-    f32). `dtype` picks the grid — jnp.int8 (127-level integer ladder)
-    or jnp.float8_e4m3fn (e4m3 float grid, block absmax mapped onto
-    ±448); anything else is a loud error, never a silent fallback.
+    """Symmetric-absmax quantization per (block, kv-head): pool-layout
+    blocks [..., n_kv, block_size, head_dim] -> (quantized blocks,
+    scales [..., n_kv] f32). `dtype` picks the grid — jnp.int8
+    (127-level integer ladder) or jnp.float8_e4m3fn (e4m3 float grid,
+    block absmax mapped onto ±448); anything else is a loud error,
+    never a silent fallback.
     Zero blocks get scale 1.0 (models/quant's convention), so fresh
     pools roundtrip exactly."""
     dt = jnp.dtype(dtype)
     if dt == jnp.dtype(jnp.int8):
-        qt = _quantize(rows, axes=(-3, -1))
+        qt = _quantize(rows, axes=(-2, -1))
     elif dt == jnp.dtype(jnp.float8_e4m3fn):
-        qt = _quantize_fp8(rows, axes=(-3, -1))
+        qt = _quantize_fp8(rows, axes=(-2, -1))
     else:
         raise ValueError(
             f"quantize_blocks: unsupported pool dtype {dt} (expected "
             "int8 or float8_e4m3fn)")
-    return qt.q, jnp.squeeze(qt.s, axis=(-3, -1))
+    return qt.q, jnp.squeeze(qt.s, axis=(-2, -1))
 
 
 def scatter_token(pool: jax.Array, table: jax.Array, pos: jax.Array,
                   val: jax.Array) -> jax.Array:
     """Write one token row per slot into the pool.
 
-    pool: [num_blocks, block_size, n_kv, head_dim]; table: [B,
+    pool: [num_blocks, n_kv, block_size, head_dim]; table: [B,
     max_blocks]; pos: [B] int32 logical positions; val: [B, n_kv,
-    head_dim]. Slot b's row lands at (table[b, pos[b]//bs],
+    head_dim]. Slot b's row lands at (table[b, pos[b]//bs], :,
     pos[b]%bs) — dead slots point their whole table at a reserved
     trash block, so their masked lanes scatter harmlessly."""
-    bs = pool.shape[1]
+    bs = pool.shape[2]
     rows = jnp.arange(table.shape[0])
     bidx = table[rows, pos // bs]
-    return pool.at[bidx, pos % bs].set(val)
+    return pool.at[bidx, :, pos % bs].set(val)
 
 
 def scatter_window(pool: jax.Array, table: jax.Array, pos0: jax.Array,
                    vals: jax.Array) -> jax.Array:
     """Write a W-token window of rows per slot into the pool.
 
-    pool: [num_blocks, block_size, n_kv, head_dim]; table: [B,
+    pool: [num_blocks, n_kv, block_size, head_dim]; table: [B,
     max_blocks]; pos0: [B] int32 first logical position per slot; vals:
     [B, W, n_kv, head_dim]. Slot b's window row i lands at
-    (table[b, (pos0[b]+i)//bs], (pos0[b]+i)%bs) — the speculative
+    (table[b, (pos0[b]+i)//bs], :, (pos0[b]+i)%bs) — the speculative
     verify scatter, where the tail of a slot's window may run past its
     mapped (or even mappable) range.
 
@@ -163,21 +178,21 @@ def scatter_window(pool: jax.Array, table: jax.Array, pos0: jax.Array,
     positions past the table's extent are routed to block index
     `num_blocks` (one past the pool) and the scatter uses
     ``mode="drop"``."""
-    nb, bs = pool.shape[0], pool.shape[1]
+    nb, bs = pool.shape[0], pool.shape[2]
     b, w = vals.shape[0], vals.shape[1]
     rows = jnp.arange(b)[:, None]
     p = pos0[:, None] + jnp.arange(w)[None, :]          # [B, W]
     maxb = table.shape[1]
     bidx = table[rows, jnp.minimum(p // bs, maxb - 1)]
     bidx = jnp.where(p < maxb * bs, bidx, nb)           # OOB -> dropped
-    return pool.at[bidx, p % bs].set(vals, mode="drop")
+    return pool.at[bidx, :, p % bs].set(vals, mode="drop")
 
 
 def scatter_token_q(pool_q: jax.Array, scales: jax.Array,
                     table: jax.Array, pos: jax.Array,
                     val: jax.Array):
     """`scatter_token` for quantized pools: read-modify-write the
-    frontier block. pool_q int8/fp8 [num_blocks, block_size, n_kv,
+    frontier block. pool_q int8/fp8 [num_blocks, n_kv, block_size,
     head_dim] (its dtype picks the requantization grid); scales f32
     [num_blocks, n_kv]; val [B, n_kv, head_dim] full-precision.
     Returns (pool_q, scales).
@@ -194,14 +209,14 @@ def scatter_token_q(pool_q: jax.Array, scales: jax.Array,
     `scatter_window`: both the block write and the scale write are
     routed to block index num_blocks and dropped, so an OOB row can
     neither corrupt a live block nor skew its scale."""
-    nb, bs = pool_q.shape[0], pool_q.shape[1]
+    nb, bs = pool_q.shape[0], pool_q.shape[2]
     maxb = table.shape[1]
     rows = jnp.arange(table.shape[0])
     bidx = table[rows, jnp.minimum(pos // bs, maxb - 1)]
-    blk = pool_q[bidx]                    # [B, bs, nkv, hd] int8
+    blk = pool_q[bidx]                    # [B, nkv, bs, hd] int8
     scl = scales[bidx]                    # [B, nkv]
-    deq = blk.astype(jnp.float32) * scl[:, None, :, None]
-    deq = deq.at[rows, pos % bs].set(val.astype(jnp.float32))
+    deq = blk.astype(jnp.float32) * scl[:, :, None, None]
+    deq = deq.at[rows, :, pos % bs].set(val.astype(jnp.float32))
     q8, s_new = quantize_blocks(deq, pool_q.dtype)
     bidx = jnp.where(pos < maxb * bs, bidx, nb)         # OOB -> dropped
     pool_q = pool_q.at[bidx].set(q8, mode="drop")
@@ -233,7 +248,7 @@ def scatter_blocks_q(pool_q: jax.Array, scales: jax.Array,
     """`scatter_blocks` for quantized pools: whole blocks quantize in
     one shot (no RMW — the writes fully replace their targets).
     Returns (pool_q, scales)."""
-    q8, s = quantize_blocks(rows, pool_q.dtype)
+    q8, s = quantize_blocks(block_rows(rows), pool_q.dtype)
     return pool_q.at[bids].set(q8), scales.at[bids].set(s)
 
 
@@ -244,7 +259,7 @@ def scatter_seq_blocks_q(pool_q: jax.Array, scales: jax.Array,
     duplicates behave exactly as in the bf16 splice — garbage blocks
     get garbage scales, gathered only under exact-zero masks. Returns
     (pool_q, scales)."""
-    q8, s = quantize_blocks(rows, pool_q.dtype)
+    q8, s = quantize_blocks(block_rows(rows), pool_q.dtype)
     return (pool_q.at[table_row].set(q8),
             scales.at[table_row].set(s))
 
@@ -252,8 +267,8 @@ def scatter_seq_blocks_q(pool_q: jax.Array, scales: jax.Array,
 def scatter_blocks(pool: jax.Array, bids: jax.Array,
                    rows: jax.Array) -> jax.Array:
     """Bulk-write whole blocks (prefill splice): bids [n] int32, rows
-    [n, block_size, n_kv, head_dim]."""
-    return pool.at[bids].set(rows.astype(pool.dtype))
+    [n, block_size, n_kv, head_dim] in token-row order."""
+    return pool.at[bids].set(block_rows(rows).astype(pool.dtype))
 
 
 def scatter_seq_blocks(pool: jax.Array, table_row: jax.Array,
@@ -269,7 +284,7 @@ def scatter_seq_blocks(pool: jax.Array, table_row: jax.Array,
     under an exact-zero mask. Real block ids are unique within a row
     (the allocator hands each out once), so live blocks get exactly
     their own scratch rows."""
-    return pool.at[table_row].set(rows.astype(pool.dtype))
+    return pool.at[table_row].set(block_rows(rows).astype(pool.dtype))
 
 
 def paged_decode_attention(q: jax.Array, k_new: jax.Array,
@@ -277,7 +292,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
                            v_pool: jax.Array, table: jax.Array,
                            pos: jax.Array, k_scale: jax.Array = None,
                            v_scale: jax.Array = None,
-                           fused=False, interpret=None):
+                           fused=False, interpret=None, write=None):
     """One decode step of attention over paged K/V.
 
     q: [B, 1, n_q, head_dim] (post-rope); k_new/v_new: [B, n_kv,
@@ -295,16 +310,23 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
     writes either way. Quantized (int8/fp8) pools pass k_scale/v_scale
     ([num_blocks, n_kv] f32): the new rows quantize at write time
     (frontier RMW, grid picked off the pool dtype) and the return grows
-    to (att, k_pool, v_pool, k_scale, v_scale)."""
+    to (att, k_pool, v_pool, k_scale, v_scale).
+
+    `write=(table, pos)` names the WRITE side's table and positions
+    where they are not the attend side's: sharded serving hands every
+    dp shard the rows of ALL slots (k_new/v_new then carry that larger
+    batch) so each replica of the pool takes every write and the
+    replicas stay equal, while each shard attends its own slots."""
     quant = k_scale is not None
+    wtable, wpos = (table, pos) if write is None else write
     if quant:
-        k_pool, k_scale = scatter_token_q(k_pool, k_scale, table, pos,
-                                          k_new)
-        v_pool, v_scale = scatter_token_q(v_pool, v_scale, table, pos,
-                                          v_new)
+        k_pool, k_scale = scatter_token_q(k_pool, k_scale, wtable,
+                                          wpos, k_new)
+        v_pool, v_scale = scatter_token_q(v_pool, v_scale, wtable,
+                                          wpos, v_new)
     else:
-        k_pool = scatter_token(k_pool, table, pos, k_new)
-        v_pool = scatter_token(v_pool, table, pos, v_new)
+        k_pool = scatter_token(k_pool, wtable, wpos, k_new)
+        v_pool = scatter_token(v_pool, wtable, wpos, v_new)
     if fused:
         fpa = (fused_paged_online_attention if fused == "online"
                else fused_paged_attention)
@@ -336,7 +358,7 @@ def paged_window_attention(q: jax.Array, k_new: jax.Array,
                            v_pool: jax.Array, table: jax.Array,
                            pos0: jax.Array, k_scale: jax.Array = None,
                            v_scale: jax.Array = None,
-                           fused=False, interpret=None):
+                           fused=False, interpret=None, write=None):
     """W-token speculative-verify attention over paged K/V.
 
     q: [B, W, n_q, head_dim] (post-rope); k_new/v_new: [B, W, n_kv,
@@ -360,16 +382,18 @@ def paged_window_attention(q: jax.Array, k_new: jax.Array,
     quantized pools that garbage ALSO sits under the block's absmax until
     rewritten — rejected rows can widen their block's scale, which
     costs the block's live rows at most one extra requantization
-    rounding, identically on the gather and fused paths."""
+    rounding, identically on the gather and fused paths. `write=` as
+    in `paged_decode_attention`."""
     quant = k_scale is not None
+    wtable, wpos0 = (table, pos0) if write is None else write
     if quant:
-        k_pool, k_scale = scatter_window_q(k_pool, k_scale, table,
-                                           pos0, k_new)
-        v_pool, v_scale = scatter_window_q(v_pool, v_scale, table,
-                                           pos0, v_new)
+        k_pool, k_scale = scatter_window_q(k_pool, k_scale, wtable,
+                                           wpos0, k_new)
+        v_pool, v_scale = scatter_window_q(v_pool, v_scale, wtable,
+                                           wpos0, v_new)
     else:
-        k_pool = scatter_window(k_pool, table, pos0, k_new)
-        v_pool = scatter_window(v_pool, table, pos0, v_new)
+        k_pool = scatter_window(k_pool, wtable, wpos0, k_new)
+        v_pool = scatter_window(v_pool, wtable, wpos0, v_new)
     if fused:
         fpa = (fused_paged_online_attention if fused == "online"
                else fused_paged_attention)

@@ -49,7 +49,7 @@ def sharded_heat_step(mesh: Mesh, axis: str = "x",
     The returned fn(u_sharded, coef) keeps u sharded over `axis`;
     ICI traffic is 2 * halo_steps elements per shard per call.
     """
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
 
     w = halo_steps
 
@@ -72,7 +72,7 @@ def sharded_multistep(mesh: Mesh, axis: str, steps: int,
     """T-step sharded stencil: fori_loop of exchange+update inside ONE
     jitted program — the whole time loop is a single XLA computation with
     ICI collectives compiled in (no host round-trips)."""
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
 
     w = halo_steps
     outer = steps // w

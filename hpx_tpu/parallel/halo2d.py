@@ -84,7 +84,7 @@ def sharded_jacobi_step(mesh: Mesh, grid: Tuple[int, int],
     the convergence diagnostic, computed on-device so the host never syncs
     unless it reads it.
     """
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
 
     nx, ny = grid
     npx, npy = mesh.shape[ax], mesh.shape[ay]
@@ -108,7 +108,7 @@ def sharded_jacobi_multistep(mesh: Mesh, grid: Tuple[int, int], steps: int,
     shard_map): per-sweep halo exchange rides ICI with no host round-trip.
     fn(u) -> (u_new, last_residual).
     """
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
 
     nx, ny = grid
     npx, npy = mesh.shape[ax], mesh.shape[ay]

@@ -154,7 +154,7 @@ def device_spmd_block(fn: Callable[..., Any], mesh: Any = None,
         out = step(sharded_array)
     """
     import jax
-    from ..utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if mesh is None:

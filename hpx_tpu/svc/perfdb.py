@@ -56,8 +56,8 @@ Provenance rides every row with the same stamps as bench.py:
 ``onchip``/``provenance`` default from the live backend (TPU ->
 ``on-chip``, anything else -> ``builder-session``), and
 ``ladder_search`` refuses to mint a "learned" ladder from
-builder-session-only samples without ``--allow-session`` — the
-ROADMAP tunnel backlog stays honest.
+builder-session-only samples without ``--allow-session``: a number
+from a CPU run is never trusted as a device measurement.
 
 Counters: ``/perfdb{locality#N/total}/{keys,observations,hits,misses,
 stale}`` — hits/misses count boot-time ladder lookups; ``stale``
@@ -185,7 +185,7 @@ def device_kind() -> str:
 def _default_stamps() -> Dict[str, Any]:
     """bench.py's provenance discipline, computed from the live
     backend: rows measured off-TPU are builder-session, never
-    on-chip — see the ROADMAP tunnel-backlog note."""
+    on-chip."""
     try:
         import jax
         onchip = jax.default_backend() == "tpu"

@@ -1,4 +1,4 @@
-"""8-locality soak (VERDICT r2 #7 / r3 plan #9): collectives
+"""8-locality soak: collectives
 generations, the communication_set tree across real processes, a
 channel-communicator soak, and a concurrent migrate-vs-invoke storm on
 components. Exit 0 per locality on success.

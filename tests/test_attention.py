@@ -163,7 +163,7 @@ class TestRing:
         q, k, v = (jax.device_put(x, sh) for x in (q, k, v))
         want = reference_attention(q, k, v, True)
 
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         import hpx_tpu.ops.attention as att
 
         def body(qc, kc, vc):
@@ -253,7 +253,7 @@ class TestGqaXlaPaths:
     def test_ring_sharded_gqa(self, devices):
         """GQA through the XLA ring path under a 4-shard sp mesh."""
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from hpx_tpu.ops.attention import (reference_attention,
                                            ring_attention_sharded)
         mesh = Mesh(np.array(devices[:4]), ("sp",))
@@ -293,7 +293,7 @@ class TestGqaXlaPaths:
         library broadcasts grouped K/V before the chunk kernel —
         regression for the nshards>1 flash branch."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from hpx_tpu.ops.attention import (reference_attention,
                                            ring_attention_sharded)
         mesh = Mesh(np.array(devices[:4]), ("sp",))

@@ -123,7 +123,7 @@ class TestRingFlashGrad:
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_oracle(self, causal, devices):
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(devices[:4]), ("sp",))
         B, S, N, H = 2, 64, 2, 32
@@ -145,7 +145,7 @@ class TestRingFlashGrad:
         _cmp(got, want, 3e-4)
 
     def test_forward_value_matches(self, devices):
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(devices[:4]), ("sp",))
         B, S, N, H = 2, 64, 2, 32
@@ -235,7 +235,7 @@ class TestStripedRingGrad:
     through the XLA scan."""
 
     def _striped(self, q, k, v, w, devices, use_flash):
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from hpx_tpu.ops.attention import (
             ring_attention_sharded, stripe_sequence)
@@ -277,7 +277,7 @@ class TestRingFlashGQAGrad:
 
     @pytest.mark.parametrize("striped", [False, True])
     def test_matches_repeat_oracle(self, striped, devices):
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from hpx_tpu.ops.attention import _ring_flash, stripe_sequence
         mesh = Mesh(np.array(devices[:4]), ("sp",))
@@ -320,7 +320,7 @@ class TestRingFlashGQAGrad:
     def test_grouped_chunks_on_the_wire(self, devices):
         """The compiled program must ppermute KV-sized buffers, never
         q-head-expanded ones — the whole point of grouped GQA rings."""
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from hpx_tpu.ops.attention import ring_attention_sharded
         mesh = Mesh(np.array(devices[:4]), ("sp",))

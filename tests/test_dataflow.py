@@ -330,7 +330,7 @@ def step(fn, pool, tok):
 
 HPX021_BAD = """\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 def build(devs):
@@ -362,7 +362,7 @@ def test_hpx021_declared_axis_is_silent():
 def test_hpx021_specs_fallback_when_mesh_is_opaque():
     src = """\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 def build(mesh):
@@ -382,7 +382,7 @@ def test_hpx021_opaque_mesh_and_specs_skip_not_guess():
     # though "tp" looks suspicious
     src = """\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def build(mesh, pspecs):
     def body(x):
@@ -396,7 +396,7 @@ def build(mesh, pspecs):
 def test_hpx021_partition_spec_fragment_in_body():
     src = """\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 def build(devs):
@@ -420,7 +420,7 @@ def build(devs):
 # path must CHECK these collectives, not skip them.
 HPX021_EP = """\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 def _moe(x):

@@ -421,8 +421,10 @@ def test_export_fetch_prefix_roundtrip_quantized(params, kvd):
                 g = np.asarray(pool)[np.asarray(bids)]
                 sc = np.asarray(srv._scales[li][side])[
                     np.asarray(bids)]
+                # pools are [blocks, kv heads, rows, hd]; rows export
+                # in token order
                 ref = (g.astype(np.float32)
-                       * sc[:, None, :, None]).reshape(
+                       * sc[:, :, None, None]).swapaxes(1, 2).reshape(
                            matched, CFG.kv_heads, CFG.head_dim)
                 np.testing.assert_array_equal(
                     rows[li, side], ref.astype(rows.dtype))

@@ -73,8 +73,8 @@ def _paged_state(bs, maxb, B=3, nkv=2, nq=4, hd=8, w=1,
     positions, one slot pinned to the partial-first-block corner."""
     rng = np.random.default_rng(seed)
     nb = B * maxb + 2
-    kp = jnp.asarray(rng.standard_normal((nb, bs, nkv, hd)), dtype)
-    vp = jnp.asarray(rng.standard_normal((nb, bs, nkv, hd)), dtype)
+    kp = jnp.asarray(rng.standard_normal((nb, nkv, bs, hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((nb, nkv, bs, hd)), dtype)
     perm = rng.permutation(np.arange(1, nb))[:B * maxb]
     table = jnp.asarray(perm.reshape(B, maxb).astype(np.int32))
     pos = rng.integers(0, maxb * bs - w, size=B).astype(np.int32)
@@ -219,20 +219,20 @@ def test_fp8_quantize_roundtrip():
     round-trip lands on the fp8 grid — relative error bounded by the
     format's 2^-4 mantissa step, never biased past one step."""
     rng = np.random.default_rng(9)
-    rows = jnp.asarray(rng.standard_normal((4, 16, 2, 8)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((4, 2, 16, 8)), jnp.float32)
     pq, sc = quantize_blocks(rows, jnp.float8_e4m3fn)
     assert pq.dtype == jnp.float8_e4m3fn
     assert sc.shape == (4, 2)                 # per-(block, kv-head)
     deq = (np.asarray(pq, np.float32)
-           * np.asarray(sc)[:, None, :, None])
+           * np.asarray(sc)[:, :, None, None])
     orig = np.asarray(rows)
     err = np.abs(deq - orig)
-    amax = np.abs(orig).max(axis=(1, 3), keepdims=True)
+    amax = np.abs(orig).max(axis=(2, 3), keepdims=True)
     assert (err <= np.abs(orig) * 2.0 ** -4 + amax * 2.0 ** -7).all()
 
 
 def test_quantize_blocks_rejects_unknown_dtype():
-    rows = jnp.zeros((1, 4, 1, 8), jnp.float32)
+    rows = jnp.zeros((1, 1, 4, 8), jnp.float32)
     with pytest.raises(ValueError, match="unsupported pool dtype"):
         quantize_blocks(rows, jnp.float16)
 
@@ -274,7 +274,7 @@ def test_scatter_window_q_oob_drops_rows_and_scales():
     block's scale via the sidecar's own scatter."""
     bs, maxb, nkv, hd = 4, 2, 2, 8
     rng = np.random.default_rng(3)
-    base = jnp.asarray(rng.standard_normal((3, bs, nkv, hd)),
+    base = jnp.asarray(rng.standard_normal((3, nkv, bs, hd)),
                        jnp.float32)
     pq, sc = quantize_blocks(base)
     table = jnp.asarray([[0, 1]], jnp.int32)
@@ -288,15 +288,17 @@ def test_scatter_window_q_oob_drops_rows_and_scales():
     assert (np.asarray(nsc[0]) == np.asarray(sc[0])).all()
     assert (np.asarray(nsc[2]) == np.asarray(sc[2])).all()
     deq = (np.asarray(npq[1], np.float32)
-           * np.asarray(nsc[1])[None, :, None])
+           * np.asarray(nsc[1])[:, None, None])
     orig = np.asarray(base[1])
     amax = np.abs(np.asarray(vals)).max() + np.abs(orig).max()
     tol = amax / 127 + 1e-6                 # one quantization step
     # the two in-range rows hold the window's first two values; the
     # block's pre-existing rows survive the RMW requantization
-    np.testing.assert_allclose(deq[2], np.asarray(vals[0, 0]), atol=tol)
-    np.testing.assert_allclose(deq[3], np.asarray(vals[0, 1]), atol=tol)
-    np.testing.assert_allclose(deq[:2], orig[:2], atol=tol)
+    np.testing.assert_allclose(deq[:, 2], np.asarray(vals[0, 0]),
+                               atol=tol)
+    np.testing.assert_allclose(deq[:, 3], np.asarray(vals[0, 1]),
+                               atol=tol)
+    np.testing.assert_allclose(deq[:, :2], orig[:, :2], atol=tol)
 
 
 def test_scatter_window_q_oob_drops_fp8_rows_and_scales():
@@ -306,7 +308,7 @@ def test_scatter_window_q_oob_drops_fp8_rows_and_scales():
     new scatter bug — pin it anyway."""
     bs, maxb, nkv, hd = 4, 2, 2, 8
     rng = np.random.default_rng(13)
-    base = jnp.asarray(rng.standard_normal((3, bs, nkv, hd)),
+    base = jnp.asarray(rng.standard_normal((3, nkv, bs, hd)),
                        jnp.float32)
     pq, sc = quantize_blocks(base, jnp.float8_e4m3fn)
     table = jnp.asarray([[0, 1]], jnp.int32)
@@ -321,13 +323,15 @@ def test_scatter_window_q_oob_drops_fp8_rows_and_scales():
     assert (np.asarray(nsc[0]) == np.asarray(sc[0])).all()
     assert (np.asarray(nsc[2]) == np.asarray(sc[2])).all()
     deq = (np.asarray(npq[1], np.float32)
-           * np.asarray(nsc[1])[None, :, None])
+           * np.asarray(nsc[1])[:, None, None])
     orig = np.asarray(base[1])
     amax = np.abs(np.asarray(vals)).max() + np.abs(orig).max()
     tol = amax * 2.0 ** -4 + 1e-6           # one e4m3 grid step
-    np.testing.assert_allclose(deq[2], np.asarray(vals[0, 0]), atol=tol)
-    np.testing.assert_allclose(deq[3], np.asarray(vals[0, 1]), atol=tol)
-    np.testing.assert_allclose(deq[:2], orig[:2], atol=tol)
+    np.testing.assert_allclose(deq[:, 2], np.asarray(vals[0, 0]),
+                               atol=tol)
+    np.testing.assert_allclose(deq[:, 3], np.asarray(vals[0, 1]),
+                               atol=tol)
+    np.testing.assert_allclose(deq[:, :2], orig[:, :2], atol=tol)
 
 
 # -- block-size resolution ---------------------------------------------------

@@ -80,7 +80,7 @@ def test_residual_decreases(mesh2d):
 
 def test_edge_shift_zero_fills(mesh1d):
     """Non-periodic shift: boundary shard receives zeros (Dirichlet)."""
-    from hpx_tpu.utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from hpx_tpu.parallel.halo2d import edge_shift
 

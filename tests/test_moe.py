@@ -91,7 +91,7 @@ class TestSingleShard:
 class TestExpertParallel:
     @pytest.mark.parametrize("top_k", [1, 2])
     def test_sharded_matches_single_shard(self, top_k, devices):
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         ep = 4
         mesh = Mesh(np.array(devices[:ep]), ("ep",))
@@ -129,7 +129,7 @@ class TestExpertParallel:
                                    rtol=1e-5)
 
     def test_sharded_grads_match(self, devices):
-        from hpx_tpu.utils.jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         ep = 2
         mesh = Mesh(np.array(devices[:ep]), ("ep",))

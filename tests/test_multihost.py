@@ -64,7 +64,7 @@ def test_init_single_process_real():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     code = (
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
+        "import jax\n"
         "from hpx_tpu.parallel import multihost\n"
         "ok = multihost.init(coordinator_address='127.0.0.1:12357',\n"
         "                    num_processes=1, process_id=0)\n"

@@ -14,7 +14,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from hpx_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from hpx_tpu.ops.attention import _pvary

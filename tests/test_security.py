@@ -1,6 +1,6 @@
 """Parcelport security: auth handshake, bind policy, stale-.so guard,
-and backend gating — regression tests for the round-2/3 advisor
-findings (VERDICT.md weak #5).
+and backend gating — regression tests for findings of the round-2/3
+reviews.
 
 The core property under test: bytes from an unauthenticated connection
 must NEVER reach pickle. A raw TCP client sends a pickled payload whose

@@ -115,7 +115,7 @@ def test_generate_consistent_with_forward():
     mesh1 = tfm.make_mesh_3d(1)
     sp = 1
     from hpx_tpu.models.transformer import _ln, _block
-    from hpx_tpu.utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def fwd(p, toks):
@@ -157,7 +157,7 @@ def test_generate_matches_full_forward_oracle():
     # oracle: grow the sequence one token at a time through the full
     # forward pass (same shard_map-on-mesh1 path the other tests use)
     from hpx_tpu.models.transformer import _ln, _block
-    from hpx_tpu.utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def fwd(p, toks):
@@ -434,7 +434,7 @@ def test_rope_positions_matter():
     prompts aside)."""
     params = tfm.init_params(ROPE_CFG, jax.random.PRNGKey(0))
     from hpx_tpu.models.transformer import _ln, _block
-    from hpx_tpu.utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh1 = tfm.make_mesh_3d(1)
     sp = tfm.shard_params(params, ROPE_CFG, mesh1)
@@ -492,7 +492,7 @@ def test_rope_generate_matches_forward_oracle():
     out = tfm.generate(params, ROPE_CFG, prompt, max_new=6)
 
     from hpx_tpu.models.transformer import _ln, _block
-    from hpx_tpu.utils.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def fwd(p, toks):

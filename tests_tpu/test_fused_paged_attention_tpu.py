@@ -6,7 +6,9 @@ AND the O(block)-scratch `fused_online` online-softmax kernel. tests/
 covers interpret mode on CPU; this is the only place the actual
 Mosaic lowering (incl. the double-buffered online carry) is checked,
 so a regression fails a test instead of silently showing up as a
-serving numerics drift. Skips cleanly off-chip (see conftest)."""
+serving numerics drift. Skipped only under JAX_PLATFORMS=cpu (see
+conftest). Head shapes: the small 4q/2kv x 64 one and the smoke's
+16q/4kv x 128."""
 
 import jax
 import jax.numpy as jnp
@@ -16,10 +18,15 @@ import pytest
 pytestmark = pytest.mark.tpu
 
 
+HEADS = pytest.mark.parametrize(
+    "nkv,nq,hd", [(2, 4, 64), (4, 16, 128)], ids=["4q2kv_h64",
+                                                  "16q4kv_h128"])
+
+
 def _pools(nb, bs, nkv, hd, dtype=jnp.bfloat16, seed=0):
     rng = np.random.default_rng(seed)
     return tuple(jnp.asarray(
-        rng.standard_normal((nb, bs, nkv, hd), np.float32), dtype)
+        rng.standard_normal((nb, nkv, bs, hd), np.float32), dtype)
         for _ in range(2))
 
 
@@ -36,9 +43,10 @@ def _close(a, b, tol):
 
 
 class TestFusedPagedDecode:
-    def test_matches_gather_bf16(self):
+    @HEADS
+    def test_matches_gather_bf16(self, nkv, nq, hd):
         from hpx_tpu.ops.paged_attention import paged_decode_attention
-        B, nb, bs, maxb, nkv, nq, hd = 2, 16, 16, 4, 2, 4, 64
+        B, nb, bs, maxb = 2, 16, 16, 4
         kp, vp = _pools(nb, bs, nkv, hd)
         table = _table(B, maxb, nb)
         pos = jnp.asarray([37, 22], jnp.int32)
@@ -60,12 +68,13 @@ class TestFusedPagedDecode:
         # past the bitwise kernel's — identical bf16 tolerance here
         _close(run("online"), run(False), 3e-2)
 
-    def test_matches_gather_int8(self):
+    @HEADS
+    def test_matches_gather_int8(self, nkv, nq, hd):
         """int8 pools + absmax scale sidecars: both paths dequantize
         the SAME stored bytes, so they agree to bf16 tolerance."""
         from hpx_tpu.ops.paged_attention import (paged_decode_attention,
                                                  quantize_blocks)
-        B, nb, bs, maxb, nkv, nq, hd = 2, 16, 32, 2, 2, 4, 64
+        B, nb, bs, maxb = 2, 16, 32, 2
         kf, vf = _pools(nb, bs, nkv, hd, seed=3)
         kp, ks = quantize_blocks(kf)
         vp, vs = quantize_blocks(vf)
@@ -88,14 +97,15 @@ class TestFusedPagedDecode:
         _close(run(True), run(False), 3e-2)
         _close(run("online"), run(False), 3e-2)
 
-    def test_matches_gather_fp8(self):
+    @HEADS
+    def test_matches_gather_fp8(self, nkv, nq, hd):
         """fp8 (e4m3) pools + the same f32 scale sidecars: the Mosaic
         lowering of the in-kernel float8 dequant must agree with the
         gather formulation over the same stored bytes — both fused
         kernels."""
         from hpx_tpu.ops.paged_attention import (paged_decode_attention,
                                                  quantize_blocks)
-        B, nb, bs, maxb, nkv, nq, hd = 2, 16, 32, 2, 2, 4, 64
+        B, nb, bs, maxb = 2, 16, 16, 4     # the server's default block
         kf, vf = _pools(nb, bs, nkv, hd, seed=9)
         kp, ks = quantize_blocks(kf, jnp.float8_e4m3fn)
         vp, vs = quantize_blocks(vf, jnp.float8_e4m3fn)
@@ -120,11 +130,12 @@ class TestFusedPagedDecode:
 
 
 class TestFusedPagedWindow:
-    def test_matches_gather_bf16(self):
+    @HEADS
+    def test_matches_gather_bf16(self, nkv, nq, hd):
         """The verify-window horizon (row i attends <= pos0+i) must
         agree between the kernel's per-row mask and the gather mask."""
         from hpx_tpu.ops.paged_attention import paged_window_attention
-        B, W, nb, bs, maxb, nkv, nq, hd = 2, 4, 16, 16, 4, 2, 4, 64
+        B, W, nb, bs, maxb = 2, 4, 16, 16, 4
         kp, vp = _pools(nb, bs, nkv, hd, seed=6)
         table = _table(B, maxb, nb, seed=7)
         pos0 = jnp.asarray([29, 12], jnp.int32)

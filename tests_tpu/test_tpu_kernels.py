@@ -40,7 +40,7 @@ class TestFlashForward:
                        )(q, k, v)
         _close(got, want, 3e-2)
 
-    def test_f32_tighter(self):
+    def test_f32_tighter(self, float32_products):
         from hpx_tpu.ops.attention import blockwise_attention
         from hpx_tpu.ops.attention_pallas import flash_attention
         q, k, v = _qkv(1, 512, 2, 128, dtype=jnp.float32)
@@ -70,7 +70,7 @@ class TestFlashBackward:
 
 
 class TestChunkKernel:
-    def test_host_simulated_ring(self):
+    def test_host_simulated_ring(self, float32_products):
         """flash_attention_chunk (scalar-prefetch d) compiled by Mosaic:
         fold all chunks of a 4-way ring on-chip, compare to the
         reference O(S^2) oracle."""
@@ -104,7 +104,7 @@ class TestChunkKernel:
 
 
 class TestRingInShardMap:
-    def test_vma_checked_shard_map_single_chip(self):
+    def test_vma_checked_shard_map_single_chip(self, float32_products):
         """The exact wiring the training step uses — _ring_flash inside
         a vma-checked shard_map (degenerate 1-device mesh on one chip;
         multi-chip runs the same code over real ICI)."""
@@ -121,7 +121,7 @@ class TestRingInShardMap:
             mesh=mesh, in_specs=(spec,) * 3, out_specs=spec))(q, k, v)
         _close(out, blockwise_attention(q, k, v, True), 3e-4)
 
-    def test_grad_through_shard_map(self):
+    def test_grad_through_shard_map(self, float32_products):
         from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
@@ -173,9 +173,9 @@ class TestTrainStepOnChip:
         def run(use_flash):
             orig = att.ring_attention_sharded
 
-            def patched(qc, kc, vc, axis, nshards, causal=False):
+            def patched(qc, kc, vc, axis, nshards, causal=False, **kw):
                 return orig(qc, kc, vc, axis, nshards, causal,
-                            use_flash=use_flash)
+                            use_flash=use_flash, **kw)
 
             att.ring_attention_sharded = patched
             tfm.ring_attention_sharded = patched
@@ -354,7 +354,7 @@ class TestStripedAndGQAChunks:
     offsets (d in {0,-1}) and GQA row-remapped K/V tiles in
     flash_attention_chunk."""
 
-    def test_striped_chunk_fold(self):
+    def test_striped_chunk_fold(self, float32_products):
         from hpx_tpu.ops.attention import (reference_attention,
                                            stripe_sequence,
                                            unstripe_sequence)
@@ -387,7 +387,7 @@ class TestStripedAndGQAChunks:
                                 nsh).astype(q.dtype)
         _close(got, want, 3e-4)
 
-    def test_gqa_grouped_chunk_fold(self):
+    def test_gqa_grouped_chunk_fold(self, float32_products):
         """Grouped K/V rows through the chunk kernel's BlockSpec remap
         (the grouped-wire ring path) vs the repeat oracle."""
         from hpx_tpu.ops.attention import reference_attention
