@@ -97,14 +97,14 @@ _PAGED_ITEMSIZE = {"bf16": 2, "int8": 1, "fp8": 1}
 _PAGED_KERNELS = ("gather", "fused", "fused_online")
 
 
-def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3):
-    """Time one paged decode attention step at the serving shape:
+def paged_step(jax, jnp, S, bs, kvd, kern):
+    """Build one paged decode attention step at the serving shape:
     8 slots, every table fully mapped to DISTINCT pool blocks at a
     near-S horizon (the steady-state worst case — block-size effects
     show up as grid/tiling overhead, not masked work). `kern` picks
     the formulation: gather (XLA oracle), fused (bitwise Pallas), or
-    fused_online (O(block)-scratch online softmax). Returns
-    (HBM-read GB/s, us per call, spread)."""
+    fused_online (O(block)-scratch online softmax). Returns (jitted
+    step, its q operand, HBM bytes one call reads)."""
     from hpx_tpu.ops.attention_pallas import (fused_paged_attention,
                                               fused_paged_online_attention)
     from hpx_tpu.ops.paged_attention import (gather_block_kv,
@@ -126,8 +126,9 @@ def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((B, 1, nq, H), np.float32),
                     jnp.bfloat16)
-    kp = rng.standard_normal((nb, bs, nkv, H), np.float32)
-    vp = rng.standard_normal((nb, bs, nkv, H), np.float32)
+    # pool layout: heads ahead of rows (ops/paged_attention)
+    kp = rng.standard_normal((nb, nkv, bs, H), np.float32)
+    vp = rng.standard_normal((nb, nkv, bs, H), np.float32)
     table = jnp.asarray(
         np.arange(1, B * maxb + 1, dtype=np.int32).reshape(B, maxb))
     pos = jnp.full((B,), S - 1, jnp.int32)
@@ -160,6 +161,16 @@ def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3):
                else fused_paged_attention)
         f = jax.jit(lambda qq: fpa(qq, kq, vq, table, pos,
                                    k_scale=ks, v_scale=vs))
+    hbm = 2 * B * maxb * bs * nkv * H * itemsize    # K + V pool reads
+    if kvd in ("int8", "fp8"):
+        hbm += 2 * B * maxb * nkv * 4               # scale sidecars
+    return f, q, hbm
+
+
+def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3):
+    """Time `paged_step`. Returns (HBM-read GB/s, us per call,
+    spread)."""
+    f, q, hbm = paged_step(jax, jnp, S, bs, kvd, kern)
     out = f(q)
     jax.block_until_ready(out)
 
@@ -173,9 +184,6 @@ def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3):
 
     pers = sorted(slope_time(chain, 8, 50) for _ in range(samples))
     per = pers[(samples - 1) // 2]
-    hbm = 2 * B * maxb * bs * nkv * H * itemsize    # K + V pool reads
-    if kvd in ("int8", "fp8"):
-        hbm += 2 * B * maxb * nkv * 4               # scale sidecars
     return hbm / per / 1e9, per * 1e6, (pers[-1] - pers[0]) / per
 
 

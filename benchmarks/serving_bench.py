@@ -226,7 +226,6 @@ def main() -> int:
     if "--cpu" in sys.argv:
         os.environ["JAX_PLATFORMS"] = "cpu"     # before jax is imported
     import jax
-    from hpx_tpu.utils.compile_cache import enable_compile_cache
     dev = jax.devices()[0]
     print(f"# device: platform={dev.platform} kind={dev.device_kind} "
           f"count={len(jax.devices())}", file=sys.stderr, flush=True)
@@ -234,7 +233,12 @@ def main() -> int:
         print("serving_bench measures on a TPU; --cpu is the only way "
               "onto the CPU", file=sys.stderr)
         return 1
-    enable_compile_cache()
+    # no persistent compile cache here, even where the environment
+    # places one: cold compiles are a MEASURED quantity of this bench
+    # (compile counts, cold TTFT, the ladder search's compile_s), and
+    # `_PROGRAMS.clear()` is a true cold boot only while no earlier
+    # run's programs come back from disk
+    jax.config.update("jax_enable_compilation_cache", False)
     import jax.numpy as jnp
     import numpy as np
     from hpx_tpu.models import transformer as tfm

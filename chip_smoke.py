@@ -46,12 +46,13 @@ TRAIN_LAYERS = 4
 MESH_LAYERS = 4
 PROMPT_RUNGS = (128, 256, 512, 1024)   # few distinct lengths: generate()
                                        # compiles one program per length
-# tolerances, fixed before the first chip run
+# tolerances, fixed before the first chip run (TIE_TOL then 0.25; cut
+# once the chip's gaps were known)
 DOT_RTOL = 1e-4          # f32 tree reduction of 2^24 products vs float64
 STENCIL_ATOL = 1e-5      # f32 heat steps vs the same f32 NumPy expression
 FUSED_ATOL = 1e-3        # 1024 fused f32 steps, reassociated
 TRAIN_LOSS_TOL = 0.05    # bf16 flash train loss vs f32 materialised softmax
-TIE_TOL = 0.25           # see near_tie_gap
+TIE_TOL = 0.1            # see near_tie_gap; the chip's largest gap: 0.038
 LIMIT_SECONDS = 1150     # the contract allows 1200, compilation included
 
 # tuning tables and records a checkout does not carry: the smoke runs

@@ -357,6 +357,20 @@ def _dp_rows(x, dp):
             else jax.lax.bitcast_convert_type(out, x.dtype))
 
 
+def _write_rows(k, v, write):
+    """(k_new, v_new, paged_attention's `write=`) for one layer. Off
+    the mesh (`write` None) a step writes the rows it attends. On it,
+    `write` = (dp, all-slot table, all-slot positions): every dp shard
+    writes EVERY slot's new rows into its copy of the pools — K and V
+    closed by one psum over dp — which is what keeps the copies equal,
+    the replication the pool spec declares."""
+    if write is None:
+        return k, v, None
+    dp, table, pos = write
+    kv = _dp_rows(jnp.stack([k, v], axis=1), dp)
+    return kv[:, 0], kv[:, 1], (table, pos)
+
+
 def _block_decode_rows(x, lp, kv, pos, cfg: TransformerConfig,
                        moe_cf=None, moe_ep=None, moe_sink=None,
                        moe_ms=None):
@@ -420,7 +434,7 @@ def _decode_rows(params, caches, tok, pos, cfg, moe_cf=None,
 def _paged_block_rows(x, lp, pools, scales, table, pos,
                       cfg: TransformerConfig, fused=False,
                       tp_axis=None, moe_cf=None, moe_ep=None,
-                      moe_sink=None, dp=None, write=None):
+                      moe_sink=None, write=None):
     """_block_decode_rows with the K/V rows living in a shared BLOCK
     POOL instead of per-slot dense buffers. x: [B, 1, D]; pools:
     (k_pool, v_pool) each [num_blocks, Nkv, block_size, H]; scales:
@@ -436,10 +450,7 @@ def _paged_block_rows(x, lp, pools, scales, table, pos,
     tensor-parallel axis: every shard sees its LOCAL kv-head slice of
     the pools (block axis replicated over dp) and the partial attention
     / ffn outputs close with explicit psums — the same two reduction
-    points `_block_decode` uses. `dp` = (axis, size) with `write` =
-    the all-slot (table, pos): every dp shard then writes EVERY slot's
-    new rows into its copy of the pools, which is what keeps the
-    copies equal — the replication the pool spec declares."""
+    points `_block_decode` uses; `write` as in `_write_rows`."""
     kp, vp = pools
     b = x.shape[0]
     h = _ln(x, lp["ln1"])
@@ -447,7 +458,7 @@ def _paged_block_rows(x, lp, pools, scales, table, pos,
     if cfg.rope:
         q = _rope_rows(q, pos, cfg)
         k = _rope_rows(k, pos, cfg)
-    kn, vn = _dp_rows(k[:, 0], dp), _dp_rows(v[:, 0], dp)
+    kn, vn, write = _write_rows(k[:, 0], v[:, 0], write)
     if scales is None:
         att, kp, vp = paged_decode_attention(q, kn, vn, kp, vp, table,
                                              pos, fused=fused,
@@ -485,13 +496,12 @@ def _paged_decode_rows(params, pools, scales, tok, table, pos, cfg,
     x = params["emb"][tok][:, None, :]
     new_pools, new_scales = [], []
     sink = []
-    write = None if dp is None else (_dp_rows(table, dp),
-                                     _dp_rows(pos, dp))
+    write = dp and (dp, _dp_rows(table, dp), _dp_rows(pos, dp))
     for i, (lp, pl) in enumerate(zip(params["layers"], pools)):
         sc = None if scales is None else scales[i]
         x, pl, sc = _paged_block_rows(x, lp, pl, sc, table, pos, cfg,
                                       fused, tp_axis, moe_cf, moe_ep,
-                                      sink, dp, write)
+                                      sink, write)
         new_pools.append(pl)
         new_scales.append(sc)
     x = _ln(x, params["ln_f"])
@@ -570,14 +580,14 @@ def _decode_window_rows(params, caches, toks, pos0, cfg, moe_cf=None,
 def _paged_window_rows(x, lp, pools, scales, table, pos0,
                        cfg: TransformerConfig, fused=False,
                        tp_axis=None, moe_cf=None, moe_ep=None,
-                       moe_sink=None, dp=None, write=None):
+                       moe_sink=None, write=None):
     """`_window_rows` over paged pools: the scatter/gather and the
     per-query horizon live in `ops.paged_attention.
     paged_window_attention`; projections/rope/ffn are byte-identical
     to the dense window, which keeps paged == dense token-exact under
     speculation too. `tp_axis` closes the per-shard partial sums under
-    shard_map, and `dp` / `write` keep the pool copies equal, exactly
-    as in `_paged_block_rows`."""
+    shard_map, and `write` keeps the pool copies equal, exactly as in
+    `_paged_block_rows`."""
     kp, vp = pools
     b, w = x.shape[0], x.shape[1]
     h = _ln(x, lp["ln1"])
@@ -586,7 +596,7 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
     if cfg.rope:
         q = _rope_win(q, posw, cfg)
         k = _rope_win(k, posw, cfg)
-    kn, vn = _dp_rows(k, dp), _dp_rows(v, dp)
+    kn, vn, write = _write_rows(k, v, write)
     if scales is None:
         att, kp, vp = paged_window_attention(q, kn, vn, kp, vp, table,
                                              pos0, fused=fused,
@@ -621,13 +631,12 @@ def _paged_decode_window_rows(params, pools, scales, toks, table, pos0,
     x = params["emb"][toks]
     new_pools, new_scales = [], []
     sink = []
-    write = None if dp is None else (_dp_rows(table, dp),
-                                     _dp_rows(pos0, dp))
+    write = dp and (dp, _dp_rows(table, dp), _dp_rows(pos0, dp))
     for i, (lp, pl) in enumerate(zip(params["layers"], pools)):
         sc = None if scales is None else scales[i]
         x, pl, sc = _paged_window_rows(x, lp, pl, sc, table, pos0, cfg,
                                        fused, tp_axis, moe_cf, moe_ep,
-                                       sink, dp, write)
+                                       sink, write)
         new_pools.append(pl)
         new_scales.append(sc)
     x = _ln(x, params["ln_f"])

@@ -86,21 +86,20 @@ def test_program_cache_is_o_buckets(params):
     def mine(k):
         return k[0] == "cb_chunk" and k[1] == CFG and k[3] == 64
 
-    # the cache is process-wide: another test file of this shape in the
-    # same xdist worker may have left other widths, so judge what THIS
-    # workload added
-    before = set(tfm._PROGRAMS)
+    # the cache is process-wide: drop what earlier tests of this shape
+    # (this file or another in the same xdist worker) left, so the
+    # count below is this workload's alone
+    for k in [k for k in tfm._PROGRAMS if mine(k)]:
+        del tfm._PROGRAMS[k]
     srv = ContinuousServer(params, CFG, slots=3, smax=64,
                            prefill_chunk=CHUNK, prefill_buckets=LADDER)
     for plen in PLENS:
         srv.submit(_prompt(plen, seed=200 + plen), max_new=4)
     srv.run()
-    added = [k for k in set(tfm._PROGRAMS) - before if mine(k)]
-    assert len(added) <= len(srv.prefill_buckets)
-    assert {k[2] for k in added} <= set(srv.prefill_buckets)
-    # built now or by an earlier test of this file: the ladder is there
-    assert any(mine(k) and k[2] in srv.prefill_buckets
-               for k in tfm._PROGRAMS)
+    chunk_keys = [k for k in tfm._PROGRAMS if mine(k)]
+    assert 0 < len(chunk_keys) <= len(srv.prefill_buckets)
+    widths = sorted(k[2] for k in chunk_keys)
+    assert set(widths) <= set(srv.prefill_buckets)
 
 
 def test_second_server_reuses_programs(params):

@@ -266,6 +266,24 @@ def test_fused_fp8_matches_gather_fp8(bs):
                                rtol=5e-6, atol=5e-6)
 
 
+@pytest.mark.parametrize("kern", ["gather", "fused", "fused_online"])
+@pytest.mark.parametrize("kvd", ["bf16", "int8", "fp8"])
+def test_flash_tune_paged_step_follows_pool_layout(kvd, kern):
+    """The block-size sweep (benchmarks/flash_tune.py --paged) builds
+    its own pools: every kernel it times must accept them and agree
+    with the gather formulation over the same pools, so a pool layout
+    move cannot skip the tool that banks ops/paged_blocks.json."""
+    from benchmarks import flash_tune
+    f, q, hbm = flash_tune.paged_step(jax, jnp, 32, 16, kvd, kern)
+    out = np.asarray(f(q), np.float32)
+    assert out.shape == q.shape and np.isfinite(out).all()
+    assert hbm > 0
+    g, qg, _ = flash_tune.paged_step(jax, jnp, 32, 16, kvd, "gather")
+    # bf16 outputs: one ulp at |x| <= 1
+    np.testing.assert_allclose(out, np.asarray(g(qg), np.float32),
+                               atol=8e-3)
+
+
 # -- quantized scatter: OOB drop regression ---------------------------------
 
 def test_scatter_window_q_oob_drops_rows_and_scales():
