@@ -50,6 +50,7 @@ from ..core.config import runtime_config
 from ..futures.future import (Future, SharedState, make_exceptional_future,
                               make_ready_future)
 from .executors import BaseExecutor
+from ..svc import tracing
 from ..synchronization import Mutex
 
 
@@ -237,24 +238,34 @@ class TpuExecutor(BaseExecutor):
         # for an explicit compiled dispatch-and-forget.
         fn(*args, **kwargs)
 
+    # `hpx.exec.dispatch` spans the compiled call alone: jit's own
+    # overhead, or the host's wait on a full dispatch queue
+
     def post_compiled(self, fn: Callable[..., Any], *args: Any,
                       **kwargs: Any) -> None:
         TpuExecutor.dispatch_count += 1
-        self._compiled(fn)(*args, **kwargs)
+        compiled = self._compiled(fn)
+        with tracing.span("hpx.exec.dispatch", "hpx"):
+            compiled(*args, **kwargs)
 
     def sync_execute(self, fn: Callable[..., Any], *args: Any,
                      **kwargs: Any) -> Any:
         import jax
         TpuExecutor.dispatch_count += 1
+        compiled = self._compiled(fn)
+        with tracing.span("hpx.exec.dispatch", "hpx"):
+            value = compiled(*args, **kwargs)
         # hpxlint: disable-next=HPX002 — sync_execute()'s contract
         # is to block until the result is ready
-        return jax.block_until_ready(self._compiled(fn)(*args, **kwargs))
+        return jax.block_until_ready(value)
 
     def async_execute(self, fn: Callable[..., Any], *args: Any,
                       **kwargs: Any) -> Future:
         TpuExecutor.dispatch_count += 1
         try:
-            value = self._compiled(fn)(*args, **kwargs)
+            compiled = self._compiled(fn)
+            with tracing.span("hpx.exec.dispatch", "hpx"):
+                value = compiled(*args, **kwargs)
         except BaseException as e:  # noqa: BLE001 — trace/compile errors
             return make_exceptional_future(e)
         if self.eager:
@@ -266,7 +277,8 @@ class TpuExecutor(BaseExecutor):
         """Dispatch an already-compiled/arbitrary callable (no jit wrap)."""
         TpuExecutor.dispatch_count += 1
         try:
-            value = fn(*args, **kwargs)
+            with tracing.span("hpx.exec.dispatch", "hpx"):
+                value = fn(*args, **kwargs)
         except BaseException as e:  # noqa: BLE001
             return make_exceptional_future(e)
         return make_ready_future(value) if self.eager else get_future(value)

@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, List, Tuple
 from .async_ import Launch
 from .future import Future, SharedState, is_future
 from ..runtime.threadpool import default_pool
+from ..svc import tracing
 
 
 def _collect_futures(obj: Any, acc: List[Future]) -> None:
@@ -59,19 +60,32 @@ def dataflow(fn: Callable[..., Any], *args: Any,
     their values instead. If fn returns a Future it is unwrapped into the
     result (dataflow returns future<T>, not future<future<T>>).
     """
-    deps: List[Future] = []
-    _collect_futures(args, deps)
-    _collect_futures(kwargs, deps)
-
+    # spans: `hpx.dataflow.node` is one node's bookkeeping (pack
+    # traversal, shared state, callbacks, scheduling) with the user's
+    # body (`hpx.dataflow.body`) inside it, so node less body is what
+    # the futures layer itself costs. A node whose dependencies are
+    # ready fires inside this call; one that fires later, from a
+    # dependency's callback or on a pool thread, opens a node span of
+    # its own there.
+    builder = [threading.get_ident()]   # cleared when this call returns
     out: SharedState = SharedState()
 
-    def fire() -> None:
+    def run() -> None:
         try:
             a = _substitute(args, unwrap)
             kw = _substitute(kwargs, unwrap)
-            out.set_value(fn(*a, **kw))
+            with tracing.span("hpx.dataflow.body", "hpx"):
+                value = fn(*a, **kw)
+            out.set_value(value)
         except BaseException as e:  # noqa: BLE001
             out.set_exception(e)
+
+    def fire() -> None:
+        if builder[0] == threading.get_ident():
+            run()
+        else:
+            with tracing.span("hpx.dataflow.node", "hpx"):
+                run()
 
     def schedule() -> None:
         if policy is Launch.sync or policy is Launch.fork:
@@ -81,23 +95,30 @@ def dataflow(fn: Callable[..., Any], *args: Any,
         else:
             default_pool().submit(fire)
 
-    if not deps:
-        schedule()
-        return Future(out)
+    with tracing.span("hpx.dataflow.node", "hpx"):
+        try:
+            deps: List[Future] = []
+            _collect_futures(args, deps)
+            _collect_futures(kwargs, deps)
+            if not deps:
+                schedule()
+                return Future(out)
 
-    remaining = [len(deps)]
-    lock = threading.Lock()
+            remaining = [len(deps)]
+            lock = threading.Lock()
 
-    def on_dep(_st: SharedState) -> None:
-        with lock:
-            remaining[0] -= 1
-            done = remaining[0] == 0
-        if done:
-            schedule()
+            def on_dep(_st: SharedState) -> None:
+                with lock:
+                    remaining[0] -= 1
+                    done = remaining[0] == 0
+                if done:
+                    schedule()
 
-    for d in deps:
-        d._state.add_callback(on_dep)
-    return Future(out)
+            for d in deps:
+                d._state.add_callback(on_dep)
+            return Future(out)
+        finally:
+            builder[0] = None
 
 
 class unwrapping:

@@ -1101,6 +1101,7 @@ class ContinuousServer:
         self.timeline = _metrics.RequestTimeline()
         self._last_step_t: Optional[float] = None
         self._stall_live = False
+        self._step_n = 0               # step() calls: serving.step's `n`
         # closed-loop adaptive tuning (svc/autotune): tick at flush
         # boundaries only — the one point where no step is in flight,
         # so a knob write cannot tear a dispatched program. Config
@@ -2381,12 +2382,17 @@ class ContinuousServer:
             self._caches = self._splice_prog()(
                 self._caches, caches, jnp.asarray(slot, jnp.int32))
         del self._pending[slot]
-        if req.temperature > 0.0:
-            # generate()'s tok0 draw: position plen-1, row 0
-            tok0 = int(_sample_row(logits[0], req.temperature,
-                                   req.key, plen - 1, 0))
-        else:
-            tok0 = int(jnp.argmax(logits[0]))
+        # a blocking device->host read at every admission: the host
+        # stands still until the probe's logits exist, and the dispatch
+        # queue drains meanwhile
+        with tracing.span("serving.first_token.wait", "serving",
+                          rid=req.rid):
+            if req.temperature > 0.0:
+                # generate()'s tok0 draw: position plen-1, row 0
+                tok0 = int(_sample_row(logits[0], req.temperature,
+                                       req.key, plen - 1, 0))
+            else:
+                tok0 = int(jnp.argmax(logits[0]))
         req.tokens.append(tok0)
         req.sent = 1
         self._slot_req[slot] = req
@@ -2558,14 +2564,16 @@ class ContinuousServer:
         splices and goes live the same step."""
         if not self._pending:
             return
-        p = min(self._pending.values(),
-                key=lambda q: (q.remaining, q.seq))
-        self._advance_chunk(p)
-        if p.remaining == 0:
-            with tracing.span("serving.prefill", "serving",
-                              rid=p.req.rid, plen=len(p.req.prompt),
-                              chunked=True):
-                self._finish_prefill(p)
+        with tracing.span("serving.prefill_tick", "serving",
+                          pending=len(self._pending)):
+            p = min(self._pending.values(),
+                    key=lambda q: (q.remaining, q.seq))
+            self._advance_chunk(p)
+            if p.remaining == 0:
+                with tracing.span("serving.prefill", "serving",
+                                  rid=p.req.rid,
+                                  plen=len(p.req.prompt), chunked=True):
+                    self._finish_prefill(p)
 
     # -- speculative decode ----------------------------------------------
 
@@ -3065,41 +3073,51 @@ class ContinuousServer:
 
     def _flush(self) -> None:
         """Materialize every buffered step's token vector and replay
-        the per-slot bookkeeping in dispatch order — the ONLY
-        device->host read in the decode loop. Also the knob actuation
-        boundary: external config writes land (_reload_knobs) and the
-        adaptive tuner ticks HERE, never mid-step."""
-        while self._buf:
-            nxt, lanes = self._buf.popleft()
-            vals = np.asarray(nxt)
-            for s, req in lanes:
-                t = int(vals[s])
-                req.tokens.append(t)
-                self._cur[s] = t
-                hit_eos = (req.eos_id is not None
-                           and t == req.eos_id)
-                if hit_eos or len(req.tokens) >= req.max_new:
-                    self._finalize(s, req, hit_eos)
-        # MoE routing stats buffered by the step/verify programs: one
-        # small [2+E] vector per dispatched step, read here so the
-        # async window never gains an extra host sync
-        while self._moe_buf:
-            ms = np.asarray(self._moe_buf.popleft())
-            self._moe_routed += float(ms[0])
-            self._moe_dropped += float(ms[1])
-            self._moe_occ = [float(v) for v in ms[2:]]
-        self._ckpt_sweep()
-        self._reload_knobs()
-        # SLO burn evaluation shares the tuner's boundary: no step in
-        # flight, so a flight-bundle capture sees consistent state. A
-        # firing alert also holds the tuner — probing against
-        # regressed traffic tunes toward the incident.
-        alerting = False
-        if self._alerts is not None:
-            self._alerts.maybe_tick()
-            alerting = self._alerts.active() > 0
-        if self._tuner is not None:
-            self._tuner.maybe_tick(self._tune_signals, hold=alerting)
+        the per-slot bookkeeping in dispatch order. With the seed-token
+        read of `_finish_prefill` (one per admission,
+        `serving.first_token.wait`) these are the ONLY device->host
+        reads in the decode loop: one per buffered step
+        (`serving.flush.wait`), oldest first, so that the replay of an
+        early step overlaps the device's work on a later one; the read
+        of the newest step is the one that empties the dispatch queue.
+        Also the knob actuation boundary: external config writes land
+        (_reload_knobs) and the adaptive tuner ticks HERE, never
+        mid-step."""
+        with tracing.span("serving.flush", "serving",
+                          steps=len(self._buf)):
+            while self._buf:
+                nxt, lanes = self._buf.popleft()
+                with tracing.span("serving.flush.wait", "serving"):
+                    vals = np.asarray(nxt)
+                for s, req in lanes:
+                    t = int(vals[s])
+                    req.tokens.append(t)
+                    self._cur[s] = t
+                    hit_eos = (req.eos_id is not None
+                               and t == req.eos_id)
+                    if hit_eos or len(req.tokens) >= req.max_new:
+                        self._finalize(s, req, hit_eos)
+            # MoE routing stats buffered by the step/verify programs:
+            # one small [2+E] vector per dispatched step, read here so
+            # the async window never gains an extra host sync
+            while self._moe_buf:
+                ms = np.asarray(self._moe_buf.popleft())
+                self._moe_routed += float(ms[0])
+                self._moe_dropped += float(ms[1])
+                self._moe_occ = [float(v) for v in ms[2:]]
+            self._ckpt_sweep()
+            self._reload_knobs()
+            # SLO burn evaluation shares the tuner's boundary: no step
+            # in flight, so a flight-bundle capture sees consistent
+            # state. A firing alert also holds the tuner — probing
+            # against regressed traffic tunes toward the incident.
+            alerting = False
+            if self._alerts is not None:
+                self._alerts.maybe_tick()
+                alerting = self._alerts.active() > 0
+            if self._tuner is not None:
+                self._tuner.maybe_tick(self._tune_signals,
+                                       hold=alerting)
 
     def _reload_knobs(self) -> None:
         """Propagate runtime config writes into the live server at
@@ -3222,32 +3240,34 @@ class ContinuousServer:
         fault-free run would (differential contract). If the retry
         budget exhausts, every in-flight request sheds with a typed
         error into `failed` and the loop moves on."""
-        self._shed_expired()
-        # decode-stall feed: the gap between consecutive step() entries
-        # while the PREVIOUS step left live slots — the inter-token
-        # latency a streaming client would observe
-        now = time.monotonic()
-        if self._stall_live and self._last_step_t is not None:
-            # the stall is shared by every live slot; attribute the
-            # exemplar to the first live rid (deterministic pick — any
-            # of them observed this inter-token gap)
-            stall_rid = next((r.rid for r in self._slot_req
-                              if r is not None), None)
-            self.hist["decode_stall"].record(now - self._last_step_t,
-                                             rid=stall_rid)
-        self._last_step_t = now
-        try:
-            return sync_replay(
-                self._step_retries, self._step_inner,
-                retry_on=(faultinject.InjectedFault, CacheOOM),
-                on_retry=self._recover,
-                backoff_s=self._retry_backoff_s)
-        except (faultinject.InjectedFault, CacheOOM) as e:
-            self._shed_everything(e)
-            return bool(self._queue or self._pending)
-        finally:
-            self._stall_live = any(r is not None
-                                   for r in self._slot_req)
+        self._step_n += 1
+        with tracing.span("serving.step", "serving", n=self._step_n):
+            self._shed_expired()
+            # decode-stall feed: the gap between consecutive step()
+            # entries while the PREVIOUS step left live slots — the
+            # inter-token latency a streaming client would observe
+            now = time.monotonic()
+            if self._stall_live and self._last_step_t is not None:
+                # the stall is shared by every live slot; attribute
+                # the exemplar to the first live rid (deterministic
+                # pick — any of them observed this inter-token gap)
+                stall_rid = next((r.rid for r in self._slot_req
+                                  if r is not None), None)
+                self.hist["decode_stall"].record(
+                    now - self._last_step_t, rid=stall_rid)
+            self._last_step_t = now
+            try:
+                return sync_replay(
+                    self._step_retries, self._step_inner,
+                    retry_on=(faultinject.InjectedFault, CacheOOM),
+                    on_retry=self._recover,
+                    backoff_s=self._retry_backoff_s)
+            except (faultinject.InjectedFault, CacheOOM) as e:
+                self._shed_everything(e)
+                return bool(self._queue or self._pending)
+            finally:
+                self._stall_live = any(r is not None
+                                       for r in self._slot_req)
 
     def _step_inner(self) -> bool:
         self._admit()
@@ -3259,14 +3279,10 @@ class ContinuousServer:
             return bool(self._queue or self._pending)
         if self._spec:
             with tracing.span("serving.decode", "serving",
-                              live=len(live), spec=True,
-                              rids=[self._slot_req[s].rid
-                                    for s in live]):
+                              live=len(live), spec=True):
                 self._spec_step(live)
             return True
-        with tracing.span("serving.decode", "serving",
-                          live=len(live),
-                          rids=[self._slot_req[s].rid for s in live]):
+        with tracing.span("serving.decode", "serving", live=len(live)):
             # fault site "decode": before the step dispatch and before
             # any host bookkeeping commits — at this point every
             # BUFFERED step already completed on device, so recovery's
@@ -3333,5 +3349,28 @@ class ContinuousServer:
         the result — their typed errors are in `self.failed`."""
         while self.step():
             pass
+        return self.poll_finished()
+
+    # -- for a caller that drives step() itself --------------------------
+
+    def flush(self) -> None:
+        """Land every buffered step's tokens on the host now (one
+        blocking read a step); step() does so by itself at each
+        retirement, eos check and every `hpx.serving.max_async_steps`
+        steps."""
+        self._flush()
+
+    def poll_finished(self) -> Dict[int, List[int]]:
+        """Hand out {request_id: tokens} of the requests finished since
+        the last call, and forget them (run() returns through here). A
+        request's tokens reach the host at a flush, so a request whose
+        last step is still buffered is not in the result yet."""
         out, self._done = self._done, {}
         return out
+
+    def live_positions(self) -> Dict[int, int]:
+        """{slot: next write position} of every live slot: what the
+        next decode step attends over (each slot reads its positions
+        0..p-1 and writes p). Host-only, no device read."""
+        return {s: self._pos[s] for s in range(self.slots)
+                if self._slot_req[s] is not None}

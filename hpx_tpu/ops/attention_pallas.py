@@ -254,6 +254,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret,
 
     res = pl.pallas_call(
         kernel,
+        name="hpx_flash_fwd",
         grid=(b * n, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, h), lambda bn, iq, ik: (bn, iq, 0)),
@@ -542,6 +543,7 @@ def flash_attention_bwd(q, k, v, do, delta, lse, d,
         functools.partial(
             _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
             nk=nk, causal=causal, scale=scale, seq_k=seq_k),
+        name="hpx_flash_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bn, nq, nk),
@@ -575,6 +577,7 @@ def flash_attention_bwd(q, k, v, do, delta, lse, d,
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
             nq=nq, causal=causal, scale=scale, seq_k=seq_k),
+        name="hpx_flash_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bn, nk, nq),
@@ -766,6 +769,7 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
 
     acc2, m2, l2 = pl.pallas_call(
         kernel,
+        name="hpx_flash_chunk",
         grid_spec=grid_spec,
         out_shape=[
             _sds((bn, sq, h), f32, q, k, v, acc, m, l),
@@ -1196,6 +1200,7 @@ def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
 
     out = pl.pallas_call(
         kernel,
+        name="hpx_paged_fused_online" if online else "hpx_paged_fused",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, nkv, maxb),

@@ -92,6 +92,7 @@ def pallas_multistep(u: jax.Array, coef, steps: int) -> jax.Array:
 
     out = pl.pallas_call(
         functools.partial(_pallas_kernel, steps=steps),
+        name="hpx_stencil_multistep",
         out_shape=jax.ShapeDtypeStruct(u2.shape, u2.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -180,6 +181,7 @@ def pallas_heat_step(u: jax.Array, coef,
 
     out = pl.pallas_call(
         _pallas_blocked_kernel,
+        name="hpx_stencil_blocked",
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((r, LANES), lambda i: (i, 0)),

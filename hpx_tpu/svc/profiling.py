@@ -16,9 +16,12 @@ TPU-first: two planes —
 from __future__ import annotations
 
 import contextlib
-from ..synchronization import Mutex
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+import jax
+
+from ..synchronization import Mutex
 
 # ---------------------------------------------------------------------------
 # external-timer registry (APEX hook analog)
@@ -151,8 +154,8 @@ def task_timing():
 
 @contextlib.contextmanager
 def profile_trace(logdir: str):
-    """Capture a jax.profiler trace (view in Perfetto/TensorBoard)."""
-    import jax
+    """Capture a jax.profiler trace (view in Perfetto/TensorBoard).
+    Every `tracing.span()` opened meanwhile lies in its host plane."""
     jax.profiler.start_trace(logdir)
     try:
         yield
@@ -160,15 +163,18 @@ def profile_trace(logdir: str):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named range visible in profiler traces (itt task annotation
-    analog); usable as a context manager."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+#: Named range on the profiler's clock (itt task annotation analog),
+#: a context manager, `annotate(name, **args)`: in a live
+#: `jax.profiler` session a host event of the `.xplane.pb` with `args`
+#: as its statistics; with no session a no-op in C++ that never reads
+#: `args`. `tracing.span()` opens one for every span, so it is the
+#: class itself and not a wrapper (a wrapper doubles the cost of the
+#: untraced path). This module is the one place that touches
+#: `jax.profiler`.
+annotate = jax.profiler.TraceAnnotation
 
 
 def device_memory_stats(device_index: int = 0) -> Dict[str, Any]:
-    import jax
     try:
         return dict(jax.devices()[device_index].memory_stats() or {})
     except Exception:  # noqa: BLE001

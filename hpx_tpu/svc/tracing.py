@@ -24,10 +24,21 @@ records, into a bounded drop-oldest ring:
 `svc/trace_export.py` turns the ring into Chrome trace-event JSON that
 loads directly in ``chrome://tracing`` / Perfetto.
 
-Zero-overhead discipline: everything is OFF by default. The
-instrumented hot paths (pool submit, ``Future.then``, serving steps,
-radix match) each pay one module-global load plus an ``is None`` test
-when no tracer is active — no allocation, no lock, no call. The ring
+Two sinks, one entry point. Every :func:`span` is ALSO a
+``jax.profiler.TraceAnnotation`` (through ``svc/profiling.annotate``),
+so in any live profiler session (``profile_trace()``, TensorBoard, a
+benchmark's traced run) the program's spans lie in the host plane of
+the same ``.xplane.pb`` as the device ops, on its clock; the ring below
+is the second sink, for the Chrome-trace export, and is OFF by default.
+
+Cost when nothing records: a span is one ``TraceAnnotation`` that C++
+turns into a no-op without reading its arguments (measured on the CPU,
+jax 0.9.0: 0.5 us enter+exit, 0.9 us with three arguments; the shared
+null span it replaced: 0.3 us, 0.4 us). Call sites therefore pass only
+arguments that cost nothing to BUILD. The other instrumented hot paths
+(pool submit, ``Future.then``, radix match) still pay one
+module-global load plus an ``is None`` test when no tracer is active —
+no allocation, no lock, no call. The ring
 itself is append-only under the GIL (no lock on the event path); the
 drop counter is best-effort under concurrent appends.
 
@@ -47,6 +58,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+from .profiling import annotate as _annotate
 
 __all__ = [
     "Tracer", "TaskCtx", "active_tracer", "start_tracing",
@@ -80,8 +93,8 @@ class TaskCtx:
 
 
 class _NullSpan:
-    """The shared no-op returned by module-level span() when tracing is
-    off — one immortal object, so the disabled path allocates nothing."""
+    """The shared no-op of :func:`null_span`: for instrumentation that
+    writes to a ring of its own and to no other sink."""
 
     __slots__ = ()
     id = None
@@ -97,10 +110,11 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context manager recording one B/E pair; nesting via the
-    tracer's per-thread span stack gives the parent id."""
+    """Context manager recording one B/E pair in the ring and the same
+    range on the profiler's clock; nesting via the tracer's per-thread
+    span stack gives the parent id."""
 
-    __slots__ = ("_tr", "name", "cat", "args", "id")
+    __slots__ = ("_tr", "name", "cat", "args", "id", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  args: Optional[dict]) -> None:
@@ -112,9 +126,12 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self.id = self._tr._begin(self.name, self.cat, self.args)
+        self._ann = _annotate(self.name, **(self.args or {}))
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
+        self._ann.__exit__(*exc)
         self._tr._end(self.name, self.cat, self.id)
         return False
 
@@ -484,12 +501,13 @@ def trace(capacity: Optional[int] = None,
 
 
 def span(name: str, cat: str = "user", **args: Any):
-    """Module-level span: a real span under an active tracer, the
-    shared no-op object otherwise (the instrumentation call sites'
-    single entry point)."""
+    """Module-level span, the instrumentation call sites' single entry
+    point: a range on the profiler's clock always (a no-op in C++
+    unless a `jax.profiler` session is live), and a ring span too
+    under an active tracer. `cat` goes to the ring alone."""
     tr = _active
     if tr is None:
-        return _NULL_SPAN
+        return _annotate(name, **args)
     return tr.span(name, cat, **args)
 
 

@@ -198,3 +198,47 @@ def test_instant_eos_frees_slot_same_pass(params):
     out = srv.run()
     assert out[a] == _ref(params, CFG, [3, 1, 4], 5, eos_id=tok0)
     assert out[b] == _ref(params, CFG, [2, 7], 4)
+
+
+# -- the public names for a caller that drives step() itself -------------
+
+def test_flush_lands_buffered_tokens_on_the_host(params):
+    """Two decode steps stay on the device until something needs their
+    values; flush() reads them now."""
+    srv = ContinuousServer(params, CFG, slots=1, smax=32)
+    srv.submit([3, 1, 4], max_new=6)
+    srv.step()
+    srv.step()
+    req = srv._slot_req[0]
+    assert len(req.tokens) == 1 and req.sent == 3   # the seed token only
+    srv.flush()
+    assert req.tokens == _ref(params, CFG, [3, 1, 4], 3)
+
+
+def test_poll_finished_hands_out_and_forgets(params):
+    srv = ContinuousServer(params, CFG, slots=2, smax=32)
+    a = srv.submit([3, 1, 4], max_new=2)
+    b = srv.submit([2, 7], max_new=6)
+    got = {}
+    while srv.step():
+        fresh = srv.poll_finished()
+        assert not set(fresh) & set(got)
+        got.update(fresh)
+    got.update(srv.poll_finished())
+    assert got == {a: _ref(params, CFG, [3, 1, 4], 2),
+                   b: _ref(params, CFG, [2, 7], 6)}
+    assert srv.poll_finished() == {} and srv.run() == {}
+
+
+def test_live_positions_follow_the_decode_steps(params):
+    srv = ContinuousServer(params, CFG, slots=2, smax=32)
+    assert srv.live_positions() == {}
+    srv.submit([3, 1, 4], max_new=4)
+    srv.submit([2, 7], max_new=2)
+    srv.step()              # both admitted and decoded once
+    # slot 1's request was dispatched its last token: the slot is free
+    assert srv.live_positions() == {0: 4}
+    srv.step()
+    assert srv.live_positions() == {0: 5}
+    srv.run()
+    assert srv.live_positions() == {}

@@ -1,8 +1,10 @@
 """Causal task tracer (svc/tracing + svc/trace_export).
 
-Contracts under test: the disabled path is a structural no-op (no
-tracer, no hooks, one shared null span object); spans nest and record
-causal parents; parents and flow arrows propagate across async_ /
+Contracts under test: with no tracer there are no hooks and no ring,
+and a span is a profiler annotation that reads no argument unless a
+`jax.profiler` session is live; in such a session the serving loop's
+and the dataflow layer's spans lie in the trace's host plane, nested
+as the code nests; spans nest and record causal parents; parents and flow arrows propagate across async_ /
 .then() / when_all joins; the ring drops oldest at capacity; exported
 Chrome-trace JSON always validates (matched B/E, resolving flows,
 monotonic ts); counter samples interleave on the same timeline; and the
@@ -10,7 +12,9 @@ ContinuousServer emits the admit -> prefill / decode -> retire causal
 chain end to end (the CI smoke).
 """
 
+import glob
 import json
+import os
 import time
 
 import jax
@@ -75,14 +79,28 @@ class TestDisabled:
         assert threadpool._trace_pending is None
         assert future_mod._trace_continuation is None
 
-    def test_span_is_shared_null_object(self):
-        # module-level span() off the fast path returns ONE immortal
-        # no-op — no allocation, args never touched
-        a = tracing.span("x", "user", heavy=object())
-        b = tracing.span("y")
-        assert a is b is tracing._NULL_SPAN
-        with a:
-            assert a.id is None
+    def test_span_off_records_nothing_and_reads_no_argument(self):
+        # with no tracer a span is a profiler annotation and nothing
+        # else: no ring comes to be, and with no profiler session
+        # either its arguments are never read
+        class Heavy:
+            read = 0
+
+            def __repr__(self):
+                Heavy.read += 1
+                return "heavy"
+            __str__ = __repr__
+
+        with tracing.span("x", "user", heavy=Heavy()) as a:
+            assert isinstance(a, profiling.annotate)
+            with tracing.span("y"):
+                assert tracing.current_span_id() is None
+        assert Heavy.read == 0
+        assert tracing.active_tracer() is None
+        # instrumentation with a ring of its own keeps the shared no-op
+        assert tracing.null_span() is tracing.null_span()
+        with tracing.null_span() as n:
+            assert n.id is None
 
     def test_instant_is_noop(self):
         tracing.instant("nothing", "user", k=1)   # must not raise
@@ -385,12 +403,14 @@ class TestServingSmoke:
         assert len(decodes) >= 2          # two decode steps minimum
         assert len(retires) == 2
 
-        # causal edges: prefill nests under its admit, retire under a
-        # decode step
+        # causal edges: prefill nests under its admit, retire under the
+        # flush of a decode step
         admit_ids = {e[ID] for e in admits}
         decode_ids = {e[ID] for e in decodes}
+        flushes = {e[ID]: e for e in spans_named(ev, "serving.flush")}
         assert all(e[PARENT] in admit_ids for e in prefills)
-        assert all(e[PARENT] in decode_ids for e in retires)
+        assert all(flushes[e[PARENT]][PARENT] in decode_ids
+                   for e in retires)
         # rid args connect admit to its retire
         rids = {e[ARGS]["rid"] for e in admits}
         assert rids == {a, b}
@@ -417,12 +437,155 @@ class TestServingSmoke:
         # matches the prefix the first one published at retire
         assert matches[-1][ARGS]["matched"] >= 16
 
-    def test_untraced_serving_output_identical(self, params):
-        srv = ContinuousServer(params, CFG, slots=2, smax=32)
-        r = srv.submit([3, 1, 4], max_new=2)
-        base = srv.run()[r]
+    def test_untraced_serving_output_identical(self, params, tmp_path):
+        def serve():
+            srv = ContinuousServer(params, CFG, slots=2, smax=32)
+            r = srv.submit([3, 1, 4], max_new=2)
+            return srv.run()[r]
+        base = serve()
         with tracing.trace(sample_counters=False):
-            srv2 = ContinuousServer(params, CFG, slots=2, smax=32)
-            r2 = srv2.submit([3, 1, 4], max_new=2)
-            traced = srv2.run()[r2]
+            traced = serve()
         assert traced == base
+        # a profiler session alone: the spans go to ITS trace, no
+        # tracer comes to be, and the tokens are the same
+        with profiling.profile_trace(str(tmp_path)):
+            profiled = serve()
+            assert tracing.active_tracer() is None
+        assert profiled == base
+        assert "serving.step" in {sp.name for sp in host_spans(tmp_path)}
+
+
+# ---------------------------------------------------------------------------
+# the same spans on the profiler's clock: a jax.profiler session's host plane
+# ---------------------------------------------------------------------------
+
+class HostSpan:
+    """One `serving.*` / `hpx.*` event of a trace's host plane."""
+
+    def __init__(self, ev, thread):
+        self.name, self.thread = ev.name, thread
+        self.start, self.end = ev.start_ns, ev.start_ns + ev.duration_ns
+        self.args = dict(ev.stats)
+
+    def holds(self, other) -> bool:
+        return (self.thread == other.thread and self is not other
+                and self.start <= other.start and other.end <= self.end)
+
+
+def host_spans(logdir):
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(logdir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            out += [HostSpan(ev, (plane.name, i)) for ev in line.events
+                    if ev.name.startswith(("serving.", "hpx."))]
+    return sorted(out, key=lambda sp: sp.start)
+
+
+def named(spans, name):
+    return [sp for sp in spans if sp.name == name]
+
+
+class TestProfilerPlane:
+    def test_serving_spans_nest_in_the_host_plane(self, params, tmp_path):
+        """Two slots. A (3 tokens) and B (5) are admitted in step 1, C
+        (2) waits for A's slot: A's last step is dispatched in step 2,
+        C's (admitted in step 3) in step 3, B's in step 4; step 5 finds
+        nothing to do. By hand: one blocking read of a first token an
+        admission (3) and one of each decode step's tokens (4), at the
+        flush of a step in which a request is dispatched its last token
+        (steps 2, 3, 4: the first of them reads two steps)."""
+        srv = ContinuousServer(params, CFG, slots=2, smax=48, paged=True)
+        with profiling.profile_trace(str(tmp_path)):
+            rids = [srv.submit(p, max_new=m) for p, m in
+                    (([3, 1, 4], 3), ([2, 7], 5), ([5, 6, 7], 2))]
+            calls = 1
+            while srv.step():
+                calls += 1
+            out = srv.poll_finished()
+        assert set(out) == set(rids) and calls == 5
+        spans = host_spans(tmp_path)
+
+        steps = named(spans, "serving.step")
+        assert [sp.args["n"] for sp in steps] == [1, 2, 3, 4, 5]
+        admits = named(spans, "serving.admit")
+        assert [sp.args["rid"] for sp in admits] == rids
+        reads = named(spans, "serving.first_token.wait")
+        waits = named(spans, "serving.flush.wait")
+        flushes = named(spans, "serving.flush")
+        decodes = named(spans, "serving.decode")
+        assert len(reads) == 3 and len(waits) == 4 and len(decodes) == 4
+        # step 5's flush holds no step and so reads none
+        assert [sp.args["steps"] for sp in flushes] == [2, 1, 1, 0]
+        assert [sum(f.holds(w) for w in waits) for f in flushes] == \
+            [2, 1, 1, 0]
+        # each wait lies inside its parent, and that inside a step
+        for read, admit in zip(reads, admits):
+            assert admit.holds(read) and read.args["rid"] == \
+                admit.args["rid"]
+        for wait in waits:
+            (flush,) = [f for f in flushes if f.holds(wait)]
+            (decode,) = [d for d in decodes if d.holds(flush)]
+            assert sum(st.holds(decode) for st in steps) == 1
+        for sp in spans:
+            if sp.name != "serving.step":
+                assert sum(st.holds(sp) for st in steps) == 1, sp.name
+        # blocking device-to-host reads a step: what the benchmark's
+        # host_syncs_per_step reads from the same spans
+        blocking = [sp for sp in spans if sp.name.endswith(".wait")]
+        assert len(blocking) / len(steps) == (3 + 4) / 5
+
+    def test_chunked_prefill_ticks_inside_a_step(self, params, tmp_path):
+        srv = ContinuousServer(params, CFG, slots=1, smax=48,
+                               prefill_chunk=4)
+        with profiling.profile_trace(str(tmp_path)):
+            srv.submit(list(range(1, 12)), max_new=2)
+            srv.run()
+        spans = host_spans(tmp_path)
+        ticks = named(spans, "serving.prefill_tick")
+        chunks = named(spans, "serving.prefill_chunk")
+        assert ticks and len(chunks) == len(ticks)
+        for tick in ticks:
+            assert sum(tick.holds(c) for c in chunks) == 1
+        (read,) = named(spans, "serving.first_token.wait")
+        assert ticks[-1].holds(read)
+
+    def test_dataflow_nodes_hold_body_and_dispatch(self, tmp_path):
+        from hpx_tpu.exec.tpu import TpuExecutor
+        from hpx_tpu.models import stencil1d
+        p = stencil1d.StencilParams(nx=64, np_=4, nt=3)
+        ex = TpuExecutor()
+        stencil1d.gather_dataflow_result(
+            stencil1d.stencil_dataflow(p, ex)).block_until_ready()
+        with profiling.profile_trace(str(tmp_path)):
+            out = stencil1d.gather_dataflow_result(
+                stencil1d.stencil_dataflow(p, ex))
+            out.block_until_ready()
+        spans = host_spans(tmp_path)
+        nodes = named(spans, "hpx.dataflow.node")
+        bodies = named(spans, "hpx.dataflow.body")
+        sent = named(spans, "hpx.exec.dispatch")
+        assert len(nodes) == len(bodies) == len(sent) == p.np_ * p.nt
+        for node in nodes:
+            (body,) = [b for b in bodies if node.holds(b)]
+            (call,) = [d for d in sent if node.holds(d)]
+            assert body.holds(call)
+
+    def test_a_deferred_node_opens_a_span_of_its_own(self, tmp_path):
+        from hpx_tpu.futures.dataflow import dataflow
+        from hpx_tpu.futures.future import Future, SharedState
+        dep = SharedState()
+        with profiling.profile_trace(str(tmp_path)):
+            f = dataflow(lambda d: d.get() + 1, Future(dep),
+                         policy=hpx.Launch.sync)
+            assert not f.is_ready()
+            dep.set_value(41)           # fires the node from a callback
+            assert f.get() == 42
+        spans = host_spans(tmp_path)
+        built, fired = named(spans, "hpx.dataflow.node")
+        (body,) = named(spans, "hpx.dataflow.body")
+        assert fired.holds(body) and not built.holds(body)
