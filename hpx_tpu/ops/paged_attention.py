@@ -6,7 +6,16 @@ request lives in one preallocated per-layer pool of fixed-size blocks
 one head of one block is a contiguous (block_size, head_dim) tile, the
 shape the fused kernels stream), and a per-step int32
 block table (`cache/page_table.py`) maps each slot's logical positions
-to physical blocks. This module is pure jit-safe array plumbing — no
+to physical blocks. The layout rule for every write into a pool: index
+EVERY axis ahead of `head_dim` (block, kv head, row — the update window
+is then one contiguous `head_dim` row), never a slice between two
+indexed axes. The fused kernels pin their pool operands to the pool's
+own `{3,2,1,0}` layout; `pool.at[bidx, :, row]` makes the chip's
+compiler run the scatter in `{3,1,2,0}`, which costs two whole-pool
+copies per pool, layer and step (`tests/test_chip_compile.py` guards
+it). Whole-block writes (`pool.at[bids]`) index a prefix of the axes
+and are fine.
+This module is pure jit-safe array plumbing — no
 host state, no syncs — so the serving layer can compose it with its
 projections while the numerics stay in one place.
 
@@ -153,11 +162,13 @@ def scatter_token(pool: jax.Array, table: jax.Array, pos: jax.Array,
     max_blocks]; pos: [B] int32 logical positions; val: [B, n_kv,
     head_dim]. Slot b's row lands at (table[b, pos[b]//bs], :,
     pos[b]%bs) — dead slots point their whole table at a reserved
-    trash block, so their masked lanes scatter harmlessly."""
-    bs = pool.shape[2]
+    trash block, so their masked lanes scatter harmlessly. Block, kv
+    head and row are indexed together (the module's layout rule)."""
+    nkv, bs = pool.shape[1], pool.shape[2]
     rows = jnp.arange(table.shape[0])
     bidx = table[rows, pos // bs]
-    return pool.at[bidx, :, pos % bs].set(val)
+    return pool.at[bidx[:, None], jnp.arange(nkv)[None, :],
+                   (pos % bs)[:, None]].set(val)
 
 
 def scatter_window(pool: jax.Array, table: jax.Array, pos0: jax.Array,
@@ -178,14 +189,15 @@ def scatter_window(pool: jax.Array, table: jax.Array, pos0: jax.Array,
     positions past the table's extent are routed to block index
     `num_blocks` (one past the pool) and the scatter uses
     ``mode="drop"``."""
-    nb, bs = pool.shape[0], pool.shape[2]
+    nb, nkv, bs = pool.shape[:3]
     b, w = vals.shape[0], vals.shape[1]
     rows = jnp.arange(b)[:, None]
     p = pos0[:, None] + jnp.arange(w)[None, :]          # [B, W]
     maxb = table.shape[1]
     bidx = table[rows, jnp.minimum(p // bs, maxb - 1)]
     bidx = jnp.where(p < maxb * bs, bidx, nb)           # OOB -> dropped
-    return pool.at[bidx, :, p % bs].set(vals, mode="drop")
+    return pool.at[bidx[:, :, None], jnp.arange(nkv)[None, None, :],
+                   (p % bs)[:, :, None]].set(vals, mode="drop")
 
 
 def scatter_token_q(pool_q: jax.Array, scales: jax.Array,

@@ -7,6 +7,8 @@ compiler either accepts it or raises what the chip would raise. The
 interpret-mode tests next door cannot see a refused block shape, an
 unaligned store or too much VMEM; this file can, at about two seconds a
 case. A compile that passes is not a chip run — `chip_smoke.py` is.
+It also reads the compiled text for what the compiler ADDS around a
+kernel: the pool-layout guard below fails on a whole-pool `copy(`.
 
 The topology is described inside a module-scoped fixture (never at
 import, in a skipif or in parametrize arguments): only one process may
@@ -23,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from hpx_tpu.ops import attention_pallas as ap
+from hpx_tpu.ops import paged_attention as pa
 from hpx_tpu.ops import stencil
 
 _POOL_DTYPES = {"bf16": jnp.bfloat16, "int8": jnp.int8,
@@ -103,6 +106,83 @@ def test_paged_attention_decode_compiles(sds, kernel, kv, nkv):
 def test_paged_attention_window_and_hd64_compile(sds, kernel, kv, nkv,
                                                  hd, w):
     _kernel_text(*_paged_case(sds, kernel, kv, nkv, hd, w))
+
+
+# -- the pool layout rule: no whole-pool copy around a row write ---------
+#
+# The fused kernels pin their pool operands to `{3,2,1,0}`; a write that
+# the compiler runs in another layout costs two copies of the WHOLE pool
+# a pool, layer and step (ops/paged_attention's docstring). Shapes of
+# the benchmark's serving cell: 32 slots, 24 q / 2 kv x 128, block 16,
+# smax 2,048 (8,193 blocks), pools donated as the server donates them.
+
+_C_SLOTS, _C_NQ, _C_NKV, _C_HD, _C_BS = 32, 24, 2, 128, 16
+
+
+def _pool_copies(text, pool) -> list:
+    """The `copy(` ops of a compiled module whose RESULT has the
+    pool's shape."""
+    tag = {"bfloat16": "bf16", "int8": "s8"}[jnp.dtype(pool.dtype).name]
+    shape = f"{tag}[{','.join(map(str, pool.shape))}]"
+    return [ln.strip() for ln in text.splitlines()
+            if " copy(" in ln and shape in ln.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("w", [1, 4], ids=["decode", "window4"])
+def test_paged_write_leaves_the_pool_where_it_lies(sds, w, kv):
+    b, maxb = _C_SLOTS, _SMAX // _C_BS
+    nb = 2 * b * maxb + 1
+    pool = sds((nb, _C_NKV, _C_BS, _C_HD), _POOL_DTYPES[kv])
+    new = (b, _C_NKV, _C_HD) if w == 1 else (b, w, _C_NKV, _C_HD)
+    shapes = [sds((b, w, _C_NQ, _C_HD), jnp.bfloat16),
+              sds(new, jnp.bfloat16), sds(new, jnp.bfloat16), pool, pool,
+              sds((b, maxb), jnp.int32), sds((b,), jnp.int32)]
+    donate = (3, 4)
+    if kv != "bf16":
+        shapes += [sds((nb, _C_NKV), jnp.float32)] * 2
+        donate += (7, 8)
+    attend = (pa.paged_decode_attention if w == 1
+              else pa.paged_window_attention)
+
+    def call(q, kn, vn, kp, vp, table, pos, ks=None, vs=None):
+        return attend(q, kn, vn, kp, vp, table, pos, k_scale=ks,
+                      v_scale=vs, fused=True, interpret=False)
+    text = jax.jit(call, donate_argnums=donate).lower(
+        *shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _pool_copies(text, pool) == []
+
+
+def test_server_step_program_has_no_pool_copy(sds, monkeypatch):
+    """The server's own `jit_step` (two layers of the cell's widths,
+    built by `_paged_step_prog`) for the described chip. The server is
+    built here on the CPU from parameter SHAPES; `jax.default_backend`
+    is steered so that it picks the `fused` kernel and the kernel
+    lowers for the chip, as both do there."""
+    from hpx_tpu.models.serving import ContinuousServer
+    from hpx_tpu.models.transformer import TransformerConfig, init_params
+    cfg = TransformerConfig(vocab=512, d_model=256, n_heads=_C_NQ,
+                            head_dim=_C_HD, n_layers=2, d_ff=512,
+                            n_kv_heads=_C_NKV, rope=True,
+                            dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    srv = ContinuousServer(params, cfg, paged=True, slots=_C_SLOTS,
+                           smax=_SMAX)
+    assert srv._paged_kernel == "fused" and srv.block_size == _C_BS
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    s, maxb = srv.slots, srv._maxb
+    text = srv._paged_step_prog().lower(
+        on_chip(params), on_chip(srv._pools), None,
+        sds((s,), jnp.int32), sds((s,), jnp.int32),
+        sds((s, maxb), jnp.int32), sds((s,), jnp.float32),
+        sds((s, 2), jnp.uint32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= cfg.n_layers
+    assert _pool_copies(text, srv._pools[0][0]) == []
 
 
 # -- flash attention (training forward/backward, ring chunk) -------------

@@ -39,6 +39,8 @@ from hpx_tpu.ops.paged_attention import (
     paged_decode_attention,
     paged_window_attention,
     quantize_blocks,
+    scatter_token,
+    scatter_window,
     scatter_window_q,
 )
 
@@ -282,6 +284,121 @@ def test_flash_tune_paged_step_follows_pool_layout(kvd, kern):
     # bf16 outputs: one ulp at |x| <= 1
     np.testing.assert_allclose(out, np.asarray(g(qg), np.float32),
                                atol=8e-3)
+
+
+# -- row writes: scatter_token / scatter_window vs a NumPy row loop ---------
+
+_TRASH = 0
+
+
+def _write_case(kind, nkv, dtype, w, seed):
+    """(pool, table, pos, vals [B, W, nkv, hd]) of one traffic shape:
+    shuffled tables, ragged positions, random rows."""
+    bs, maxb, hd = 4, 3, 8
+    rng = np.random.default_rng(seed)
+    b = {"live": 3, "dead": 4, "past": 2, "mesh": 6}[kind]
+    nb = b * maxb + 1
+    pool = jnp.asarray(rng.standard_normal((nb, nkv, bs, hd)), dtype)
+    table = rng.permutation(np.arange(1, nb)).reshape(b, maxb)
+    pos = rng.integers(0, maxb * bs - w + 1, size=b)
+    if kind == "dead":          # slots 1 and 3: all-trash tables,
+        table[[1, 3]] = _TRASH  # both on the same trash row
+        pos[3] = pos[1]
+    if kind == "past":          # slot 0 starts on the table's last row
+        pos[0] = maxb * bs - 1
+    vals = jnp.asarray(rng.standard_normal((b, w, nkv, hd)), dtype)
+    return (pool, jnp.asarray(table.astype(np.int32)),
+            jnp.asarray(pos.astype(np.int32)), vals)
+
+
+def _reference_rows(pool, table, pos, vals):
+    """The write, row by row in NumPy (a later duplicate overwrites an
+    earlier one; rows past the table's extent are dropped)."""
+    out = np.array(pool)
+    table, pos, vals = map(np.asarray, (table, pos, vals))
+    bs, maxb = out.shape[2], table.shape[1]
+    for b in range(vals.shape[0]):
+        for i in range(vals.shape[1]):
+            p = pos[b] + i
+            if p < maxb * bs:
+                out[table[b, p // bs], :, p % bs] = vals[b, i]
+    return out
+
+
+def _assert_pool_bits(got, pool, table, pos, vals):
+    """Bitwise the row loop's pool outside the trash block; inside it
+    a row some slot wrote holds ONE of the rows written there (which
+    duplicate wins is unspecified) and every other row is untouched."""
+    got, old = np.asarray(got), np.asarray(pool)
+    want = _reference_rows(pool, table, pos, vals)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert (got[_TRASH + 1:] == want[_TRASH + 1:]).all()
+    table, pos, vals = map(np.asarray, (table, pos, vals))
+    bs = old.shape[2]
+    for r in range(bs):
+        cands = [vals[b, i] for b in range(vals.shape[0])
+                 for i in range(vals.shape[1])
+                 if (pos[b] + i) < table.shape[1] * bs
+                 and table[b, (pos[b] + i) // bs] == _TRASH
+                 and (pos[b] + i) % bs == r] or [old[_TRASH, :, r]]
+        for h in range(old.shape[1]):
+            assert any((got[_TRASH, h, r] == c[h]).all() for c in cands)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("nkv", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["live", "dead"])
+def test_scatter_token_matches_row_loop(kind, nkv, dtype):
+    pool, table, pos, vals = _write_case(kind, nkv, dtype, 1, 7 * nkv)
+    got = jax.jit(scatter_token)(pool, table, pos, vals[:, 0])
+    _assert_pool_bits(got, pool, table, pos, vals)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("nkv", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["live", "dead", "past"])
+def test_scatter_window_matches_row_loop(kind, nkv, dtype):
+    pool, table, pos, vals = _write_case(kind, nkv, dtype, 3, 11 * nkv)
+    got = jax.jit(scatter_window)(pool, table, pos, vals)
+    _assert_pool_bits(got, pool, table, pos, vals)
+    if kind == "past":
+        # slot 0's window starts on the table's last row: one row
+        # lands, two drop, and the last real block keeps every other
+        # row (a clamped write would have hit its rows 0 and 1)
+        last = int(table[0, -1])
+        assert (np.asarray(got)[last, :, :3]
+                == np.asarray(pool)[last, :, :3]).all()
+        assert (np.asarray(got)[last, :, 3]
+                == np.asarray(vals)[0, 0]).all()
+
+
+@pytest.mark.parametrize("nkv", [1, 2, 4])
+@pytest.mark.parametrize("fused", [False, True], ids=["gather", "fused"])
+def test_write_rows_outnumber_attended_slots(fused, nkv):
+    """The mesh form: `write=` carries ALL slots' rows (6) while this
+    shard attends its own 3; every row lands, the attention is the
+    plain call's over the same pools."""
+    kp, table, pos, vals = _write_case("mesh", nkv, jnp.float32, 1, 5)
+    vp = kp * 0.5
+    kn, vn = vals[:, 0], vals[:, 0] + 1.0
+    q = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (3, 1, 2 * nkv, 8)), jnp.float32)
+    att, kg, vg = paged_decode_attention(
+        q, kn, vn, kp, vp, table[:3], pos[:3], fused=fused,
+        interpret=True, write=(table, pos))
+    assert (np.asarray(kg)
+            == _reference_rows(kp, table, pos, kn[:, None])).all()
+    assert (np.asarray(vg)
+            == _reference_rows(vp, table, pos, vn[:, None])).all()
+    # slots 3..5 written beforehand, then the plain call: same answer
+    k0 = scatter_token(kp, table[3:], pos[3:], kn[3:])
+    v0 = scatter_token(vp, table[3:], pos[3:], vn[3:])
+    want, _, _ = paged_decode_attention(q, kn[:3], vn[:3], k0, v0,
+                                        table[:3], pos[:3], fused=fused,
+                                        interpret=True)
+    assert (np.asarray(att) == np.asarray(want)).all()
 
 
 # -- quantized scatter: OOB drop regression ---------------------------------
