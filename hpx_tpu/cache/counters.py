@@ -35,6 +35,9 @@ MoE servers (``cfg.n_experts > 0``) add the expert-routing feed::
     /serving{locality#L/server#i}/moe/tokens-dropped  claims over capacity
     /serving{locality#L/server#i}/moe/expert#e/occupancy  latest capacity
                                                           fraction, per expert
+    /serving{locality#L/server#i}/moe/experts-hit     distinct experts hit a
+                                                      decode step and sparse
+                                                      layer (mean since start)
 
 Tuned servers (``hpx.tune.enable``) add the closed-loop controller's
 accounting — ``/serving{...}/tune/ticks``, ``tune/evals``,
@@ -56,6 +59,14 @@ Paged servers additionally export the cache counters::
                                                           sidecars incl. — fp8 pools
                                                           report the ~0.25x ratio vs
                                                           an f32 compute dtype)
+
+Models with window layers add their second block group::
+
+    /cache{locality#L/server#i}/window/blocks-in-use    window-group blocks held
+    /cache{locality#L/server#i}/window/blocks-freed     blocks released behind a
+                                                        window (cumulative)
+    /cache{locality#L/server#i}/window/prefix-refused   admissions whose prefix
+                                                        match was refused
 
 Tiered servers (``hpx.cache.tier.enable``) add the host-tier feed::
 
@@ -189,6 +200,9 @@ def register_server(srv) -> str:
             put("serving", f"moe/expert#{e}/occupancy",
                 pc.CallbackCounter(_read(
                     ref, lambda s, e=e: s._moe_occ[e])))
+        put("serving", "moe/experts-hit",
+            pc.CallbackCounter(_read(ref, lambda s: (
+                s._moe_hit_sum / s._moe_steps if s._moe_steps else 0.0))))
 
     if getattr(srv, "_tuner", None) is not None:
         # closed-loop tuner observability (svc/autotune): tick/probe/
@@ -247,6 +261,16 @@ def register_server(srv) -> str:
         put("cache", "bytes/hbm-read-per-token",
             pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
                                ["hbm_read_bytes_per_token"])))
+        if getattr(srv, "_win", 0):
+            # the window block group (serving._init_paged)
+            put("cache", "window/blocks-in-use",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._walloc.in_use)))
+            put("cache", "window/blocks-freed",
+                pc.CallbackCounter(_read(ref, lambda s: s._win_freed)))
+            put("cache", "window/prefix-refused",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._prefix_refused)))
         if getattr(srv, "_tier", None) is not None:
             # host-RAM demotion tier (cache/tier.py): occupancy,
             # demote/promote/drop/decline totals, cumulative hit

@@ -22,7 +22,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-__all__ = ["PageTable", "device_table", "materialize", "occupancy"]
+__all__ = ["PageTable", "WindowTable", "device_table", "materialize",
+           "occupancy"]
 
 _UIDS = itertools.count()
 
@@ -102,6 +103,61 @@ class PageTable:
         return row
 
 
+class WindowTable(PageTable):
+    """Block map of one request in a WINDOW group of layers: only the
+    blocks that hold a row some later query can still see stay mapped.
+    `blocks[i]` backs logical block `base + i`; `free_behind(pos)`
+    drops the blocks wholly behind `pos`'s window (a query at `pos`
+    sees rows j with pos - window < j) and the caller returns them to
+    the group's allocator, where another request takes them.
+
+    The jitted programs read the map as a RING of `ring` columns,
+    logical block b in column b % ring (`as_row`): with ring >=
+    ceil(window / block_size) + 1 no two live blocks share a column,
+    so the fused kernel walks `ring` entries whatever the context's
+    length."""
+
+    def __init__(self, block_size: int, window: int, base: int = 0,
+                 blocks: Sequence[int] = ()) -> None:
+        super().__init__(block_size)
+        self.window, self.base = int(window), int(base)
+        self.blocks = list(blocks)
+
+    @property
+    def capacity(self) -> int:
+        return (self.base + len(self.blocks)) * self.block_size
+
+    def first_needed(self, pos: int) -> int:
+        """The first logical block a query at `pos` still sees."""
+        return max(0, pos - self.window + 1) // self.block_size
+
+    def free_behind(self, pos: int) -> List[int]:
+        n = min(len(self.blocks), self.first_needed(pos) - self.base)
+        if n <= 0:
+            return []
+        freed, self.blocks = self.blocks[:n], self.blocks[n:]
+        self.base += n
+        self.version += 1
+        return freed
+
+    def as_row(self, ring: int, pad: int) -> np.ndarray:
+        if len(self.blocks) > ring:
+            raise ValueError(f"window table holds {len(self.blocks)} "
+                             f"live blocks, its ring has {ring} columns")
+        row = np.full((ring,), pad, np.int32)
+        for i, bid in enumerate(self.blocks):
+            row[(self.base + i) % ring] = bid
+        return row
+
+    def as_linear_row(self, max_blocks: int, pad: int) -> np.ndarray:
+        """Column b = logical block b (pad where it is not mapped): the
+        write row of the prefill splice, whose scratch holds every row
+        of the sequence."""
+        row = np.full((max_blocks,), pad, np.int32)
+        row[self.base:self.base + len(self.blocks)] = self.blocks
+        return row
+
+
 def occupancy(tables: Sequence[Optional[PageTable]]) -> int:
     """Total MAPPED blocks across live slots (dead/None slots count 0)
     — the table-occupancy input to the decode-attention
@@ -117,7 +173,7 @@ def materialize(tables: Sequence[Optional[PageTable]], max_blocks: int,
     out = np.full((len(tables), max_blocks), pad, np.int32)
     for i, pt in enumerate(tables):
         if pt is not None:
-            out[i, :len(pt.blocks)] = pt.blocks
+            out[i] = pt.as_row(max_blocks, pad)
     return out
 
 

@@ -31,6 +31,7 @@ standard Switch behavior. The auxiliary load-balance loss
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -38,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["MoeConfig", "init_moe_params", "moe_ffn", "moe_ffn_decode",
-           "moe_param_specs"]
+           "moe_ffn_serve", "moe_param_specs", "route"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,19 +50,40 @@ class MoeConfig:
     d_model: int = 64
     d_ff: int = 128                # per-expert hidden
     dtype: Any = jnp.float32
+    # the expert and the router, as the model defines them. The
+    # capacity path (moe_ffn, training) computes only the defaults; the
+    # drop-free serving path (moe_ffn_serve) computes all of them.
+    mlp: str = "gelu"              # | "swiglu": w2(silu(w1 x) * w3 x)
+    router: str = "softmax"        # | "sigmoid" scores
+    renorm: bool = False           # weights / their sum over the chosen
+    scale: float = 1.0             # routed_scaling_factor
+    shared_d_ff: int = 0           # one shared expert every token takes
 
 
 def init_moe_params(cfg: MoeConfig, key: jax.Array) -> Dict[str, Any]:
     k1, k2, k3 = jax.random.split(key, 3)
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
     s = 1.0 / math.sqrt(d)
-    return {
+    out = {
         "wg": (jax.random.normal(k1, (d, e)) * s).astype(cfg.dtype),
         "w1": (jax.random.normal(k2, (e, d, f)) * s).astype(cfg.dtype),
-        "b1": jnp.zeros((e, f), cfg.dtype),
         "w2": (jax.random.normal(k3, (e, f, d)) / math.sqrt(f)
                ).astype(cfg.dtype),
     }
+    if cfg.mlp != "swiglu":
+        out["b1"] = jnp.zeros((e, f), cfg.dtype)
+        return out
+    k4, k5 = jax.random.split(jax.random.fold_in(key, 1))
+    out["w3"] = (jax.random.normal(k4, (e, d, f)) * s).astype(cfg.dtype)
+    if cfg.shared_d_ff:
+        sf = cfg.shared_d_ff
+        ka, kb, kc = jax.random.split(k5, 3)
+        out["shared"] = {
+            "w1": (jax.random.normal(ka, (d, sf)) * s).astype(cfg.dtype),
+            "w3": (jax.random.normal(kb, (d, sf)) * s).astype(cfg.dtype),
+            "w2": (jax.random.normal(kc, (sf, d)) / math.sqrt(sf)
+                   ).astype(cfg.dtype)}
+    return out
 
 
 def moe_param_specs(axis: str = "ep",
@@ -159,6 +181,15 @@ def moe_ffn(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
     if cfg.top_k > e:
         # an all-masked gate row would silently re-route to expert 0
         raise ValueError(f"top_k ({cfg.top_k}) > n_experts ({e})")
+    if (cfg.mlp, cfg.router, cfg.renorm, cfg.shared_d_ff) != (
+            "gelu", "softmax", False, 0) or cfg.scale != 1.0:
+        raise NotImplementedError(
+            "models/moe.moe_ffn (GShard capacity dispatch: training, "
+            "expert-parallel decode, a finite hpx.serving.moe."
+            "capacity_factor) computes softmax-gated GELU experts only; "
+            f"this model's experts ({cfg.mlp}, {cfg.router} router, "
+            f"shared width {cfg.shared_d_ff}) run drop-free through "
+            "moe_ffn_serve on one shard")
     e_loc = e // p
     capacity = max(1, math.ceil(t * cfg.top_k
                                 * cfg.capacity_factor / e))
@@ -254,3 +285,131 @@ def moe_ffn_decode(x: jax.Array, params: Dict[str, Any],
                                                axis=0)
     out = jax.lax.psum(full, axis)[:t]
     return out, jax.lax.pmean(aux, axis), stats
+
+
+def route(x: jax.Array, wg: jax.Array, cfg: MoeConfig):
+    """Scores -> the top_k experts of every token and their weights:
+    (idx [T, k] int32, w [T, k] f32). Scores in float32 (`softmax` over
+    the experts, or element-wise `sigmoid`); the k largest win, ties to
+    the lower expert id; with `renorm` the weights are divided by their
+    sum over the chosen k; then scaled."""
+    logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)
+    scores = (jax.nn.sigmoid(logits) if cfg.router == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    w, idx = jax.lax.top_k(scores, cfg.top_k)
+    if cfg.renorm:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx, w * cfg.scale
+
+
+def _experts_xla(xa, sizes, params, cfg, dt):
+    """The grouped expert FFN as XLA sees it (`lax.ragged_dot` over the
+    expert-sorted rows): the oracle of the Pallas kernel, and the path
+    off the TPU. xa [A, D] sorted by expert, sizes [E]."""
+    from .quant import dequant
+    rd = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                           preferred_element_type=jnp.float32)
+    h = rd(xa, dequant(params["w1"], dt))
+    if cfg.mlp == "swiglu":
+        h = jax.nn.silu(h) * rd(xa, dequant(params["w3"], dt))
+    else:
+        eid = jnp.repeat(jnp.arange(sizes.shape[0]), sizes,
+                         total_repeat_length=xa.shape[0])
+        h = jax.nn.gelu(h + params["b1"].astype(jnp.float32)[eid])
+    return rd(h.astype(dt), dequant(params["w2"], dt)).astype(dt)
+
+
+def moe_ffn_serve(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
+                  kernel: Any = None, held: Any = None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """DROP-FREE sparse FFN for serving on one shard: every token's
+    top_k experts compute it, whatever the routing's skew.
+
+    x [T, D] -> (out [T, D], stats [2 + E] f32). Static shapes: the
+    T * k assignments are sorted by expert (`argsort`), the group sizes
+    counted, and ONE grouped matrix product runs over the experts that
+    were hit: `kernel="pallas"` the `hpx_moe_gmm` kernel
+    (ops/moe_gmm.py; rows padded per expert to its row tile, an expert
+    no token chose is never read), `kernel="xla"` `lax.ragged_dot`;
+    None picks pallas on a TPU for SiLU-gated experts and xla
+    elsewhere. Each token then gathers its k rows back and sums them
+    under its weights (a gather, no scatter-add: deterministic), plus
+    the shared expert where the model has one.
+
+    `held=(lo, hi)`: this shard's SHARE of the experts. `params` then
+    hold experts lo..hi-1 only ([hi - lo, ...] matrices) and, on the
+    one shard that is to count it, the shared expert; the router keeps
+    its published width, every token is routed over all the experts,
+    and the result is the part that the held experts give (what the
+    absent ones would add is left out, with no stand-in for them). The
+    shares of a layer add up to the whole layer.
+
+    stats: [claims routed (T * k), claims dropped (0 by construction),
+    per-expert occupancy]. With no capacity an expert's occupancy
+    reads 1.0 where at least one token chose it and 0.0 where none
+    did, so the vector's tail sums to the distinct experts hit."""
+    from .quant import dequant
+    t, d = x.shape
+    e, k, dt = cfg.n_experts, cfg.top_k, cfg.dtype
+    if k > e:
+        raise ValueError(f"top_k ({k}) > n_experts ({e})")
+    idx, w = route(x, params["wg"], cfg)
+    a = t * k
+    flat = idx.reshape(a)
+    mine = None
+    if held is not None:
+        lo, hi = held
+        mine = jnp.logical_and(flat >= lo, flat < hi)
+        e = hi - lo
+        flat = jnp.where(mine, flat - lo, e)         # absent: sorted last
+        w = jnp.where(mine.reshape(t, k), w, 0.0)
+    order = jnp.argsort(flat, stable=True)           # by expert
+    sizes = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
+    xd = x.astype(dt)
+    if kernel is None:
+        kernel = ("pallas" if jax.default_backend() == "tpu"
+                  and cfg.mlp == "swiglu" else "xla")
+    if kernel == "pallas":
+        if cfg.mlp != "swiglu":
+            raise NotImplementedError(
+                "ops/moe_gmm.hpx_moe_gmm computes SiLU-gated experts; "
+                "GELU experts with a bias take kernel='xla'")
+        from ..ops.moe_gmm import grouped_swiglu, row_tile
+        tm = row_tile(dt)
+        n_tiles = -(-a // tm) + e
+        tiles = -(-sizes // tm)                      # row tiles an expert
+        tile_end = jnp.cumsum(tiles)
+        n_used = tile_end[-1:]
+        # sorted assignment j sits at rank j - start[e_j] of its group
+        es = jnp.minimum(flat[order], e - 1)
+        start = jnp.cumsum(sizes) - sizes
+        dest_sorted = jnp.minimum(
+            (tile_end - tiles)[es] * tm + (jnp.arange(a) - start[es]),
+            n_tiles * tm - 1)
+        dest = jnp.zeros((a,), jnp.int32).at[order].set(
+            dest_sorted.astype(jnp.int32))           # by (token, choice)
+        x_pad = jnp.zeros((n_tiles * tm, d), dt).at[dest].set(
+            jnp.repeat(xd, k, axis=0))
+        tile_e = jnp.searchsorted(
+            tile_end, jnp.minimum(jnp.arange(n_tiles), n_used[0] - 1),
+            side="right").astype(jnp.int32)
+        y = grouped_swiglu(
+            x_pad, jnp.minimum(tile_e, e - 1), n_used,
+            dequant(params["w1"], dt), dequant(params["w3"], dt),
+            dequant(params["w2"], dt))[dest]
+    else:
+        ys = _experts_xla(xd[order // k], sizes, params, cfg, dt)
+        y = jnp.zeros_like(ys).at[order].set(ys)     # by (token, choice)
+    if mine is not None:        # an absent expert's row is nobody's
+        y = jnp.where(mine[:, None], y, 0)
+    out = jnp.sum(y.reshape(t, k, d).astype(jnp.float32)
+                  * w[..., None], axis=1)
+    if "shared" in params:
+        sp = params["shared"]
+        hs = (jax.nn.silu(xd @ dequant(sp["w1"], dt))
+              * (xd @ dequant(sp["w3"], dt))) @ dequant(sp["w2"], dt)
+        out = out + hs.astype(jnp.float32)
+    stats = jnp.concatenate(
+        [jnp.asarray([a, 0.0], jnp.float32),
+         (sizes > 0).astype(jnp.float32)])
+    return out.astype(x.dtype), stats

@@ -71,7 +71,6 @@ stays the lean fast path for uniform decode).
 from __future__ import annotations
 
 import dataclasses
-import math
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
@@ -82,7 +81,8 @@ import numpy as np
 
 from ..cache.block_allocator import BlockAllocator, CacheOOM, block_bytes
 from ..cache.ngram import propose as _ngram_propose
-from ..cache.page_table import PageTable, materialize, occupancy
+from ..cache.page_table import (PageTable, WindowTable, materialize,
+                                occupancy)
 from ..cache.radix import RadixCache
 from ..core.errors import Error, HpxError
 from ..svc import faultinject, flight, tracing
@@ -99,12 +99,12 @@ from ..ops.paged_attention import (
 from .transformer import (
     _PREFILL_CHUNK,
     TransformerConfig,
+    _cached_attention,
     _cached_program,
     _decode_window,
-    _dq,
-    _ln,
+    _layer,
+    _logits,
     _pick_row,
-    _qkv_proj,
     _sample_row,
     _tree_key,
 )
@@ -272,53 +272,35 @@ def _rc_at_default(rc, key: str) -> bool:
     return entry is not None and rc.get(key) == entry.default
 
 
-def _rope_win(x, posw, cfg: TransformerConfig):
-    """Rotate-half RoPE over a PER-ROW position GRID: x [B, W, N, H],
-    posw [B, W] int32 — each (row, window-column) pair rotates at its
-    own absolute position (transformer._rope takes one shared [S]
-    vector; `_rope_rows` is the W == 1 special case)."""
-    hd = x.shape[-1]
-    half = hd // 2
-    freq = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32)
-                              / half)
-    ang = posw.astype(jnp.float32)[..., None] * freq  # [B, W, half]
-    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x1 * sin + x2 * cos], axis=-1)
-
-
-def _rope_rows(x, pos, cfg: TransformerConfig):
-    """Rotate-half RoPE with PER-ROW positions: x [B, 1, N, H],
-    pos [B] int32."""
-    return _rope_win(x, pos[:, None], cfg)
-
-
-def _moe_rows(h2, lp, cfg, moe_cf=None, moe_ep=None, moe_sink=None,
+def _moe_rows(h, lp, cfg, moe_cf=None, moe_ep=None, moe_sink=None,
               moe_ms=None):
-    """Shared MoE branch of the serving block fns: expert FFN over the
-    flattened [T, D] token block. `moe_cf` overrides the capacity
-    factor (None = drop-free n_experts, the token-identity default);
-    `moe_ep` = (axis_name, axis_size) routes expert-parallel through
-    `moe_ffn_decode` — only valid inside a shard_map body; `moe_sink`
-    (a list) collects the per-layer psum-complete stats vector;
-    `moe_ms` is the replicated stats sharding for GSPMD bodies (see
-    moe_ffn's stats_sharding)."""
-    from .moe import moe_ffn, moe_ffn_decode
+    """The sparse FFN of the serving bodies, over the token block h
+    [B, W, D]. On one shard with no finite capacity set (`moe_cf`
+    None or >= n_experts: the token-identity default) it is the
+    drop-free `moe_ffn_serve`. `moe_ep` = (axis_name, axis_size)
+    routes expert-parallel through `moe_ffn_decode` — only valid
+    inside a shard_map body; `moe_ms` is the replicated stats sharding
+    of the GSPMD bodies (see moe_ffn's stats_sharding); both, and a
+    finite `moe_cf`, take the GShard capacity dispatch. `moe_sink` (a
+    list) collects the per-layer psum-complete stats vector."""
+    from .moe import moe_ffn, moe_ffn_decode, moe_ffn_serve
     from .transformer import _moe_cfg
+    b, w, d = h.shape
+    h2 = h.reshape(b * w, d)
     cf = float(cfg.n_experts) if moe_cf is None else float(moe_cf)
     mcfg = dataclasses.replace(_moe_cfg(cfg), capacity_factor=cf)
     if moe_ep is not None:
         out, _aux, stats = moe_ffn_decode(h2, lp["moe"], mcfg,
                                           moe_ep[0], moe_ep[1])
+    elif moe_ms is None and cf >= cfg.n_experts:
+        out, stats = moe_ffn_serve(h2, lp["moe"], mcfg)
     else:
         out, _aux, stats = moe_ffn(h2, lp["moe"], mcfg,
                                    return_stats=True,
                                    stats_sharding=moe_ms)
     if moe_sink is not None:
         moe_sink.append(stats)
-    return out
+    return out.reshape(b, w, d)
 
 
 def _moe_fold(sink):
@@ -371,278 +353,144 @@ def _write_rows(k, v, write):
     return kv[:, 0], kv[:, 1], (table, pos)
 
 
-def _block_decode_rows(x, lp, kv, pos, cfg: TransformerConfig,
-                       moe_cf=None, moe_ep=None, moe_sink=None,
-                       moe_ms=None):
-    """One decoder block for ONE new token per slot with PER-SLOT cache
-    positions. x: [B, 1, D]; kv: (k_cache, v_cache) [B, Smax, Nkv, H];
-    pos: [B] int32 — slot b's token lands at pos[b], and its query
-    attends cache positions <= pos[b]. The write is a batched scatter
-    (row b at pos[b]); everything else mirrors _block_decode. MoE
-    layers route through `_moe_rows` (expert-parallel when `moe_ep`
-    names a mesh axis)."""
-    kc, vc = kv
-    b = x.shape[0]
-    h = _ln(x, lp["ln1"])
-    q, k, v = _qkv_proj(h, lp)
-    if cfg.rope:
-        q = _rope_rows(q, pos, cfg)
-        k = _rope_rows(k, pos, cfg)
-    rows = jnp.arange(b)
-    kc = kc.at[rows, pos].set(k[:, 0])
-    vc = vc.at[rows, pos].set(v[:, 0])
-    nq, hd = q.shape[2], q.shape[3]
-    nkv = kc.shape[2]
-    g = nq // nkv
-    qg = q.reshape(b, 1, nkv, g, hd)
-    s = jnp.einsum("bqngh,bknh->bngqk", qg, kc) / math.sqrt(hd)
-    kpos = jnp.arange(kc.shape[1])
-    live = kpos[None, :] <= pos[:, None]               # [B, Smax]
-    s = jnp.where(live[:, None, None, None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-    att = jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(b, 1, nq, hd)
-    o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
-    x = x + o
-    h = _ln(x, lp["ln2"])
-    if "moe" in lp:
-        d = h.shape[-1]
-        out = _moe_rows(h.reshape(b, d), lp, cfg, moe_cf, moe_ep,
-                        moe_sink, moe_ms)
-        return x + out.reshape(b, 1, d), (kc, vc)
-    h = jax.nn.gelu(h @ _dq(lp["w1"], h) + lp["b1"]) @ _dq(lp["w2"], h)
-    return x + h, (kc, vc)
+def _window_rows(x, lp, kv, pos0, cfg: TransformerConfig, li=0,
+                 moe_cf=None, moe_ep=None, moe_sink=None, moe_ms=None):
+    """Layer `li` for a W-token window per slot at PER-SLOT positions
+    over dense per-slot caches: `_layer` with those caches as its
+    mixer. x [B, W, D] (W = 1: one decode token a slot; W > 1: the
+    speculative verify window); kv: (k_cache, v_cache) [B, Smax, Nkv,
+    H]; pos0 [B] int32 — slot b's window row i lands at cache position
+    pos0[b] + i and attends positions <= pos0[b] + i.
 
+    Same projections, same einsum contractions over the same smax
+    rows, same -inf mask and f32 softmax at every W — so window column
+    i's output is byte-identical to what the i-th SEQUENTIAL step
+    would compute (K/V rows are functions of (token, position) alone,
+    and column i's horizon includes exactly the window rows < i it
+    would have already written). Window columns past smax-1 (a dead
+    slot's stale cursor, or batch-width padding beyond a short slot's
+    budget) scatter with ``mode="drop"``: clamping would corrupt row
+    smax-1, which can hold live K/V."""
+    posw = pos0[:, None] + jnp.arange(x.shape[1])[None, :]   # [B, W]
+    rows = jnp.arange(x.shape[0])[:, None]
 
-def _decode_rows(params, caches, tok, pos, cfg, moe_cf=None,
-                 moe_ep=None, moe_ms=None):
-    """One token per slot through every block at per-slot positions;
-    returns (caches, f32 logits [B, V], mstats) — mstats is the folded
-    MoE stats vector (None for dense models)."""
-    x = params["emb"][tok][:, None, :]
-    new_caches = []
-    sink = []
-    for lp, kv in zip(params["layers"], caches):
-        x, kv = _block_decode_rows(x, lp, kv, pos, cfg, moe_cf,
-                                   moe_ep, sink, moe_ms)
-        new_caches.append(kv)
-    x = _ln(x, params["ln_f"])
-    logits = jnp.einsum("bsd,vd->bsv", x, params["emb"])
-    return (new_caches, logits[:, 0, :].astype(jnp.float32),
-            _moe_fold(sink))
+    def attend(q, k, v):
+        kc = kv[0].at[rows, posw].set(k, mode="drop")
+        vc = kv[1].at[rows, posw].set(v, mode="drop")
+        return _cached_attention(q, kc, vc, posw, cfg.window(li)), \
+            (kc, vc)
 
-
-def _paged_block_rows(x, lp, pools, scales, table, pos,
-                      cfg: TransformerConfig, fused=False,
-                      tp_axis=None, moe_cf=None, moe_ep=None,
-                      moe_sink=None, write=None):
-    """_block_decode_rows with the K/V rows living in a shared BLOCK
-    POOL instead of per-slot dense buffers. x: [B, 1, D]; pools:
-    (k_pool, v_pool) each [num_blocks, Nkv, block_size, H]; scales:
-    (k_scale, v_scale) [num_blocks, Nkv] f32 sidecars for int8 pools,
-    or None; table: [B, max_blocks] int32 logical->physical block map;
-    pos: [B] int32. Projections/rope/ffn are byte-identical to the
-    dense path; only the cache write (scatter through the table) and
-    read (gather in logical order — same row values at the same
-    logical indices, or the fused Pallas table walk) differ, which is
-    what keeps paged == dense token-exact.
-
-    Under shard_map on a (dp, tp) mesh, `tp_axis` names the
-    tensor-parallel axis: every shard sees its LOCAL kv-head slice of
-    the pools (block axis replicated over dp) and the partial attention
-    / ffn outputs close with explicit psums — the same two reduction
-    points `_block_decode` uses; `write` as in `_write_rows`."""
-    kp, vp = pools
-    b = x.shape[0]
-    h = _ln(x, lp["ln1"])
-    q, k, v = _qkv_proj(h, lp)
-    if cfg.rope:
-        q = _rope_rows(q, pos, cfg)
-        k = _rope_rows(k, pos, cfg)
-    kn, vn, write = _write_rows(k[:, 0], v[:, 0], write)
-    if scales is None:
-        att, kp, vp = paged_decode_attention(q, kn, vn, kp, vp, table,
-                                             pos, fused=fused,
-                                             write=write)
-    else:
-        ks, vs = scales
-        att, kp, vp, ks, vs = paged_decode_attention(
-            q, kn, vn, kp, vp, table, pos,
-            k_scale=ks, v_scale=vs, fused=fused, write=write)
-        scales = (ks, vs)
-    o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
-    if tp_axis is not None:
-        o = jax.lax.psum(o, tp_axis)
-    x = x + o
-    h = _ln(x, lp["ln2"])
-    if "moe" in lp:
-        d = h.shape[-1]
-        out = _moe_rows(h.reshape(b, d), lp, cfg, moe_cf, moe_ep,
-                        moe_sink)
-        return x + out.reshape(b, 1, d), (kp, vp), scales
-    h = jax.nn.gelu(h @ _dq(lp["w1"], h) + lp["b1"]) @ _dq(lp["w2"], h)
-    if tp_axis is not None:
-        h = jax.lax.psum(h, tp_axis)
-    return x + h, (kp, vp), scales
-
-
-def _paged_decode_rows(params, pools, scales, tok, table, pos, cfg,
-                       fused=False, tp_axis=None, moe_cf=None,
-                       moe_ep=None, dp=None):
-    """One token per slot through every block over paged pools;
-    returns (pools, scales, f32 logits [B, V], mstats) — the
-    _decode_rows analog. `scales` is the per-layer list of
-    (k_scale, v_scale) sidecars for int8 pools, or None (passed
-    through untouched)."""
-    x = params["emb"][tok][:, None, :]
-    new_pools, new_scales = [], []
-    sink = []
-    write = dp and (dp, _dp_rows(table, dp), _dp_rows(pos, dp))
-    for i, (lp, pl) in enumerate(zip(params["layers"], pools)):
-        sc = None if scales is None else scales[i]
-        x, pl, sc = _paged_block_rows(x, lp, pl, sc, table, pos, cfg,
-                                      fused, tp_axis, moe_cf, moe_ep,
-                                      sink, write)
-        new_pools.append(pl)
-        new_scales.append(sc)
-    x = _ln(x, params["ln_f"])
-    logits = jnp.einsum("bsd,vd->bsv", x, params["emb"])
-    return (new_pools, None if scales is None else new_scales,
-            logits[:, 0, :].astype(jnp.float32), _moe_fold(sink))
-
-
-def _window_rows(x, lp, kv, pos0, cfg: TransformerConfig,
-                 moe_cf=None, moe_ep=None, moe_sink=None,
-                 moe_ms=None):
-    """One decoder block for a W-token VERIFY WINDOW per slot at
-    PER-SLOT positions: x [B, W, D]; slot b's window row i lands at
-    cache position pos0[b] + i and attends positions <= pos0[b] + i.
-
-    This is `_block_decode_rows` stretched to W columns — same
-    projections, same einsum contractions over the same smax rows,
-    same -inf mask and f32 softmax — so window column i's output is
-    byte-identical to what the i-th SEQUENTIAL step would compute
-    (K/V rows are functions of (token, position) alone, and column
-    i's horizon includes exactly the window rows < i it would have
-    already written). Window columns past smax-1 (a dead slot's stale
-    cursor, or batch-width padding beyond a short slot's budget)
-    scatter with ``mode="drop"``: clamping would corrupt row smax-1,
-    which can hold live K/V."""
-    kc, vc = kv
-    b, w = x.shape[0], x.shape[1]
-    h = _ln(x, lp["ln1"])
-    q, k, v = _qkv_proj(h, lp)
-    posw = pos0[:, None] + jnp.arange(w)[None, :]      # [B, W]
-    if cfg.rope:
-        q = _rope_win(q, posw, cfg)
-        k = _rope_win(k, posw, cfg)
-    rows = jnp.arange(b)[:, None]
-    kc = kc.at[rows, posw].set(k, mode="drop")
-    vc = vc.at[rows, posw].set(v, mode="drop")
-    nq, hd = q.shape[2], q.shape[3]
-    nkv = kc.shape[2]
-    g = nq // nkv
-    qg = q.reshape(b, w, nkv, g, hd)
-    s = jnp.einsum("bqngh,bknh->bngqk", qg, kc) / math.sqrt(hd)
-    kpos = jnp.arange(kc.shape[1])
-    live = kpos[None, None, :] <= posw[:, :, None]     # [B, W, Smax]
-    s = jnp.where(live[:, None, None, :, :], s, -jnp.inf)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-    att = jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(b, w, nq, hd)
-    o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
-    x = x + o
-    h = _ln(x, lp["ln2"])
-    if "moe" in lp:
-        d = h.shape[-1]
-        out = _moe_rows(h.reshape(b * w, d), lp, cfg, moe_cf, moe_ep,
-                        moe_sink, moe_ms)
-        return x + out.reshape(b, w, d), (kc, vc)
-    h = jax.nn.gelu(h @ _dq(lp["w1"], h) + lp["b1"]) @ _dq(lp["w2"], h)
-    return x + h, (kc, vc)
+    return _layer(x, lp, cfg, li, posw, attend, None,
+                  lambda h: _moe_rows(h, lp, cfg, moe_cf, moe_ep,
+                                      moe_sink, moe_ms))
 
 
 def _decode_window_rows(params, caches, toks, pos0, cfg, moe_cf=None,
                         moe_ep=None, moe_ms=None):
-    """W tokens per slot through every block at per-slot positions
-    (the speculative-verify forward); toks [B, W] int32, pos0 [B]
-    int32. Returns (caches, f32 logits [B, W, V], mstats)."""
+    """W tokens per slot through every layer at per-slot positions
+    (W = 1: the decode step; else the speculative-verify forward);
+    toks [B, W] int32, pos0 [B] int32. Returns (caches, f32 logits
+    [B, W, V], mstats) — mstats is the folded MoE stats vector (None
+    for dense models)."""
     x = params["emb"][toks]
     new_caches = []
     sink = []
-    for lp, kv in zip(params["layers"], caches):
-        x, kv = _window_rows(x, lp, kv, pos0, cfg, moe_cf, moe_ep,
+    for li, (lp, kv) in enumerate(zip(params["layers"], caches)):
+        x, kv = _window_rows(x, lp, kv, pos0, cfg, li, moe_cf, moe_ep,
                              sink, moe_ms)
         new_caches.append(kv)
-    x = _ln(x, params["ln_f"])
-    logits = jnp.einsum("bsd,vd->bsv", x, params["emb"])
-    return new_caches, logits.astype(jnp.float32), _moe_fold(sink)
+    return (new_caches, _logits(params, x, cfg).astype(jnp.float32),
+            _moe_fold(sink))
+
+
+def _decode_rows(params, caches, tok, pos, cfg, moe_cf=None,
+                 moe_ep=None, moe_ms=None):
+    """One token per slot: the W == 1 case of `_decode_window_rows`
+    (logits [B, V])."""
+    caches, logits, ms = _decode_window_rows(
+        params, caches, tok[:, None], pos, cfg, moe_cf, moe_ep, moe_ms)
+    return caches, logits[:, 0, :], ms
 
 
 def _paged_window_rows(x, lp, pools, scales, table, pos0,
-                       cfg: TransformerConfig, fused=False,
+                       cfg: TransformerConfig, li=0, fused=False,
                        tp_axis=None, moe_cf=None, moe_ep=None,
                        moe_sink=None, write=None):
-    """`_window_rows` over paged pools: the scatter/gather and the
-    per-query horizon live in `ops.paged_attention.
-    paged_window_attention`; projections/rope/ffn are byte-identical
-    to the dense window, which keeps paged == dense token-exact under
-    speculation too. `tp_axis` closes the per-shard partial sums under
-    shard_map, and `write` keeps the pool copies equal, exactly as in
-    `_paged_block_rows`."""
-    kp, vp = pools
-    b, w = x.shape[0], x.shape[1]
-    h = _ln(x, lp["ln1"])
-    q, k, v = _qkv_proj(h, lp)
+    """`_window_rows` with the K/V rows living in a shared BLOCK POOL
+    instead of per-slot dense buffers: `_layer` with the pools as its
+    mixer. x: [B, W, D]; pools: (k_pool, v_pool) each [num_blocks,
+    Nkv, block_size, H]; scales: (k_scale, v_scale) [num_blocks, Nkv]
+    f32 sidecars for quantized pools, or None; table: [B, max_blocks]
+    int32 logical->physical block map OF THIS LAYER'S GROUP (a window
+    layer's is its ring, `cfg.window(li)` names the width); pos0: [B]
+    int32. Projections/rope/ffn are byte-identical to the dense path;
+    only the cache write (scatter through the table) and read (gather
+    in logical order — same row values at the same logical indices, or
+    the fused Pallas table walk) differ, which is what keeps paged ==
+    dense token-exact, under speculation too (`ops.paged_attention`
+    holds both, W == 1 as `paged_decode_attention`).
+
+    Under shard_map on a (dp, tp) mesh, `tp_axis` names the
+    tensor-parallel axis: every shard sees its LOCAL kv-head slice of
+    the pools (block axis replicated over dp) and the partial attention
+    / ffn outputs close with explicit psums; `write` as in
+    `_write_rows`."""
+    w = x.shape[1]
     posw = pos0[:, None] + jnp.arange(w)[None, :]
-    if cfg.rope:
-        q = _rope_win(q, posw, cfg)
-        k = _rope_win(k, posw, cfg)
-    kn, vn, write = _write_rows(k, v, write)
-    if scales is None:
-        att, kp, vp = paged_window_attention(q, kn, vn, kp, vp, table,
-                                             pos0, fused=fused,
-                                             write=write)
-    else:
-        ks, vs = scales
-        att, kp, vp, ks, vs = paged_window_attention(
-            q, kn, vn, kp, vp, table, pos0,
-            k_scale=ks, v_scale=vs, fused=fused, write=write)
-        scales = (ks, vs)
-    o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
-    if tp_axis is not None:
-        o = jax.lax.psum(o, tp_axis)
-    x = x + o
-    h = _ln(x, lp["ln2"])
-    if "moe" in lp:
-        d = h.shape[-1]
-        out = _moe_rows(h.reshape(b * w, d), lp, cfg, moe_cf, moe_ep,
-                        moe_sink)
-        return x + out.reshape(b, w, d), (kp, vp), scales
-    h = jax.nn.gelu(h @ _dq(lp["w1"], h) + lp["b1"]) @ _dq(lp["w2"], h)
-    if tp_axis is not None:
-        h = jax.lax.psum(h, tp_axis)
-    return x + h, (kp, vp), scales
+    kw = {"fused": fused, "window": cfg.window(li)}
+    if scales is not None:
+        kw.update(k_scale=scales[0], v_scale=scales[1])
+
+    def attend(q, k, v):
+        if w == 1:
+            k, v, paged = k[:, 0], v[:, 0], paged_decode_attention
+        else:
+            paged = paged_window_attention
+        kn, vn, wr = _write_rows(k, v, write)
+        out = paged(q, kn, vn, *pools, table, pos0, write=wr, **kw)
+        return out[0], (out[1:3], tuple(out[3:]) or None)
+
+    x, (pools, scales) = _layer(
+        x, lp, cfg, li, posw, attend, tp_axis,
+        lambda h: _moe_rows(h, lp, cfg, moe_cf, moe_ep, moe_sink))
+    return x, pools, scales
 
 
-def _paged_decode_window_rows(params, pools, scales, toks, table, pos0,
+def _paged_decode_window_rows(params, pools, scales, toks, tables, pos0,
                               cfg, fused=False, tp_axis=None,
                               moe_cf=None, moe_ep=None, dp=None):
-    """W tokens per slot over paged pools; returns (pools, scales, f32
-    logits [B, W, V], mstats) — the `_decode_window_rows` analog."""
+    """W tokens per slot over paged pools (W = 1: the decode step);
+    returns (pools, scales, f32 logits [B, W, V], mstats) — the
+    `_decode_window_rows` analog. `tables`: one [B, max_blocks] map a
+    block GROUP, (full,) or (full, window ring); a layer reads its
+    group's. `scales` is the per-layer list of (k_scale, v_scale)
+    sidecars for quantized pools, or None (passed through untouched)."""
     x = params["emb"][toks]
     new_pools, new_scales = [], []
     sink = []
-    write = dp and (dp, _dp_rows(table, dp), _dp_rows(pos0, dp))
-    for i, (lp, pl) in enumerate(zip(params["layers"], pools)):
-        sc = None if scales is None else scales[i]
-        x, pl, sc = _paged_window_rows(x, lp, pl, sc, table, pos0, cfg,
-                                       fused, tp_axis, moe_cf, moe_ep,
-                                       sink, write)
+    writes = [dp and (dp, _dp_rows(t, dp), _dp_rows(pos0, dp))
+              for t in tables]
+    for li, (lp, pl) in enumerate(zip(params["layers"], pools)):
+        grp = 1 if cfg.window(li) else 0
+        sc = None if scales is None else scales[li]
+        x, pl, sc = _paged_window_rows(x, lp, pl, sc, tables[grp], pos0,
+                                       cfg, li, fused, tp_axis, moe_cf,
+                                       moe_ep, sink, writes[grp])
         new_pools.append(pl)
         new_scales.append(sc)
-    x = _ln(x, params["ln_f"])
-    logits = jnp.einsum("bsd,vd->bsv", x, params["emb"])
     return (new_pools, None if scales is None else new_scales,
-            logits.astype(jnp.float32), _moe_fold(sink))
+            _logits(params, x, cfg).astype(jnp.float32), _moe_fold(sink))
+
+
+def _paged_decode_rows(params, pools, scales, tok, tables, pos, cfg,
+                       fused=False, tp_axis=None, moe_cf=None,
+                       moe_ep=None, dp=None):
+    """One token per slot over paged pools: the W == 1 case of
+    `_paged_decode_window_rows` (logits [B, V])."""
+    pools, scales, logits, ms = _paged_decode_window_rows(
+        params, pools, scales, tok[:, None], tables, pos, cfg, fused,
+        tp_axis, moe_cf, moe_ep, dp)
+    return pools, scales, logits[:, 0, :], ms
 
 
 def _verify_tail(logits, toks, kvec, temp, keys, pos0, width):
@@ -707,6 +555,9 @@ class SlotCheckpoint:
     slot_k: int                    # spec adaptive-k at capture
     slot_acc: float                # spec acceptance EMA at capture
     pins: List[int] = dataclasses.field(default_factory=list)
+    # window group: (base, every block live at capture, the frontier
+    # too — nothing shares a window block, so no pin forks one)
+    wpins: Optional[Tuple[int, List[int]]] = None
 
 
 @dataclasses.dataclass
@@ -742,8 +593,10 @@ class _PendingPrefill:
     seq: int                       # admission order (FIFO tiebreak)
     pt: Optional[PageTable] = None  # paged: blocks held for the request
     trow: Any = None               # paged: device [maxb] table row
-    wrow: Any = None               # paged: splice WRITE row (matched
-                                   # prefix entries point at trash)
+    wrow: Any = None               # paged: splice WRITE rows, one a
+                                   # block group (matched prefix
+                                   # entries point at trash)
+    wt: Optional[WindowTable] = None   # blocks held in the window group
     flow: Optional[int] = None     # tracing flow id chaining the chunks
 
     @property
@@ -818,6 +671,15 @@ class ContinuousServer:
         self.mesh = mesh
         self.paged = bool(paged)
         nkv, hd = cfg.kv_heads, cfg.head_dim
+        # the window layers' width: they are ONE block group beside the
+        # full layers' (cache/page_table.WindowTable), so one width
+        wins = {cfg.window(i) for i in range(cfg.n_layers)} - {0}
+        if len(wins) > 1:
+            raise NotImplementedError(
+                f"window layers of widths {sorted(wins)}: the paged "
+                "cache keeps one window block group (models/serving.py "
+                "_init_paged, cache/page_table.WindowTable)")
+        self._win = wins.pop() if wins else 0
         from ..core.config import runtime_config
         rc = runtime_config()
         cache_sh = None
@@ -867,6 +729,8 @@ class ContinuousServer:
         self._moe_routed = 0.0
         self._moe_dropped = 0.0
         self._moe_occ = [0.0] * max(0, cfg.n_experts)
+        self._moe_hit_sum = 0.0     # sum over drained steps of the
+        self._moe_steps = 0         # occupancy vector's total
         self._moe_buf: deque = deque()
 
         # learned-ladder boot consult (svc/perfdb): with
@@ -930,6 +794,12 @@ class ContinuousServer:
         if spec is None:
             spec = rc.get_bool("hpx.serving.spec.enable", False)
         self._spec = bool(spec)
+        if self._spec and self._win:
+            raise NotImplementedError(
+                "speculative verify on a model with window layers: a "
+                "W-token window needs window + W rows, the window "
+                "group's ring holds window + 2 blocks (models/serving.py "
+                "_spec_step, ops/paged_attention.paged_window_attention)")
         if spec_draft is None:
             spec_draft = rc.get("hpx.serving.spec.draft", "prompt")
             if draft_params is not None:
@@ -1212,6 +1082,33 @@ class ContinuousServer:
         self._prefix_reuse = bool(prefix_reuse)
         self._alloc = BlockAllocator(num_blocks, bs,
                                      kv_dtype=self._kv_dtype)
+        # the WINDOW block group: layers that see the last `window`
+        # rows keep only those. A request's map there is a ring of
+        # ceil(window / bs) + 2 columns whose blocks behind the window
+        # go back to this group's own allocator as decode advances;
+        # its pools hold slots x ring blocks, not slots x smax rows,
+        # plus what a slot's checkpoint pins behind its window (the
+        # tokens between two captures and in flight) and a trash block.
+        self._ring = self._walloc = self._wtrash = None
+        self._win_freed = self._prefix_refused = 0
+        if self._win:
+            for what, on in (("a (dp, tp) mesh", self.mesh is not None),
+                             ("a quantized hpx.cache.kv_dtype",
+                              self._kv_dtype != "bf16"),
+                             ("the host tier", rc.get_bool(
+                                 "hpx.cache.tier.enable", False))):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} on a model with window layers: the "
+                        "window block group (models/serving.py "
+                        "_init_paged) is single-device bf16 pools")
+            self._ring = -(-self._win // bs) + 2
+            lag = rc.get_int("hpx.serving.ckpt_every", 16) + rc.get_int(
+                "hpx.serving.max_async_steps", 32)
+            self._walloc = BlockAllocator(
+                slots * (self._ring + -(-lag // bs) + 1) + 1, bs,
+                kv_dtype=self._kv_dtype)
+            self._wtrash = self._walloc.alloc()
         # the trash block: dead slots' tables and table padding point
         # here, so masked decode lanes scatter into rows nothing reads
         self._trash = self._alloc.alloc()
@@ -1272,8 +1169,13 @@ class ContinuousServer:
                 return jnp.zeros((num_blocks, nkv, bs, hd), dt,
                                  device=self._pool_sh)
             return jnp.zeros((num_blocks, nkv, bs, hd), dt)
-        self._pools = [(pzeros(), pzeros())
-                       for _ in range(cfg.n_layers)]
+
+        def wzeros():
+            return jnp.zeros((self._walloc.num_blocks, nkv, bs, hd),
+                             cfg.dtype)
+        self._pools = [(wzeros(), wzeros()) if cfg.window(i)
+                       else (pzeros(), pzeros())
+                       for i in range(cfg.n_layers)]
         if self._kv_dtype in ("int8", "fp8"):
             def sones():
                 # scale 1.0 is quantize_blocks' zero-block convention:
@@ -1287,8 +1189,10 @@ class ContinuousServer:
         else:
             self._scales = None
         self._tables: List[Optional[PageTable]] = [None] * slots
-        self._tables_sig = None     # (uid, version) per slot
-        self._tables_arr = None     # cached device [slots, maxb] map
+        self._wtables: List[Optional[WindowTable]] = [None] * slots
+        self._tables_sig = None     # (uid, version) per slot and group
+        self._tables_arr = None     # cached device maps, one a group:
+                                    # ([slots, maxb], [slots, ring]?)
         self._prefill_saved = 0
         self._prefill_computed = 0
 
@@ -1438,6 +1342,7 @@ class ContinuousServer:
         nb, bs = self._alloc.num_blocks, self.block_size
         ck = ("pg_step", cfg, slots, smax, nb, bs, self._kv_dtype,
               self._paged_kernel, self._moe_capacity_pct, self.mesh,
+              self._walloc and self._walloc.num_blocks,
               _tree_key(self.params))
 
         def build():
@@ -1480,7 +1385,7 @@ class ContinuousServer:
             return self._jit_step(jax.shard_map(
                 step, mesh=self.mesh,
                 in_specs=(pspecs, pool_sp, scale_sp, P("dp"),
-                          P("dp"), P("dp", None), P("dp"),
+                          P("dp"), (P("dp", None),), P("dp"),
                           P("dp", None)),
                 out_specs=(pool_sp, scale_sp, P("dp"), P())))
         return self._program(ck, build)
@@ -1562,15 +1467,17 @@ class ContinuousServer:
         nb, bs = self._alloc.num_blocks, self.block_size
         maxb = self._maxb
         ck = ("pg_splice", cfg, self.smax, nb, bs, self._kv_dtype,
-              self.mesh, _tree_key(self.params))
+              self.mesh, self._walloc and self._walloc.num_blocks,
+              _tree_key(self.params))
 
         def build():
             pool_sh, scale_sh = self._pool_sh, self._scale_sh
 
-            def splice(pools, scales, one, wrow):
+            def splice(pools, scales, one, wrows):
                 outp, outs = [], []
                 for i, ((kp, vp), (kc, vc)) in enumerate(
                         zip(pools, one)):
+                    wrow = wrows[1 if cfg.window(i) else 0]
                     kseg = kc[0].reshape(maxb, bs, *kc.shape[2:])
                     vseg = vc[0].reshape(maxb, bs, *vc.shape[2:])
                     if scales is None:
@@ -1615,9 +1522,12 @@ class ContinuousServer:
             pool_sh, scale_sh = self._pool_sh, self._scale_sh
 
             def copy(pools, scales, src, dst):
-                pools = [(kp.at[dst].set(kp[src]),
-                          vp.at[dst].set(vp[src]))
-                         for kp, vp in pools]
+                # full-group ids: a window layer's pools are another
+                # group's (nothing shares its blocks, none is forked)
+                pools = [(kp, vp) if self.cfg.window(i)
+                         else (kp.at[dst].set(kp[src]),
+                               vp.at[dst].set(vp[src]))
+                         for i, (kp, vp) in enumerate(pools)]
                 if scales is not None:
                     scales = [(ks.at[dst].set(ks[src]),
                                vs.at[dst].set(vs[src]))
@@ -1740,7 +1650,7 @@ class ContinuousServer:
             return jax.jit(jax.shard_map(
                 verify, mesh=self.mesh,
                 in_specs=(pspecs, pool_sp, scale_sp, P("dp", None),
-                          P("dp"), P("dp", None), P("dp"), P("dp"),
+                          P("dp"), (P("dp", None),), P("dp"), P("dp"),
                           P("dp", None)),
                 out_specs=(pool_sp, scale_sp, P("dp", None), P())),
                 donate_argnums=(1, 2))
@@ -1835,6 +1745,18 @@ class ContinuousServer:
         while pt.capacity <= pos:
             pt.append_block(self._alloc_block())
         self._cow_guard(pt, pos // self.block_size)
+        wt = self._wtables[slot]
+        if wt is not None:
+            while wt.capacity <= pos:
+                wt.append_block(self._walloc.alloc())
+            freed = wt.free_behind(pos)
+            if freed:
+                with tracing.span("serving.window_free", "serving",
+                                  rid=self._slot_req[slot].rid,
+                                  blocks=len(freed)):
+                    self._win_freed += len(freed)
+                    for bid in freed:
+                        self._walloc.decref(bid)
 
     def _ensure_window(self, slot: int, pos0: int, last: int) -> None:
         """`_ensure_block` generalized to a speculative verify window:
@@ -1860,12 +1782,15 @@ class ContinuousServer:
         table_residency` (slot rows over dp by default) via
         cache.page_table.device_table; ids stay GLOBAL either way."""
         sig = tuple((pt.uid, pt.version) if pt is not None else None
-                    for pt in self._tables)
+                    for pt in self._tables + self._wtables)
         if sig != self._tables_sig or self._tables_arr is None:
             from ..cache.page_table import device_table
-            self._tables_arr = device_table(
+            self._tables_arr = (device_table(
                 self._tables, self._maxb, self._trash, mesh=self.mesh,
-                residency=self._table_residency)
+                residency=self._table_residency),)
+            if self._win:
+                self._tables_arr += (jnp.asarray(materialize(
+                    self._wtables, self._ring, self._wtrash)),)
             self._tables_sig = sig
         return self._tables_arr
 
@@ -1877,7 +1802,9 @@ class ContinuousServer:
         pt = self._tables[slot]
         if pt is None:
             return
-        if self._prefix_reuse:
+        self._free_window(self._wtables[slot])
+        self._wtables[slot] = None
+        if self._prefix_reuse and not self._win:
             nfull = len(req.prompt) // self.block_size
             if nfull:
                 self._radix.insert(
@@ -1886,6 +1813,13 @@ class ContinuousServer:
         for bid in pt.blocks:
             self._alloc.decref(bid)
         self._tables[slot] = None
+
+    def _free_window(self, wt: Optional[WindowTable]) -> None:
+        """Drop a request's references in the window group."""
+        if wt is not None:
+            for bid in wt.blocks:
+                self._walloc.decref(bid)
+            wt.blocks = []
 
     # -- host tier (cache/tier.py): demotion + gated promotion -----------
 
@@ -2006,6 +1940,12 @@ class ContinuousServer:
             st.update(self._tier.stats())
         st["prefill_tokens_saved"] = self._prefill_saved
         st["prefill_tokens_computed"] = self._prefill_computed
+        if self._win:
+            # the window block group, beside the full group's fields
+            st["window_num_blocks"] = self._walloc.num_blocks
+            st["window_in_use"] = self._walloc.in_use
+            st["window_blocks_freed"] = self._win_freed
+            st["window_prefix_refused"] = self._prefix_refused
         st.update(self.hbm_read_stats())
         if self.mesh is not None:
             # per-dp-shard slot accounting: slots map to dp shards by
@@ -2075,6 +2015,16 @@ class ContinuousServer:
             # what `auto` resolved to: gather | fused | fused_online
             "paged_kernel": self._paged_kernel,
         }
+
+    def moe_stats(self) -> Dict[str, float]:
+        """The sparse FFN's routing, summed over the decode steps whose
+        statistics a flush has drained: claims routed and dropped, the
+        steps, and `experts_hit_sum` (per step the distinct experts
+        hit, mean over the sparse layers) — the feed of the
+        /serving{...}/moe/* counters."""
+        return {"routed": self._moe_routed, "dropped": self._moe_dropped,
+                "steps": self._moe_steps,
+                "experts_hit_sum": self._moe_hit_sum}
 
     def spec_stats(self) -> Dict[str, float]:
         """Speculation observability snapshot (the same numbers the
@@ -2170,6 +2120,11 @@ class ContinuousServer:
             raise ValueError(
                 "admit_prefilled() requires paged=True (the transfer "
                 "protocol ships block-granular KV)")
+        if self._win:
+            raise NotImplementedError(
+                "admit_prefilled() on a model with window layers: the "
+                "transfer protocol (cache/transfer.KVSegment) ships one "
+                "block map a request, the window group needs its own")
         if self._closed:
             raise ServerClosedError()
         prompt = [int(t) for t in prompt]
@@ -2267,6 +2222,13 @@ class ContinuousServer:
                 return w
         return self.prefill_buckets[-1]
 
+    def _fresh_scratch(self):
+        """An empty b=1 prefill scratch: (k, v) [1, smax] a layer."""
+        shape = (1, self.smax, self.cfg.kv_heads, self.cfg.head_dim)
+        return [(jnp.zeros(shape, self.cfg.dtype),
+                 jnp.zeros(shape, self.cfg.dtype))
+                for _ in range(self.cfg.n_layers)]
+
     def _start_prefill(self, req: "_Request",
                        slot: int) -> _PendingPrefill:
         """Reserve `slot` and stand up the b=1 scratch cache (paged:
@@ -2276,13 +2238,8 @@ class ContinuousServer:
         if self.paged:
             p = self._start_paged(req, slot)
         else:
-            nkv, hd = self.cfg.kv_heads, self.cfg.head_dim
-
-            def z():
-                return jnp.zeros((1, self.smax, nkv, hd),
-                                 self.cfg.dtype)
-            scratch = [(z(), z()) for _ in range(self.cfg.n_layers)]
-            p = _PendingPrefill(req=req, slot=slot, caches=scratch,
+            p = _PendingPrefill(req=req, slot=slot,
+                                caches=self._fresh_scratch(),
                                 done=0, seq=self._pf_seq)
         self._pending[slot] = p
         self._admit_defers.pop(req.rid, None)   # admitted: ladder done
@@ -2292,7 +2249,12 @@ class ContinuousServer:
                      slot: int) -> _PendingPrefill:
         plen = len(req.prompt)
         matched, mbids, tier_ext = 0, [], []
-        if self._prefix_reuse:
+        if self._prefix_reuse and self._win:
+            # a prefix hit would hand the full layers their rows and
+            # leave the window layers without the matched prefix's last
+            # window: refused (and counted) until the tree keeps it
+            self._prefix_refused += 1
+        elif self._prefix_reuse:
             # always leave >= 1 suffix token: admission needs the LAST
             # prompt token's logits to seed generation
             if self._tier is not None:
@@ -2326,12 +2288,32 @@ class ContinuousServer:
         # splice never rewrites them (see _paged_splice_prog)
         wnp = row.copy()
         wnp[:matched // self.block_size] = self._trash
-        wrow = jnp.asarray(wnp)
-        caches = self._paged_gather_prog()(self._pools, self._scales,
-                                           trow, jnp.int32(matched))
-        return _PendingPrefill(req=req, slot=slot, caches=caches,
-                               done=matched, seq=self._pf_seq, pt=pt,
-                               trow=trow, wrow=wrow)
+        wrow = (jnp.asarray(wnp),)
+        if not self._win:
+            caches = self._paged_gather_prog()(
+                self._pools, self._scales, trow, jnp.int32(matched))
+            return _PendingPrefill(req=req, slot=slot, caches=caches,
+                                   done=matched, seq=self._pf_seq, pt=pt,
+                                   trow=trow, wrow=wrow)
+        # window group: the blocks the FIRST decode step (at plen) can
+        # still see; the splice writes the scratch's rows of exactly
+        # those (nothing matched, so the scratch starts empty)
+        wt = WindowTable(self.block_size, self._win)
+        wt.base = wt.first_needed(plen)
+        try:
+            while wt.capacity < plen:
+                wt.append_block(self._walloc.alloc())
+        except CacheOOM:
+            self._free_window(wt)
+            for bid in pt.blocks:
+                self._alloc.decref(bid)
+            raise
+        wrow += (jnp.asarray(wt.as_linear_row(self._maxb,
+                                              self._wtrash)),)
+        return _PendingPrefill(req=req, slot=slot,
+                               caches=self._fresh_scratch(),
+                               done=0, seq=self._pf_seq, pt=pt,
+                               trow=trow, wrow=wrow, wt=wt)
 
     def _advance_chunk(self, p: _PendingPrefill) -> None:
         """Run ONE bucketed chunk of p's prompt into its scratch.
@@ -2377,7 +2359,7 @@ class ContinuousServer:
         if self.paged:
             self._pools, self._scales = self._paged_splice_prog()(
                 self._pools, self._scales, caches, p.wrow)
-            self._tables[slot] = p.pt
+            self._tables[slot], self._wtables[slot] = p.pt, p.wt
         else:
             self._caches = self._splice_prog()(
                 self._caches, caches, jnp.asarray(slot, jnp.int32))
@@ -2495,7 +2477,7 @@ class ContinuousServer:
             raise
         pt.tokens = plen
         self._admit_defers.pop(req.rid, None)
-        trow = jnp.asarray(pt.as_row(self._maxb, self._trash))
+        trow = (jnp.asarray(pt.as_row(self._maxb, self._trash)),)
         nkv, hd = self.cfg.kv_heads, self.cfg.head_dim
         rows = req.xfer_rows
         scratch = []
@@ -2776,20 +2758,28 @@ class ContinuousServer:
             pins = list(pt.blocks[:pos // self.block_size])
             for bid in pins:
                 self._alloc.incref(bid)
-        old = self._ckpt.get(slot)
+        wpins = None
+        wt = self._wtables[slot] if self.paged else None
+        if wt is not None:
+            wpins = (wt.base, list(wt.blocks))
+            for bid in wpins[1]:
+                self._walloc.incref(bid)
+        old = self._ckpt.pop(slot, None)
         self._ckpt[slot] = SlotCheckpoint(
             rid=req.rid, tokens=list(req.tokens), pos=pos,
             cur=self._cur[slot], slot_k=self._slot_k[slot],
-            slot_acc=self._slot_acc[slot], pins=pins)
-        if old is not None:
-            for bid in old.pins:
-                self._alloc.decref(bid)
+            slot_acc=self._slot_acc[slot], pins=pins, wpins=wpins)
+        self._unpin(old)
 
-    def _drop_ckpt(self, slot: int) -> None:
-        ck = self._ckpt.pop(slot, None)
+    def _unpin(self, ck: Optional[SlotCheckpoint]) -> None:
         if ck is not None:
             for bid in ck.pins:
                 self._alloc.decref(bid)
+            for bid in (ck.wpins or (0, ()))[1]:
+                self._walloc.decref(bid)
+
+    def _drop_ckpt(self, slot: int) -> None:
+        self._unpin(self._ckpt.pop(slot, None))
 
     def _ckpt_sweep(self) -> None:
         """Advance checkpoints at a flush boundary: every live slot
@@ -2846,6 +2836,15 @@ class ContinuousServer:
                     for bid in pt.blocks:     # bids must not hit 0
                         self._alloc.decref(bid)
                 self._tables[slot] = npt
+                if ck.wpins is not None:
+                    # the window group as it stood at capture: rows
+                    # past ck.pos in its blocks are rewritten by the
+                    # replay before any query sees them
+                    for bid in ck.wpins[1]:
+                        self._walloc.incref(bid)
+                    self._free_window(self._wtables[slot])
+                    self._wtables[slot] = WindowTable(
+                        self.block_size, self._win, *ck.wpins)
             else:
                 self._reprefill_dense(slot, req.prompt
                                       + req.tokens[:-1])
@@ -2859,11 +2858,7 @@ class ContinuousServer:
         [0, len(seq)) by re-running bucketed prefill over the known
         token sequence into a fresh b=1 scratch, then splice. No
         probe: the checkpoint already knows the feedback token."""
-        nkv, hd = self.cfg.kv_heads, self.cfg.head_dim
-
-        def z():
-            return jnp.zeros((1, self.smax, nkv, hd), self.cfg.dtype)
-        scratch = [(z(), z()) for _ in range(self.cfg.n_layers)]
+        scratch = self._fresh_scratch()
         done = 0
         while done < len(seq):
             n = min(self.prefill_chunk, len(seq) - done)
@@ -2888,6 +2883,8 @@ class ContinuousServer:
             for bid in p.pt.blocks:
                 self._alloc.decref(bid)
             p.pt = None
+        self._free_window(p.wt)
+        p.wt = None
         return p
 
     def _restart_pending(self, slot: int) -> None:
@@ -3101,10 +3098,16 @@ class ContinuousServer:
             # one small [2+E] vector per dispatched step, read here so
             # the async window never gains an extra host sync
             while self._moe_buf:
-                ms = np.asarray(self._moe_buf.popleft())
+                with tracing.span("serving.flush.moe_stats.wait",
+                                  "serving"):
+                    ms = np.asarray(self._moe_buf.popleft())
                 self._moe_routed += float(ms[0])
                 self._moe_dropped += float(ms[1])
                 self._moe_occ = [float(v) for v in ms[2:]]
+                # drop-free steps report 1.0 for an expert that was
+                # hit (moe_ffn_serve), averaged over the sparse layers
+                self._moe_hit_sum += float(ms[2:].sum())
+                self._moe_steps += 1
             self._ckpt_sweep()
             self._reload_knobs()
             # SLO burn evaluation shares the tuner's boundary: no step

@@ -34,7 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import auto_attention, ring_attention_sharded
 
-__all__ = ["TransformerConfig", "init_params", "make_train_step",
+__all__ = ["RopeSpec", "TransformerConfig", "init_params", "make_train_step",
            "make_mesh_3d", "shard_params", "shard_batch", "sample_batch",
            "make_opt_state", "generate", "make_pipelined_train_step",
            "stack_pipeline_params", "shard_pipeline_params",
@@ -42,6 +42,46 @@ __all__ = ["TransformerConfig", "init_params", "make_train_step",
            "speculative_generate", "speculative_sample",
            "deinterleave_pipeline_params", "prepare_pipeline_params",
            "beam_search"]
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """One layer kind's rotary embedding (rotate-half). `rotary_dim`
+    0 rotates the whole head, else the head's first `rotary_dim` dims.
+    `factor` > 1 is YaRN as Hugging Face's `rope_type: "yarn"` computes
+    it: inverse frequencies blended between theta-spaced and those /
+    factor by the linear ramp between the two correction dims of
+    (beta_fast, beta_slow) over `original_max`, and cos/sin multiplied
+    by `attention_factor`."""
+    theta: float = 10000.0
+    rotary_dim: int = 0
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, rot: int):
+        """[rot // 2] float32 inverse frequencies."""
+        half = rot // 2
+        if self.factor == 1.0:
+            return self.theta ** (-jnp.arange(0, half, dtype=jnp.float32)
+                                  / half)
+        import numpy as np
+        pos_freqs = self.theta ** (np.arange(0, rot, 2, dtype=np.float64)
+                                   / rot)
+
+        def corr(n_rot):
+            return (rot * math.log(self.original_max
+                                   / (n_rot * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+        low = max(math.floor(corr(self.beta_fast)), 0)
+        high = min(math.ceil(corr(self.beta_slow)), rot - 1)
+        ramp = np.clip((np.arange(half) - low)
+                       / ((high - low) or 0.001), 0, 1)
+        inv = (1 / (self.factor * pos_freqs) * ramp
+               + 1 / pos_freqs * (1 - ramp))
+        return jnp.asarray(inv, jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,10 +130,58 @@ class TransformerConfig:
     # positions stay GLOBAL so weights are layout-independent — decode
     # and checkpoints are unaffected.
     striped_ring: bool = False
+    # -- what describes a LAYER: read by `_layer`, the one definition
+    # every forward body takes. The defaults are the block this file
+    # always built (LayerNorm, tanh-GELU with a first bias, tied head).
+    norm: str = "layernorm"         # | "rmsnorm" (no mean, no bias)
+    norm_eps: float = 1e-5
+    mlp: str = "gelu"               # | "swiglu": w2(silu(w1 h) * w3 h)
+    tied: bool = True               # False: params["head"] [V, D]
+    attn_gate: bool = False         # o_h *= sigmoid(h @ wgate)[h]
+    # per-layer tuples; empty = every layer as the scalars say
+    layer_heads: Tuple[int, ...] = ()    # q heads of layer i
+    layer_window: Tuple[int, ...] = ()   # 0 full; W: i - W < j <= i
+    layer_rope: Tuple[Any, ...] = ()     # RopeSpec (or None) of layer i
+    layer_sparse: Tuple[bool, ...] = ()  # MoE FFN on layer i
+    # the experts as served drop-free (moe.moe_ffn_serve)
+    moe_d_ff: int = 0               # expert width; 0 = d_ff
+    moe_shared_d_ff: int = 0        # one shared expert of this width
+    moe_router: str = "softmax"     # | "sigmoid"
+    moe_renorm: bool = False        # weights / their sum over the top k
+    moe_scale: float = 1.0
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    def heads(self, i: int) -> int:
+        return self.layer_heads[i] if self.layer_heads else self.n_heads
+
+    def window(self, i: int) -> int:
+        return self.layer_window[i] if self.layer_window else 0
+
+    def rope_of(self, i: int) -> Optional[RopeSpec]:
+        if self.layer_rope:
+            return self.layer_rope[i]
+        return RopeSpec(self.rope_theta) if self.rope else None
+
+    def sparse(self, i: int) -> bool:
+        if self.layer_sparse:
+            return bool(self.layer_sparse[i])
+        return self.n_experts > 0
+
+    def only(self, body: str, module: str, *allowed: str) -> None:
+        """Refuse, by mechanism and module, a model whose layers `body`
+        cannot compute: every layer-describing field outside `allowed`
+        must be at its default."""
+        for f in ("norm", "mlp", "tied", "attn_gate", "layer_heads",
+                  "layer_window", "layer_rope", "layer_sparse",
+                  "moe_shared_d_ff", "moe_router", "moe_renorm"):
+            if f not in allowed and getattr(self, f) != getattr(
+                    TransformerConfig, f):
+                raise NotImplementedError(
+                    f"{body} ({module}) cannot compute this model: "
+                    f"`{f}` = {getattr(self, f)!r} has no path there")
 
 
 def make_mesh_3d(n_devices: int, devices=None):
@@ -122,20 +210,23 @@ def _moe_cfg(cfg: TransformerConfig):
     from .moe import MoeConfig
     return MoeConfig(n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
                      capacity_factor=cfg.moe_capacity,
-                     d_model=cfg.d_model, d_ff=cfg.d_ff,
-                     dtype=cfg.dtype)
+                     d_model=cfg.d_model, d_ff=cfg.moe_d_ff or cfg.d_ff,
+                     dtype=cfg.dtype, mlp=cfg.mlp,
+                     router=cfg.moe_router, renorm=cfg.moe_renorm,
+                     scale=cfg.moe_scale,
+                     shared_d_ff=cfg.moe_shared_d_ff)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     """Weight pytree. tp-sharded leaves carry their FULL logical shape
     here; shard_params() places them."""
-    d, nh, hd, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
+    d, hd, f = cfg.d_model, cfg.head_dim, cfg.d_ff
     keys = jax.random.split(key, 2 + cfg.n_layers)
     s = 1.0 / math.sqrt(d)
 
-    def layer(k):
+    def layer(k, i):
         k1, k2, k3, k4 = jax.random.split(k, 4)
-        nkv = cfg.kv_heads
+        nh, nkv = cfg.heads(i), cfg.kv_heads
         if nh % nkv:
             raise ValueError(f"n_heads={nh} not a multiple of "
                              f"n_kv_heads={nkv}")
@@ -155,30 +246,42 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
                    ).astype(cfg.dtype),
             "ln2": jnp.ones((d,), cfg.dtype),
         }
-        if cfg.n_experts > 0:
+        if cfg.attn_gate:
+            out["wgate"] = (jax.random.normal(
+                jax.random.fold_in(k2, 1), (d, nh)) * s).astype(cfg.dtype)
+        if cfg.sparse(i):
             from .moe import init_moe_params
             out["moe"] = init_moe_params(_moe_cfg(cfg), k3)
+            return out
+        out.update({
+            "w1": (jax.random.normal(k3, (d, f)) * s).astype(cfg.dtype),
+            "w2": (jax.random.normal(k4, (f, d)) / math.sqrt(f)
+                   ).astype(cfg.dtype),
+        })
+        if cfg.mlp == "swiglu":
+            out["w3"] = (jax.random.normal(
+                jax.random.fold_in(k3, 1), (d, f)) * s).astype(cfg.dtype)
         else:
-            out.update({
-                "w1": (jax.random.normal(k3, (d, f)) * s
-                       ).astype(cfg.dtype),
-                "b1": jnp.zeros((f,), cfg.dtype),
-                "w2": (jax.random.normal(k4, (f, d)) / math.sqrt(f)
-                       ).astype(cfg.dtype),
-            })
+            out["b1"] = jnp.zeros((f,), cfg.dtype)
         return out
 
-    return {
+    params = {
         "emb": (jax.random.normal(keys[0], (cfg.vocab, d)) * s
                 ).astype(cfg.dtype),
         "ln_f": jnp.ones((d,), cfg.dtype),
-        "layers": [layer(keys[2 + i]) for i in range(cfg.n_layers)],
+        "layers": [layer(keys[2 + i], i) for i in range(cfg.n_layers)],
     }
+    if not cfg.tied:
+        params["head"] = (jax.random.normal(keys[1], (cfg.vocab, d)) * s
+                          ).astype(cfg.dtype)
+    return params
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpecs: heads/ffn over tp; MoE experts over dp (the ep
     layout — see TransformerConfig); everything else replicated."""
+    cfg.only("param_specs: the (dp, sp, tp) placement",
+             "models/transformer.py")
     if cfg.kv_heads == cfg.n_heads:
         qkv = {"wqkv": P(None, None, "tp", None)}
     else:
@@ -233,6 +336,16 @@ def _ln(x, scale):
     return (x - mu) * jax.lax.rsqrt(var + 1e-5) * scale
 
 
+def _norm(x, scale, cfg: TransformerConfig):
+    """The model's norm: LayerNorm (scale, no bias) or RMSNorm."""
+    if cfg.norm == "rmsnorm":
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return (xf * jax.lax.rsqrt(ms + cfg.norm_eps)).astype(x.dtype) \
+            * scale
+    return _ln(x, scale)
+
+
 def _dq(w, like):
     """Dequantize int8 serving weights at use (models/quant.QTensor);
     dense weights pass through untouched."""
@@ -251,55 +364,131 @@ def _qkv_proj(h, lp):
     return q, k, v
 
 
-def _rope(x, pos, cfg: TransformerConfig):
-    """Rotate q/k by position (GPT-NeoX rotate-half). x: [B, S, N, H]
-    (or S=1 decode); pos: [S] int positions (global under sp)."""
+def _rope(x, pos, spec: RopeSpec):
+    """Rotate q/k by position (GPT-NeoX rotate-half). x: [B, S, N, H];
+    pos: [S] positions shared by the batch (global under sp), or a
+    [B, S] grid where every (row, column) sits at its own."""
     hd = x.shape[-1]
-    if hd % 2:
-        raise ValueError(f"rope needs an even head_dim; got {hd}")
-    half = hd // 2
-    freq = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32)
-                              / half)
-    ang = pos.astype(jnp.float32)[:, None] * freq[None, :]   # [S, half]
-    cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x1 * sin + x2 * cos], axis=-1)
+    rot = spec.rotary_dim or hd
+    if rot % 2:
+        raise ValueError(f"rope needs an even head_dim (or rotary_dim); "
+                         f"got {rot}")
+    half = rot // 2
+    ang = pos.astype(jnp.float32)[..., None] * spec.inv_freq(rot)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if spec.attention_factor != 1.0:
+        cos, sin = cos * spec.attention_factor, sin * spec.attention_factor
+    if pos.ndim == 1:
+        cos, sin = cos[None], sin[None]
+    cos = cos[:, :, None, :].astype(x.dtype)
+    sin = sin[:, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:rot]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+                           + ([x[..., rot:]] if rot < hd else []),
+                           axis=-1)
+
+
+def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
+           tp_axis: Optional[str] = None, moe=None):
+    """THE decoder layer, the one definition every forward body takes:
+    norm, mixer, residual, norm, FFN, residual. What differs between
+    the bodies is where K/V live, and that is `attend(q, k, v) ->
+    (att, carry)`: the body's own cache write and attention read
+    (dense cache, paged pools, the sp ring); `carry` (its new cache
+    state) is handed back beside x. `pos`: the positions of x's
+    columns, [S] or [B, S]. `moe(h) -> out` is the body's sparse FFN
+    (it closes its own collectives). What differs between LAYERS is in
+    `cfg` (norm, per-layer rope, window via `attend`) and in the
+    parameters themselves: head counts are read off the arrays, a
+    "wgate" gates the heads, a "w3" makes the MLP SiLU-gated, a "moe"
+    makes it sparse."""
+    h = _norm(x, lp["ln1"], cfg)
+    q, k, v = _qkv_proj(h, lp)
+    rope = cfg.rope_of(li)
+    if rope is not None:
+        q, k = _rope(q, pos, rope), _rope(k, pos, rope)
+    att, carry = attend(q, k, v)
+    if "wgate" in lp:
+        gate = jax.nn.sigmoid(jnp.einsum("bsd,dn->bsn", h,
+                                         _dq(lp["wgate"], h)))
+        att = att * gate[..., None]
+    o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
+    if tp_axis:
+        o = jax.lax.psum(o, tp_axis)       # Megatron row-parallel close
+    x = x + o
+    h = _norm(x, lp["ln2"], cfg)
+    if "moe" in lp:
+        return x + moe(h), carry
+    if "w3" in lp:
+        h = (jax.nn.silu(h @ _dq(lp["w1"], h)) * (h @ _dq(lp["w3"], h))
+             ) @ _dq(lp["w2"], h)
+    else:
+        h = jax.nn.gelu(h @ _dq(lp["w1"], h) + lp["b1"]) \
+            @ _dq(lp["w2"], h)
+    if tp_axis:
+        h = jax.lax.psum(h, tp_axis)
+    return x + h, carry
+
+
+def _cached_attention(q, kc, vc, qpos, window: int = 0):
+    """Attention of q [B, Q, Nq, H] over dense caches kc/vc [B, S, Nkv,
+    H] (this step's rows already written): the query at position
+    qpos[.., i] ([Q], or [B, Q] per row) sees cache positions <= it,
+    and on a window layer > it - window. GQA by the grouped reshape;
+    masked scores are -inf, softmax in f32."""
+    b, sq, nq, hd = q.shape
+    nkv = kc.shape[2]
+    qg = q.reshape(b, sq, nkv, nq // nkv, hd)
+    s = jnp.einsum("bqngh,bknh->bngqk", qg, kc) / math.sqrt(hd)
+    kpos = jnp.arange(kc.shape[1])
+    live = kpos <= qpos[..., None]                     # [(B,) Q, S]
+    if window:
+        live = jnp.logical_and(live, kpos > qpos[..., None] - window)
+    live = live[None] if live.ndim == 2 else live
+    s = jnp.where(live[:, None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(b, sq, nq, hd)
+
+
+def _logits(params, x, cfg: TransformerConfig):
+    """Final norm and the head (the embedding's transpose when tied)."""
+    x = _norm(x, params["ln_f"], cfg)
+    return jnp.einsum("bsd,vd->bsv", x, params.get("head", params["emb"]))
 
 
 def _block(x, lp, cfg: TransformerConfig, sp_size: int, dp_size: int):
     """One decoder block on a [B/dp, S/sp, D] shard; heads already
     tp-local. The Megatron f/g conjugate pair is implicit: with vma
     tracking on, jax transposes the closing psums and reduces the
-    mixed replicated/partial cotangents itself. Returns (x, moe_aux)."""
-    h = _ln(x, lp["ln1"])
-    q, k, v = _qkv_proj(h, lp)
+    mixed replicated/partial cotangents itself. Returns (x, moe_aux).
+    `_layer` with the sp ring as its mixer and the capacity MoE."""
+    pos = None
     if cfg.rope:
         # GLOBAL positions from THE layout definition the ring uses
         from ..ops.attention import ring_positions
         pos = ring_positions(jax.lax.axis_index("sp"), sp_size,
-                             q.shape[1], cfg.striped_ring)
-        q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
-    # GQA layouts pass straight through: ring_attention_sharded
-    # broadcasts grouped K/V itself on the paths that need it
-    att = ring_attention_sharded(q, k, v, "sp", sp_size, causal=True,
-                                 striped=cfg.striped_ring)
-    o = jnp.einsum("bsnh,nhd->bsd", att, lp["wo"])
-    o = jax.lax.psum(o, "tp")              # Megatron row-parallel close
-    x = x + o
-    h = _ln(x, lp["ln2"])
-    if "moe" in lp:
+                             x.shape[1], cfg.striped_ring)
+
+    def attend(q, k, v):
+        # GQA layouts pass straight through: ring_attention_sharded
+        # broadcasts grouped K/V itself on the paths that need it
+        return ring_attention_sharded(
+            q, k, v, "sp", sp_size, causal=True,
+            striped=cfg.striped_ring), None
+
+    aux = [jnp.float32(0.0)]
+
+    def moe(h):
         from .moe import moe_ffn
-        b, s, d = x.shape
-        h, aux = moe_ffn(h.reshape(b * s, d), lp["moe"], _moe_cfg(cfg),
-                         axis="dp", axis_size=dp_size)
-        h = jax.lax.psum(h, "tp")      # experts' d_ff is tp-sharded
-        return x + h.reshape(b, s, d), aux
-    h = jax.nn.gelu(h @ lp["w1"] + lp["b1"])
-    h = h @ lp["w2"]
-    h = jax.lax.psum(h, "tp")
-    return x + h, jnp.float32(0.0)
+        b, s, d = h.shape
+        out, aux[0] = moe_ffn(h.reshape(b * s, d), lp["moe"],
+                              _moe_cfg(cfg), axis="dp",
+                              axis_size=dp_size)
+        # experts' d_ff is tp-sharded
+        return jax.lax.psum(out, "tp").reshape(b, s, d)
+
+    x, _ = _layer(x, lp, cfg, 0, pos, attend, "tp", moe)
+    return x, aux[0]
 
 
 def _nll_head(params, x, targets):
@@ -357,6 +546,8 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer: Any = None):
     the step (its sharding follows the params') — see
     make_opt_state().
     """
+    cfg.only("make_train_step: the sp ring and the capacity MoE",
+             "models/transformer.py")
     sp_size = mesh.shape["sp"]
     dp_size = mesh.shape["dp"]
     tp_size = mesh.shape["tp"]
@@ -553,23 +744,11 @@ def _pp_block(x, lp, cfg: TransformerConfig, tp_axis: Optional[str]):
     """One decoder block on a [mb, S, D] microbatch shard inside the
     pipeline: attention is sequence-LOCAL (auto_attention — flash on
     TPU; the sp ring belongs to the dp x sp x tp step), heads/ffn
-    tp-sharded when a tp axis exists."""
-    h = _ln(x, lp["ln1"])
-    q, k, v = _qkv_proj(h, lp)
-    if cfg.rope:
-        pos = jnp.arange(q.shape[1])    # sequence is pp-local in full
-        q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
-    att = auto_attention(q, k, v, causal=True)
-    o = jnp.einsum("bsnh,nhd->bsd", att, lp["wo"])
-    if tp_axis:
-        o = jax.lax.psum(o, tp_axis)
-    x = x + o
-    h = _ln(x, lp["ln2"])
-    h = jax.nn.gelu(h @ lp["w1"] + lp["b1"])
-    h = h @ lp["w2"]
-    if tp_axis:
-        h = jax.lax.psum(h, tp_axis)
-    return x + h
+    tp-sharded when a tp axis exists. `_layer` with flash as mixer."""
+    return _layer(
+        x, lp, cfg, 0, jnp.arange(x.shape[1]),   # sequence is pp-local
+        lambda q, k, v: (auto_attention(q, k, v, causal=True), None),
+        tp_axis)[0]
 
 
 def _pipelined_opt_state_specs(cfg: TransformerConfig, optimizer: Any,
@@ -636,6 +815,8 @@ def make_pipelined_train_step(cfg: TransformerConfig, mesh,
     come back in that layout; invert with
     deinterleave_pipeline_params).
     """
+    cfg.only("make_pipelined_train_step: stacked identical layers",
+             "models/transformer.py")
     if cfg.striped_ring:
         raise NotImplementedError(
             "striped_ring is wired for make_train_step's sp ring; the "
@@ -765,71 +946,52 @@ def make_pipelined_train_step(cfg: TransformerConfig, mesh,
 
 def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
                   tp_axis: Optional[str] = None,
-                  ep_axis: Optional[str] = None, ep_size: int = 1):
-    """One decoder block for a single new token position with a KV
-    cache. x: [B, 1, D]; kv: (k_cache, v_cache) each [B, Smax, N, H]
-    (N = the tp-LOCAL head count under sharded decode); write_at:
-    scalar index. With tp_axis set, the wo/w2 contractions close with
-    a psum — the same Megatron split the train step uses, so the KV
-    cache shards over heads and never replicates. GQA: the cache holds
-    only the kv heads ([B, Smax, Nkv, H] — the n_heads/n_kv_heads
-    serving-memory saving); q heads attend grouped."""
-    kc, vc = kv
-    h = _ln(x, lp["ln1"])
-    q, k, v = _qkv_proj(h, lp)
-    sq = x.shape[1]
-    if cfg.rope:
-        # rotate at the write positions; the cache stores POST-rope k,
-        # so cached entries never need re-rotation. sq > 1 is the
-        # WINDOW decode (speculative verification / chunked prefill):
-        # token i of the window sits at write_at + i.
-        pos = jnp.asarray(write_at) + jnp.arange(sq)
-        q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
-    kc = jax.lax.dynamic_update_slice_in_dim(kc, k, write_at, axis=1)
-    vc = jax.lax.dynamic_update_slice_in_dim(vc, v, write_at, axis=1)
-    b, sq, nq, hd = q.shape
-    nkv = kc.shape[2]
-    g = nq // nkv
-    qg = q.reshape(b, sq, nkv, g, hd)
-    s = jnp.einsum("bqngh,bknh->bngqk", qg, kc) / math.sqrt(hd)
-    pos = jnp.arange(kc.shape[1])
-    # per-query causal horizon: window token i attends cache positions
-    # <= write_at + i (collapses to the old scalar mask at sq == 1)
-    qpos = jnp.asarray(write_at) + jnp.arange(sq)
-    s = jnp.where(pos[None, None, None, None, :]
-                  <= qpos[None, None, None, :, None], s, -jnp.inf)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-    att = jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(b, sq, nq, hd)
-    o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
-    if tp_axis:
-        o = jax.lax.psum(o, tp_axis)
-    x = x + o
-    h = _ln(x, lp["ln2"])
-    if "moe" in lp:
-        from .moe import moe_ffn, moe_ffn_decode
-        b, s, d = h.shape
-        # decode routes DROP-FREE (capacity_factor = n_experts makes
-        # C >= every possible claim): with no drops, each token's output
+                  ep_axis: Optional[str] = None, ep_size: int = 1,
+                  li: int = 0):
+    """Layer `li` for a WINDOW of new token positions with a dense KV
+    cache: `_layer` with the cache as its mixer. x: [B, W, D] (W = 1
+    plain decode; W > 1 speculative verification / chunked prefill:
+    token i of the window sits at write_at + i); kv: (k_cache,
+    v_cache) each [B, Smax, N, H] (N = the tp-LOCAL head count under
+    sharded decode; GQA caches hold only the kv heads); write_at:
+    scalar index. The cache stores POST-rope k, so cached entries never
+    need re-rotation. With tp_axis set, the wo/w2 contractions close
+    with a psum, so the KV cache shards over heads and never
+    replicates. A window layer masks what lies behind its window; the
+    dense cache keeps every row all the same."""
+    qpos = jnp.asarray(write_at) + jnp.arange(x.shape[1])
+
+    def attend(q, k, v):
+        kc = jax.lax.dynamic_update_slice_in_dim(kv[0], k, write_at,
+                                                 axis=1)
+        vc = jax.lax.dynamic_update_slice_in_dim(kv[1], v, write_at,
+                                                 axis=1)
+        return _cached_attention(q, kc, vc, qpos, cfg.window(li)), \
+            (kc, vc)
+
+    def moe(h):
+        # serving routes DROP-FREE: with no drops, each token's output
         # is independent of the rest of the batch — generating a prompt
         # alone or inside a batch yields identical tokens, and the
         # serving path never silently zeroes a token the way
         # capacity-limited training legitimately does
-        mcfg = dataclasses.replace(_moe_cfg(cfg),
-                                   capacity_factor=float(cfg.n_experts))
+        from .moe import moe_ffn_decode, moe_ffn_serve
+        b, s, d = h.shape
         if ep_axis is not None:
             # expert-parallel decode: experts shard over ep_axis; the
             # replicated token block splits across it and the outputs
-            # close with a psum (moe_ffn_decode) — the expert-axis
-            # analogue of the dense branch's row-parallel tp psum
-            out, _aux, _stats = moe_ffn_decode(
-                h.reshape(b * s, d), lp["moe"], mcfg, ep_axis, ep_size)
+            # close with a psum (moe_ffn_decode) — capacity dispatch
+            # made drop-free by capacity_factor = n_experts
+            mcfg = dataclasses.replace(
+                _moe_cfg(cfg), capacity_factor=float(cfg.n_experts))
+            out = moe_ffn_decode(h.reshape(b * s, d), lp["moe"], mcfg,
+                                 ep_axis, ep_size)[0]
         else:
-            out, _aux = moe_ffn(h.reshape(b * s, d), lp["moe"], mcfg)
-        return x + out.reshape(b, s, d), (kc, vc)
-    h = jax.nn.gelu(h @ _dq(lp["w1"], h) + lp["b1"]) @ _dq(lp["w2"], h)
-    if tp_axis:
-        h = jax.lax.psum(h, tp_axis)
-    return x + h, (kc, vc)
+            out = moe_ffn_serve(h.reshape(b * s, d), lp["moe"],
+                                _moe_cfg(cfg))[0]
+        return out.reshape(b, s, d)
+
+    return _layer(x, lp, cfg, li, qpos, attend, tp_axis, moe)
 
 
 def _decode_forward(params, caches, tok, pos, cfg, tp_axis=None,
@@ -858,15 +1020,13 @@ def _decode_window(params, caches, toks, pos0, cfg, tp_axis=None,
     side effects (returns (caches, None))."""
     x = params["emb"][toks]
     new_caches = []
-    for lp, kv in zip(params["layers"], caches):
+    for li, (lp, kv) in enumerate(zip(params["layers"], caches)):
         x, kv = _block_decode(x, lp, kv, pos0, cfg, tp_axis=tp_axis,
-                              ep_axis=ep_axis, ep_size=ep_size)
+                              ep_axis=ep_axis, ep_size=ep_size, li=li)
         new_caches.append(kv)
     if not need_logits:
         return new_caches, None
-    x = _ln(x, params["ln_f"])
-    logits = jnp.einsum("bsd,vd->bsv", x, params["emb"])
-    return new_caches, logits.astype(jnp.float32)
+    return new_caches, _logits(params, x, cfg).astype(jnp.float32)
 
 
 # CHUNK tokens per prefill window: large enough that every weight read
@@ -1118,6 +1278,8 @@ def _decode_mesh_check(cfg: TransformerConfig, mesh, batch: int):
     "tp" (or a dedicated "ep" axis when the mesh declares one), token
     routing rides moe_ffn's tiled all_to_all, and n_experts must
     divide the expert axis. Returns (dp, tp)."""
+    cfg.only("sharded decode: the (dp, tp) placement and the "
+             "expert-parallel capacity MoE", "models/transformer.py")
     names = mesh.axis_names
     if "dp" not in names or "tp" not in names:
         raise ValueError(f"decode mesh needs ('dp','tp'); has {names}")

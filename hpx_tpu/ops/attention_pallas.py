@@ -929,9 +929,30 @@ def _dequant_tile(x, sc_ref, blk, head, dtype):
     return (x.astype(jnp.float32) * scale).astype(dtype)
 
 
+def _ring_kpos(slot, row, qpos, block_size: int, nblk: int):
+    """Absolute position of row `row` of ring slot `slot` as a query at
+    `qpos` finds it: a window group's table is a RING over logical
+    blocks (logical block b sits in slot b % nblk), so the slot holds
+    the newest block <= qpos's own that is congruent to it. Slots the
+    sequence has not reached yet come out negative."""
+    cb = qpos // block_size
+    blk = cb - jax.lax.rem(cb - slot + nblk, nblk)
+    return blk * block_size + row
+
+
+def _live(kpos, qpos, window: int):
+    """The horizon: a query at qpos sees kpos <= qpos, and on a window
+    layer only qpos - window < kpos (and no slot not yet reached)."""
+    live = kpos <= qpos
+    if window:
+        live = jnp.logical_and(live, jnp.logical_and(
+            kpos > qpos - window, kpos >= 0))
+    return live
+
+
 def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                   block_size: int, nblk: int, group: int,
-                  quantized: bool):
+                  quantized: bool, window: int = 0):
     """One (slot b, kv-head h, logical block i) grid step.
 
     q_ref: (Wg, hd) the slot's query rows for this kv head (window row
@@ -972,7 +993,10 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         sf = (s / math.sqrt(q.shape[-1])).astype(jnp.float32)
         kpos = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 1)
         wrow = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 0) // group
-        live = kpos <= pos0 + wrow                 # per-window-row horizon
+        if window:
+            kpos = _ring_kpos(kpos // block_size, kpos % block_size,
+                              pos0 + wrow, block_size, nblk)
+        live = _live(kpos, pos0 + wrow, window)    # per-window-row horizon
         sf = jnp.where(live, sf, -jnp.inf)
         p = jax.nn.softmax(sf, axis=-1)            # oracle op order
         att = jax.lax.dot_general(
@@ -997,7 +1021,7 @@ def paged_online_scratch_shapes(wg_pad: int, head_dim: int) -> list:
 
 def _paged_online_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
                          *rest, block_size: int, nblk: int, group: int,
-                         quantized: bool):
+                         quantized: bool, window: int = 0):
     """One (slot b, kv-head h, logical block i) grid step of the
     online-softmax paged walk.
 
@@ -1040,10 +1064,11 @@ def _paged_online_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
     s = s / math.sqrt(q.shape[-1])                 # (Wg, bs) f32
 
     pos0 = pos_ref[b]
-    kpos = i * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
+    krow = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     wrow = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-    live = kpos <= pos0 + wrow                     # per-window-row horizon
+    kpos = (_ring_kpos(i, krow, pos0 + wrow, block_size, nblk)
+            if window else i * block_size + krow)
+    live = _live(kpos, pos0 + wrow, window)        # per-window-row horizon
     s = jnp.where(live, s, _NEG_INF)
 
     m_prev = m_s[:, :1]                            # (Wg, 1)
@@ -1069,7 +1094,7 @@ def _paged_online_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
 
     @pl.when(i == nblk - 1)
     def _finish():
-        # every real row has at least position 0 live, so l > 0; the
+        # every real row has its own position live, so l > 0; the
         # guard covers only the 8-sublane pad rows (sliced off outside)
         l = l_s[:, :1]
         den = jnp.where(l > 0, l, 1.0)
@@ -1081,7 +1106,8 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
                           pos0: jax.Array,
                           k_scale: Optional[jax.Array] = None,
                           v_scale: Optional[jax.Array] = None,
-                          interpret: Optional[bool] = None) -> jax.Array:
+                          interpret: Optional[bool] = None,
+                          window: int = 0) -> jax.Array:
     """Decode/verify attention that walks the block table in-kernel.
 
     q: [B, W, n_q, head_dim] post-rope queries (W = 1 for plain decode,
@@ -1111,9 +1137,16 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
     scalar-prefetched table's global block ids index the local pool
     directly, and int8/fp8 scales arrive pre-sliced per (block, local
     head) — no kernel-visible difference from the single-device
-    call."""
+    call.
+
+    `window` > 0: a WINDOW layer. `table` is then the window group's
+    RING ([B, ring]: logical block b in slot b % ring, `_ring_kpos`),
+    the kernel walks those `ring` entries and no more, a row counts
+    only where pos0 + w - window < its position, and the call is named
+    `hpx_paged_fused_win` so that a trace tells the two apart."""
     return _fused_paged_call(q, k_pool, v_pool, table, pos0,
-                             k_scale, v_scale, interpret, online=False)
+                             k_scale, v_scale, interpret, online=False,
+                             window=window)
 
 
 def fused_paged_online_attention(q: jax.Array, k_pool: jax.Array,
@@ -1121,8 +1154,8 @@ def fused_paged_online_attention(q: jax.Array, k_pool: jax.Array,
                                  pos0: jax.Array,
                                  k_scale: Optional[jax.Array] = None,
                                  v_scale: Optional[jax.Array] = None,
-                                 interpret: Optional[bool] = None
-                                 ) -> jax.Array:
+                                 interpret: Optional[bool] = None,
+                                 window: int = 0) -> jax.Array:
     """`fused_paged_attention` with an in-kernel online softmax —
     the O(block)-scratch variant (`hpx.serving.paged_kernel=
     fused_online`).
@@ -1143,11 +1176,13 @@ def fused_paged_online_attention(q: jax.Array, k_pool: jax.Array,
     dense/paged/spec test sweep. When byte-identity is the requirement,
     use `fused` — that kernel stays the bitwise reference."""
     return _fused_paged_call(q, k_pool, v_pool, table, pos0,
-                             k_scale, v_scale, interpret, online=True)
+                             k_scale, v_scale, interpret, online=True,
+                             window=window)
 
 
 def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
-                      interpret, online: bool) -> jax.Array:
+                      interpret, online: bool,
+                      window: int = 0) -> jax.Array:
     """Shared launch path for the two paged kernels: identical grid,
     BlockSpec table indirection, quantized-scale plumbing, and
     pad/slice layout — only the kernel body and its scratch differ."""
@@ -1174,7 +1209,8 @@ def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
     quantized = k_scale is not None
     kernel = functools.partial(
         _paged_online_kernel if online else _paged_kernel,
-        block_size=bs, nblk=maxb, group=g, quantized=quantized)
+        block_size=bs, nblk=maxb, group=g, quantized=quantized,
+        window=window)
     if online:
         # the flash carry — O(block), no sequence extent anywhere
         scratch = paged_online_scratch_shapes(wg_pad, hd)
@@ -1200,7 +1236,8 @@ def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
 
     out = pl.pallas_call(
         kernel,
-        name="hpx_paged_fused_online" if online else "hpx_paged_fused",
+        name=("hpx_paged_fused_online" if online else "hpx_paged_fused")
+        + ("_win" if window else ""),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, nkv, maxb),

@@ -81,7 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.quant import _quantize, _quantize_fp8
-from .attention_pallas import (fused_paged_attention,
+from .attention_pallas import (_live, _ring_kpos, fused_paged_attention,
                                fused_paged_online_attention)
 
 __all__ = [
@@ -155,7 +155,7 @@ def quantize_blocks(rows: jax.Array, dtype=jnp.int8):
 
 
 def scatter_token(pool: jax.Array, table: jax.Array, pos: jax.Array,
-                  val: jax.Array) -> jax.Array:
+                  val: jax.Array, ring: bool = False) -> jax.Array:
     """Write one token row per slot into the pool.
 
     pool: [num_blocks, n_kv, block_size, head_dim]; table: [B,
@@ -163,10 +163,13 @@ def scatter_token(pool: jax.Array, table: jax.Array, pos: jax.Array,
     head_dim]. Slot b's row lands at (table[b, pos[b]//bs], :,
     pos[b]%bs) — dead slots point their whole table at a reserved
     trash block, so their masked lanes scatter harmlessly. Block, kv
-    head and row are indexed together (the module's layout rule)."""
+    head and row are indexed together (the module's layout rule).
+    `ring`: the table is a window group's ring, logical block b in
+    column b % max_blocks."""
     nkv, bs = pool.shape[1], pool.shape[2]
     rows = jnp.arange(table.shape[0])
-    bidx = table[rows, pos // bs]
+    col = pos // bs
+    bidx = table[rows, col % table.shape[1] if ring else col]
     return pool.at[bidx[:, None], jnp.arange(nkv)[None, :],
                    (pos % bs)[:, None]].set(val)
 
@@ -304,7 +307,8 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
                            v_pool: jax.Array, table: jax.Array,
                            pos: jax.Array, k_scale: jax.Array = None,
                            v_scale: jax.Array = None,
-                           fused=False, interpret=None, write=None):
+                           fused=False, interpret=None, write=None,
+                           window: int = 0):
     """One decode step of attention over paged K/V.
 
     q: [B, 1, n_q, head_dim] (post-rope); k_new/v_new: [B, n_kv,
@@ -328,23 +332,33 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
     where they are not the attend side's: sharded serving hands every
     dp shard the rows of ALL slots (k_new/v_new then carry that larger
     batch) so each replica of the pool takes every write and the
-    replicas stay equal, while each shard attends its own slots."""
+    replicas stay equal, while each shard attends its own slots.
+
+    `window` > 0: a WINDOW layer over its group's ring table ([B,
+    ring]; `attention_pallas._ring_kpos`): the new row lands in ring
+    column (pos // bs) % ring, and only rows at positions > pos -
+    window are attended; the fused call is `hpx_paged_fused_win`."""
     quant = k_scale is not None
     wtable, wpos = (table, pos) if write is None else write
     if quant:
+        if window:
+            raise NotImplementedError(
+                "a quantized K/V pool under a window group's ring table "
+                "(ops/paged_attention.scatter_token_q reads its block "
+                "by logical column)")
         k_pool, k_scale = scatter_token_q(k_pool, k_scale, wtable,
                                           wpos, k_new)
         v_pool, v_scale = scatter_token_q(v_pool, v_scale, wtable,
                                           wpos, v_new)
     else:
-        k_pool = scatter_token(k_pool, wtable, wpos, k_new)
-        v_pool = scatter_token(v_pool, wtable, wpos, v_new)
+        k_pool = scatter_token(k_pool, wtable, wpos, k_new, bool(window))
+        v_pool = scatter_token(v_pool, wtable, wpos, v_new, bool(window))
     if fused:
         fpa = (fused_paged_online_attention if fused == "online"
                else fused_paged_attention)
         att = fpa(q, k_pool, v_pool, table, pos,
                   k_scale=k_scale, v_scale=v_scale,
-                  interpret=interpret)
+                  interpret=interpret, window=window)
     else:
         kc = gather_block_kv(k_pool, table, k_scale, q.dtype)
         vc = gather_block_kv(v_pool, table, v_scale, q.dtype)
@@ -354,7 +368,13 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array,
         qg = q.reshape(b, 1, nkv, g, hd)
         s = jnp.einsum("bqngh,bknh->bngqk", qg, kc) / math.sqrt(hd)
         kpos = jnp.arange(kc.shape[1])
-        live = kpos[None, :] <= pos[:, None]            # [B, S]
+        if window:
+            bs = k_pool.shape[2]
+            kpos = _ring_kpos(kpos[None, :] // bs, kpos[None, :] % bs,
+                              pos[:, None], bs, table.shape[1])
+            live = _live(kpos, pos[:, None], window)
+        else:
+            live = kpos[None, :] <= pos[:, None]        # [B, S]
         s = jnp.where(live[:, None, None, None, :], s, -jnp.inf)
         p = jax.nn.softmax(s.astype(jnp.float32), axis=-1
                            ).astype(q.dtype)
@@ -370,7 +390,8 @@ def paged_window_attention(q: jax.Array, k_new: jax.Array,
                            v_pool: jax.Array, table: jax.Array,
                            pos0: jax.Array, k_scale: jax.Array = None,
                            v_scale: jax.Array = None,
-                           fused=False, interpret=None, write=None):
+                           fused=False, interpret=None, write=None,
+                           window: int = 0):
     """W-token speculative-verify attention over paged K/V.
 
     q: [B, W, n_q, head_dim] (post-rope); k_new/v_new: [B, W, n_kv,
@@ -396,6 +417,12 @@ def paged_window_attention(q: jax.Array, k_new: jax.Array,
     costs the block's live rows at most one extra requantization
     rounding, identically on the gather and fused paths. `write=` as
     in `paged_decode_attention`."""
+    if window:
+        raise NotImplementedError(
+            "a W-token verify window over a window group's ring table "
+            "(ops/paged_attention.scatter_window writes by logical "
+            "column, and a ring of ceil(window / block) + 2 blocks "
+            "cannot hold window + W rows)")
     quant = k_scale is not None
     wtable, wpos0 = (table, pos0) if write is None else write
     if quant:

@@ -154,18 +154,91 @@ def test_paged_write_leaves_the_pool_where_it_lies(sds, w, kv):
     assert _pool_copies(text, pool) == []
 
 
-def test_server_step_program_has_no_pool_copy(sds, monkeypatch):
-    """The server's own `jit_step` (two layers of the cell's widths,
-    built by `_paged_step_prog`) for the described chip. The server is
-    built here on the CPU from parameter SHAPES; `jax.default_backend`
-    is steered so that it picks the `fused` kernel and the kernel
-    lowers for the chip, as both do there."""
+# the window block group of the benchmark's Laguna cell: 32 slots, 64 q /
+# 8 kv x 128 on a window layer, window 512 = a ring of 34 columns over
+# a pool of 32 x (34 + 4) + 1 blocks (serving._init_paged)
+_W_NQ, _W_NKV, _W_WIN, _W_RING, _W_NB = 64, 8, 512, 34, 1217
+
+
+@pytest.mark.parametrize("kernel", ["fused", "fused_online"])
+def test_window_group_write_and_kernel_leave_the_pool_where_it_lies(
+        sds, kernel):
+    """The ring write (`scatter_token(ring=True)`) keeps the layout
+    rule, and `hpx_paged_fused_win` lowers over a 34-column table."""
+    b = _C_SLOTS
+    pool = sds((_W_NB, _W_NKV, _C_BS, _C_HD), jnp.bfloat16)
+    new = sds((b, _W_NKV, _C_HD), jnp.bfloat16)
+
+    def call(q, kn, vn, kp, vp, table, pos):
+        return pa.paged_decode_attention(
+            q, kn, vn, kp, vp, table, pos, interpret=False,
+            fused=True if kernel == "fused" else "online", window=_W_WIN)
+    text = jax.jit(call, donate_argnums=(3, 4)).lower(
+        sds((b, 1, _W_NQ, _C_HD), jnp.bfloat16), new, new, pool, pool,
+        sds((b, _W_RING), jnp.int32), sds((b,), jnp.int32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "_win" in text
+    assert _pool_copies(text, pool) == []
+
+
+@pytest.mark.parametrize("tokens", [32, 128], ids=["decode32", "chunk128"])
+def test_moe_gmm_compiles_at_the_cells_widths(sds, tokens, monkeypatch):
+    """`hpx_moe_gmm` inside the whole drop-free sparse FFN at Laguna's
+    widths: 256 experts of 2048 x 512 in bfloat16, top-8, a decode
+    step's 32 tokens and a prefill chunk's 128."""
+    from hpx_tpu.models import moe
+    cfg = moe.MoeConfig(n_experts=256, top_k=8, d_model=2048, d_ff=512,
+                        dtype=jnp.bfloat16, mlp="swiglu", router="sigmoid",
+                        renorm=True, scale=2.5, shared_d_ff=512)
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: moe.init_moe_params(
+            cfg, jax.random.PRNGKey(0))))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _kernel_text(lambda x, p: moe.moe_ffn_serve(x, p, cfg),
+                        sds((tokens, 2048), jnp.bfloat16), params)
+    assert "hpx_moe_gmm" in text
+
+
+def _two_group_model():
+    """Two layers of the Laguna cell's kinds: full attention (48 q
+    heads, YaRN on half the head, dense MLP) and window attention (64 q
+    heads, window 512, sparse FFN), 8 kv heads x 128, at a width a test
+    compiles in seconds."""
+    from hpx_tpu.models.transformer import RopeSpec, TransformerConfig
+    return TransformerConfig(
+        vocab=512, d_model=256, n_heads=48, head_dim=_C_HD, n_layers=2,
+        d_ff=512, n_kv_heads=_W_NKV, rope=True, dtype=jnp.bfloat16,
+        norm="rmsnorm", norm_eps=1e-6, mlp="swiglu", tied=False,
+        attn_gate=True, layer_heads=(48, _W_NQ),
+        layer_window=(0, _W_WIN),
+        layer_rope=(RopeSpec(5e5, 64, 64.0, 4096, 64.0, 1.0, 1.4159),
+                    RopeSpec(1e4)),
+        layer_sparse=(False, True), n_experts=16, moe_top_k=8,
+        moe_d_ff=128, moe_shared_d_ff=128, moe_router="sigmoid",
+        moe_renorm=True, moe_scale=2.5)
+
+
+def _one_group_model():
+    from hpx_tpu.models.transformer import TransformerConfig
+    return TransformerConfig(vocab=512, d_model=256, n_heads=_C_NQ,
+                             head_dim=_C_HD, n_layers=2, d_ff=512,
+                             n_kv_heads=_C_NKV, rope=True,
+                             dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("model", [_one_group_model, _two_group_model],
+                         ids=["one-group", "full+window"])
+def test_server_step_program_has_no_pool_copy(sds, monkeypatch, model):
+    """The server's own `jit_step` (two layers of a cell's widths,
+    built by `_paged_step_prog`) for the described chip, for a model
+    with one block group and for one with a full and a window group.
+    The server is built here on the CPU from parameter SHAPES;
+    `jax.default_backend` is steered so that it picks the `fused`
+    kernel and the kernels lower for the chip, as they do there."""
     from hpx_tpu.models.serving import ContinuousServer
-    from hpx_tpu.models.transformer import TransformerConfig, init_params
-    cfg = TransformerConfig(vocab=512, d_model=256, n_heads=_C_NQ,
-                            head_dim=_C_HD, n_layers=2, d_ff=512,
-                            n_kv_heads=_C_NKV, rope=True,
-                            dtype=jnp.bfloat16)
+    from hpx_tpu.models.transformer import init_params
+    cfg = model()
     params = jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0)))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -175,14 +248,21 @@ def test_server_step_program_has_no_pool_copy(sds, monkeypatch):
 
     def on_chip(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-    s, maxb = srv.slots, srv._maxb
+    s = srv.slots
+    tables = (sds((s, srv._maxb), jnp.int32),)
+    if srv._win:
+        assert srv._ring == _W_RING and \
+            srv._pools[1][0].shape[0] == _W_NB
+        tables += (sds((s, srv._ring), jnp.int32),)
     text = srv._paged_step_prog().lower(
         on_chip(params), on_chip(srv._pools), None,
-        sds((s,), jnp.int32), sds((s,), jnp.int32),
-        sds((s, maxb), jnp.int32), sds((s,), jnp.float32),
-        sds((s, 2), jnp.uint32)).compile().as_text()
+        sds((s,), jnp.int32), sds((s,), jnp.int32), tables,
+        sds((s,), jnp.float32), sds((s, 2), jnp.uint32)).compile().as_text()
     assert text.count("tpu_custom_call") >= cfg.n_layers
-    assert _pool_copies(text, srv._pools[0][0]) == []
+    for pools in srv._pools:
+        assert _pool_copies(text, pools[0]) == []
+    assert ("hpx_paged_fused_win" in text) == bool(srv._win)
+    assert ("hpx_moe_gmm" in text) == bool(srv._win)
 
 
 # -- flash attention (training forward/backward, ring chunk) -------------
