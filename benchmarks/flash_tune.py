@@ -19,8 +19,16 @@ a hard error, never a silent fall-through to bf16 byte accounting.
 
 Usage: python benchmarks/flash_tune.py [--quick] [--paged]
                                        [--perfdb PATH]
+                                       [--positions P[,P...]
+                                        [--heads QxKV] [--smax S]]
   --quick: S in {2k, 4k} only and fewer samples (smoke/dev loops).
   --paged: tune the paged decode kernel instead of flash forward.
+  --positions 15,511,2047: with --paged, no sweep and nothing banked:
+    time the `fused` kernel (bf16, block 16, S 2048) once per listed
+    position, every slot AT that position and the table's tail on one
+    trash block as the server lays it out: 32 slots, 24 q / 2 kv heads
+    (the StarCoder2-3B cell's shape). [--heads QxKV] [--smax S] give
+    another cell's: 48x8 and 4864 (304 entries) are Laguna's full layers.
   --perfdb PATH: with --paged, additionally bank every sweep point
     into the persistent perf store (svc/perfdb) as provenance-stamped
     observations, and each kv_dtype winner into its learned-blocks
@@ -97,14 +105,18 @@ _PAGED_ITEMSIZE = {"bf16": 2, "int8": 1, "fp8": 1}
 _PAGED_KERNELS = ("gather", "fused", "fused_online")
 
 
-def paged_step(jax, jnp, S, bs, kvd, kern):
+def paged_step(jax, jnp, S, bs, kvd, kern, pos=None, heads=(8, 8, 8)):
     """Build one paged decode attention step at the serving shape:
     8 slots, every table fully mapped to DISTINCT pool blocks at a
     near-S horizon (the steady-state worst case — block-size effects
     show up as grid/tiling overhead, not masked work). `kern` picks
     the formulation: gather (XLA oracle), fused (bitwise Pallas), or
-    fused_online (O(block)-scratch online softmax). Returns (jitted
-    step, its q operand, HBM bytes one call reads)."""
+    fused_online (O(block)-scratch online softmax). `pos` puts every
+    slot at that position instead (an int, or one per slot) and lays
+    the table out as the server does: the entries a slot's position
+    has reached map to distinct blocks, the tail to ONE trash block
+    (block 0). `heads` is (slots, q heads, kv heads). Returns (jitted
+    step, its q operand, HBM bytes one call has to read)."""
     from hpx_tpu.ops.attention_pallas import (fused_paged_attention,
                                               fused_paged_online_attention)
     from hpx_tpu.ops.paged_attention import (gather_block_kv,
@@ -120,7 +132,7 @@ def paged_step(jax, jnp, S, bs, kvd, kern):
         raise ValueError(
             f"flash_tune --paged: unknown kernel {kern!r} (expected one "
             f"of {_PAGED_KERNELS})")
-    B, nq, nkv, H = 8, 8, 8, 128
+    (B, nq, nkv), H = heads, 128
     maxb = S // bs
     nb = B * maxb + 1                  # + a trash-style spare block
     rng = np.random.default_rng(0)
@@ -129,9 +141,13 @@ def paged_step(jax, jnp, S, bs, kvd, kern):
     # pool layout: heads ahead of rows (ops/paged_attention)
     kp = rng.standard_normal((nb, nkv, bs, H), np.float32)
     vp = rng.standard_normal((nb, nkv, bs, H), np.float32)
+    pos = np.broadcast_to(
+        np.asarray(S - 1 if pos is None else pos, np.int32), (B,))
+    live = pos // bs + 1               # entries reached (S - 1: all)
+    table = np.arange(1, B * maxb + 1, dtype=np.int32).reshape(B, maxb)
     table = jnp.asarray(
-        np.arange(1, B * maxb + 1, dtype=np.int32).reshape(B, maxb))
-    pos = jnp.full((B,), S - 1, jnp.int32)
+        np.where(np.arange(maxb)[None, :] < live[:, None], table, 0))
+    pos = jnp.asarray(pos)
     ks = vs = None
     if kvd == "bf16":
         kq = jnp.asarray(kp, jnp.bfloat16)
@@ -161,16 +177,17 @@ def paged_step(jax, jnp, S, bs, kvd, kern):
                else fused_paged_attention)
         f = jax.jit(lambda qq: fpa(qq, kq, vq, table, pos,
                                    k_scale=ks, v_scale=vs))
-    hbm = 2 * B * maxb * bs * nkv * H * itemsize    # K + V pool reads
+    nlive = int(live.sum())                         # mapped entries
+    hbm = 2 * nlive * bs * nkv * H * itemsize       # K + V pool reads
     if kvd in ("int8", "fp8"):
-        hbm += 2 * B * maxb * nkv * 4               # scale sidecars
+        hbm += 2 * nlive * nkv * 4                  # scale sidecars
     return f, q, hbm
 
 
-def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3):
+def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3, **layout):
     """Time `paged_step`. Returns (HBM-read GB/s, us per call,
     spread)."""
-    f, q, hbm = paged_step(jax, jnp, S, bs, kvd, kern)
+    f, q, hbm = paged_step(jax, jnp, S, bs, kvd, kern, **layout)
     out = f(q)
     jax.block_until_ready(out)
 
@@ -185,6 +202,21 @@ def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3):
     pers = sorted(slope_time(chain, 8, 50) for _ in range(samples))
     per = pers[(samples - 1) // 2]
     return hbm / per / 1e9, per * 1e6, (pers[-1] - pers[0]) / per
+
+
+def paged_positions(jax, jnp, positions, heads, S) -> int:
+    """`--positions`: one line per position, nothing banked."""
+    bs, kvd, kern = 16, "bf16", "fused"
+    for p in positions:
+        gbs, us, spread = paged_measure(jax, jnp, S, bs, kvd, kern,
+                                        pos=p, heads=heads)
+        print(json.dumps({"S": S, "block_size": bs, "kv_dtype": kvd,
+                          "kernel": kern, "slots": heads[0],
+                          "q_heads": heads[1], "kv_heads": heads[2],
+                          "position": p, "us_per_step": round(us, 1),
+                          "hbm_gb_per_s": round(gbs, 1),
+                          "spread": round(spread, 3)}), flush=True)
+    return 0
 
 
 def paged_main(jax, jnp, quick: bool, perfdb_path=None) -> int:
@@ -269,6 +301,11 @@ def main() -> int:
         return 1
     enable_compile_cache()
 
+    if "--paged" in sys.argv and _arg("--positions"):
+        nq, nkv = map(int, (_arg("--heads") or "24x2").split("x"))
+        return paged_positions(
+            jax, jnp, [int(p) for p in _arg("--positions").split(",")],
+            (32, nq, nkv), int(_arg("--smax") or 2048))
     if "--paged" in sys.argv:
         return paged_main(jax, jnp, quick, perfdb_path=_arg("--perfdb"))
 

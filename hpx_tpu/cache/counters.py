@@ -59,6 +59,11 @@ Paged servers additionally export the cache counters::
                                                           sidecars incl. — fp8 pools
                                                           report the ~0.25x ratio vs
                                                           an f32 compute dtype)
+    /cache{locality#L/server#i}/count/walk-entries-per-slot  table entries the
+                                                          bounded `fused` walk
+                                                          visits per live slot
+    /cache{locality#L/server#i}/walk-share              the same over the table's
+                                                          width
 
 Models with window layers add their second block group::
 
@@ -261,6 +266,14 @@ def register_server(srv) -> str:
         put("cache", "bytes/hbm-read-per-token",
             pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
                                ["hbm_read_bytes_per_token"])))
+        # how far the bounded `fused` walk goes (entries a live slot,
+        # and the share of the table's width)
+        put("cache", "count/walk-entries-per-slot",
+            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                               ["walk_entries_per_slot"])))
+        put("cache", "walk-share",
+            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                               ["walk_share"])))
         if getattr(srv, "_win", 0):
             # the window block group (serving._init_paged)
             put("cache", "window/blocks-in-use",

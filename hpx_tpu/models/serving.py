@@ -1990,9 +1990,15 @@ class ContinuousServer:
 
         Each decode step emits one token per live slot and streams
         every MAPPED block of that slot once per layer, K and V pools
-        both (the fused kernels read the padded table tail too, but
-        those entries all alias the single resident trash block —
-        occupancy is the honest per-slot traffic). bytes/token uses
+        both. How far a fused kernel WALKS a slot's table is another
+        matter: the `fused` kernel over unquantized pools with a head
+        of whole 128-lane rows stops at the block of the slot's
+        position (`walk_entries_per_slot`, mean over the live slots of
+        min(p // block_size + 1, max_blocks); `walk_share` is that over
+        the table's width: what is left of a full-width walk), every
+        other fused call visits all max_blocks entries, whose tail
+        aliases the single resident trash block — occupancy is the
+        honest per-slot traffic either way. bytes/token uses
         `cache.block_allocator.block_bytes`, so the int8/fp8 sidecar
         scales are included: vs a bf16 compute dtype the quantized
         pools read ~0.5x, and vs tier-1's f32 compute dtype ~0.25x —
@@ -2005,9 +2011,16 @@ class ContinuousServer:
         bb = block_bytes(self.block_size, self.cfg.kv_heads,
                          self.cfg.head_dim, self._kv_acct_dtype(),
                          layers=self.cfg.n_layers)
+        walks = [min(p // self.block_size + 1, self._maxb)
+                 for p in self.live_positions().values()]
+        walk = sum(walks) / len(walks) if walks else 0.0
         return {
             "hbm_read_blocks_per_token": per_tok,
             "hbm_read_bytes_per_token": per_tok * bb,
+            # the bounded walk of the next decode step (the full
+            # group's table; `attention_pallas._walk_entries` at W = 1)
+            "walk_entries_per_slot": walk,
+            "walk_share": walk / self._maxb,
             # where this server's block_size came from: arg | config |
             # env | learned (perfdb) | seed (paged_blocks.json) |
             # default — the satellite audit hook for learned ladders

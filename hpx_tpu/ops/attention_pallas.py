@@ -862,6 +862,30 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
 # contract (rollback-heavy speculation audits, A/B token equality);
 # pick `fused_online` when context length presses VMEM — the knob is
 # hpx.serving.paged_kernel = fused | fused_online | gather | auto.
+#
+# THE WALK'S BOUND (`_paged_live_kernel`). The grid walk above visits
+# every table entry of every slot, and a grid step costs its ~0.1 us
+# whether the entry is live or the trash block (PERF.md section 6, PR
+# 31): on the serving cells' tables, a quarter to a third live, the
+# kernel ran at 2-3% of its roofline. So a `fused` call over
+# unquantized pools whose head_dim is a multiple of 128 (what
+# `_fused_paged_call` can read off its operands; every call the
+# benchmark's serving cells, the verify window and the mesh form make)
+# takes a second launch path under the same names: grid (slot, kv head)
+# only, the pools LEFT IN HBM, and the kernel itself copies
+# (`pltpu.make_async_copy`) physical block table[b, j], head h into
+# rows j*block_size .. of the same two (S, hd) banks for j below
+# `_walk_entries` = min((pos0 + W - 1) // block_size + 1, max_blocks):
+# a trip count read from DATA, so one compiled program serves every
+# length. The tail of the table is never fetched. The finish is the
+# grid walk's, op for op, over the whole bank (its fixed cost a
+# (slot, kv head)). Every other call keeps the grid walk, for reasons
+# that conflict with this path's: a one-byte pool would need a staging
+# bank and a per-entry dequantization between landing and banking
+# (which costs more than the copy it halves, and whose semaphore
+# discipline is easy to get wrong); the chip copies out of an HBM array
+# only whole 128-lane rows, so a 64-wide head cannot be sliced out of
+# its pool at all; `fused_online` gains nothing at a full table.
 
 _PAGED_BLOCKS_FILE = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "paged_blocks.json")
@@ -1006,6 +1030,83 @@ def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[...] = att.astype(o_ref.dtype)
 
 
+def _walk_entries(pos0, w: int, block_size: int, nblk: int):
+    """How many table entries the bounded walk of a slot visits: the
+    entry of its LAST query row (pos0 + w - 1) and every one before it,
+    at most the table's width. On a window group's ring those are the
+    slots the sequence has reached (all of them once it has gone
+    round). Every row `_live` lets through lies in one of them."""
+    return jnp.minimum((pos0 + w - 1) // block_size + 1, nblk)
+
+
+def _paged_live_kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       k_s, v_s, sem, *, block_size: int, nblk: int,
+                       group: int, w: int, window: int = 0):
+    """One (slot b, kv-head h) grid step: the slot's whole walk, bounded
+    by its live length (`_paged_kernel`'s result for unquantized pools
+    without its dead grid steps).
+
+    q_ref / o_ref as in `_paged_kernel`; k_hbm / v_hbm: the POOLS, left
+    in HBM. The first `_walk_entries` table entries are copied into the
+    k_s / v_s banks (entry j to rows j*bs ..), all in flight at once on
+    ONE DMA semaphore a pool. Such a semaphore counts bytes landed from
+    ANY copy that signals it, so a wait that returns says nothing of
+    ITS copy: NOTHING IS READ FROM A BANK BEFORE EVERY WAIT OF BOTH
+    LOOPS' COPIES HAS RETURNED. Then `_paged_kernel`'s finish, op for
+    op. Rows past the walk hold whatever VMEM held (the previous grid
+    step's rows, NaN for all we know): their scores are masked to -inf
+    as every dead row's are (a select, so a NaN score goes too), and
+    V's are SELECTED to zero ahead of the product, because 0 x NaN is
+    NaN."""
+    b = pl.program_id(0)
+    h = pl.program_id(1)
+    pos0 = pos_ref[b]
+    n_live = _walk_entries(pos0, w, block_size, nblk)
+
+    def copies(j):
+        rows = pl.ds(pl.multiple_of(j * block_size, block_size),
+                     block_size)
+        blk = table_ref[b, j]
+        return (pltpu.make_async_copy(k_hbm.at[blk, h], k_s.at[rows, :],
+                                      sem.at[0]),
+                pltpu.make_async_copy(v_hbm.at[blk, h], v_s.at[rows, :],
+                                      sem.at[1]))
+
+    def start(j, carry):
+        for c in copies(j):
+            c.start()
+        return carry
+
+    def wait(j, carry):
+        for c in copies(j):
+            c.wait()
+        return carry
+
+    jax.lax.fori_loop(0, n_live, start, 0)
+    jax.lax.fori_loop(0, n_live, wait, 0)
+
+    q = q_ref[...]                                 # (Wg, hd)
+    s = jax.lax.dot_general(
+        q, k_s[...].astype(q.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(q.dtype)
+    sf = (s / math.sqrt(q.shape[-1])).astype(jnp.float32)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 1)
+    wrow = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 0) // group
+    if window:
+        kpos = _ring_kpos(kpos // block_size, kpos % block_size,
+                          pos0 + wrow, block_size, nblk)
+    live = _live(kpos, pos0 + wrow, window)        # per-window-row horizon
+    sf = jnp.where(live, sf, -jnp.inf)
+    p = jax.nn.softmax(sf, axis=-1)                # oracle op order
+    vrow = jax.lax.broadcasted_iota(jnp.int32, v_s.shape, 0)
+    v = jnp.where(vrow < n_live * block_size, v_s[...], 0)
+    att = jax.lax.dot_general(
+        p.astype(o_ref.dtype), v.astype(o_ref.dtype),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    o_ref[...] = att.astype(o_ref.dtype)
+
+
 def paged_online_scratch_shapes(wg_pad: int, head_dim: int) -> list:
     """The fused_online VMEM carry: (acc, m, l) — (W*g, hd) f32
     accumulator plus two lane-replicated (W*g, 128) running-max /
@@ -1121,12 +1222,19 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
     pools (None for bf16/f32 pools). Returns att [B, W, n_q, head_dim]
     in q.dtype.
 
-    Every logical block (trash-padded tail included) is processed and
-    masked, never skipped — rows past pos0+w contribute exact-zero
-    probability, matching `paged_decode_attention` element-for-element:
-    bitwise-equal scores and softmax, logits within ~1 ulp (see the
-    section comment), same tokens. GQA via the same grouped-query
-    reshape, so n_q % n_kv == 0.
+    Rows past pos0+w contribute exact-zero probability, matching
+    `paged_decode_attention` element-for-element: bitwise-equal scores
+    and softmax, logits within ~1 ulp (see the section comment), same
+    tokens. GQA via the same grouped-query reshape, so n_q % n_kv == 0.
+
+    How far the table is walked depends on the pools. Unquantized
+    pools with head_dim % 128 == 0 (`_paged_live_kernel`): as far as
+    the block of the slot's last query row, min((pos0 + W - 1) //
+    block_size + 1, max_blocks) entries, and no further — the tail is
+    never fetched (a dead slot at position 0 reads one block), one
+    compiled program for every length. Quantized pools and narrower
+    heads (`_paged_kernel`): every logical block, trash-padded tail
+    included, is fetched and masked. Same result either way.
 
     Falls back to interpret mode off-TPU (CPU tier-1 stays green).
 
@@ -1141,7 +1249,8 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
 
     `window` > 0: a WINDOW layer. `table` is then the window group's
     RING ([B, ring]: logical block b in slot b % ring, `_ring_kpos`),
-    the kernel walks those `ring` entries and no more, a row counts
+    the kernel walks those `ring` entries and no more (the bounded
+    walk: the slots the sequence has reached), a row counts
     only where pos0 + w - window < its position, and the call is named
     `hpx_paged_fused_win` so that a trace tells the two apart."""
     return _fused_paged_call(q, k_pool, v_pool, table, pos0,
@@ -1180,12 +1289,49 @@ def fused_paged_online_attention(q: jax.Array, k_pool: jax.Array,
                              window=window)
 
 
+def _live_walk_call(qk, k_pool, v_pool, table, pos0, *, w: int,
+                    group: int, window: int, interpret: bool) -> jax.Array:
+    """Launch `_paged_live_kernel`: qk [B, n_kv, Wg_pad, hd] in, the
+    same out. Grid (slot, kv head), both parallel; the pools stay in HBM
+    for the kernel's own copies; table and pos0 scalar-prefetched; the
+    two (S, hd) banks in the pools' dtype and a DMA semaphore a pool."""
+    b, nkv, wg_pad, hd = qk.shape
+    bs = k_pool.shape[2]
+    maxb = table.shape[1]
+    q_spec = pl.BlockSpec((None, None, wg_pad, hd),
+                          lambda bb, hh, *_: (bb, hh, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    return pl.pallas_call(
+        functools.partial(_paged_live_kernel, block_size=bs, nblk=maxb,
+                          group=group, w=w, window=window),
+        name="hpx_paged_fused" + ("_win" if window else ""),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, nkv),
+            in_specs=[q_spec, pool_spec, pool_spec],
+            out_specs=[q_spec],
+            scratch_shapes=[pltpu.VMEM((maxb * bs, hd), k_pool.dtype),
+                            pltpu.VMEM((maxb * bs, hd), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[_sds((b, nkv, wg_pad, hd), qk.dtype, qk, k_pool,
+                        v_pool)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(table.astype(jnp.int32), pos0.astype(jnp.int32), qk, k_pool,
+      v_pool)[0]
+
+
 def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
                       interpret, online: bool,
                       window: int = 0) -> jax.Array:
-    """Shared launch path for the two paged kernels: identical grid,
-    BlockSpec table indirection, quantized-scale plumbing, and
-    pad/slice layout — only the kernel body and its scratch differ."""
+    """Shared launch path for the paged kernels: the pad/slice layout
+    of q, then the ONE place that decides which walk a call takes —
+    the bounded one (`_live_walk_call`) for `fused` over unquantized
+    pools with head_dim % 128 == 0, else the grid walk: identical grid,
+    BlockSpec table indirection and quantized-scale plumbing for its
+    two kernels, only the kernel body and its scratch differ."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, w, nq, hd = q.shape
@@ -1205,6 +1351,15 @@ def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
     qk = qk.reshape(b, nkv, wg, hd)
     if wg_pad != wg:
         qk = jnp.pad(qk, ((0, 0), (0, 0), (0, wg_pad - wg), (0, 0)))
+
+    if not online and k_scale is None and hd % 128 == 0:
+        # the bounded walk (section comment): decided HERE and nowhere
+        # else, from the operands alone, the same on the CPU as on the
+        # chip; every other call goes on below, as it always has
+        out = _live_walk_call(qk, k_pool, v_pool, table, pos0, w=w,
+                              group=g, window=window, interpret=interpret)
+        return jnp.moveaxis(out[:, :, :wg].reshape(b, nkv, w, g, hd), 1, 2
+                            ).reshape(b, w, nq, hd)
 
     quantized = k_scale is not None
     kernel = functools.partial(

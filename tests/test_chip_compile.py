@@ -56,8 +56,15 @@ def topo():
 def sds(topo):
     one_chip = SingleDeviceSharding(topo.devices[0])
 
-    def make(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    def make(shape, dtype, row_major=False):
+        # row_major: the parameter arrives in {n-1, .., 0}, as an array
+        # a program left behind does (else the compiler picks)
+        where = one_chip
+        if row_major:
+            from jax.experimental.layout import Format, Layout
+            where = Format(Layout(major_to_minor=tuple(
+                range(len(shape)))), one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
     return make
 
 
@@ -108,6 +115,76 @@ def test_paged_attention_window_and_hd64_compile(sds, kernel, kv, nkv,
     _kernel_text(*_paged_case(sds, kernel, kv, nkv, hd, w))
 
 
+# the benchmark's two serving cells at their real widths: 32 slots;
+# StarCoder2-3B 24 q / 2 kv over 128 entries of 8,193 blocks; Laguna's
+# full layers 48 q / 8 kv over 304 entries of 19,457 blocks, its window
+# layers 64 q / 8 kv over a ring of 34 in 1,217 blocks
+_CELL_WIDTHS = {"sc2-3b": (24, 2, 128, 8193, 0),
+                "laguna-full": (48, 8, 304, 19457, 0),
+                "laguna-window": (64, 8, 34, 1217, 512)}
+
+
+def _cell_case(make, cell, w=1, hd=128, **pool_kw):
+    """(`fused` call, pool, argument shapes) of one of `_CELL_WIDTHS`,
+    32 slots; `make(shape, dtype)` places a shape."""
+    nq, nkv, maxb, nb, window = _CELL_WIDTHS[cell]
+    pool = make((nb, nkv, 16, hd), jnp.bfloat16, **pool_kw)
+
+    def call(q, kp, vp, table, pos):
+        return ap.fused_paged_attention(q, kp, vp, table, pos,
+                                        interpret=False, window=window)
+    return call, pool, (make((32, w, nq, hd), jnp.bfloat16), pool, pool,
+                        make((32, maxb), jnp.int32), make((32,), jnp.int32))
+
+
+@pytest.mark.parametrize("w", [1, 4], ids=["decode", "window4"])
+@pytest.mark.parametrize("cell", sorted(_CELL_WIDTHS))
+def test_bounded_walk_compiles_at_the_cells_widths(sds, cell, w):
+    """`_paged_live_kernel` (pools left in HBM, a data-dependent loop
+    of block copies in one grid step a (slot, kv head)) at the two
+    serving cells' widths, full table and ring, under both names, with
+    nothing pool-shaped copied around it."""
+    call, pool, shapes = _cell_case(sds, cell, w)
+    text = _kernel_text(call, *shapes)
+    assert ("hpx_paged_fused_win" in text) == (cell == "laguna-window")
+    assert "hpx_paged_fused" in text
+    assert _pool_ops(text, pool) == []
+
+
+def test_bounded_walk_compiles_under_the_serving_mesh(topo):
+    """The mesh form: the same call inside `shard_map` on Mesh(dp=2,
+    tp=2) of the described chips, slots over dp, kv heads over tp, the
+    pools' block axis replicated (serving._paged_shard_specs) — the
+    pools stay in each chip's HBM and the kernel's copies index them by
+    the table's global block ids."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    specs = {4: P("dp", None, "tp", None), 2: P("dp", None), 1: P("dp")}
+    pool_sp = P(None, "tp", None, None)
+
+    def on(shape, dtype):
+        # the pools lead with their blocks, all else with the 32 slots
+        spec = pool_sp if shape[0] != 32 else specs[len(shape)]
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    call, _, shapes = _cell_case(on, "sc2-3b")
+    text = _kernel_text(
+        jax.shard_map(call, mesh=mesh, out_specs=specs[4],
+                      in_specs=(specs[4], pool_sp, pool_sp, specs[2],
+                                specs[1])), *shapes)
+    assert "hpx_paged_fused" in text
+
+
+def test_a_head_of_64_streams_its_pools_without_a_copy(sds):
+    """A head narrower than the 128 lanes the chip copies out of an
+    HBM array keeps the grid walk, whose BlockSpec streams (block, 64)
+    tiles in place: given pools in the layout the kernels pin, no
+    pool-shaped `pad` or `copy` around the kernel (widening the pools
+    for the bounded walk would cost two a call)."""
+    call, pool, shapes = _cell_case(sds, "sc2-3b", hd=64, row_major=True)
+    assert _pool_ops(_kernel_text(call, *shapes), pool) == []
+
+
 # -- the pool layout rule: no whole-pool copy around a row write ---------
 #
 # The fused kernels pin their pool operands to `{3,2,1,0}`; a write that
@@ -119,13 +196,13 @@ def test_paged_attention_window_and_hd64_compile(sds, kernel, kv, nkv,
 _C_SLOTS, _C_NQ, _C_NKV, _C_HD, _C_BS = 32, 24, 2, 128, 16
 
 
-def _pool_copies(text, pool) -> list:
-    """The `copy(` ops of a compiled module whose RESULT has the
-    pool's shape."""
+def _pool_ops(text, pool) -> list:
+    """The `copy(` / `pad(` ops of a compiled module whose RESULT has
+    the pool's leading dimensions (a pad widens the last one)."""
     tag = {"bfloat16": "bf16", "int8": "s8"}[jnp.dtype(pool.dtype).name]
-    shape = f"{tag}[{','.join(map(str, pool.shape))}]"
-    return [ln.strip() for ln in text.splitlines()
-            if " copy(" in ln and shape in ln.split(" copy(")[0]]
+    shape = f"{tag}[{','.join(map(str, pool.shape[:-1]))},"
+    return [ln.strip() for ln in text.splitlines() for op in ("copy", "pad")
+            if f" {op}(" in ln and shape in ln.split(f" {op}(")[0]]
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -151,7 +228,7 @@ def test_paged_write_leaves_the_pool_where_it_lies(sds, w, kv):
     text = jax.jit(call, donate_argnums=donate).lower(
         *shapes).compile().as_text()
     assert "tpu_custom_call" in text
-    assert _pool_copies(text, pool) == []
+    assert _pool_ops(text, pool) == []
 
 
 # the window block group of the benchmark's Laguna cell: 32 slots, 64 q /
@@ -178,7 +255,7 @@ def test_window_group_write_and_kernel_leave_the_pool_where_it_lies(
         sds((b, _W_RING), jnp.int32), sds((b,), jnp.int32)
     ).compile().as_text()
     assert "tpu_custom_call" in text and "_win" in text
-    assert _pool_copies(text, pool) == []
+    assert _pool_ops(text, pool) == []
 
 
 @pytest.mark.parametrize("tokens", [32, 128], ids=["decode32", "chunk128"])
@@ -260,7 +337,7 @@ def test_server_step_program_has_no_pool_copy(sds, monkeypatch, model):
         sds((s,), jnp.float32), sds((s, 2), jnp.uint32)).compile().as_text()
     assert text.count("tpu_custom_call") >= cfg.n_layers
     for pools in srv._pools:
-        assert _pool_copies(text, pools[0]) == []
+        assert _pool_ops(text, pools[0]) == []
     assert ("hpx_paged_fused_win" in text) == bool(srv._win)
     assert ("hpx_moe_gmm" in text) == bool(srv._win)
 

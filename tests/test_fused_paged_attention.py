@@ -268,6 +268,188 @@ def test_fused_fp8_matches_gather_fp8(bs):
                                rtol=5e-6, atol=5e-6)
 
 
+# -- the bounded walk: unquantized pools, head_dim % 128 == 0 -----------------
+#
+# `_fused_paged_call` sends a `fused` call over such pools to
+# `_paged_live_kernel` (grid (slot, kv head); the kernel copies the
+# entries below `_walk_entries` itself) and every other call to the
+# grid walk (grid (slot, kv head, entry)), which the tests above pin.
+
+_HD = 128
+
+
+def _dead_tail_case(dtype, w, window, poison):
+    """Pools, a table and positions whose DEAD entries (a slot's table
+    tail; a ring's slots not reached yet) all point at block 1, which
+    no live position maps to; `poison` fills it with NaN, else zeros."""
+    bs, maxb, B, nkv, nq = 16, 8, 3, 2, 4
+    rng = np.random.default_rng(31)
+    nb = B * maxb + 2
+    kp = rng.standard_normal((nb, nkv, bs, _HD)).astype(np.float32)
+    vp = rng.standard_normal((nb, nkv, bs, _HD)).astype(np.float32)
+    kp[1] = vp[1] = np.nan if poison else 0.0
+    pos = np.array([0, 2 * bs - 1, 5 * bs + 3], np.int32)
+    table = rng.permutation(np.arange(2, nb))[:B * maxb].reshape(B, maxb)
+    entries = (pos + w - 1) // bs + 1
+    table = np.where(np.arange(maxb)[None, :] < entries[:, None], table, 1)
+    q = jnp.asarray(rng.standard_normal((B, w, nq, _HD)), dtype)
+    return (q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+            jnp.asarray(table.astype(np.int32)), jnp.asarray(pos))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("w", [1, 4])
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
+def test_fused_never_reads_a_dead_entry(window, w, dtype):
+    """Two pools that differ only in a block no live position maps to
+    give the same bits: the walk stops at the slot's live length, and
+    what the bank held past it never reaches the output (the grid walk
+    fetched the block, and 0 x NaN is NaN in its p @ V)."""
+    outs = []
+    for poison in (True, False):
+        q, kp, vp, table, pos = _dead_tail_case(dtype, w, window, poison)
+        outs.append(np.asarray(ap.fused_paged_attention(
+            q, kp, vp, table, pos, interpret=True, window=window),
+            np.float32))
+    assert np.isfinite(outs[0]).all()
+    assert (outs[0] == outs[1]).all()
+
+
+def _assert_close_to_oracle(fused, gather, dtype):
+    """f32: ulp-tight; bf16: one ulp of the final cast at |x| <= 2."""
+    tol = 2e-6 if dtype == jnp.float32 else 1.6e-2
+    np.testing.assert_allclose(np.asarray(gather, np.float32),
+                               np.asarray(fused, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("corner,w", [
+    ("first_row", 1), ("block_end", 1), ("block_start", 1),
+    ("table_end", 1), ("first_row", 4), ("block_end", 4),
+    ("crosses_edge", 4), ("table_end", 4), ("dead_slot", 1)])
+def test_bounded_walk_corners_match_gather(corner, w, dtype):
+    """The corners of n_live = min((pos0 + W - 1) // bs + 1, maxb)
+    against the gather oracle: position 0, the last row of a block, the
+    first of the next, the table's last row, a verify window whose rows
+    cross a block edge, and a dead slot (all trash, position 0)."""
+    bs, maxb = 8, 6
+    kp, vp, table, pos, q, kn, vn = _paged_state(
+        bs, maxb, B=2, hd=_HD, w=w, dtype=dtype, seed=41)
+    p0 = {"first_row": 0, "block_end": bs - 1, "block_start": bs,
+          "table_end": maxb * bs - w, "crosses_edge": 2 * bs - 2,
+          "dead_slot": 0}[corner]
+    pos = pos.at[1].set(p0)
+    if corner == "dead_slot":
+        table = table.at[1].set(0)
+    attend = paged_decode_attention if w == 1 else paged_window_attention
+    ag = attend(q, kn, vn, kp, vp, table, pos)[0]
+    af = attend(q, kn, vn, kp, vp, table, pos, fused=True,
+                interpret=True)[0]
+    _assert_close_to_oracle(af, ag, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("lap", ["first_slot", "not_round", "ring_full",
+                                 "gone_round", "round_twice"])
+def test_bounded_walk_on_a_ring_matches_gather(lap, dtype):
+    """A window group's ring (logical block b in slot b % ring): a
+    sequence that has reached one slot, some, exactly all of them, and
+    that has gone round (every slot live, the window's edge inside an
+    overwritten one), against the gather oracle over the same ring."""
+    bs, ring, window = 8, 5, 20
+    kp, vp, table, pos, q, kn, vn = _paged_state(
+        bs, ring, B=2, hd=_HD, dtype=dtype, seed=43)
+    p0 = {"first_slot": 3, "not_round": 2 * bs + 5,
+          "ring_full": ring * bs - 1, "gone_round": ring * bs + 2,
+          "round_twice": 2 * ring * bs + bs + 1}[lap]
+    pos = jnp.asarray([p0, ring * bs + bs + 4], jnp.int32)
+    ag = paged_decode_attention(q, kn, vn, kp, vp, table, pos,
+                                window=window)[0]
+    af = paged_decode_attention(q, kn, vn, kp, vp, table, pos,
+                                window=window, fused=True,
+                                interpret=True)[0]
+    _assert_close_to_oracle(af, ag, dtype)
+
+
+def _paged_grid(fn, *args):
+    """The grid of the one `pallas_call` in fn's jaxpr."""
+    calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return tuple(calls[0].params["grid_mapping"].grid)
+
+
+@pytest.mark.parametrize("interpret", [True, False],
+                         ids=["interpret", "compiled"])
+@pytest.mark.parametrize("kernel,pool,hd,w,window,axes", [
+    ("fused", "bf16", 128, 1, 0, 2), ("fused", "f32", 128, 1, 0, 2),
+    ("fused", "bf16", 256, 1, 0, 2), ("fused", "bf16", 128, 4, 0, 2),
+    ("fused", "bf16", 128, 1, 40, 2),
+    ("fused", "int8", 128, 1, 0, 3), ("fused", "fp8", 128, 1, 0, 3),
+    ("fused", "bf16", 64, 1, 0, 3), ("fused", "f32", 8, 1, 0, 3),
+    ("fused", "bf16", 64, 1, 40, 3),
+    ("fused_online", "bf16", 128, 1, 0, 3),
+    ("fused_online", "int8", 128, 1, 0, 3)])
+def test_which_calls_take_the_bounded_walk(kernel, pool, hd, w, window,
+                                           axes, interpret):
+    """The dispatch, read off the jaxpr: a `fused` call over
+    unquantized pools with a head of whole 128-lane rows launches the
+    two-axis grid (slot, kv head); a quantized pool, a narrower head
+    and `fused_online` still launch the grid walk's three axes (slot,
+    kv head, table entry). The choice looks at the operands alone, so
+    it is the same in interpret mode and compiled for the chip."""
+    B, nkv, nq, bs, maxb = 2, 2, 4, 16, 3
+    dtype = jnp.float32 if pool == "f32" else jnp.bfloat16
+    kp, vp, table, pos, q, _, _ = _paged_state(
+        bs, maxb, B=B, nkv=nkv, nq=nq, hd=hd, w=w, dtype=dtype, seed=47)
+    ks = vs = None
+    if pool in ("int8", "fp8"):
+        qd = jnp.int8 if pool == "int8" else jnp.float8_e4m3fn
+        kp, ks = quantize_blocks(kp, qd)
+        vp, vs = quantize_blocks(vp, qd)
+    fpa = (ap.fused_paged_online_attention if kernel == "fused_online"
+           else ap.fused_paged_attention)
+    grid = _paged_grid(
+        lambda q, kp, vp: fpa(q, kp, vp, table, pos, k_scale=ks,
+                              v_scale=vs, interpret=interpret,
+                              window=window), q, kp, vp)
+    assert grid == ((B, nkv) if axes == 2 else (B, nkv, maxb))
+
+
+def test_server_walk_share_follows_the_positions(params):
+    """`hbm_read_stats()` says how far the next step's bounded walk
+    goes: per live slot p // block + 1 entries of the table's width."""
+    from hpx_tpu.svc import performance_counters as pc
+    srv = ContinuousServer(params, CFG, slots=3, smax=64, paged=True,
+                           paged_kernel="fused", block_size=8)
+    st = srv.hbm_read_stats()
+    assert st["walk_share"] == 0.0 == st["walk_entries_per_slot"]
+    for r in REQS[:3]:
+        srv.submit(**r)
+    seen = 0
+    while srv.step():
+        live = srv.live_positions()
+        if not live:
+            continue
+        want = np.mean([p // 8 + 1 for p in live.values()])
+        st = srv.hbm_read_stats()
+        assert st["walk_entries_per_slot"] == pytest.approx(want)
+        assert st["walk_share"] == pytest.approx(want / (64 // 8))
+        assert st["walk_share"] == srv.cache_stats()["walk_share"]
+        # the registry carries the same two numbers
+        for name, key in (("count/walk-entries-per-slot",
+                           "walk_entries_per_slot"),
+                          ("walk-share", "walk_share")):
+            assert pc.query_counter(pc.counter_name(
+                "cache", name, srv.counter_instance)).value == st[key]
+        seen += 1
+    assert seen > 3
+
+
 @pytest.mark.parametrize("kern", ["gather", "fused", "fused_online"])
 @pytest.mark.parametrize("kvd", ["bf16", "int8", "fp8"])
 def test_flash_tune_paged_step_follows_pool_layout(kvd, kern):
@@ -284,6 +466,23 @@ def test_flash_tune_paged_step_follows_pool_layout(kvd, kern):
     # bf16 outputs: one ulp at |x| <= 1
     np.testing.assert_allclose(out, np.asarray(g(qg), np.float32),
                                atol=8e-3)
+
+
+@pytest.mark.parametrize("pos", [0, 20, 63], ids=lambda p: f"pos{p}")
+def test_flash_tune_paged_step_at_a_position(pos):
+    """`--positions`: every slot at one position, the table's tail on
+    the one trash block as the server lays it out, and the bytes one
+    call has to read counted over the entries reached."""
+    from benchmarks import flash_tune
+    heads = (4, 4, 2)
+    f, q, hbm = flash_tune.paged_step(jax, jnp, 64, 16, "bf16", "fused",
+                                      pos=pos, heads=heads)
+    g, qg, _ = flash_tune.paged_step(jax, jnp, 64, 16, "bf16", "gather",
+                                     pos=pos, heads=heads)
+    assert q.shape == (4, 1, 4, 128)
+    assert hbm == 2 * 4 * (pos // 16 + 1) * 16 * 2 * 128 * 2
+    np.testing.assert_allclose(np.asarray(f(q), np.float32),
+                               np.asarray(g(qg), np.float32), atol=8e-3)
 
 
 # -- row writes: scatter_token / scatter_window vs a NumPy row loop ---------
@@ -503,6 +702,27 @@ def test_server_fused_matches_dense_and_gather(params, reqs):
     fused, srv = _serve(params, reqs, paged=True, paged_kernel="fused")
     assert srv._paged_kernel == "fused"
     assert fused == gather == dense
+
+
+@pytest.mark.parametrize("mode", ["greedy", "sampled", "spec1", "spec2"])
+def test_server_bounded_walk_matches_dense_and_gather(mode):
+    """A model whose head is 128 wide serves `fused` through the
+    bounded walk (decode W = 1; the speculative verify window W = k +
+    1): the same tokens as the dense and the gather servers."""
+    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=2,
+                                head_dim=_HD, n_layers=2, d_ff=64)
+    p128 = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    reqs = SAMPLED if mode == "sampled" else REQS[:4]
+    kw = (dict(spec=True, spec_k=int(mode[-1]))
+          if mode.startswith("spec") else {})
+
+    def serve(**kw):
+        srv = ContinuousServer(p128, cfg, slots=3, smax=64, **kw)
+        for r in reqs:
+            srv.submit(**r)
+        return srv.run()
+    fused = serve(paged=True, paged_kernel="fused", **kw)
+    assert fused == serve(paged=True, paged_kernel="gather") == serve()
 
 
 @pytest.mark.parametrize("k", [1, 2])

@@ -7,8 +7,14 @@ covers interpret mode on CPU; this is the only place the actual
 Mosaic lowering (incl. the double-buffered online carry) is checked,
 so a regression fails a test instead of silently showing up as a
 serving numerics drift. Skipped only under JAX_PLATFORMS=cpu (see
-conftest). Head shapes: the small 4q/2kv x 64 one and the smoke's
-16q/4kv x 128."""
+conftest). Head shapes: the small 4q/2kv x 64 one (the grid walk: a
+head narrower than 128 lanes) and the smoke's 16q/4kv x 128, which
+over bf16 pools takes the walk bounded by each slot's live length
+(`_paged_live_kernel`, PR 31). Of that walk only the chip can show two
+things: that hundreds of copies in flight on one semaphore have ALL
+landed before the banks are read, and that the rows of the VMEM banks
+the PREVIOUS grid step left behind a short walk stay out of the
+result."""
 
 import jax
 import jax.numpy as jnp
@@ -155,3 +161,71 @@ class TestFusedPagedWindow:
         _close(run(True), run(False), 3e-2)
         # per-window-row horizon under the online (acc, m, l) carry
         _close(run("online"), run(False), 3e-2)
+
+
+class TestBoundedWalk:
+    """What only the chip can show of the walk bounded by `pos0`."""
+
+    @pytest.mark.parametrize("nkv,nq,maxb", [(2, 24, 128), (8, 48, 304)],
+                             ids=["sc2-3b", "laguna-full"])
+    def test_ragged_long_tables_match_gather(self, nkv, nq, maxb):
+        """The cells' table widths and head counts, ragged slots from
+        one block to the whole table: up to 2 x 304 copies in flight at
+        once on two semaphores, every one landed before the finish."""
+        from hpx_tpu.ops.paged_attention import paged_decode_attention
+        B, bs, hd = 8, 16, 128
+        nb = B * maxb + 1
+        kp, vp = _pools(nb, bs, nkv, hd, seed=20)
+        table = _table(B, maxb, nb, seed=21)
+        top = maxb * bs - 1
+        pos = jnp.asarray([0, 15, 16, 527, top // 3, top // 2, top - 16,
+                           top], jnp.int32)
+        rng = np.random.default_rng(22)
+        q = jnp.asarray(rng.standard_normal((B, 1, nq, hd), np.float32),
+                        jnp.bfloat16)
+        kn, vn = (jnp.asarray(
+            rng.standard_normal((B, nkv, hd), np.float32), jnp.bfloat16)
+            for _ in range(2))
+
+        def run(fused):
+            att, *_ = jax.jit(
+                lambda q, kn, vn, kp, vp: paged_decode_attention(
+                    q, kn, vn, kp, vp, table, pos, fused=fused)
+            )(q, kn, vn, kp, vp)
+            return np.asarray(att, np.float32)
+        want, got = run(False), run(True)
+        _close(got, want, 3e-2)
+        for _ in range(3):      # a race would not show every time
+            assert (run(True) == got).all()
+
+    @pytest.mark.parametrize("w", [1, 4])
+    @pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
+    def test_rows_the_last_grid_step_left_stay_out(self, window, w):
+        """Slot 0 walks its whole table over NaN blocks; the ragged
+        slots after it walk a few entries of sound ones: their rows of
+        the banks past the walk still hold slot 0's NaN, and their
+        outputs equal, bit for bit, the run in which slot 0's blocks
+        hold zeros."""
+        from hpx_tpu.ops.attention_pallas import fused_paged_attention
+        B, bs, maxb, nkv, nq, hd = 4, 16, 16, 2, 8, 128
+        nb = B * maxb + 1
+        kf, vf = _pools(nb, bs, nkv, hd, seed=30)
+        table = np.arange(1, nb, dtype=np.int32).reshape(B, maxb)
+        pos = np.asarray([maxb * bs - w, 3, 2 * bs, 5 * bs - 1], np.int32)
+        live = np.arange(maxb)[None, :] <= ((pos + w - 1) // bs)[:, None]
+        table = jnp.asarray(np.where(live, table, 0))    # tail: trash
+        rng = np.random.default_rng(31)
+        q = jnp.asarray(rng.standard_normal((B, w, nq, hd), np.float32),
+                        jnp.bfloat16)
+        first = np.arange(1, maxb + 1)                   # slot 0's blocks
+
+        def run(fill):
+            kp, vp = kf.at[first].set(fill), vf.at[first].set(fill)
+            return np.asarray(jax.jit(
+                lambda q, kp, vp: fused_paged_attention(
+                    q, kp, vp, table, jnp.asarray(pos), window=window)
+            )(q, kp, vp), np.float32)
+        bad, good = run(np.nan), run(0.0)
+        assert np.isnan(bad[0]).all()        # the poison was in the bank
+        assert np.isfinite(bad[1:]).all()
+        assert (bad[1:] == good[1:]).all()
