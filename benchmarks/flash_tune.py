@@ -1,4 +1,4 @@
-"""Flash-attention forward block-size autotune (real TPU).
+"""Flash-attention forward block-size tuning sweep (real TPU).
 
 Sweeps (block_q, block_k) per shape class — S in {2k, 4k, 8k, 16k},
 causal x non-causal at the bench head layout (N8 H128 bf16) — with the
@@ -18,7 +18,6 @@ hpx_tpu/ops/paged_blocks.json keyed ``hd<head_dim>x<kv_dtype>``, which
 a hard error, never a silent fall-through to bf16 byte accounting.
 
 Usage: python benchmarks/flash_tune.py [--quick] [--paged]
-                                       [--perfdb PATH]
                                        [--positions P[,P...]
                                         [--heads QxKV] [--smax S]]
   --quick: S in {2k, 4k} only and fewer samples (smoke/dev loops).
@@ -29,10 +28,6 @@ Usage: python benchmarks/flash_tune.py [--quick] [--paged]
     trash block as the server lays it out: 32 slots, 24 q / 2 kv heads
     (the StarCoder2-3B cell's shape). [--heads QxKV] [--smax S] give
     another cell's: 48x8 and 4864 (304 entries) are Laguna's full layers.
-  --perfdb PATH: with --paged, additionally bank every sweep point
-    into the persistent perf store (svc/perfdb) as provenance-stamped
-    observations, and each kv_dtype winner into its learned-blocks
-    tier — the producer half of benchmarks/ladder_search.py.
 """
 
 import functools
@@ -219,17 +214,8 @@ def paged_positions(jax, jnp, positions, heads, S) -> int:
     return 0
 
 
-def paged_main(jax, jnp, quick: bool, perfdb_path=None) -> int:
+def paged_main(jax, jnp, quick: bool) -> int:
     from hpx_tpu.ops.attention_pallas import _PAGED_BLOCKS_FILE
-    db = None
-    if perfdb_path:
-        # producer mode: every sweep point lands in the perfdb
-        # observation log (provenance-stamped from the live backend)
-        # and each kv_dtype winner in its learned-blocks tier —
-        # ladder_search re-derives the block table from these instead
-        # of trusting only the seed json
-        from hpx_tpu.svc.perfdb import PerfDB, PerfKey, device_kind
-        db = PerfDB(perfdb_path)
     S = 1024 if quick else 2048
     samples = 2 if quick else 3
     kernels = ("fused", "fused_online") if quick else _PAGED_KERNELS
@@ -237,7 +223,6 @@ def paged_main(jax, jnp, quick: bool, perfdb_path=None) -> int:
     table = {}
     for kvd in ("bf16", "int8", "fp8"):
         best = None                    # (us, block_size, kernel)
-        nmeas = 0
         for kern in kernels:
             for bs in (8, 16, 32, 64):
                 try:
@@ -256,31 +241,16 @@ def paged_main(jax, jnp, quick: bool, perfdb_path=None) -> int:
                                   "us_per_step": round(us, 1),
                                   "spread": round(spread, 3)}),
                       flush=True)
-                nmeas += 1
-                if db is not None:
-                    db.observe(
-                        PerfKey(device_kind(), f"paged.hd{H}.s{S}",
-                                kvd, kern),
-                        "paged_step_us", us, n=samples,
-                        program=f"bs{bs}", source="flash_tune")
                 if best is None or us < best[0]:
                     best = (us, bs, kern)
         if best:
             table[f"hd{H}x{kvd}"] = best[1]
             total = _bank(table, _PAGED_BLOCKS_FILE)
-            if db is not None:
-                from hpx_tpu.svc.perfdb import _default_stamps
-                db.record_block(f"hd{H}x{kvd}", {
-                    "block_size": best[1], "kernel": best[2],
-                    "samples": nmeas, **_default_stamps()})
-                db.save()   # after EVERY class — same incremental
-                            # discipline as _bank above
             print(json.dumps({"kv_dtype": kvd, "winner": best[1],
                               "kernel": best[2],
                               "us_per_step": round(best[0], 1),
                               "banked": total}), flush=True)
-    print(json.dumps({"wrote": _PAGED_BLOCKS_FILE, "new": len(table),
-                      "perfdb": perfdb_path}))
+    print(json.dumps({"wrote": _PAGED_BLOCKS_FILE, "new": len(table)}))
     return 0
 
 
@@ -307,7 +277,7 @@ def main() -> int:
             jax, jnp, [int(p) for p in _arg("--positions").split(",")],
             (32, nq, nkv), int(_arg("--smax") or 2048))
     if "--paged" in sys.argv:
-        return paged_main(jax, jnp, quick, perfdb_path=_arg("--perfdb"))
+        return paged_main(jax, jnp, quick)
 
     seqs = (2048, 4096) if quick else (2048, 4096, 8192, 16384)
     if shape_only:

@@ -142,23 +142,11 @@ PATH, loadable directly in chrome://tracing or https://ui.perfetto.dev.
                            prefill tokens, zero leaked device blocks
                            and zero leaked host buffers at drain.
 
- 12. serving_ladder      — the learned-ladder wave (--ladder): seed a
-                           perfdb (svc/perfdb) from a live profiled
-                           run of the mixed-unbucketed mix, re-derive
-                           the prefill ladder offline with
-                           benchmarks/ladder_search, then cold-boot
-                           (program cache cleared) the hand-picked
-                           and learned servers on the same mix.
-                           Reports warm tok/s + cold compile count
-                           for both, provenance-stamped, and GATES on
-                           sha-identical outputs — the ROADMAP item 5
-                           acceptance loop.
-
 Usage: python benchmarks/serving_bench.py [--cpu] [--scale N]
                                           [--prefix-only] [--spec-only]
                                           [--paged-decode-only] [--mesh]
                                           [--moe] [--chaos] [--disagg]
-                                          [--fleet] [--ladder]
+                                          [--fleet]
                                           [--tier] [--alerts]
                                           [--trace-out PATH]
                                           [--metrics-out PATH]
@@ -204,14 +192,6 @@ def metrics_artifact(histograms, counters=None,
             "counters": dict(counters or {})}
 
 
-def _configured_perfdb():
-    """The persistent perf store at ``hpx.perfdb.path``, or None when
-    unset.  Schema errors stay loud — a corrupt store must fail the
-    producer, not silently drop its medians."""
-    from hpx_tpu.svc import perfdb
-    return perfdb.configured_db()
-
-
 def write_metrics_artifact(path, doc):
     """Atomic write (tmp + rename) so a watcher never reads a torn
     artifact."""
@@ -235,7 +215,7 @@ def main() -> int:
         return 1
     # no persistent compile cache here, even where the environment
     # places one: cold compiles are a MEASURED quantity of this bench
-    # (compile counts, cold TTFT, the ladder search's compile_s), and
+    # (compile counts, cold TTFT), and
     # `_PROGRAMS.clear()` is a true cold boot only while no earlier
     # run's programs come back from disk
     jax.config.update("jax_enable_compilation_cache", False)
@@ -274,10 +254,8 @@ def main() -> int:
     # "<bench>/<name>" — e.g. the MoE wave's overflow-drop rate
     collected_counters = {}
     # per-wave cold/warm compile counts (utils/compilemon), keyed
-    # "<bench>[/<leg>]" -> {"cold": n, "warm": n}.  compilemon was
-    # already counting these for the JSON lines; the artifact used to
-    # DROP them, which made ladder wins unauditable — finish() now
-    # embeds the dict as the artifact's "compiles" section
+    # "<bench>[/<leg>]" -> {"cold": n, "warm": n}; finish() embeds
+    # the dict as the artifact's "compiles" section
     collected_compiles = {}
     # (label, chrome-doc) pairs from the fleet wave's worker rings —
     # finish() stitches them with the router tracer into ONE trace
@@ -1261,373 +1239,6 @@ def main() -> int:
                 flush=True)
             raise SystemExit(2)
 
-    # 8. the closed-loop tuner wave (--autotune): the same serving
-    # mixes, each run twice — once with the hand-tuned settings the waves above
-    # use, once from schema defaults with the online tuner live
-    # (hpx.tune.enable=1, svc/autotune). Three gates per mix: output
-    # byte-identity (the tuner moves only output-invariant knobs —
-    # divergence exits 2), the tuner actually evaluated, and the
-    # reported band check (auto warm tok/s and stall p99 within 5% of
-    # hand-tuned). Stall histograms land in collected_hists so
-    # --metrics-out feeds slo_gate.py --baseline.
-    def autotune_bench():
-        import hashlib
-
-        from hpx_tpu.core.config import runtime_config
-        from hpx_tpu.svc.metrics import HistogramCounter
-        rc = runtime_config()
-
-        mreqs = [(rng.integers(
-                      1, 1000, int(rng.integers(5, 150))).tolist(),
-                  int(rng.integers(16, 96))) for _ in range(12)]
-        shared = rng.integers(1, 1000, 64).tolist()
-        preqs = [(shared + rng.integers(1, 1000, 8).tolist(),
-                  int(rng.integers(16, 33))) for _ in range(12)]
-        sreqs = [(([11, 23, 7, 42] * 12)[:40], 48) for _ in range(4)] \
-            + [(rng.integers(1, 1000, 24).tolist(),
-                int(rng.integers(24, 49))) for _ in range(4)]
-        mixes = [
-            ("mixed", mreqs, dict(slots=4, smax=256),
-             "12 reqs plen5-149 (unbucketed) new16-96 over 4 slots"),
-            ("prefix", preqs, dict(slots=4, smax=160, paged=True),
-             "12 reqs 64-tok shared prefix + 8-tok tail, paged"),
-            ("spec", sreqs,
-             dict(slots=4, smax=128, spec=True, spec_k=4),
-             "4 periodic + 4 random reqs, prompt-lookup spec"),
-        ]
-
-        from hpx_tpu.utils.compilemon import count_compiles
-
-        def run(reqs, srv_kw, tune):
-            rc.set("hpx.tune.enable", "1" if tune else "0")
-            rc.set("hpx.tune.interval_ticks", "4")
-            try:
-                def once():
-                    srv = ContinuousServer(params, cfg, **srv_kw)
-                    for p, m in reqs:
-                        srv.submit(p, max_new=m)
-                    t0 = time.perf_counter()
-                    stalls = []
-                    alive = True
-                    while alive:
-                        s0 = time.perf_counter()
-                        alive = srv.step()
-                        stalls.append(time.perf_counter() - s0)
-                    secs = time.perf_counter() - t0
-                    out = dict(srv._done)
-                    srv._done.clear()
-                    return out, secs, stalls, srv
-
-                with count_compiles() as c_cold:
-                    once()                             # compile
-                with count_compiles() as c_warm:
-                    res = once()                       # warm
-                return res + (int(c_cold), int(c_warm))
-            finally:
-                rc.set("hpx.tune.enable", "0")
-
-        def sha(out):
-            return hashlib.sha256(json.dumps(
-                [out[r] for r in sorted(out)]).encode()).hexdigest()
-
-        for name, reqs, srv_kw, mix in mixes:
-            total = sum(m for _, m in reqs)
-            h_out, h_secs, h_stalls, _, h_cold, h_warm = \
-                run(reqs, srv_kw, False)
-            a_out, a_secs, a_stalls, a_srv, a_cold, a_warm = \
-                run(reqs, srv_kw, True)
-            collected_compiles[f"serving_autotune_{name}/hand"] = {
-                "cold": h_cold, "warm": h_warm}
-            collected_compiles[f"serving_autotune_{name}/auto"] = {
-                "cold": a_cold, "warm": a_warm}
-            # producer leg: with a store configured, the wave's warm
-            # medians land in the perfdb under the server's key — the
-            # "serving_bench --autotune" producer from ROADMAP item 5
-            pdb = _configured_perfdb()
-            if pdb is not None:
-                pdb.observe(a_srv.perf_key(), "warm_tok_s",
-                            total / a_secs,
-                            source=f"serving_bench/autotune_{name}")
-                pdb.save()
-            t = a_srv._tuner
-            hh, ha = HistogramCounter(), HistogramCounter()
-            for s in h_stalls:
-                hh.record(s)
-            for s in a_stalls:
-                ha.record(s)
-            collected_hists[
-                f"serving_autotune_{name}/decode_stall_hand"] = hh
-            collected_hists[
-                f"serving_autotune_{name}/decode_stall_auto"] = ha
-            h_tps, a_tps = total / h_secs, total / a_secs
-            h_p99 = float(np.percentile(h_stalls, 99))
-            a_p99 = float(np.percentile(a_stalls, 99))
-            identical = sha(a_out) == sha(h_out)
-            within = (a_tps >= 0.95 * h_tps
-                      and a_p99 <= 1.05 * max(h_p99, 1e-4))
-            emit(f"serving_autotune_{name}", total, a_secs,
-                 mix=mix,
-                 hand_tokens_per_s=round(h_tps, 1),
-                 hand_stall_p99_ms=round(1e3 * h_p99, 2),
-                 auto_stall_p99_ms=round(1e3 * a_p99, 2),
-                 tuner_evals=t.evals, tuner_probes=t.probes,
-                 tuner_accepts=t.accepts, tuner_reverts=t.reverts,
-                 tuned_knobs=t.knob_values(),
-                 output_identical=identical,
-                 within_band=within)
-            if not identical:
-                print(json.dumps({
-                    "error": "autotuned output diverged",
-                    "wave": name,
-                    "hand_sha": sha(h_out)[:16],
-                    "auto_sha": sha(a_out)[:16]}), flush=True)
-                raise SystemExit(2)
-            if t.evals == 0:
-                print(json.dumps({
-                    "error": "tuner never evaluated",
-                    "wave": name}), flush=True)
-                raise SystemExit(2)
-
-        # disagg leg: the same contract through the router — every
-        # in-proc worker gets its own tuner, joined to the router's
-        # TuneArbiter for the shared budgets
-        from hpx_tpu.models.disagg import DisaggRouter
-        dreqs = [(rng.integers(
-                      1, 1000, int(rng.integers(8, 64))).tolist(),
-                  int(rng.integers(16, 49))) for _ in range(10)]
-        dtotal = sum(m for _, m in dreqs)
-
-        def run_disagg(tune):
-            rc.set("hpx.tune.enable", "1" if tune else "0")
-            rc.set("hpx.tune.interval_ticks", "4")
-            try:
-                def once():
-                    r = DisaggRouter(params, cfg, prefill_workers=2,
-                                     decode_workers=2, slots=4,
-                                     smax=128)
-                    for p, m in dreqs:
-                        r.submit(p, m)
-                    t0 = time.perf_counter()
-                    out = r.run()
-                    secs = time.perf_counter() - t0
-                    hist = r.merged_hist()["decode_stall"]
-                    r.close()
-                    return out, secs, hist
-                once()                                 # compile
-                return once()                          # warm
-            finally:
-                rc.set("hpx.tune.enable", "0")
-
-        h_out, h_secs, h_hist = run_disagg(False)
-        a_out, a_secs, a_hist = run_disagg(True)
-        collected_hists["serving_autotune_disagg/"
-                        "decode_stall_hand"] = h_hist
-        collected_hists["serving_autotune_disagg/"
-                        "decode_stall_auto"] = a_hist
-        h_tps, a_tps = dtotal / h_secs, dtotal / a_secs
-        h_p99, a_p99 = h_hist.quantile(0.99), a_hist.quantile(0.99)
-        identical = sha(a_out) == sha(h_out)
-        emit("serving_autotune_disagg", dtotal, a_secs,
-             mix="10 reqs plen8-63 new16-48, 2 prefill x 2 decode",
-             hand_tokens_per_s=round(h_tps, 1),
-             hand_stall_p99_ms=round(1e3 * h_p99, 2),
-             auto_stall_p99_ms=round(1e3 * a_p99, 2),
-             output_identical=identical,
-             within_band=(a_tps >= 0.95 * h_tps
-                          and a_p99 <= 1.05 * max(h_p99, 1e-4)))
-        if not identical:
-            print(json.dumps({
-                "error": "autotuned output diverged",
-                "wave": "disagg",
-                "hand_sha": sha(h_out)[:16],
-                "auto_sha": sha(a_out)[:16]}), flush=True)
-            raise SystemExit(2)
-
-    # 12. the learned-ladder wave (--ladder): the full offline loop
-    # from ROADMAP item 5 in one wave. Seed a perfdb from a live
-    # profiled run of the mixed-unbucketed mix (the compile-storm
-    # shape), re-derive the ladder offline with
-    # benchmarks/ladder_search, then COLD-BOOT (program cache
-    # cleared) the hand-picked server and the learned one on the same
-    # mix and compare: warm tokens/s, total cold compile count, and
-    # sha-identical outputs (the ladder moves WORK, never tokens —
-    # divergence exits 2). Off-TPU the derivation carries
-    # builder-session provenance and is installed under
-    # --allow-session semantics, stamped on the emitted line.
-    def ladder_bench():
-        import hashlib
-        import tempfile
-
-        from hpx_tpu.core.config import runtime_config
-        from hpx_tpu.models.transformer import _PROGRAMS
-        from hpx_tpu.svc import perfdb as pdbm
-        from hpx_tpu.svc import progprof
-        from hpx_tpu.utils.compilemon import count_compiles
-        import ladder_search
-
-        rc = runtime_config()
-        db_path = (rc.get("hpx.perfdb.path", "") or "").strip() or \
-            os.path.join(tempfile.mkdtemp(prefix="hpx_perfdb_"),
-                         "perfdb.json")
-        rc.set("hpx.perfdb.path", db_path)
-        pdbm.reset_configured()
-
-        lreqs = [(rng.integers(
-                      1, 1000, int(rng.integers(5, 150))).tolist(),
-                  int(rng.integers(16, 96))) for _ in range(12)]
-        ltotal = sum(m for _, m in lreqs)
-
-        def drive(srv):
-            for p, m in lreqs:
-                srv.submit(p, max_new=m)
-            t0 = time.perf_counter()
-            while srv.step():
-                pass
-            secs = time.perf_counter() - t0
-            out = dict(srv._done)
-            srv._done.clear()
-            return out, secs
-
-        def sha(out):
-            return hashlib.sha256(json.dumps(
-                [out[r] for r in sorted(out)]).encode()).hexdigest()
-
-        # -- seed: a profiled cold run + a warm rerun bank the cost
-        # surface. progprof's per-program build times undercount the
-        # true minting cost wherever jit compiles lazily (first call,
-        # not build), so the seed ALSO banks the honest wave-level
-        # estimate: (cold - warm wall time) / programs minted —
-        # exactly what the search's amortization term needs.
-        own_prof = progprof.active_profiler() is None
-        prof = progprof.start_profiling() if own_prof else \
-            progprof.active_profiler()
-        _PROGRAMS.clear()
-        seed_srv = ContinuousServer(params, cfg, slots=4, smax=256)
-        _, seed_cold_s = drive(seed_srv)
-        warm_srv = ContinuousServer(params, cfg, slots=4, smax=256)
-        _, seed_warm_s = drive(warm_srv)
-        db = pdbm.configured_db()
-        key = seed_srv.perf_key()
-        pdbm.bank_profile(db, prof.profile_table(), key)
-        misses = seed_srv._prog_misses
-        if seed_cold_s > seed_warm_s and misses:
-            db.observe(key, "compile_s",
-                       (seed_cold_s - seed_warm_s) / misses,
-                       n=misses, source="serving_bench/ladder_seed")
-        db.observe(key, "warm_tok_s", ltotal / seed_warm_s,
-                   source="serving_bench/ladder_seed")
-        # prefill-only probe: the wall-clock share of a full run spent
-        # prefilling is what the search's padded-work term scales by
-        # (per-call exec timers see async dispatch, not compute, so
-        # they cannot price padding — wall-clock can)
-        probe_srv = ContinuousServer(params, cfg, slots=4, smax=256)
-        for p, _ in lreqs:
-            probe_srv.submit(p, max_new=1)
-        t0 = time.perf_counter()
-        while probe_srv.step():
-            pass
-        probe_s = time.perf_counter() - t0
-        db.observe(key, "prefill_frac",
-                   min(1.0, probe_s / seed_warm_s),
-                   source="serving_bench/ladder_seed")
-        # per-rung chunk-demand histogram: how many prefill chunks
-        # this mix lands on each rung of the ladder it ran under.
-        # The offline search re-prices candidate ladders against THIS
-        # demand (a candidate rung's cost is the demand that rounds
-        # up into it), not a uniform length assumption — remainder
-        # chunks of long prompts pile onto the small rungs.
-        demand = {}
-        for p, _ in lreqs:
-            n = len(p)
-            while n > 0:
-                step = min(n, seed_srv.prefill_chunk)
-                rung = next(b for b in seed_srv.prefill_buckets
-                            if b >= step)
-                demand[rung] = demand.get(rung, 0) + 1
-                n -= step
-        for rung in sorted(demand):
-            db.observe(key, "chunk_demand", float(demand[rung]),
-                       program=f"r{rung}",
-                       source="serving_bench/ladder_seed")
-        db.save()
-        if own_prof:
-            progprof.stop_profiling()
-
-        # -- offline search (the serving path never explores) --------
-        search_argv = ["ladder_search", "--db", db_path,
-                       "--key", seed_srv.perf_key(),
-                       "--allow-session"]
-        argv0 = sys.argv
-        try:
-            sys.argv = search_argv
-            rcode = ladder_search.main()
-        finally:
-            sys.argv = argv0
-        if rcode != 0:
-            print(json.dumps({"error": "ladder_search derived "
-                              "nothing", "exit": rcode}), flush=True)
-            raise SystemExit(2)
-        pdbm.reset_configured()
-        proposal = pdbm.configured_db().ladder(seed_srv.perf_key())
-
-        # -- cold-boot A/B: hand-picked vs learned -------------------
-        # _PROGRAMS.clear() makes each leg a TRUE cold boot (the
-        # seeding run would otherwise have pre-minted both ladders'
-        # programs and the compile comparison would read 0 == 0)
-        def leg(use_learned):
-            rc.set("hpx.perfdb.use_learned_ladders",
-                   "1" if use_learned else "0")
-            rc.set("hpx.perfdb.allow_session", "1")
-            _PROGRAMS.clear()
-            with count_compiles() as c_cold:
-                srv = ContinuousServer(params, cfg, slots=4, smax=256)
-                out_cold, _ = drive(srv)
-            srv = ContinuousServer(params, cfg, slots=4, smax=256)
-            with count_compiles() as c_warm:
-                out, secs = drive(srv)
-            # warm tok/s = best of 3 drives: the noise-floor estimate
-            # (identical deterministic work each drive; min wall time
-            # is the least-perturbed sample)
-            for _ in range(2):
-                out2, secs2 = drive(srv)
-                assert sha(out2) == sha(out)
-                secs = min(secs, secs2)
-            rc.set("hpx.perfdb.use_learned_ladders", "0")
-            return (out, secs, int(c_cold), int(c_warm),
-                    srv.prefill_buckets, out_cold)
-
-        h_out, h_secs, h_cold, h_warm, h_buckets, h_out_c = leg(False)
-        l_out, l_secs, l_cold, l_warm, l_buckets, l_out_c = leg(True)
-        collected_compiles["serving_ladder/hand"] = {
-            "cold": h_cold, "warm": h_warm}
-        collected_compiles["serving_ladder/learned"] = {
-            "cold": l_cold, "warm": l_warm}
-        h_tps, l_tps = ltotal / h_secs, ltotal / l_secs
-        identical = (sha(l_out) == sha(h_out)
-                     and sha(l_out_c) == sha(h_out_c))
-        stamps = pdbm._default_stamps()
-        emit("serving_ladder", ltotal, l_secs,
-             mix="12 reqs plen5-149 (unbucketed) new16-96 over 4 "
-                 "slots, hand vs learned ladder",
-             hand_tokens_per_s=round(h_tps, 1),
-             learned_tokens_per_s=round(l_tps, 1),
-             hand_compiles_cold=h_cold,
-             learned_compiles_cold=l_cold,
-             hand_buckets=list(h_buckets),
-             learned_buckets=list(l_buckets),
-             ladder_samples=proposal["samples"] if proposal else 0,
-             learned_beats_default=(l_tps > h_tps
-                                    and l_cold < h_cold),
-             output_identical=identical,
-             onchip=stamps["onchip"],
-             provenance=stamps["provenance"])
-        if not identical:
-            print(json.dumps({
-                "error": "learned-ladder output diverged",
-                "hand_sha": sha(h_out)[:16],
-                "learned_sha": sha(l_out)[:16]}), flush=True)
-            raise SystemExit(2)
-
     def finish() -> int:
         if tracer is not None:
             from hpx_tpu.svc import tracing
@@ -1715,14 +1326,6 @@ def main() -> int:
 
     if "--fleet" in sys.argv:
         fleet_bench()
-        return finish()
-
-    if "--autotune" in sys.argv:
-        autotune_bench()
-        return finish()
-
-    if "--ladder" in sys.argv:
-        ladder_bench()
         return finish()
 
     if "--alerts" in sys.argv:

@@ -31,9 +31,9 @@ Per-file tier (rules.py) — each rule sees one parsed file:
   ``/object{locality#N/instance}/counter`` registry grammar, and bare
   ``h.record()`` statements that drop the histogram timing context
   manager unrecorded.
-* HPX018 tunable-knob-mutation — direct writes to the knob attributes
-  backing ``tunable=`` config keys outside ``__init__`` /
-  ``_reload_knobs`` (they race the adaptive tuner; see svc/autotune).
+* HPX018 knob-mutation — direct writes to the attributes behind
+  ``models/serving._RELOADABLE_KNOBS`` outside ``__init__`` /
+  ``_reload_knobs`` (they can land mid-step).
 
 Whole-program tier (project.py) — every file is parsed once into a
 shared :class:`~.project.ProjectIndex` (symbol table, class-level lock

@@ -1072,56 +1072,52 @@ class ProgramCacheBypassRule(Rule):
         yield from out
 
 
-# instance attributes backed by declared-tunable config keys
-# (``tunable=`` markers in core/config_schema.py) — the knob map
-# svc/autotune.server_tuner binds.  Keyed attr -> backing config key
-# so the finding names both.
-_TUNABLE_KNOB_ATTRS = {
+# the instance attributes behind models/serving._RELOADABLE_KNOBS: the
+# knobs a live server re-reads from the runtime config at its flush
+# boundary.  Keyed attr -> backing config key so the finding names
+# both.
+_RELOADABLE_KNOB_ATTRS = {
     "prefill_chunk": "hpx.serving.prefill_chunk",
     "_max_async": "hpx.serving.max_async_steps",
-    "_spec_k": "hpx.serving.spec.k",
     "_ckpt_every": "hpx.serving.ckpt_every",
+    "_spec_k": "hpx.serving.spec.k",
+    "_moe_capacity_pct": "hpx.serving.moe.capacity_factor",
     "budget_blocks": "hpx.cache.radix_budget_blocks",
-    "max_queue": "hpx.serving.disagg.max_queue",
+    "budget_bytes": "hpx.cache.tier.host_budget_mb",
 }
 
-# the config actuation path: construction reads the schema default,
+# the config actuation path: construction reads the config,
 # _reload_knobs() applies operator config writes at the flush
-# boundary.  Everything else must go through the runtime config (or
-# the AdaptiveTuner, whose KnobBinding setters live in svc/autotune).
-_TUNE_SANCTIONED_FUNCS = {"__init__", "_reload_knobs"}
+# boundary.  Everything else must go through the runtime config.
+_KNOB_SANCTIONED_FUNCS = {"__init__", "_reload_knobs"}
 
 
 @register
-class TunableKnobMutationRule(Rule):
-    """HPX018: direct mutation of an adaptive-tuner-owned knob
-    attribute outside the config actuation path.
+class KnobMutationRule(Rule):
+    """HPX018: direct mutation of a reloadable knob attribute outside
+    the config actuation path.
 
-    The serving knobs the online tuner owns (``prefill_chunk``,
-    ``_max_async``, ``_spec_k``, ``_ckpt_every``, ``budget_blocks``,
-    ``max_queue`` — the attributes backing the ``tunable=`` keys in
-    ``core/config_schema``) change ONLY at the flush/admit boundary:
-    construction reads the schema default, ``_reload_knobs()`` applies
-    operator config writes, and ``svc/autotune``'s KnobBinding setters
-    actuate tuner probes.  A write anywhere else races the controller
-    — the tuner's next probe silently reverts it, its decision log no
-    longer explains the live value, and flight-bundle replay diverges
-    from what actually ran.  Fix: route the change through
-    ``runtime_config().set(...)`` (picked up at the next flush) or
-    declare the attribute's owner a tuner binding in svc/autotune.
+    The serving knobs a live server re-reads (``prefill_chunk``,
+    ``_max_async``, ``_ckpt_every``, ``_spec_k``,
+    ``_moe_capacity_pct``, ``budget_blocks``, ``budget_bytes`` — the
+    attributes behind ``models/serving._RELOADABLE_KNOBS``) change
+    ONLY at the flush boundary, where no step is in flight:
+    construction reads the config and ``_reload_knobs()`` applies
+    operator config writes.  A write anywhere else can tear a
+    dispatched program's geometry, and the live value no longer
+    matches what the runtime config (and a flight bundle's config
+    dump) says it is.  Fix: route the change through
+    ``runtime_config().set(...)`` (picked up at the next flush).
     """
 
     id = "HPX018"
-    name = "tunable-knob-mutation"
+    name = "knob-mutation"
     severity = "warning"
 
     _SCOPE = ("hpx_tpu/models/", "hpx_tpu/svc/")
 
     def check(self, ctx: FileContext) -> Iterable[Finding]:
         if not ctx.in_subpath(*self._SCOPE):
-            return
-        # the tuner's KnobBinding setters ARE the actuation path
-        if ctx.display_path.endswith("svc/autotune.py"):
             return
         out: List[Finding] = []
 
@@ -1138,38 +1134,36 @@ class TunableKnobMutationRule(Rule):
                     targets = [child.target]
                 for t in targets:
                     if isinstance(t, ast.Attribute) \
-                            and t.attr in _TUNABLE_KNOB_ATTRS \
-                            and child_scope not in _TUNE_SANCTIONED_FUNCS:
-                        key = _TUNABLE_KNOB_ATTRS[t.attr]
+                            and t.attr in _RELOADABLE_KNOB_ATTRS \
+                            and child_scope not in _KNOB_SANCTIONED_FUNCS:
+                        key = _RELOADABLE_KNOB_ATTRS[t.attr]
                         out.append(self.finding(
                             ctx, child,
-                            f"direct write to tuner-owned knob "
+                            f"direct write to reloadable knob "
                             f"attribute `{t.attr}` (backing {key}) in "
                             f"{child_scope}() bypasses the config "
-                            "actuation path — it races the adaptive "
-                            "tuner and breaks flight-bundle replay; "
-                            "route it through runtime_config().set() "
-                            "(applied by _reload_knobs at the next "
-                            "flush) or a svc/autotune KnobBinding"))
+                            "actuation path — it can land mid-step "
+                            "and leaves the live value out of step "
+                            "with the runtime config; route it "
+                            "through runtime_config().set() (applied "
+                            "by _reload_knobs at the next flush)"))
                 walk(child, child_scope)
 
         walk(ctx.tree, "<module>")
         yield from out
 
 
-# shape-ladder knobs with a resolver chain: explicit operator config,
-# then the perfdb learned tier, then the declared schema default.
-# Keyed param/kwarg name -> the chain a baked literal bypasses.
+# shape-ladder knobs with a resolver: explicit operator config, then
+# the constant (for the block size: the measured table, then the
+# constant).  Keyed param/kwarg name -> what a baked literal bypasses.
 _SHAPE_KNOB_PARAMS = {
-    "block_size": "hpx.paged.block_size + the perfdb learned-blocks "
-                  "tier (ops.attention_pallas.resolve_paged_block)",
-    "prefill_chunk": "hpx.serving.prefill_chunk + the perfdb "
-                     "learned-ladder tier",
-    "prefill_buckets": "hpx.serving.prefill_buckets + the perfdb "
-                       "learned-ladder tier",
-    "spec_k": "hpx.serving.spec.k + the perfdb learned-ladder tier",
-    "page_size": "hpx.paged.block_size + the perfdb learned-blocks "
-                 "tier",
+    "block_size": "hpx.cache.block_size, then "
+                  "ops.attention_pallas.resolve_paged_block",
+    "prefill_chunk": "hpx.serving.prefill_chunk",
+    "prefill_buckets": "hpx.serving.prefill_buckets",
+    "spec_k": "hpx.serving.spec.k",
+    "page_size": "hpx.cache.block_size, then "
+                 "ops.attention_pallas.resolve_paged_block",
 }
 
 
@@ -1190,17 +1184,16 @@ class BakedShapeConstantRule(Rule):
     literal in a parameter default or call-site keyword inside
     ``models/``/``svc/``/``ops/``.
 
-    These knobs have three legitimate sources, consulted in order:
-    explicit operator config (``hpx.serving.*``/``hpx.paged.*``), the
-    perfdb learned tier (``hpx.perfdb.use_learned_ladders`` — the
-    geometry benchmarks/ladder_search.py re-derived from measured
-    costs), and the declared schema default.  A literal baked at a
-    signature or call site silently pins the geometry for every
-    caller: the learned ladder never applies there, and two
+    These knobs are decided in ONE place each: explicit operator
+    config (``hpx.serving.*``/``hpx.cache.*``), else the declared
+    default (for the block size: ``resolve_paged_block``'s measured
+    table, then 16).  A literal baked at a signature or call site
+    silently pins the geometry for every caller: an operator's
+    setting or a measured table entry never applies there, and two
     components can disagree about a shape they must share (a prefill
     worker emitting 16-row segments into a decode pool tuned to 32).
-    Fix: default the parameter to ``None`` and resolve through the
-    chain (``resolve_paged_block``, ``_resolve_buckets``), or thread
+    Fix: default the parameter to ``None`` and resolve
+    (``resolve_paged_block``, ``_resolve_buckets``), or thread
     the owning component's already-resolved value.  A deliberate bake
     (reference path, fixed-geometry kernel) carries ``# hpxlint:
     disable=HPX024 — <why>`` or a baseline entry with justification.
@@ -1232,7 +1225,7 @@ class BakedShapeConstantRule(Rule):
                             ctx, default,
                             f"parameter `{param.arg}` of "
                             f"{node.name}() bakes a shape constant "
-                            "in its default — the resolver chain "
+                            "in its default — the resolver "
                             f"({_SHAPE_KNOB_PARAMS[param.arg]}) "
                             "never applies for callers that omit "
                             "it; default to None and resolve, or "
@@ -1246,7 +1239,7 @@ class BakedShapeConstantRule(Rule):
                             f"call-site keyword `{kw.arg}` bakes a "
                             "shape constant — it pins this "
                             "component's geometry against the "
-                            "resolver chain "
+                            "resolver "
                             f"({_SHAPE_KNOB_PARAMS[kw.arg]}); pass "
                             "the resolved value (or omit the "
                             "keyword and let the callee resolve)")

@@ -39,10 +39,6 @@ MoE servers (``cfg.n_experts > 0``) add the expert-routing feed::
                                                       decode step and sparse
                                                       layer (mean since start)
 
-Tuned servers (``hpx.tune.enable``) add the closed-loop controller's
-accounting — ``/serving{...}/tune/ticks``, ``tune/evals``,
-``tune/probes``, ``tune/accepts``, ``tune/reverts``, ``tune/holds``.
-
 Paged servers additionally export the cache counters::
 
     /cache{locality#L/server#i}/hit-rate                radix prefix hit rate
@@ -208,24 +204,6 @@ def register_server(srv) -> str:
         put("serving", "moe/experts-hit",
             pc.CallbackCounter(_read(ref, lambda s: (
                 s._moe_hit_sum / s._moe_steps if s._moe_steps else 0.0))))
-
-    if getattr(srv, "_tuner", None) is not None:
-        # closed-loop tuner observability (svc/autotune): tick/probe/
-        # accept/revert totals — /serving{...}/tune/*. The default
-        # hpx.trace.counters pattern /serving* samples these too, so
-        # a trace shows tuner activity alongside the decode track.
-        put("serving", "tune/ticks",
-            pc.CallbackCounter(_read(ref, lambda s: s._tuner.ticks)))
-        put("serving", "tune/evals",
-            pc.CallbackCounter(_read(ref, lambda s: s._tuner.evals)))
-        put("serving", "tune/probes",
-            pc.CallbackCounter(_read(ref, lambda s: s._tuner.probes)))
-        put("serving", "tune/accepts",
-            pc.CallbackCounter(_read(ref, lambda s: s._tuner.accepts)))
-        put("serving", "tune/reverts",
-            pc.CallbackCounter(_read(ref, lambda s: s._tuner.reverts)))
-        put("serving", "tune/holds",
-            pc.CallbackCounter(_read(ref, lambda s: s._tuner.holds)))
 
     if getattr(srv, "_alerts", None) is not None:
         # SLO burn-rate alerting (svc/slo_alerts): evaluation and

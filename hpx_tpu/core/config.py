@@ -264,7 +264,7 @@ class Configuration:
 
     def generation(self) -> int:
         """Change counter: bumped by every set(). A live server caches
-        this and re-reads its tunable knobs at the next flush boundary
+        this and re-reads its reloadable knobs at the next flush boundary
         when it moved (see ContinuousServer._reload_knobs)."""
         with self._lock:
             return self._gen

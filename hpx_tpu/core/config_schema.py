@@ -31,28 +31,6 @@ _VALID_TYPES = ("str", "int", "bool", "float")
 
 
 @dataclasses.dataclass(frozen=True)
-class Tunable:
-    """Live-tuning contract for one knob (the ``tunable=`` field).
-
-    Declaring a knob tunable asserts two things: moving it at a safe
-    boundary (flush/admit tick, never mid-step) cannot change emitted
-    tokens (output invariance — the sha-identity tests pin this), and
-    the adaptive tuner may move it inside ``[lo, hi]`` one bounded
-    ``step`` at a time.  ``geometric=True`` steps multiply/divide by
-    ``step`` instead of adding/subtracting it (power-of-two ladders).
-    ``compiles=True`` marks a knob whose move can mint new jit
-    programs — the tuner charges such moves their measured compile
-    time (svc/progprof) and only keeps them when the projected
-    steady-state win amortizes it."""
-
-    lo: int
-    hi: int
-    step: int = 1
-    geometric: bool = False
-    compiles: bool = False
-
-
-@dataclasses.dataclass(frozen=True)
 class ConfigKey:
     """One declared configuration knob."""
 
@@ -66,8 +44,6 @@ class ConfigKey:
     # the valid set in the error — a typo'd kv_dtype=fp8_e5m2 fails at
     # the set, not as a silently-ignored setting downstream.
     choices: Optional[Tuple[str, ...]] = None
-    # non-None marks the knob safe for the online tuner (svc/autotune)
-    tunable: Optional[Tunable] = None
 
 
 _SCHEMA: Dict[str, ConfigKey] = {}
@@ -75,12 +51,10 @@ _SCHEMA: Dict[str, ConfigKey] = {}
 
 def declare(key: str, type: str, default: Optional[str], doc: str,
             reserved: bool = False,
-            choices: Optional[Tuple[str, ...]] = None,
-            tunable: Optional[Tunable] = None) -> ConfigKey:
+            choices: Optional[Tuple[str, ...]] = None) -> ConfigKey:
     """Register one knob; duplicate keys and unknown types are errors.
     ``choices`` declares a closed value set for enumerated str knobs
-    (the declared default must be a member); ``tunable`` declares the
-    knob safe for online auto-tuning with its bounds/step contract."""
+    (the declared default must be a member)."""
     if type not in _VALID_TYPES:
         raise ValueError(f"config key {key!r}: bad type {type!r} "
                          f"(expected one of {_VALID_TYPES})")
@@ -94,21 +68,7 @@ def declare(key: str, type: str, default: Optional[str], doc: str,
         if default is not None and default not in choices:
             raise ValueError(f"config key {key!r}: default {default!r} "
                              f"not in choices {choices}")
-    if tunable is not None:
-        if type not in ("int", "str"):
-            # str covers "auto"-defaulted knobs whose live values are
-            # integers (radix budget); bool/float knobs have no bounded
-            # step semantics the tuner understands
-            raise ValueError(f"config key {key!r}: tunable= needs an "
-                             "int-valued knob (type 'int' or 'str')")
-        if tunable.lo > tunable.hi:
-            raise ValueError(f"config key {key!r}: tunable lo "
-                             f"{tunable.lo} > hi {tunable.hi}")
-        if tunable.step < (2 if tunable.geometric else 1):
-            raise ValueError(f"config key {key!r}: tunable step "
-                             f"{tunable.step} too small")
-    entry = ConfigKey(key, type, default, doc, reserved, choices,
-                      tunable)
+    entry = ConfigKey(key, type, default, doc, reserved, choices)
     _SCHEMA[key] = entry
     return entry
 
@@ -131,12 +91,6 @@ def defaults() -> Dict[str, str]:
     exactly the declared keys that carry a non-None default."""
     return {k: e.default for k, e in _SCHEMA.items()
             if e.default is not None}
-
-
-def tunable_keys() -> Dict[str, ConfigKey]:
-    """The declared tunable subset (key -> ConfigKey) — the ONLY knobs
-    the adaptive tuner may ever move."""
-    return {k: e for k, e in _SCHEMA.items() if e.tunable is not None}
 
 
 # ---------------------------------------------------------------------------
@@ -245,13 +199,13 @@ declare("hpx.counters.print_interval", "float", None,
 
 # -- KV cache ---------------------------------------------------------------
 declare("hpx.cache.block_size", "str", "auto",
-        "KV tokens per paged block (auto: HPX_PAGED_BLOCK env, then the "
-        "table banked by benchmarks/flash_tune.py --paged, then 16)")
+        "KV tokens per paged block (auto: HPX_PAGED_BLOCK env, then "
+        "ops/paged_blocks.json as written by benchmarks/flash_tune.py "
+        "--paged, then 16: ops.attention_pallas.resolve_paged_block)")
 declare("hpx.cache.num_blocks", "str", "auto",
         "pool size (auto: 2x worst case)")
 declare("hpx.cache.radix_budget_blocks", "str", "auto",
-        "prefix-tree HBM budget",
-        tunable=Tunable(lo=8, hi=1 << 20, step=2, geometric=True))
+        "prefix-tree HBM budget")
 declare("hpx.cache.prefix_reuse", "bool", "1",
         "radix prefix matching on admit")
 declare("hpx.cache.kv_dtype", "str", "bf16",
@@ -264,8 +218,7 @@ declare("hpx.cache.tier.enable", "bool", "0",
         "quantized bytes + scale sidecars) to pooled host buffers "
         "instead of dropping them")
 declare("hpx.cache.tier.host_budget_mb", "int", "256",
-        "host tier byte budget; LRU-to-oblivion past it",
-        tunable=Tunable(lo=1, hi=1 << 20, step=2, geometric=True))
+        "host tier byte budget; LRU-to-oblivion past it")
 declare("hpx.cache.tier.min_speedup", "float", "1.0",
         "promote only when estimated re-prefill time exceeds restore "
         "time by this factor")
@@ -287,20 +240,16 @@ declare("hpx.serving.paged_kernel", "str", "auto",
         "tolerance-budgeted vs the oracle, VMEM no longer bounds smax)",
         choices=("auto", "gather", "fused", "fused_online"))
 declare("hpx.serving.prefill_chunk", "int", "128",
-        "prompt tokens per prefill chunk",
-        tunable=Tunable(lo=16, hi=1024, step=2, geometric=True,
-                        compiles=True))
+        "prompt tokens per prefill chunk")
 declare("hpx.serving.prefill_buckets", "str", "auto",
         "chunk-width ladder (csv|auto)")
 declare("hpx.serving.async_dispatch", "bool", "1",
         "decode without per-step sync")
 declare("hpx.serving.max_async_steps", "int", "32",
-        "buffered steps before a sync",
-        tunable=Tunable(lo=1, hi=256, step=2, geometric=True))
+        "buffered steps before a sync")
 declare("hpx.serving.spec.enable", "bool", "0",
         "speculative decode in serving")
-declare("hpx.serving.spec.k", "int", "4", "draft tokens per slot per step",
-        tunable=Tunable(lo=1, hi=16, step=1))
+declare("hpx.serving.spec.k", "int", "4", "draft tokens per slot per step")
 declare("hpx.serving.spec.draft", "str", "prompt",
         "draft source: prompt | model")
 declare("hpx.serving.spec.ngram", "int", "3",
@@ -312,8 +261,7 @@ declare("hpx.serving.spec.adapt", "bool", "1",
 declare("hpx.serving.spec.max_verify_faults", "int", "2",
         "verify faults before speculation self-disables")
 declare("hpx.serving.ckpt_every", "int", "16",
-        "tokens between slot checkpoints",
-        tunable=Tunable(lo=4, hi=256, step=2, geometric=True))
+        "tokens between slot checkpoints")
 declare("hpx.serving.step_retries", "int", "4",
         "step attempts before shedding")
 declare("hpx.serving.retry_backoff_s", "float", "0.005",
@@ -323,8 +271,7 @@ declare("hpx.serving.admit_retries", "int", "8",
 declare("hpx.serving.default_deadline_s", "float", "0",
         "per-request deadline (0=none)")
 declare("hpx.serving.disagg.max_queue", "int", None,
-        "disaggregated router: bound on queued prefill jobs",
-        tunable=Tunable(lo=4, hi=1024, step=2, geometric=True))
+        "disaggregated router: bound on queued prefill jobs")
 declare("hpx.serving.disagg.pump_steps", "int", None,
         "decode steps per disagg pump iteration")
 declare("hpx.serving.disagg.prefill_jobs", "int", None,
@@ -335,9 +282,7 @@ declare("hpx.serving.moe.capacity_factor", "int", "0",
         "MoE decode expert capacity factor as an integer PERCENT "
         "(100 = GShard cf 1.0; C = ceil(T*k*pct/100 / E)); 0 = auto = "
         "drop-free (cf = n_experts), the token-identity default. "
-        "Lower trades overflow drops for smaller expert exchanges",
-        tunable=Tunable(lo=100, hi=6400, step=2, geometric=True,
-                        compiles=True))
+        "Lower trades overflow drops for smaller expert exchanges")
 declare("hpx.serving.mesh.paged", "bool", "1",
         "sharded paged serving (0 restores the single-device refusal)")
 declare("hpx.serving.mesh.table_residency", "str", "sharded",
@@ -415,26 +360,6 @@ declare("hpx.prof.peak_gflops", "float", "0",
         "roofline denominator in GFLOP/s (0 = infer from device kind; "
         "unknown kinds report roofline fraction 0)")
 
-# -- persistent perf database (svc/perfdb) ----------------------------------
-declare("hpx.perfdb.path", "str", "",
-        "versioned cross-run performance store (JSON); empty = no "
-        "store — producers no-op, consumers fall back to constants")
-declare("hpx.perfdb.use_learned_ladders", "bool", "0",
-        "boot-time consult of the perfdb ladders: on a key hit with "
-        "enough samples the server overrides the hand-picked serving "
-        "ladder defaults; off (or on a miss) it is byte-identical to "
-        "the constants")
-declare("hpx.perfdb.min_samples", "int", "3",
-        "samples a learned ladder/block entry needs before a cold "
-        "boot trusts it (below = counted stale, constants win)")
-declare("hpx.perfdb.record", "bool", "0",
-        "bank the live progprof table into the perfdb on "
-        "stop_profiling() (needs hpx.perfdb.path)")
-declare("hpx.perfdb.allow_session", "bool", "0",
-        "accept builder-session-provenance ladders at boot (default "
-        "off: only on-chip-derived ladders override constants — same "
-        "discipline as bench.py medians)")
-
 # -- flight recorder (svc/flight) -------------------------------------------
 declare("hpx.flight.enabled", "bool", "1",
         "fault flight recorder master switch (lazy: allocates nothing "
@@ -445,34 +370,6 @@ declare("hpx.flight.max_bundles", "int", "8",
         "bundles retained on disk (oldest pruned first)")
 declare("hpx.flight.spans", "int", "256",
         "last-N trace spans captured into each bundle")
-
-# -- adaptive tuner (svc/autotune) ------------------------------------------
-declare("hpx.tune.enable", "bool", "0",
-        "closed-loop auto-tuning of the tunable serving knobs (off by "
-        "default: enabling it must be an operator decision)")
-declare("hpx.tune.interval_ticks", "int", "32",
-        "flush ticks between tuner evaluations (tick-counted, not "
-        "wall-clock, so decisions replay deterministically)")
-declare("hpx.tune.w_tokens", "float", "1.0",
-        "objective weight on decayed decode tokens/s")
-declare("hpx.tune.w_stall", "float", "100.0",
-        "objective weight on the decode-stall p99 (seconds) delta")
-declare("hpx.tune.w_queue", "float", "0.05",
-        "objective weight on admission queue depth")
-declare("hpx.tune.hysteresis_pct", "float", "5",
-        "relative objective improvement (percent) a probe must show "
-        "before its knob move is kept (anti-thrash band)")
-declare("hpx.tune.cooldown_ticks", "int", "2",
-        "evaluation intervals a knob is held after a reverted probe")
-declare("hpx.tune.freeze", "str", "",
-        "csv knob names the tuner must never move")
-declare("hpx.tune.compile_amortize_s", "float", "30",
-        "amortization horizon: a compile-minting move is kept only if "
-        "its projected win over this many seconds covers the measured "
-        "compile cost")
-declare("hpx.tune.seed", "int", "0",
-        "deterministic probe-order seed (rotates the round-robin "
-        "starting knob)")
 
 # -- live observability (svc/exemplars, svc/slo_alerts, svc/opsplane) ------
 declare("hpx.obs.port", "int", "-1",

@@ -152,11 +152,11 @@ class PrefillWorker(_WorkerRing):
                  block_size: Optional[int] = None,
                  **server_kwargs) -> None:
         if block_size is None:
-            # the decode pool's geometry authority (env > perfdb
-            # learned tier > seed table > default) — emitted segments
-            # must match the pool the router splices them into
+            # the decode pool's geometry authority (env > seed table
+            # > default) — emitted segments must match the pool the
+            # router splices them into
             from ..ops.attention_pallas import resolve_paged_block
-            block_size = resolve_paged_block(cfg.head_dim)
+            block_size = resolve_paged_block(cfg.head_dim)[0]
         self.block_size = int(block_size)
         self._eng = ContinuousServer(params, cfg, slots=1, smax=smax,
                                      paged=False, async_dispatch=False,
@@ -593,19 +593,6 @@ class DisaggRouter:
                     locality=0)
                 for _ in range(prefill_workers)]
         self._prefill = list(prefill_handles)
-        # closed-loop tuning under a router: each embedded server
-        # already built its own tuner (hpx.tune.enable); the in-proc
-        # ones join ONE router-level arbiter so the prefill and decode
-        # sides never probe a shared-budget knob (radix HBM budget,
-        # queue bound) concurrently — two workers growing one budget
-        # together would double-spend it and corrupt each other's
-        # probe measurements
-        from ..svc.autotune import TuneArbiter, attach_arbiter
-        self._tune_arbiter = TuneArbiter()
-        for i, h in enumerate(self._decode):
-            attach_arbiter(h, self._tune_arbiter, f"decode#{i}")
-        for i, h in enumerate(self._prefill):
-            attach_arbiter(h, self._tune_arbiter, f"prefill#{i}")
         self._reqs: Dict[int, _RouterReq] = {}
         self._qi: deque = deque()      # interactive rids
         self._qb: deque = deque()      # batch rids

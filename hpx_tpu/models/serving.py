@@ -87,7 +87,7 @@ from ..cache.radix import RadixCache
 from ..core.errors import Error, HpxError
 from ..svc import faultinject, flight, tracing
 from ..svc.resiliency import sync_replay
-from ..ops.attention_pallas import resolve_paged_block_src
+from ..ops.attention_pallas import resolve_paged_block
 from ..ops.paged_attention import (
     block_rows,
     gather_block_kv,
@@ -226,9 +226,8 @@ def _resolve_buckets(spec, chunk: int) -> Tuple[int, ...]:
 
 
 def _resolve_kv_dtype(kv_dtype, rc) -> str:
-    """The hpx.cache.kv_dtype resolution _init_paged applies, factored
-    out so the perfdb boot consult can key on the RESOLVED dtype
-    before the paged state is built."""
+    """The pool dtype of a paged server: the constructor argument,
+    else ``hpx.cache.kv_dtype``; validated."""
     if kv_dtype is None:
         kv_dtype = rc.get("hpx.cache.kv_dtype", "bf16")
     if kv_dtype not in ("bf16", "int8", "fp8"):
@@ -241,9 +240,9 @@ def _resolve_kv_dtype(kv_dtype, rc) -> str:
 
 
 def _resolve_paged_kernel(paged_kernel, rc) -> str:
-    """hpx.serving.paged_kernel resolution (auto -> fused on TPU,
-    gather elsewhere), factored out of _init_paged for the same
-    reason as _resolve_kv_dtype."""
+    """The paged attention formulation: the constructor argument,
+    else ``hpx.serving.paged_kernel``; ``auto`` -> fused on TPU,
+    gather elsewhere; validated."""
     if paged_kernel is None:
         paged_kernel = rc.get("hpx.serving.paged_kernel", "auto")
     if paged_kernel in (None, "", "auto"):
@@ -260,16 +259,6 @@ def _resolve_paged_kernel(paged_kernel, rc) -> str:
             "'fused_online' (O(block)-scratch online softmax), "
             f"got {paged_kernel!r}")
     return paged_kernel
-
-
-def _rc_at_default(rc, key: str) -> bool:
-    """True when the effective config value for ``key`` is its
-    DECLARED default — the learned-ladder override policy: a value an
-    operator set explicitly (ini/env/CLI/set()) always beats the
-    perfdb, even when the store holds a hit for the shape."""
-    from ..core import config_schema
-    entry = config_schema.lookup(key)
-    return entry is not None and rc.get(key) == entry.default
 
 
 def _moe_rows(h, lp, cfg, moe_cf=None, moe_ep=None, moe_sink=None,
@@ -733,51 +722,13 @@ class ContinuousServer:
         self._moe_steps = 0         # occupancy vector's total
         self._moe_buf: deque = deque()
 
-        # learned-ladder boot consult (svc/perfdb): with
-        # hpx.perfdb.use_learned_ladders=1 the store is keyed on this
-        # server's (device, shape, kv_dtype, kernel, mesh) and a
-        # usable hit overrides the hand-picked ladder DEFAULTS below.
-        # Explicit settings — constructor args, or config values moved
-        # off their declared defaults — always win, and with the knob
-        # off (or on a miss/stale entry) every resolution below is
-        # byte-identical to the constants (pinned by
-        # tests/test_perfdb.py).
-        self._learned_ladder = None
-        self._ladder_source = "default"
-        self._block_size_src = "n/a"
-        if rc.get_bool("hpx.perfdb.use_learned_ladders", False):
-            from ..svc import perfdb as _perfdb
-            _perfdb.ensure_counters()
-            if self.paged:
-                lk_kvd = _resolve_kv_dtype(kv_dtype, rc)
-                lk_kern = _resolve_paged_kernel(paged_kernel, rc)
-            else:
-                lk_kvd, lk_kern = "-", "dense"
-            self._learned_ladder = _perfdb.learned_ladder_for(
-                cfg, lk_kvd, lk_kern, mesh)
-        # "learned" only when a stored value actually lands — an
-        # explicit constructor arg or operator config write beats the
-        # store, and the source string must say so
-        learned = self._learned_ladder or {}
-
         if prefill_chunk is None:
-            if learned.get("prefill_chunk") and \
-                    _rc_at_default(rc, "hpx.serving.prefill_chunk"):
-                prefill_chunk = int(learned["prefill_chunk"])
-                self._ladder_source = "learned"
-            else:
-                prefill_chunk = rc.get_int("hpx.serving.prefill_chunk",
-                                           _PREFILL_CHUNK)
+            prefill_chunk = rc.get_int("hpx.serving.prefill_chunk",
+                                       _PREFILL_CHUNK)
         self.prefill_chunk = max(1, int(prefill_chunk))
         if prefill_buckets is None:
-            if learned.get("prefill_buckets") and \
-                    _rc_at_default(rc, "hpx.serving.prefill_buckets"):
-                prefill_buckets = ",".join(
-                    str(int(b)) for b in learned["prefill_buckets"])
-                self._ladder_source = "learned"
-            else:
-                prefill_buckets = rc.get("hpx.serving.prefill_buckets",
-                                         "auto")
+            prefill_buckets = rc.get("hpx.serving.prefill_buckets",
+                                     "auto")
         self.prefill_buckets = _resolve_buckets(prefill_buckets,
                                                 self.prefill_chunk)
         if async_dispatch is None:
@@ -810,13 +761,7 @@ class ContinuousServer:
                 f"got {spec_draft!r}")
         self._spec_source = spec_draft
         if spec_k is None:
-            sk = learned.get("spec_k") or {}
-            if sk.get("best") and _rc_at_default(rc,
-                                                "hpx.serving.spec.k"):
-                spec_k = int(sk["best"])
-                self._ladder_source = "learned"
-            else:
-                spec_k = rc.get_int("hpx.serving.spec.k", 4)
+            spec_k = rc.get_int("hpx.serving.spec.k", 4)
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
         # the verify window (k drafts + the current token) rides the
@@ -895,13 +840,6 @@ class ContinuousServer:
                 return jnp.zeros((slots, smax, nkv, hd), cfg.dtype)
             self._caches = [(zeros(), zeros())
                             for _ in range(cfg.n_layers)]
-        # live progprof producer attribution: while hpx.perfdb.record
-        # is on, this server's key names the cost-surface point the
-        # profiled programs belong to (see svc/perfdb.bank_profile)
-        from ..svc import perfdb as _perfdb
-        if _perfdb.record_enabled():
-            _perfdb.ensure_counters()
-            _perfdb.note_live_key(self.perf_key())
         # windowed decode throughput, read by the serving counters
         from ..svc.performance_counters import RateCounter
         self._rate = RateCounter(window_s=5.0)
@@ -972,19 +910,13 @@ class ContinuousServer:
         self._last_step_t: Optional[float] = None
         self._stall_live = False
         self._step_n = 0               # step() calls: serving.step's `n`
-        # closed-loop adaptive tuning (svc/autotune): tick at flush
-        # boundaries only — the one point where no step is in flight,
-        # so a knob write cannot tear a dispatched program. Config
-        # writes from OUTSIDE (operator set()) propagate through the
-        # same boundary via _reload_knobs, keyed on the config
-        # generation counter.
+        # operator config writes (runtime_config().set()) to the
+        # _RELOADABLE_KNOBS land at flush boundaries only — the one
+        # point where no step is in flight, so a knob write cannot
+        # tear a dispatched program — via _reload_knobs, keyed on the
+        # config generation counter.
         self._cfg_gen = rc.generation()
         self._knob_raw = {k: rc.get(k) for k in _RELOADABLE_KNOBS}
-        self._tune_stall_prev = None    # decode_stall snapshot at tick
-        self._tuner = None
-        if rc.get_bool("hpx.tune.enable", False):
-            from ..svc.autotune import server_tuner
-            self._tuner = server_tuner(self)
         # live observability (svc/exemplars, svc/slo_alerts,
         # svc/opsplane): every piece is None/empty unless its
         # hpx.obs.* knob is on, so the record and flush fast paths
@@ -1028,23 +960,14 @@ class ContinuousServer:
         # the O(block) online-softmax kernel
         self._paged_fused = {"gather": False, "fused": True,
                              "fused_online": "online"}[paged_kernel]
-        learned = self._learned_ladder or {}
         if block_size is None:
             v = rc.get("hpx.cache.block_size", "auto")
             if v in (None, "", "auto"):
-                if learned.get("block_size"):
-                    # this shape's learned ladder carries its own
-                    # block size — most specific tier, beats the
-                    # (head_dim, kv_dtype)-keyed tables below
-                    block_size = int(learned["block_size"])
-                    self._block_size_src = "learned"
-                else:
-                    # perfdb learned-blocks tier, then the seed table
-                    # banked by `benchmarks/flash_tune.py --paged`
-                    # (ops/paged_blocks.json), then 16
-                    block_size, self._block_size_src = \
-                        resolve_paged_block_src(cfg.head_dim,
-                                                self._kv_dtype, 16)
+                # HPX_PAGED_BLOCK, then the seed table banked by
+                # `benchmarks/flash_tune.py --paged`
+                # (ops/paged_blocks.json), then 16
+                block_size, self._block_size_src = resolve_paged_block(
+                    cfg.head_dim, self._kv_dtype)
             else:
                 block_size = int(v)
                 self._block_size_src = "config"
@@ -1970,18 +1893,6 @@ class ContinuousServer:
         return ("f32" if jnp.dtype(self.cfg.dtype).itemsize == 4
                 else "bf16")
 
-    def perf_key(self) -> str:
-        """This server's point on the perfdb cost surface —
-        ``device|shape|kv_dtype|kernel|mesh`` (see svc/perfdb).  The
-        key the learned-ladder boot consult resolves against, and the
-        one producers bank this server's costs under."""
-        from ..svc import perfdb as _perfdb
-        return str(_perfdb.PerfKey(
-            _perfdb.device_kind(), _perfdb.shape_str(self.cfg),
-            self._kv_dtype if self.paged else "-",
-            self._paged_kernel if self.paged else "dense",
-            _perfdb.mesh_str(self.mesh)))
-
     def hbm_read_stats(self) -> Dict[str, Any]:
         """Modeled decode-attention HBM read cost per generated token,
         fed from pool dtype + table occupancy (the
@@ -2022,8 +1933,7 @@ class ContinuousServer:
             "walk_entries_per_slot": walk,
             "walk_share": walk / self._maxb,
             # where this server's block_size came from: arg | config |
-            # env | learned (perfdb) | seed (paged_blocks.json) |
-            # default — the satellite audit hook for learned ladders
+            # env | seed (paged_blocks.json) | default
             "block_size_source": self._block_size_src,
             # what `auto` resolved to: gather | fused | fused_online
             "paged_kernel": self._paged_kernel,
@@ -3091,8 +3001,7 @@ class ContinuousServer:
         early step overlaps the device's work on a later one; the read
         of the newest step is the one that empties the dispatch queue.
         Also the knob actuation boundary: external config writes land
-        (_reload_knobs) and the adaptive tuner ticks HERE, never
-        mid-step."""
+        (_reload_knobs) HERE, never mid-step."""
         with tracing.span("serving.flush", "serving",
                           steps=len(self._buf)):
             while self._buf:
@@ -3123,17 +3032,10 @@ class ContinuousServer:
                 self._moe_steps += 1
             self._ckpt_sweep()
             self._reload_knobs()
-            # SLO burn evaluation shares the tuner's boundary: no step
-            # in flight, so a flight-bundle capture sees consistent
-            # state. A firing alert also holds the tuner — probing
-            # against regressed traffic tunes toward the incident.
-            alerting = False
+            # SLO burn evaluation shares this boundary: no step in
+            # flight, so a flight-bundle capture sees consistent state
             if self._alerts is not None:
                 self._alerts.maybe_tick()
-                alerting = self._alerts.active() > 0
-            if self._tuner is not None:
-                self._tuner.maybe_tick(self._tune_signals,
-                                       hold=alerting)
 
     def _reload_knobs(self) -> None:
         """Propagate runtime config writes into the live server at
@@ -3182,38 +3084,10 @@ class ContinuousServer:
                 # shrink applies on the next demotion's LRU sweep
                 self._tier.budget_bytes = max(1, int(raw)) << 20
 
-    def _tune_signals(self):
-        """One TuneSignals sample for the tuner: decayed tokens/s,
-        the decode-stall p99 over the window SINCE the last sample
-        (histogram delta, not lifetime), queue depth, and progprof's
-        cumulative compile seconds (None freezes compile-minting
-        knobs). Host-only reads — no device sync."""
-        from ..svc import progprof
-        from ..svc.autotune import TuneSignals
-        from ..svc.metrics import HistogramCounter
-        h = self.hist["decode_stall"]
-        prev, self._tune_stall_prev = self._tune_stall_prev, \
-            h.snapshot()
-        # quantile() on a DETACHED window copy, never on the live
-        # histogram — the live scan is the O(buckets)-under-load read
-        # hpxlint HPX023 bans from paths reachable off the flush
-        # boundary (first tick: the snapshot just taken IS the window)
-        p99 = HistogramCounter.from_snapshot(
-            h.delta(prev) if prev is not None
-            else self._tune_stall_prev).quantile(0.99)
-        comp = None
-        prof = progprof.active_profiler()
-        if prof is not None:
-            comp = sum(float(r.compile_s) for r in prof.records())
-        return TuneSignals(
-            tok_rate=self._rate.rate(), stall_p99=p99,
-            queue_depth=float(len(self._queue)),
-            compile_s_total=comp)
-
     def _statusz(self) -> Dict[str, Any]:
         """This server's /statusz section (svc/opsplane provider):
-        live queue/slot state, the SLO alert burn state, tuner flight
-        state, and tier occupancy — host-only reads, no device sync
+        live queue/slot state, the SLO alert burn state and tier
+        occupancy — host-only reads, no device sync
         (an ops scrape must never stall the decode loop)."""
         doc: Dict[str, Any] = {
             "kind": "server",
@@ -3229,8 +3103,6 @@ class ContinuousServer:
             "tok_rate": float(self._rate.rate()),
             "timeline_rids": len(self.timeline),
         }
-        if self._tuner is not None:
-            doc["tuner"] = self._tuner.flight_state()
         if self._alerts is not None:
             doc["alerts"] = self._alerts.state()
         if self.paged:

@@ -41,8 +41,7 @@ __all__ = ["flash_attention", "flash_attention_chunk",
            "flash_attention_bwd", "fused_paged_attention",
            "fused_paged_online_attention",
            "paged_online_scratch_shapes",
-           "resolve_blocks", "resolve_paged_block",
-           "resolve_paged_block_src"]
+           "resolve_blocks", "resolve_paged_block"]
 
 
 # ---------------------------------------------------------------------------
@@ -340,7 +339,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """[B, S, N, H] flash attention as one pallas_call per device.
 
     block_q/block_k default to resolve_blocks' per-shape-class choice
-    (env override / measured autotune table / 1024). S is padded to the
+    (env override / measured flash_tune table / 1024). S is padded to the
     block size internally; H should be a multiple of the 128-lane
     layout's tile for best MXU utilization (64/128).
     Differentiable: jax.custom_vjp routes reverse-mode through the
@@ -903,40 +902,22 @@ def _load_paged_blocks() -> dict:
     return _paged_blocks_table
 
 
-def resolve_paged_block_src(head_dim: int, kv_dtype: str = "bf16",
-                            default: int = 16) -> tuple:
+def resolve_paged_block(head_dim: int, kv_dtype: str = "bf16") -> tuple:
     """The cache block_size `hpx.cache.block_size=auto` resolves to,
-    with its source: ``(value, 'env' | 'learned' | 'seed' |
-    'default')``.
+    with its source: ``(value, 'env' | 'seed' | 'default')``.
 
-    Resolution order: HPX_PAGED_BLOCK env > perfdb learned-blocks
-    tier (``hpx.perfdb.use_learned_ladders=1`` and the configured
-    store holds a usable ``hd<head_dim>x<kv_dtype>`` entry — see
-    svc/perfdb) > seed table (benchmarks/flash_tune.py --paged writes
-    paged_blocks.json next to this file, same key grammar) >
-    `default`.  The source lands in
+    Resolution order: HPX_PAGED_BLOCK env > seed table
+    (benchmarks/flash_tune.py --paged writes paged_blocks.json next to
+    this file, keys ``hd<head_dim>x<kv_dtype>``) > 16.  The
+    source lands in
     ``ContinuousServer.hbm_read_stats()['block_size_source']``."""
     env = os.environ.get("HPX_PAGED_BLOCK")
     if env:
         return int(env), "env"
-    # lazy import: svc.perfdb is stdlib-only but lives a layer up;
-    # importing at call time keeps ops import-light and cycle-free
-    from ..svc import perfdb as _perfdb
-    learned = _perfdb.learned_block(head_dim, kv_dtype)
-    if learned:
-        return int(learned), "learned"
-    table = _load_paged_blocks()
-    val = table.get(f"hd{head_dim}x{kv_dtype}")
+    val = _load_paged_blocks().get(f"hd{head_dim}x{kv_dtype}")
     if val:
         return int(val), "seed"
-    return default, "default"
-
-
-def resolve_paged_block(head_dim: int, kv_dtype: str = "bf16",
-                        default: int = 16) -> int:
-    """``resolve_paged_block_src`` without the source (the historical
-    interface — callers that only need the number)."""
-    return resolve_paged_block_src(head_dim, kv_dtype, default)[0]
+    return 16, "default"
 
 
 def _dequant_tile(x, sc_ref, blk, head, dtype):

@@ -334,16 +334,9 @@ class FleetRouter(DisaggRouter):
 
     def _new_decode_handle(self) -> WorkerHandle:
         if self._decode_factory is not None:
-            h = self._decode_factory()
-        else:
-            h = InProcHandle("decode", self._make_decode_worker(),
-                             locality=len(self._decode))
-        # autoscaled workers join the router-level tune arbiter like
-        # the construction-time pool (DisaggRouter.__init__)
-        from .autotune import attach_arbiter
-        attach_arbiter(h, self._tune_arbiter,
-                       f"decode#{len(self._decode)}")
-        return h
+            return self._decode_factory()
+        return InProcHandle("decode", self._make_decode_worker(),
+                            locality=len(self._decode))
 
     def _autoscale(self) -> None:
         """One scale decision per tick, queue-depth driven: mint a

@@ -149,17 +149,10 @@ def build_bundle(kind: str, site: Optional[str] = None,
                      if timeline is not None and rid is not None
                      else []),
     }
-    # adaptive-tuner black box: every live tuner's decision log +
-    # signal history, so a post-incident dump answers "what did the
-    # tuner do leading up to this shed/failover" (and replays it —
-    # autotune.replay). {} when no tuner is live; only runs inside a
-    # bundle capture, so the zero-cost discipline holds.
-    from . import autotune
-    doc["tune"] = autotune.flight_snapshot()
     # host-tier state: occupancy + demote/promote/drop totals across
     # every live tier, so a shed bundle answers "was the cold tier
     # absorbing evictions or thrashing when this request died". {}
-    # when no tier is live (the key stays optional, like tune).
+    # when no tier is live (the key stays optional).
     from ..cache import tier as _tier
     doc["tier"] = _tier.flight_snapshot()
     if extra:
@@ -301,9 +294,6 @@ def validate_bundle(doc: Dict[str, Any]) -> List[str]:
         errs.append("programs must be null or a profile table")
     if not isinstance(doc.get("timeline"), list):
         errs.append("timeline must be a list")
-    tune = doc.get("tune")
-    if tune is not None and not isinstance(tune, dict):
-        errs.append("tune must be absent or an object")
     tier = doc.get("tier")
     if tier is not None and not isinstance(tier, dict):
         errs.append("tier must be absent or an object")

@@ -13,8 +13,8 @@ views:
     exemplars on the ``_bucket`` rows and a ``# EOF`` terminator.
 ``/statusz``
     JSON: per-provider server/fleet/worker state (queue depths, live
-    slots, autoscale state), the tuner flight snapshot, tier
-    occupancy, and the dist heartbeat table.
+    slots, autoscale state), tier occupancy, and the dist heartbeat
+    table.
 ``/tracez``
     The recent slowest completed spans sampled from the live trace
     ring (empty list when tracing is off).
@@ -183,7 +183,6 @@ class OpsPlane:
     # -- views --------------------------------------------------------
 
     def statusz(self) -> Dict[str, Any]:
-        from . import autotune
         from ..cache import tier as _tier
         with self._lock:
             providers = dict(self._providers)
@@ -191,7 +190,6 @@ class OpsPlane:
             "wall_time": time.time(),
             "pid": os.getpid(),
             "uptime_s": round(time.time() - self.started, 3),
-            "tune": autotune.flight_snapshot(),
             "tier": _tier.flight_snapshot(),
             "heartbeats": _heartbeat_table(),
             "providers": {},

@@ -422,29 +422,13 @@ def start_profiling(sample_memory: bool = True,
 
 def stop_profiling() -> Optional[ProgramProfiler]:
     """Stop and detach the active profiler (returned so callers can
-    still fold its table into artifacts).  With ``hpx.perfdb.record=1``
-    and a store configured, the table is also banked into the perfdb
-    observation log (per-program compile/execute costs, provenance-
-    stamped) — the live producer half of the offline ladder loop."""
+    still fold its table into artifacts)."""
     global _active
     prof = _active
     _active = None
     if prof is not None:
         prof.close()
-        _bank_to_perfdb(prof)
     return prof
-
-
-def _bank_to_perfdb(prof: ProgramProfiler) -> None:
-    from . import perfdb
-    if not perfdb.record_enabled():
-        return
-    db = perfdb.configured_db()
-    if db is None:
-        return
-    if perfdb.bank_profile(db, prof.profile_table(),
-                           perfdb.live_key()):
-        db.save()
 
 
 def active_profiler() -> Optional[ProgramProfiler]:

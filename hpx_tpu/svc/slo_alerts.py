@@ -74,7 +74,6 @@ class SloRule:
 
 # the built-in objectives when hpx.obs.alert_rules is empty: e2e for
 # the user-visible contract, decode_stall for the inter-token signal
-# the tuner also optimizes
 DEFAULT_RULES: Tuple[SloRule, ...] = (
     SloRule("e2e", 1.0, 0.95),
     SloRule("decode_stall", 0.25, 0.99),
@@ -330,7 +329,7 @@ class SloAlerts:
 
 
 # live evaluators, for /healthz aggregation — weak so an evaluator
-# never outlives its server (same pattern as autotune._live)
+# never outlives its server
 _live: "weakref.WeakSet[SloAlerts]" = weakref.WeakSet()
 
 

@@ -878,7 +878,7 @@ def test_hpx017_scoped_to_models_and_ops():
 
 
 # ---------------------------------------------------------------------------
-# HPX018 — tuner-owned knob mutated outside the config actuation path
+# HPX018 — reloadable knob mutated outside the config actuation path
 # ---------------------------------------------------------------------------
 
 HPX018_BAD = """\
@@ -921,20 +921,19 @@ def test_hpx018_silent_on_actuation_path():
     assert findings(HPX018_GOOD, path="hpx_tpu/svc/fixture.py") == []
 
 
-def test_hpx018_scope_and_autotune_exemption():
-    # svc/ is in scope; the tuner's own KnobBinding setters are the
-    # actuation path and stay exempt; layers outside models//svc/
-    # (e.g. cache/radix's budget_blocks __init__) are out of scope
+def test_hpx018_scope():
+    # svc/ is in scope, no file of it exempt; layers outside
+    # models//svc/ (e.g. cache/radix's budget_blocks __init__) are
+    # out of scope
     fs = findings(HPX018_BAD, path="hpx_tpu/svc/fixture.py")
     assert rules_of(fs) == ["HPX018", "HPX018"]
-    assert findings(HPX018_BAD, path="hpx_tpu/svc/autotune.py") == []
     assert findings(HPX018_BAD, path="hpx_tpu/cache/fixture.py") == []
 
 
 def test_hpx018_real_tree_is_clean():
     # ground truth for the rule shipping with an empty baseline: the
-    # only in-tree writes to tunable-backed attrs are construction and
-    # _reload_knobs
+    # only in-tree writes to the reloadable knobs' attrs are
+    # construction and _reload_knobs
     res = lint_paths([os.path.join(REPO, "hpx_tpu")],
                      rules=all_rules(["HPX018"]))
     assert [f.rule for f in res.findings] == []
@@ -998,8 +997,8 @@ def test_hpx024_scope():
 
 def test_hpx024_real_tree_is_clean():
     # ground truth: the shipped models//svc//ops layers resolve every
-    # shape knob through the config/perfdb chain (PrefillWorker's
-    # block_size routes through resolve_paged_block)
+    # shape knob through its config key (PrefillWorker's block_size
+    # routes through resolve_paged_block)
     res = lint_paths([os.path.join(REPO, "hpx_tpu")],
                      rules=all_rules(["HPX024"]))
     assert [f.rule for f in res.findings] == []

@@ -80,7 +80,7 @@ _SLOTS, _NQ, _SMAX = 8, 16, 2048
 
 
 def _paged_case(sds, kernel, kv, nkv, hd, w):
-    bs = ap.resolve_paged_block(hd, kv)
+    bs = ap.resolve_paged_block(hd, kv)[0]
     maxb = _SMAX // bs
     nb = 2 * _SLOTS * maxb + 1          # ContinuousServer's auto sizing
     pool = sds((nb, nkv, bs, hd), _POOL_DTYPES[kv])

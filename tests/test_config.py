@@ -128,7 +128,7 @@ def test_strict_mode_reserved_vs_unknown_are_distinct_errors():
 
 def test_set_bumps_generation():
     """Every set() bumps the change counter a live server polls to
-    re-read its tunable knobs at the next flush boundary."""
+    re-read its reloadable knobs at the next flush boundary."""
     cfg = Configuration(environ={})
     g0 = cfg.generation()
     cfg.set("hpx.serving.prefill_chunk", "64")
@@ -148,23 +148,3 @@ def test_declare_validates_choices():
     assert key.choices == ("bf16", "int8", "fp8")
     assert config_schema.lookup("hpx.serving.paged_kernel").choices == \
         ("auto", "gather", "fused", "fused_online")
-
-
-def test_tunable_registry():
-    """The tunable subset is the closed set of knobs the adaptive
-    tuner may move; each carries bounds and a compile-cost flag."""
-    from hpx_tpu.core import config_schema
-    tk = config_schema.tunable_keys()
-    assert "hpx.serving.prefill_chunk" in tk
-    assert "hpx.serving.max_async_steps" in tk
-    assert "hpx.serving.spec.k" in tk
-    assert "hpx.cache.radix_budget_blocks" in tk
-    spec = tk["hpx.serving.prefill_chunk"].tunable
-    assert spec.compiles and spec.geometric and spec.lo <= 128 <= spec.hi
-    assert not tk["hpx.serving.max_async_steps"].tunable.compiles
-    # bool/float knobs have no bounded-step semantics
-    with pytest.raises(ValueError, match="tunable"):
-        config_schema.declare("hpx.test.bogus_tunable", "bool", "0",
-                              "no step semantics",
-                              tunable=config_schema.Tunable(lo=0, hi=1))
-    assert not config_schema.is_declared("hpx.test.bogus_tunable")

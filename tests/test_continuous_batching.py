@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hpx_tpu.core.config import runtime_config
 from hpx_tpu.models import transformer as tfm
 from hpx_tpu.models.serving import ContinuousServer
 
@@ -242,3 +243,34 @@ def test_live_positions_follow_the_decode_steps(params):
     assert srv.live_positions() == {0: 5}
     srv.run()
     assert srv.live_positions() == {}
+
+
+def test_reload_knobs_applies_config_writes_at_flush(params):
+    """The operator path: a runtime_config().set() of a reloadable key
+    is picked up by _reload_knobs (generation-gated), clamped to the
+    server's ladders; constructor overrides survive unrelated
+    writes."""
+    rc = runtime_config()
+    srv = ContinuousServer(params, CFG, slots=2, smax=64,
+                           prefill_chunk=8)
+    assert srv.prefill_chunk == 8
+    saved = rc.get("hpx.serving.ckpt_every")
+    try:
+        # unrelated write: bumps the generation, must NOT clobber the
+        # prefill_chunk=8 constructor override back to the default
+        rc.set("hpx.serving.ckpt_every", "128")
+        srv._reload_knobs()
+        assert srv.prefill_chunk == 8
+        assert srv._ckpt_every == 128
+        # a write to the key itself IS applied, clamped to the ladder
+        saved_pc = rc.get("hpx.serving.prefill_chunk")
+        try:
+            rc.set("hpx.serving.prefill_chunk", "1000000")
+            srv._reload_knobs()
+            assert srv.prefill_chunk == srv.prefill_buckets[-1]
+        finally:
+            rc.set("hpx.serving.prefill_chunk",
+                   saved_pc if saved_pc is not None else "auto")
+    finally:
+        rc.set("hpx.serving.ckpt_every", saved if saved is not None
+               else "16")

@@ -673,10 +673,10 @@ def test_scatter_window_q_oob_drops_fp8_rows_and_scales():
 def test_resolve_paged_block_order(monkeypatch):
     monkeypatch.setattr(ap, "_paged_blocks_table", {"hd8xint8": 32})
     monkeypatch.delenv("HPX_PAGED_BLOCK", raising=False)
-    assert ap.resolve_paged_block(8, "int8") == 32     # measured table
-    assert ap.resolve_paged_block(8, "bf16") == 16     # default
+    assert ap.resolve_paged_block(8, "int8") == (32, "seed")
+    assert ap.resolve_paged_block(8, "bf16") == (16, "default")
     monkeypatch.setenv("HPX_PAGED_BLOCK", "64")
-    assert ap.resolve_paged_block(8, "int8") == 64     # env wins
+    assert ap.resolve_paged_block(8, "int8") == (64, "env")
 
 
 def test_server_auto_block_size_honors_env(params, monkeypatch):

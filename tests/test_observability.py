@@ -1,8 +1,7 @@
 """Live observability (PR 18): exemplar reservoirs on the SLO
 histograms, OpenMetrics exposition with exemplars, SLO burn-rate
-alerting at the flush boundary, the alert-aware tuner hold, the
-flight --list CLI, and the opsplane HTTP endpoint — including the
-tier-1 smoke that boots the plane on an ephemeral port during a real
+alerting at the flush boundary, the flight --list CLI, and the
+opsplane HTTP endpoint — including the tier-1 smoke that boots the plane on an ephemeral port during a real
 ContinuousServer run.
 """
 
@@ -15,11 +14,9 @@ import pytest
 
 from hpx_tpu.core import config_schema
 from hpx_tpu.core.config import runtime_config
-from hpx_tpu.core.config_schema import Tunable
 from hpx_tpu.models import transformer as tfm
 from hpx_tpu.models.serving import ContinuousServer
 from hpx_tpu.svc import exemplars, faultinject, flight, metrics, opsplane
-from hpx_tpu.svc.autotune import AdaptiveTuner, KnobBinding, TuneSignals
 from hpx_tpu.svc.metrics import HistogramCounter
 from hpx_tpu.svc.slo_alerts import (
     DEFAULT_RULES,
@@ -320,33 +317,6 @@ def test_alerts_off_is_none(params):
 
 
 # ---------------------------------------------------------------------------
-# alert-aware tuner hold
-# ---------------------------------------------------------------------------
-
-def test_tuner_hold_blocks_new_probes_only():
-    cell = {"k": 8}
-    knob = KnobBinding(
-        "k", Tunable(lo=1, hi=256, step=2, geometric=True),
-        lambda: cell["k"], lambda v: cell.__setitem__("k", v))
-    t = AdaptiveTuner([knob], interval_ticks=1, cooldown_ticks=1)
-    sig = TuneSignals(tok_rate=100.0, stall_p99=0.0, queue_depth=0.0)
-    dec = t.evaluate(sig, hold=True)
-    assert dec["action"] == "hold" and t.holds == 1
-    assert t._phase != "probe" and cell["k"] == 8
-    # without the hold a probe starts; a hold DURING the probe still
-    # lets it settle (the in-flight experiment is not abandoned)
-    dec = t.evaluate(sig)
-    assert dec["action"] == "probe" and t._phase == "probe"
-    moved = cell["k"]
-    assert moved != 8
-    dec = t.evaluate(sig, hold=True)
-    assert dec["action"] in ("accept", "revert")
-    assert t._phase != "probe"
-    # the hold landed in the recorded sample stream for exact replay
-    assert any(s.get("alert_hold") for s in t._signals)
-
-
-# ---------------------------------------------------------------------------
 # flight --list CLI
 # ---------------------------------------------------------------------------
 
@@ -427,11 +397,11 @@ def test_opsplane_smoke_during_serving_run(params, knobs):
             names = {e["name"] for e in srv.timeline.events(rid)}
             assert "submit" in names and "retire" in names
 
-        # /statusz: valid JSON with the tune + tier flight snapshots
-        # and this server's provider section
+        # /statusz: valid JSON with the tier flight snapshot and this
+        # server's provider section
         code, _, body = _get(f"{plane.url}/statusz")
         doc = json.loads(body)
-        assert code == 200 and "tune" in doc and "tier" in doc
+        assert code == 200 and "tier" in doc
         sect = doc["providers"][f"serving/{srv.counter_instance}"]
         assert sect["kind"] == "server" and sect["slots"] == 2
         assert sect["timeline_rids"] == 2 and sect["live_slots"] == 0

@@ -1,0 +1,184 @@
+"""One place decides each construction-time shape of a
+ContinuousServer: the constructor argument, else its config key, else
+the constant (for the block size: HPX_PAGED_BLOCK, the measured table
+`ops/paged_blocks.json`, then 16). And a live server takes a config
+write to one of its reloadable knobs at its next flush, never
+mid-step."""
+
+import dataclasses
+
+import jax
+import pytest
+
+from hpx_tpu.core import config_schema
+from hpx_tpu.core.config import runtime_config
+from hpx_tpu.models import serving
+from hpx_tpu.models import transformer as tfm
+from hpx_tpu.models.serving import ContinuousServer
+from hpx_tpu.ops import attention_pallas as ap
+
+CFG = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
+                            n_layers=2, d_ff=64)
+MOE = dataclasses.replace(CFG, n_experts=4, moe_top_k=2)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return tfm.init_params(CFG, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def moe_params():
+    return tfm.init_params(MOE, jax.random.PRNGKey(0))
+
+
+@pytest.fixture()
+def knobs():
+    """Set config keys for one test; each goes back to its declared
+    default afterwards."""
+    rc = runtime_config()
+    touched = []
+
+    def set_(key, value):
+        touched.append(key)
+        rc.set(key, value)
+
+    yield set_
+    for key in touched:
+        rc.set(key, config_schema.lookup(key).default)
+
+
+@pytest.fixture(autouse=True)
+def _no_env_no_table(monkeypatch):
+    monkeypatch.delenv("HPX_PAGED_BLOCK", raising=False)
+    monkeypatch.setattr(ap, "_paged_blocks_table", {})
+
+
+# knob -> (what the server resolved, the constant, config key, the
+# value written to it and what that resolves to, constructor argument
+# and what that resolves to)
+_KNOBS = {
+    "block_size": (lambda s: s.block_size, 16,
+                   "hpx.cache.block_size", "8", 8, 32, 32),
+    "prefill_chunk": (lambda s: s.prefill_chunk, 128,
+                      "hpx.serving.prefill_chunk", "64", 64, 32, 32),
+    "prefill_buckets": (lambda s: s.prefill_buckets,
+                        (8, 16, 32, 64, 128),
+                        "hpx.serving.prefill_buckets", "4,16",
+                        (4, 16, 128), "32", (32, 128)),
+    "spec_k": (lambda s: s._spec_k, 4,
+               "hpx.serving.spec.k", "6", 6, 2, 2),
+    # off the chip `auto` is the XLA gather formulation
+    "paged_kernel": (lambda s: s._paged_kernel, "gather",
+                     "hpx.serving.paged_kernel", "fused", "fused",
+                     "fused_online", "fused_online"),
+    "kv_dtype": (lambda s: s._kv_dtype, "bf16",
+                 "hpx.cache.kv_dtype", "int8", "int8", "fp8", "fp8"),
+}
+
+
+@pytest.mark.parametrize("source", ["constant", "config", "arg"])
+@pytest.mark.parametrize("knob", sorted(_KNOBS))
+def test_resolution(params, knobs, knob, source):
+    got, const, key, written, from_config, arg, from_arg = _KNOBS[knob]
+    kwargs = {}
+    if source != "constant":
+        knobs(key, written)
+    if source == "arg":
+        kwargs[knob] = arg
+    srv = ContinuousServer(params, CFG, slots=2, smax=64, paged=True,
+                           **kwargs)
+    want = {"constant": const, "config": from_config,
+            "arg": from_arg}[source]
+    assert got(srv) == want
+    if knob == "block_size":
+        assert srv.hbm_read_stats()["block_size_source"] == {
+            "constant": "default"}.get(source, source)
+
+
+@pytest.mark.parametrize("source", ["seed", "env"])
+def test_block_size_source(params, monkeypatch, source):
+    """Below the config key: the environment beats the measured table,
+    the table beats 16, and the server says which it took."""
+    monkeypatch.setattr(ap, "_paged_blocks_table", {"hd8xbf16": 32})
+    if source == "env":
+        monkeypatch.setenv("HPX_PAGED_BLOCK", "8")
+    srv = ContinuousServer(params, CFG, slots=2, smax=64, paged=True)
+    assert srv.block_size == {"seed": 32, "env": 8}[source]
+    assert srv.hbm_read_stats()["block_size_source"] == source
+
+
+# the benchmark's two serving configurations construct their server
+# with these arguments and nothing else (their files' "server" entry)
+_CELLS = {
+    "starcoder2-3b": dict(paged=True, slots=32, smax=2048),
+    "laguna-xs2": dict(paged=True, slots=32, smax=4864),
+}
+
+
+@pytest.mark.parametrize("config", sorted(_CELLS))
+def test_default_geometry_of_the_cells(params, config):
+    srv = ContinuousServer(params, CFG, **_CELLS[config])
+    stats = srv.hbm_read_stats()
+    assert (srv.block_size, stats["block_size_source"]) == (16, "default")
+    assert srv.prefill_chunk == 128
+    assert srv.prefill_buckets == (8, 16, 32, 64, 128)
+    assert srv._max_async == 32
+    assert srv._spec_k == 4 and not srv._spec
+    assert (srv._kv_dtype, stats["paged_kernel"]) == ("bf16", "gather")
+    assert srv._maxb == _CELLS[config]["smax"] // 16
+
+
+# key -> (the server that has the knob, what it resolved, a value in
+# range and what it lands as, a value out of range and its clamp)
+_RELOADS = {
+    "hpx.serving.prefill_chunk": (
+        {}, lambda s: s.prefill_chunk, "16", 16, "1000000", 128),
+    "hpx.serving.max_async_steps": (
+        {}, lambda s: s._max_async, "8", 8, "0", 1),
+    "hpx.serving.ckpt_every": (
+        {}, lambda s: s._ckpt_every, "128", 128, "0", 1),
+    "hpx.serving.spec.k": (
+        {"spec": True}, lambda s: s._spec_k, "2", 2, "1000", 127),
+    "hpx.serving.moe.capacity_factor": (
+        {"moe": True}, lambda s: s._moe_capacity_pct, "200", 200,
+        "-3", 400),
+    "hpx.cache.radix_budget_blocks": (
+        {}, lambda s: s._radix.budget_blocks, "7", 7, "0", 1),
+    "hpx.cache.tier.host_budget_mb": (
+        {"tier": True}, lambda s: s._tier.budget_bytes, "3", 3 << 20,
+        "0", 1 << 20),
+}
+
+
+def test_the_reload_cases_are_the_reloadable_knobs():
+    assert sorted(_RELOADS) == sorted(serving._RELOADABLE_KNOBS)
+
+
+@pytest.mark.parametrize("key", sorted(_RELOADS))
+def test_reload_knobs_each_key(params, moe_params, knobs, key):
+    """A value written between two steps is unseen until the next
+    flush, then applied, clamped to what the built server can take."""
+    kind, got, legal, applied, wild, clamped = _RELOADS[key]
+    if kind.get("tier"):
+        knobs("hpx.cache.tier.enable", "1")
+    cfg, p = (MOE, moe_params) if kind.get("moe") else (CFG, params)
+    srv = ContinuousServer(p, cfg, slots=2, smax=64, paged=True,
+                           spec=bool(kind.get("spec")))
+    before = got(srv)
+    assert before != applied != clamped     # each landing shows
+    srv.submit([3, 1, 4, 1, 5], max_new=24)
+    srv.step()
+    srv.step()
+    knobs(key, legal)
+    assert got(srv) == before               # the write alone: unseen
+    if not srv._spec:                       # spec steps flush each time
+        srv.step()
+        assert srv._buf, "the step after the write must not have flushed"
+        assert got(srv) == before           # nor does a step apply it
+    srv.flush()
+    assert got(srv) == applied
+    knobs(key, wild)
+    assert got(srv) == applied
+    srv.flush()
+    assert got(srv) == clamped

@@ -137,64 +137,3 @@ def test_capacity_pct_rekeys_bounded_programs(params, mesh):
         assert list(out2.values()) == list(base_out.values())
     finally:
         rc.set("hpx.serving.moe.capacity_factor", "0")
-
-
-# -- autotune ----------------------------------------------------------------
-
-def test_moe_capacity_tuner_accepts_and_replays():
-    """The declared hpx.serving.moe.capacity_factor tunable, bound the
-    way server_tuner binds it (hi capped at n_experts*100), accepts a
-    probe on a favorable surface — compile cost measured and small —
-    and the flight state replays to the identical decision log."""
-    import dataclasses
-
-    from hpx_tpu.core import config_schema
-    from hpx_tpu.svc.autotune import (AdaptiveTuner, KnobBinding,
-                                      TuneSignals, replay)
-
-    entry = config_schema.tunable_keys()[
-        "hpx.serving.moe.capacity_factor"]
-    spec = dataclasses.replace(entry.tunable, hi=min(entry.tunable.hi,
-                                                     400))
-    cell = {"pct": 400}                      # auto = n_experts * 100
-    knob = KnobBinding("hpx.serving.moe.capacity_factor", spec,
-                       lambda: cell["pct"],
-                       lambda v: cell.__setitem__("pct", max(1, v)))
-    t = AdaptiveTuner([knob], interval_ticks=1, hysteresis_pct=1.0,
-                      cooldown_ticks=0, compile_amortize_s=30.0)
-    comp = {"s": 1.0}
-    seen = set()
-
-    def surface():
-        if cell["pct"] not in seen:
-            seen.add(cell["pct"])
-            comp["s"] += 0.2          # each new pct mints one program
-        # smaller capacity -> smaller expert exchange -> faster decode
-        return TuneSignals(tok_rate=100.0 * (400.0 / cell["pct"]) ** 0.5,
-                           stall_p99=0.0, queue_depth=0.0,
-                           compile_s_total=comp["s"])
-
-    for _ in range(12):
-        t.maybe_tick(surface)
-    assert t.accepts >= 1
-    assert cell["pct"] < 400          # walked down toward cheaper routing
-    assert spec.lo <= cell["pct"] <= spec.hi
-    assert replay(t.flight_state()) == t.decisions()
-
-
-def test_server_tuner_binds_moe_knob(params, mesh):
-    """An MoE server's tuner includes the capacity knob with hi capped
-    at n_experts*100; a dense server's tuner does not bind it."""
-    from hpx_tpu.svc.autotune import server_tuner
-    srv = ContinuousServer(params, MOE, slots=4, smax=64, mesh=mesh)
-    t = server_tuner(srv)
-    assert "hpx.serving.moe.capacity_factor" in t.knobs
-    assert t.knobs["hpx.serving.moe.capacity_factor"].spec.hi \
-        == MOE.n_experts * 100
-    dense_cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4,
-                                      head_dim=8, n_layers=2, d_ff=64)
-    dsrv = ContinuousServer(tfm.init_params(dense_cfg,
-                                            jax.random.PRNGKey(1)),
-                            dense_cfg, slots=2, smax=64)
-    dt = server_tuner(dsrv)
-    assert "hpx.serving.moe.capacity_factor" not in dt.knobs
