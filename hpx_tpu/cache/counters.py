@@ -17,6 +17,10 @@ Every server gets the serving counters::
     /serving{locality#L/server#i}/prefill/pending   in-flight chunked prefills
     /serving{locality#L/server#i}/programs/cache-hits    program-cache hits
     /serving{locality#L/server#i}/programs/cache-misses  program builds (compiles)
+    /serving{locality#L/server#i}/reads/overlapped  blocking device->host reads
+                                with a decode step queued behind the value
+    /serving{locality#L/server#i}/reads/draining    ... with none: the read
+                                empties the dispatch queue
 
 Speculative servers (``hpx.serving.spec.enable``) add::
 
@@ -141,6 +145,13 @@ def register_server(srv) -> str:
         pc.CallbackCounter(_read(ref, lambda s: s._prog_hits)))
     put("serving", "programs/cache-misses",
         pc.CallbackCounter(_read(ref, lambda s: s._prog_misses)))
+
+    # blocking device->host reads, by whether a decode step was
+    # queued behind the value read (ContinuousServer.read_stats)
+    put("serving", "reads/overlapped",
+        pc.CallbackCounter(_read(ref, lambda s: s._reads_overlapped)))
+    put("serving", "reads/draining",
+        pc.CallbackCounter(_read(ref, lambda s: s._reads_draining)))
 
     # fault/recovery ladder observability (svc/faultinject +
     # ContinuousServer.fault_stats): injected faults seen, step

@@ -24,10 +24,20 @@ Three throughput disciplines shape the hot loop:
   step between decode dispatches, so an admit never stalls the live
   batch; pending prefills are served shortest-remaining-first, so a
   short prompt is never stuck behind a long one's tail chunks.
-* ASYNC dispatch: the step loop feeds each step's sampled tokens back
-  device-side and only syncs to the host when a token VALUE is needed
-  (eos check, retirement) or the ``hpx.serving.max_async_steps`` cap
-  hits — host Python overlaps device execution.
+* ASYNC dispatch, DISPATCH BEFORE READ: the step loop feeds each
+  step's sampled tokens back device-side, and every program a step
+  enqueues (chunk, probe, splice, decode) is enqueued before that
+  step's first blocking device->host read. The reads LAG by one step:
+  a retirement by max_new (or ``hpx.serving.max_async_steps`` buffered
+  steps) notes that a read is due, and the NEXT step() makes it after
+  its own decode dispatch, taking every buffered step but the newest
+  — each blocking read has a whole decode step queued behind it. An
+  admission's seed token stays a device value (written into the
+  feedback vector) until the step's decode is enqueued, then is read
+  in the same step(). The order read-then-dispatch stays where the
+  VALUE is needed before the next dispatch: a request with an
+  ``eos_id``, ``max_new == 1``, a speculative server,
+  ``async_dispatch=False`` (`read_stats()` counts both kinds).
 * SPECULATIVE decode steps (``hpx.serving.spec.*``): each step drafts
   k tokens per slot — zero-model prompt-lookup over the slot's own
   history (plus the radix prefix tree), or a smaller draft checkpoint
@@ -51,9 +61,11 @@ comes from a 1-token logits probe of the last prompt position.
 RESILIENCY (ROADMAP item 5): the step loop runs under a bounded
 `svc.resiliency.sync_replay`. Every live slot keeps a host-side
 `SlotCheckpoint` (tokens, position, feedback token, paged block pins)
-captured at flush boundaries every ``hpx.serving.ckpt_every`` tokens;
-a step-level fault — injected via `svc/faultinject`, or a KV-pool OOM
-eviction couldn't clear — flushes the completed suffix, rewinds live
+captured at flush boundaries every ``hpx.serving.ckpt_every`` tokens,
+at the frontier the HOST holds (the tokens landed; a step still in
+flight is ahead of it and is replayed); a step-level fault — injected
+via `svc/faultinject`, or a KV-pool OOM eviction couldn't clear —
+flushes the completed suffix (the step in flight included), rewinds live
 slots to their checkpoints and replays only the lost tail. The
 differential contract is what makes this sha-provable: replayed steps
 re-emit the SAME tokens, so a faulted run's outputs are byte-identical
@@ -515,9 +527,11 @@ def _verify_tail(logits, toks, kvec, temp, keys, pos0, width):
 @dataclasses.dataclass
 class SlotCheckpoint:
     """Host-side restore point for one LIVE slot, captured at flush
-    boundaries (host and device agree there: ``pos = plen +
-    len(tokens) - 1``, cache rows [0, pos) hold prompt ++ tokens[:-1],
-    and ``cur = tokens[-1]`` is the next feedback token) every
+    boundaries, at the frontier of the tokens the host holds (``pos =
+    plen + len(tokens) - 1``, cache rows [0, pos) hold prompt ++
+    tokens[:-1], and ``cur = tokens[-1]`` is the next feedback token;
+    a step still in flight has written rows at and past pos, which the
+    replay rewrites with the same bytes) every
     ``hpx.serving.ckpt_every`` emitted tokens.
 
     ``pins`` (paged mode) hold ONE extra allocator reference per FULL
@@ -856,8 +870,16 @@ class ContinuousServer:
         self._pending: Dict[int, _PendingPrefill] = {}
         self._pf_seq = 0
         # async-dispatch state: buffered (nxt, [(slot, req)]) steps
-        # plus device-resident mirrors of the per-slot host vectors
+        # plus device-resident mirrors of the per-slot host vectors;
+        # `_seeds`: (req, slot, device token) of admissions whose seed
+        # token the host has not read yet; `_read_due`: the step just
+        # dispatched asked for a read, which the next step() makes
         self._buf: deque = deque()
+        self._seeds: deque = deque()
+        self._read_due = False
+        # blocking reads with / without a decode step queued behind
+        self._reads_overlapped = 0
+        self._reads_draining = 0
         self._cur_dev = None            # [slots] int32 token feedback
         self._temp_dev = None           # [slots] f32 (with _keys_dev)
         self._keys_dev = None
@@ -911,10 +933,10 @@ class ContinuousServer:
         self._stall_live = False
         self._step_n = 0               # step() calls: serving.step's `n`
         # operator config writes (runtime_config().set()) to the
-        # _RELOADABLE_KNOBS land at flush boundaries only — the one
-        # point where no step is in flight, so a knob write cannot
-        # tear a dispatched program — via _reload_knobs, keyed on the
-        # config generation counter.
+        # _RELOADABLE_KNOBS land at flush boundaries only — between
+        # two dispatches, so a knob write cannot tear a step's host
+        # preparation — via _reload_knobs, keyed on the config
+        # generation counter.
         self._cfg_gen = rc.generation()
         self._knob_raw = {k: rc.get(k) for k in _RELOADABLE_KNOBS}
         # live observability (svc/exemplars, svc/slo_alerts,
@@ -1672,7 +1694,10 @@ class ContinuousServer:
         if wt is not None:
             while wt.capacity <= pos:
                 wt.append_block(self._walloc.alloc())
-            freed = wt.free_behind(pos)
+            # one step behind the write: the step in flight when a
+            # checkpoint captures the host's frontier (pos - 1) must
+            # not have given that frontier's oldest block away
+            freed = wt.free_behind(pos - 1)
             if freed:
                 with tracing.span("serving.window_free", "serving",
                                   rid=self._slot_req[slot].rid,
@@ -1948,6 +1973,13 @@ class ContinuousServer:
         return {"routed": self._moe_routed, "dropped": self._moe_dropped,
                 "steps": self._moe_steps,
                 "experts_hit_sum": self._moe_hit_sum}
+
+    def read_stats(self) -> Dict[str, int]:
+        """The blocking device->host reads so far (seed tokens, token
+        vectors, MoE statistics), by whether a decode step was queued
+        behind the value read — the /serving{...}/reads/* counters."""
+        return {"reads_overlapped": self._reads_overlapped,
+                "reads_draining": self._reads_draining}
 
     def spec_stats(self) -> Dict[str, float]:
         """Speculation observability snapshot (the same numbers the
@@ -2269,7 +2301,13 @@ class ContinuousServer:
     def _finish_prefill(self, p: _PendingPrefill) -> None:
         """Prompt fully chunked: probe the last position's logits,
         splice the scratch into the slot (dense rows / paged blocks),
-        seed the first generated token, go live."""
+        seed the first generated token, go live. The seed token is
+        picked on the device and goes into the feedback vector as a
+        device value; the host reads it (`_land_seeds`) once this
+        step's decode is enqueued — at once only where its VALUE
+        decides what happens before that dispatch (an eos check, an
+        instant retire, a speculative step's host-fed drafts, a
+        synchronous server)."""
         req, slot = p.req, p.slot
         plen = len(req.prompt)
         tok = jnp.asarray([[req.prompt[-1]]], jnp.int32)
@@ -2287,24 +2325,21 @@ class ContinuousServer:
             self._caches = self._splice_prog()(
                 self._caches, caches, jnp.asarray(slot, jnp.int32))
         del self._pending[slot]
-        # a blocking device->host read at every admission: the host
-        # stands still until the probe's logits exist, and the dispatch
-        # queue drains meanwhile
-        with tracing.span("serving.first_token.wait", "serving",
-                          rid=req.rid):
-            if req.temperature > 0.0:
-                # generate()'s tok0 draw: position plen-1, row 0
-                tok0 = int(_sample_row(logits[0], req.temperature,
-                                       req.key, plen - 1, 0))
-            else:
-                tok0 = int(jnp.argmax(logits[0]))
-        req.tokens.append(tok0)
+        if req.temperature > 0.0:
+            # generate()'s tok0 draw: position plen-1, row 0
+            tok0 = _sample_row(logits[0], req.temperature, req.key,
+                               plen - 1, 0)
+        else:
+            tok0 = jnp.argmax(logits[0])
+        at_once = (req.eos_id is not None or req.max_new == 1
+                   or self._spec or not self._async)
+        if self._cur_dev is None and not at_once:
+            self._cur_dev = jnp.asarray(self._cur, jnp.int32)
+        if self._cur_dev is not None:
+            self._cur_dev = self._cur_dev.at[slot].set(tok0)
         req.sent = 1
         self._slot_req[slot] = req
         self._pos[slot] = plen
-        self._cur[slot] = tok0
-        if self._cur_dev is not None:
-            self._cur_dev = self._cur_dev.at[slot].set(tok0)
         self._temp[slot] = req.temperature
         self._key[slot] = (req.key if req.key is not None
                            else jax.random.PRNGKey(0))
@@ -2314,15 +2349,44 @@ class ContinuousServer:
             self._slot_acc[slot] = 1.0
             if self._draft_params is not None:
                 self._draft_prefill(slot, req.prompt)
-        ttft = time.monotonic() - req.t_submit
-        self.ttft[req.rid] = ttft
-        self.hist["ttft"].record(ttft, rid=req.rid)
-        self.timeline.event(req.rid, "first_token", slot=slot)
-        # seed checkpoint: a fault before the first cadence capture
-        # restores to the freshly-admitted state instead of losing the
-        # slot (the seed token is already part of the checkpoint)
-        self._capture(slot)
-        self._maybe_retire(slot)
+        self._seeds.append((req, slot, tok0))
+        if at_once:
+            self._land_seeds(behind=0)
+
+    def _wait(self, name: str, behind: int, **args):
+        """The span around ONE blocking device->host read. `behind`:
+        decode steps dispatched after the program whose value it
+        waits for — none, and the read empties the dispatch queue
+        (`reads_draining`); else the device has a step to run while
+        the host stands still and goes on (`reads_overlapped`)."""
+        if behind > 0:
+            self._reads_overlapped += 1
+        else:
+            self._reads_draining += 1
+        return tracing.span(name, "serving", behind=behind, **args)
+
+    def _land_seeds(self, behind: int) -> None:
+        """Read the seed tokens `_finish_prefill` left on the device,
+        in admission order: each reaches `req.tokens` before any
+        decoded token (`_flush` comes here first)."""
+        while self._seeds:
+            req, slot, tok0 = self._seeds.popleft()
+            with self._wait("serving.first_token.wait", behind,
+                            rid=req.rid):
+                tok0 = int(tok0)
+            req.tokens.append(tok0)
+            self._cur[slot] = tok0
+            ttft = time.monotonic() - req.t_submit
+            self.ttft[req.rid] = ttft
+            self.hist["ttft"].record(ttft, rid=req.rid)
+            self.timeline.event(req.rid, "first_token", slot=slot)
+            if self._slot_req[slot] is req:
+                # seed checkpoint: a fault before the first cadence
+                # capture restores to the freshly-admitted state
+                # instead of losing the slot. (Else max_new == 2: the
+                # slot retired at the dispatch this read followed.)
+                self._capture(slot)
+                self._maybe_retire(slot)
 
     def _admit(self) -> None:
         """Fill free slots from the queue. A prompt whose remaining
@@ -2668,13 +2732,16 @@ class ContinuousServer:
     # -- checkpoint / restore / shed (ROADMAP item 5) --------------------
 
     def _capture(self, slot: int) -> None:
-        """Snapshot one live slot's restore point. Callers guarantee
-        flush-consistency (``req.sent == len(req.tokens)``); paged
-        pins take one extra ref per FULL block below pos — never the
-        partial frontier block, whose pin would force a COW fork on
-        the next token write (see SlotCheckpoint)."""
+        """Snapshot one live slot's restore point at the frontier the
+        host holds: the tokens landed, and the position and feedback
+        token that follow from them — `_pos` is ahead of it by the
+        steps in flight (``req.sent - len(req.tokens)``, at most one
+        where a flush sweeps). Paged pins take one extra ref per FULL
+        block below pos — never the partial frontier block, whose pin
+        would force a COW fork on the next token write (see
+        SlotCheckpoint)."""
         req = self._slot_req[slot]
-        pos = self._pos[slot]
+        pos = len(req.prompt) + len(req.tokens) - 1
         pins: List[int] = []
         if self.paged:
             pt = self._tables[slot]
@@ -2684,13 +2751,16 @@ class ContinuousServer:
         wpins = None
         wt = self._wtables[slot] if self.paged else None
         if wt is not None:
+            # `_ensure_block` frees one step behind the write, so the
+            # frontier's window is still mapped with a step in flight
+            assert wt.base <= wt.first_needed(pos), (wt.base, pos)
             wpins = (wt.base, list(wt.blocks))
             for bid in wpins[1]:
                 self._walloc.incref(bid)
         old = self._ckpt.pop(slot, None)
         self._ckpt[slot] = SlotCheckpoint(
             rid=req.rid, tokens=list(req.tokens), pos=pos,
-            cur=self._cur[slot], slot_k=self._slot_k[slot],
+            cur=req.tokens[-1], slot_k=self._slot_k[slot],
             slot_acc=self._slot_acc[slot], pins=pins, wpins=wpins)
         self._unpin(old)
 
@@ -2706,13 +2776,14 @@ class ContinuousServer:
 
     def _ckpt_sweep(self) -> None:
         """Advance checkpoints at a flush boundary: every live slot
-        whose emissions grew by >= hpx.serving.ckpt_every since its
-        last capture (or whose checkpoint is missing/stale) captures
-        now. Runs at the end of _flush and after spec commits — the
-        two points where host and device state provably agree."""
+        whose landed tokens grew by >= hpx.serving.ckpt_every since
+        its last capture (or whose checkpoint is missing/stale)
+        captures now. Runs at the end of _flush (of a flush that left
+        the newest step in flight too: `_capture` takes the frontier
+        the host holds) and after spec commits."""
         for s in range(self.slots):
             req = self._slot_req[s]
-            if req is None or req.sent != len(req.tokens):
+            if req is None or not req.tokens:
                 continue
             ck = self._ckpt.get(s)
             if (ck is None or ck.rid != req.rid
@@ -2991,27 +3062,44 @@ class ContinuousServer:
                 if self.paged:
                     self._release_slot(slot, req)
 
-    def _flush(self) -> None:
-        """Materialize every buffered step's token vector and replay
-        the per-slot bookkeeping in dispatch order. With the seed-token
-        read of `_finish_prefill` (one per admission,
-        `serving.first_token.wait`) these are the ONLY device->host
-        reads in the decode loop: one per buffered step
-        (`serving.flush.wait`), oldest first, so that the replay of an
-        early step overlaps the device's work on a later one; the read
-        of the newest step is the one that empties the dispatch queue.
+    def _flush(self, keep: int = 0) -> None:
+        """Materialize the buffered steps' token vectors, all but the
+        newest `keep`, and replay the per-slot bookkeeping in dispatch
+        order. With the seed-token reads (`_land_seeds`, one per
+        admission, `serving.first_token.wait`) these are the ONLY
+        device->host reads in the decode loop: one per buffered step
+        (`serving.flush.wait`), oldest first, then one per step's MoE
+        statistics (`serving.flush.moe_stats.wait`; the newest step's
+        stay buffered with its tokens).
+
+        `keep=1` is the decode loop's own read, made right after a
+        step's dispatch for the retirement (or the full buffer) of the
+        step BEFORE it: every read then has a decode step queued
+        behind it and the device never waits for the host's next
+        preparation. `keep=0` drains everything, the newest step last
+        — the read that empties the dispatch queue: where a value is
+        needed before the next dispatch (an eos check,
+        async_dispatch=False, a speculative step), where nothing is
+        live, in `_recover`, and for a caller (`flush()`, the
+        benchmark's window edges).
+
         Also the knob actuation boundary: external config writes land
         (_reload_knobs) HERE, never mid-step."""
+        self._land_seeds(behind=0)
+        if not keep:
+            self._read_due = False
         with tracing.span("serving.flush", "serving",
                           steps=len(self._buf)):
-            while self._buf:
+            while len(self._buf) > keep:
                 nxt, lanes = self._buf.popleft()
-                with tracing.span("serving.flush.wait", "serving"):
+                with self._wait("serving.flush.wait", len(self._buf)):
                     vals = np.asarray(nxt)
                 for s, req in lanes:
                     t = int(vals[s])
                     req.tokens.append(t)
-                    self._cur[s] = t
+                    if self._slot_req[s] is req:    # else retired at
+                        self._cur[s] = t            # dispatch: the slot
+                                                    # may be another's
                     hit_eos = (req.eos_id is not None
                                and t == req.eos_id)
                     if hit_eos or len(req.tokens) >= req.max_new:
@@ -3019,10 +3107,11 @@ class ContinuousServer:
             # MoE routing stats buffered by the step/verify programs:
             # one small [2+E] vector per dispatched step, read here so
             # the async window never gains an extra host sync
-            while self._moe_buf:
-                with tracing.span("serving.flush.moe_stats.wait",
-                                  "serving"):
-                    ms = np.asarray(self._moe_buf.popleft())
+            while len(self._moe_buf) > keep:
+                ms = self._moe_buf.popleft()
+                with self._wait("serving.flush.moe_stats.wait",
+                                len(self._moe_buf)):
+                    ms = np.asarray(ms)
                 self._moe_routed += float(ms[0])
                 self._moe_dropped += float(ms[1])
                 self._moe_occ = [float(v) for v in ms[2:]]
@@ -3032,8 +3121,8 @@ class ContinuousServer:
                 self._moe_steps += 1
             self._ckpt_sweep()
             self._reload_knobs()
-            # SLO burn evaluation shares this boundary: no step in
-            # flight, so a flight-bundle capture sees consistent state
+            # SLO burn evaluation shares this boundary: the host's
+            # state is consistent (at most the newest step in flight)
             if self._alerts is not None:
                 self._alerts.maybe_tick()
 
@@ -3204,8 +3293,10 @@ class ContinuousServer:
                 self._moe_buf.append(ms)
             self._cur_dev = nxt
             self._rate.mark(float(len(live)))
+            tracing.instant("serving.dispatch", "serving",
+                            live=len(live))
             lanes = []
-            need_sync = not self._async
+            need_sync, retired = not self._async, False
             for s in live:
                 req = self._slot_req[s]
                 assert req is not None
@@ -3219,15 +3310,26 @@ class ContinuousServer:
                 elif req.sent >= req.max_new:
                     # bookkeeping retire at dispatch: the slot frees
                     # NOW (admissible next step); token values land at
-                    # the flush this triggers
+                    # the read this asks for, in the next step()
                     self._slot_req[s] = None
                     self._drop_ckpt(s)
                     if self.paged:
                         self._release_slot(s, req)
-                    need_sync = True
+                    retired = True
             self._buf.append((nxt, lanes))
-            if need_sync or len(self._buf) >= self._max_async:
+            # every program of this step is enqueued: now the reads.
+            # The seed tokens of this step's admissions first, then —
+            # one step late, so that this step runs meanwhile — what
+            # the step before asked for; all of it at once only where
+            # a value decides the next dispatch
+            self._land_seeds(behind=1)
+            if need_sync:
                 self._flush()
+            else:
+                if self._read_due:
+                    self._flush(keep=1)
+                self._read_due = (retired
+                                  or len(self._buf) >= self._max_async)
         return True
 
     def run(self) -> Dict[int, List[int]]:
@@ -3243,16 +3345,18 @@ class ContinuousServer:
 
     def flush(self) -> None:
         """Land every buffered step's tokens on the host now (one
-        blocking read a step); step() does so by itself at each
-        retirement, eos check and every `hpx.serving.max_async_steps`
-        steps."""
+        blocking read a step); step() does so by itself at each eos
+        check, and one step after each retirement and every
+        `hpx.serving.max_async_steps` steps (all but the step just
+        dispatched)."""
         self._flush()
 
     def poll_finished(self) -> Dict[int, List[int]]:
         """Hand out {request_id: tokens} of the requests finished since
         the last call, and forget them (run() returns through here). A
         request's tokens reach the host at a flush, so a request whose
-        last step is still buffered is not in the result yet."""
+        last step is still buffered (the step() after its last
+        dispatch reads it) is not in the result yet."""
         out, self._done = self._done, {}
         return out
 

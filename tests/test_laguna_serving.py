@@ -111,6 +111,7 @@ def test_prefill_then_paged_decode_logits_equal_the_reference(toy, kernel):
     while srv._slot_req[0] is None:
         srv._admit()
         srv._prefill_tick()
+    srv.flush()             # the seed token is a device value until read
     seq = prompt + [srv._cur[0]]
     for _ in range(steps):
         pos = srv._pos[0]

@@ -199,6 +199,77 @@ def test_mixed_sites_identical(params):
     assert srv.failed == {}
 
 
+# -- recovery with a step in flight (reads lag one step) ---------------------
+
+@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("site", ["decode", "prefill"])
+def test_fault_while_a_step_is_buffered_identical(params, monkeypatch, site,
+                                                  paged):
+    """The decode loop reads one step behind its newest dispatch, so a
+    fault finds a step (and, right after an admission, a seed token)
+    that the host has not read: `_recover` lands them all, restores
+    from checkpoints taken at the host's frontier, and the replay
+    emits the fault-free tokens. prefill_chunk=2 keeps a chunked
+    prefill pending beside a live decode."""
+    kw = dict(prefill_chunk=2)
+    if paged:
+        kw.update(paged=True, block_size=4, num_blocks=64)
+    base, srv0 = _serve(params, **kw)
+    buffered = []
+    orig = ContinuousServer._recover
+
+    def spy(self, attempt, exc):
+        buffered.append(len(self._buf))
+        orig(self, attempt, exc)
+        assert not self._buf and not self._seeds
+        assert all(r is None or r.sent == len(r.tokens)
+                   for r in self._slot_req)
+    monkeypatch.setattr(ContinuousServer, "_recover", spy)
+    got, srv = _serve(params, fi_kw=dict(schedule={site: {3, 6, 8}}), **kw)
+    assert got == base
+    assert len(buffered) == 3 and max(buffered) >= 1
+    assert srv.fault_stats()["restored_by_site"].get(site, 0) >= 1
+    assert srv.failed == {} and srv._ckpt == {}
+    if paged:
+        assert srv._alloc.stats()["free"] == srv0._alloc.stats()["free"]
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_checkpoints_advance_under_steady_decode(params, paged):
+    """One request, no retirement: the only reads are the lagged ones
+    of a full buffer, each of which leaves a step in flight. The
+    checkpoint still advances every ckpt_every tokens, at the frontier
+    the host holds, and a fault restores from the newest."""
+    kw = dict(paged=True, block_size=4, num_blocks=64) if paged else {}
+    prompt, max_new, every = [3, 1, 4, 1, 5], 30, 4
+    want = _ref(params, CFG, prompt, max_new)
+    srv = ContinuousServer(params, CFG, slots=1, smax=64, **kw)
+    srv._ckpt_every, srv._max_async = every, 2
+    rid = srv.submit(prompt, max_new=max_new)
+    seen = []                   # (tokens in the checkpoint, steps in flight)
+    with _inject(schedule={"decode": {20}}):
+        while srv.step():
+            ck = srv._ckpt.get(0)
+            if ck is None:
+                continue
+            n = len(ck.tokens)
+            assert ck.tokens == want[:n] and ck.cur == want[n - 1]
+            assert ck.pos == len(prompt) + n - 1
+            if not seen or seen[-1][0] != n:
+                seen.append((n, len(srv._buf)))
+    sizes = [n for n, _ in seen]
+    assert sizes[0] == 1                            # the seed checkpoint
+    assert len(sizes) >= 5
+    assert all(every <= b - a < every + 2 for a, b in zip(sizes, sizes[1:]))
+    assert all(inflight == 1 for _, inflight in seen)   # no full flush
+    assert srv.poll_finished() == {rid: want}
+    st = srv.fault_stats()
+    assert st["injected"] == 1 and st["restored"] == 1
+    # every read but the fault's drain and the last had a step behind it
+    rs = srv.read_stats()
+    assert rs["reads_draining"] <= 3 < rs["reads_overlapped"]
+
+
 # -- typed errors: shutdown, deadlines, retry exhaustion ---------------------
 
 def test_submit_after_shutdown_raises_typed(params):

@@ -403,13 +403,15 @@ class TestServingSmoke:
         assert len(decodes) >= 2          # two decode steps minimum
         assert len(retires) == 2
 
-        # causal edges: prefill nests under its admit, retire under the
-        # flush of a decode step
+        # causal edges: prefill nests under its admit, retire under a
+        # flush — the one of the step() AFTER the request's last
+        # dispatch (reads lag by one step), which here finds nothing
+        # live and so flushes outside any decode span
         admit_ids = {e[ID] for e in admits}
-        decode_ids = {e[ID] for e in decodes}
+        step_ids = {e[ID] for e in spans_named(ev, "serving.step")}
         flushes = {e[ID]: e for e in spans_named(ev, "serving.flush")}
         assert all(e[PARENT] in admit_ids for e in prefills)
-        assert all(flushes[e[PARENT]][PARENT] in decode_ids
+        assert all(flushes[e[PARENT]][PARENT] in step_ids
                    for e in retires)
         # rid args connect admit to its retire
         rids = {e[ARGS]["rid"] for e in admits}
@@ -495,10 +497,12 @@ class TestProfilerPlane:
         """Two slots. A (3 tokens) and B (5) are admitted in step 1, C
         (2) waits for A's slot: A's last step is dispatched in step 2,
         C's (admitted in step 3) in step 3, B's in step 4; step 5 finds
-        nothing to do. By hand: one blocking read of a first token an
-        admission (3) and one of each decode step's tokens (4), at the
-        flush of a step in which a request is dispatched its last token
-        (steps 2, 3, 4: the first of them reads two steps)."""
+        nothing live. By hand: one blocking read of a first token an
+        admission (3), after that step's decode dispatch, and one of
+        each decode step's tokens (4), at the flush of the step AFTER
+        one in which a request is dispatched its last token (steps 3,
+        4: each leaves the step just dispatched in the buffer; step 3
+        reads two steps) and of step 5, which drains."""
         srv = ContinuousServer(params, CFG, slots=2, smax=48, paged=True)
         with profiling.profile_trace(str(tmp_path)):
             rids = [srv.submit(p, max_new=m) for p, m in
@@ -519,18 +523,22 @@ class TestProfilerPlane:
         flushes = named(spans, "serving.flush")
         decodes = named(spans, "serving.decode")
         assert len(reads) == 3 and len(waits) == 4 and len(decodes) == 4
-        # step 5's flush holds no step and so reads none
-        assert [sp.args["steps"] for sp in flushes] == [2, 1, 1, 0]
+        assert [sp.args["steps"] for sp in flushes] == [3, 2, 1]
         assert [sum(f.holds(w) for w in waits) for f in flushes] == \
-            [2, 1, 1, 0]
-        # each wait lies inside its parent, and that inside a step
+            [2, 1, 1]
+        # every read but the draining one has a step queued behind it
+        assert [sp.args["behind"] for sp in reads] == [1, 1, 1]
+        assert [sp.args["behind"] for sp in waits] == [2, 1, 1, 0]
+        # a first token is read inside the decode span of the step
+        # that admitted it, after the admission
         for read, admit in zip(reads, admits):
-            assert admit.holds(read) and read.args["rid"] == \
-                admit.args["rid"]
-        for wait in waits:
-            (flush,) = [f for f in flushes if f.holds(wait)]
-            (decode,) = [d for d in decodes if d.holds(flush)]
-            assert sum(st.holds(decode) for st in steps) == 1
+            (decode,) = [d for d in decodes if d.holds(read)]
+            (step,) = [st for st in steps if st.holds(decode)]
+            assert step.holds(admit) and admit.end <= read.start
+            assert read.args["rid"] == admit.args["rid"]
+        # a flush lies inside a decode span, but for the draining one
+        assert [sum(d.holds(f) for d in decodes) for f in flushes] == \
+            [1, 1, 0]
         for sp in spans:
             if sp.name != "serving.step":
                 assert sum(st.holds(sp) for st in steps) == 1, sp.name
@@ -551,8 +559,14 @@ class TestProfilerPlane:
         assert ticks and len(chunks) == len(ticks)
         for tick in ticks:
             assert sum(tick.holds(c) for c in chunks) == 1
+        # the first token is read once the step's decode is enqueued:
+        # after the last tick, inside the same step's decode span
         (read,) = named(spans, "serving.first_token.wait")
-        assert ticks[-1].holds(read)
+        (decode,) = [d for d in named(spans, "serving.decode")
+                     if d.holds(read)]
+        (step,) = [st for st in named(spans, "serving.step")
+                   if st.holds(decode)]
+        assert step.holds(ticks[-1]) and ticks[-1].end <= read.start
 
     def test_dataflow_nodes_hold_body_and_dispatch(self, tmp_path):
         from hpx_tpu.exec.tpu import TpuExecutor
