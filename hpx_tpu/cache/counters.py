@@ -65,6 +65,17 @@ Paged servers additionally export the cache counters::
     /cache{locality#L/server#i}/walk-share              the same over the table's
                                                           width
 
+Models with recurrent ("kda") layers add their per-slot state, models
+with latent-attention ("mla") layers their rows on the full group::
+
+    /cache{locality#L/server#i}/state/bytes             the state arrays, all slots
+    /cache{locality#L/server#i}/state/slots-live        slots whose state is a request's
+    /cache{locality#L/server#i}/state/resets            admissions that zeroed a state
+    /cache{locality#L/server#i}/latent/blocks-in-use    blocks of latent rows held
+    /serving{locality#L/server#i}/state/prefix-refused  admissions whose prefix match
+                                                        was refused (no state snapshot)
+    /serving{locality#L/server#i}/state/reprefills      restores that recomputed a state
+
 Models with window layers add their second block group::
 
     /cache{locality#L/server#i}/window/blocks-in-use    window-group blocks held
@@ -208,7 +219,7 @@ def register_server(srv) -> str:
             pc.CallbackCounter(_read(ref, lambda s: s._moe_routed)))
         put("serving", "moe/tokens-dropped",
             pc.CallbackCounter(_read(ref, lambda s: s._moe_dropped)))
-        for e in range(srv.cfg.n_experts):
+        for e in range(len(srv._moe_occ)):      # the experts held
             put("serving", f"moe/expert#{e}/occupancy",
                 pc.CallbackCounter(_read(
                     ref, lambda s, e=e: s._moe_occ[e])))
@@ -273,6 +284,27 @@ def register_server(srv) -> str:
             put("cache", "window/prefix-refused",
                 pc.CallbackCounter(_read(
                     ref, lambda s: s._prefix_refused)))
+        if getattr(srv, "_recurrent", False):
+            # the per-slot recurrent state (serving._init_paged): no
+            # blocks, reset at admission, recomputed at a restore
+            put("cache", "state/bytes",
+                pc.CallbackCounter(_read(ref, lambda s: s._state_bytes)))
+            put("cache", "state/slots-live",
+                pc.CallbackCounter(_read(ref, lambda s: sum(
+                    r is not None for r in s._slot_req)
+                    + len(s._pending))))
+            put("cache", "state/resets",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._state_resets)))
+            put("serving", "state/prefix-refused",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._prefix_refused)))
+            put("serving", "state/reprefills",
+                pc.CallbackCounter(_read(ref, lambda s: s._reprefills)))
+        if "mla" in getattr(srv.cfg, "layer_mixer", ()):
+            # latent rows live on the full group's blocks
+            put("cache", "latent/blocks-in-use",
+                pc.CallbackCounter(_read(ref, lambda s: s._alloc.in_use)))
         if getattr(srv, "_tier", None) is not None:
             # host-RAM demotion tier (cache/tier.py): occupancy,
             # demote/promote/drop/decline totals, cumulative hit

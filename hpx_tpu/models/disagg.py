@@ -207,7 +207,8 @@ class PrefillWorker(_WorkerRing):
                 job.caches = eng._chunk_prog(width)(
                     eng.params, job.caches,
                     jnp.asarray([toks], jnp.int32),
-                    jnp.asarray(job.done, jnp.int32))
+                    jnp.asarray(job.done, jnp.int32),
+                    jnp.asarray(n, jnp.int32))
                 job.done += n
             segs: List[KVSegment] = []
             # pre-probe emission cap: row plen-1 is rewritten by the

@@ -58,18 +58,28 @@ class MoeConfig:
     renorm: bool = False           # weights / their sum over the chosen
     scale: float = 1.0             # routed_scaling_factor
     shared_d_ff: int = 0           # one shared expert every token takes
+    bias: bool = False             # a selection bias params["bias"] [E]
+    # (lo, hi): the share of the experts these parameters hold (empty:
+    # all). The router keeps its n_experts columns (moe_ffn_serve).
+    held: Tuple[int, ...] = ()
 
 
 def init_moe_params(cfg: MoeConfig, key: jax.Array) -> Dict[str, Any]:
     k1, k2, k3 = jax.random.split(key, 3)
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
     s = 1.0 / math.sqrt(d)
+    wg = (jax.random.normal(k1, (d, e)) * s).astype(cfg.dtype)
+    if cfg.held:
+        e = cfg.held[1] - cfg.held[0]      # the router stays full width
     out = {
-        "wg": (jax.random.normal(k1, (d, e)) * s).astype(cfg.dtype),
+        "wg": wg,
         "w1": (jax.random.normal(k2, (e, d, f)) * s).astype(cfg.dtype),
         "w2": (jax.random.normal(k3, (e, f, d)) / math.sqrt(f)
                ).astype(cfg.dtype),
     }
+    if cfg.bias:
+        out["bias"] = 0.1 * jax.random.normal(
+            jax.random.fold_in(key, 2), (cfg.n_experts,), jnp.float32)
     if cfg.mlp != "swiglu":
         out["b1"] = jnp.zeros((e, f), cfg.dtype)
         return out
@@ -181,8 +191,9 @@ def moe_ffn(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
     if cfg.top_k > e:
         # an all-masked gate row would silently re-route to expert 0
         raise ValueError(f"top_k ({cfg.top_k}) > n_experts ({e})")
-    if (cfg.mlp, cfg.router, cfg.renorm, cfg.shared_d_ff) != (
-            "gelu", "softmax", False, 0) or cfg.scale != 1.0:
+    if (cfg.mlp, cfg.router, cfg.renorm, cfg.shared_d_ff, cfg.bias,
+            tuple(cfg.held)) != ("gelu", "softmax", False, 0, False, ()) \
+            or cfg.scale != 1.0:
         raise NotImplementedError(
             "models/moe.moe_ffn (GShard capacity dispatch: training, "
             "expert-parallel decode, a finite hpx.serving.moe."
@@ -287,16 +298,23 @@ def moe_ffn_decode(x: jax.Array, params: Dict[str, Any],
     return out, jax.lax.pmean(aux, axis), stats
 
 
-def route(x: jax.Array, wg: jax.Array, cfg: MoeConfig):
+def route(x: jax.Array, wg: jax.Array, cfg: MoeConfig, bias=None):
     """Scores -> the top_k experts of every token and their weights:
     (idx [T, k] int32, w [T, k] f32). Scores in float32 (`softmax` over
     the experts, or element-wise `sigmoid`); the k largest win, ties to
-    the lower expert id; with `renorm` the weights are divided by their
-    sum over the chosen k; then scaled."""
+    the lower expert id; `bias` [E] (a selection bias) is added for the
+    CHOICE only, the weights are the chosen experts' plain scores; with
+    `renorm` the weights are divided by their sum over the chosen k;
+    then scaled."""
     logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)
     scores = (jax.nn.sigmoid(logits) if cfg.router == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
-    w, idx = jax.lax.top_k(scores, cfg.top_k)
+    if bias is None:
+        w, idx = jax.lax.top_k(scores, cfg.top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                               cfg.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
     if cfg.renorm:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return idx, w * cfg.scale
@@ -336,7 +354,8 @@ def moe_ffn_serve(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
     under its weights (a gather, no scatter-add: deterministic), plus
     the shared expert where the model has one.
 
-    `held=(lo, hi)`: this shard's SHARE of the experts. `params` then
+    `held=(lo, hi)` (default: `cfg.held`): this shard's SHARE of the
+    experts. `params` then
     hold experts lo..hi-1 only ([hi - lo, ...] matrices) and, on the
     one shard that is to count it, the shared expert; the router keeps
     its published width, every token is routed over all the experts,
@@ -353,11 +372,12 @@ def moe_ffn_serve(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
     e, k, dt = cfg.n_experts, cfg.top_k, cfg.dtype
     if k > e:
         raise ValueError(f"top_k ({k}) > n_experts ({e})")
-    idx, w = route(x, params["wg"], cfg)
+    idx, w = route(x, params["wg"], cfg, params.get("bias"))
     a = t * k
     flat = idx.reshape(a)
     mine = None
-    if held is not None:
+    held = held or cfg.held
+    if held:
         lo, hi = held
         mine = jnp.logical_and(flat >= lo, flat < hi)
         e = hi - lo

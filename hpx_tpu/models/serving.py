@@ -100,10 +100,12 @@ from ..core.errors import Error, HpxError
 from ..svc import faultinject, flight, tracing
 from ..svc.resiliency import sync_replay
 from ..ops.attention_pallas import resolve_paged_block
+from ..ops.kda import kda_mix
 from ..ops.paged_attention import (
     block_rows,
     gather_block_kv,
     paged_decode_attention,
+    paged_latent_attention,
     paged_window_attention,
     scatter_seq_blocks,
     scatter_seq_blocks_q,
@@ -435,7 +437,12 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
     tensor-parallel axis: every shard sees its LOCAL kv-head slice of
     the pools (block axis replicated over dp) and the partial attention
     / ffn outputs close with explicit psums; `write` as in
-    `_write_rows`."""
+    `_write_rows`.
+
+    A layer's MIXER kind names what `pools` holds: K/V pools (above); a
+    "kda" layer's per-slot (state [B, H, d, d] float32, conv tail [B,
+    K - 1, 3 H d]), no table, no positions; an "mla" layer's (latent
+    pool [num_blocks, 1, block_size, R],) on the full group's table."""
     w = x.shape[1]
     posw = pos0[:, None] + jnp.arange(w)[None, :]
     kw = {"fused": fused, "window": cfg.window(li)}
@@ -450,6 +457,23 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
         kn, vn, wr = _write_rows(k, v, write)
         out = paged(q, kn, vn, *pools, table, pos0, write=wr, **kw)
         return out[0], (out[1:3], tuple(out[3:]) or None)
+
+    if "kda" in lp:
+        def attend(pre, g, beta):                           # noqa: F811
+            o, carry = kda_mix(pre, g, beta, lp["kda"]["conv"], *pools)
+            return o, (carry, None)
+    elif "mla" in lp:
+        def attend(q, row):                                 # noqa: F811
+            if w != 1:
+                raise NotImplementedError(
+                    "a W-token window over a paged latent pool "
+                    "(ops/paged_attention.paged_latent_attention "
+                    "attends one row a slot)")
+            o, pool = paged_latent_attention(
+                q[:, 0], row[:, 0], pools[0], table, pos0,
+                rank=cfg.mla_rank, fused=fused,
+                scale=(cfg.mla_nope_dim + cfg.mla_rope_dim) ** -0.5)
+            return o[:, None], ((pool,), None)
 
     x, (pools, scales) = _layer(
         x, lp, cfg, li, posw, attend, tp_axis,
@@ -492,6 +516,22 @@ def _paged_decode_rows(params, pools, scales, tok, tables, pos, cfg,
         params, pools, scales, tok[:, None], tables, pos, cfg, fused,
         tp_axis, moe_cf, moe_ep, dp)
     return pools, scales, logits[:, 0, :], ms
+
+
+def _scratch_entry(cfg: TransformerConfig, smax: int, i: int):
+    """Layer i's part of an empty b=1 prefill scratch, by its mixer's
+    kind: (k, v) [1, smax, n_kv, hd]; (latent rows [1, smax, 1, R],);
+    or (state [1, H, d, d] float32, conv tail [1, K - 1, 3 H d]), zeros
+    being the state of no tokens."""
+    kind = cfg.mixer(i)
+    if kind == "mla":
+        return (jnp.zeros((1, smax, 1, cfg.mla_row), cfg.dtype),)
+    if kind == "kda":
+        h, d = cfg.kda_heads, cfg.kda_head_dim
+        return (jnp.zeros((1, h, d, d), jnp.float32),
+                jnp.zeros((1, cfg.kda_conv - 1, 3 * h * d), cfg.dtype))
+    shape = (1, smax, cfg.kv_heads, cfg.head_dim)
+    return (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
 def _verify_tail(logits, toks, kvec, temp, keys, pos0, width):
@@ -601,10 +641,14 @@ class _PendingPrefill:
                                    # entries point at trash)
     wt: Optional[WindowTable] = None   # blocks held in the window group
     flow: Optional[int] = None     # tracing flow id chaining the chunks
+    hold: int = 0                  # prompt tokens the chunks leave to
+                                   # the probe (1 on a recurrent model:
+                                   # the probe is no idempotent rewrite
+                                   # there, it consumes the last token)
 
     @property
     def remaining(self) -> int:
-        return len(self.req.prompt) - self.done
+        return len(self.req.prompt) - self.hold - self.done
 
 
 class ContinuousServer:
@@ -685,6 +729,21 @@ class ContinuousServer:
         self._win = wins.pop() if wins else 0
         from ..core.config import runtime_config
         rc = runtime_config()
+        # the mixer kinds beside softmax attention: a "kda" layer keeps
+        # a per-slot recurrent state, an "mla" layer latent rows; both
+        # live in the paged cache pytree only (`_init_paged`)
+        self._recurrent = cfg.recurrent
+        self._kinds = kinds = sorted(set(cfg.layer_mixer) - {"attn"})
+        for what, on in (
+                ("paged=False: the dense per-slot caches hold K/V "
+                 "pairs", not self.paged),
+                ("a (dp, tp) mesh: the state and the latent pool have "
+                 "no placement", mesh is not None)):
+            if kinds and on:
+                raise NotImplementedError(
+                    f"{what}; a model with {kinds} mixers runs "
+                    "on ContinuousServer(paged=True) on one device "
+                    "(models/serving.py _init_paged)")
         cache_sh = None
         if self.paged and mesh is not None and \
                 not rc.get_bool("hpx.serving.mesh.paged", True):
@@ -731,7 +790,7 @@ class ContinuousServer:
                                   else max(1, int(pct)))
         self._moe_routed = 0.0
         self._moe_dropped = 0.0
-        self._moe_occ = [0.0] * max(0, cfg.n_experts)
+        self._moe_occ = [0.0] * max(0, cfg.experts_held)
         self._moe_hit_sum = 0.0     # sum over drained steps of the
         self._moe_steps = 0         # occupancy vector's total
         self._moe_buf: deque = deque()
@@ -759,6 +818,13 @@ class ContinuousServer:
         if spec is None:
             spec = rc.get_bool("hpx.serving.spec.enable", False)
         self._spec = bool(spec)
+        if self._spec and kinds:
+            raise NotImplementedError(
+                "speculative verify on a model with "
+                f"{kinds} mixers: a rejected draft needs the "
+                "recurrent state rolled back, and the latent pool "
+                "attends one row a slot (models/serving.py _spec_step, "
+                "ops/kda.py, ops/paged_attention.paged_latent_attention)")
         if self._spec and self._win:
             raise NotImplementedError(
                 "speculative verify on a model with window layers: a "
@@ -1012,7 +1078,10 @@ class ContinuousServer:
             # worst-case live demand (every slot at smax) + the trash
             # block + equal headroom for radix retention, so prefix
             # chains persist before OOM-eviction starts recycling them
-            num_blocks = 2 * slots * self._maxb + 1
+            # (none on a recurrent model: prefix reuse is refused
+            # there, the headroom would hold nothing)
+            num_blocks = (1 if self._recurrent else 2) \
+                * slots * self._maxb + 1
         if num_blocks < self._maxb + 1:
             raise ValueError(
                 f"num_blocks {num_blocks} cannot hold one max-length "
@@ -1036,6 +1105,17 @@ class ContinuousServer:
         # tokens between two captures and in flight) and a trash block.
         self._ring = self._walloc = self._wtrash = None
         self._win_freed = self._prefix_refused = 0
+        self._state_resets = self._reprefills = 0
+        for what, on in (("a quantized hpx.cache.kv_dtype (a quantized "
+                          "latent row has no write or kernel)",
+                          self._kv_dtype != "bf16"),
+                         ("the host tier (it demotes K/V pairs)",
+                          rc.get_bool("hpx.cache.tier.enable", False))):
+            if self._kinds and on:
+                raise NotImplementedError(
+                    f"{what} on a model with {self._kinds} mixers "
+                    "(models/serving.py _init_paged, ops/paged_attention"
+                    ".paged_latent_attention)")
         if self._win:
             for what, on in (("a (dp, tp) mesh", self.mesh is not None),
                              ("a quantized hpx.cache.kv_dtype",
@@ -1118,9 +1198,25 @@ class ContinuousServer:
         def wzeros():
             return jnp.zeros((self._walloc.num_blocks, nkv, bs, hd),
                              cfg.dtype)
-        self._pools = [(wzeros(), wzeros()) if cfg.window(i)
-                       else (pzeros(), pzeros())
-                       for i in range(cfg.n_layers)]
+
+        def entry(i):
+            """Layer i's part of the cache pytree, by its mixer's kind:
+            two K/V pools; ONE pool of latent rows on the full group's
+            table; or the per-slot recurrent state and conv tail (no
+            blocks, no positions: `_fresh_scratch` is a slot's row)."""
+            kind = cfg.mixer(i)
+            if kind == "mla":
+                return (jnp.zeros((num_blocks, 1, bs, cfg.mla_row),
+                                  cfg.dtype),)
+            if kind == "kda":
+                return tuple(jnp.zeros((slots,) + a.shape[1:], a.dtype)
+                             for a in _scratch_entry(cfg, smax, i))
+            return (wzeros(), wzeros()) if cfg.window(i) \
+                else (pzeros(), pzeros())
+        self._pools = [entry(i) for i in range(cfg.n_layers)]
+        self._state_bytes = sum(
+            a.nbytes for i, e in enumerate(self._pools)
+            if cfg.mixer(i) == "kda" for a in e)
         if self._kv_dtype in ("int8", "fp8"):
             def sones():
                 # scale 1.0 is quantize_blocks' zero-block convention:
@@ -1223,15 +1319,17 @@ class ContinuousServer:
         prompt length — the whole point. Pad rows land past the real
         frontier; they are never attended (causal mask) and the next
         chunk or the decode steps overwrite them before their
-        positions ever go live."""
+        positions ever go live. `n`: how many of the columns are real
+        (a recurrent layer's state consumes those alone)."""
         cfg, smax = self.cfg, self.smax
         ck = ("cb_chunk", cfg, width, smax, self.mesh,
               _tree_key(self.params))
 
         def build():
-            def chunk(params, caches, toks, pos0):
+            def chunk(params, caches, toks, pos0, n):
                 caches, _ = _decode_window(params, caches, toks, pos0,
-                                           cfg, need_logits=False)
+                                           cfg, need_logits=False,
+                                           valid=n)
                 return caches
             return jax.jit(chunk, donate_argnums=(1,))
         return self._program(ck, build)
@@ -1240,7 +1338,10 @@ class ContinuousServer:
         """Seed-logits probe: rerun the LAST prompt token at its own
         position (an idempotent K/V rewrite — same bytes) and return
         its logits. One program serves every prompt length, so the
-        chunk programs never need a logits variant per bucket."""
+        chunk programs never need a logits variant per bucket. On a
+        recurrent model the chunks stop one token short
+        (`_PendingPrefill.hold`) and the probe is that token's one and
+        only pass."""
         cfg, smax = self.cfg, self.smax
         ck = ("cb_probe", cfg, smax, self.mesh, _tree_key(self.params))
 
@@ -1378,13 +1479,9 @@ class ContinuousServer:
             def gather(pools, scales, trow, valid):
                 keep = (jnp.arange(rows) < valid)[None, :, None, None]
                 if scales is None:
-                    return [(jnp.where(keep,
-                                       gather_block_kv(kp, trow[None]),
-                                       0),
-                             jnp.where(keep,
-                                       gather_block_kv(vp, trow[None]),
-                                       0))
-                            for kp, vp in pools]
+                    return [tuple(jnp.where(
+                        keep, gather_block_kv(p, trow[None]), 0)
+                        for p in pl) for pl in pools]
                 return [(jnp.where(keep,
                                    gather_block_kv(kp, trow[None], ks,
                                                    dt), 0),
@@ -1407,7 +1504,8 @@ class ContinuousServer:
         skipping it is what keeps prefix reuse exact. The trash-padded
         tail (and the redirected prefix) is garbage-on-garbage (see
         scatter_seq_blocks); int8 splices quantize whole blocks here
-        (scatter_seq_blocks_q)."""
+        (scatter_seq_blocks_q). `slot`: where a recurrent layer's
+        scratch state lands (it has no blocks)."""
         cfg = self.cfg
         nb, bs = self._alloc.num_blocks, self.block_size
         maxb = self._maxb
@@ -1418,18 +1516,26 @@ class ContinuousServer:
         def build():
             pool_sh, scale_sh = self._pool_sh, self._scale_sh
 
-            def splice(pools, scales, one, wrows):
+            def splice(pools, scales, one, wrows, slot):
                 outp, outs = [], []
-                for i, ((kp, vp), (kc, vc)) in enumerate(
-                        zip(pools, one)):
+                for i, (pl, sc) in enumerate(zip(pools, one)):
                     wrow = wrows[1 if cfg.window(i) else 0]
-                    kseg = kc[0].reshape(maxb, bs, *kc.shape[2:])
-                    vseg = vc[0].reshape(maxb, bs, *vc.shape[2:])
-                    if scales is None:
-                        outp.append(
-                            (scatter_seq_blocks(kp, wrow, kseg),
-                             scatter_seq_blocks(vp, wrow, vseg)))
+                    if cfg.mixer(i) == "kda":
+                        # the slot's row of the state and the tail,
+                        # whole: nothing of the last occupant survives
+                        outp.append(tuple(
+                            jax.lax.dynamic_update_index_in_dim(
+                                p, c[0], slot, 0)
+                            for p, c in zip(pl, sc)))
+                    elif scales is None:
+                        outp.append(tuple(
+                            scatter_seq_blocks(p, wrow, c[0].reshape(
+                                maxb, bs, *c.shape[2:]))
+                            for p, c in zip(pl, sc)))
                     else:
+                        (kp, vp), (kc, vc) = pl, sc
+                        kseg = kc[0].reshape(maxb, bs, *kc.shape[2:])
+                        vseg = vc[0].reshape(maxb, bs, *vc.shape[2:])
                         ks, vs = scales[i]
                         kp, ks = scatter_seq_blocks_q(kp, ks, wrow,
                                                       kseg)
@@ -1469,10 +1575,11 @@ class ContinuousServer:
             def copy(pools, scales, src, dst):
                 # full-group ids: a window layer's pools are another
                 # group's (nothing shares its blocks, none is forked)
-                pools = [(kp, vp) if self.cfg.window(i)
-                         else (kp.at[dst].set(kp[src]),
-                               vp.at[dst].set(vp[src]))
-                         for i, (kp, vp) in enumerate(pools)]
+                # (a recurrent layer's state has no blocks at all)
+                pools = [pl if self.cfg.window(i)
+                         or self.cfg.mixer(i) == "kda"
+                         else tuple(p.at[dst].set(p[src]) for p in pl)
+                         for i, pl in enumerate(pools)]
                 if scales is not None:
                     scales = [(ks.at[dst].set(ks[src]),
                                vs.at[dst].set(vs[src]))
@@ -1752,7 +1859,7 @@ class ContinuousServer:
             return
         self._free_window(self._wtables[slot])
         self._wtables[slot] = None
-        if self._prefix_reuse and not self._win:
+        if self._prefix_reuse and not (self._win or self._recurrent):
             nfull = len(req.prompt) // self.block_size
             if nfull:
                 self._radix.insert(
@@ -1894,6 +2001,17 @@ class ContinuousServer:
             st["window_in_use"] = self._walloc.in_use
             st["window_blocks_freed"] = self._win_freed
             st["window_prefix_refused"] = self._prefix_refused
+        if self._recurrent:
+            # the second kind of cached state: per-slot, no blocks
+            st["state_bytes"] = self._state_bytes
+            st["state_slots_live"] = sum(
+                1 for r in self._slot_req if r is not None) \
+                + len(self._pending)
+            st["state_resets"] = self._state_resets
+            st["state_prefix_refused"] = self._prefix_refused
+            st["state_reprefills"] = self._reprefills
+        if "mla" in self._kinds:
+            st["latent_blocks_in_use"] = self._alloc.in_use
         st.update(self.hbm_read_stats())
         if self.mesh is not None:
             # per-dp-shard slot accounting: slots map to dp shards by
@@ -1944,9 +2062,14 @@ class ContinuousServer:
         live = sum(1 for pt in self._tables if pt is not None)
         blocks = occupancy(self._tables)
         per_tok = (blocks / live) if live else 0.0
+        kinds = [self.cfg.mixer(i) for i in range(self.cfg.n_layers)]
         bb = block_bytes(self.block_size, self.cfg.kv_heads,
                          self.cfg.head_dim, self._kv_acct_dtype(),
-                         layers=self.cfg.n_layers)
+                         layers=kinds.count("attn"))
+        # a latent layer's block is one pool's rows (a recurrent
+        # layer has none)
+        bb += kinds.count("mla") * self.block_size * self.cfg.mla_row \
+            * jnp.dtype(self.cfg.dtype).itemsize
         walks = [min(p // self.block_size + 1, self._maxb)
                  for p in self.live_positions().values()]
         walk = sum(walks) / len(walks) if walks else 0.0
@@ -2075,6 +2198,7 @@ class ContinuousServer:
             raise ValueError(
                 "admit_prefilled() requires paged=True (the transfer "
                 "protocol ships block-granular KV)")
+        self._only_kv_pairs("admit_prefilled()")
         if self._win:
             raise NotImplementedError(
                 "admit_prefilled() on a model with window layers: the "
@@ -2133,6 +2257,7 @@ class ContinuousServer:
         BYTES, not references — nothing here can leak pool blocks)."""
         if not self.paged:
             raise ValueError("export_prefix_rows() requires paged=True")
+        self._only_kv_pairs("export_prefix_rows()")
         matched, bids = self._radix.match(tokens)
         if not matched:
             return 0, None
@@ -2161,6 +2286,16 @@ class ContinuousServer:
                 self._alloc.decref(bid)
         return matched, rows
 
+    def _only_kv_pairs(self, what: str) -> None:
+        """Refuse a transfer of cache rows on a model some of whose
+        layers cache no K/V pair."""
+        if self._kinds:
+            raise NotImplementedError(
+                f"{what} on a model with {self._kinds} mixers: the "
+                "transfer protocol ships K/V rows a layer, and a "
+                "recurrent state or a latent row is neither "
+                "(models/serving.py, models/disagg.py, cache/transfer.py)")
+
     def shutdown(self) -> None:
         """Close the intake: every later submit() raises
         ServerClosedError. Queued and in-flight requests are NOT
@@ -2178,11 +2313,13 @@ class ContinuousServer:
         return self.prefill_buckets[-1]
 
     def _fresh_scratch(self):
-        """An empty b=1 prefill scratch: (k, v) [1, smax] a layer."""
-        shape = (1, self.smax, self.cfg.kv_heads, self.cfg.head_dim)
-        return [(jnp.zeros(shape, self.cfg.dtype),
-                 jnp.zeros(shape, self.cfg.dtype))
-                for _ in range(self.cfg.n_layers)]
+        """An empty b=1 prefill scratch, one entry a layer, made by ONE
+        program: an admission enqueues one dispatch for it, not two
+        tiny ones a layer."""
+        cfg, smax = self.cfg, self.smax    # not `self`: the program
+        ck = ("cb_scratch", cfg, smax)      # cache outlives the server
+        return self._program(ck, lambda: jax.jit(lambda: [
+            _scratch_entry(cfg, smax, i) for i in range(cfg.n_layers)]))()
 
     def _start_prefill(self, req: "_Request",
                        slot: int) -> _PendingPrefill:
@@ -2204,10 +2341,12 @@ class ContinuousServer:
                      slot: int) -> _PendingPrefill:
         plen = len(req.prompt)
         matched, mbids, tier_ext = 0, [], []
-        if self._prefix_reuse and self._win:
+        if self._prefix_reuse and (self._win or self._recurrent):
             # a prefix hit would hand the full layers their rows and
             # leave the window layers without the matched prefix's last
-            # window: refused (and counted) until the tree keeps it
+            # window, a recurrent layer without its state at the
+            # block's boundary: refused (and counted) until the tree
+            # keeps them
             self._prefix_refused += 1
         elif self._prefix_reuse:
             # always leave >= 1 suffix token: admission needs the LAST
@@ -2244,6 +2383,18 @@ class ContinuousServer:
         wnp = row.copy()
         wnp[:matched // self.block_size] = self._trash
         wrow = (jnp.asarray(wnp),)
+        if self._recurrent:
+            # the request's state starts from zeros in its scratch and
+            # the splice overwrites the slot's row whole: the reset
+            n_rec = sum(self.cfg.mixer(i) == "kda"
+                        for i in range(self.cfg.n_layers))
+            with tracing.span("serving.state_reset", "serving",
+                              rid=req.rid, slot=slot, layers=n_rec):
+                caches = self._fresh_scratch()
+                self._state_resets += 1
+            return _PendingPrefill(req=req, slot=slot, caches=caches,
+                                   done=0, seq=self._pf_seq, pt=pt,
+                                   trow=trow, wrow=wrow, hold=1)
         if not self._win:
             caches = self._paged_gather_prog()(
                 self._pools, self._scales, trow, jnp.int32(matched))
@@ -2281,7 +2432,9 @@ class ContinuousServer:
         """
         faultinject.check("prefill")
         req, plen = p.req, len(p.req.prompt)
-        n = min(self.prefill_chunk, plen - p.done)
+        n = min(self.prefill_chunk, p.remaining)
+        if n == 0:      # a one-token prompt whose token the probe takes
+            return
         width = self._bucket_width(n)
         toks = req.prompt[p.done:p.done + n] + [0] * (width - n)
         with tracing.span("serving.prefill_chunk", "serving",
@@ -2292,10 +2445,10 @@ class ContinuousServer:
                 p.flow = None
             p.caches = self._chunk_prog(width)(
                 self.params, p.caches, jnp.asarray([toks], jnp.int32),
-                jnp.asarray(p.done, jnp.int32))
+                jnp.asarray(p.done, jnp.int32), jnp.asarray(n, jnp.int32))
             p.done += n
             self._chunks += 1
-            if p.done < plen:
+            if p.remaining:
                 p.flow = tracing.flow_begin("serving.prefill_chunks")
 
     def _finish_prefill(self, p: _PendingPrefill) -> None:
@@ -2319,7 +2472,8 @@ class ContinuousServer:
             p.flow = None
         if self.paged:
             self._pools, self._scales = self._paged_splice_prog()(
-                self._pools, self._scales, caches, p.wrow)
+                self._pools, self._scales, caches, p.wrow,
+                jnp.asarray(slot, jnp.int32))
             self._tables[slot], self._wtables[slot] = p.pt, p.wt
         else:
             self._caches = self._splice_prog()(
@@ -2477,7 +2631,8 @@ class ContinuousServer:
                 jnp.asarray(rows[li, 1], self.cfg.dtype))
             scratch.append((k, v))
         self._pools, self._scales = self._paged_splice_prog()(
-            self._pools, self._scales, scratch, trow)
+            self._pools, self._scales, scratch, trow,
+            jnp.asarray(slot, jnp.int32))
         self._tables[slot] = pt
         req.xfer_rows = None           # host copy no longer needed
         tok0 = int(req.xfer_seed)
@@ -2743,7 +2898,7 @@ class ContinuousServer:
         req = self._slot_req[slot]
         pos = len(req.prompt) + len(req.tokens) - 1
         pins: List[int] = []
-        if self.paged:
+        if self.paged and not self._recurrent:
             pt = self._tables[slot]
             pins = list(pt.blocks[:pos // self.block_size])
             for bid in pins:
@@ -2803,6 +2958,8 @@ class ContinuousServer:
         so a restored run's outputs match the fault-free run."""
         ck = self._ckpt[slot]
         req = self._slot_req[slot]
+        if self._recurrent:
+            return self._restore_recurrent(slot, req)
         with tracing.span("serving.restore", "serving", rid=req.rid,
                           slot=slot, pos=ck.pos,
                           replayed=len(req.tokens) - len(ck.tokens)):
@@ -2840,18 +2997,43 @@ class ContinuousServer:
                     self._wtables[slot] = WindowTable(
                         self.block_size, self._win, *ck.wpins)
             else:
-                self._reprefill_dense(slot, req.prompt
-                                      + req.tokens[:-1])
+                self._caches = self._splice_prog()(
+                    self._caches,
+                    self._reprefill(req.prompt + req.tokens[:-1]),
+                    jnp.asarray(slot, jnp.int32))
             if self._spec and self._draft_params is not None:
                 self._draft_prefill(slot, req.prompt
                                     + req.tokens[:-1])
         self._flt_restored += 1
 
-    def _reprefill_dense(self, slot: int, seq: List[int]) -> None:
-        """Dense restore path: rebuild the slot's cache rows
-        [0, len(seq)) by re-running bucketed prefill over the known
-        token sequence into a fresh b=1 scratch, then splice. No
-        probe: the checkpoint already knows the feedback token."""
+    def _restore_recurrent(self, slot: int, req: "_Request") -> None:
+        """Restore of a slot whose layers keep a recurrent state: that
+        state is a function of the tokens and cannot be rewound, so no
+        snapshot of it is kept (no copy, no second buffer). The host
+        holds every token the slot has landed (`_recover` flushed
+        first): the state, the conv tails and the latent rows of
+        prompt ++ tokens[:-1] are recomputed into a fresh scratch and
+        spliced over the slot's row and blocks, and the slot goes on
+        from the host's frontier with nothing to replay."""
+        seq = req.prompt + req.tokens[:-1]
+        with tracing.span("serving.reprefill", "serving", rid=req.rid,
+                          slot=slot, tokens=len(seq)):
+            req.sent = len(req.tokens)
+            self._pos[slot] = len(seq)
+            self._cur[slot] = req.tokens[-1]
+            pt = self._tables[slot]
+            wrow = (jnp.asarray(pt.as_row(self._maxb, self._trash)),)
+            self._pools, self._scales = self._paged_splice_prog()(
+                self._pools, self._scales, self._reprefill(seq), wrow,
+                jnp.asarray(slot, jnp.int32))
+            self._reprefills += 1
+        self._flt_restored += 1
+
+    def _reprefill(self, seq: List[int]):
+        """The restore path that recomputes: a fresh b=1 scratch with
+        the cache state of `seq`, by re-running bucketed prefill over
+        the known tokens (the caller splices it). No probe: the
+        restore point already knows the feedback token."""
         scratch = self._fresh_scratch()
         done = 0
         while done < len(seq):
@@ -2861,10 +3043,9 @@ class ContinuousServer:
             scratch = self._chunk_prog(width)(
                 self.params, scratch,
                 jnp.asarray([toks], jnp.int32),
-                jnp.asarray(done, jnp.int32))
+                jnp.asarray(done, jnp.int32), jnp.asarray(n, jnp.int32))
             done += n
-        self._caches = self._splice_prog()(
-            self._caches, scratch, jnp.asarray(slot, jnp.int32))
+        return scratch
 
     def _drop_pending(self, slot: int) -> _PendingPrefill:
         """Tear down one in-flight prefill (blocks decref'd, trace
@@ -3359,6 +3540,23 @@ class ContinuousServer:
         dispatch reads it) is not in the result yet."""
         out, self._done = self._done, {}
         return out
+
+    def recurrent_state(self, slot: int):
+        """(tokens, state) of a live slot on a recurrent model: the
+        token ids its recurrent state has consumed (prompt ++ every
+        landed token but the last, which is fed next) and the float32
+        state [H, d, d] of the model's FIRST recurrent layer, read from
+        the device. For a caller that checks the state against a
+        recomputation; call `flush()` first, so that no step is in
+        flight and the host's frontier is the device's."""
+        req = self._slot_req[slot]
+        if not self._recurrent or req is None or self._buf:
+            raise ValueError("recurrent_state() needs a recurrent model, "
+                             "a live slot and no step in flight "
+                             "(flush() first)")
+        li = self.cfg.layer_mixer.index("kda")
+        return (req.prompt + req.tokens[:-1],
+                np.asarray(self._pools[li][0][slot]))
 
     def live_positions(self) -> Dict[int, int]:
         """{slot: next write position} of every live slot: what the

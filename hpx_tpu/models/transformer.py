@@ -149,6 +149,24 @@ class TransformerConfig:
     moe_router: str = "softmax"     # | "sigmoid"
     moe_renorm: bool = False        # weights / their sum over the top k
     moe_scale: float = 1.0
+    moe_bias: bool = False          # a selection bias: the choice only
+    # the SHARE of the experts this program holds, (lo, hi); empty =
+    # all n_experts. The router keeps its published width either way.
+    moe_held: Tuple[int, ...] = ()
+    # the MIXER of layer i: "attn" (softmax attention over K/V pairs,
+    # what the fields above describe), "kda" (a gated delta rule over a
+    # per-slot recurrent state, ops/kda.py) or "mla" (latent attention:
+    # one cached row of mla_rank + mla_rope_dim values a token, no
+    # rotation). Empty = every layer "attn".
+    layer_mixer: Tuple[str, ...] = ()
+    kda_heads: int = 0              # heads of kda_head_dim x kda_head_dim
+    kda_head_dim: int = 0
+    kda_conv: int = 4               # taps of the short convolution
+    kda_rank: int = 0               # low-rank width of the two gates
+    mla_rank: int = 0               # the compressed K/V row (kv_lora_rank)
+    mla_nope_dim: int = 0           # a head's q/k dims against the latent
+    mla_rope_dim: int = 0           # a head's dims against the shared r
+    mla_v_dim: int = 0              # a head's value dims
 
     @property
     def kv_heads(self) -> int:
@@ -170,13 +188,46 @@ class TransformerConfig:
             return bool(self.layer_sparse[i])
         return self.n_experts > 0
 
+    def mixer(self, i: int) -> str:
+        return self.layer_mixer[i] if self.layer_mixer else "attn"
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps a per-slot state that is a function of the
+        tokens consumed, not rows addressed by position."""
+        return "kda" in self.layer_mixer
+
+    @property
+    def mla_row(self) -> int:
+        """Width of a cached latent row: rank + rope dims, rounded up
+        to whole 128-lane rows (the chip pads the minor dim to that
+        anyway; the pad columns are written as zeros)."""
+        return -(-(self.mla_rank + self.mla_rope_dim) // 128) * 128
+
+    @property
+    def experts_held(self) -> int:
+        return (self.moe_held[1] - self.moe_held[0] if self.moe_held
+                else self.n_experts)
+
+    def kv_pairs_only(self, body: str, module: str) -> None:
+        """Refuse a body whose caches hold K/V pairs addressed by
+        position a model some of whose mixers cache something else."""
+        kinds = sorted(set(self.layer_mixer) - {"attn"})
+        if kinds:
+            raise NotImplementedError(
+                f"{body} ({module}) cannot compute this model: its "
+                f"caches hold K/V pairs, `layer_mixer` has {kinds}; "
+                "ContinuousServer(paged=True) holds the recurrent state "
+                "and the latent rows (models/serving.py _init_paged)")
+
     def only(self, body: str, module: str, *allowed: str) -> None:
         """Refuse, by mechanism and module, a model whose layers `body`
         cannot compute: every layer-describing field outside `allowed`
         must be at its default."""
         for f in ("norm", "mlp", "tied", "attn_gate", "layer_heads",
                   "layer_window", "layer_rope", "layer_sparse",
-                  "moe_shared_d_ff", "moe_router", "moe_renorm"):
+                  "moe_shared_d_ff", "moe_router", "moe_renorm",
+                  "moe_bias", "moe_held", "layer_mixer"):
             if f not in allowed and getattr(self, f) != getattr(
                     TransformerConfig, f):
                 raise NotImplementedError(
@@ -214,7 +265,8 @@ def _moe_cfg(cfg: TransformerConfig):
                      dtype=cfg.dtype, mlp=cfg.mlp,
                      router=cfg.moe_router, renorm=cfg.moe_renorm,
                      scale=cfg.moe_scale,
-                     shared_d_ff=cfg.moe_shared_d_ff)
+                     shared_d_ff=cfg.moe_shared_d_ff,
+                     bias=cfg.moe_bias, held=cfg.moe_held)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
@@ -224,8 +276,49 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     keys = jax.random.split(key, 2 + cfg.n_layers)
     s = 1.0 / math.sqrt(d)
 
+    def nrm(k, shape, scale):
+        return (jax.random.normal(k, shape) * scale).astype(cfg.dtype)
+
+    def mixer(k, kind):
+        """The leaves of a "kda" / "mla" mixer (its own `wo` among
+        them); the small gate and decay parameters stay float32."""
+        ks = jax.random.split(k, 12)
+        if kind == "kda":
+            h, hd, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank
+            return {"kda": {
+                "wqkv": nrm(ks[0], (d, 3, h, hd), s),
+                "conv": nrm(ks[1], (cfg.kda_conv, 3, h, hd),
+                            1.0 / math.sqrt(cfg.kda_conv)),
+                "wf1": nrm(ks[2], (d, r), s),
+                "wf2": nrm(ks[3], (r, h, hd), 1.0 / math.sqrt(r)),
+                "A_log": jnp.log(jax.random.uniform(
+                    ks[4], (h,), jnp.float32, 1.0, 16.0)),
+                "dt_bias": jax.random.uniform(
+                    ks[5], (h, hd), jnp.float32, -4.0, -1.0),
+                "wb": nrm(ks[6], (d, h), s),
+                "wg1": nrm(ks[7], (d, r), s),
+                "wg2": nrm(ks[8], (r, h, hd), 1.0 / math.sqrt(r)),
+                "bg": jnp.zeros((h, hd), jnp.float32),
+                "onorm": jnp.ones((hd,), cfg.dtype),
+                "wo": nrm(ks[9], (h, hd, d), 1.0 / math.sqrt(h * hd))}}
+        h, r = cfg.n_heads, cfg.mla_rank
+        return {"mla": {
+            "wq": nrm(ks[0], (d, h, cfg.mla_nope_dim + cfg.mla_rope_dim),
+                      s),
+            "wdkv": nrm(ks[1], (d, r + cfg.mla_rope_dim), s),
+            "kvnorm": jnp.ones((r,), cfg.dtype),
+            "wuk": nrm(ks[2], (r, h, cfg.mla_nope_dim),
+                       1.0 / math.sqrt(r)),
+            "wuv": nrm(ks[3], (r, h, cfg.mla_v_dim), 1.0 / math.sqrt(r)),
+            "wo": nrm(ks[4], (h, cfg.mla_v_dim, d),
+                      1.0 / math.sqrt(h * cfg.mla_v_dim))}}
+
     def layer(k, i):
         k1, k2, k3, k4 = jax.random.split(k, 4)
+        if cfg.mixer(i) != "attn":
+            return ffn({"ln1": jnp.ones((d,), cfg.dtype),
+                        **mixer(k1, cfg.mixer(i)),
+                        "ln2": jnp.ones((d,), cfg.dtype)}, i, k3, k4)
         nh, nkv = cfg.heads(i), cfg.kv_heads
         if nh % nkv:
             raise ValueError(f"n_heads={nh} not a multiple of "
@@ -249,6 +342,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         if cfg.attn_gate:
             out["wgate"] = (jax.random.normal(
                 jax.random.fold_in(k2, 1), (d, nh)) * s).astype(cfg.dtype)
+        return ffn(out, i, k3, k4)
+
+    def ffn(out, i, k3, k4):
         if cfg.sparse(i):
             from .moe import init_moe_params
             out["moe"] = init_moe_params(_moe_cfg(cfg), k3)
@@ -392,27 +488,36 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
            tp_axis: Optional[str] = None, moe=None):
     """THE decoder layer, the one definition every forward body takes:
     norm, mixer, residual, norm, FFN, residual. What differs between
-    the bodies is where K/V live, and that is `attend(q, k, v) ->
-    (att, carry)`: the body's own cache write and attention read
-    (dense cache, paged pools, the sp ring); `carry` (its new cache
-    state) is handed back beside x. `pos`: the positions of x's
+    the bodies is where a mixer's cached state lives, and that is
+    `attend(*operands) -> (out, carry)`: the body hands the mixer its
+    cache state and takes the new one back (`carry`, returned beside
+    x), whatever its kind. For attention the operands are (q, k, v)
+    and the body's own cache write and attention read answer (dense
+    cache, paged pools, the sp ring); for "kda" (pre, g, beta) and the
+    body's state and conv tail (`_kda_mixer`); for "mla" (q, row) and
+    the body's latent rows (`_mla_mixer`). `pos`: the positions of x's
     columns, [S] or [B, S]. `moe(h) -> out` is the body's sparse FFN
     (it closes its own collectives). What differs between LAYERS is in
     `cfg` (norm, per-layer rope, window via `attend`) and in the
-    parameters themselves: head counts are read off the arrays, a
-    "wgate" gates the heads, a "w3" makes the MLP SiLU-gated, a "moe"
-    makes it sparse."""
+    parameters themselves: a "kda" or "mla" names the mixer's kind,
+    head counts are read off the arrays, a "wgate" gates the heads, a
+    "w3" makes the MLP SiLU-gated, a "moe" makes it sparse."""
     h = _norm(x, lp["ln1"], cfg)
-    q, k, v = _qkv_proj(h, lp)
-    rope = cfg.rope_of(li)
-    if rope is not None:
-        q, k = _rope(q, pos, rope), _rope(k, pos, rope)
-    att, carry = attend(q, k, v)
-    if "wgate" in lp:
-        gate = jax.nn.sigmoid(jnp.einsum("bsd,dn->bsn", h,
-                                         _dq(lp["wgate"], h)))
-        att = att * gate[..., None]
-    o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
+    if "kda" in lp:
+        o, carry = _kda_mixer(h, lp["kda"], cfg, attend)
+    elif "mla" in lp:
+        o, carry = _mla_mixer(h, lp["mla"], cfg, attend)
+    else:
+        q, k, v = _qkv_proj(h, lp)
+        rope = cfg.rope_of(li)
+        if rope is not None:
+            q, k = _rope(q, pos, rope), _rope(k, pos, rope)
+        att, carry = attend(q, k, v)
+        if "wgate" in lp:
+            gate = jax.nn.sigmoid(jnp.einsum("bsd,dn->bsn", h,
+                                             _dq(lp["wgate"], h)))
+            att = att * gate[..., None]
+        o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
     if tp_axis:
         o = jax.lax.psum(o, tp_axis)       # Megatron row-parallel close
     x = x + o
@@ -428,6 +533,73 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
     if tp_axis:
         h = jax.lax.psum(h, tp_axis)
     return x + h, carry
+
+
+def _kda_mixer(h, m, cfg: TransformerConfig, attend):
+    """A Kimi-Delta-Attention mixer around the body's recurrent core.
+    h [B, W, D] -> (y [B, W, D], carry). Here: the three streams ahead
+    of the convolution, the per-channel log decay g = -exp(A_log) *
+    softplus(W_f2 (W_f1 h) + dt_bias) and beta = sigmoid(w_b . h), both
+    float32; after the core, RMSNorm over each head with one learned
+    scale, the sigmoid output gate, W_o. The core (`attend(pre, g,
+    beta)` = ops/kda.kda_mix over the body's state and conv tail:
+    convolution, SiLU, L2 norms, the delta rule) has no positions."""
+    f32 = jnp.float32
+    pre = jnp.einsum("bsd,dchk->bschk", h, _dq(m["wqkv"], h))
+    low = lambda w1, w2: jnp.einsum(                        # noqa: E731
+        "bsr,rhk->bshk", h @ _dq(w1, h), _dq(w2, h)).astype(f32)
+    g = -jnp.exp(m["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        low(m["wf1"], m["wf2"]) + m["dt_bias"].astype(f32))
+    beta = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", h, _dq(m["wb"], h)
+                                     ).astype(f32))
+    o, carry = attend(pre, g, beta)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                          + cfg.norm_eps) * m["onorm"].astype(f32)
+    o = o * jax.nn.sigmoid(low(m["wg1"], m["wg2"]) + m["bg"].astype(f32))
+    o = o.astype(h.dtype)
+    return jnp.einsum("bshk,hkd->bsd", o, _dq(m["wo"], o)), carry
+
+
+def _mla_mixer(h, m, cfg: TransformerConfig, attend):
+    """A latent-attention (MLA) mixer in the ABSORBED form, NoPE: no
+    rotation, the mla_rope_dim dims are plain. h [B, W, D] -> (y, carry).
+    The cached row of a token is [RMSNorm(W_dkv h); W_kr h; zero pad]
+    (`cfg.mla_row` wide). A head's query against it is [W_uk^T q^C;
+    q^R; 0], its score q . row / sqrt(nope + rope dims), its value the
+    row's first mla_rank columns: `attend(q, row)` writes the rows and
+    returns sum_j p_j c_j [B, W, H, rank], which W_uv takes to the
+    head's value dims ahead of W_o. One form for a decode step and a
+    prefill chunk."""
+    r, dn = cfg.mla_rank, cfg.mla_nope_dim
+    ckr = h @ _dq(m["wdkv"], h)                             # [B, W, r+dr]
+    cf = ckr[..., :r].astype(jnp.float32)
+    c = (cf * jax.lax.rsqrt(jnp.mean(cf * cf, -1, keepdims=True)
+                            + cfg.norm_eps)).astype(h.dtype) * m["kvnorm"]
+    q = jnp.einsum("bsd,dhk->bshk", h, _dq(m["wq"], h))
+    qa = jnp.einsum("bshn,rhn->bshr", q[..., :dn], _dq(m["wuk"], h))
+    pad = cfg.mla_row - r - cfg.mla_rope_dim
+    row = jnp.concatenate(
+        [c, ckr[..., r:], jnp.zeros(c.shape[:-1] + (pad,), h.dtype)], -1)
+    qf = jnp.concatenate(
+        [qa, q[..., dn:], jnp.zeros(qa.shape[:-1] + (pad,), h.dtype)], -1)
+    ol, carry = attend(qf, row)
+    o = jnp.einsum("bshr,rhv->bshv", ol, _dq(m["wuv"], ol))
+    return jnp.einsum("bshv,hvd->bsd", o, _dq(m["wo"], o)), carry
+
+
+def _latent_attention(q, lat, qpos, rank: int, scale: float):
+    """Absorbed latent attention of q [B, Q, H, R] over a DENSE cache
+    of latent rows lat [B, S, R] (this window's rows already written):
+    the query at qpos[i] sees rows <= it. Scores and softmax float32,
+    the value a row's first `rank` columns (`ops/paged_attention.
+    paged_latent_attention` is the same over a paged pool)."""
+    s = jnp.einsum("bqhr,bkr->bhqk", q, lat,
+                   preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(lat.shape[1]) <= qpos[..., None]      # [Q, S]
+    p = jax.nn.softmax(jnp.where(live[None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkr->bqhr", p.astype(q.dtype),
+                      lat[..., :rank],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
 
 
 def _cached_attention(q, kc, vc, qpos, window: int = 0):
@@ -947,7 +1119,7 @@ def make_pipelined_train_step(cfg: TransformerConfig, mesh,
 def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
                   tp_axis: Optional[str] = None,
                   ep_axis: Optional[str] = None, ep_size: int = 1,
-                  li: int = 0):
+                  li: int = 0, valid=None):
     """Layer `li` for a WINDOW of new token positions with a dense KV
     cache: `_layer` with the cache as its mixer. x: [B, W, D] (W = 1
     plain decode; W > 1 speculative verification / chunked prefill:
@@ -958,7 +1130,11 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
     need re-rotation. With tp_axis set, the wo/w2 contractions close
     with a psum, so the KV cache shards over heads and never
     replicates. A window layer masks what lies behind its window; the
-    dense cache keeps every row all the same."""
+    dense cache keeps every row all the same. A "kda" layer's `kv` is
+    (state, conv tail) and `valid` the count of the window's real
+    columns (the rest is bucket padding, which a recurrent state must
+    not consume; None = all); an "mla" layer's is (latent rows [B,
+    Smax, 1, R],)."""
     qpos = jnp.asarray(write_at) + jnp.arange(x.shape[1])
 
     def attend(q, k, v):
@@ -968,6 +1144,20 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
                                                  axis=1)
         return _cached_attention(q, kc, vc, qpos, cfg.window(li)), \
             (kc, vc)
+
+    if "kda" in lp:
+        from ..ops.kda import kda_mix
+
+        def attend(pre, g, beta):                           # noqa: F811
+            return kda_mix(pre, g, beta, lp["kda"]["conv"], *kv,
+                           valid=valid)
+    elif "mla" in lp:
+        def attend(q, row):                                 # noqa: F811
+            lat = jax.lax.dynamic_update_slice_in_dim(
+                kv[0], row[:, :, None, :], write_at, axis=1)
+            scale = (cfg.mla_nope_dim + cfg.mla_rope_dim) ** -0.5
+            return _latent_attention(q, lat[:, :, 0], qpos, cfg.mla_rank,
+                                     scale), (lat,)
 
     def moe(h):
         # serving routes DROP-FREE: with no drops, each token's output
@@ -1008,7 +1198,7 @@ def _decode_forward(params, caches, tok, pos, cfg, tp_axis=None,
 
 
 def _decode_window(params, caches, toks, pos0, cfg, tp_axis=None,
-                   ep_axis=None, ep_size=1, need_logits=True):
+                   ep_axis=None, ep_size=1, need_logits=True, valid=None):
     """A WINDOW of new tokens through the cached blocks in one pass:
     toks [B, W] at positions pos0..pos0+W-1. Returns (caches, f32
     logits [B, W, V]). One MXU-batched forward where a scan would run
@@ -1017,12 +1207,15 @@ def _decode_window(params, caches, toks, pos0, cfg, tp_axis=None,
     token, which is the whole memory-bandwidth case for speculative
     decoding). need_logits=False is the cache-only prefill: skips the
     final ln + [B, W, V] unembedding when the caller only wants the KV
-    side effects (returns (caches, None))."""
+    side effects (returns (caches, None)). `valid`: how many of the W
+    columns are real, for the layers whose state must not consume
+    padding (see `_block_decode`)."""
     x = params["emb"][toks]
     new_caches = []
     for li, (lp, kv) in enumerate(zip(params["layers"], caches)):
         x, kv = _block_decode(x, lp, kv, pos0, cfg, tp_axis=tp_axis,
-                              ep_axis=ep_axis, ep_size=ep_size, li=li)
+                              ep_axis=ep_axis, ep_size=ep_size, li=li,
+                              valid=valid)
         new_caches.append(kv)
     if not need_logits:
         return new_caches, None
@@ -1087,6 +1280,8 @@ def generate(params, cfg: TransformerConfig, prompt: jax.Array,
     shard over tp (or a dedicated "ep" mesh axis), routing drop-free
     through moe_ffn_decode's all_to_all exchange — token-identical to
     the single-device MoE path."""
+    cfg.kv_pairs_only("generate: the dense K/V caches",
+                      "models/transformer.py")
     if temperature > 0.0 and key is None:
         raise ValueError("temperature > 0 needs a PRNG key")
     if temperature <= 0.0 and (top_k > 0 or key is not None):
@@ -1401,6 +1596,9 @@ def speculative_generate(params, cfg: TransformerConfig,
     PAST the accepted position; they are harmless because the next
     round rewrites positions sequentially from the rewound cursor and
     the causal mask never lets a query see beyond its own position."""
+    for c in (cfg, draft_cfg):
+        c.kv_pairs_only("speculative_generate: the dense K/V caches",
+                        "models/transformer.py")
     if k < 1:
         raise ValueError(f"speculative_generate: k must be >= 1, got {k}")
     if draft_cfg.vocab != cfg.vocab:
@@ -1569,6 +1767,9 @@ def speculative_sample(params, cfg: TransformerConfig,
     case: per-row acceptance counts would need per-row cache
     positions). Greedy/batched/sharded speculation: see
     speculative_generate."""
+    for c in (cfg, draft_cfg):
+        c.kv_pairs_only("speculative_sample: the dense K/V caches",
+                        "models/transformer.py")
     if key is None:
         raise ValueError("speculative_sample needs a PRNG key")
     if temperature <= 0.0:
@@ -1691,6 +1892,8 @@ def beam_search(params, cfg: TransformerConfig, prompt: jax.Array,
     beam_width=1 reproduces greedy decode exactly. No eos handling —
     beams run to max_new (finished-hypothesis freezing composes with
     this scheme but is not wired)."""
+    cfg.kv_pairs_only("beam_search: the dense K/V caches",
+                      "models/transformer.py")
     if beam_width < 1:
         raise ValueError("beam_width >= 1")
     b, plen = prompt.shape

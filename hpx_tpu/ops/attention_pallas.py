@@ -1304,6 +1304,107 @@ def _live_walk_call(qk, k_pool, v_pool, table, pos0, *, w: int,
       v_pool)[0]
 
 
+def _latent_kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, bank, sem,
+                   *, block_size: int, nblk: int, rank: int,
+                   scale: float):
+    """One slot's absorbed latent attention (MLA decode): every query
+    head over ONE cached head of latent rows whose value is its own
+    first `rank` columns. q_ref (H, R), o_ref (H, rank); pool_hbm: the
+    latent pool [num_blocks, 1, block_size, R], left in HBM. The walk
+    is `_paged_live_kernel`'s: the first `_walk_entries` table entries
+    are copied into `bank` (entry j to rows j*bs ..), all in flight on
+    one DMA semaphore, and NOTHING IS READ FROM THE BANK BEFORE EVERY
+    WAIT HAS RETURNED; rows past the walk are masked out of the scores
+    and selected to zero ahead of the value product."""
+    b = pl.program_id(0)
+    pos = pos_ref[b]
+    n_live = _walk_entries(pos, 1, block_size, nblk)
+
+    def copy(j):
+        rows = pl.ds(pl.multiple_of(j * block_size, block_size),
+                     block_size)
+        return pltpu.make_async_copy(pool_hbm.at[table_ref[b, j], 0],
+                                     bank.at[rows, :], sem.at[0])
+
+    def start(j, carry):
+        copy(j).start()
+        return carry
+
+    def wait(j, carry):
+        copy(j).wait()
+        return carry
+
+    jax.lax.fori_loop(0, n_live, start, 0)
+    jax.lax.fori_loop(0, n_live, wait, 0)
+
+    q = q_ref[...]
+    lat = bank[...]
+    sf = jax.lax.dot_general(
+        q, lat.astype(q.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    kpos = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 1)
+    sf = jnp.where(kpos <= pos, sf, -jnp.inf)
+    p = jax.nn.softmax(sf, axis=-1)
+    vrow = jax.lax.broadcasted_iota(jnp.int32, (lat.shape[0], rank), 0)
+    v = jnp.where(vrow < n_live * block_size, lat[:, :rank], 0)
+    o_ref[...] = jax.lax.dot_general(
+        p.astype(q.dtype), v.astype(q.dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def fused_latent_attention(q: jax.Array, pool: jax.Array,
+                           table: jax.Array, pos: jax.Array, *,
+                           rank: int, scale: float,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """Absorbed MLA decode attention that walks the block table
+    in-kernel (`hpx_mla_paged`), bounded by each slot's live length.
+
+    q: [B, H, R] absorbed queries (W_uk^T q^C, then the plain q^R dims,
+    then zeros up to the pool's row width R); pool: [num_blocks, 1,
+    block_size, R] latent rows (c, r, zero pad) with this step's row
+    ALREADY written; table: [B, max_blocks] int32; pos: [B] int32, the
+    slot attends rows <= pos. Returns sum_j p_j c_j, [B, H, rank]: the
+    scores are q . row * scale in float32 (the pad columns are zero on
+    both sides), the softmax float32, the value a row's first `rank`
+    columns: K and V are the same bytes, read once.
+
+    ONE pool of rows R = rank + rope dims rounded up to whole 128-lane
+    rows, not a 512-wide and a 64-wide pool on one table: the chip pads
+    a minor dim of 64 to 128 lanes in HBM anyway, so the split saves
+    nothing, and one pool is one DMA a block, one bank and one matmul
+    for the scores. Needs R % 128 == 0 and rank % 128 == 0 (the value
+    slice is then whole lanes); `ops/paged_attention.
+    paged_latent_attention` decides and keeps the gather form for every
+    other width. Grid (slot,), parallel."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, r = q.shape
+    bs = pool.shape[2]
+    maxb = table.shape[1]
+    bank = maxb * bs * r * jnp.dtype(pool.dtype).itemsize
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, block_size=bs, nblk=maxb,
+                          rank=rank, scale=scale),
+        name="hpx_mla_paged",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((None, h, r), lambda bb, *_: (bb, 0, 0)),
+                      pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=[pl.BlockSpec((None, h, rank),
+                                    lambda bb, *_: (bb, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((maxb * bs, r), pool.dtype),
+                            pltpu.SemaphoreType.DMA((1,))],
+        ),
+        out_shape=[_sds((b, h, rank), q.dtype, q, pool)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # the bank, its value as loaded, the selected value rows
+            vmem_limit_bytes=int(max(4 * bank + (8 << 20), 32 << 20))),
+        interpret=interpret,
+    )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool)[0]
+
+
 def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,
                       interpret, online: bool,
                       window: int = 0) -> jax.Array:

@@ -81,13 +81,15 @@ import jax
 import jax.numpy as jnp
 
 from ..models.quant import _quantize, _quantize_fp8
-from .attention_pallas import (_live, _ring_kpos, fused_paged_attention,
+from .attention_pallas import (_live, _ring_kpos, fused_latent_attention,
+                               fused_paged_attention,
                                fused_paged_online_attention)
 
 __all__ = [
     "block_rows",
     "gather_block_kv",
     "paged_decode_attention",
+    "paged_latent_attention",
     "paged_window_attention",
     "quantize_blocks",
     "scatter_blocks",
@@ -458,3 +460,42 @@ def paged_window_attention(q: jax.Array, k_new: jax.Array,
     if quant:
         return att, k_pool, v_pool, k_scale, v_scale
     return att, k_pool, v_pool
+
+
+def paged_latent_attention(q: jax.Array, row_new: jax.Array,
+                           pool: jax.Array, table: jax.Array,
+                           pos: jax.Array, *, rank: int, scale: float,
+                           fused=False, interpret=None):
+    """One decode step of ABSORBED latent attention (MLA) over a paged
+    pool of latent rows: one cached head, whose value is its own first
+    `rank` columns, under every query head.
+
+    q: [B, H, R] (W_uk^T q^C, the plain q^R dims, zeros up to R);
+    row_new: [B, R] this step's row (c, r, zero pad); pool:
+    [num_blocks, 1, block_size, R]; table: [B, max_blocks] (the FULL
+    group's); pos: [B] int32. Returns (sum_j p_j c_j [B, H, rank],
+    pool) with the new row written first (`scatter_token`: block, the
+    one head and row indexed together, the module's layout rule), so a
+    slot attends its own token.
+
+    `fused` (any of the fused kernels' names) takes `hpx_mla_paged`,
+    the table walk bounded by the slot's live length, where the value
+    slice is whole lanes (rank % 128 == 0: decided HERE, from the
+    operands); every other call the gather form below, the kernel's
+    oracle: the same float32 scores, mask, softmax and the
+    probabilities rounded to the rows' type ahead of the value
+    product."""
+    pool = scatter_token(pool, table, pos, row_new[:, None, :])
+    if fused and rank % 128 == 0:
+        return fused_latent_attention(q, pool, table, pos, rank=rank,
+                                      scale=scale,
+                                      interpret=interpret), pool
+    lat = gather_block_kv(pool, table)[:, :, 0]          # [B, S, R]
+    s = jnp.einsum("bhr,bkr->bhk", q, lat.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(lat.shape[1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(live[:, None, :], s, -jnp.inf), axis=-1)
+    v = jnp.where(live[:, :, None], lat[..., :rank], 0)
+    o = jnp.einsum("bhk,bkr->bhr", p.astype(q.dtype), v.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    return o.astype(q.dtype), pool

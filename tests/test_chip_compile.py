@@ -342,6 +342,103 @@ def test_server_step_program_has_no_pool_copy(sds, monkeypatch, model):
     assert ("hpx_moe_gmm" in text) == bool(srv._win)
 
 
+# -- the recurrent state and the latent pool (kimi-linear.reason-closed) --
+#
+# 48 slots; a KDA layer's state 32 heads x 128 x 128 float32 a slot; an
+# MLA layer's pool 48 x 264 + 1 blocks of 16 rows of 640 (512 + 64 and
+# the pad to whole lanes), 32 query heads; both donated as the server
+# donates its cache pytree.
+
+_K_SLOTS, _K_HEADS, _K_HD, _K_ROW, _K_RANK, _K_MAXB = 48, 32, 128, 640, 512, 264
+
+
+def _copies_of(text, shape: str) -> list:
+    return [ln.strip() for ln in text.splitlines()
+            if " copy(" in ln and shape in ln.split(" copy(")[0]]
+
+
+def test_the_states_update_runs_in_place(sds):
+    """`hpx_kda_step` over the cell's state: the kernel's state operand
+    is its own output (`input_output_aliases`), so the donated 201 MB
+    of a layer are neither copied nor re-laid."""
+    from hpx_tpu.ops import kda
+    b, h, d = _K_SLOTS, _K_HEADS, _K_HD
+    vec = sds((b, h, d), jnp.float32)
+    state = sds((b, h, d, d), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, v, g, beta, s: kda.kda_step(
+            q, k, v, g, beta, s, kernel="pallas", interpret=False),
+        donate_argnums=(5,)).lower(
+        vec, vec, vec, vec, sds((b, h), jnp.float32), state).compile()
+    text = compiled.as_text()
+    assert "hpx_kda_step" in text
+    assert _copies_of(text, f"f32[{b},{h},{d},{d}]") == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        b * h * d * d * 4
+
+
+def test_the_latent_rows_write_leaves_the_pool_where_it_lies(sds):
+    """The latent row's write under the pool layout rule (block, the
+    one head and row indexed together) and `hpx_mla_paged` over the
+    cell's pool: no pool-shaped copy around either."""
+    b, nb = _K_SLOTS, _K_SLOTS * _K_MAXB + 1
+    pool = sds((nb, 1, _C_BS, _K_ROW), jnp.bfloat16)
+    text = jax.jit(
+        lambda q, row, p, table, pos: pa.paged_latent_attention(
+            q, row, p, table, pos, rank=_K_RANK, scale=192 ** -0.5,
+            fused=True, interpret=False),
+        donate_argnums=(2,)).lower(
+        sds((b, _K_HEADS, _K_ROW), jnp.bfloat16),
+        sds((b, _K_ROW), jnp.bfloat16), pool,
+        sds((b, _K_MAXB), jnp.int32), sds((b,), jnp.int32)
+    ).compile().as_text()
+    assert "hpx_mla_paged" in text
+    assert _pool_ops(text, pool) == []
+
+
+def test_hybrid_server_step_program_copies_neither_state_nor_pool(
+        sds, monkeypatch):
+    """The server's own `jit_step` for a KDA layer and an MLA layer of
+    the cell's mixer widths (a narrow d_model, a sparse FFN that holds
+    a share of its experts), built by `_paged_step_prog` from parameter
+    SHAPES: one Pallas call a mixer and one for the experts, the state
+    and the latent pool donated and left where they lie."""
+    from hpx_tpu.models.serving import ContinuousServer
+    from hpx_tpu.models.transformer import TransformerConfig, init_params
+    cfg = TransformerConfig(
+        vocab=512, d_model=256, n_heads=_K_HEADS, n_layers=2, d_ff=512,
+        dtype=jnp.bfloat16, norm="rmsnorm", mlp="swiglu", tied=False,
+        layer_mixer=("kda", "mla"), kda_heads=_K_HEADS,
+        kda_head_dim=_K_HD, kda_conv=4, kda_rank=128, mla_rank=_K_RANK,
+        mla_nope_dim=128, mla_rope_dim=64, mla_v_dim=128,
+        layer_sparse=(False, True), n_experts=64, moe_held=(16, 32),
+        moe_top_k=8, moe_d_ff=128, moe_shared_d_ff=128,
+        moe_router="sigmoid", moe_renorm=True, moe_scale=2.446,
+        moe_bias=True)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    srv = ContinuousServer(params, cfg, paged=True, slots=_K_SLOTS,
+                           smax=_K_MAXB * _C_BS)
+    assert srv._paged_kernel == "fused" and srv.block_size == _C_BS
+    assert srv._alloc.num_blocks == _K_SLOTS * _K_MAXB + 1
+    assert srv._pools[1][0].shape[-1] == _K_ROW
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    s = srv.slots
+    text = srv._paged_step_prog().lower(
+        on_chip(params), on_chip(srv._pools), None,
+        sds((s,), jnp.int32), sds((s,), jnp.int32),
+        (sds((s, srv._maxb), jnp.int32),),
+        sds((s,), jnp.float32), sds((s, 2), jnp.uint32)).compile().as_text()
+    for name in ("hpx_kda_step", "hpx_mla_paged", "hpx_moe_gmm"):
+        assert name in text
+    assert _copies_of(
+        text, f"f32[{s},{_K_HEADS},{_K_HD},{_K_HD}]") == []
+    assert _pool_ops(text, srv._pools[1][0]) == []
+
+
 # -- flash attention (training forward/backward, ring chunk) -------------
 
 @pytest.mark.parametrize("n,nkv", [(8, 8), (16, 4)],

@@ -46,8 +46,8 @@ def test_mixed_length_workload_compiles_o_buckets(params):
     assert len(out) == len(PLENS)
     buckets = len(srv.prefill_buckets)
     # program builds: one chunk program per bucket + probe + splice +
-    # step, NOT one per prompt length
-    assert srv._prog_misses <= buckets + 3
+    # step + the empty scratch, NOT one per prompt length
+    assert srv._prog_misses <= buckets + 4
     # total backend compiles: program builds plus a constant floor of
     # first-touch eager ops (argmax/sampling/zeros); 12 per-length
     # prefill+splice programs would blow far past this

@@ -13,7 +13,10 @@ read from the span ring (`hpx.trace.enabled`), event by event.
 
 each still token for token `generate()`'s output, over the dense
 server, the paged one, and the paged one with a window block group and
-experts (the Laguna toy of tests/test_laguna_serving.py).
+experts (the Laguna toy of tests/test_laguna_serving.py); and, against
+the plain reference's own greedy continuation, over the paged server of
+a model with recurrent and latent-attention layers (the Kimi-Linear toy
+of tests/test_kimi_serving.py: `generate()` has no path for it).
 """
 
 import json
@@ -25,7 +28,9 @@ import numpy as np
 import pytest
 
 from chipbench import harness
+from chipbench.drivers import serving_hybrid as hybrid_drv
 from chipbench.drivers import serving_mixed as drv
+from chipbench.reference import kimi_linear as hybrid_ref
 from hpx_tpu.core.config import runtime_config
 from hpx_tpu.models import transformer as tfm
 from hpx_tpu.models.serving import ContinuousServer
@@ -39,7 +44,7 @@ PH, NAME, ARGS = 0, 1, 7
 READS = ("serving.first_token.wait", "serving.flush.wait",
          "serving.flush.moe_stats.wait")
 DISPATCH = "serving.dispatch"
-MODES = ["dense", "paged", "mixed"]
+MODES = ["dense", "paged", "mixed", "hybrid"]
 
 
 @pytest.fixture(scope="module")
@@ -49,8 +54,17 @@ def models():
     with open(os.path.join(ROOT, "chipbench/tests/rehearse_mixed.json")) as f:
         conf = harness._merge(conf, json.load(f)["config"])
     toy = drv.build_cfg(conf)
+    with open(os.path.join(ROOT,
+                           "chipbench/configs/kimi-linear-48b.json")) as f:
+        hconf = json.load(f)
+    with open(os.path.join(ROOT,
+                           "chipbench/tests/rehearse_hybrid.json")) as f:
+        hconf = harness._merge(hconf, json.load(f)["config"])
+    htoy = hybrid_drv.build_cfg(hconf)
     return {"dense": (CFG, tfm.init_params(CFG, jax.random.PRNGKey(0))),
-            "mixed": (toy, drv.make_params(toy, 11))}
+            "mixed": (toy, drv.make_params(toy, 11)),
+            "hybrid": (htoy, hybrid_drv.make_params(htoy, 11)),
+            "hybrid_conf": hconf}
 
 
 @pytest.fixture()
@@ -67,11 +81,13 @@ def ring():
 
 
 def _server(models, mode, **kw):
-    cfg, params = models["mixed" if mode == "mixed" else "dense"]
+    cfg, params = models[mode if mode in ("mixed", "hybrid") else "dense"]
     base = {"dense": dict(smax=64),
             "paged": dict(paged=True, smax=64, block_size=8),
             "mixed": dict(paged=True, smax=128, block_size=4,
-                          prefill_chunk=8)}[mode]
+                          prefill_chunk=8),
+            "hybrid": dict(paged=True, smax=64, block_size=4,
+                           prefill_chunk=8)}[mode]
     return ContinuousServer(params, cfg, **{"slots": 2, **base, **kw})
 
 
@@ -80,6 +96,21 @@ def _prompt(n, seed):
 
 
 def _generate(models, mode, prompt, max_new, eos_id=None):
+    if mode == "hybrid":
+        # the reference's greedy continuation in one padded frame, the
+        # tail pinned to eos as generate() pins it
+        _, params = models["hybrid"]
+        seq, out = list(prompt), []
+        while len(out) < max_new:
+            if out and out[-1] == eos_id:
+                out.append(eos_id)
+                continue
+            toks = np.zeros((1, 64), np.int32)
+            toks[0, :len(seq)] = seq
+            lg = hybrid_ref.logits(params, models["hybrid_conf"], toks)
+            out.append(int(np.asarray(lg)[0, len(seq) - 1].argmax()))
+            seq.append(out[-1])
+        return out
     cfg, params = models["mixed" if mode == "mixed" else "dense"]
     out = tfm.generate(params, cfg, jnp.asarray([prompt], jnp.int32),
                        max_new=max_new, eos_id=eos_id)
@@ -246,8 +277,9 @@ KEPT = {
 def test_inputs_that_need_the_value_keep_todays_order(models, ring, mode,
                                                       kind):
     srv_kw, req_kw = KEPT[kind]
-    if kind == "spec" and mode == "mixed":
-        with pytest.raises(NotImplementedError, match="window layers"):
+    if kind == "spec" and mode in ("mixed", "hybrid"):
+        with pytest.raises(NotImplementedError,
+                           match="speculative verify"):
             _server(models, mode, **srv_kw)
         return
     srv = _server(models, mode, **srv_kw)
