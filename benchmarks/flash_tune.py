@@ -19,15 +19,18 @@ a hard error, never a silent fall-through to bf16 byte accounting.
 
 Usage: python benchmarks/flash_tune.py [--quick] [--paged]
                                        [--positions P[,P...]
-                                        [--heads QxKV] [--smax S]]
+                                        [--heads [B x]QxKV] [--smax S]]
   --quick: S in {2k, 4k} only and fewer samples (smoke/dev loops).
   --paged: tune the paged decode kernel instead of flash forward.
   --positions 15,511,2047: with --paged, no sweep and nothing banked:
     time the `fused` kernel (bf16, block 16, S 2048) once per listed
     position, every slot AT that position and the table's tail on one
     trash block as the server lays it out: 32 slots, 24 q / 2 kv heads
-    (the StarCoder2-3B cell's shape). [--heads QxKV] [--smax S] give
-    another cell's: 48x8 and 4864 (304 entries) are Laguna's full layers.
+    (the StarCoder2-3B cell's shape, `--heads 32x24x2`). [--heads
+    [B x]QxKV] [--smax S] give another cell's: 32x48x8 and 4864 (304
+    entries) are Laguna's full layers. Times are the DEVICE's
+    (`paged_measure`), so a call under the host's dispatch latency reads
+    as what it is.
 """
 
 import functools
@@ -100,22 +103,38 @@ _PAGED_ITEMSIZE = {"bf16": 2, "int8": 1, "fp8": 1}
 _PAGED_KERNELS = ("gather", "fused", "fused_online")
 
 
-def paged_step(jax, jnp, S, bs, kvd, kern, pos=None, heads=(8, 8, 8)):
-    """Build one paged decode attention step at the serving shape:
-    8 slots, every table fully mapped to DISTINCT pool blocks at a
-    near-S horizon (the steady-state worst case — block-size effects
-    show up as grid/tiling overhead, not masked work). `kern` picks
-    the formulation: gather (XLA oracle), fused (bitwise Pallas), or
-    fused_online (O(block)-scratch online softmax). `pos` puts every
-    slot at that position instead (an int, or one per slot) and lays
-    the table out as the server does: the entries a slot's position
-    has reached map to distinct blocks, the tail to ONE trash block
-    (block 0). `heads` is (slots, q heads, kv heads). Returns (jitted
-    step, its q operand, HBM bytes one call has to read)."""
+def _paged_pools(jnp, S, bs, kvd, heads):
+    """(q, K pool, V pool, K scales, V scales) of one shape, seeded.
+    A cell's pools are gigabytes, seconds to draw: `paged_positions`
+    draws them once for all its positions."""
+    from hpx_tpu.ops.paged_attention import quantize_blocks
+    (B, nq, nkv), H = heads, 128
+    nb = B * (S // bs) + 1             # + a trash-style spare block
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((B, 1, nq, H), np.float32),
+                    jnp.bfloat16)
+    # pool layout: heads ahead of rows (ops/paged_attention)
+    kp = jnp.asarray(rng.standard_normal((nb, nkv, bs, H), np.float32))
+    vp = jnp.asarray(rng.standard_normal((nb, nkv, bs, H), np.float32))
+    if kvd == "bf16":
+        return q, kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16), \
+            None, None
+    pool_dt = jnp.int8 if kvd == "int8" else jnp.float8_e4m3fn
+    kq, ks = quantize_blocks(kp, pool_dt)
+    vq, vs = quantize_blocks(vp, pool_dt)
+    return q, kq, vq, ks, vs
+
+
+def _paged_case(jax, jnp, S, bs, kvd, kern, pos=None, heads=(8, 8, 8),
+                pools=None):
+    """One paged decode attention step at the serving shape, unjitted:
+    (step(q, *operands), operands, q, HBM bytes one call has to read).
+    The pools are OPERANDS: a jitted step that closes over them
+    compiles gigabytes of constants into its program (minutes a
+    position at a cell's shape). See `paged_step`."""
     from hpx_tpu.ops.attention_pallas import (fused_paged_attention,
                                               fused_paged_online_attention)
-    from hpx_tpu.ops.paged_attention import (gather_block_kv,
-                                             quantize_blocks)
+    from hpx_tpu.ops.paged_attention import gather_block_kv
     try:
         itemsize = _PAGED_ITEMSIZE[kvd]
     except KeyError:
@@ -129,13 +148,7 @@ def paged_step(jax, jnp, S, bs, kvd, kern, pos=None, heads=(8, 8, 8)):
             f"of {_PAGED_KERNELS})")
     (B, nq, nkv), H = heads, 128
     maxb = S // bs
-    nb = B * maxb + 1                  # + a trash-style spare block
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((B, 1, nq, H), np.float32),
-                    jnp.bfloat16)
-    # pool layout: heads ahead of rows (ops/paged_attention)
-    kp = rng.standard_normal((nb, nkv, bs, H), np.float32)
-    vp = rng.standard_normal((nb, nkv, bs, H), np.float32)
+    q, kq, vq, ks, vs = pools or _paged_pools(jnp, S, bs, kvd, heads)
     pos = np.broadcast_to(
         np.asarray(S - 1 if pos is None else pos, np.int32), (B,))
     live = pos // bs + 1               # entries reached (S - 1: all)
@@ -143,18 +156,10 @@ def paged_step(jax, jnp, S, bs, kvd, kern, pos=None, heads=(8, 8, 8)):
     table = jnp.asarray(
         np.where(np.arange(maxb)[None, :] < live[:, None], table, 0))
     pos = jnp.asarray(pos)
-    ks = vs = None
-    if kvd == "bf16":
-        kq = jnp.asarray(kp, jnp.bfloat16)
-        vq = jnp.asarray(vp, jnp.bfloat16)
-    else:
-        pool_dt = jnp.int8 if kvd == "int8" else jnp.float8_e4m3fn
-        kq, ks = quantize_blocks(jnp.asarray(kp, jnp.float32), pool_dt)
-        vq, vs = quantize_blocks(jnp.asarray(vp, jnp.float32), pool_dt)
     if kern == "gather":
         g = nq // nkv
 
-        def step(qq):
+        def step(qq, kq, vq, table, pos, ks, vs):
             kc = gather_block_kv(kq, table, ks, qq.dtype)
             vc = gather_block_kv(vq, table, vs, qq.dtype)
             qg = qq.reshape(B, 1, nkv, g, H)
@@ -165,36 +170,64 @@ def paged_step(jax, jnp, S, bs, kvd, kern, pos=None, heads=(8, 8, 8)):
                                ).astype(qq.dtype)
             return jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(
                 B, 1, nq, H)
-
-        f = jax.jit(step)
     else:
         fpa = (fused_paged_online_attention if kern == "fused_online"
                else fused_paged_attention)
-        f = jax.jit(lambda qq: fpa(qq, kq, vq, table, pos,
-                                   k_scale=ks, v_scale=vs))
+
+        def step(qq, kq, vq, table, pos, ks, vs):
+            return fpa(qq, kq, vq, table, pos, k_scale=ks, v_scale=vs)
     nlive = int(live.sum())                         # mapped entries
     hbm = 2 * nlive * bs * nkv * H * itemsize       # K + V pool reads
     if kvd in ("int8", "fp8"):
         hbm += 2 * nlive * nkv * 4                  # scale sidecars
-    return f, q, hbm
+    return step, (kq, vq, table, pos, ks, vs), q, hbm
+
+
+def paged_step(jax, jnp, S, bs, kvd, kern, pos=None, heads=(8, 8, 8)):
+    """Build one paged decode attention step at the serving shape:
+    8 slots, every table fully mapped to DISTINCT pool blocks at a
+    near-S horizon (the steady-state worst case — block-size effects
+    show up as grid/tiling overhead, not masked work). `kern` picks
+    the formulation: gather (XLA oracle), fused (bitwise Pallas), or
+    fused_online (O(block)-scratch online softmax). `pos` puts every
+    slot at that position instead (an int, or one per slot) and lays
+    the table out as the server does: the entries a slot's position
+    has reached map to distinct blocks, the tail to ONE trash block
+    (block 0). `heads` is (slots, q heads, kv heads). Returns (jitted
+    step of q alone, its q operand, HBM bytes one call has to read)."""
+    step, operands, q, hbm = _paged_case(jax, jnp, S, bs, kvd, kern,
+                                         pos=pos, heads=heads)
+    f = jax.jit(step)
+    return (lambda qq: f(qq, *operands)), q, hbm
 
 
 def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3, **layout):
-    """Time `paged_step`. Returns (HBM-read GB/s, us per call,
-    spread)."""
-    f, q, hbm = paged_step(jax, jnp, S, bs, kvd, kern, **layout)
-    out = f(q)
-    jax.block_until_ready(out)
+    """Time `paged_step` ON THE DEVICE. Returns (HBM-read GB/s, us per
+    call, spread).
+
+    `n` calls run inside ONE jitted `lax.fori_loop` whose carry feeds q
+    (no call can be dropped or overlap the next), so a timing costs one
+    dispatch whatever `n`, and the slope over two `n` is the device's
+    time a call. One dispatch a call would put the host's 0.2-0.3 ms a
+    dispatch under every reading as a floor, and the cells' calls take
+    0.1 ms."""
+    step, operands, q, hbm = _paged_case(jax, jnp, S, bs, kvd, kern,
+                                         **layout)
+
+    @jax.jit
+    def loop(qq, n, *ops):
+        return jax.lax.fori_loop(
+            0, n, lambda _, x: step(x, *ops).astype(qq.dtype), qq)
 
     def chain(kk):
-        qq = q
         t0 = time.perf_counter()
-        for _ in range(kk):
-            qq = f(qq.astype(q.dtype))
-        _ = float(qq[0, 0, 0, 0])
+        jax.block_until_ready(loop(q, kk, *operands))
         return time.perf_counter() - t0
 
-    pers = sorted(slope_time(chain, 8, 50) for _ in range(samples))
+    chain(8)                           # compiles (n is data: once)
+    # enough calls that the slope stands on >= 0.25 s of device work
+    k2 = 8 + min(max(int(0.25 * 64 / chain(64)), 64), 4096)
+    pers = sorted(slope_time(chain, 8, k2) for _ in range(samples))
     per = pers[(samples - 1) // 2]
     return hbm / per / 1e9, per * 1e6, (pers[-1] - pers[0]) / per
 
@@ -202,9 +235,10 @@ def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3, **layout):
 def paged_positions(jax, jnp, positions, heads, S) -> int:
     """`--positions`: one line per position, nothing banked."""
     bs, kvd, kern = 16, "bf16", "fused"
+    pools = _paged_pools(jnp, S, bs, kvd, heads)
     for p in positions:
         gbs, us, spread = paged_measure(jax, jnp, S, bs, kvd, kern,
-                                        pos=p, heads=heads)
+                                        pos=p, heads=heads, pools=pools)
         print(json.dumps({"S": S, "block_size": bs, "kv_dtype": kvd,
                           "kernel": kern, "slots": heads[0],
                           "q_heads": heads[1], "kv_heads": heads[2],
@@ -272,10 +306,10 @@ def main() -> int:
     enable_compile_cache()
 
     if "--paged" in sys.argv and _arg("--positions"):
-        nq, nkv = map(int, (_arg("--heads") or "24x2").split("x"))
+        heads = tuple(map(int, (_arg("--heads") or "24x2").split("x")))
         return paged_positions(
             jax, jnp, [int(p) for p in _arg("--positions").split(",")],
-            (32, nq, nkv), int(_arg("--smax") or 2048))
+            (32,) * (3 - len(heads)) + heads, int(_arg("--smax") or 2048))
     if "--paged" in sys.argv:
         return paged_main(jax, jnp, quick)
 

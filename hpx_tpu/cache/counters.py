@@ -64,6 +64,13 @@ Paged servers additionally export the cache counters::
                                                           visits per live slot
     /cache{locality#L/server#i}/walk-share              the same over the table's
                                                           width
+    /cache{locality#L/server#i}/count/heads-per-copy    kv heads one copy of a
+                                                          table entry carries (a
+                                                          grid step's group; 0:
+                                                          the grid walk)
+    /cache{locality#L/server#i}/count/walk-copies-per-slot  DMA descriptors (K and
+                                                          V) a slot, layer and
+                                                          step
 
 Models with recurrent ("kda") layers add their per-slot state, models
 with latent-attention ("mla") layers their rows on the full group::
@@ -274,6 +281,14 @@ def register_server(srv) -> str:
         put("cache", "walk-share",
             pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
                                ["walk_share"])))
+        # the kv heads that share one copy of an entry, and the copies
+        # a slot, layer and step then issues
+        put("cache", "count/heads-per-copy",
+            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                               ["heads_per_copy"])))
+        put("cache", "count/walk-copies-per-slot",
+            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                               ["walk_copies_per_slot"])))
         if getattr(srv, "_win", 0):
             # the window block group (serving._init_paged)
             put("cache", "window/blocks-in-use",

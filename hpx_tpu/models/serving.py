@@ -99,7 +99,8 @@ from ..cache.radix import RadixCache
 from ..core.errors import Error, HpxError
 from ..svc import faultinject, flight, tracing
 from ..svc.resiliency import sync_replay
-from ..ops.attention_pallas import resolve_paged_block
+from ..ops.attention_pallas import (resolve_paged_block,
+                                     walk_heads_per_copy)
 from ..ops.kda import kda_mix
 from ..ops.paged_attention import (
     block_rows,
@@ -2036,6 +2037,28 @@ class ContinuousServer:
         return ("f32" if jnp.dtype(self.cfg.dtype).itemsize == 4
                 else "bf16")
 
+    def _walk_group(self) -> Tuple[int, int]:
+        """(`hg`, n_kv) of the full group's decode call: the kv heads
+        one grid step of the bounded walk owns, and one copy of a table
+        entry carries (`attention_pallas.walk_heads_per_copy` of the
+        call's shapes, W = 1), of the call's kv heads (the shard's
+        under a mesh). `hg` is 0 where this server's calls keep the
+        grid walk (another kernel, quantized pools, a head that is not
+        whole 128-lane rows) or no layer attends over the full group's
+        K/V pools."""
+        cfg = self.cfg
+        nkv = cfg.kv_heads // (
+            self.mesh.shape["tp"] if self.mesh is not None else 1)
+        full = [i for i in range(cfg.n_layers)
+                if cfg.mixer(i) == "attn" and not cfg.window(i)]
+        if (self._paged_kernel != "fused" or self._kv_dtype != "bf16"
+                or cfg.head_dim % 128 or not full):
+            return 0, nkv
+        item = jnp.dtype(cfg.dtype).itemsize
+        return walk_heads_per_copy(
+            nkv, self._maxb * self.block_size, cfg.head_dim,
+            cfg.heads(full[0]) // cfg.kv_heads, item, item), nkv
+
     def hbm_read_stats(self) -> Dict[str, Any]:
         """Modeled decode-attention HBM read cost per generated token,
         fed from pool dtype + table occupancy (the
@@ -2049,10 +2072,14 @@ class ContinuousServer:
         of whole 128-lane rows stops at the block of the slot's
         position (`walk_entries_per_slot`, mean over the live slots of
         min(p // block_size + 1, max_blocks); `walk_share` is that over
-        the table's width: what is left of a full-width walk), every
-        other fused call visits all max_blocks entries, whose tail
-        aliases the single resident trash block — occupancy is the
-        honest per-slot traffic either way. bytes/token uses
+        the table's width: what is left of a full-width walk) and
+        copies an entry ONCE for the `heads_per_copy` kv heads a grid
+        step owns (`walk_copies_per_slot` = `walk_entries_per_slot` x 2
+        pools x n_kv / `heads_per_copy`: the DMA descriptors a slot,
+        layer and step; both 0 where the calls keep the grid walk),
+        every other fused call visits all max_blocks entries, whose
+        tail aliases the single resident trash block — occupancy is
+        the honest per-slot traffic either way. bytes/token uses
         `cache.block_allocator.block_bytes`, so the int8/fp8 sidecar
         scales are included: vs a bf16 compute dtype the quantized
         pools read ~0.5x, and vs tier-1's f32 compute dtype ~0.25x —
@@ -2073,6 +2100,7 @@ class ContinuousServer:
         walks = [min(p // self.block_size + 1, self._maxb)
                  for p in self.live_positions().values()]
         walk = sum(walks) / len(walks) if walks else 0.0
+        hg, nkv = self._walk_group()
         return {
             "hbm_read_blocks_per_token": per_tok,
             "hbm_read_bytes_per_token": per_tok * bb,
@@ -2080,6 +2108,10 @@ class ContinuousServer:
             # group's table; `attention_pallas._walk_entries` at W = 1)
             "walk_entries_per_slot": walk,
             "walk_share": walk / self._maxb,
+            # how many kv heads share one copy of an entry, and the
+            # copies (K and V) a slot, layer and step then issues
+            "heads_per_copy": hg,
+            "walk_copies_per_slot": walk * 2 * nkv / hg if hg else 0.0,
             # where this server's block_size came from: arg | config |
             # env | seed (paged_blocks.json) | default
             "block_size_source": self._block_size_src,

@@ -870,15 +870,40 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
 # unquantized pools whose head_dim is a multiple of 128 (what
 # `_fused_paged_call` can read off its operands; every call the
 # benchmark's serving cells, the verify window and the mesh form make)
-# takes a second launch path under the same names: grid (slot, kv head)
-# only, the pools LEFT IN HBM, and the kernel itself copies
-# (`pltpu.make_async_copy`) physical block table[b, j], head h into
-# rows j*block_size .. of the same two (S, hd) banks for j below
+# takes a second launch path under the same names: grid (slot, group
+# of kv heads) only, the pools LEFT IN HBM, and the kernel itself
+# copies (`pltpu.make_async_copy`) physical block table[b, j] into
+# rows j*block_size .. of the banks for j below
 # `_walk_entries` = min((pos0 + W - 1) // block_size + 1, max_blocks):
 # a trip count read from DATA, so one compiled program serves every
 # length. The tail of the table is never fetched. The finish is the
-# grid walk's, op for op, over the whole bank (its fixed cost a
-# (slot, kv head)). Every other call keeps the grid walk, for reasons
+# grid walk's, op for op, a head at a time over that head's whole
+# (S, hd) bank (its fixed cost a (slot, kv head): ~0.2 ns a bank row).
+#
+# ONE COPY AN ENTRY FOR A GROUP OF HEADS (PR 35). Every kv head of a
+# slot walks the SAME entries (the bound depends on pos0 alone), and the
+# n_kv tiles of one block lie back to back in the pool ([num_blocks,
+# n_kv, block_size, head_dim]). One (block_size, head_dim) tile a
+# descriptor is 4 KB in bfloat16, and the copies, started and waited
+# one by one in two scalar loops, were the larger part of a grid step
+# (0.043 us a table entry, K + V: 190 GB/s of 819; PERF.md section 6).
+# So a grid step owns `hg` kv heads and copies an entry ONCE for all of
+# them: `pool.at[blk, h0 : h0 + hg]` -> `bank.at[:, rows, :]`, banks
+# (hg, S, hd). The source is one contiguous run of hg tiles, the
+# destination hg runs a bank apart, which the DMA engine strides over;
+# the entry-major alternative (max_blocks, hg, block_size, head_dim)
+# makes both sides contiguous but hands the finish a head's rows in
+# max_blocks pieces, and measured no faster (PERF.md section 6), so the
+# finish keeps reading a head's bank as one array, as the grid walk's
+# does. `hg` is `walk_heads_per_copy`: the largest divisor of the
+# call's n_kv whose banks fit `_WALK_VMEM_BUDGET`, read off the
+# operands (StarCoder2-3B's 2 heads: 2; Laguna-XS.2's 8: 8 on the full
+# tables and on the ring; 1 where a bank is too long to share VMEM,
+# which is PR 31's kernel); the launch states its VMEM limit from the
+# same bytes. Starts, waits and table reads fall by `hg`; the bytes
+# stay; the semaphore rule stays word for word.
+#
+# Every other call keeps the grid walk, for reasons
 # that conflict with this path's: a one-byte pool would need a staging
 # bank and a per-entry dequantization between landing and banking
 # (which costs more than the copy it halves, and whose semaphore
@@ -1022,25 +1047,30 @@ def _walk_entries(pos0, w: int, block_size: int, nblk: int):
 
 def _paged_live_kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                        k_s, v_s, sem, *, block_size: int, nblk: int,
-                       group: int, w: int, window: int = 0):
-    """One (slot b, kv-head h) grid step: the slot's whole walk, bounded
-    by its live length (`_paged_kernel`'s result for unquantized pools
-    without its dead grid steps).
+                       group: int, w: int, hg: int, window: int = 0):
+    """One (slot b, group of `hg` kv heads) grid step: the slot's whole
+    walk, bounded by its live length (`_paged_kernel`'s result for
+    unquantized pools without its dead grid steps), each table entry
+    copied ONCE for all the group's heads.
 
-    q_ref / o_ref as in `_paged_kernel`; k_hbm / v_hbm: the POOLS, left
-    in HBM. The first `_walk_entries` table entries are copied into the
-    k_s / v_s banks (entry j to rows j*bs ..), all in flight at once on
+    q_ref / o_ref: (hg, Wg, hd), a head's rows as in `_paged_kernel`;
+    k_hbm / v_hbm: the POOLS, left in HBM; k_s / v_s: (hg, S, hd), a
+    bank a head. The first `_walk_entries` table entries are copied into
+    the banks (entry j, heads h0 .. h0 + hg, which lie back to back in
+    the pool, to rows j*bs .. of every head's bank: one descriptor a
+    pool), all in flight at once on
     ONE DMA semaphore a pool. Such a semaphore counts bytes landed from
     ANY copy that signals it, so a wait that returns says nothing of
     ITS copy: NOTHING IS READ FROM A BANK BEFORE EVERY WAIT OF BOTH
     LOOPS' COPIES HAS RETURNED. Then `_paged_kernel`'s finish, op for
-    op. Rows past the walk hold whatever VMEM held (the previous grid
+    op, head by head over that head's (S, hd) bank. Rows past the walk
+    hold whatever VMEM held (the previous grid
     step's rows, NaN for all we know): their scores are masked to -inf
     as every dead row's are (a select, so a NaN score goes too), and
     V's are SELECTED to zero ahead of the product, because 0 x NaN is
     NaN."""
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    heads = pl.ds(pl.multiple_of(pl.program_id(1) * hg, hg), hg)
     pos0 = pos_ref[b]
     n_live = _walk_entries(pos0, w, block_size, nblk)
 
@@ -1048,10 +1078,10 @@ def _paged_live_kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         rows = pl.ds(pl.multiple_of(j * block_size, block_size),
                      block_size)
         blk = table_ref[b, j]
-        return (pltpu.make_async_copy(k_hbm.at[blk, h], k_s.at[rows, :],
-                                      sem.at[0]),
-                pltpu.make_async_copy(v_hbm.at[blk, h], v_s.at[rows, :],
-                                      sem.at[1]))
+        return (pltpu.make_async_copy(k_hbm.at[blk, heads],
+                                      k_s.at[:, rows, :], sem.at[0]),
+                pltpu.make_async_copy(v_hbm.at[blk, heads],
+                                      v_s.at[:, rows, :], sem.at[1]))
 
     def start(j, carry):
         for c in copies(j):
@@ -1066,26 +1096,31 @@ def _paged_live_kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     jax.lax.fori_loop(0, n_live, start, 0)
     jax.lax.fori_loop(0, n_live, wait, 0)
 
-    q = q_ref[...]                                 # (Wg, hd)
-    s = jax.lax.dot_general(
-        q, k_s[...].astype(q.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(q.dtype)
-    sf = (s / math.sqrt(q.shape[-1])).astype(jnp.float32)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 1)
-    wrow = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 0) // group
+    # the horizon is the slot's, the same for every head of the group
+    sshape = (q_ref.shape[1], k_s.shape[1])
+    kpos = jax.lax.broadcasted_iota(jnp.int32, sshape, 1)
+    wrow = jax.lax.broadcasted_iota(jnp.int32, sshape, 0) // group
     if window:
         kpos = _ring_kpos(kpos // block_size, kpos % block_size,
                           pos0 + wrow, block_size, nblk)
     live = _live(kpos, pos0 + wrow, window)        # per-window-row horizon
-    sf = jnp.where(live, sf, -jnp.inf)
-    p = jax.nn.softmax(sf, axis=-1)                # oracle op order
-    vrow = jax.lax.broadcasted_iota(jnp.int32, v_s.shape, 0)
-    v = jnp.where(vrow < n_live * block_size, v_s[...], 0)
-    att = jax.lax.dot_general(
-        p.astype(o_ref.dtype), v.astype(o_ref.dtype),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    o_ref[...] = att.astype(o_ref.dtype)
+    vrow = jax.lax.broadcasted_iota(jnp.int32, k_s.shape[1:], 0)
+    vlive = vrow < n_live * block_size
+
+    for i in range(hg):                            # a head's finish
+        q = q_ref[i]                               # (Wg, hd)
+        s = jax.lax.dot_general(
+            q, k_s[i].astype(q.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(q.dtype)
+        sf = (s / math.sqrt(q.shape[-1])).astype(jnp.float32)
+        sf = jnp.where(live, sf, -jnp.inf)
+        p = jax.nn.softmax(sf, axis=-1)            # oracle op order
+        v = jnp.where(vlive, v_s[i], 0)
+        att = jax.lax.dot_general(
+            p.astype(o_ref.dtype), v.astype(o_ref.dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[i] = att.astype(o_ref.dtype)
 
 
 def paged_online_scratch_shapes(wg_pad: int, head_dim: int) -> list:
@@ -1270,35 +1305,77 @@ def fused_paged_online_attention(q: jax.Array, k_pool: jax.Array,
                              window=window)
 
 
+# VMEM the bounded walk may plan with: the two banks of a grid step's
+# heads and one head's finish. v5e has 128 MiB; half is left to the
+# compiler (the q / o blocks it double-buffers, what it spills).
+_WALK_VMEM_BUDGET = 64 << 20
+
+
+def _walk_vmem_bytes(hg: int, seq: int, hd: int, wg: int,
+                     pool_itemsize: int, q_itemsize: int) -> int:
+    """What a grid step of `_paged_live_kernel` holds in VMEM with `hg`
+    heads a group: the K and V banks of all of them, and ONE head's
+    finish (its K rows as loaded and cast, its V rows selected and
+    cast, and the score rows, `wg` = W * group padded to 8 sublanes,
+    through mask and softmax in float32)."""
+    banks = 2 * hg * seq * hd * pool_itemsize
+    finish = 2 * seq * hd * (pool_itemsize + q_itemsize) \
+        + 6 * (wg + -wg % 8) * seq * 4
+    return banks + finish
+
+
+def walk_heads_per_copy(nkv: int, seq: int, hd: int, wg: int,
+                        pool_itemsize: int, q_itemsize: int) -> int:
+    """How many kv heads one grid step of the bounded walk owns, and
+    with them one copy of a table entry carries: the largest divisor of
+    `nkv` (the CALL's: under `shard_map` the shard's) whose
+    `_walk_vmem_bytes` fit `_WALK_VMEM_BUDGET`; 1 where none does,
+    whatever the bytes (a bank too long for VMEM is then the compiler's
+    to refuse, as it always was)."""
+    for hg in range(nkv, 1, -1):
+        if nkv % hg == 0 and _walk_vmem_bytes(
+                hg, seq, hd, wg, pool_itemsize,
+                q_itemsize) <= _WALK_VMEM_BUDGET:
+            return hg
+    return 1
+
+
 def _live_walk_call(qk, k_pool, v_pool, table, pos0, *, w: int,
                     group: int, window: int, interpret: bool) -> jax.Array:
     """Launch `_paged_live_kernel`: qk [B, n_kv, Wg_pad, hd] in, the
-    same out. Grid (slot, kv head), both parallel; the pools stay in HBM
+    same out. Grid (slot, n_kv // hg), both parallel, `hg` =
+    `walk_heads_per_copy` of the operands' shapes; the pools stay in HBM
     for the kernel's own copies; table and pos0 scalar-prefetched; the
-    two (S, hd) banks in the pools' dtype and a DMA semaphore a pool."""
+    two (hg, S, hd) banks in the pools' dtype and a DMA semaphore a
+    pool; the VMEM limit stated from those bytes."""
     b, nkv, wg_pad, hd = qk.shape
     bs = k_pool.shape[2]
     maxb = table.shape[1]
-    q_spec = pl.BlockSpec((None, None, wg_pad, hd),
-                          lambda bb, hh, *_: (bb, hh, 0, 0))
+    sizes = (maxb * bs, hd, wg_pad, jnp.dtype(k_pool.dtype).itemsize,
+             jnp.dtype(qk.dtype).itemsize)
+    hg = walk_heads_per_copy(nkv, *sizes)
+    q_spec = pl.BlockSpec((None, hg, wg_pad, hd),
+                          lambda bb, gg, *_: (bb, gg, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     return pl.pallas_call(
         functools.partial(_paged_live_kernel, block_size=bs, nblk=maxb,
-                          group=group, w=w, window=window),
+                          group=group, w=w, hg=hg, window=window),
         name="hpx_paged_fused" + ("_win" if window else ""),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, nkv),
+            grid=(b, nkv // hg),
             in_specs=[q_spec, pool_spec, pool_spec],
             out_specs=[q_spec],
-            scratch_shapes=[pltpu.VMEM((maxb * bs, hd), k_pool.dtype),
-                            pltpu.VMEM((maxb * bs, hd), v_pool.dtype),
+            scratch_shapes=[pltpu.VMEM((hg, maxb * bs, hd), k_pool.dtype),
+                            pltpu.VMEM((hg, maxb * bs, hd), v_pool.dtype),
                             pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=[_sds((b, nkv, wg_pad, hd), qk.dtype, qk, k_pool,
                         v_pool)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=int(max(
+                _walk_vmem_bytes(hg, *sizes) + (8 << 20), 32 << 20))),
         interpret=interpret,
     )(table.astype(jnp.int32), pos0.astype(jnp.int32), qk, k_pool,
       v_pool)[0]

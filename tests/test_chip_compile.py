@@ -137,26 +137,49 @@ def _cell_case(make, cell, w=1, hd=128, **pool_kw):
                         make((32, maxb), jnp.int32), make((32,), jnp.int32))
 
 
+def _walk_grids(fn, *shapes) -> list:
+    """The grid of every `pallas_call` in fn's jaxpr, `shard_map`'s and
+    `jit`'s bodies included."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield tuple(e.params["grid_mapping"].grid)
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from walk(inner)
+    return list(walk(jax.make_jaxpr(fn)(*shapes).jaxpr))
+
+
+# heads a copy carries at the cells' widths (W = 1 and 4 alike)
+_CELL_HG = {"sc2-3b": 2, "laguna-full": 8, "laguna-window": 8}
+
+
 @pytest.mark.parametrize("w", [1, 4], ids=["decode", "window4"])
 @pytest.mark.parametrize("cell", sorted(_CELL_WIDTHS))
 def test_bounded_walk_compiles_at_the_cells_widths(sds, cell, w):
     """`_paged_live_kernel` (pools left in HBM, a data-dependent loop
-    of block copies in one grid step a (slot, kv head)) at the two
+    of block copies in one grid step a (slot, group of kv heads), one
+    copy an entry for the whole group) at the two
     serving cells' widths, full table and ring, under both names, with
     nothing pool-shaped copied around it."""
     call, pool, shapes = _cell_case(sds, cell, w)
+    nkv = _CELL_WIDTHS[cell][1]
+    assert _walk_grids(call, *shapes) == [(32, nkv // _CELL_HG[cell])]
     text = _kernel_text(call, *shapes)
     assert ("hpx_paged_fused_win" in text) == (cell == "laguna-window")
     assert "hpx_paged_fused" in text
     assert _pool_ops(text, pool) == []
 
 
-def test_bounded_walk_compiles_under_the_serving_mesh(topo):
+@pytest.mark.parametrize("cell", ["sc2-3b", "laguna-full"])
+def test_bounded_walk_compiles_under_the_serving_mesh(topo, cell):
     """The mesh form: the same call inside `shard_map` on Mesh(dp=2,
     tp=2) of the described chips, slots over dp, kv heads over tp, the
     pools' block axis replicated (serving._paged_shard_specs) — the
     pools stay in each chip's HBM and the kernel's copies index them by
-    the table's global block ids."""
+    the table's global block ids. The group divides the SHARD's kv
+    heads: one of StarCoder2-3B's two, four of Laguna's eight."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
     specs = {4: P("dp", None, "tp", None), 2: P("dp", None), 1: P("dp")}
@@ -167,12 +190,47 @@ def test_bounded_walk_compiles_under_the_serving_mesh(topo):
         spec = pool_sp if shape[0] != 32 else specs[len(shape)]
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
-    call, _, shapes = _cell_case(on, "sc2-3b")
-    text = _kernel_text(
-        jax.shard_map(call, mesh=mesh, out_specs=specs[4],
-                      in_specs=(specs[4], pool_sp, pool_sp, specs[2],
-                                specs[1])), *shapes)
-    assert "hpx_paged_fused" in text
+    call, _, shapes = _cell_case(on, cell)
+    sharded = jax.shard_map(call, mesh=mesh, out_specs=specs[4],
+                            in_specs=(specs[4], pool_sp, pool_sp, specs[2],
+                                      specs[1]))
+    shard_nkv = _CELL_WIDTHS[cell][1] // 2
+    (slots, groups), = _walk_grids(sharded, *shapes)
+    assert slots == 16 and shard_nkv % groups == 0
+    assert shard_nkv // groups == {"sc2-3b": 1, "laguna-full": 4}[cell]
+    assert "hpx_paged_fused" in _kernel_text(sharded, *shapes)
+
+
+@pytest.mark.parametrize("nkv,smax,dtype,hg", [
+    (8, 4864, jnp.float32, 8),       # 8 KB a head and entry, 40 MB of banks
+    (8, 9728, jnp.float32, 4),
+    (8, 16384, jnp.bfloat16, 4),
+    (6, 16384, jnp.bfloat16, 3),
+    (1, 28672, jnp.bfloat16, 1),     # ROADMAP A11: must not move down
+    (8, 28672, jnp.bfloat16, 2),
+    (1, 131072, jnp.bfloat16, 1),    # the stated VMEM limit's reach
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_bounded_walk_compiles_at_every_group_the_rule_picks(
+        sds, nkv, smax, dtype, hg):
+    """The launch states its VMEM limit from the banks it allocates, so
+    whatever group `walk_heads_per_copy` picks compiles: float32 pools,
+    a group that is no power of two, and the long tables at which only
+    one head fits (smax 28,672 compiled before this path grouped heads,
+    and still does)."""
+    maxb, b, g = smax // 16, 8, 3
+    item = jnp.dtype(dtype).itemsize
+    assert ap.walk_heads_per_copy(nkv, smax, 128, g, item, item) == hg
+    pool = sds((b * maxb + 1, nkv, 16, 128), dtype)
+
+    def call(q, kp, vp, table, pos):
+        return ap.fused_paged_attention(q, kp, vp, table, pos,
+                                        interpret=False)
+    shapes = (sds((b, 1, g * nkv, 128), dtype), pool, pool,
+              sds((b, maxb), jnp.int32), sds((b,), jnp.int32))
+    assert _walk_grids(call, *shapes) == [(b, nkv // hg)]
+    text = _kernel_text(call, *shapes)
+    if dtype == jnp.bfloat16:
+        assert _pool_ops(text, pool) == []
 
 
 def test_a_head_of_64_streams_its_pools_without_a_copy(sds):

@@ -316,6 +316,46 @@ def test_fused_never_reads_a_dead_entry(window, w, dtype):
     assert (outs[0] == outs[1]).all()
 
 
+@pytest.mark.parametrize("head", [0, 2, 3])
+@pytest.mark.parametrize("w", [1, 4])
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
+def test_a_dead_row_of_any_head_of_the_group_stays_out(window, w, head):
+    """Four kv heads share a grid step and a copy (hg = 4). NaN sits in
+    ONE head's rows of the block every dead entry points at, and in
+    that head's rows of every block slot 0 walks (so that head's bank
+    holds NaN past the walks of the slots after it): slot 0's queries
+    of that head read NaN, as they must, and every other output equals,
+    bit for bit, the run with zeros in the NaN's place."""
+    bs, maxb, B, nkv, nq = 16, 8, 3, 4, 8
+    rng = np.random.default_rng(37)
+    nb = B * maxb + 2
+    kp = rng.standard_normal((nb, nkv, bs, _HD)).astype(np.float32)
+    vp = rng.standard_normal((nb, nkv, bs, _HD)).astype(np.float32)
+    pos = np.array([maxb * bs - w, 3, 2 * bs + 1], np.int32)
+    table = np.arange(2, nb, dtype=np.int32).reshape(B, maxb)
+    entries = (pos + w - 1) // bs + 1
+    table = np.where(np.arange(maxb)[None, :] < entries[:, None], table, 1)
+    q = jnp.asarray(rng.standard_normal((B, w, nq, _HD)), jnp.bfloat16)
+    assert ap.walk_heads_per_copy(*_walk_sizes(
+        nkv, maxb, bs, _HD, w * nq // nkv, jnp.bfloat16)) == nkv
+    outs = []
+    for fill in (np.nan, 0.0):
+        for pool in (kp, vp):
+            pool[1, head] = fill
+            pool[table[0], head] = fill
+        outs.append(np.asarray(ap.fused_paged_attention(
+            q, jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16),
+            jnp.asarray(table), jnp.asarray(pos), interpret=True,
+            window=window), np.float32))
+    bad, good = outs
+    g = nq // nkv
+    mine = np.zeros(bad.shape, bool)
+    mine[0, :, head * g:(head + 1) * g] = True     # slot 0, that head
+    assert np.isnan(bad[mine]).all()
+    assert np.isfinite(bad[~mine]).all()
+    assert (bad[~mine] == good[~mine]).all()
+
+
 def _assert_close_to_oracle(fused, gather, dtype):
     """f32: ulp-tight; bf16: one ulp of the final cast at |x| <= 2."""
     tol = 2e-6 if dtype == jnp.float32 else 1.6e-2
@@ -383,6 +423,12 @@ def _paged_grid(fn, *args):
     return tuple(calls[0].params["grid_mapping"].grid)
 
 
+def _walk_sizes(nkv, maxb, bs, hd, wg, dtype):
+    """`walk_heads_per_copy`'s arguments for a call's shapes."""
+    item = jnp.dtype(dtype).itemsize
+    return nkv, maxb * bs, hd, wg, item, item
+
+
 @pytest.mark.parametrize("interpret", [True, False],
                          ids=["interpret", "compiled"])
 @pytest.mark.parametrize("kernel,pool,hd,w,window,axes", [
@@ -398,7 +444,9 @@ def test_which_calls_take_the_bounded_walk(kernel, pool, hd, w, window,
                                            axes, interpret):
     """The dispatch, read off the jaxpr: a `fused` call over
     unquantized pools with a head of whole 128-lane rows launches the
-    two-axis grid (slot, kv head); a quantized pool, a narrower head
+    two-axis grid (slot, n_kv // hg), a grid step a slot and GROUP of
+    `hg` kv heads (here both heads: the banks are small); a quantized
+    pool, a narrower head
     and `fused_online` still launch the grid walk's three axes (slot,
     kv head, table entry). The choice looks at the operands alone, so
     it is the same in interpret mode and compiled for the chip."""
@@ -417,7 +465,127 @@ def test_which_calls_take_the_bounded_walk(kernel, pool, hd, w, window,
         lambda q, kp, vp: fpa(q, kp, vp, table, pos, k_scale=ks,
                               v_scale=vs, interpret=interpret,
                               window=window), q, kp, vp)
-    assert grid == ((B, nkv) if axes == 2 else (B, nkv, maxb))
+    hg = ap.walk_heads_per_copy(
+        *_walk_sizes(nkv, maxb, bs, hd, w * nq // nkv, dtype))
+    assert hg == nkv
+    assert grid == ((B, nkv // hg) if axes == 2 else (B, nkv, maxb))
+
+
+# the cells' calls and the corners of the rule: (n_kv, table entries,
+# W * group, pool dtype) -> heads a copy. Block 16, head 128.
+_HG_CASES = [
+    ((2, 128, 12, jnp.bfloat16), 2),      # StarCoder2-3B: 2 MB of banks
+    ((8, 304, 6, jnp.bfloat16), 8),       # Laguna's full layers: 20 MB
+    ((8, 34, 8, jnp.bfloat16), 8),        # Laguna's ring: 2.2 MB
+    ((1, 128, 24, jnp.bfloat16), 1),      # one kv head (tp = n_kv)
+    ((4, 304, 6, jnp.bfloat16), 4),       # Laguna's shard on tp = 2
+    ((8, 304, 6, jnp.float32), 8),        # float32 pools: 40 MB
+    ((8, 608, 6, jnp.float32), 4),        # and twice the rows
+    ((8, 1024, 6, jnp.bfloat16), 4),      # smax 16,384
+    ((6, 1024, 8, jnp.bfloat16), 3),      # a divisor, not a power of two
+    ((2, 1792, 12, jnp.bfloat16), 1),     # smax 28,672: one head fits
+    ((8, 1792, 6, jnp.bfloat16), 2),
+    ((8, 4096, 6, jnp.bfloat16), 1),      # nothing fits: still 1
+]
+
+
+@pytest.mark.parametrize("shape,want", _HG_CASES, ids=[
+    f"nkv{c[0][0]}-maxb{c[0][1]}-{jnp.dtype(c[0][3]).name}"
+    for c in _HG_CASES])
+def test_heads_per_copy_follows_the_banks(shape, want):
+    """`hg` is the largest divisor of the call's n_kv whose banks and
+    one head's finish fit the stated VMEM budget, and 1 where none
+    does; the grid the launch pins is (B, n_kv // hg)."""
+    nkv, maxb, wg, dtype = shape
+    sizes = _walk_sizes(nkv, maxb, 16, _HD, wg, dtype)
+    hg = ap.walk_heads_per_copy(*sizes)
+    assert hg == want and nkv % hg == 0
+    assert hg == 1 or ap._walk_vmem_bytes(hg, *sizes[1:]) \
+        <= ap._WALK_VMEM_BUDGET
+    for more in range(hg + 1, nkv + 1):
+        assert nkv % more or ap._walk_vmem_bytes(more, *sizes[1:]) \
+            > ap._WALK_VMEM_BUDGET
+    if maxb <= 304:
+        q = jax.ShapeDtypeStruct((2, 1, nkv * wg, _HD), dtype)
+        pool = jax.ShapeDtypeStruct((5, nkv, 16, _HD), dtype)
+        grid = _paged_grid(
+            lambda q, kp, vp: ap.fused_paged_attention(
+                q, kp, vp, jnp.zeros((2, maxb), jnp.int32),
+                jnp.zeros((2,), jnp.int32), interpret=True), q, pool, pool)
+        assert grid == (2, nkv // hg)
+
+
+def _forced(monkeypatch, hg):
+    """Make every bounded-walk launch group `hg` heads (`None`: what the
+    shapes give). The rule is the ONE place a launch asks."""
+    if hg is None:
+        monkeypatch.undo()
+    else:
+        monkeypatch.setattr(ap, "walk_heads_per_copy", lambda *a: hg)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
+@pytest.mark.parametrize("w", [1, 4])
+@pytest.mark.parametrize("nkv", [1, 2, 8])
+def test_a_group_of_heads_gives_one_heads_bits(nkv, w, window, dtype,
+                                               monkeypatch):
+    """Every group size a call's n_kv admits (n_kv 1 / 2 / 8: hg 1, 2,
+    and 2 / 4 / 8) gives, bit for bit, what one head a grid step gives
+    (PR 31's kernel), and that agrees with the gather oracle as it
+    always has: a head's finish runs over its own bank whatever shares
+    the copy."""
+    bs, maxb, B = 16, 6, 3
+    kp, vp, table, pos, q, kn, vn = _paged_state(
+        bs, maxb, B=B, nkv=nkv, nq=2 * nkv, hd=_HD, w=w, dtype=dtype,
+        seed=53 + nkv)
+    pos = jnp.asarray([0, 2 * bs + 3, maxb * bs - w], jnp.int32)
+
+    def run():
+        return np.asarray(ap.fused_paged_attention(
+            q, kp, vp, table, pos, interpret=True, window=window),
+            np.float32)
+    assert ap.walk_heads_per_copy(*_walk_sizes(
+        nkv, maxb, bs, _HD, 2 * w, dtype)) == nkv
+    _forced(monkeypatch, 1)
+    one = run()
+    for hg in [h for h in (2, 4, 8) if nkv % h == 0] + [None]:
+        _forced(monkeypatch, hg)
+        assert (run() == one).all(), hg
+    if w > 1 and window:
+        return      # no oracle: a verify window over a ring is refused
+    attend = paged_decode_attention if w == 1 else paged_window_attention
+    ag = attend(q, kn, vn, kp, vp, table, pos, window=window)[0]
+    af = attend(q, kn, vn, kp, vp, table, pos, window=window, fused=True,
+                interpret=True)[0]
+    _assert_close_to_oracle(af, ag, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_a_bank_too_long_for_a_group_walks_one_head(dtype, monkeypatch):
+    """An smax at which only hg = 1 fits (the budget shrunk to this
+    toy's banks, as 28,672 rows do to the real one): the launch falls
+    back to one head a grid step and gives the grouped kernel's bits."""
+    bs, maxb, B, nkv = 16, 6, 2, 4
+    kp, vp, table, pos, q, _, _ = _paged_state(
+        bs, maxb, B=B, nkv=nkv, nq=8, hd=_HD, dtype=dtype, seed=59)
+    sizes = _walk_sizes(nkv, maxb, bs, _HD, 2, dtype)
+
+    def run():
+        fn = lambda q, kp, vp: ap.fused_paged_attention(  # noqa: E731
+            q, kp, vp, table, pos, interpret=True)
+        return _paged_grid(fn, q, kp, vp), np.asarray(fn(q, kp, vp),
+                                                      np.float32)
+    grid, grouped = run()
+    assert grid == (B, 1)
+    monkeypatch.setattr(ap, "_WALK_VMEM_BUDGET",
+                        ap._walk_vmem_bytes(2, *sizes[1:]) - 1)
+    assert ap.walk_heads_per_copy(*sizes) == 1
+    grid, single = run()
+    assert grid == (B, nkv)
+    assert (single == grouped).all()
 
 
 def test_server_walk_share_follows_the_positions(params):
@@ -448,6 +616,55 @@ def test_server_walk_share_follows_the_positions(params):
                 "cache", name, srv.counter_instance)).value == st[key]
         seen += 1
     assert seen > 3
+
+
+@pytest.mark.parametrize("kernel,kv_dtype,hd,nkv,want", [
+    ("fused", "bf16", _HD, 2, 2), ("fused", "bf16", _HD, 4, 4),
+    ("fused", "bf16", _HD, 1, 1),
+    ("fused", "int8", _HD, 2, 0), ("fused_online", "bf16", _HD, 2, 0),
+    ("gather", "bf16", _HD, 2, 0), ("fused", "bf16", 8, 2, 0)])
+def test_server_counts_the_heads_a_copy_carries(kernel, kv_dtype, hd, nkv,
+                                                want):
+    """`hbm_read_stats()` carries the group of the full group's decode
+    call and the copies a slot, layer and step issues (entries x 2
+    pools x n_kv / hg), 0 where the server's calls keep the grid walk;
+    the registry carries both."""
+    from hpx_tpu.svc import performance_counters as pc
+    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4,
+                                n_kv_heads=nkv, head_dim=hd, n_layers=2,
+                                d_ff=64)
+    srv = ContinuousServer(tfm.init_params(cfg, jax.random.PRNGKey(0)),
+                           cfg, slots=3, smax=64, paged=True, block_size=8,
+                           paged_kernel=kernel, kv_dtype=kv_dtype)
+    st = srv.hbm_read_stats()
+    assert st["heads_per_copy"] == want
+    assert st["walk_copies_per_slot"] == 0.0
+    for r in REQS[:3]:
+        srv.submit(**r)
+    seen = 0
+    while srv.step():
+        st = srv.hbm_read_stats()
+        if not srv.live_positions():
+            continue
+        assert st["heads_per_copy"] == want
+        assert st["walk_copies_per_slot"] == pytest.approx(
+            st["walk_entries_per_slot"] * 2 * nkv / want if want else 0.0)
+        for name, key in (("count/heads-per-copy", "heads_per_copy"),
+                          ("count/walk-copies-per-slot",
+                           "walk_copies_per_slot")):
+            assert pc.query_counter(pc.counter_name(
+                "cache", name, srv.counter_instance)).value == st[key]
+        seen += 1
+    assert seen > 3
+    if want:
+        # what the launch itself groups, read off the step's jaxpr
+        q = jnp.zeros((3, 1, 4, hd), cfg.dtype)
+        pool = jnp.zeros((4, nkv, 8, hd), cfg.dtype)
+        grid = _paged_grid(
+            lambda q, kp, vp: ap.fused_paged_attention(
+                q, kp, vp, jnp.zeros((3, 8), jnp.int32),
+                jnp.zeros((3,), jnp.int32), interpret=True), q, pool, pool)
+        assert grid == (3, nkv // want)
 
 
 @pytest.mark.parametrize("kern", ["gather", "fused", "fused_online"])
@@ -483,6 +700,26 @@ def test_flash_tune_paged_step_at_a_position(pos):
     assert hbm == 2 * 4 * (pos // 16 + 1) * 16 * 2 * 128 * 2
     np.testing.assert_allclose(np.asarray(f(q), np.float32),
                                np.asarray(g(qg), np.float32), atol=8e-3)
+
+
+@pytest.mark.parametrize("kern", ["gather", "fused"])
+def test_flash_tune_paged_measure_times_one_dispatch(kern, monkeypatch):
+    """`paged_measure` chains its calls inside ONE jitted loop (the trip
+    count is data: one compile) and reads the clock around one dispatch
+    a chain, so the host's dispatch latency is not in the slope."""
+    from benchmarks import flash_tune
+    chains = []
+    real = flash_tune.slope_time
+
+    def spy(run_chain, k1, k2, repeats=3):
+        chains.append((k1, k2))
+        return real(run_chain, k1, k2, repeats=1)
+    monkeypatch.setattr(flash_tune, "slope_time", spy)
+    gbs, us, spread = flash_tune.paged_measure(
+        jax, jnp, 64, 16, "bf16", kern, samples=1, pos=20, heads=(2, 4, 2))
+    assert us > 0 and gbs > 0 and spread == 0.0
+    (k1, k2), = chains
+    assert k1 == 8 and k2 - k1 >= 64       # the slope's two trip counts
 
 
 # -- row writes: scatter_token / scatter_window vs a NumPy row loop ---------

@@ -14,7 +14,9 @@ over bf16 pools takes the walk bounded by each slot's live length
 things: that hundreds of copies in flight on one semaphore have ALL
 landed before the banks are read, and that the rows of the VMEM banks
 the PREVIOUS grid step left behind a short walk stay out of the
-result."""
+result. Since PR 35 a grid step owns a GROUP of kv heads and copies a
+table entry once for all of them (`TestHeadGroups`: every group size
+bit for bit one head's result, at both serving cells' shapes)."""
 
 import jax
 import jax.numpy as jnp
@@ -229,3 +231,115 @@ class TestBoundedWalk:
         assert np.isnan(bad[0]).all()        # the poison was in the bank
         assert np.isfinite(bad[1:]).all()
         assert (bad[1:] == good[1:]).all()
+
+
+class TestHeadGroups:
+    """What only the chip can show of a grid step that owns a GROUP of
+    kv heads (PR 35): one descriptor moves `hg` heads' tiles into `hg`
+    banks (a strided destination), and the heads' finishes run one
+    after another over banks that all landed on the same semaphores."""
+
+    @staticmethod
+    def _case(nkv, nq, maxb, w, dtype, seed):
+        B, bs, hd = 8, 16, 128
+        nb = B * maxb + 1
+        kp, vp = _pools(nb, bs, nkv, hd, dtype, seed=seed)
+        table = _table(B, maxb, nb, seed=seed + 1)
+        top = maxb * bs - w
+        pos = jnp.asarray([0, 15, 16, min(527, top), top // 3, top // 2,
+                           max(top - 16, 0), top], jnp.int32)
+        rng = np.random.default_rng(seed + 2)
+        q = jnp.asarray(rng.standard_normal((B, w, nq, hd), np.float32),
+                        dtype)
+        return q, kp, vp, table, pos
+
+    @pytest.mark.parametrize("nkv,nq,maxb,window,w,dtype,hg", [
+        (2, 24, 128, 0, 1, jnp.bfloat16, 2),       # sc2-3b.gen-closed
+        (8, 48, 304, 0, 1, jnp.bfloat16, 8),       # Laguna's full layers
+        (8, 64, 34, 512, 1, jnp.bfloat16, 8),      # Laguna's ring
+        (8, 48, 304, 0, 4, jnp.bfloat16, 8),       # a verify window
+        (8, 16, 304, 0, 1, jnp.float32, 8),        # 8 KB a head and entry
+        (6, 12, 64, 0, 1, jnp.bfloat16, 6),        # no power of two
+    ], ids=["sc2-3b", "laguna-full", "laguna-window", "laguna-full-w4",
+            "f32", "nkv6"])
+    def test_every_group_gives_one_heads_bits(self, monkeypatch, nkv, nq,
+                                              maxb, window, w, dtype, hg):
+        """At the cells' shapes the group the rule picks, and every
+        smaller divisor, give bit for bit what one head a grid step
+        gives (PR 31's kernel), which stays as close to the gather
+        oracle as it was."""
+        from hpx_tpu.ops import attention_pallas as ap
+        from hpx_tpu.ops.paged_attention import gather_block_kv
+        q, kp, vp, table, pos = self._case(nkv, nq, maxb, w, dtype, 40)
+        item = jnp.dtype(dtype).itemsize
+        assert ap.walk_heads_per_copy(nkv, maxb * 16, 128, w * nq // nkv,
+                                      item, item) == hg
+
+        def run(force):
+            if force:
+                monkeypatch.setattr(ap, "walk_heads_per_copy",
+                                    lambda *a: force)
+            else:
+                monkeypatch.undo()
+            return np.asarray(jax.jit(
+                lambda q, kp, vp: ap.fused_paged_attention(
+                    q, kp, vp, table, pos, window=window)
+            )(q, kp, vp), np.float32)
+        one = run(1)
+        assert np.isfinite(one).all()
+        for force in [d for d in range(2, nkv + 1) if nkv % d == 0] + [0]:
+            for _ in range(2):      # a race would not show every time
+                assert (run(force) == one).all(), force
+        if not window:
+            # the oracle over the same pools (nothing written this step)
+            g = nq // nkv
+            kc = gather_block_kv(kp, table, None, q.dtype)
+            vc = gather_block_kv(vp, table, None, q.dtype)
+            qg = q.reshape(8, w, nkv, g, 128)
+            s = (jnp.einsum("bqngh,bknh->bngqk", qg, kc)
+                 / np.sqrt(128)).astype(jnp.float32)
+            live = (jnp.arange(kc.shape[1])[None, None, :]
+                    <= pos[:, None, None] + jnp.arange(w)[None, :, None])
+            s = jnp.where(live[:, None, None], s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            want = jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(q.shape)
+            # float32 too: at the default precision the chip multiplies
+            # the oracle's einsums in bfloat16 passes
+            _close(one, want, 3e-2)
+
+    @pytest.mark.parametrize("head", [0, 5, 7])
+    @pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
+    def test_a_dead_row_of_any_head_of_the_group_stays_out(self, window,
+                                                           head):
+        """Eight heads share a grid step. NaN in ONE head's rows of the
+        blocks slot 0 walks (its whole table) and of the trash block:
+        that head's bank holds NaN behind the short walks of the slots
+        after it. Only slot 0's queries of that head read NaN; all else
+        equals, bit for bit, the run with zeros in its place."""
+        from hpx_tpu.ops.attention_pallas import fused_paged_attention
+        B, bs, maxb, nkv, nq, hd = 4, 16, 16, 8, 16, 128
+        nb = B * maxb + 1
+        kf, vf = _pools(nb, bs, nkv, hd, seed=50)
+        table = np.arange(1, nb, dtype=np.int32).reshape(B, maxb)
+        pos = np.asarray([maxb * bs - 1, 3, 2 * bs, 5 * bs - 1], np.int32)
+        live = np.arange(maxb)[None, :] <= (pos // bs)[:, None]
+        table = jnp.asarray(np.where(live, table, 0))    # tail: trash
+        rng = np.random.default_rng(51)
+        q = jnp.asarray(rng.standard_normal((B, 1, nq, hd), np.float32),
+                        jnp.bfloat16)
+        bad_blocks = np.arange(0, maxb + 1)      # trash + slot 0's
+
+        def run(fill):
+            kp = kf.at[bad_blocks, head].set(fill)
+            vp = vf.at[bad_blocks, head].set(fill)
+            return np.asarray(jax.jit(
+                lambda q, kp, vp: fused_paged_attention(
+                    q, kp, vp, table, jnp.asarray(pos), window=window)
+            )(q, kp, vp), np.float32)
+        bad, good = run(np.nan), run(0.0)
+        g = nq // nkv
+        mine = np.zeros(bad.shape, bool)
+        mine[0, :, head * g:(head + 1) * g] = True
+        assert np.isnan(bad[mine]).all()     # the poison was in the bank
+        assert np.isfinite(bad[~mine]).all()
+        assert (bad[~mine] == good[~mine]).all()
