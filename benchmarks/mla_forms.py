@@ -1,0 +1,196 @@
+"""Latent attention's open choices, timed on the chip (device clock).
+
+Two questions a cell cannot split, one line of JSON a reading:
+
+1. `hpx_mla_paged` alone (the blocked walk of ops/attention_pallas.py)
+   at a cell's shape, every slot at one position: how its time follows
+   the live length, under DeepSeek-V2's 128 heads (64 slots, a table of
+   1,576 entries) and under Kimi-Linear's 32 (48 slots, 264 entries).
+   With `--entries N[,N...]` at other sizes of a fold
+   (`LATENT_WALK_ENTRIES` table entries a buffer), with `--block 64`
+   over pools of another block size (the same rows).
+2. A prefill chunk of W queries over `--rows` cached rows: the ABSORBED
+   form the program keeps (`transformer._latent_attention`: every head
+   scores the 640-wide row and weighs its first 512 columns) against
+   the EXPANDED form (K and V of every head materialised from the
+   latent a block, then 192-wide scores and 128-wide values), both
+   walked in the same blocks under the same online softmax.
+
+Times as benchmarks/flash_tune.py `paged_measure` takes them: `n` calls
+inside ONE jitted loop, the slope over two `n`. Exits non-zero without
+a TPU.
+
+Usage: python benchmarks/mla_forms.py [--kernel] [--chunk]
+           [--entries 16,32,64] [--block 16]
+           [--positions 1535,15231] [--rows 15360] [--widths 128,256]
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))))
+
+from bench import slope_time  # noqa: E402 — one timing discipline
+
+SHAPES = {"deepseek-v2": (64, 128, 1576, (1023, 8191, 15231, 25215)),
+          "kimi-linear": (48, 32, 264, (511, 1535, 4223))}
+ROW, RANK, BS = 640, 512, 16
+
+
+def device_us(jax, step, x, operands, samples=3):
+    @jax.jit
+    def loop(xx, n, *ops):
+        return jax.lax.fori_loop(
+            0, n, lambda _, y: step(y, *ops).astype(xx.dtype), xx)
+
+    def chain(k):
+        t0 = time.perf_counter()
+        jax.block_until_ready(loop(x, k, *operands))
+        return time.perf_counter() - t0
+    chain(4)
+    k2 = 4 + min(max(int(0.25 * 16 / chain(16)), 8), 2048)
+    pers = sorted(slope_time(chain, 4, k2) for _ in range(samples))
+    return pers[(samples - 1) // 2] * 1e6
+
+
+def kernel_lines(jax, jnp, entries, block=BS, positions=None):
+    from hpx_tpu.ops import attention_pallas as ap
+    for name, (b, h, maxb16, own) in SHAPES.items():
+        maxb = maxb16 * BS // block
+        nb = b * maxb + 1
+        ks = jax.random.split(jax.random.PRNGKey(0), 2)
+        pool = jax.random.normal(ks[0], (nb, 1, block, ROW), jnp.bfloat16)
+        q = (jax.random.normal(ks[1], (b, h, ROW)) * 0.3).astype(
+            jnp.bfloat16)
+        table = (1 + jnp.arange(b * maxb, dtype=jnp.int32)).reshape(b, maxb)
+        for fold in entries:
+            ap.LATENT_WALK_ENTRIES = fold
+            for p in positions or own:
+                if p >= maxb * block:
+                    continue
+                pos = jnp.full((b,), p, jnp.int32)
+
+                def step(qq, pool, table, pos):
+                    o = ap.fused_latent_attention(
+                        qq, pool, table, pos, rank=RANK, scale=0.1)
+                    return jnp.pad(o, ((0, 0), (0, 0), (0, ROW - RANK)))
+                us = device_us(jax, step, q, (pool, table, pos))
+                rows = b * (p + 1)
+                print(json.dumps({
+                    "what": "hpx_mla_paged", "shape": name, "slots": b,
+                    "heads": h, "position": p, "block_size": block,
+                    "fold_entries": fold, "us_per_call": round(us, 1),
+                    "live_gb_per_s": round(rows * 1152 / us / 1e3, 1),
+                    "live_tflop_per_s": round(
+                        rows * h * 2 * 1088 / us / 1e6, 1)}), flush=True)
+
+
+def expanded_chunk(jax, jnp, blk):
+    """The expanded form of a chunk, blocked like `_latent_attention`:
+    q [1, W, H, dn + dr] against K = [lat W_uk ; k^R], V = lat W_uv."""
+    def attend(q, lat, wuk, wuv, qpos, scale):
+        b, nq, h, _ = q.shape
+        dn, dv = wuk.shape[-1], wuv.shape[-1]
+        n_blk = jnp.max(qpos) // blk + 1
+
+        def body(j, carry):
+            m, l, acc = carry
+            rows = jax.lax.dynamic_slice_in_dim(lat, j * blk, blk, axis=1)
+            c, kr = rows[..., :RANK], rows[..., RANK:RANK + q.shape[-1] - dn]
+            k = jnp.einsum("bkr,rhn->bkhn", c, wuk)
+            v = jnp.einsum("bkr,rhv->bkhv", c, wuv)
+            s = (jnp.einsum("bqhn,bkhn->bhqk", q[..., :dn], k,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bqhd,bkd->bhqk", q[..., dn:], kr,
+                              preferred_element_type=jnp.float32)) * scale
+            kpos = j * blk + jnp.arange(blk)
+            s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, -1))
+            p = jnp.exp(s - m_new[..., None])
+            fade = jnp.exp(m - m_new)
+            acc = acc * fade[..., None] + jnp.einsum(
+                "bhqk,bkhv->bhqv", p.astype(q.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l * fade + jnp.sum(p, -1), acc
+        m, l, acc = jax.lax.fori_loop(
+            0, n_blk, body,
+            (jnp.full((b, h, nq), -jnp.inf, jnp.float32),
+             jnp.zeros((b, h, nq), jnp.float32),
+             jnp.zeros((b, h, nq, dv), jnp.float32)))
+        return (acc / l[..., None]).astype(q.dtype)
+    return attend
+
+
+def chunk_lines(jax, jnp, rows, widths):
+    from hpx_tpu.models import transformer as tfm
+    h, dn, dr, dv = 128, 128, 64, 128
+    smax = 25216
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    lat = jax.random.normal(ks[0], (1, smax, ROW), jnp.bfloat16)
+    wuk = (jax.random.normal(ks[1], (RANK, h, dn)) * RANK ** -0.5).astype(
+        jnp.bfloat16)
+    wuv = (jax.random.normal(ks[2], (RANK, h, dv)) * RANK ** -0.5).astype(
+        jnp.bfloat16)
+    blk = tfm.LATENT_ROWS_A_BLOCK
+    expanded = expanded_chunk(jax, jnp, blk)
+    for w in widths:
+        qpos = rows + jnp.arange(w)
+        q = (jax.random.normal(ks[3], (1, w, h, dn + dr)) * 0.3).astype(
+            jnp.bfloat16)
+
+        def absorbed(qq, lat, wuk, wuv, qpos):
+            qa = jnp.einsum("bqhn,rhn->bqhr", qq[..., :dn], wuk)
+            qf = jnp.concatenate(
+                [qa, qq[..., dn:],
+                 jnp.zeros(qa.shape[:-1] + (ROW - RANK - dr,), qq.dtype)], -1)
+            o = tfm._latent_attention(qf, lat, qpos, RANK, 0.1)
+            o = jnp.einsum("bqhr,rhv->bqhv", o, wuv)
+            return jnp.pad(o, ((0, 0),) * 3 + ((0, dn + dr - dv),))
+
+        def expand(qq, lat, wuk, wuv, qpos):
+            o = expanded(qq, lat, wuk, wuv, qpos, 0.1)
+            return jnp.pad(jnp.moveaxis(o, 1, 2),
+                           ((0, 0),) * 3 + ((0, dn + dr - dv),))
+        for name, fn in (("absorbed", absorbed), ("expanded", expand)):
+            us = device_us(jax, fn, q, (lat, wuk, wuv, qpos))
+            print(json.dumps({"what": "prefill_chunk_attention",
+                              "form": name, "width": w, "rows": rows,
+                              "block_rows": blk, "heads": h,
+                              "us_per_layer": round(us, 1)}), flush=True)
+
+
+def main() -> int:
+    ap_ = argparse.ArgumentParser()
+    ap_.add_argument("--kernel", action="store_true")
+    ap_.add_argument("--chunk", action="store_true")
+    ap_.add_argument("--entries", default="32")
+    ap_.add_argument("--block", type=int, default=BS)
+    ap_.add_argument("--positions", default="")
+    ap_.add_argument("--rows", type=int, default=15360)
+    ap_.add_argument("--widths", default="128,256")
+    args = ap_.parse_args()
+    import jax
+    import jax.numpy as jnp
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"mla_forms: no TPU (platform {dev.platform!r})",
+              file=sys.stderr)
+        return 2
+    print(json.dumps({"device": dev.device_kind}), flush=True)
+    both = not (args.kernel or args.chunk)
+    if args.kernel or both:
+        kernel_lines(jax, jnp, [int(e) for e in args.entries.split(",")],
+                     args.block,
+                     [int(p) for p in args.positions.split(",") if p])
+    if args.chunk or both:
+        chunk_lines(jax, jnp, args.rows,
+                    [int(w) for w in args.widths.split(",")])
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

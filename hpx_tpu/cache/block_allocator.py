@@ -97,6 +97,7 @@ class BlockAllocator:
         self.total_allocs = 0
         self.total_frees = 0
         self.total_cow_copies = 0
+        self._shared = 0        # blocks held more than once right now
 
     # -- queries ----------------------------------------------------------
 
@@ -109,6 +110,13 @@ class BlockAllocator:
     def in_use(self) -> int:
         with self._lock:
             return self.num_blocks - len(self._free)
+
+    @property
+    def shared_count(self) -> int:
+        """Blocks with more than one holder (a published prefix under
+        the tree and the requests reading it)."""
+        with self._lock:
+            return self._shared
 
     def refcount(self, bid: int) -> int:
         with self._lock:
@@ -139,6 +147,7 @@ class BlockAllocator:
             if n < 1:
                 raise ValueError(f"incref on unallocated block {bid}")
             self._ref[bid] = n + 1
+            self._shared += n == 1
             return n + 1
 
     def decref(self, bid: int) -> bool:
@@ -150,6 +159,7 @@ class BlockAllocator:
                 raise ValueError(f"decref on unallocated block {bid}")
             if n > 1:
                 self._ref[bid] = n - 1
+                self._shared -= n == 2
                 return False
             del self._ref[bid]
             self._free.append(bid)
@@ -176,6 +186,7 @@ class BlockAllocator:
                     f"block {bid} ({self.num_blocks} blocks in use)",
                     "BlockAllocator.fork")
             self._ref[bid] = n - 1
+            self._shared -= n == 2
             new = self._free.pop()
             self._ref[new] = 1
             self.total_allocs += 1
@@ -216,6 +227,7 @@ class BlockAllocator:
                 "kv_dtype": self.kv_dtype,
                 "free": len(self._free),
                 "in_use": self.num_blocks - len(self._free),
+                "shared": self._shared,
                 "total_allocs": self.total_allocs,
                 "total_frees": self.total_frees,
                 "total_cow_copies": self.total_cow_copies,

@@ -42,12 +42,21 @@ MoE servers (``cfg.n_experts > 0``) add the expert-routing feed::
     /serving{locality#L/server#i}/moe/experts-hit     distinct experts hit a
                                                       decode step and sparse
                                                       layer (mean since start)
+    /serving{locality#L/server#i}/moe/routed-here     under a group-limited
+                                                      router: assignments to
+                                                      the held experts
+    /serving{locality#L/server#i}/moe/tokens-here     ... and tokens (a sparse
+                                                      layer each) whose kept
+                                                      groups include a held one
 
 Paged servers additionally export the cache counters::
 
     /cache{locality#L/server#i}/hit-rate                radix prefix hit rate
     /cache{locality#L/server#i}/blocks/in-use           pool blocks allocated
     /cache{locality#L/server#i}/blocks/free             pool blocks free
+    /cache{locality#L/server#i}/blocks/shared           blocks with more than one
+                                                        holder (a published prefix
+                                                        and its readers)
     /cache{locality#L/server#i}/blocks/radix-held       blocks retained by the tree
     /cache{locality#L/server#i}/count/evictions         LRU chains dropped
     /cache{locality#L/server#i}/prefill-tokens/saved    prompt tokens NOT recomputed
@@ -79,6 +88,8 @@ with latent-attention ("mla") layers their rows on the full group::
     /cache{locality#L/server#i}/state/slots-live        slots whose state is a request's
     /cache{locality#L/server#i}/state/resets            admissions that zeroed a state
     /cache{locality#L/server#i}/latent/blocks-in-use    blocks of latent rows held
+    /cache{locality#L/server#i}/latent/rows-walked      rows a decode step's latent
+                                                        walks read, a latent layer
     /serving{locality#L/server#i}/state/prefix-refused  admissions whose prefix match
                                                         was refused (no state snapshot)
     /serving{locality#L/server#i}/state/reprefills      restores that recomputed a state
@@ -230,6 +241,13 @@ def register_server(srv) -> str:
             put("serving", f"moe/expert#{e}/occupancy",
                 pc.CallbackCounter(_read(
                     ref, lambda s, e=e: s._moe_occ[e])))
+        if getattr(srv.cfg, "moe_n_group", 1) > 1:
+            # a group-limited router's share of the work that fell here
+            put("serving", "moe/routed-here",
+                pc.CallbackCounter(_read(ref, lambda s: s._moe_here)))
+            put("serving", "moe/tokens-here",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._moe_tokens_here)))
         put("serving", "moe/experts-hit",
             pc.CallbackCounter(_read(ref, lambda s: (
                 s._moe_hit_sum / s._moe_steps if s._moe_steps else 0.0))))
@@ -255,6 +273,9 @@ def register_server(srv) -> str:
             pc.CallbackCounter(_read(ref, lambda s: s._alloc.in_use)))
         put("cache", "blocks/free",
             pc.CallbackCounter(_read(ref, lambda s: s._alloc.free_count)))
+        put("cache", "blocks/shared",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._alloc.shared_count)))
         put("cache", "blocks/radix-held",
             pc.CallbackCounter(_read(ref, lambda s: s._radix.blocks_held)))
         put("cache", "count/evictions",
@@ -320,6 +341,10 @@ def register_server(srv) -> str:
             # latent rows live on the full group's blocks
             put("cache", "latent/blocks-in-use",
                 pc.CallbackCounter(_read(ref, lambda s: s._alloc.in_use)))
+            # rows a decode step's latent walks read, a latent layer
+            put("cache", "latent/rows-walked",
+                pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
+                                   ["latent_rows_walked_per_step"])))
         if getattr(srv, "_tier", None) is not None:
             # host-RAM demotion tier (cache/tier.py): occupancy,
             # demote/promote/drop/decline totals, cumulative hit

@@ -32,6 +32,7 @@ decides per hit (crossover gate) whether to restore or re-prefill.
 from __future__ import annotations
 
 import hashlib
+import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..svc import tracing
@@ -334,21 +335,32 @@ class RadixCache:
         return parent, _chain(parent, node.key)
 
     def _evict_locked(self, n: int) -> Tuple[int, int]:
+        """ONE walk of the tree collects the idle leaves (the tree
+        holds their only reference) in a heap by last use; a victim
+        whose parent it leaves childless and idle exposes that parent
+        as the next candidate. The order is the one a fresh search of
+        the whole tree a block would give (the clock is unique a
+        touch), at one walk a call and not one a block: with a node a
+        block, a retirement that trims ten blocks off a tree of
+        fifteen thousand walked it ten times."""
         demoted = dropped = 0
-        while demoted + dropped < n:
-            victim: Optional[_Node] = None
-            stack = [self._root]
-            while stack:
-                node = stack.pop()
-                stack.extend(node.children.values())
-                if node is self._root or node.children:
-                    continue
-                if self.allocator.refcount(node.bid) != 1:
-                    continue          # a live request still reads it
-                if victim is None or node.last_used < victim.last_used:
-                    victim = node
-            if victim is None:
-                break
+        if n <= 0:
+            return 0, 0
+        idle = []
+        stack = [self._root]
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            if node is not self._root and not node.children \
+                    and self.allocator.refcount(node.bid) == 1:
+                idle.append((node.last_used, id(node), node))
+        heapq.heapify(idle)
+        while demoted + dropped < n and idle:
+            victim = heapq.heappop(idle)[2]
+            up = victim.parent
+            if up is not self._root and len(up.children) == 1 \
+                    and self.allocator.refcount(up.bid) == 1:
+                heapq.heappush(idle, (up.last_used, id(up), up))
             kept = False
             hook = self.demote_hook
             if hook is not None:

@@ -62,6 +62,15 @@ class MoeConfig:
     # (lo, hi): the share of the experts these parameters hold (empty:
     # all). The router keeps its n_experts columns (moe_ffn_serve).
     held: Tuple[int, ...] = ()
+    # device-limited routing: n_group equal groups of experts, of which
+    # a token keeps its topk_group best (1 / 1: one flat top-k)
+    n_group: int = 1
+    topk_group: int = 1
+
+
+# what a group-limited router's statistics vector carries behind the
+# per-expert occupancy (moe_ffn_serve)
+STATS_HERE = 2
 
 
 def init_moe_params(cfg: MoeConfig, key: jax.Array) -> Dict[str, Any]:
@@ -192,7 +201,8 @@ def moe_ffn(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
         # an all-masked gate row would silently re-route to expert 0
         raise ValueError(f"top_k ({cfg.top_k}) > n_experts ({e})")
     if (cfg.mlp, cfg.router, cfg.renorm, cfg.shared_d_ff, cfg.bias,
-            tuple(cfg.held)) != ("gelu", "softmax", False, 0, False, ()) \
+            tuple(cfg.held), cfg.n_group) != (
+                "gelu", "softmax", False, 0, False, (), 1) \
             or cfg.scale != 1.0:
         raise NotImplementedError(
             "models/moe.moe_ffn (GShard capacity dispatch: training, "
@@ -298,26 +308,42 @@ def moe_ffn_decode(x: jax.Array, params: Dict[str, Any],
     return out, jax.lax.pmean(aux, axis), stats
 
 
-def route(x: jax.Array, wg: jax.Array, cfg: MoeConfig, bias=None):
+def route(x: jax.Array, wg: jax.Array, cfg: MoeConfig, bias=None,
+          groups: bool = False):
     """Scores -> the top_k experts of every token and their weights:
     (idx [T, k] int32, w [T, k] f32). Scores in float32 (`softmax` over
     the experts, or element-wise `sigmoid`); the k largest win, ties to
     the lower expert id; `bias` [E] (a selection bias) is added for the
     CHOICE only, the weights are the chosen experts' plain scores; with
     `renorm` the weights are divided by their sum over the chosen k;
-    then scaled."""
+    then scaled.
+
+    With `n_group` > 1 the choice is GROUP-LIMITED (device-limited
+    routing): experts e * n_group // E share group, a group's score is
+    the largest selection score of its experts, the `topk_group` best
+    groups stay (ties to the lower group), every other group's scores
+    are set to 0 ahead of the top-k. `groups=True` also returns the
+    groups kept, [T, n_group] bool."""
     logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)
     scores = (jax.nn.sigmoid(logits) if cfg.router == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
-    if bias is None:
+    sel = scores if bias is None else scores + bias.astype(jnp.float32)
+    t, e = scores.shape
+    kept = jnp.ones((t, cfg.n_group), bool)
+    if cfg.n_group > 1:
+        per = e // cfg.n_group
+        best = jnp.max(sel.reshape(t, cfg.n_group, per), axis=-1)
+        _, gi = jax.lax.top_k(best, cfg.topk_group)
+        kept = jnp.any(gi[..., None] == jnp.arange(cfg.n_group), axis=1)
+        sel = jnp.where(jnp.repeat(kept, per, axis=1), sel, 0.0)
+    if bias is None and cfg.n_group == 1:
         w, idx = jax.lax.top_k(scores, cfg.top_k)
     else:
-        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
-                               cfg.top_k)
+        _, idx = jax.lax.top_k(sel, cfg.top_k)
         w = jnp.take_along_axis(scores, idx, axis=-1)
     if cfg.renorm:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return idx, w * cfg.scale
+    return (idx, w * cfg.scale, kept) if groups else (idx, w * cfg.scale)
 
 
 def _experts_xla(xa, sizes, params, cfg, dt):
@@ -366,13 +392,17 @@ def moe_ffn_serve(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
     stats: [claims routed (T * k), claims dropped (0 by construction),
     per-expert occupancy]. With no capacity an expert's occupancy
     reads 1.0 where at least one token chose it and 0.0 where none
-    did, so the vector's tail sums to the distinct experts hit."""
+    did, so the vector's tail sums to the distinct experts hit. A
+    group-limited router (`cfg.n_group` > 1) appends STATS_HERE more
+    numbers: the assignments that fell to the held experts, and the
+    tokens whose kept groups include a held one."""
     from .quant import dequant
     t, d = x.shape
     e, k, dt = cfg.n_experts, cfg.top_k, cfg.dtype
     if k > e:
         raise ValueError(f"top_k ({k}) > n_experts ({e})")
-    idx, w = route(x, params["wg"], cfg, params.get("bias"))
+    idx, w, kept = route(x, params["wg"], cfg, params.get("bias"),
+                         groups=True)
     a = t * k
     flat = idx.reshape(a)
     mine = None
@@ -429,7 +459,14 @@ def moe_ffn_serve(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
         hs = (jax.nn.silu(xd @ dequant(sp["w1"], dt))
               * (xd @ dequant(sp["w3"], dt))) @ dequant(sp["w2"], dt)
         out = out + hs.astype(jnp.float32)
-    stats = jnp.concatenate(
-        [jnp.asarray([a, 0.0], jnp.float32),
-         (sizes > 0).astype(jnp.float32)])
-    return out.astype(x.dtype), stats
+    stats = [jnp.asarray([a, 0.0], jnp.float32),
+             (sizes > 0).astype(jnp.float32)]
+    if cfg.n_group > 1:
+        lo, hi = held or (0, cfg.n_experts)
+        per = cfg.n_experts // cfg.n_group
+        stats.append(jnp.stack([
+            jnp.float32(a) if mine is None
+            else jnp.sum(mine, dtype=jnp.float32),
+            jnp.sum(jnp.any(kept[:, lo // per:(hi - 1) // per + 1], axis=1),
+                    dtype=jnp.float32)]))
+    return out.astype(x.dtype), jnp.concatenate(stats)

@@ -307,16 +307,20 @@ def _moe_rows(h, lp, cfg, moe_cf=None, moe_ep=None, moe_sink=None,
     return out.reshape(b, w, d)
 
 
-def _moe_fold(sink):
+def _moe_fold(sink, cfg=None):
     """Fold the per-layer MoE stats vectors into ONE [2 + E] f32
     program output: routed / dropped-over-capacity claims SUM over
-    layers, per-expert occupancy fractions AVERAGE over layers.
+    layers, per-expert occupancy fractions AVERAGE over layers; what a
+    group-limited router appends (`moe.STATS_HERE` counts) sums too.
     Returns None (an empty pytree — legal jit/shard_map output) for
     dense models, so every driver can return it unconditionally."""
     if not sink:
         return None
+    from .moe import STATS_HERE
     s = jnp.sum(jnp.stack(sink), axis=0)
-    return jnp.concatenate([s[:2], s[2:] / len(sink)])
+    end = s.shape[0] - (STATS_HERE if cfg is not None
+                        and cfg.moe_n_group > 1 else 0)
+    return jnp.concatenate([s[:2], s[2:end] / len(sink), s[end:]])
 
 
 def _dp_rows(x, dp):
@@ -472,8 +476,7 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
                     "attends one row a slot)")
             o, pool = paged_latent_attention(
                 q[:, 0], row[:, 0], pools[0], table, pos0,
-                rank=cfg.mla_rank, fused=fused,
-                scale=(cfg.mla_nope_dim + cfg.mla_rope_dim) ** -0.5)
+                rank=cfg.mla_rank, fused=fused, scale=cfg.mla_scale)
             return o[:, None], ((pool,), None)
 
     x, (pools, scales) = _layer(
@@ -505,7 +508,8 @@ def _paged_decode_window_rows(params, pools, scales, toks, tables, pos0,
         new_pools.append(pl)
         new_scales.append(sc)
     return (new_pools, None if scales is None else new_scales,
-            _logits(params, x, cfg).astype(jnp.float32), _moe_fold(sink))
+            _logits(params, x, cfg).astype(jnp.float32),
+            _moe_fold(sink, cfg))
 
 
 def _paged_decode_rows(params, pools, scales, tok, tables, pos, cfg,
@@ -794,6 +798,10 @@ class ContinuousServer:
         self._moe_occ = [0.0] * max(0, cfg.experts_held)
         self._moe_hit_sum = 0.0     # sum over drained steps of the
         self._moe_steps = 0         # occupancy vector's total
+        # a group-limited router's own: assignments that fell to the
+        # held experts, and tokens whose kept groups include a held one
+        # (summed over the sparse layers and the drained steps)
+        self._moe_here = self._moe_tokens_here = 0.0
         self._moe_buf: deque = deque()
 
         if prefill_chunk is None:
@@ -1994,6 +2002,8 @@ class ContinuousServer:
         st.update(self._radix.stats())
         if self._tier is not None:
             st.update(self._tier.stats())
+        # prompt tokens the tree served / the chunks computed; blocks
+        # more than one holder shares are the allocator's `shared`
         st["prefill_tokens_saved"] = self._prefill_saved
         st["prefill_tokens_computed"] = self._prefill_computed
         if self._win:
@@ -2013,6 +2023,11 @@ class ContinuousServer:
             st["state_reprefills"] = self._reprefills
         if "mla" in self._kinds:
             st["latent_blocks_in_use"] = self._alloc.in_use
+            # what a step's latent walks read: live rows of the live
+            # slots, a latent layer each (the kernel stops there)
+            st["latent_rows_walked_per_step"] = sum(
+                self._pos[s_] + 1 for s_ in range(self.slots)
+                if self._slot_req[s_] is not None)
         st.update(self.hbm_read_stats())
         if self.mesh is not None:
             # per-dp-shard slot accounting: slots map to dp shards by
@@ -2124,10 +2139,18 @@ class ContinuousServer:
         statistics a flush has drained: claims routed and dropped, the
         steps, and `experts_hit_sum` (per step the distinct experts
         hit, mean over the sparse layers) — the feed of the
-        /serving{...}/moe/* counters."""
-        return {"routed": self._moe_routed, "dropped": self._moe_dropped,
-                "steps": self._moe_steps,
-                "experts_hit_sum": self._moe_hit_sum}
+        /serving{...}/moe/* counters. Under a group-limited router
+        also `routed_here` (the assignments that fell to the held
+        experts) and `tokens_here` (the tokens, a sparse layer each,
+        whose kept groups include a held one): `routed` counts every
+        assignment, `routed / top_k` every token."""
+        st = {"routed": self._moe_routed, "dropped": self._moe_dropped,
+              "steps": self._moe_steps,
+              "experts_hit_sum": self._moe_hit_sum}
+        if self.cfg.moe_n_group > 1:
+            st["routed_here"] = self._moe_here
+            st["tokens_here"] = self._moe_tokens_here
+        return st
 
     def read_stats(self) -> Dict[str, int]:
         """The blocking device->host reads so far (seed tokens, token
@@ -2382,12 +2405,17 @@ class ContinuousServer:
             self._prefix_refused += 1
         elif self._prefix_reuse:
             # always leave >= 1 suffix token: admission needs the LAST
-            # prompt token's logits to seed generation
-            if self._tier is not None:
-                matched, mbids, tier_ext = self._radix.match_tiered(
-                    req.prompt[:-1], self._tier)
-            else:
-                matched, mbids = self._radix.match(req.prompt[:-1])
+            # prompt token's logits to seed generation. A model whose
+            # every layer caches rows addressed by position (K/V pairs
+            # or latent rows, each a function of its own token and
+            # position alone) takes the match.
+            with tracing.span("serving.prefix_match", "serving",
+                              rid=req.rid, plen=plen):
+                if self._tier is not None:
+                    matched, mbids, tier_ext = self._radix.match_tiered(
+                        req.prompt[:-1], self._tier)
+                else:
+                    matched, mbids = self._radix.match(req.prompt[:-1])
         if tier_ext:
             # crossover-gated restore: a promoted chain extends the
             # hot match (mbids grows, matched covers the restored
@@ -2428,8 +2456,12 @@ class ContinuousServer:
                                    done=0, seq=self._pf_seq, pt=pt,
                                    trow=trow, wrow=wrow, hold=1)
         if not self._win:
-            caches = self._paged_gather_prog()(
-                self._pools, self._scales, trow, jnp.int32(matched))
+            # the matched rows, out of the shared blocks into the
+            # request's scratch (the pools' kind: K/V or latent rows)
+            with tracing.span("serving.prefix_gather", "serving",
+                              rid=req.rid, matched=matched, plen=plen):
+                caches = self._paged_gather_prog()(
+                    self._pools, self._scales, trow, jnp.int32(matched))
             return _PendingPrefill(req=req, slot=slot, caches=caches,
                                    done=matched, seq=self._pf_seq, pt=pt,
                                    trow=trow, wrow=wrow)
@@ -3327,11 +3359,15 @@ class ContinuousServer:
                     ms = np.asarray(ms)
                 self._moe_routed += float(ms[0])
                 self._moe_dropped += float(ms[1])
-                self._moe_occ = [float(v) for v in ms[2:]]
+                occ = ms[2:2 + len(self._moe_occ)]
+                self._moe_occ = [float(v) for v in occ]
                 # drop-free steps report 1.0 for an expert that was
                 # hit (moe_ffn_serve), averaged over the sparse layers
-                self._moe_hit_sum += float(ms[2:].sum())
+                self._moe_hit_sum += float(occ.sum())
                 self._moe_steps += 1
+                if self.cfg.moe_n_group > 1:
+                    self._moe_here += float(ms[-2])
+                    self._moe_tokens_here += float(ms[-1])
             self._ckpt_sweep()
             self._reload_knobs()
             # SLO burn evaluation shares this boundary: the host's

@@ -156,8 +156,8 @@ class TransformerConfig:
     # the MIXER of layer i: "attn" (softmax attention over K/V pairs,
     # what the fields above describe), "kda" (a gated delta rule over a
     # per-slot recurrent state, ops/kda.py) or "mla" (latent attention:
-    # one cached row of mla_rank + mla_rope_dim values a token, no
-    # rotation). Empty = every layer "attn".
+    # one cached row of mla_rank + mla_rope_dim values a token).
+    # Empty = every layer "attn".
     layer_mixer: Tuple[str, ...] = ()
     kda_heads: int = 0              # heads of kda_head_dim x kda_head_dim
     kda_head_dim: int = 0
@@ -167,6 +167,18 @@ class TransformerConfig:
     mla_nope_dim: int = 0           # a head's q/k dims against the latent
     mla_rope_dim: int = 0           # a head's dims against the shared r
     mla_v_dim: int = 0              # a head's value dims
+    # the query's low rank (q_lora_rank: W_uq RMSNorm(W_dq h)); 0 = one
+    # full W_q. The mla_rope_dim dims of q and of the cached row are
+    # rotated by the layer's RopeSpec (`layer_rope`; None = NoPE), and
+    # the softmax scale is (nope + rope dims)^-1/2 * mla_mscale^2 (the
+    # YaRN mscale a model trained under a stretched rotation carries).
+    mla_q_rank: int = 0
+    mla_mscale: float = 1.0
+    # device-limited routing: the experts lie in moe_n_group equal
+    # groups, a token keeps its moe_topk_group best groups (a group's
+    # score: its largest expert score) and picks its top_k inside them
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
 
     @property
     def kv_heads(self) -> int:
@@ -205,6 +217,12 @@ class TransformerConfig:
         return -(-(self.mla_rank + self.mla_rope_dim) // 128) * 128
 
     @property
+    def mla_scale(self) -> float:
+        """The softmax scale of a latent-attention layer's scores."""
+        return (self.mla_nope_dim + self.mla_rope_dim) ** -0.5 \
+            * self.mla_mscale ** 2
+
+    @property
     def experts_held(self) -> int:
         return (self.moe_held[1] - self.moe_held[0] if self.moe_held
                 else self.n_experts)
@@ -227,7 +245,8 @@ class TransformerConfig:
         for f in ("norm", "mlp", "tied", "attn_gate", "layer_heads",
                   "layer_window", "layer_rope", "layer_sparse",
                   "moe_shared_d_ff", "moe_router", "moe_renorm",
-                  "moe_bias", "moe_held", "layer_mixer"):
+                  "moe_bias", "moe_held", "layer_mixer", "moe_n_group",
+                  "moe_topk_group"):
             if f not in allowed and getattr(self, f) != getattr(
                     TransformerConfig, f):
                 raise NotImplementedError(
@@ -266,7 +285,9 @@ def _moe_cfg(cfg: TransformerConfig):
                      router=cfg.moe_router, renorm=cfg.moe_renorm,
                      scale=cfg.moe_scale,
                      shared_d_ff=cfg.moe_shared_d_ff,
-                     bias=cfg.moe_bias, held=cfg.moe_held)
+                     bias=cfg.moe_bias, held=cfg.moe_held,
+                     n_group=cfg.moe_n_group,
+                     topk_group=cfg.moe_topk_group)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
@@ -302,9 +323,13 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
                 "onorm": jnp.ones((hd,), cfg.dtype),
                 "wo": nrm(ks[9], (h, hd, d), 1.0 / math.sqrt(h * hd))}}
         h, r = cfg.n_heads, cfg.mla_rank
+        dq, qr = cfg.mla_nope_dim + cfg.mla_rope_dim, cfg.mla_q_rank
+        wq = {"wq": nrm(ks[0], (d, h, dq), s)} if not qr else {
+            "wdq": nrm(ks[0], (d, qr), s),
+            "qnorm": jnp.ones((qr,), cfg.dtype),
+            "wuq": nrm(ks[5], (qr, h, dq), 1.0 / math.sqrt(qr))}
         return {"mla": {
-            "wq": nrm(ks[0], (d, h, cfg.mla_nope_dim + cfg.mla_rope_dim),
-                      s),
+            **wq,
             "wdkv": nrm(ks[1], (d, r + cfg.mla_rope_dim), s),
             "kvnorm": jnp.ones((r,), cfg.dtype),
             "wuk": nrm(ks[2], (r, h, cfg.mla_nope_dim),
@@ -506,7 +531,8 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
     if "kda" in lp:
         o, carry = _kda_mixer(h, lp["kda"], cfg, attend)
     elif "mla" in lp:
-        o, carry = _mla_mixer(h, lp["mla"], cfg, attend)
+        o, carry = _mla_mixer(h, lp["mla"], cfg, attend, pos,
+                              cfg.rope_of(li))
     else:
         q, k, v = _qkv_proj(h, lp)
         rope = cfg.rope_of(li)
@@ -560,31 +586,51 @@ def _kda_mixer(h, m, cfg: TransformerConfig, attend):
     return jnp.einsum("bshk,hkd->bsd", o, _dq(m["wo"], o)), carry
 
 
-def _mla_mixer(h, m, cfg: TransformerConfig, attend):
-    """A latent-attention (MLA) mixer in the ABSORBED form, NoPE: no
-    rotation, the mla_rope_dim dims are plain. h [B, W, D] -> (y, carry).
-    The cached row of a token is [RMSNorm(W_dkv h); W_kr h; zero pad]
-    (`cfg.mla_row` wide). A head's query against it is [W_uk^T q^C;
-    q^R; 0], its score q . row / sqrt(nope + rope dims), its value the
-    row's first mla_rank columns: `attend(q, row)` writes the rows and
-    returns sum_j p_j c_j [B, W, H, rank], which W_uv takes to the
-    head's value dims ahead of W_o. One form for a decode step and a
-    prefill chunk."""
+def _mla_mixer(h, m, cfg: TransformerConfig, attend, pos=None,
+               rope: Optional[RopeSpec] = None):
+    """A latent-attention (MLA) mixer in the ABSORBED form. h [B, W, D]
+    -> (y, carry). The query is W_q h, or W_uq RMSNorm(W_dq h) where
+    the parameters hold a low-rank pair ("wdq"). The cached row of a
+    token is [RMSNorm(W_dkv h); k^R; zero pad] (`cfg.mla_row` wide),
+    k^R the mla_rope_dim dims ONE rotary key shares between the heads:
+    rotated by `rope` at the token's position `pos` BEFORE the row is
+    written (a cached row never depends on who reads it), as the
+    query's q^R dims are; `rope` None leaves both plain (NoPE). A
+    head's query against the row is [W_uk^T q^C; q^R; 0], its score
+    q . row * `cfg.mla_scale`, its value the row's first mla_rank
+    columns: `attend(q, row)` writes the rows and returns sum_j p_j c_j
+    [B, W, H, rank], which W_uv takes to the head's value dims ahead of
+    W_o. One form for a decode step and a prefill chunk."""
     r, dn = cfg.mla_rank, cfg.mla_nope_dim
+
+    def rms(x, scale):
+        xf = x.astype(jnp.float32)
+        return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                                   + cfg.norm_eps)).astype(h.dtype) * scale
     ckr = h @ _dq(m["wdkv"], h)                             # [B, W, r+dr]
-    cf = ckr[..., :r].astype(jnp.float32)
-    c = (cf * jax.lax.rsqrt(jnp.mean(cf * cf, -1, keepdims=True)
-                            + cfg.norm_eps)).astype(h.dtype) * m["kvnorm"]
-    q = jnp.einsum("bsd,dhk->bshk", h, _dq(m["wq"], h))
+    c = rms(ckr[..., :r], m["kvnorm"])
+    if "wdq" in m:
+        q = jnp.einsum("bsr,rhk->bshk",
+                       rms(h @ _dq(m["wdq"], h), m["qnorm"]),
+                       _dq(m["wuq"], h))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", h, _dq(m["wq"], h))
+    qr, kr = q[..., dn:], ckr[..., r:]
+    if rope is not None:
+        qr = _rope(qr, pos, rope)
+        kr = _rope(kr[:, :, None, :], pos, rope)[:, :, 0]
     qa = jnp.einsum("bshn,rhn->bshr", q[..., :dn], _dq(m["wuk"], h))
     pad = cfg.mla_row - r - cfg.mla_rope_dim
     row = jnp.concatenate(
-        [c, ckr[..., r:], jnp.zeros(c.shape[:-1] + (pad,), h.dtype)], -1)
+        [c, kr, jnp.zeros(c.shape[:-1] + (pad,), h.dtype)], -1)
     qf = jnp.concatenate(
-        [qa, q[..., dn:], jnp.zeros(qa.shape[:-1] + (pad,), h.dtype)], -1)
+        [qa, qr, jnp.zeros(qa.shape[:-1] + (pad,), h.dtype)], -1)
     ol, carry = attend(qf, row)
     o = jnp.einsum("bshr,rhv->bshv", ol, _dq(m["wuv"], ol))
     return jnp.einsum("bshv,hvd->bsd", o, _dq(m["wo"], o)), carry
+
+
+LATENT_ROWS_A_BLOCK = 512
 
 
 def _latent_attention(q, lat, qpos, rank: int, scale: float):
@@ -592,14 +638,47 @@ def _latent_attention(q, lat, qpos, rank: int, scale: float):
     of latent rows lat [B, S, R] (this window's rows already written):
     the query at qpos[i] sees rows <= it. Scores and softmax float32,
     the value a row's first `rank` columns (`ops/paged_attention.
-    paged_latent_attention` is the same over a paged pool)."""
-    s = jnp.einsum("bqhr,bkr->bhqk", q, lat,
-                   preferred_element_type=jnp.float32) * scale
-    live = jnp.arange(lat.shape[1]) <= qpos[..., None]      # [Q, S]
-    p = jax.nn.softmax(jnp.where(live[None, None], s, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkr->bqhr", p.astype(q.dtype),
-                      lat[..., :rank],
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    paged_latent_attention` is the same over a paged pool).
+
+    Walked in blocks of LATENT_ROWS_A_BLOCK rows under an online
+    softmax (running max, sum and a float32 accumulator [B, H, Q,
+    rank]) and BOUNDED by the last query's position: no [H, Q, S]
+    array exists and no row past the window is scored, so a chunk's
+    cost follows the rows it can see, not the scratch's length."""
+    b, nq, h, _ = q.shape
+    s_len = lat.shape[1]
+    blk = min(LATENT_ROWS_A_BLOCK, s_len)
+    qp = jnp.broadcast_to(qpos, (b, nq)) if qpos.ndim == 1 else qpos
+    n_blk = jnp.minimum(jnp.max(qp) // blk + 1, -(-s_len // blk))
+
+    def body(j, carry):
+        m, l, acc = carry
+        # the last block of a scratch that is no whole number of them
+        # starts early; its rows below j * blk were the last block's
+        start = jnp.minimum(j * blk, s_len - blk)
+        rows = jax.lax.dynamic_slice_in_dim(lat, start, blk, axis=1)
+        kpos = start + jnp.arange(blk)
+        live = jnp.logical_and(kpos[None, None, :] <= qp[..., None],
+                               kpos >= j * blk)             # [B, Q, blk]
+        s = jnp.einsum("bqhr,bkr->bhqk", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live[:, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bhqk,bkr->bhqr", p.astype(q.dtype), rows[..., :rank],
+            preferred_element_type=jnp.float32)
+        return m_new, l * fade + jnp.sum(p, axis=-1), acc
+
+    # row 0 is live for every query, so the running max is finite from
+    # the first block on
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blk, body,
+        (jnp.full((b, h, nq), -jnp.inf, jnp.float32),
+         jnp.zeros((b, h, nq), jnp.float32),
+         jnp.zeros((b, h, nq, rank), jnp.float32)))
+    return jnp.transpose(acc / l[..., None], (0, 2, 1, 3)).astype(q.dtype)
 
 
 def _cached_attention(q, kc, vc, qpos, window: int = 0):
@@ -1155,9 +1234,8 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
         def attend(q, row):                                 # noqa: F811
             lat = jax.lax.dynamic_update_slice_in_dim(
                 kv[0], row[:, :, None, :], write_at, axis=1)
-            scale = (cfg.mla_nope_dim + cfg.mla_rope_dim) ** -0.5
             return _latent_attention(q, lat[:, :, 0], qpos, cfg.mla_rank,
-                                     scale), (lat,)
+                                     cfg.mla_scale), (lat,)
 
     def moe(h):
         # serving routes DROP-FREE: with no drops, each token's output

@@ -1381,52 +1381,115 @@ def _live_walk_call(qk, k_pool, v_pool, table, pos0, *, w: int,
       v_pool)[0]
 
 
-def _latent_kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, bank, sem,
-                   *, block_size: int, nblk: int, rank: int,
-                   scale: float):
+# table entries one buffer of the latent walk holds: 32 blocks of 16
+# rows = 512 rows a fold (a 640-wide bfloat16 buffer is 655 KB)
+LATENT_WALK_ENTRIES = 32
+
+
+def _latent_kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, bank,
+                   acc_s, m_s, l_s, sem, *, block_size: int, nblk: int,
+                   chunk: int, rank: int, scale: float):
     """One slot's absorbed latent attention (MLA decode): every query
     head over ONE cached head of latent rows whose value is its own
     first `rank` columns. q_ref (H, R), o_ref (H, rank); pool_hbm: the
-    latent pool [num_blocks, 1, block_size, R], left in HBM. The walk
-    is `_paged_live_kernel`'s: the first `_walk_entries` table entries
-    are copied into `bank` (entry j to rows j*bs ..), all in flight on
-    one DMA semaphore, and NOTHING IS READ FROM THE BANK BEFORE EVERY
-    WAIT HAS RETURNED; rows past the walk are masked out of the scores
-    and selected to zero ahead of the value product."""
+    latent pool [num_blocks, 1, block_size, R], left in HBM.
+
+    The slot's live table entries (`_walk_entries`: none past `pos`)
+    are walked `chunk` at a time through TWO buffers, `bank` (2, chunk
+    * block_size, R): while one buffer's rows are scored and folded
+    into the running softmax (`acc_s` (H, rank), `m_s`, `l_s`, float32:
+    the flash carry), the next `chunk` entries land in the other. VMEM
+    holds two buffers and the carry whatever the table's width, and no
+    row past the last live entry is copied, scored or weighed. A
+    buffer's copies signal that buffer's OWN semaphore, and a buffer is
+    read only after every wait of its copies has returned (a DMA
+    semaphore counts bytes landed from any copy that signals it). Only
+    the LAST fold has rows past `pos` (the tail of the last live block,
+    and what the buffer held before): there the scores are masked and
+    the value rows selected to zero, because 0 x NaN is NaN."""
     b = pl.program_id(0)
     pos = pos_ref[b]
     n_live = _walk_entries(pos, 1, block_size, nblk)
+    n_fold = (n_live + chunk - 1) // chunk
+    tail = n_live - (n_fold - 1) * chunk        # the last fold's entries
+    rows = chunk * block_size
 
-    def copy(j):
-        rows = pl.ds(pl.multiple_of(j * block_size, block_size),
-                     block_size)
-        return pltpu.make_async_copy(pool_hbm.at[table_ref[b, j], 0],
-                                     bank.at[rows, :], sem.at[0])
+    def copy(c, buf, j):
+        at = pl.ds(pl.multiple_of(j * block_size, block_size), block_size)
+        return pltpu.make_async_copy(
+            pool_hbm.at[table_ref[b, c * chunk + j], 0],
+            bank.at[buf, at, :], sem.at[buf])
 
-    def start(j, carry):
-        copy(j).start()
-        return carry
+    def each(c, buf, count, what):
+        """`what` of fold c's first `count` copies: straight-line code
+        where the count is static (a whole fold: on the chip 20% under
+        the same descriptors issued from a loop, PERF.md PR 37), a loop
+        where it is the slot's own (the last fold's)."""
+        if isinstance(count, int):
+            for j in range(count):
+                what(copy(c, buf, j))
+            return
 
-    def wait(j, carry):
-        copy(j).wait()
-        return carry
+        def body(j, carry):
+            what(copy(c, buf, j))
+            return carry
+        jax.lax.fori_loop(0, count, body, 0)
 
-    jax.lax.fori_loop(0, n_live, start, 0)
-    jax.lax.fori_loop(0, n_live, wait, 0)
+    def start(cp):
+        cp.start()
 
+    def wait(cp):
+        cp.wait()
+
+    acc_s[...] = jnp.zeros_like(acc_s)
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
     q = q_ref[...]
-    lat = bank[...]
-    sf = jax.lax.dot_general(
-        q, lat.astype(q.dtype), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    kpos = jax.lax.broadcasted_iota(jnp.int32, sf.shape, 1)
-    sf = jnp.where(kpos <= pos, sf, -jnp.inf)
-    p = jax.nn.softmax(sf, axis=-1)
-    vrow = jax.lax.broadcasted_iota(jnp.int32, (lat.shape[0], rank), 0)
-    v = jnp.where(vrow < n_live * block_size, lat[:, :rank], 0)
-    o_ref[...] = jax.lax.dot_general(
-        p.astype(q.dtype), v.astype(q.dtype), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    each(0, 0, jnp.minimum(chunk, n_live), start)
+
+    def fold(c, nxt, count, last: bool):
+        """Fold c: start the `nxt` entries of fold c + 1 into the other
+        buffer, wait for this fold's `count`, score and fold them in."""
+        buf = c % 2
+        if nxt is not None:
+            each(c + 1, 1 - buf, nxt, start)
+        each(c, buf, count, wait)
+        lat = bank[buf]
+        s = jax.lax.dot_general(
+            q, lat.astype(q.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # (H, rows)
+        v = lat[:, :rank]
+        if last:
+            kpos = c * rows + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(kpos <= pos, s, _NEG_INF)
+            vrow = c * rows + jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0)
+            v = jnp.where(vrow <= pos, v, 0)
+        m_prev = m_s[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)              # a masked score: exactly 0
+        fade = jnp.exp(m_prev - m_new)
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[...] = l_s[...] * fade + jnp.broadcast_to(
+            p.sum(axis=1, keepdims=True), l_s.shape)
+        acc_s[...] = acc_s[...] * fade + jax.lax.dot_general(
+            p.astype(q.dtype), v.astype(q.dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def whole(c, carry):            # a whole fold ahead of a whole fold
+        fold(c, chunk, chunk, False)
+        return carry
+
+    jax.lax.fori_loop(0, n_fold - 2, whole, 0)
+
+    @pl.when(n_fold >= 2)           # the whole fold ahead of the last
+    def _():
+        fold(n_fold - 2, tail, chunk, False)
+
+    fold(n_fold - 1, None, tail, True)
+    o_ref[...] = (acc_s[...] / l_s[:, :1]).astype(o_ref.dtype)
 
 
 def fused_latent_attention(q: jax.Array, pool: jax.Array,
@@ -1434,11 +1497,12 @@ def fused_latent_attention(q: jax.Array, pool: jax.Array,
                            rank: int, scale: float,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Absorbed MLA decode attention that walks the block table
-    in-kernel (`hpx_mla_paged`), bounded by each slot's live length.
+    in-kernel (`hpx_mla_paged`), in blocks of rows under an online
+    softmax, bounded by each slot's live length.
 
-    q: [B, H, R] absorbed queries (W_uk^T q^C, then the plain q^R dims,
-    then zeros up to the pool's row width R); pool: [num_blocks, 1,
-    block_size, R] latent rows (c, r, zero pad) with this step's row
+    q: [B, H, R] absorbed queries (W_uk^T q^C, then the q^R dims, then
+    zeros up to the pool's row width R); pool: [num_blocks, 1,
+    block_size, R] latent rows (c, k^R, zero pad) with this step's row
     ALREADY written; table: [B, max_blocks] int32; pos: [B] int32, the
     slot attends rows <= pos. Returns sum_j p_j c_j, [B, H, rank]: the
     scores are q . row * scale in float32 (the pad columns are zero on
@@ -1448,20 +1512,23 @@ def fused_latent_attention(q: jax.Array, pool: jax.Array,
     ONE pool of rows R = rank + rope dims rounded up to whole 128-lane
     rows, not a 512-wide and a 64-wide pool on one table: the chip pads
     a minor dim of 64 to 128 lanes in HBM anyway, so the split saves
-    nothing, and one pool is one DMA a block, one bank and one matmul
-    for the scores. Needs R % 128 == 0 and rank % 128 == 0 (the value
-    slice is then whole lanes); `ops/paged_attention.
+    nothing, and one pool is one DMA a block, one buffer and one matmul
+    for the scores (whose contraction the 128-wide unit would pad from
+    576 to 640 itself). Needs R % 128 == 0 and rank % 128 == 0 (the
+    value slice is then whole lanes); `ops/paged_attention.
     paged_latent_attention` decides and keeps the gather form for every
-    other width. Grid (slot,), parallel."""
+    other width. Grid (slot,), parallel. The VMEM need is two buffers
+    of LATENT_WALK_ENTRIES blocks, the carry and a fold's scores: the
+    same at every table width (`latent_vmem_bytes`)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, r = q.shape
     bs = pool.shape[2]
     maxb = table.shape[1]
-    bank = maxb * bs * r * jnp.dtype(pool.dtype).itemsize
+    chunk = min(LATENT_WALK_ENTRIES, maxb)
     return pl.pallas_call(
         functools.partial(_latent_kernel, block_size=bs, nblk=maxb,
-                          rank=rank, scale=scale),
+                          chunk=chunk, rank=rank, scale=scale),
         name="hpx_mla_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -1470,16 +1537,33 @@ def fused_latent_attention(q: jax.Array, pool: jax.Array,
                       pl.BlockSpec(memory_space=pltpu.HBM)],
             out_specs=[pl.BlockSpec((None, h, rank),
                                     lambda bb, *_: (bb, 0, 0))],
-            scratch_shapes=[pltpu.VMEM((maxb * bs, r), pool.dtype),
-                            pltpu.SemaphoreType.DMA((1,))],
+            scratch_shapes=[pltpu.VMEM((2, chunk * bs, r), pool.dtype),
+                            pltpu.VMEM((h, rank), jnp.float32),
+                            pltpu.VMEM((h, 128), jnp.float32),
+                            pltpu.VMEM((h, 128), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=[_sds((b, h, rank), q.dtype, q, pool)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            # the bank, its value as loaded, the selected value rows
-            vmem_limit_bytes=int(max(4 * bank + (8 << 20), 32 << 20))),
+            vmem_limit_bytes=latent_vmem_bytes(
+                h, r, rank, chunk * bs, jnp.dtype(pool.dtype).itemsize)),
         interpret=interpret,
     )(table.astype(jnp.int32), pos.astype(jnp.int32), q, pool)[0]
+
+
+def latent_vmem_bytes(h: int, r: int, rank: int, rows: int,
+                      itemsize: int) -> int:
+    """The scoped VMEM `hpx_mla_paged` asks for: the two buffers, a
+    buffer's rows as loaded and as selected value rows, q and the
+    output doubled by the pipeline, the float32 carry, a fold's scores
+    and probabilities, and 4 MB for the compiler's own. No term holds
+    the table's width."""
+    buffers = (2 + 2) * rows * r * itemsize
+    carry = h * (rank + 256) * 4
+    scores = 3 * h * rows * 4
+    io = 2 * h * (r + rank) * itemsize
+    return int(max(buffers + carry + scores + io + (4 << 20), 16 << 20))
 
 
 def _fused_paged_call(q, k_pool, v_pool, table, pos0, k_scale, v_scale,

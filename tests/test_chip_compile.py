@@ -435,23 +435,38 @@ def test_the_states_update_runs_in_place(sds):
         b * h * d * d * 4
 
 
-def test_the_latent_rows_write_leaves_the_pool_where_it_lies(sds):
+# (slots, query heads, table width): Kimi-Linear's cell, and
+# DeepSeek-V2's (128 heads over a table of 1,576 entries = 25,216 rows)
+_LATENT_SHAPES = {"kimi": (_K_SLOTS, _K_HEADS, _K_MAXB, 12673),
+                  "deepseek-v2": (64, 128, 1576, 20000)}
+
+
+@pytest.mark.parametrize("cell", sorted(_LATENT_SHAPES))
+def test_the_latent_rows_write_leaves_the_pool_where_it_lies(sds, cell):
     """The latent row's write under the pool layout rule (block, the
     one head and row indexed together) and `hpx_mla_paged` over the
-    cell's pool: no pool-shaped copy around either."""
-    b, nb = _K_SLOTS, _K_SLOTS * _K_MAXB + 1
+    cell's pool: no pool-shaped copy around either, and the kernel's
+    VMEM does not hold the table's width (two buffers and a carry:
+    the same bytes under 128 heads at 25,216 rows as a bank of 4,224
+    rows alone took)."""
+    b, heads, maxb, nb = _LATENT_SHAPES[cell]
     pool = sds((nb, 1, _C_BS, _K_ROW), jnp.bfloat16)
     text = jax.jit(
         lambda q, row, p, table, pos: pa.paged_latent_attention(
             q, row, p, table, pos, rank=_K_RANK, scale=192 ** -0.5,
             fused=True, interpret=False),
         donate_argnums=(2,)).lower(
-        sds((b, _K_HEADS, _K_ROW), jnp.bfloat16),
+        sds((b, heads, _K_ROW), jnp.bfloat16),
         sds((b, _K_ROW), jnp.bfloat16), pool,
-        sds((b, _K_MAXB), jnp.int32), sds((b,), jnp.int32)
+        sds((b, maxb), jnp.int32), sds((b,), jnp.int32)
     ).compile().as_text()
     assert "hpx_mla_paged" in text
     assert _pool_ops(text, pool) == []
+    need = ap.latent_vmem_bytes(
+        heads, _K_ROW, _K_RANK, ap.LATENT_WALK_ENTRIES * _C_BS, 2)
+    assert need <= 16 << 20 and need == ap.latent_vmem_bytes(
+        heads, _K_ROW, _K_RANK, min(ap.LATENT_WALK_ENTRIES, 4 * maxb)
+        * _C_BS, 2)
 
 
 def test_hybrid_server_step_program_copies_neither_state_nor_pool(
