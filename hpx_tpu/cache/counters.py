@@ -15,6 +15,12 @@ Every server gets the serving counters::
                                                     (windowed RateCounter)
     /serving{locality#L/server#i}/prefill/chunks    prefill chunk dispatches
     /serving{locality#L/server#i}/prefill/pending   in-flight chunked prefills
+    /serving{locality#L/server#i}/prefill/chunk-width    rows of a full chunk
+    /serving{locality#L/server#i}/prefill/chunk-derived  1: the width follows
+                                the device's ridge; 0: an argument or the
+                                config key stated it
+    /serving{locality#L/server#i}/prefill/rows-per-chunk prompt tokens a
+                                chunk dispatch carried (mean so far)
     /serving{locality#L/server#i}/programs/cache-hits    program-cache hits
     /serving{locality#L/server#i}/programs/cache-misses  program builds (compiles)
     /serving{locality#L/server#i}/reads/overlapped  blocking device->host reads
@@ -170,6 +176,14 @@ def register_server(srv) -> str:
         pc.CallbackCounter(_read(ref, lambda s: s._chunks)))
     put("serving", "prefill/pending",
         pc.CallbackCounter(_read(ref, lambda s: len(s._pending))))
+    put("serving", "prefill/chunk-width",
+        pc.CallbackCounter(_read(ref, lambda s: s.prefill_chunk)))
+    put("serving", "prefill/chunk-derived",
+        pc.CallbackCounter(_read(
+            ref, lambda s: s._prefill_chunk_src == "ridge")))
+    put("serving", "prefill/rows-per-chunk",
+        pc.CallbackCounter(_read(
+            ref, lambda s: s.prefill_stats()["prefill_rows_per_chunk"])))
     put("serving", "programs/cache-hits",
         pc.CallbackCounter(_read(ref, lambda s: s._prog_hits)))
     put("serving", "programs/cache-misses",

@@ -239,8 +239,10 @@ declare("hpx.serving.paged_kernel", "str", "auto",
         "(flash-style online softmax, O(block) scratch — "
         "tolerance-budgeted vs the oracle, VMEM no longer bounds smax)",
         choices=("auto", "gather", "fused", "fused_online"))
-declare("hpx.serving.prefill_chunk", "int", "128",
-        "prompt tokens per prefill chunk")
+declare("hpx.serving.prefill_chunk", "str", "auto",
+        "prompt tokens per prefill chunk (int|auto: the width at which "
+        "a chunk's arithmetic takes as long as its weight read on this "
+        "device, 128 where the device is unknown)")
 declare("hpx.serving.prefill_buckets", "str", "auto",
         "chunk-width ladder (csv|auto)")
 declare("hpx.serving.async_dispatch", "bool", "1",

@@ -200,15 +200,9 @@ class PrefillWorker(_WorkerRing):
         eng, plen, bs = self._eng, len(job.prompt), self.block_size
         with self._wspan("prefill.step", rid=rid):
             if job.done < plen:
-                n = min(eng.prefill_chunk, plen - job.done)
-                width = eng._bucket_width(n)
-                toks = (job.prompt[job.done:job.done + n]
-                        + [0] * (width - n))
-                job.caches = eng._chunk_prog(width)(
-                    eng.params, job.caches,
-                    jnp.asarray([toks], jnp.int32),
-                    jnp.asarray(job.done, jnp.int32),
-                    jnp.asarray(n, jnp.int32))
+                n, width = eng._next_chunk(job.done, plen - job.done)
+                job.caches = eng._run_chunk(job.caches, job.prompt,
+                                            job.done, n, width)
                 job.done += n
             segs: List[KVSegment] = []
             # pre-probe emission cap: row plen-1 is rewritten by the

@@ -72,6 +72,10 @@ class MoeConfig:
 # per-expert occupancy (moe_ffn_serve)
 STATS_HERE = 2
 
+# the leaves of `init_moe_params` with an expert axis: a row multiplies
+# its top_k experts' part of them, and all of every other leaf
+ROUTED_LEAVES = ("w1", "w2", "w3", "b1")
+
 
 def init_moe_params(cfg: MoeConfig, key: jax.Array) -> Dict[str, Any]:
     k1, k2, k3 = jax.random.split(key, 3)

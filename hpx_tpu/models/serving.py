@@ -83,6 +83,7 @@ stays the lean fast path for uniform decode).
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
@@ -97,7 +98,7 @@ from ..cache.page_table import (PageTable, WindowTable, materialize,
                                 occupancy)
 from ..cache.radix import RadixCache
 from ..core.errors import Error, HpxError
-from ..svc import faultinject, flight, tracing
+from ..svc import faultinject, flight, progprof, tracing
 from ..svc.resiliency import sync_replay
 from ..ops.attention_pallas import (resolve_paged_block,
                                      walk_heads_per_copy)
@@ -111,6 +112,7 @@ from ..ops.paged_attention import (
     scatter_seq_blocks,
     scatter_seq_blocks_q,
 )
+from .moe import ROUTED_LEAVES
 from .transformer import (
     _PREFILL_CHUNK,
     TransformerConfig,
@@ -238,6 +240,40 @@ def _resolve_buckets(spec, chunk: int) -> Tuple[int, ...]:
             f"hpx.serving.prefill_buckets parsed to nothing: {spec!r}")
     vals.append(chunk)
     return tuple(sorted(set(vals)))
+
+
+# the widest chunk the ridge rule hands any model: `jit_chunk` at 512
+# rows compiles for the chip beside the pools at every benchmark cell's
+# real size (PERF.md section 6, PR 38: its temporaries grow with the
+# rows); one number for every model
+_CHUNK_CEILING = 512
+
+
+def _ridge_chunk(params, cfg: TransformerConfig, ridge: float) -> int:
+    """The chunk width at which a chunk's arithmetic takes as long as
+    its weight read on a device of `ridge` FLOPs a byte: ridge x B /
+    (2 N), B the bytes of the layers' parameter leaves (what a chunk
+    without logits reads: every HELD expert of a sparse layer, neither
+    embedding nor head) and N the parameters one row multiplies (the
+    same leaves' elements, a routed expert's counted by the share of
+    the ROUTER's experts a row goes to). Below it a wider chunk is
+    nearly free, above it time grows with the rows. The power of two
+    nearest in ratio, between today's 128 and `_CHUNK_CEILING`; 128
+    where the device's ridge is unknown (0)."""
+    if ridge <= 0.0:
+        return _PREFILL_CHUNK
+    share = cfg.moe_top_k / cfg.n_experts if cfg.n_experts else 1.0
+    nbytes = mults = 0.0
+    for lp in params["layers"]:
+        moe = lp.get("moe", {})
+        routed = sum(x.size for x in jax.tree.leaves(
+            [moe[k] for k in ROUTED_LEAVES if k in moe]))
+        leaves = jax.tree.leaves(lp)
+        nbytes += sum(x.size * x.dtype.itemsize for x in leaves)
+        mults += sum(x.size for x in leaves) - (1.0 - share) * routed
+    rows = ridge * nbytes / (2.0 * mults)
+    return min(max(2 ** round(math.log2(rows)), _PREFILL_CHUNK),
+               _CHUNK_CEILING)
 
 
 def _resolve_kv_dtype(kv_dtype, rc) -> str:
@@ -804,9 +840,18 @@ class ContinuousServer:
         self._moe_here = self._moe_tokens_here = 0.0
         self._moe_buf: deque = deque()
 
+        # the chunk width: the argument, else the config key, else the
+        # device's ridge over the weights a chunk reads (`_ridge_chunk`)
+        self._prefill_chunk_src = "arg"
         if prefill_chunk is None:
-            prefill_chunk = rc.get_int("hpx.serving.prefill_chunk",
-                                       _PREFILL_CHUNK)
+            v = rc.get("hpx.serving.prefill_chunk", "auto")
+            if v in (None, "", "auto"):
+                prefill_chunk = _ridge_chunk(params, cfg,
+                                             progprof.device_ridge())
+                self._prefill_chunk_src = "ridge"
+            else:
+                prefill_chunk = int(v)
+                self._prefill_chunk_src = "config"
         self.prefill_chunk = max(1, int(prefill_chunk))
         if prefill_buckets is None:
             prefill_buckets = rc.get("hpx.serving.prefill_buckets",
@@ -960,6 +1005,7 @@ class ContinuousServer:
         self._keys_dev = None
         # observability
         self._chunks = 0                # prefill chunk dispatches
+        self._chunk_rows = 0            # prompt tokens they computed
         self._prog_hits = 0             # program-cache hits
         self._prog_misses = 0           # program-cache misses (compiles)
         self.ttft: Dict[int, float] = {}  # rid -> submit->seed seconds
@@ -2029,6 +2075,7 @@ class ContinuousServer:
                 self._pos[s_] + 1 for s_ in range(self.slots)
                 if self._slot_req[s_] is not None)
         st.update(self.hbm_read_stats())
+        st.update(self.prefill_stats())
         if self.mesh is not None:
             # per-dp-shard slot accounting: slots map to dp shards by
             # index range (the P("dp") slot-axis sharding), so shard
@@ -2151,6 +2198,18 @@ class ContinuousServer:
             st["routed_here"] = self._moe_here
             st["tokens_here"] = self._moe_tokens_here
         return st
+
+    def prefill_stats(self) -> Dict[str, Any]:
+        """The chunk width this server prefills at, where it came from
+        (`arg` | `config` | `ridge`: derived from the device's ridge
+        over the weights a chunk reads, `_ridge_chunk`) and the prompt
+        tokens a chunk dispatch has carried so far — the
+        /serving{...}/prefill/* counters."""
+        return {"prefill_chunk": self.prefill_chunk,
+                "prefill_chunk_source": self._prefill_chunk_src,
+                "prefill_rows_per_chunk":
+                    self._chunk_rows / self._chunks if self._chunks
+                    else 0.0}
 
     def read_stats(self) -> Dict[str, int]:
         """The blocking device->host reads so far (seed tokens, token
@@ -2367,6 +2426,37 @@ class ContinuousServer:
                 return w
         return self.prefill_buckets[-1]
 
+    def _next_chunk(self, pos0: int, remaining: int) -> Tuple[int, int]:
+        """(tokens, ladder width) of the chunk that starts at row
+        `pos0` with `remaining` tokens to go. A tail chunk's pad rows
+        reach pos0 + width, and a `dynamic_update_slice` whose window
+        would pass the scratch's `smax` rows is CLAMPED and shifts the
+        real rows: there the chunk is the widest bucket that fits and
+        the rest a further chunk; (1, 0) where none fits: one row,
+        through the probe's program (`_run_chunk`)."""
+        n = min(self.prefill_chunk, remaining)
+        width = self._bucket_width(n)
+        room = self.smax - pos0
+        if width > room:
+            width = max((w for w in self.prefill_buckets if w <= room),
+                        default=0)
+            n = min(n, width) or 1
+        return n, width
+
+    def _run_chunk(self, caches, seq: List[int], done: int, n: int,
+                   width: int):
+        """Dispatch the chunk `_next_chunk` planned, seq[done:done + n],
+        into the b=1 scratch `caches` (width 0: the probe's program,
+        its logits unread)."""
+        if not width:
+            return self._probe_prog()(
+                self.params, caches, jnp.asarray([[seq[done]]], jnp.int32),
+                jnp.asarray(done, jnp.int32))[0]
+        toks = seq[done:done + n] + [0] * (width - n)
+        return self._chunk_prog(width)(
+            self.params, caches, jnp.asarray([toks], jnp.int32),
+            jnp.asarray(done, jnp.int32), jnp.asarray(n, jnp.int32))
+
     def _fresh_scratch(self):
         """An empty b=1 prefill scratch, one entry a layer, made by ONE
         program: an admission enqueues one dispatch for it, not two
@@ -2495,23 +2585,19 @@ class ContinuousServer:
         radix prefix, so already-resident blocks are not recomputed).
         """
         faultinject.check("prefill")
-        req, plen = p.req, len(p.req.prompt)
-        n = min(self.prefill_chunk, p.remaining)
-        if n == 0:      # a one-token prompt whose token the probe takes
-            return
-        width = self._bucket_width(n)
-        toks = req.prompt[p.done:p.done + n] + [0] * (width - n)
+        req = p.req
+        n, width = self._next_chunk(p.done, p.remaining)
         with tracing.span("serving.prefill_chunk", "serving",
                           rid=req.rid, pos0=p.done, tokens=n,
-                          width=width):
+                          width=width or 1):
             if p.flow is not None:
                 tracing.flow_end(p.flow, "serving.prefill_chunks")
                 p.flow = None
-            p.caches = self._chunk_prog(width)(
-                self.params, p.caches, jnp.asarray([toks], jnp.int32),
-                jnp.asarray(p.done, jnp.int32), jnp.asarray(n, jnp.int32))
+            p.caches = self._run_chunk(p.caches, req.prompt, p.done, n,
+                                       width)
             p.done += n
             self._chunks += 1
+            self._chunk_rows += n
             if p.remaining:
                 p.flow = tracing.flow_begin("serving.prefill_chunks")
 
@@ -2652,7 +2738,8 @@ class ContinuousServer:
                                               plen=plen,
                                               matched=p.done,
                                               suffix=p.remaining):
-                                self._advance_chunk(p)
+                                while p.remaining:
+                                    self._advance_chunk(p)
                                 self._finish_prefill(p)
                         else:
                             p.flow = tracing.flow_begin(
@@ -2772,8 +2859,11 @@ class ContinuousServer:
         O(buckets) too)."""
         done, plen = 0, len(prompt)
         while done < plen:
-            n = min(self.prefill_chunk, plen - done)
-            width = self._bucket_width(n)
+            n, width = self._next_chunk(done, plen - done)
+            if not width:
+                # no bucket fits under smax: the rows stay unwritten,
+                # which costs acceptance and never content
+                break
             toks = prompt[done:done + n] + [0] * (width - n)
             self._draft_caches = self._draft_chunk_prog(width)(
                 self._draft_params, self._draft_caches,
@@ -3101,13 +3191,8 @@ class ContinuousServer:
         scratch = self._fresh_scratch()
         done = 0
         while done < len(seq):
-            n = min(self.prefill_chunk, len(seq) - done)
-            width = self._bucket_width(n)
-            toks = seq[done:done + n] + [0] * (width - n)
-            scratch = self._chunk_prog(width)(
-                self.params, scratch,
-                jnp.asarray([toks], jnp.int32),
-                jnp.asarray(done, jnp.int32), jnp.asarray(n, jnp.int32))
+            n, width = self._next_chunk(done, len(seq) - done)
+            scratch = self._run_chunk(scratch, seq, done, n, width)
             done += n
         return scratch
 

@@ -57,6 +57,7 @@ __all__ = [
     "active_profiler",
     "start_if_configured",
     "profile_table",
+    "device_ridge",
 ]
 
 PROFILE_SCHEMA = "hpx_tpu.progprof.v1"
@@ -79,6 +80,43 @@ _DEVICE_PEAK_GFLOPS: Tuple[Tuple[str, float], ...] = (
     ("v3", 123_000.0),
     ("v2", 45_000.0),
 )
+
+# HBM GB/s per device kind, matched as the table above: with it the
+# RIDGE of a kind, the FLOPs a byte read from HBM has to feed before
+# the matrix unit and not the read paces a program
+_DEVICE_HBM_GBPS: Tuple[Tuple[str, float], ...] = (
+    ("v6e", 1_640.0),
+    ("v5p", 2_765.0),
+    ("v5e", 819.0),
+    ("v5 lite", 819.0),
+    ("v4", 1_228.0),
+    ("v3", 900.0),
+    ("v2", 700.0),
+)
+
+
+def _by_kind(table: Tuple[Tuple[str, float], ...],
+             kind: Optional[str] = None) -> float:
+    """The table's entry for `kind` (None: the first device's); 0 for a
+    kind it does not know, the CPU among them."""
+    if kind is None:
+        try:
+            import jax
+            kind = jax.devices()[0].device_kind
+        except Exception:  # noqa: BLE001
+            return 0.0
+    kind = kind.lower()
+    for frag, value in table:
+        if frag in kind:
+            return value
+    return 0.0
+
+
+def device_ridge(kind: Optional[str] = None) -> float:
+    """Peak bf16 FLOP/s over HBM bytes/s of a device kind (TPU v5 lite:
+    197e12 / 819e9 = 240); 0 = unknown."""
+    gbps = _by_kind(_DEVICE_HBM_GBPS, kind)
+    return _by_kind(_DEVICE_PEAK_GFLOPS, kind) / gbps if gbps else 0.0
 
 
 def _host_rss_bytes() -> int:
@@ -248,15 +286,7 @@ class ProgramProfiler:
         v = _cfg().get_float("hpx.prof.peak_gflops", 0.0)
         if v > 0.0:
             return v
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind.lower()
-        except Exception:  # noqa: BLE001
-            return 0.0
-        for frag, peak in _DEVICE_PEAK_GFLOPS:
-            if frag in kind:
-                return peak
-        return 0.0
+        return _by_kind(_DEVICE_PEAK_GFLOPS)
 
     # -- the cached_program build hook --------------------------------
 

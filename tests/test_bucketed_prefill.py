@@ -15,7 +15,9 @@ import numpy as np
 import pytest
 
 from hpx_tpu.models import transformer as tfm
-from hpx_tpu.models.serving import ContinuousServer, _resolve_buckets
+from hpx_tpu.models.serving import (_CHUNK_CEILING, ContinuousServer,
+                                    _resolve_buckets)
+from hpx_tpu.svc import progprof
 
 CFG = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
                             n_layers=2, d_ff=64)
@@ -43,6 +45,23 @@ def _solo(params, prompt, m, t=0.0, key=None, eos_id=None):
     return [int(x) for x in np.asarray(out)[0]]
 
 
+# a DERIVED chunk width: no argument, no config key, and a device whose
+# ridge a test states. The float32 toy reads 4 bytes a parameter, so
+# its ridge width is twice the device's ridge: 120 -> 256, 240 -> the
+# ceiling (tests/test_server_geometry.py has the rule's own cases)
+_RIDGE_OF = {256: 120.0, _CHUNK_CEILING: 240.0}
+_SMAX_WIDE = 640
+
+
+def _derived_server(monkeypatch, params, width, **kw):
+    monkeypatch.setattr(progprof, "device_ridge",
+                        lambda: _RIDGE_OF[width])
+    srv = ContinuousServer(params, CFG, slots=3, smax=_SMAX_WIDE, **kw)
+    assert srv.prefill_chunk == width == srv.prefill_buckets[-1]
+    assert srv.prefill_stats()["prefill_chunk_source"] == "ridge"
+    return srv
+
+
 def test_resolve_buckets():
     assert _resolve_buckets("auto", 128) == (8, 16, 32, 64, 128)
     assert _resolve_buckets("auto", 8) == (8,)
@@ -59,14 +78,24 @@ def test_resolve_buckets():
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 @pytest.mark.parametrize("async_dispatch", [True, False],
                          ids=["async", "sync"])
-def test_boundary_plens_match_generate(params, paged, async_dispatch):
+@pytest.mark.parametrize("width", [CHUNK, 256, _CHUNK_CEILING],
+                         ids=["stated-8", "derived-256", "ceiling"])
+def test_boundary_plens_match_generate(params, monkeypatch, paged,
+                                       async_dispatch, width):
     """Every bucket-boundary prompt length, greedy AND sampled mixed in
-    one batch, byte-identical to the solo run."""
-    srv = ContinuousServer(params, CFG, slots=3, smax=64, paged=paged,
-                           prefill_chunk=CHUNK, prefill_buckets=LADDER,
-                           async_dispatch=async_dispatch)
+    one batch, byte-identical to the solo run; at a derived width, one
+    under, at and one over that width and its half."""
+    if width == CHUNK:
+        srv = ContinuousServer(params, CFG, slots=3, smax=64, paged=paged,
+                               prefill_chunk=CHUNK, prefill_buckets=LADDER,
+                               async_dispatch=async_dispatch)
+        plens = PLENS
+    else:
+        srv = _derived_server(monkeypatch, params, width, paged=paged,
+                              async_dispatch=async_dispatch)
+        plens = [w + d for w in (width // 2, width) for d in (-1, 0, 1)]
     want = {}
-    for i, plen in enumerate(PLENS):
+    for i, plen in enumerate(plens):
         p = _prompt(plen, seed=100 + plen)
         if i % 2:
             k = jax.random.PRNGKey(7 * i)
@@ -79,27 +108,43 @@ def test_boundary_plens_match_generate(params, paged, async_dispatch):
     assert out == want
 
 
-def test_program_cache_is_o_buckets(params):
+@pytest.mark.parametrize("ladder", ["stated", "derived"])
+def test_program_cache_is_o_buckets(params, monkeypatch, ladder):
     """After a mixed-length workload, the module program cache holds at
     most one chunk program PER LADDER WIDTH for this server shape —
-    not one per prompt length."""
+    not one per prompt length; the longer ladder of a derived width
+    (8 doubling to the ceiling) adds rungs, nothing else."""
+    smax = {"stated": 64, "derived": _SMAX_WIDE}[ladder]
+
     def mine(k):
-        return k[0] == "cb_chunk" and k[1] == CFG and k[3] == 64
+        return k[0] == "cb_chunk" and k[1] == CFG and k[3] == smax
 
     # the cache is process-wide: drop what earlier tests of this shape
     # (this file or another in the same xdist worker) left, so the
     # count below is this workload's alone
     for k in [k for k in tfm._PROGRAMS if mine(k)]:
         del tfm._PROGRAMS[k]
-    srv = ContinuousServer(params, CFG, slots=3, smax=64,
-                           prefill_chunk=CHUNK, prefill_buckets=LADDER)
-    for plen in PLENS:
+    if ladder == "stated":
+        srv = ContinuousServer(params, CFG, slots=3, smax=64,
+                               prefill_chunk=CHUNK, prefill_buckets=LADDER)
+        plens = PLENS
+    else:
+        srv = _derived_server(monkeypatch, params, _CHUNK_CEILING)
+        assert srv.prefill_buckets == (8, 16, 32, 64, 128, 256, 512)
+        plens = PLENS + [30, 33, 100, 129, 200, 300, 513, 600]
+    for plen in plens:
         srv.submit(_prompt(plen, seed=200 + plen), max_new=4)
     srv.run()
     chunk_keys = [k for k in tfm._PROGRAMS if mine(k)]
     assert 0 < len(chunk_keys) <= len(srv.prefill_buckets)
     widths = sorted(k[2] for k in chunk_keys)
     assert set(widths) <= set(srv.prefill_buckets)
+    if ladder == "derived":
+        assert widths == list(srv.prefill_buckets)
+        # 600 = one full chunk + 88 in the 128 bucket: two dispatches
+        assert srv.prefill_stats()["prefill_rows_per_chunk"] == \
+            sum(plens) / srv._chunks
+        assert srv._chunks == len(plens) + 2
 
 
 def test_second_server_reuses_programs(params):
@@ -199,6 +244,36 @@ def test_paged_prefix_reuse_skips_chunks(params):
     assert srv.cache_stats()["prefill_tokens_saved"] >= 32
     assert out1[a] == _solo(params, p1, 4)
     assert out2[b] == _solo(params, p2, 4)
+
+
+@pytest.mark.parametrize("matched", [0, 20], ids=["cold", "radix-20"])
+def test_a_prompt_that_ends_near_smax_matches_generate(params, matched):
+    """A tail chunk's pad rows reach pos0 + width: where that passes
+    the scratch's smax rows the write would be CLAMPED and shift the
+    real rows. The host takes the widest bucket that fits and sends the
+    rest as a further chunk (within the narrowest bucket of the end:
+    a row at a time through the probe's program). After a radix match
+    of 20 tokens (blocks of 4) the chunks start off every chunk
+    boundary: 20, 36, 52 (room 12: the 8 bucket), 60 (room 4: rows)."""
+    shared = _prompt(20, seed=20)
+    srv = ContinuousServer(params, CFG, slots=1, smax=64, paged=True,
+                           block_size=4, prefill_chunk=16,
+                           prefill_buckets="8,16")
+    if matched:
+        first = shared + _prompt(4, seed=21)
+        a = srv.submit(first, max_new=3)
+        assert srv.run()[a] == _solo(params, first, 3)
+    p = shared + _prompt(41, seed=22)          # 61 + 3 = smax
+    before = srv._chunks
+    b = srv.submit(p, max_new=3)
+    out = srv.run()
+    assert srv.cache_stats()["prefill_tokens_saved"] == matched
+    # cold: 16 16 16 and 13 in the 16 bucket, which ends at 64 exactly
+    assert srv._chunks - before == 4
+    assert srv._next_chunk(48, 13) == (13, 16)
+    assert srv._next_chunk(52, 9) == (8, 8)
+    assert srv._next_chunk(60, 1) == (1, 0)
+    assert out[b] == _solo(params, p, 3)
 
 
 def test_async_buffer_caps_and_flushes(params):

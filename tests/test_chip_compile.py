@@ -316,11 +316,13 @@ def test_window_group_write_and_kernel_leave_the_pool_where_it_lies(
     assert _pool_ops(text, pool) == []
 
 
-@pytest.mark.parametrize("tokens", [32, 128], ids=["decode32", "chunk128"])
+@pytest.mark.parametrize("tokens", [32, 128, 512],
+                         ids=["decode32", "chunk128", "chunk512"])
 def test_moe_gmm_compiles_at_the_cells_widths(sds, tokens, monkeypatch):
     """`hpx_moe_gmm` inside the whole drop-free sparse FFN at Laguna's
     widths: 256 experts of 2048 x 512 in bfloat16, top-8, a decode
-    step's 32 tokens and a prefill chunk's 128."""
+    step's 32 tokens, a prefill chunk's 128 and the 512 of a chunk as
+    wide as the model's ridge (`serving._ridge_chunk`'s ceiling)."""
     from hpx_tpu.models import moe
     cfg = moe.MoeConfig(n_experts=256, top_k=8, d_model=2048, d_ff=512,
                         dtype=jnp.bfloat16, mlp="swiglu", router="sigmoid",

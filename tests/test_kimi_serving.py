@@ -545,3 +545,31 @@ def test_the_16_shares_of_a_sparse_layer_add_up_to_the_uncut_reference():
         assert stats.shape == (2 + 16,)
         total = total + out
     np.testing.assert_allclose(np.asarray(total), whole, atol=2e-5, rtol=0)
+
+
+def test_served_at_the_ceiling_width_gives_the_tokens_of_128(toy,
+                                                             monkeypatch):
+    """A model with recurrent and latent layers served with no stated chunk width on a
+    device whose ridge puts it at the ceiling: the chunks of 512 rows
+    (and the tail that smax splits: 512 + 256 > 720, so 128 then 64)
+    leave the tokens that chunks of 128 leave."""
+    from hpx_tpu.svc import progprof
+    _, cfg, params = toy
+    reqs = [(_prompt(700, 5), 12), (_prompt(513, 6), 8),
+            (_prompt(90, 7), 10)]
+
+    def serve(**kw):
+        srv = ContinuousServer(params, cfg, paged=True, slots=2, smax=720,
+                               **kw)
+        rids = [srv.submit(p, max_new=m) for p, m in reqs]
+        out = srv.run()
+        return [out[r] for r in rids], srv
+    base, at128 = serve(prefill_chunk=128)
+    monkeypatch.setattr(progprof, "device_ridge", lambda: 240.0)
+    got, srv = serve()
+    st = srv.cache_stats()
+    assert (st["prefill_chunk"], st["prefill_chunk_source"]) == (
+        serving._CHUNK_CEILING, "ridge")
+    assert got == base and srv.failed == {}
+    # the probe takes the last token: 699: 512 128 64; 512: 512; 89: 128
+    assert (at128._chunks, srv._chunks) == (6 + 4 + 1, 3 + 1 + 1)

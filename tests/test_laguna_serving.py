@@ -338,3 +338,31 @@ def test_dense_server_and_generate_compute_the_same_model(toy):
         outs.append([out[r] for r in rids])
     assert outs[0] == outs[1] == [_generate(params, cfg, p, 30)
                                   for p in prompts]
+
+
+def test_served_at_the_ceiling_width_gives_the_tokens_of_128(toy,
+                                                             monkeypatch):
+    """A model with window layers and sparse layers served with no stated chunk width on a
+    device whose ridge puts it at the ceiling: the chunks of 512 rows
+    (and the tail that smax splits: 512 + 256 > 720, so 128 then 64)
+    leave the tokens that chunks of 128 leave."""
+    from hpx_tpu.svc import progprof
+    _, cfg, params = toy
+    reqs = [(_prompt(700, 5), 12), (_prompt(513, 6), 8),
+            (_prompt(90, 7), 10)]
+
+    def serve(**kw):
+        srv = ContinuousServer(params, cfg, paged=True, slots=2, smax=720,
+                               **kw)
+        rids = [srv.submit(p, max_new=m) for p, m in reqs]
+        out = srv.run()
+        return [out[r] for r in rids], srv
+    base, at128 = serve(prefill_chunk=128)
+    monkeypatch.setattr(progprof, "device_ridge", lambda: 240.0)
+    got, srv = serve()
+    st = srv.cache_stats()
+    assert (st["prefill_chunk"], st["prefill_chunk_source"]) == (
+        serving._CHUNK_CEILING, "ridge")
+    assert got == base and srv.failed == {}
+    # 700: 512 128 64; 513: 512 and 1 in the 8 bucket; 90: 128
+    assert (at128._chunks, srv._chunks) == (6 + 5 + 1, 3 + 2 + 1)

@@ -1,9 +1,10 @@
 """One place decides each construction-time shape of a
 ContinuousServer: the constructor argument, else its config key, else
 the constant (for the block size: HPX_PAGED_BLOCK, the measured table
-`ops/paged_blocks.json`, then 16). And a live server takes a config
-write to one of its reloadable knobs at its next flush, never
-mid-step."""
+`ops/paged_blocks.json`, then 16; for the chunk width: the device's
+ridge over the weights a chunk reads, 128 on a device of unknown
+ridge). And a live server takes a config write to one of its
+reloadable knobs at its next flush, never mid-step."""
 
 import dataclasses
 
@@ -16,6 +17,7 @@ from hpx_tpu.models import serving
 from hpx_tpu.models import transformer as tfm
 from hpx_tpu.models.serving import ContinuousServer
 from hpx_tpu.ops import attention_pallas as ap
+from hpx_tpu.svc import progprof
 
 CFG = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
                             n_layers=2, d_ff=64)
@@ -94,6 +96,78 @@ def test_resolution(params, knobs, knob, source):
     if knob == "block_size":
         assert srv.hbm_read_stats()["block_size_source"] == {
             "constant": "default"}.get(source, source)
+    if knob == "prefill_chunk":
+        # the third tier is derived: on a device of unknown ridge (the
+        # CPU) it comes out as the old constant
+        assert srv.cache_stats()["prefill_chunk_source"] == {
+            "constant": "ridge"}.get(source, source)
+        assert srv.cache_stats()["prefill_chunk"] == want
+
+
+# a dense model in bfloat16 reads 2 bytes a parameter and multiplies
+# each once a row: its ridge width is the device's ridge itself. A row
+# of SPARSE goes to 1 of 32 experts and the chunk reads all 32.
+DENSE16 = dataclasses.replace(CFG, dtype=jax.numpy.bfloat16)
+SPARSE = dataclasses.replace(CFG, n_experts=32, moe_top_k=1)
+# a share of the experts held under the full-width router: a row's
+# choices fall to the held ones by top_k / n_experts of the ROUTER's
+HELD = dataclasses.replace(CFG, n_experts=32, moe_top_k=4, moe_held=(0, 4),
+                           mlp="swiglu")
+
+_RIDGE_CASES = {
+    # case: (model, the device kind's ridge, config key, argument,
+    #        the width, its source)
+    "unknown-kind": (DENSE16, 0.0, None, None, 128, "ridge"),
+    "dense-at-240": (DENSE16, 240.0, None, None, 256, "ridge"),
+    "dense-f32-at-240": (CFG, 240.0, None, None, 512, "ridge"),
+    "dense-at-60": (DENSE16, 60.0, None, None, 128, "ridge"),
+    "dense-at-560": (DENSE16, 560.0, None, None, 512, "ridge"),
+    "sparse-at-240": (SPARSE, 240.0, None, None,
+                      serving._CHUNK_CEILING, "ridge"),
+    # all 4 held experts' rows counted a row would read 120 -> 128
+    "held-share-at-60": (HELD, 60.0, None, None, 512, "ridge"),
+    "config-wins": (SPARSE, 240.0, "64", None, 64, "config"),
+    "arg-wins": (SPARSE, 240.0, "64", 32, 32, "arg"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RIDGE_CASES))
+def test_prefill_chunk_follows_the_ridge(knobs, monkeypatch, case):
+    """Where neither the argument nor the config key states a width,
+    the chunk is as wide as the model's ridge on this device: the
+    power of two nearest ridge x bytes read / (2 x parameters a row
+    multiplies), between 128 and the one ceiling; the ladder follows."""
+    cfg, ridge, written, arg, want, source = _RIDGE_CASES[case]
+    monkeypatch.setattr(progprof, "device_ridge", lambda: ridge)
+    if written is not None:
+        knobs("hpx.serving.prefill_chunk", written)
+    p = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    assert serving._ridge_chunk(p, cfg, ridge) == (
+        want if source == "ridge" else serving._CHUNK_CEILING)
+    srv = ContinuousServer(tfm.init_params(cfg, jax.random.PRNGKey(0)),
+                           cfg, slots=2, smax=64, paged=True,
+                           **({} if arg is None else
+                              {"prefill_chunk": arg}))
+    st = srv.cache_stats()
+    assert (srv.prefill_chunk, st["prefill_chunk_source"]) == (want, source)
+    assert st["prefill_chunk"] == want
+    assert st["prefill_rows_per_chunk"] == 0.0
+    assert srv.prefill_buckets[-1] == want
+    assert srv.prefill_buckets[0] == min(8, want)
+    srv.submit([3, 1, 4, 1, 5], max_new=2)
+    srv.run()
+    assert srv.cache_stats()["prefill_rows_per_chunk"] == 5.0
+
+
+def test_the_ridge_of_a_device_kind():
+    """One table by `device_kind`, beside the peak FLOP/s: the chip the
+    benchmark runs on, and a kind nobody entered."""
+    assert int(progprof.device_ridge("TPU v5 lite")) == 240
+    assert progprof.device_ridge("TPU v5e") == \
+        progprof.device_ridge("TPU v5 lite")
+    assert progprof.device_ridge("cpu") == 0.0
+    assert progprof.device_ridge() == 0.0       # the tier-1 tests' CPU
 
 
 @pytest.mark.parametrize("source", ["seed", "env"])
