@@ -995,8 +995,8 @@ class ProgramCacheBypassRule(Rule):
     ``core.programs.cached_program`` (via a module's
     ``_cached_program`` / ``self._program`` wrapper) — the single
     funnel where the per-program profiler (``svc/progprof``)
-    interposes to account compile wall time, per-call latency, and
-    roofline fraction.  A raw ``jax.jit(...)`` (or ``@jax.jit``
+    interposes to account compile wall time and the hold of each
+    call.  A raw ``jax.jit(...)`` (or ``@jax.jit``
     decorator) in ``models/`` or ``ops/`` builds a program the
     profiler and the ``/programs{...}`` counters can never see — its
     compiles and calls vanish from the --metrics-out artifact and

@@ -27,6 +27,14 @@ Every server gets the serving counters::
                                 with a decode step queued behind the value
     /serving{locality#L/server#i}/reads/draining    ... with none: the read
                                 empties the dispatch queue
+    /serving{locality#L/server#i}/steps/slow        step() calls the step's
+                                account called slow (svc/tracing.py: over
+                                250 ms and 4 paces a program queued ahead
+                                of it (decode steps unread, chunks since
+                                the last read), or the end of a block of
+                                32 over 2.5 median blocks and 1 s more)
+    /serving{locality#L/server#i}/steps/slow-seconds  ... and the seconds they
+                                took beyond the median
 
 Speculative servers (``hpx.serving.spec.enable``) add::
 
@@ -195,6 +203,11 @@ def register_server(srv) -> str:
         pc.CallbackCounter(_read(ref, lambda s: s._reads_overlapped)))
     put("serving", "reads/draining",
         pc.CallbackCounter(_read(ref, lambda s: s._reads_draining)))
+    # the step's account (ContinuousServer.step_accounts)
+    put("serving", "steps/slow",
+        pc.CallbackCounter(_read(ref, lambda s: s._acct.slow)))
+    put("serving", "steps/slow-seconds",
+        pc.CallbackCounter(_read(ref, lambda s: s._acct.slow_ns / 1e9)))
 
     # fault/recovery ladder observability (svc/faultinject +
     # ContinuousServer.fault_stats): injected faults seen, step

@@ -354,13 +354,8 @@ declare("hpx.metrics.timeline_capacity", "int", "1024",
 # -- program profiler (svc/progprof) ----------------------------------------
 declare("hpx.prof.programs", "bool", "0",
         "per-program continuous profiler: wrap every cached_program() "
-        "build in a timing/cost-accounting proxy")
-declare("hpx.prof.cost_analysis", "bool", "1",
-        "query XLA cost analysis (FLOPs / bytes accessed) on first call "
-        "of each profiled program")
-declare("hpx.prof.peak_gflops", "float", "0",
-        "roofline denominator in GFLOP/s (0 = infer from device kind; "
-        "unknown kinds report roofline fraction 0)")
+        "build in a proxy that times its compile and the host's wall "
+        "around each (asynchronous) call")
 
 # -- flight recorder (svc/flight) -------------------------------------------
 declare("hpx.flight.enabled", "bool", "1",

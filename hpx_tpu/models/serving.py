@@ -692,6 +692,58 @@ class _PendingPrefill:
         return len(self.req.prompt) - self.hold - self.done
 
 
+_now_ns = time.perf_counter_ns
+
+
+class _Dispatch:
+    """What `ContinuousServer._program()` hands out: the named
+    program, its call inside a `serving.dispatch` span (`prog`, and
+    `rid` where an admission is under way) and its nanoseconds on the
+    step's account: the time the runtime HOLDS the host in the call,
+    not the program's execution. Anything else (`lower`, ...) is the
+    program's own."""
+
+    __slots__ = ("_srv", "_name", "_prog")
+
+    def __init__(self, srv: "ContinuousServer", name: str, prog) -> None:
+        self._srv, self._name, self._prog = srv, name, prog
+
+    def __call__(self, *args, **kwargs):
+        srv, name = self._srv, self._name
+        rid = srv._rid
+        t0 = _now_ns()
+        with (tracing.span("serving.dispatch", "serving", prog=name)
+              if rid is None else
+              tracing.span("serving.dispatch", "serving", prog=name,
+                           rid=rid)):
+            out = self._prog(*args, **kwargs)
+        srv._acct.dispatched(name, _now_ns() - t0)
+        return out
+
+    def __getattr__(self, attr: str):
+        return getattr(self._prog, attr)
+
+
+class _Read:
+    """The span around ONE blocking device->host read
+    (`ContinuousServer._wait`), its nanoseconds on the step's account."""
+
+    __slots__ = ("_acct", "_span", "_t0")
+
+    def __init__(self, acct: tracing.StepAccount, span) -> None:
+        self._acct, self._span = acct, span
+
+    def __enter__(self) -> "_Read":
+        self._t0 = _now_ns()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._acct.waited_ns += _now_ns() - self._t0
+        return False
+
+
 class ContinuousServer:
     """Slot-based continuous batching, per-request greedy or sampled.
 
@@ -1000,6 +1052,12 @@ class ContinuousServer:
         # blocking reads with / without a decode step queued behind
         self._reads_overlapped = 0
         self._reads_draining = 0
+        # the step's account (svc/tracing.StepAccount): fed by step(),
+        # by every program `_program` hands out and by `_wait`; `_rid`:
+        # the request an admission's dispatches are for
+        self._acct = tracing.StepAccount()
+        self._rid: Optional[int] = None
+        self._admits = 0                # requests `_admit` took up
         self._cur_dev = None            # [slots] int32 token feedback
         self._temp_dev = None           # [slots] f32 (with _keys_dev)
         self._keys_dev = None
@@ -1300,13 +1358,16 @@ class ContinuousServer:
         counters; the compile-count guard test reads them too).
         Builders that donate (donate_argnums) rely on callers
         rebinding the result over the donated binding — hpxlint
-        HPX020 flags any other use after the donating call."""
+        HPX020 flags any other use after the donating call.
+        What comes back is the program inside its `serving.dispatch`
+        span and on the step's account (`_Dispatch`), under the name
+        its key leads with."""
         from .transformer import _PROGRAMS
         if ck in _PROGRAMS:
             self._prog_hits += 1
         else:
             self._prog_misses += 1
-        return _cached_program(ck, build)
+        return _Dispatch(self, ck[0], _cached_program(ck, build))
 
     def _moe_cf(self):
         """Effective decode capacity factor from the int-percent knob
@@ -2218,6 +2279,18 @@ class ContinuousServer:
         return {"reads_overlapped": self._reads_overlapped,
                 "reads_draining": self._reads_draining}
 
+    def step_accounts(self) -> List[tracing.StepRecord]:
+        """The account of each of the last 4,096 step() calls, oldest
+        first (`svc/tracing.StepRecord`: wall = work + held in dispatch
+        calls + waited on reads, the program that held longest, the
+        step's dispatches, reads, admissions and chunks, how much of
+        the work lay in the sections of eager ops, and the process's
+        own view: CPU time, collector pauses; `slow` where the step, or
+        the 32 steps it ends, stood out — the /serving{...}/steps/*
+        counters count those, and each leaves a `svc/flight` bundle,
+        one in 5 s)."""
+        return self._acct.records()
+
     def spec_stats(self) -> Dict[str, float]:
         """Speculation observability snapshot (the same numbers the
         /serving{...}/spec/* performance counters export)."""
@@ -2629,6 +2702,10 @@ class ContinuousServer:
             self._caches = self._splice_prog()(
                 self._caches, caches, jnp.asarray(slot, jnp.int32))
         del self._pending[slot]
+        # eager, unnamed programs (the slice, the pick, the scatter): on
+        # the step's account as `eager_ns`, for a full queue holds the
+        # host here as it does in a named program's call
+        t0 = self._acct.work_clock()
         if req.temperature > 0.0:
             # generate()'s tok0 draw: position plen-1, row 0
             tok0 = _sample_row(logits[0], req.temperature, req.key,
@@ -2641,6 +2718,7 @@ class ContinuousServer:
             self._cur_dev = jnp.asarray(self._cur, jnp.int32)
         if self._cur_dev is not None:
             self._cur_dev = self._cur_dev.at[slot].set(tok0)
+        self._acct.eager_ns += self._acct.work_clock() - t0
         req.sent = 1
         self._slot_req[slot] = req
         self._pos[slot] = plen
@@ -2667,7 +2745,8 @@ class ContinuousServer:
             self._reads_overlapped += 1
         else:
             self._reads_draining += 1
-        return tracing.span(name, "serving", behind=behind, **args)
+        return _Read(self._acct,
+                     tracing.span(name, "serving", behind=behind, **args))
 
     def _land_seeds(self, behind: int) -> None:
         """Read the seed tokens `_finish_prefill` left on the device,
@@ -2724,6 +2803,8 @@ class ContinuousServer:
                         rid=req.rid)
                     self.timeline.event(req.rid, "prefill_start",
                                         slot=slot)
+                self._admits += 1
+                self._rid = req.rid
                 try:
                     with tracing.span("serving.admit", "serving",
                                       rid=req.rid, slot=slot,
@@ -2843,6 +2924,7 @@ class ContinuousServer:
                           pending=len(self._pending)):
             p = min(self._pending.values(),
                     key=lambda q: (q.remaining, q.seq))
+            self._rid = p.req.rid
             self._advance_chunk(p)
             if p.remaining == 0:
                 with tracing.span("serving.prefill", "serving",
@@ -3550,8 +3632,22 @@ class ContinuousServer:
         suffix against intact KV state and emits the SAME tokens the
         fault-free run would (differential contract). If the retry
         budget exhausts, every in-flight request sheds with a typed
-        error into `failed` and the loop moves on."""
+        error into `failed` and the loop moves on.
+
+        Every call closes one record of the step's account
+        (`step_accounts()`): where its wall went, profiler on or off."""
         self._step_n += 1
+        self._acct.begin(len(self._buf))
+        try:
+            return self._step_span()
+        finally:
+            self._acct.end(
+                self._step_n, self.slots - self._slot_req.count(None),
+                self._admits, self._chunks, self._prog_misses,
+                self._reads_draining, self._reads_overlapped)
+
+    def _step_span(self) -> bool:
+        """step()'s body, inside its `serving.step` span."""
         with tracing.span("serving.step", "serving", n=self._step_n):
             self._shed_expired()
             # decode-stall feed: the gap between consecutive step()
@@ -3581,8 +3677,12 @@ class ContinuousServer:
                                        for r in self._slot_req)
 
     def _step_inner(self) -> bool:
-        self._admit()
-        self._prefill_tick()
+        try:
+            self._admit()
+            self._prefill_tick()
+        finally:
+            # what follows (a recovery's dispatches too) is every slot's
+            self._rid = None
         live = [s for s in range(self.slots)
                 if self._slot_req[s] is not None]
         if not live:
@@ -3599,26 +3699,32 @@ class ContinuousServer:
             # BUFFERED step already completed on device, so recovery's
             # flush-then-restore loses nothing
             faultinject.check("decode")
-            # dense: dead slots re-write their own last position
-            # (harmless: never read — admission overwrites rows
-            # 0..plen first). Paged: dead slots' tables are all-trash,
-            # so their writes land in the reserved trash block instead
-            # of a recycled live block. Dead slots' feedback tokens
-            # are stale argmax/sample outputs — always valid ids.
-            tok = (jnp.asarray(self._cur, jnp.int32)
-                   if self._cur_dev is None else self._cur_dev)
-            pos = jnp.asarray(self._pos, jnp.int32)
-            if self._temp_dev is None:
-                self._temp_dev = jnp.asarray(self._temp, jnp.float32)
-                self._keys_dev = jnp.stack(self._key)
+            t0 = self._acct.work_clock()
+            with tracing.span("serving.decode.operands", "serving",
+                              live=len(live)):
+                # dense: dead slots re-write their own last position
+                # (harmless: never read — admission overwrites rows
+                # 0..plen first). Paged: dead slots' tables are
+                # all-trash, so their writes land in the reserved trash
+                # block instead of a recycled live block. Dead slots'
+                # feedback tokens are stale argmax/sample outputs —
+                # always valid ids.
+                tok = (jnp.asarray(self._cur, jnp.int32)
+                       if self._cur_dev is None else self._cur_dev)
+                pos = jnp.asarray(self._pos, jnp.int32)
+                if self._temp_dev is None:
+                    self._temp_dev = jnp.asarray(self._temp, jnp.float32)
+                    self._keys_dev = jnp.stack(self._key)
+                if self.paged:
+                    for s in live:
+                        self._ensure_block(s, self._pos[s])
+                    tables = self._tables_dev()
+            self._acct.eager_ns += self._acct.work_clock() - t0
             if self.paged:
-                for s in live:
-                    self._ensure_block(s, self._pos[s])
                 self._pools, self._scales, nxt, ms = \
                     self._paged_step_prog()(
                         self.params, self._pools, self._scales, tok,
-                        pos, self._tables_dev(), self._temp_dev,
-                        self._keys_dev)
+                        pos, tables, self._temp_dev, self._keys_dev)
             else:
                 self._caches, nxt, ms = self._step_prog()(
                     self.params, self._caches, tok, pos,
@@ -3627,8 +3733,6 @@ class ContinuousServer:
                 self._moe_buf.append(ms)
             self._cur_dev = nxt
             self._rate.mark(float(len(live)))
-            tracing.instant("serving.dispatch", "serving",
-                            live=len(live))
             lanes = []
             need_sync, retired = not self._async, False
             for s in live:

@@ -7,7 +7,8 @@ schema-versioned JSON bundle at the moment of the fault: the last-N
 trace spans, a full counter + histogram registry snapshot, the resolved
 configuration, the program profile table, and the affected request's
 timeline.  Wired through ``models/serving`` (``_shed_req``,
-``_shed_everything``), ``models/disagg`` (worker failover, degrade),
+``_shed_everything``; a SLOW step's account, kind ``slow_step``, from
+``svc/tracing.StepAccount``), ``models/disagg`` (worker failover, degrade),
 ``svc/fleet`` (autoscale drain) and ``svc/resiliency`` (replay
 exhaustion).
 
@@ -20,11 +21,14 @@ crash (failures count on :func:`dropped_count`).
 
 Knobs (``hpx.flight.*``): ``enabled`` (default on), ``dir``
 (``auto`` = ``<tmpdir>/hpx_tpu_flight``), ``max_bundles`` (oldest
-pruned), ``spans`` (last-N trace spans per bundle).
+pruned, the new bundle's own kind first), ``spans`` (last-N trace
+spans per bundle).
 
-One-shot live capture::
+One-shot live capture, and what a run left behind (newest first; a
+``slow_step`` bundle's line says which part of the step to blame)::
 
     python -m hpx_tpu.svc.flight dump [--out PATH]
+    python -m hpx_tpu.svc.flight --tail 5
 """
 
 from __future__ import annotations
@@ -177,11 +181,16 @@ def _persist(doc: Dict[str, Any]) -> str:
     with open(tmp, "w") as f:
         json.dump(doc, f, indent=1, default=repr)
     os.replace(tmp, path)
-    _prune(d)
+    _prune(d, path, f"-{kind}.json")
     return path
 
 
-def _prune(d: str) -> None:
+def _prune(d: str, new: str, own: str) -> None:
+    """Down to `hpx.flight.max_bundles`, the bundle just written
+    (`new`) kept: the oldest of its own kind (file names ending in
+    `own`) goes first, so that a kind that fires often, as `slow_step`
+    may, evicts its own and not the one shed or failover bundle beside
+    them."""
     keep = max(1, _cfg().get_int("hpx.flight.max_bundles", 8))
     try:
         bundles = sorted(
@@ -190,7 +199,10 @@ def _prune(d: str) -> None:
             key=os.path.getmtime)
     except OSError:
         return
-    for path in bundles[:-keep] if len(bundles) > keep else []:
+    old = [p for p in bundles if p != new]
+    while len(old) >= keep:
+        path = next((p for p in old if p.endswith(own)), old[0])
+        old.remove(path)
         try:
             os.remove(path)
         except OSError:
@@ -249,6 +261,9 @@ def bundle_index(d: Optional[str] = None) -> List[Dict[str, Any]]:
             entry["site"] = trig.get("site")
             entry["rid"] = trig.get("rid")
             entry["schema"] = doc.get("schema")
+            blame = (doc.get("extra") or {}).get("blame")
+            if blame is not None:       # a slow step's account
+                entry["blame"] = blame
         except (OSError, ValueError) as e:
             entry["error"] = repr(e)
         out.append(entry)
@@ -302,7 +317,7 @@ def validate_bundle(doc: Dict[str, Any]) -> List[str]:
 
 # ---------------------------------------------------------------------------
 # one-shot CLI:  python -m hpx_tpu.svc.flight dump [--out PATH]
-#                python -m hpx_tpu.svc.flight --list [--tail N]
+#                python -m hpx_tpu.svc.flight --list | --tail N
 # ---------------------------------------------------------------------------
 
 def _print_index(tail: int) -> int:
@@ -315,9 +330,10 @@ def _print_index(tail: int) -> int:
         if "error" in e:
             print(f"{e['name']}  error={e['error']}")
             continue
+        blame = f"  blame={e['blame']}" if "blame" in e else ""
         print(f"{e['name']}  age={e['age_s']:.1f}s  "
               f"reason={e['reason']}  site={e['site']}  "
-              f"rid={e['rid']}  schema={e['schema']}")
+              f"rid={e['rid']}  schema={e['schema']}{blame}")
     return 0
 
 
@@ -330,13 +346,13 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="print the age-sorted bundle index "
                          "(reason/rid/schema per line) and exit")
     ap.add_argument("--tail", type=int, default=0, metavar="N",
-                    help="with --list: only the newest N bundles")
+                    help="list only the newest N bundles")
     sub = ap.add_subparsers(dest="cmd", required=False)
     dump = sub.add_parser("dump", help="capture one bundle right now")
     dump.add_argument("--out", default=None,
                       help="write here instead of hpx.flight.dir")
     args = ap.parse_args(argv)
-    if args.list_:
+    if args.list_ or args.tail > 0:
         return _print_index(args.tail)
     if args.cmd is None:
         ap.print_usage()

@@ -9,22 +9,31 @@ Every module that memoizes compiled programs funnels through
 ``core.programs.cached_program``; this module installs a build-time
 hook there so each cache MISS is timed (compile wall time) and the
 stored program is replaced by a thin callable proxy that records
-per-call execute wall time into a :class:`metrics.HistogramCounter`.
-Cache HITS return the stored proxy — the hot path pays one
-``perf_counter`` pair per call and nothing else.  When XLA cost
-analysis is available the first call additionally captures FLOPs and
-bytes-accessed per call, yielding achieved GFLOP/s and a roofline
-fraction against ``hpx.prof.peak_gflops`` (0 = infer from the device
-kind; unknown kinds report 0).
+the host's wall time around each call into a
+:class:`metrics.HistogramCounter`. Cache HITS return the stored proxy
+— the hot path pays one ``perf_counter`` pair per call and nothing
+else.
+
+What ``time/execute-s`` is NOT: an execution time. A jitted call is
+asynchronous: it returns once the program is enqueued, so the wall
+around it is the time the runtime HOLDS the host in the dispatch call
+(long where the queue is full or a donated buffer is still in use,
+microseconds where it is not) — the same quantity a serving step's
+account sums as `held` (``svc/tracing.StepAccount``,
+``serving.dispatch`` spans). A program's time on the device is read
+from a profiler trace (``chipbench/trace_reduce.py``). The achieved
+GFLOP/s and roofline fraction this module once derived by dividing
+XLA's cost analysis by that wall are gone for that reason; the device
+tables below stay for :func:`device_ridge`.
 
 Exposure planes:
 
 * ``/programs{locality#N/<tag>#i}/...`` performance counters —
-  ``time/execute-s`` (histogram + derived pNN quantiles),
-  ``count/calls``, ``time/compile-s``, ``gflops/achieved``,
-  ``roofline/fraction`` — so Prometheus rows and Perfetto counter
-  tracks (``hpx.trace.counters`` samples ``/programs*`` by default)
-  come for free from the existing exposition paths.
+  ``time/execute-s`` (the hold: histogram + derived pNN quantiles),
+  ``count/calls``, ``time/compile-s`` — so Prometheus rows and
+  Perfetto counter tracks (``hpx.trace.counters`` samples
+  ``/programs*`` by default) come for free from the existing
+  exposition paths.
 * :func:`profile_table` — a JSON-safe fold serving_bench embeds in the
   ``--metrics-out`` artifact and the flight recorder persists in every
   bundle.
@@ -68,9 +77,9 @@ def _cfg():
     return runtime_config()
 
 
-# rough bf16 peak GFLOP/s per device kind, the roofline denominator
-# when hpx.prof.peak_gflops is 0 (case-insensitive substring match on
-# jax's device_kind; CPU and unknown kinds fall through to 0 = unknown)
+# rough bf16 peak GFLOP/s per device kind (case-insensitive substring
+# match on jax's device_kind; CPU and unknown kinds fall through to
+# 0 = unknown)
 _DEVICE_PEAK_GFLOPS: Tuple[Tuple[str, float], ...] = (
     ("v6e", 918_000.0),
     ("v5p", 459_000.0),
@@ -148,60 +157,38 @@ class ProgramRecord:
     """Accounting for ONE cached program key."""
 
     __slots__ = ("key", "label", "instance", "compiles", "compile_s",
-                 "exec_hist", "flops", "bytes_accessed", "cost_pending",
-                 "counter_names")
+                 "exec_hist", "counter_names")
 
-    def __init__(self, key: Any, label: str, instance: str,
-                 cost_pending: bool) -> None:
+    def __init__(self, key: Any, label: str, instance: str) -> None:
         self.key = key
         self.label = label
         self.instance = instance
         self.compiles = 0
         self.compile_s = 0.0
-        self.exec_hist = HistogramCounter()
-        self.flops: Optional[float] = None          # per call
-        self.bytes_accessed: Optional[float] = None  # per call
-        self.cost_pending = cost_pending
+        self.exec_hist = HistogramCounter()    # the hold of each call
         self.counter_names: List[str] = []
 
     @property
     def calls(self) -> int:
         return self.exec_hist.count
 
-    def achieved_gflops(self) -> float:
-        """FLOPs/call over mean execute seconds, in GFLOP/s (0 when
-        cost analysis is unavailable or nothing ran)."""
-        mean = self.exec_hist.mean()
-        if self.flops is None or mean <= 0.0:
-            return 0.0
-        return self.flops / mean / 1e9
-
-    def roofline_fraction(self, peak_gflops: float) -> float:
-        if peak_gflops <= 0.0:
-            return 0.0
-        return self.achieved_gflops() / peak_gflops
-
 
 class _ProfiledProgram:
     """Callable proxy stored in the program cache in place of the jit
-    program: times each call into the record's histogram; everything
-    else (``lower``, ``clear_cache``, ...) passes through."""
+    program: times the host's wall around each (asynchronous) call
+    into the record's histogram; everything else (``lower``,
+    ``clear_cache``, ...) passes through."""
 
-    __slots__ = ("_prog", "_rec", "_prof")
+    __slots__ = ("_prog", "_rec")
 
-    def __init__(self, prog: Callable, rec: ProgramRecord,
-                 prof: "ProgramProfiler") -> None:
+    def __init__(self, prog: Callable, rec: ProgramRecord) -> None:
         self._prog = prog
         self._rec = rec
-        self._prof = prof
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
-        rec = self._rec
-        if rec.cost_pending:
-            self._prof._cost_analyze(rec, self._prog, args, kwargs)
         t0 = time.perf_counter()
         out = self._prog(*args, **kwargs)
-        rec.exec_hist.record(time.perf_counter() - t0)
+        self._rec.exec_hist.record(time.perf_counter() - t0)
         return out
 
     def __getattr__(self, name: str) -> Any:
@@ -270,23 +257,12 @@ class ProgramProfiler:
 
     def __init__(self, sample_memory: bool = True,
                  mem_interval_s: float = 0.05) -> None:
-        cfg = _cfg()
         self._lock = Mutex()
         self._records: Dict[Any, ProgramRecord] = {}
         self._names: List[str] = []
-        self._cost_enabled = cfg.get_bool("hpx.prof.cost_analysis", True)
-        self.peak_gflops = self._resolve_peak()
-        self.cost_failures = 0
         self._sample_memory = sample_memory
         self.memory = MemoryWatermark(mem_interval_s)
         self._installed = False
-
-    @staticmethod
-    def _resolve_peak() -> float:
-        v = _cfg().get_float("hpx.prof.peak_gflops", 0.0)
-        if v > 0.0:
-            return v
-        return _by_kind(_DEVICE_PEAK_GFLOPS)
 
     # -- the cached_program build hook --------------------------------
 
@@ -299,7 +275,7 @@ class ProgramProfiler:
         rec = self._record_for(key)
         rec.compiles += 1
         rec.compile_s += dt
-        return _ProfiledProgram(prog, rec, self)
+        return _ProfiledProgram(prog, rec)
 
     def _record_for(self, key: Any) -> ProgramRecord:
         with self._lock:
@@ -307,8 +283,7 @@ class ProgramProfiler:
             if rec is None:
                 label = _key_label(key)
                 instance = f"{label}#{len(self._records)}"
-                rec = ProgramRecord(key, label, instance,
-                                    cost_pending=self._cost_enabled)
+                rec = ProgramRecord(key, label, instance)
                 self._records[key] = rec
                 self._register_record(rec)
             return rec
@@ -324,39 +299,8 @@ class ProgramProfiler:
 
         put("count/calls", lambda r=rec: float(r.calls))
         put("time/compile-s", lambda r=rec: r.compile_s)
-        put("gflops/achieved", lambda r=rec: r.achieved_gflops())
-        put("roofline/fraction",
-            lambda r=rec, p=self: r.roofline_fraction(p.peak_gflops))
         rec.counter_names = names
         self._names.extend(names)
-
-    def _cost_analyze(self, rec: ProgramRecord, prog: Callable,
-                      args: tuple, kwargs: dict) -> None:
-        """First-call FLOPs/bytes capture: lower with the concrete
-        call's args (tracing only — donated buffers are untouched) and
-        read XLA cost analysis.  Failures are expected off-TPU; they
-        count on ``cost_failures`` and never reach the caller."""
-        rec.cost_pending = False
-        try:
-            lower = getattr(prog, "lower", None)
-            if lower is None:
-                return
-            lowered = lower(*args, **kwargs)
-            try:
-                ca = lowered.cost_analysis()
-            except Exception:  # noqa: BLE001 — platform-dependent API
-                ca = lowered.compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            if not isinstance(ca, dict):
-                return
-            flops = ca.get("flops")
-            nbytes = ca.get("bytes accessed")
-            rec.flops = float(flops) if flops is not None else None
-            rec.bytes_accessed = \
-                float(nbytes) if nbytes is not None else None
-        except Exception:  # noqa: BLE001 — profiler must not break serving
-            self.cost_failures += 1
 
     # -- lifecycle ----------------------------------------------------
 
@@ -394,8 +338,8 @@ class ProgramProfiler:
             return list(self._records.values())
 
     def profile_table(self) -> Dict[str, Any]:
-        """JSON-safe fold of every record, busiest (total execute
-        seconds) first — the section serving_bench embeds under
+        """JSON-safe fold of every record, the one that held the host
+        longest in all (total seconds inside its calls) first — the section serving_bench embeds under
         ``"programs"`` in the metrics artifact and the flight recorder
         persists per bundle."""
         rows: List[Dict[str, Any]] = []
@@ -413,16 +357,9 @@ class ProgramProfiler:
                 "p50_s": h.quantile(0.5),
                 "p99_s": h.quantile(0.99),
                 "relative_error_bound": h.relative_error_bound(),
-                "flops_per_call": rec.flops,
-                "bytes_per_call": rec.bytes_accessed,
-                "achieved_gflops": rec.achieved_gflops(),
-                "roofline_fraction":
-                    rec.roofline_fraction(self.peak_gflops),
             })
         return {
             "schema": PROFILE_SCHEMA,
-            "peak_gflops": self.peak_gflops,
-            "cost_failures": self.cost_failures,
             "memory": self.memory.snapshot(),
             "programs": rows,
         }

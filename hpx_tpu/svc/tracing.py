@@ -42,6 +42,17 @@ no allocation, no lock, no call. The ring
 itself is append-only under the GIL (no lock on the event path); the
 drop counter is best-effort under concurrent appends.
 
+The step's account (:class:`StepAccount`) is the same design for the
+serving host loop with the profiler OFF: the three choke points that
+write the spans (`ContinuousServer.step()`, the wrapper `_program()`
+hands out, `_wait()`) also add up, on ``time.perf_counter_ns()``, where
+a step's wall went — held in dispatch calls, waiting on reads, the
+host's own work, and how much of that in the eager sections — beside
+what only the process knows (CPU time, collector pauses). One
+fixed-size record a step in a ring of 4,096; a SLOW step writes the
+instant ``serving.slow_step`` and one `svc/flight` bundle. No key turns
+it on or off: it costs a handful of clock reads a step.
+
 Config (``core/config.py`` DEFAULTS, all under ``hpx.trace.*``)::
 
     hpx.trace.enabled          0        start_if_configured() gate
@@ -53,11 +64,14 @@ Config (``core/config.py`` DEFAULTS, all under ``hpx.trace.*``)::
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
+import os
+import statistics
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from .profiling import annotate as _annotate
 
@@ -65,6 +79,7 @@ __all__ = [
     "Tracer", "TaskCtx", "active_tracer", "start_tracing",
     "stop_tracing", "start_if_configured", "trace", "span", "instant",
     "current_span_id", "flow_begin", "flow_end",
+    "mark", "StepAccount", "StepRecord",
 ]
 
 # Ring entries are flat 8-tuples — the cheapest thing CPython can
@@ -519,9 +534,19 @@ def null_span() -> _NullSpan:
 
 
 def instant(name: str, cat: str = "user", **args: Any) -> None:
+    """A point event in the ring (under an active tracer)."""
     tr = _active
     if tr is not None:
         tr.instant(name, cat, **args)
+
+
+def mark(name: str, cat: str = "user", **args: Any) -> None:
+    """A point event in BOTH sinks: the ring's instant, and an empty
+    range on the profiler's clock (a live session shows it where it
+    happened). For rare events; a hot path keeps :func:`instant`."""
+    with _annotate(name, **args):
+        pass
+    instant(name, cat, **args)
 
 
 def flow_begin(name: str, cat: str = "flow") -> Optional[int]:
@@ -538,3 +563,319 @@ def flow_end(fid: Optional[int], name: str, cat: str = "flow") -> None:
     tr = _active
     if tr is not None:
         tr.flow_end(fid, name, cat)
+
+
+# ---------------------------------------------------------------------------
+# the step's account: where a serving step's wall went, profiler off
+# ---------------------------------------------------------------------------
+
+# The yardstick is a BLOCK of 32 steps, not a step: with
+# `hpx.serving.max_async_steps` steps buffered the host runs ahead of
+# the device, most steps take 2-3 ms and the step that next blocks (a
+# read, or an eager op on the full queue) rightly waits for every step
+# the host was ahead (Kimi-Linear on the chip: a median step of 3.3 ms,
+# chunk steps of 330 ms at the same step numbers in every run). A
+# step's PACE is a 32nd of the running median block (the last 8).
+# A step is SLOW where it took over 250 ms and over 4 paces for every
+# program it had to wait behind (at least one): the decode steps the
+# host was ahead when it began, and the prefill chunks enqueued since
+# the last blocking read, its own included (DeepSeek-V2 admits up to
+# four questions in a step, a chunk of 60 ms each; a loader's document
+# of 10k-24k tokens is 38-92 chunks with nothing to read between them,
+# and the step that ends it drains 0.5-1.0 s of them). A block is slow
+# where the last 32 steps together took over 2.5 median blocks and at
+# least 1 s more (all 32 crawl: 94 ms a step would pass the first
+# rule; a closed loop's burst of admissions, 1.4 s where 0.77 s is the
+# median on StarCoder2-3B, does not). In code, not in the config: an
+# admission step with a 512-row chunk is 2.5 times a decode step and
+# must not fire, and nobody should have to tune that.
+SLOW_X_PACE = 4.0
+SLOW_FLOOR_NS = 250_000_000
+BLOCK_STEPS = 32
+BLOCK_X_MEDIAN = 2.5
+BLOCK_FLOOR_NS = 1_000_000_000
+BLOCKS_OVER = 8             # blocks the running median block looks back
+ACCOUNTS_KEPT = 4096
+BUNDLE_EVERY_NS = 5_000_000_000
+BUNDLE_HISTORY = 64         # accounts before the slow one, in a bundle
+
+_now_ns = time.perf_counter_ns
+
+
+class StepRecord(NamedTuple):
+    """One step's account. Times in ns on ``time.perf_counter_ns``;
+    `wall_ns` = `work_ns` + `held_ns` + `waited_ns`. What the process
+    alone knows (CPU time, the collector) covers `gap_ns` + `wall_ns`:
+    from the end of the step before to the end of this one, so that
+    the accounts tile the host's time."""
+
+    n: int                  # step() calls so far (`serving.step`'s n)
+    end_ns: int
+    wall_ns: int
+    work_ns: int            # the host's own Python and eager ops
+    eager_ns: int           # ... of it in the sections that enqueue
+                            # eager, unnamed programs (the decode
+                            # step's operands, the seed token's pick)
+    held_ns: int            # inside dispatch calls of named programs
+    waited_ns: int          # inside blocking device->host reads
+    gap_ns: int             # the caller's, since the step before ended
+                            # (less the server's own reads and
+                            # dispatches there: a `flush()`)
+    top_prog: str           # the program whose call held longest
+    top_held_ns: int
+    dispatches: int
+    reads_draining: int     # reads with no step queued behind them
+    reads_overlapped: int
+    lead: int               # decode steps dispatched and not yet read
+                            # when the step began: the host's lead
+    owed: int               # prefill chunks enqueued since the last
+                            # read, this step's included
+    admits: int
+    chunks: int
+    live: int               # live slots when the step ended
+    compiles: int           # program-cache misses inside the step
+    cpu_thread_ns: int      # time.thread_time_ns
+    cpu_process_ns: int     # time.process_time_ns: every thread's
+    gc_ns: int              # collector pauses
+    gc_full: int            # ... of which full (generation 2) runs
+    slow: str               # "", "step" or "block"
+
+    def blame(self) -> str:
+        """The part of a slow step to look at first: `held` (in
+        `top_prog`'s call), `waited`, `caller` (the gap before the
+        step), or the host's own work, told apart as `collector`
+        (pauses make half of it), `eager` (half of it lies in the
+        sections whose eager ops are unnamed programs: the runtime
+        holds the host THERE on a full queue, as it holds it in a
+        named program's call), `off_cpu` (the thread's CPU time, gap
+        and all, is under half of it: it slept, was switched out or
+        faulted, or an eager op outside those sections met a full
+        queue) or `computing`."""
+        part, ns = max((("held", self.held_ns), ("waited", self.waited_ns),
+                        ("work", self.work_ns), ("caller", self.gap_ns)),
+                       key=lambda kv: kv[1])
+        if part != "work":
+            return part
+        if 2 * self.gc_ns >= ns:
+            return "collector"
+        if 2 * self.eager_ns >= ns:
+            return "eager"
+        return "off_cpu" if 2 * self.cpu_thread_ns < ns else "computing"
+
+
+# what a slow block sums over its steps' records
+_SUMMED = tuple(f for f in StepRecord._fields if f not in (
+    "n", "end_ns", "top_prog", "top_held_ns", "lead", "owed", "live",
+    "slow"))
+
+
+class _GcClock:
+    """The collector's pauses, summed by one ``gc.callbacks`` entry
+    installed once a process (an account reads the sums' growth)."""
+
+    def __init__(self) -> None:
+        self.ns = self.full = self._t0 = 0
+        self._installed = False
+
+    def install(self) -> None:
+        if not self._installed:
+            self._installed = True
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._t0 = _now_ns()
+        else:
+            self.ns += _now_ns() - self._t0
+            self.full += info.get("generation") == 2
+
+
+_GC = _GcClock()
+
+
+class StepAccount:
+    """Where each step's wall went, for ONE server: `begin()` and
+    `end()` from `step()`, `dispatched()` after every named program's
+    call, `waited_ns` grown by every blocking read and `eager_ns` by
+    the sections that enqueue eager ops. The last `ACCOUNTS_KEPT`
+    records stay in a ring (`records()`); a slow step (or one that
+    ends a slow block of 32) is counted (`slow`, `slow_ns`), marked in
+    both trace sinks, logged once and handed to the flight recorder
+    (one bundle in 5 s). Reporting never raises into the step
+    (`dropped` counts the reports that failed)."""
+
+    def __init__(self) -> None:
+        _GC.install()
+        self._ring: deque = deque(maxlen=ACCOUNTS_KEPT)
+        self._block: deque = deque(maxlen=BLOCK_STEPS)
+        self._block_ns = 0
+        self._blocks: deque = deque(maxlen=BLOCKS_OVER)
+        self._median_block = self._steps = self._lead = self._owed = 0
+        self._between = 0
+        self._quiet = 0             # steps since the last slow verdict
+        self._bundle_ns = -BUNDLE_EVERY_NS
+        self.slow = 0               # /serving{...}/steps/slow
+        self.slow_ns = 0            # ... /steps/slow-seconds
+        self.dropped = 0
+        self._t0 = 0
+        self._end: Optional[int] = None
+        self._seen: Tuple[int, ...] = ()
+        self.held_ns = self.waited_ns = self.eager_ns = self.dispatches = 0
+        self.top_prog, self.top_ns = "", 0
+
+    # -- the choke points (hot: no allocation) --------------------------
+
+    def begin(self, lead: int = 0) -> None:
+        self._lead = lead
+        if self._end is None:       # the first step: nothing before it
+            self._seen = (time.thread_time_ns(), time.process_time_ns(),
+                          _GC.ns, _GC.full, 0, 0, 0, 0, 0)
+            self._end = _now_ns()
+        # what the choke points saw since end(): the server's own reads
+        # and dispatches BETWEEN steps (a caller's `flush()`), not the
+        # caller's time
+        self._between = self.held_ns + self.waited_ns
+        self.held_ns = self.waited_ns = self.eager_ns = self.dispatches = 0
+        self.top_prog, self.top_ns = "", 0
+        self._t0 = _now_ns()
+
+    def work_clock(self) -> int:
+        """A clock of the host's own work: it stands still inside
+        dispatch calls and reads (`eager_ns` is a difference of two)."""
+        return _now_ns() - self.held_ns - self.waited_ns
+
+    def dispatched(self, prog: str, ns: int) -> None:
+        self.held_ns += ns
+        self.dispatches += 1
+        if ns > self.top_ns:
+            self.top_prog, self.top_ns = prog, ns
+
+    def end(self, n: int, live: int, admits: int = 0, chunks: int = 0,
+            compiles: int = 0, draining: int = 0, overlapped: int = 0
+            ) -> StepRecord:
+        """Close the step's record. `admits` .. `overlapped`: the
+        server's running totals of admissions, chunks, program-cache
+        misses and reads (draining, overlapped); like the process's
+        clocks they are read once, here, and a record holds their
+        growth since the record before."""
+        now = _now_ns()
+        wall, gap = now - self._t0, self._t0 - self._end - self._between
+        cpu, cpu_all, gc_ns, gc_full, admits0, chunks0, compiles0, \
+            draining0, overlapped0 = self._seen
+        self._seen = seen = (
+            time.thread_time_ns(), time.process_time_ns(), _GC.ns,
+            _GC.full, admits, chunks, compiles, draining, overlapped)
+        self._end = now
+        chunks -= chunks0
+        compiles -= compiles0
+        draining -= draining0
+        overlapped -= overlapped0
+        # a read leaves nothing enqueued before it unfinished
+        owed = self._owed + chunks
+        self._owed = 0 if draining or overlapped else owed
+        # a step that follows live slots owes its caller's gap too (the
+        # operator's `decode_stall`); an idle server's gap is nobody's
+        spent = wall + (gap if self._ring and self._ring[-1].live else 0)
+        # a step that built a program is slow for a reason the
+        # programs/cache-misses counter already gives: not judged, and
+        # kept out of the median and the block
+        slow = "" if compiles else self._verdict(spent, self._lead + owed)
+        rec = StepRecord(
+            n, now, wall, wall - self.held_ns - self.waited_ns,
+            self.eager_ns, self.held_ns, self.waited_ns, gap,
+            self.top_prog, self.top_ns, self.dispatches, draining,
+            overlapped, self._lead, owed, admits - admits0, chunks, live,
+            compiles, seen[0] - cpu, seen[1] - cpu_all, seen[2] - gc_ns,
+            seen[3] - gc_full, slow)
+        self._ring.append(rec)
+        self.held_ns = self.waited_ns = 0
+        if slow:
+            try:
+                self._report(rec, spent)
+            except Exception:  # noqa: BLE001 — telemetry never raises
+                self.dropped += 1       # into the serving loop
+        return rec
+
+    # -- the verdict ----------------------------------------------------
+
+    def _verdict(self, spent: int, queued: int = 0) -> str:
+        """`queued`: the decode steps the host was ahead plus the
+        chunks enqueued since the last read: what the step may rightly
+        wait behind."""
+        block = self._block
+        if len(block) == BLOCK_STEPS:
+            self._block_ns -= block[0]
+        block.append(spent)
+        self._block_ns += spent
+        self._steps += 1
+        self._quiet += 1
+        verdict, median = "", self._median_block
+        if not median:
+            pass                    # no block to hold anything against
+        elif spent > SLOW_FLOOR_NS and spent > (
+                SLOW_X_PACE * max(1, queued) * median / BLOCK_STEPS):
+            verdict = "step"
+        elif self._quiet >= BLOCK_STEPS and len(self._blocks) > 1 \
+                and self._block_ns > BLOCK_X_MEDIAN * median \
+                and self._block_ns - median >= BLOCK_FLOOR_NS:
+            verdict = "block"
+        if verdict:
+            self._quiet = 0
+        if self._steps % BLOCK_STEPS == 0:
+            # one more whole block; the median of the blocks BEFORE it
+            # judged its steps
+            self._blocks.append(self._block_ns)
+            self._median_block = int(statistics.median(self._blocks))
+        return verdict
+
+    def _report(self, rec: StepRecord, spent: int) -> None:
+        pace, ring = self._median_block // BLOCK_STEPS, list(self._ring)
+        if rec.slow == "step":
+            over, whole = spent - pace, rec
+        else:
+            # a slow block is judged, and blamed, as the sum of its steps
+            over = self._block_ns - self._median_block
+            last = ring[-BLOCK_STEPS:]
+            top = max(last, key=lambda r: r.top_held_ns)
+            whole = rec._replace(
+                top_prog=top.top_prog, top_held_ns=top.top_held_ns,
+                **{f: sum(getattr(r, f) for r in last) for f in _SUMMED})
+        self.slow += 1
+        self.slow_ns += over
+        blame = whole.blame()
+        mark("serving.slow_step", "serving", n=rec.n, kind=rec.slow,
+             blame=blame, ms=spent // 1_000_000)
+        if rec.end_ns - self._bundle_ns < BUNDLE_EVERY_NS:
+            return
+        self._bundle_ns = rec.end_ns
+        from . import flight
+        from .logging import get_logger
+        extra = {
+            "blame": blame, "kind": rec.slow, "fields": StepRecord._fields,
+            "pace_ms": pace / 1e6, "over_ms": over / 1e6,
+            "median_block_ms": self._median_block / 1e6,
+            "block_ms": self._block_ns / 1e6,
+            "slow": rec._asdict(), "block": whole._asdict(),
+            "before": [list(r) for r in ring[-BUNDLE_HISTORY - 1:-1]],
+            "cores": (len(os.sched_getaffinity(0))
+                      if hasattr(os, "sched_getaffinity")
+                      else os.cpu_count()),
+        }
+        path = flight.record_fault("slow_step", site="serving",
+                                   extra=extra)
+        get_logger("serving").warning(
+            "slow step n=%d (%s): %.0f ms where a step's pace is %.1f ms "
+            "behind %d queued program(s); look at %s first (held "
+            "%.0f ms, longest in %r; waited %.0f; work %.0f, of it in "
+            "eager sections %.0f; caller %.0f; collector %.0f; thread on "
+            "a core %.0f); bundle %s", rec.n, rec.slow,
+            (spent if whole is rec else self._block_ns) / 1e6, pace / 1e6,
+            rec.lead + rec.owed, blame, whole.held_ns / 1e6,
+            whole.top_prog, whole.waited_ns / 1e6, whole.work_ns / 1e6,
+            whole.eager_ns / 1e6, whole.gap_ns / 1e6, whole.gc_ns / 1e6,
+            whole.cpu_thread_ns / 1e6, path)
+
+    # -- reading ----------------------------------------------------------
+
+    def records(self) -> List[StepRecord]:
+        return list(self._ring)

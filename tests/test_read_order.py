@@ -1,7 +1,9 @@
 """Dispatch before read in `ContinuousServer.step()`: every program a
 step enqueues is enqueued before the step's first blocking
 device->host read, and a read never takes the newest dispatched step —
-read from the span ring (`hpx.trace.enabled`), event by event.
+read from the span ring (`hpx.trace.enabled`), event by event (a
+dispatch: the begin of the `serving.dispatch` span around the call of
+the step's program).
 
   * a step that admits reads the first token AFTER its decode dispatch
   * the read that lands a max_new retirement of step t begins after
@@ -44,6 +46,7 @@ PH, NAME, ARGS = 0, 1, 7
 READS = ("serving.first_token.wait", "serving.flush.wait",
          "serving.flush.moe_stats.wait")
 DISPATCH = "serving.dispatch"
+STEP_PROGS = ("cb_step", "pg_step")     # the decode step's, dense / paged
 MODES = ["dense", "paged", "mixed", "hybrid"]
 
 
@@ -126,8 +129,12 @@ def _step(srv, tr):
                   if e[PH] in "Bi"]
 
 
-def _names(events, keep=READS + (DISPATCH,)):
-    return [n for n, _ in events if n in keep]
+def _names(events):
+    """The reads, and the begin of the `serving.dispatch` span whose
+    `prog` is the decode step's (every other program's span: an
+    admission's chunks, probe and splice, is left out)."""
+    return [n for n, a in events if n in READS
+            or (n == DISPATCH and a.get("prog") in STEP_PROGS)]
 
 
 def _behind(events, name):
