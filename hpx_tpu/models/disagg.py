@@ -70,7 +70,7 @@ from ..svc import metrics as _metrics
 from ..svc.resiliency import sync_replay
 from .serving import (ContinuousServer, RequestShedError,
                       ServerClosedError, _normalize_key)
-from .transformer import TransformerConfig, _sample_row
+from .transformer import TransformerConfig
 
 __all__ = [
     "DecodeWorker",
@@ -214,16 +214,13 @@ class PrefillWorker(_WorkerRing):
             seed: Optional[int] = None
             finished = job.done >= plen
             if finished:
-                tok = jnp.asarray([[job.prompt[-1]]], jnp.int32)
-                job.caches, logits = eng._probe_prog()(
-                    eng.params, job.caches, tok,
-                    jnp.asarray(plen - 1, jnp.int32))
-                if job.temperature > 0.0:
-                    # generate()'s tok0 draw: position plen-1, row 0
-                    seed = int(_sample_row(logits[0], job.temperature,
-                                           job.key, plen - 1, 0))
-                else:
-                    seed = int(jnp.argmax(logits[0]))
+                # the probe picks generate()'s tok0 (position plen-1,
+                # row 0) inside its program; the engine's own lanes,
+                # which it also returns, serve nothing here
+                job.caches, *_, tok0 = eng._probe(
+                    job.caches, job.prompt[-1], plen - 1, 0,
+                    job.temperature, job.key)
+                seed = int(tok0)
                 segs.append(self._emit(rid, job, job.emitted, plen,
                                        plen))
                 del self._jobs[rid]

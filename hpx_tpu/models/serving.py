@@ -122,7 +122,6 @@ from .transformer import (
     _layer,
     _logits,
     _pick_row,
-    _sample_row,
     _tree_key,
 )
 
@@ -187,29 +186,50 @@ class DeadlineExceededError(RequestShedError):
         self.deadline_s = deadline_s
 
 
-def _normalize_key(key):
-    """Coerce a user PRNG key to the raw uint32 layout the batched
-    sampler needs: step() stacks the per-slot keys with jnp.stack, which
-    fails (or silently mis-samples) on a mix of typed jax.random.key
-    arrays and raw PRNGKey arrays. Typed keys are unwrapped via
-    key_data; raw uint32 arrays pass through; anything else is rejected
-    here at submit() instead of surfacing as a stack/shape error deep in
-    step()."""
+def _raw_key_shape() -> Tuple[int, ...]:
+    """Shape of a raw key of the default PRNG implementation (traced,
+    never run: no program, no transfer)."""
+    return jax.eval_shape(jax.random.PRNGKey, 0).shape
+
+
+def _normalize_key(key) -> np.ndarray:
+    """Bring a user PRNG key to host NumPy in the raw uint32 layout the
+    batched sampler needs, once, at submit(): the per-slot keys are ONE
+    `uint32[slots, 2]` host array whose rows an admission's program
+    sets on the device, so a typed jax.random.key array is unwrapped
+    via key_data (the one blocking read a key costs, outside step()),
+    a raw uint32 array passes through, and anything else is rejected
+    here instead of surfacing as a shape error deep in step()."""
     try:
-        arr = jnp.asarray(key)
+        if isinstance(key, jax.Array) and jnp.issubdtype(
+                key.dtype, jax.dtypes.prng_key):
+            key = jax.random.key_data(key)
+        arr = np.asarray(key)
     except (TypeError, ValueError) as e:
         raise ValueError(
             f"key is not a PRNG key (got {type(key).__name__}); pass "
             "jax.random.key(seed) or jax.random.PRNGKey(seed)") from e
-    if jnp.issubdtype(arr.dtype, jax.dtypes.prng_key):
-        arr = jax.random.key_data(arr)
-    raw = jax.random.PRNGKey(0)
-    if arr.shape != raw.shape or arr.dtype != raw.dtype:
+    raw = _raw_key_shape()
+    if arr.shape != raw or arr.dtype != np.uint32:
         raise ValueError(
             "key must be a typed jax.random.key(...) or a raw uint32 "
-            f"jax.random.PRNGKey(...) of shape {raw.shape}; got shape "
+            f"jax.random.PRNGKey(...) of shape {raw}; got shape "
             f"{arr.shape} dtype {arr.dtype}")
     return arr
+
+
+def _seed_lane(logits_row, cur, temp, keys, slot, temperature, key,
+               pos):
+    """An admission's part of the per-slot vectors, inside the program
+    that made `logits_row`: the seed token is `_pick_row` at the last
+    prompt position `pos` (the step's and the verify window's own
+    pick: argmax at temperature 0, `_sample_row`'s draw at row 0
+    otherwise, i.e. generate()'s tok0), and `slot`'s lane of the
+    feedback tokens, the temperatures and the keys is set to the
+    request's. Returns the three vectors and the token."""
+    tok0 = _pick_row(logits_row, key, temperature, pos).astype(cur.dtype)
+    return (cur.at[slot].set(tok0), temp.at[slot].set(temperature),
+            keys.at[slot].set(key), tok0)
 
 
 def _resolve_buckets(spec, chunk: int) -> Tuple[int, ...]:
@@ -676,7 +696,7 @@ class _PendingPrefill:
     done: int                      # prompt tokens already in scratch
     seq: int                       # admission order (FIFO tiebreak)
     pt: Optional[PageTable] = None  # paged: blocks held for the request
-    trow: Any = None               # paged: device [maxb] table row
+    trow: Any = None               # paged: host [maxb] table row
     wrow: Any = None               # paged: splice WRITE rows, one a
                                    # block group (matched prefix
                                    # entries point at trash)
@@ -1033,8 +1053,11 @@ class ContinuousServer:
         self._slot_req: List[Optional[_Request]] = [None] * slots
         self._pos = [0] * slots         # next write position per slot
         self._cur = [0] * slots         # token to feed next, per slot
-        self._temp = [0.0] * slots      # per-slot temperature
-        self._key = [jax.random.PRNGKey(0)] * slots
+        # per-slot sampling state, host NumPy: temperatures, and raw
+        # keys (zeros for a request without one: greedy never reads it)
+        self._temp = np.zeros((slots,), np.float32)
+        self._no_key = np.zeros(_raw_key_shape(), np.uint32)
+        self._key = np.zeros((slots,) + self._no_key.shape, np.uint32)
         self._queue: deque = deque()
         self._done: Dict[int, List[int]] = {}
         self._next_rid = 0
@@ -1058,9 +1081,14 @@ class ContinuousServer:
         self._acct = tracing.StepAccount()
         self._rid: Optional[int] = None
         self._admits = 0                # requests `_admit` took up
+        # device mirrors of the three per-slot vectors (`_feedback`,
+        # `_lanes`): the
+        # step's output feeds back as `_cur_dev`, an admission's probe
+        # sets its slot's lane of all three; None: stale, rebuilt from
+        # the host's by one transfer each
         self._cur_dev = None            # [slots] int32 token feedback
         self._temp_dev = None           # [slots] f32 (with _keys_dev)
-        self._keys_dev = None
+        self._keys_dev = None           # [slots, 2] uint32
         # observability
         self._chunks = 0                # prefill chunk dispatches
         self._chunk_rows = 0            # prompt tokens they computed
@@ -1451,23 +1479,82 @@ class ContinuousServer:
         return self._program(ck, build)
 
     def _probe_prog(self):
-        """Seed-logits probe: rerun the LAST prompt token at its own
-        position (an idempotent K/V rewrite — same bytes) and return
-        its logits. One program serves every prompt length, so the
-        chunk programs never need a logits variant per bucket. On a
-        recurrent model the chunks stop one token short
-        (`_PendingPrefill.hold`) and the probe is that token's one and
-        only pass."""
+        """Seed probe: rerun the LAST prompt token at its own position
+        (an idempotent K/V rewrite — same bytes) and pick the seed
+        token from its logits (`_seed_lane`), which never leave the
+        program: what comes back is the scratch, the three per-slot
+        vectors with the slot's lane set, and the token. One program
+        serves every prompt length, so the chunk programs never need a
+        logits variant per bucket. On a recurrent model the chunks
+        stop one token short (`_PendingPrefill.hold`) and the probe is
+        that token's one and only pass."""
         cfg, smax = self.cfg, self.smax
         ck = ("cb_probe", cfg, smax, self.mesh, _tree_key(self.params))
 
         def build():
-            def probe(params, caches, tok, pos):
+            lane_sh = self._lane_sh()
+
+            def probe(params, caches, tok, pos, cur, temp, keys, slot,
+                      temperature, key):
                 caches, lg = _decode_window(params, caches, tok, pos,
                                             cfg, need_logits=True)
-                return caches, lg[:, -1]
+                out = _seed_lane(lg[0, -1], cur, temp, keys, slot,
+                                 temperature, key, pos)
+                if lane_sh is not None:
+                    # the placement the step hands its vector back in
+                    out = tuple(jax.lax.with_sharding_constraint(v, sh)
+                                for v, sh in zip(out[:3], lane_sh)
+                                ) + out[3:]
+                return (caches,) + out
             return jax.jit(probe, donate_argnums=(1,))
         return self._program(ck, build)
+
+    def _probe(self, caches, tok: int, pos: int, slot: int = 0,
+               temperature: float = 0.0, key=None):
+        """Dispatch the probe on `tok` at row `pos` of the b=1 scratch:
+        (scratch, feedback tokens, temperatures, keys, seed token) with
+        `slot`'s lanes set to the pick and the request's temperature
+        and key. Every operand is host NumPy or already on the device:
+        no eager program beside the named one."""
+        return self._probe_prog()(
+            self.params, caches, np.asarray([[tok]], np.int32),
+            np.int32(pos), self._feedback(), *self._lanes(),
+            np.int32(slot),
+            np.float32(temperature),
+            self._no_key if key is None else key)
+
+    def _lane_sh(self):
+        """Placement of the per-slot vectors (1-d, and the keys' 2-d)
+        under the mesh: slots over dp, as the paged step's shard_map
+        takes and returns them. None on a single device."""
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        one = NamedSharding(self.mesh, P("dp"))
+        return one, one, NamedSharding(self.mesh, P("dp", None))
+
+    def _feedback(self):
+        """The feedback tokens on the device, one lane a slot: the
+        newest step's output with the lanes admissions set since, or,
+        where that mirror is stale (None: nothing dispatched yet, a
+        recovery, a speculative commit, `admit_prefilled`), the host's
+        `_cur` by ONE transfer and no program."""
+        if self._cur_dev is None:
+            self._cur_dev = jax.device_put(
+                np.asarray(self._cur, np.int32),
+                self.mesh and self._lane_sh()[0])
+        return self._cur_dev
+
+    def _lanes(self):
+        """(temperatures, keys) on the device, one lane a slot, kept
+        like `_feedback()`'s vector: an admission's probe sets its
+        lane, a stale mirror is the host's array by one transfer."""
+        if self._temp_dev is None:
+            sh = self._lane_sh() or (None,) * 3
+            # copies: the host's arrays change in place at admissions
+            self._temp_dev = jax.device_put(self._temp.copy(), sh[1])
+            self._keys_dev = jax.device_put(self._key.copy(), sh[2])
+        return self._temp_dev, self._keys_dev
 
     def _splice_prog(self):
         """Copy the b=1 scratch cache into one slot's rows — ALL smax
@@ -1898,8 +1985,8 @@ class ContinuousServer:
             new, copied = self._alloc.fork(bid)
             if copied:
                 self._pools, self._scales = self._copy_block_prog()(
-                    self._pools, self._scales, jnp.int32(bid),
-                    jnp.int32(new))
+                    self._pools, self._scales, np.int32(bid),
+                    np.int32(new))
                 pt.replace_block(bi, new)
 
     def _ensure_block(self, slot: int, pos: int) -> None:
@@ -2083,11 +2170,10 @@ class ContinuousServer:
                 self._alloc.decref(bid)
             return 0
         for i, bid in enumerate(bids):
-            blk = jnp.asarray(rows[:, :, i * bs:(i + 1) * bs])
-            sblk = (None if scs is None
-                    else jnp.asarray(scs[:, :, i]))
+            blk = rows[:, :, i * bs:(i + 1) * bs]
+            sblk = None if scs is None else scs[:, :, i]
             self._pools, self._scales = self._tier_restore_prog()(
-                self._pools, self._scales, jnp.int32(bid), blk, sblk)
+                self._pools, self._scales, np.int32(bid), blk, sblk)
         # republish: the tree takes its reference on the promoted
         # blocks (refcount 2 = tree + our lease, same as a hot match)
         self._radix.insert(req.prompt[:matched + n * bs],
@@ -2284,7 +2370,7 @@ class ContinuousServer:
         first (`svc/tracing.StepRecord`: wall = work + held in dispatch
         calls + waited on reads, the program that held longest, the
         step's dispatches, reads, admissions and chunks, how much of
-        the work lay in the sections of eager ops, and the process's
+        the work lay in the decode step's operands, and the process's
         own view: CPU time, collector pauses; `slow` where the step, or
         the 32 steps it ends, stood out — the /serving{...}/steps/*
         counters count those, and each leaves a `svc/flight` bundle,
@@ -2522,13 +2608,11 @@ class ContinuousServer:
         into the b=1 scratch `caches` (width 0: the probe's program,
         its logits unread)."""
         if not width:
-            return self._probe_prog()(
-                self.params, caches, jnp.asarray([[seq[done]]], jnp.int32),
-                jnp.asarray(done, jnp.int32))[0]
+            return self._probe(caches, seq[done], done)[0]
         toks = seq[done:done + n] + [0] * (width - n)
         return self._chunk_prog(width)(
-            self.params, caches, jnp.asarray([toks], jnp.int32),
-            jnp.asarray(done, jnp.int32), jnp.asarray(n, jnp.int32))
+            self.params, caches, np.asarray([toks], np.int32),
+            np.int32(done), np.int32(n))
 
     def _fresh_scratch(self):
         """An empty b=1 prefill scratch, one entry a layer, made by ONE
@@ -2598,14 +2682,13 @@ class ContinuousServer:
         pt.tokens = plen
         self._prefill_saved += matched
         self._prefill_computed += plen - matched
-        row = pt.as_row(self._maxb, self._trash)
-        trow = jnp.asarray(row)
+        trow = pt.as_row(self._maxb, self._trash)
         # the splice's WRITE row: radix-matched prefix blocks are
         # shared, so their entries redirect to the trash block — the
         # splice never rewrites them (see _paged_splice_prog)
-        wnp = row.copy()
+        wnp = trow.copy()
         wnp[:matched // self.block_size] = self._trash
-        wrow = (jnp.asarray(wnp),)
+        wrow = (wnp,)
         if self._recurrent:
             # the request's state starts from zeros in its scratch and
             # the splice overwrites the slot's row whole: the reset
@@ -2624,7 +2707,7 @@ class ContinuousServer:
             with tracing.span("serving.prefix_gather", "serving",
                               rid=req.rid, matched=matched, plen=plen):
                 caches = self._paged_gather_prog()(
-                    self._pools, self._scales, trow, jnp.int32(matched))
+                    self._pools, self._scales, trow, np.int32(matched))
             return _PendingPrefill(req=req, slot=slot, caches=caches,
                                    done=matched, seq=self._pf_seq, pt=pt,
                                    trow=trow, wrow=wrow)
@@ -2641,8 +2724,7 @@ class ContinuousServer:
             for bid in pt.blocks:
                 self._alloc.decref(bid)
             raise
-        wrow += (jnp.asarray(wt.as_linear_row(self._maxb,
-                                              self._wtrash)),)
+        wrow += (wt.as_linear_row(self._maxb, self._wtrash),)
         return _PendingPrefill(req=req, slot=slot,
                                caches=self._fresh_scratch(),
                                done=0, seq=self._pf_seq, pt=pt,
@@ -2675,65 +2757,52 @@ class ContinuousServer:
                 p.flow = tracing.flow_begin("serving.prefill_chunks")
 
     def _finish_prefill(self, p: _PendingPrefill) -> None:
-        """Prompt fully chunked: probe the last position's logits,
-        splice the scratch into the slot (dense rows / paged blocks),
-        seed the first generated token, go live. The seed token is
-        picked on the device and goes into the feedback vector as a
-        device value; the host reads it (`_land_seeds`) once this
-        step's decode is enqueued — at once only where its VALUE
-        decides what happens before that dispatch (an eos check, an
-        instant retire, a speculative step's host-fed drafts, a
-        synchronous server)."""
+        """Prompt fully chunked: probe the last position, which picks
+        the seed token and sets the slot's lane of the per-slot
+        vectors on the device (`_probe_prog`), splice the scratch into
+        the slot (dense rows / paged blocks), go live. An admission
+        enqueues its named programs and nothing else: every operand
+        here is host NumPy. The host reads the seed token
+        (`_land_seeds`) once this step's decode is enqueued — at once
+        only where its VALUE decides what happens before that dispatch
+        (an eos check, an instant retire, a speculative step's host-fed
+        drafts, a synchronous server)."""
         req, slot = p.req, p.slot
         plen = len(req.prompt)
-        tok = jnp.asarray([[req.prompt[-1]]], jnp.int32)
-        caches, logits = self._probe_prog()(
-            self.params, p.caches, tok,
-            jnp.asarray(plen - 1, jnp.int32))
+        caches, self._cur_dev, self._temp_dev, self._keys_dev, tok0 = \
+            self._probe(p.caches, req.prompt[-1], plen - 1, slot,
+                        req.temperature, req.key)
         if p.flow is not None:
             tracing.flow_end(p.flow, "serving.prefill_chunks")
             p.flow = None
         if self.paged:
             self._pools, self._scales = self._paged_splice_prog()(
                 self._pools, self._scales, caches, p.wrow,
-                jnp.asarray(slot, jnp.int32))
+                np.int32(slot))
             self._tables[slot], self._wtables[slot] = p.pt, p.wt
         else:
             self._caches = self._splice_prog()(
-                self._caches, caches, jnp.asarray(slot, jnp.int32))
+                self._caches, caches, np.int32(slot))
         del self._pending[slot]
-        # eager, unnamed programs (the slice, the pick, the scatter): on
-        # the step's account as `eager_ns`, for a full queue holds the
-        # host here as it does in a named program's call
-        t0 = self._acct.work_clock()
-        if req.temperature > 0.0:
-            # generate()'s tok0 draw: position plen-1, row 0
-            tok0 = _sample_row(logits[0], req.temperature, req.key,
-                               plen - 1, 0)
-        else:
-            tok0 = jnp.argmax(logits[0])
-        at_once = (req.eos_id is not None or req.max_new == 1
-                   or self._spec or not self._async)
-        if self._cur_dev is None and not at_once:
-            self._cur_dev = jnp.asarray(self._cur, jnp.int32)
-        if self._cur_dev is not None:
-            self._cur_dev = self._cur_dev.at[slot].set(tok0)
-        self._acct.eager_ns += self._acct.work_clock() - t0
         req.sent = 1
         self._slot_req[slot] = req
         self._pos[slot] = plen
-        self._temp[slot] = req.temperature
-        self._key[slot] = (req.key if req.key is not None
-                           else jax.random.PRNGKey(0))
-        self._temp_dev = None          # rebuilt with keys next step
+        self._set_lane(slot, req)
         if self._spec:
             self._slot_k[slot] = self._spec_k     # fresh adaptive k
             self._slot_acc[slot] = 1.0
             if self._draft_params is not None:
                 self._draft_prefill(slot, req.prompt)
         self._seeds.append((req, slot, tok0))
-        if at_once:
+        if (req.eos_id is not None or req.max_new == 1 or self._spec
+                or not self._async):
             self._land_seeds(behind=0)
+
+    def _set_lane(self, slot: int, req: "_Request") -> None:
+        """The host's copy of `slot`'s sampling state (what `_lanes`
+        sends the device after a recovery)."""
+        self._temp[slot] = req.temperature
+        self._key[slot] = self._no_key if req.key is None else req.key
 
     def _wait(self, name: str, behind: int, **args):
         """The span around ONE blocking device->host read. `behind`:
@@ -2850,7 +2919,7 @@ class ContinuousServer:
             raise
         pt.tokens = plen
         self._admit_defers.pop(req.rid, None)
-        trow = (jnp.asarray(pt.as_row(self._maxb, self._trash)),)
+        trow = (pt.as_row(self._maxb, self._trash),)
         nkv, hd = self.cfg.kv_heads, self.cfg.head_dim
         rows = req.xfer_rows
         scratch = []
@@ -2863,22 +2932,22 @@ class ContinuousServer:
                 jnp.asarray(rows[li, 1], self.cfg.dtype))
             scratch.append((k, v))
         self._pools, self._scales = self._paged_splice_prog()(
-            self._pools, self._scales, scratch, trow,
-            jnp.asarray(slot, jnp.int32))
+            self._pools, self._scales, scratch, trow, np.int32(slot))
         self._tables[slot] = pt
         req.xfer_rows = None           # host copy no longer needed
         tok0 = int(req.xfer_seed)
+        # the seed token is the host's: land what is in flight, so that
+        # the host's feedback tokens are the newest, set the slot's
+        # lanes there and let `_feedback` / `_lanes` send the arrays
+        if self._buf or self._seeds:
+            self._flush()
         req.tokens.append(tok0)
         req.sent = 1
         self._slot_req[slot] = req
         self._pos[slot] = plen
         self._cur[slot] = tok0
-        if self._cur_dev is not None:
-            self._cur_dev = self._cur_dev.at[slot].set(tok0)
-        self._temp[slot] = req.temperature
-        self._key[slot] = (req.key if req.key is not None
-                           else jax.random.PRNGKey(0))
-        self._temp_dev = None          # rebuilt with keys next step
+        self._set_lane(slot, req)
+        self._cur_dev = self._temp_dev = None
         if self._spec:
             self._slot_k[slot] = self._spec_k
             self._slot_acc[slot] = 1.0
@@ -2949,9 +3018,8 @@ class ContinuousServer:
             toks = prompt[done:done + n] + [0] * (width - n)
             self._draft_caches = self._draft_chunk_prog(width)(
                 self._draft_params, self._draft_caches,
-                jnp.asarray([toks], jnp.int32),
-                jnp.asarray(done, jnp.int32),
-                jnp.asarray(slot, jnp.int32))
+                np.asarray([toks], np.int32), np.int32(done),
+                np.int32(slot))
             done += n
 
     def _prompt_drafts(self, live: List[int],
@@ -2984,13 +3052,13 @@ class ContinuousServer:
         the causal mask can ever expose them. Returns [slots,
         1 + kbatch] int32 (column 0 = the committed cur tokens)."""
         prog = self._draft_step_prog()
-        tok = jnp.asarray(self._cur, jnp.int32)
-        pos = jnp.asarray(self._pos, jnp.int32)
+        tok = self._feedback()
+        pos = np.array(self._pos, np.int32)
         cols = [tok]
         for i in range(kbatch + 1):
             self._draft_caches, tok = prog(
                 self._draft_params, self._draft_caches, tok,
-                jnp.minimum(pos + i, self.smax - 1))
+                np.minimum(pos + i, self.smax - 1))
             if i < kbatch:
                 cols.append(tok)
         return jnp.stack(cols, axis=1)
@@ -3052,7 +3120,7 @@ class ContinuousServer:
                 for s, d in self._prompt_drafts(live, kcap).items():
                     mat[s, 1:1 + len(d)] = d
                     kvec_host[s] = len(d)
-                toks = jnp.asarray(mat)
+                toks = mat
         drafted = sum(kvec_host[s] for s in live)
         with tracing.span("serving.spec.verify", "serving",
                           width=width, drafted=drafted,
@@ -3063,11 +3131,9 @@ class ContinuousServer:
             # (restorable) draft-cache advance; repeated ones walk the
             # degradation ladder in _recover and turn speculation off
             faultinject.check("verify")
-            pos = jnp.asarray(self._pos, jnp.int32)
-            kvec = jnp.asarray(kvec_host, jnp.int32)
-            if self._temp_dev is None:
-                self._temp_dev = jnp.asarray(self._temp, jnp.float32)
-                self._keys_dev = jnp.stack(self._key)
+            pos = np.array(self._pos, np.int32)
+            kvec = np.array(kvec_host, np.int32)
+            temp, keys = self._lanes()
             if self.paged:
                 for s in live:
                     self._ensure_window(s, self._pos[s],
@@ -3075,12 +3141,11 @@ class ContinuousServer:
                 self._pools, self._scales, packed, ms = \
                     self._paged_verify_prog(width)(
                         self.params, self._pools, self._scales, toks,
-                        pos, self._tables_dev(), kvec, self._temp_dev,
-                        self._keys_dev)
+                        pos, self._tables_dev(), kvec, temp, keys)
             else:
                 self._caches, packed, ms = self._verify_prog(width)(
-                    self.params, self._caches, toks, pos, kvec,
-                    self._temp_dev, self._keys_dev)
+                    self.params, self._caches, toks, pos, kvec, temp,
+                    keys)
             if ms is not None:
                 self._moe_buf.append(ms)
             # the speculative step's single designed host sync: one
@@ -3236,7 +3301,7 @@ class ContinuousServer:
                 self._caches = self._splice_prog()(
                     self._caches,
                     self._reprefill(req.prompt + req.tokens[:-1]),
-                    jnp.asarray(slot, jnp.int32))
+                    np.int32(slot))
             if self._spec and self._draft_params is not None:
                 self._draft_prefill(slot, req.prompt
                                     + req.tokens[:-1])
@@ -3258,10 +3323,10 @@ class ContinuousServer:
             self._pos[slot] = len(seq)
             self._cur[slot] = req.tokens[-1]
             pt = self._tables[slot]
-            wrow = (jnp.asarray(pt.as_row(self._maxb, self._trash)),)
+            wrow = (pt.as_row(self._maxb, self._trash),)
             self._pools, self._scales = self._paged_splice_prog()(
                 self._pools, self._scales, self._reprefill(seq), wrow,
-                jnp.asarray(slot, jnp.int32))
+                np.int32(slot))
             self._reprefills += 1
         self._flt_restored += 1
 
@@ -3709,12 +3774,9 @@ class ContinuousServer:
                 # block instead of a recycled live block. Dead slots'
                 # feedback tokens are stale argmax/sample outputs —
                 # always valid ids.
-                tok = (jnp.asarray(self._cur, jnp.int32)
-                       if self._cur_dev is None else self._cur_dev)
-                pos = jnp.asarray(self._pos, jnp.int32)
-                if self._temp_dev is None:
-                    self._temp_dev = jnp.asarray(self._temp, jnp.float32)
-                    self._keys_dev = jnp.stack(self._key)
+                tok = self._feedback()
+                temp, keys = self._lanes()
+                pos = np.array(self._pos, np.int32)
                 if self.paged:
                     for s in live:
                         self._ensure_block(s, self._pos[s])
@@ -3724,11 +3786,10 @@ class ContinuousServer:
                 self._pools, self._scales, nxt, ms = \
                     self._paged_step_prog()(
                         self.params, self._pools, self._scales, tok,
-                        pos, tables, self._temp_dev, self._keys_dev)
+                        pos, tables, temp, keys)
             else:
                 self._caches, nxt, ms = self._step_prog()(
-                    self.params, self._caches, tok, pos,
-                    self._temp_dev, self._keys_dev)
+                    self.params, self._caches, tok, pos, temp, keys)
             if ms is not None:
                 self._moe_buf.append(ms)
             self._cur_dev = nxt

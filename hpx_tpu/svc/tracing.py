@@ -47,7 +47,8 @@ serving host loop with the profiler OFF: the three choke points that
 write the spans (`ContinuousServer.step()`, the wrapper `_program()`
 hands out, `_wait()`) also add up, on ``time.perf_counter_ns()``, where
 a step's wall went — held in dispatch calls, waiting on reads, the
-host's own work, and how much of that in the eager sections — beside
+host's own work, and how much of that in the decode step's operands —
+beside
 what only the process knows (CPU time, collector pauses). One
 fixed-size record a step in a ring of 4,096; a SLOW step writes the
 instant ``serving.slow_step`` and one `svc/flight` bundle. No key turns
@@ -575,18 +576,23 @@ def flow_end(fid: Optional[int], name: str, cat: str = "flow") -> None:
 # read, or an eager op on the full queue) rightly waits for every step
 # the host was ahead (Kimi-Linear on the chip: a median step of 3.3 ms,
 # chunk steps of 330 ms at the same step numbers in every run). A
-# step's PACE is a 32nd of the running median block (the last 8).
+# step's PACE is a 32nd of the running median block (the last 8), or
+# of the 32 steps before it, less their longest, where those took
+# longer.
 # A step is SLOW where it took over 250 ms and over 4 paces for every
 # program it had to wait behind (at least one): the decode steps the
 # host was ahead when it began, and the prefill chunks enqueued since
 # the last blocking read, its own included (DeepSeek-V2 admits up to
 # four questions in a step, a chunk of 60 ms each; a loader's document
 # of 10k-24k tokens is 38-92 chunks with nothing to read between them,
-# and the step that ends it drains 0.5-1.0 s of them). A block is slow
-# where the last 32 steps together took over 2.5 median blocks and at
-# least 1 s more (all 32 crawl: 94 ms a step would pass the first
-# rule; a closed loop's burst of admissions, 1.4 s where 0.77 s is the
-# median on StarCoder2-3B, does not). In code, not in the config: an
+# and the step that ends it drains 0.5-1.0 s of them; 1.7-3.2 s since
+# PR 40, whose host runs ~38 chunks ahead where it ran ~13). A block
+# is slow where the last 32 steps together, less their longest, took
+# over 2.5 median blocks, and all of them at least 1 s more than one
+# (all 32 crawl: 94 ms a step would pass the first rule; one step that
+# drains what it had queued is the first rule's to judge; a closed
+# loop's burst of admissions, 1.4 s where 0.77 s is the median on
+# StarCoder2-3B, is no crawl). In code, not in the config: an
 # admission step with a 512-row chunk is 2.5 times a decode step and
 # must not fire, and nobody should have to tune that.
 SLOW_X_PACE = 4.0
@@ -612,10 +618,14 @@ class StepRecord(NamedTuple):
     n: int                  # step() calls so far (`serving.step`'s n)
     end_ns: int
     wall_ns: int
-    work_ns: int            # the host's own Python and eager ops
-    eager_ns: int           # ... of it in the sections that enqueue
-                            # eager, unnamed programs (the decode
-                            # step's operands, the seed token's pick)
+    work_ns: int            # the host's own Python and transfers
+    eager_ns: int           # ... of it in the decode step's operands
+                            # section (`serving.decode.operands`: the
+                            # table rebuild, the host-to-device
+                            # arrays). The one place where step() once
+                            # enqueued eager, unnamed programs; none
+                            # since the seed token's pick and the
+                            # per-slot vectors moved into `cb_probe`
     held_ns: int            # inside dispatch calls of named programs
     waited_ns: int          # inside blocking device->host reads
     gap_ns: int             # the caller's, since the step before ended
@@ -645,12 +655,12 @@ class StepRecord(NamedTuple):
         `top_prog`'s call), `waited`, `caller` (the gap before the
         step), or the host's own work, told apart as `collector`
         (pauses make half of it), `eager` (half of it lies in the
-        sections whose eager ops are unnamed programs: the runtime
-        holds the host THERE on a full queue, as it holds it in a
-        named program's call), `off_cpu` (the thread's CPU time, gap
-        and all, is under half of it: it slept, was switched out or
-        faulted, or an eager op outside those sections met a full
-        queue) or `computing`."""
+        decode step's operands: a transfer that meets a full queue
+        holds the host THERE as a named program's call does, and so
+        would an eager op, should one come back), `off_cpu` (the
+        thread's CPU time, gap and all, is under half of it: it
+        slept, was switched out or faulted, or a transfer outside that
+        section met a full queue) or `computing`."""
         part, ns = max((("held", self.held_ns), ("waited", self.waited_ns),
                         ("work", self.work_ns), ("caller", self.gap_ns)),
                        key=lambda kv: kv[1])
@@ -697,7 +707,7 @@ class StepAccount:
     """Where each step's wall went, for ONE server: `begin()` and
     `end()` from `step()`, `dispatched()` after every named program's
     call, `waited_ns` grown by every blocking read and `eager_ns` by
-    the sections that enqueue eager ops. The last `ACCOUNTS_KEPT`
+    the decode step's operands section. The last `ACCOUNTS_KEPT`
     records stay in a ring (`records()`); a slow step (or one that
     ends a slow block of 32) is counted (`slow`, `slow_ns`), marked in
     both trace sinks, logged once and handed to the flight recorder
@@ -803,21 +813,32 @@ class StepAccount:
         chunks enqueued since the last read: what the step may rightly
         wait behind."""
         block = self._block
+        verdict, median = "", self._median_block
+        held_to = median
+        if spent > SLOW_FLOOR_NS and block:
+            # ... or the 32 steps before this one, less their longest:
+            # where the host runs deeper ahead than a block is long (a
+            # loader's document: 38 chunks enqueued in 0.9 ms each,
+            # then each held for one chunk's time), the median block
+            # is all run-ahead and says nothing of the device's pace;
+            # the steps just before the drain do
+            held_to = max(median, self._block_ns - max(block))
         if len(block) == BLOCK_STEPS:
             self._block_ns -= block[0]
         block.append(spent)
         self._block_ns += spent
         self._steps += 1
         self._quiet += 1
-        verdict, median = "", self._median_block
         if not median:
             pass                    # no block to hold anything against
         elif spent > SLOW_FLOOR_NS and spent > (
-                SLOW_X_PACE * max(1, queued) * median / BLOCK_STEPS):
+                SLOW_X_PACE * max(1, queued) * held_to / BLOCK_STEPS):
             verdict = "step"
         elif self._quiet >= BLOCK_STEPS and len(self._blocks) > 1 \
-                and self._block_ns > BLOCK_X_MEDIAN * median \
+                and self._block_ns - max(block) > BLOCK_X_MEDIAN * median \
                 and self._block_ns - median >= BLOCK_FLOOR_NS:
+            # less its longest step: ONE step that drains what it had
+            # queued is the first rule's to judge, a block crawls
             verdict = "block"
         if verdict:
             self._quiet = 0
@@ -867,8 +888,8 @@ class StepAccount:
             "slow step n=%d (%s): %.0f ms where a step's pace is %.1f ms "
             "behind %d queued program(s); look at %s first (held "
             "%.0f ms, longest in %r; waited %.0f; work %.0f, of it in "
-            "eager sections %.0f; caller %.0f; collector %.0f; thread on "
-            "a core %.0f); bundle %s", rec.n, rec.slow,
+            "the decode operands %.0f; caller %.0f; collector %.0f; "
+            "thread on a core %.0f); bundle %s", rec.n, rec.slow,
             (spent if whole is rec else self._block_ns) / 1e6, pace / 1e6,
             rec.lead + rec.owed, blame, whole.held_ns / 1e6,
             whole.top_prog, whole.waited_ns / 1e6, whole.work_ns / 1e6,

@@ -84,25 +84,43 @@ def test_rejects_bad_submits(params):
         srv.submit([1, 2, 3], max_new=14)
 
 
-def test_per_request_sampling_matches_solo(params):
+KEYS = {"raw": jax.random.PRNGKey,              # uint32[2] on the device
+        "typed": jax.random.key,                # a typed key array
+        "host": lambda s: np.asarray(jax.random.PRNGKey(s))}
+
+
+@pytest.mark.parametrize("staggered", [False, True])
+@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("kind", sorted(KEYS))
+def test_per_request_sampling_matches_solo(params, kind, paged, staggered):
     """Sampled requests reproduce their SOLO generate(temperature, key)
-    tokens exactly (the key folds match), mixed in one batch with
-    greedy requests."""
-    k1, k2 = jax.random.PRNGKey(11), jax.random.PRNGKey(22)
-    srv = ContinuousServer(params, CFG, slots=3, smax=64)
-    a = srv.submit([3, 1, 4], max_new=8, temperature=0.8, key=k1)
-    b = srv.submit([2, 7], max_new=6)                       # greedy
-    c = srv.submit([5, 6, 7, 8], max_new=7, temperature=1.3, key=k2)
+    tokens exactly, the seed token the probe's program picks included
+    (the key folds match), mixed in one batch with greedy requests —
+    with a key of each kind, and admitted in one step or in different
+    steps beside slots that are already decoding."""
+    k1, k2 = KEYS[kind](11), KEYS[kind](22)
+    srv = ContinuousServer(params, CFG, slots=3, smax=64, paged=paged)
+    asks = [([3, 1, 4], dict(max_new=8, temperature=0.8, key=k1)),
+            ([2, 7], dict(max_new=6)),                      # greedy
+            ([5, 6, 7, 8], dict(max_new=7, temperature=1.3, key=k2)),
+            ([9, 2, 6], dict(max_new=5, temperature=0.6, key=k1))]
+    rids = []
+    for prompt, ask in asks:
+        rids.append(srv.submit(prompt, **ask))
+        if staggered:
+            srv.step()
+            srv.step()
     out = srv.run()
 
-    def solo(prompt, m, t=0.0, key=None):
+    def solo(prompt, max_new, temperature=0.0, key=None):
         o = tfm.generate(params, CFG, jnp.asarray([prompt], jnp.int32),
-                         max_new=m, temperature=t, key=key)
+                         max_new=max_new, temperature=temperature,
+                         key=None if key is None else jnp.asarray(key)
+                         if kind == "host" else key)
         return [int(x) for x in np.asarray(o)[0]]
 
-    assert out[a] == solo([3, 1, 4], 8, 0.8, k1)
-    assert out[b] == solo([2, 7], 6)
-    assert out[c] == solo([5, 6, 7, 8], 7, 1.3, k2)
+    for rid, (prompt, ask) in zip(rids, asks):
+        assert out[rid] == solo(prompt, **ask), (rid, prompt)
 
 
 def test_sampling_requires_key(params):

@@ -349,11 +349,15 @@ def _admission_logits(srv, prompt):
     srv.submit(prompt, max_new=6)
     probe = srv._probe_prog()
     seen = []
+    # the probe's logits never leave its program (it returns the seed
+    # token): the same window over the same scratch, before the probe
+    # donates it
+    logits = jax.jit(lambda params, caches, tok, pos: tfm._decode_window(
+        params, caches, tok, pos, srv.cfg, need_logits=True)[1][0, -1])
 
     def spy(*a):
-        out = probe(*a)
-        seen.append(np.asarray(out[1][0]))
-        return out
+        seen.append(np.asarray(logits(*a[:4])))
+        return probe(*a)
     srv._probe_prog = lambda: spy
     try:
         while srv._slot_req[0] is None:

@@ -1,0 +1,125 @@
+"""Inside `ContinuousServer.step()` the only programs that reach the
+device are the ones `_program()` hands out (PR 40): the seed token is
+picked and the slot's lane of the per-slot vectors is set inside
+`cb_probe`, the vectors' host copies are NumPy, and every operand of a
+named program is host NumPy. Measured at the two real boundaries:
+
+* compiles: a fresh server driven through a mixed-length workload
+  compiles exactly `srv._prog_misses` XLA modules, i.e. not one
+  first-touch eager op (`utils/compilemon.count_compiles`);
+* executions: under the profiler every `serving.step` span holds as
+  many `PjRtCpuExecutable::Execute` events (one a program the CPU
+  client is handed: what `dev_programs_per_step` counts on the chip)
+  as the step's record has `dispatches`.
+
+A config of its own (d_ff=40) keeps other modules' warm program caches
+out of the counts, as `test_compile_guard.py` does."""
+
+import glob
+
+import jax
+import numpy as np
+import pytest
+
+from hpx_tpu.models import transformer as tfm
+from hpx_tpu.models.serving import ContinuousServer
+from hpx_tpu.utils.compilemon import count_compiles
+
+CFG = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
+                            n_layers=2, d_ff=40)
+EXECUTE = "PjRtCpuExecutable::Execute"
+MODES = ["paged", "dense"]
+# what each request of a workload asks for beside its prompt
+KINDS = {
+    "greedy": [{}] * 5,
+    "raw_key": [{"temperature": 0.8, "raw": s} for s in (3, 4, 5, 6, 7)],
+    "typed_key": [{"temperature": 1.3, "typed": s} for s in (3, 4, 5, 6, 7)],
+    "eos": [{"eos_id": 7}] * 5,
+    "mixed": [{}, {"temperature": 0.8, "raw": 3}, {"eos_id": 7},
+              {"temperature": 1.3, "typed": 4}, {}, {"max_new": 1},
+              {"temperature": 0.5, "raw": 9, "eos_id": 11}],
+}
+PLENS = [3, 21, 9, 12, 17, 5, 14]      # inline and chunked admissions
+
+
+@pytest.fixture(scope="module")
+def params():
+    return tfm.init_params(CFG, jax.random.PRNGKey(1))
+
+
+def _server(params, mode):
+    return ContinuousServer(params, CFG, slots=3, smax=64,
+                            prefill_chunk=8, prefill_buckets="4,8",
+                            paged=(mode == "paged"), block_size=8)
+
+
+def _submit(srv, kind, seed=0):
+    """Queue the workload (keys are made and brought to the host here,
+    in submit(), outside every step)."""
+    r = np.random.RandomState(seed)
+    rids = []
+    for plen, ask in zip(PLENS, KINDS[kind]):
+        ask = dict(ask)
+        if "raw" in ask:
+            ask["key"] = jax.random.PRNGKey(ask.pop("raw"))
+        if "typed" in ask:
+            ask["key"] = jax.random.key(ask.pop("typed"))
+        ask.setdefault("max_new", 6)
+        rids.append(srv.submit(
+            [int(t) for t in r.randint(1, CFG.vocab, plen)], **ask))
+    return rids
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+@pytest.mark.parametrize("mode", MODES)
+def test_a_workload_compiles_its_named_programs_and_nothing_else(
+        params, mode, kind):
+    srv = _server(params, mode)
+    rids = _submit(srv, kind)
+    with count_compiles() as c:
+        out = srv.run()
+    assert sorted(out) == rids and not srv.failed
+    assert srv._prog_hits + srv._prog_misses > 10
+    assert int(c) == srv._prog_misses
+
+
+def _step_programs(logdir):
+    """[(n, programs handed to the device inside it)] of every
+    `serving.step` span of the trace under `logdir`."""
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    steps, runs = [], []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == "serving.step":
+                    steps.append((ev.start_ns, ev.start_ns + ev.duration_ns,
+                                  int(dict(ev.stats)["n"])))
+                elif ev.name == EXECUTE:
+                    runs.append(ev.start_ns)
+    return [(n, sum(a <= t < b for t in runs))
+            for a, b, n in sorted(steps)]
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+@pytest.mark.parametrize("mode", MODES)
+def test_a_step_enqueues_its_dispatches_and_nothing_else(
+        params, mode, kind, tmp_path):
+    srv = _server(params, mode)
+    rids = _submit(srv, kind, seed=1)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = srv.run()
+    finally:
+        jax.profiler.stop_trace()
+    assert sorted(out) == rids and not srv.failed
+    recs = srv.step_accounts()
+    seen = _step_programs(tmp_path)
+    assert [n for n, _ in seen] == [r.n for r in recs]
+    assert sum(k for _, k in seen) > len(recs)      # the events are there
+    assert seen == [(r.n, r.dispatches) for r in recs]
+    # an admission is its probe, its splice and a scratch or a gather,
+    # a chunk one program, a decode step one
+    assert sum(r.dispatches for r in recs) == (
+        3 * len(rids) + srv._chunks + sum(r.eager_ns > 0 for r in recs))
