@@ -95,8 +95,22 @@ Paged servers additionally export the cache counters::
                                                           V) a slot, layer and
                                                           step
 
-Models with recurrent ("kda") layers add their per-slot state, models
-with latent-attention ("mla") layers their rows on the full group::
+Models with recurrent ("kda", "lightning") layers add their per-slot
+state, models with latent-attention ("mla") layers their rows on the
+full group, models with sparse layers their index and what the decode
+steps' queries chose (from the positions: the device's choice is never
+read)::
+
+    /cache{locality#L/server#i}/index/rows              compressed-key entries of
+                                                        the blocks held, a sparse
+                                                        layer and kv head
+    /serving{locality#L/server#i}/sparse/blocks-selected  blocks chosen, a sparse
+                                                        layer and kv group, summed
+                                                        over live slots and steps
+    /serving{locality#L/server#i}/sparse/rows-walked    rows of those blocks a
+                                                        query could see
+    /serving{locality#L/server#i}/sparse/rows-live      rows the same queries had
+                                                        behind them
 
     /cache{locality#L/server#i}/state/bytes             the state arrays, all slots
     /cache{locality#L/server#i}/state/slots-live        slots whose state is a request's
@@ -372,6 +386,21 @@ def register_server(srv) -> str:
             put("cache", "latent/rows-walked",
                 pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
                                    ["latent_rows_walked_per_step"])))
+        if "sparse" in getattr(srv.cfg, "layer_mixer", ()):
+            # the index of compressed keys beside a sparse layer's K/V
+            # pools, and what the decode steps' selections read
+            put("cache", "index/rows",
+                pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
+                                   ["index_rows"])))
+            put("serving", "sparse/blocks-selected",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._sparse_blocks)))
+            put("serving", "sparse/rows-walked",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._sparse_rows_walked)))
+            put("serving", "sparse/rows-live",
+                pc.CallbackCounter(_read(
+                    ref, lambda s: s._sparse_rows_live)))
         if getattr(srv, "_tier", None) is not None:
             # host-RAM demotion tier (cache/tier.py): occupancy,
             # demote/promote/drop/decline totals, cumulative hit

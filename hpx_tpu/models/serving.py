@@ -115,10 +115,12 @@ from ..ops.paged_attention import (
 from .moe import ROUTED_LEAVES
 from .transformer import (
     _PREFILL_CHUNK,
+    RECURRENT_KINDS,
     TransformerConfig,
     _cached_attention,
     _cached_program,
     _decode_window,
+    _embed,
     _layer,
     _logits,
     _pick_row,
@@ -456,7 +458,7 @@ def _decode_window_rows(params, caches, toks, pos0, cfg, moe_cf=None,
     toks [B, W] int32, pos0 [B] int32. Returns (caches, f32 logits
     [B, W, V], mstats) — mstats is the folded MoE stats vector (None
     for dense models)."""
-    x = params["emb"][toks]
+    x = _embed(params, toks, cfg)
     new_caches = []
     sink = []
     for li, (lp, kv) in enumerate(zip(params["layers"], caches)):
@@ -502,8 +504,13 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
 
     A layer's MIXER kind names what `pools` holds: K/V pools (above); a
     "kda" layer's per-slot (state [B, H, d, d] float32, conv tail [B,
-    K - 1, 3 H d]), no table, no positions; an "mla" layer's (latent
-    pool [num_blocks, 1, block_size, R],) on the full group's table."""
+    K - 1, 3 H d]), no table, no positions; a "lightning" layer's
+    per-slot (state,) alike; an "mla" layer's (latent pool [num_blocks,
+    1, block_size, R],) on the full group's table; a "sparse" layer's
+    (K pool, V pool, index pool [num_blocks, block_size / stride * Nkv,
+    H] float32, the last step's chosen block ids [B, Nkv, K] and their
+    count [B, Nkv]) on the full group's table
+    (ops/sparse_attention.paged_sparse_decode)."""
     w = x.shape[1]
     posw = pos0[:, None] + jnp.arange(w)[None, :]
     kw = {"fused": fused, "window": cfg.window(li)}
@@ -523,6 +530,26 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
         def attend(pre, g, beta):                           # noqa: F811
             o, carry = kda_mix(pre, g, beta, lp["kda"]["conv"], *pools)
             return o, (carry, None)
+    elif "lightning" in lp:
+        from ..ops.lightning import lightning_mix
+
+        def attend(q, k, v):                                # noqa: F811
+            o, carry = lightning_mix(q, k, v, cfg.lightning_decay(li),
+                                     *pools)
+            return o, (carry, None)
+    elif "sparse" in lp:
+        from ..ops.sparse_attention import paged_sparse_decode
+
+        def attend(q, k, v):                                # noqa: F811
+            if w != 1:
+                raise NotImplementedError(
+                    "a W-token window over a sparse layer's paged pools "
+                    "(ops/sparse_attention.paged_sparse_decode selects "
+                    "and walks for one row a slot)")
+            out = paged_sparse_decode(
+                q, k[:, 0], v[:, 0], *pools[:3], table, pos0,
+                cfg.sparse_spec, None if fused else "gather")
+            return out[0], (tuple(out[1:]), None)
     elif "mla" in lp:
         def attend(q, row):                                 # noqa: F811
             if w != 1:
@@ -550,7 +577,7 @@ def _paged_decode_window_rows(params, pools, scales, toks, tables, pos0,
     block GROUP, (full,) or (full, window ring); a layer reads its
     group's. `scales` is the per-layer list of (k_scale, v_scale)
     sidecars for quantized pools, or None (passed through untouched)."""
-    x = params["emb"][toks]
+    x = _embed(params, toks, cfg)
     new_pools, new_scales = [], []
     sink = []
     writes = [dp and (dp, _dp_rows(t, dp), _dp_rows(pos0, dp))
@@ -581,9 +608,10 @@ def _paged_decode_rows(params, pools, scales, tok, tables, pos, cfg,
 
 def _scratch_entry(cfg: TransformerConfig, smax: int, i: int):
     """Layer i's part of an empty b=1 prefill scratch, by its mixer's
-    kind: (k, v) [1, smax, n_kv, hd]; (latent rows [1, smax, 1, R],);
-    or (state [1, H, d, d] float32, conv tail [1, K - 1, 3 H d]), zeros
-    being the state of no tokens."""
+    kind: (k, v) [1, smax, n_kv, hd] (a "sparse" layer's too: its index
+    is the means of k's rows); (latent rows [1, smax, 1, R],); or a
+    recurrent layer's state of no tokens, zeros: (state [1, H, d, d]
+    float32, conv tail [1, K - 1, 3 H d]) or, "lightning", (state,)."""
     kind = cfg.mixer(i)
     if kind == "mla":
         return (jnp.zeros((1, smax, 1, cfg.mla_row), cfg.dtype),)
@@ -591,6 +619,9 @@ def _scratch_entry(cfg: TransformerConfig, smax: int, i: int):
         h, d = cfg.kda_heads, cfg.kda_head_dim
         return (jnp.zeros((1, h, d, d), jnp.float32),
                 jnp.zeros((1, cfg.kda_conv - 1, 3 * h * d), cfg.dtype))
+    if kind == "lightning":
+        h, d = cfg.lightning_heads, cfg.lightning_head_dim
+        return (jnp.zeros((1, h, d, d), jnp.float32),)
     shape = (1, smax, cfg.kv_heads, cfg.head_dim)
     return (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
@@ -842,16 +873,17 @@ class ContinuousServer:
         self._win = wins.pop() if wins else 0
         from ..core.config import runtime_config
         rc = runtime_config()
-        # the mixer kinds beside softmax attention: a "kda" layer keeps
-        # a per-slot recurrent state, an "mla" layer latent rows; both
-        # live in the paged cache pytree only (`_init_paged`)
+        # the mixer kinds beside softmax attention: a "kda" or
+        # "lightning" layer keeps a per-slot recurrent state, an "mla"
+        # layer latent rows, a "sparse" layer an index beside its K/V
+        # pools; all live in the paged cache pytree only (`_init_paged`)
         self._recurrent = cfg.recurrent
         self._kinds = kinds = sorted(set(cfg.layer_mixer) - {"attn"})
         for what, on in (
                 ("paged=False: the dense per-slot caches hold K/V "
                  "pairs", not self.paged),
-                ("a (dp, tp) mesh: the state and the latent pool have "
-                 "no placement", mesh is not None)):
+                ("a (dp, tp) mesh: the state, the latent pool and the "
+                 "index pool have no placement", mesh is not None)):
             if kinds and on:
                 raise NotImplementedError(
                     f"{what}; a model with {kinds} mixers runs "
@@ -948,9 +980,11 @@ class ContinuousServer:
             raise NotImplementedError(
                 "speculative verify on a model with "
                 f"{kinds} mixers: a rejected draft needs the "
-                "recurrent state rolled back, and the latent pool "
-                "attends one row a slot (models/serving.py _spec_step, "
-                "ops/kda.py, ops/paged_attention.paged_latent_attention)")
+                "recurrent state (and a sparse layer's index entry) "
+                "rolled back, and the latent and the sparse walk attend "
+                "one row a slot (models/serving.py _spec_step, "
+                "ops/kda.py, ops/lightning.py, ops/sparse_attention.py, "
+                "ops/paged_attention.paged_latent_attention)")
         if self._spec and self._win:
             raise NotImplementedError(
                 "speculative verify on a model with window layers: a "
@@ -1189,7 +1223,10 @@ class ContinuousServer:
         # the O(block) online-softmax kernel
         self._paged_fused = {"gather": False, "fused": True,
                              "fused_online": "online"}[paged_kernel]
-        if block_size is None:
+        if block_size is None and "sparse" in self._kinds:
+            # a sparse layer's pages ARE the blocks its queries choose
+            block_size, self._block_size_src = cfg.sparse_block, "model"
+        elif block_size is None:
             v = rc.get("hpx.cache.block_size", "auto")
             if v in (None, "", "auto"):
                 # HPX_PAGED_BLOCK, then the seed table banked by
@@ -1205,6 +1242,12 @@ class ContinuousServer:
         bs = int(block_size)
         if bs < 1:
             raise ValueError(f"block_size must be >= 1, got {bs}")
+        if "sparse" in self._kinds and bs != cfg.sparse_block:
+            raise NotImplementedError(
+                f"block_size {bs} on a model with sparse layers: their "
+                f"queries choose blocks of {cfg.sparse_block} rows, and "
+                "a page is what the walk copies (models/serving.py "
+                "_init_paged, ops/sparse_attention.paged_sparse_decode)")
         if smax % bs:
             raise ValueError(
                 f"paged serving needs smax divisible by the block "
@@ -1248,7 +1291,8 @@ class ContinuousServer:
         self._win_freed = self._prefix_refused = 0
         self._state_resets = self._reprefills = 0
         for what, on in (("a quantized hpx.cache.kv_dtype (a quantized "
-                          "latent row has no write or kernel)",
+                          "latent row, or a sparse layer's quantized "
+                          "page, has no write or kernel)",
                           self._kv_dtype != "bf16"),
                          ("the host tier (it demotes K/V pairs)",
                           rc.get_bool("hpx.cache.tier.enable", False))):
@@ -1256,7 +1300,7 @@ class ContinuousServer:
                 raise NotImplementedError(
                     f"{what} on a model with {self._kinds} mixers "
                     "(models/serving.py _init_paged, ops/paged_attention"
-                    ".paged_latent_attention)")
+                    ".paged_latent_attention, ops/sparse_attention.py)")
         if self._win:
             for what, on in (("a (dp, tp) mesh", self.mesh is not None),
                              ("a quantized hpx.cache.kv_dtype",
@@ -1343,21 +1387,36 @@ class ContinuousServer:
         def entry(i):
             """Layer i's part of the cache pytree, by its mixer's kind:
             two K/V pools; ONE pool of latent rows on the full group's
-            table; or the per-slot recurrent state and conv tail (no
-            blocks, no positions: `_fresh_scratch` is a slot's row)."""
+            table; the per-slot recurrent state (and conv tail; no
+            blocks, no positions: `_fresh_scratch` is a slot's row); or
+            two K/V pools, the INDEX pool of compressed keys on the
+            same table (one float32 entry every `sparse_stride` rows
+            and kv head) and the last step's selection."""
             kind = cfg.mixer(i)
             if kind == "mla":
                 return (jnp.zeros((num_blocks, 1, bs, cfg.mla_row),
                                   cfg.dtype),)
-            if kind == "kda":
+            if kind in RECURRENT_KINDS:
                 return tuple(jnp.zeros((slots,) + a.shape[1:], a.dtype)
                              for a in _scratch_entry(cfg, smax, i))
+            if kind == "sparse":
+                per = bs // cfg.sparse_stride
+                return (pzeros(), pzeros(),
+                        jnp.zeros((num_blocks, nkv * per, hd),
+                                  jnp.float32),
+                        jnp.zeros((slots, nkv, cfg.sparse_spec.width),
+                                  jnp.int32),
+                        jnp.zeros((slots, nkv), jnp.int32))
             return (wzeros(), wzeros()) if cfg.window(i) \
                 else (pzeros(), pzeros())
         self._pools = [entry(i) for i in range(cfg.n_layers)]
         self._state_bytes = sum(
             a.nbytes for i, e in enumerate(self._pools)
-            if cfg.mixer(i) == "kda" for a in e)
+            if cfg.mixer(i) in RECURRENT_KINDS for a in e)
+        # what the decode steps' sparse layers chose and walked, from
+        # the positions alone (`_sparse_account`)
+        self._sparse_steps = self._sparse_blocks = 0
+        self._sparse_rows_walked = self._sparse_rows_live = 0
         if self._kv_dtype in ("int8", "fp8"):
             def sones():
                 # scale 1.0 is quantize_blocks' zero-block convention:
@@ -1708,7 +1767,8 @@ class ContinuousServer:
         tail (and the redirected prefix) is garbage-on-garbage (see
         scatter_seq_blocks); int8 splices quantize whole blocks here
         (scatter_seq_blocks_q). `slot`: where a recurrent layer's
-        scratch state lands (it has no blocks)."""
+        scratch state lands (it has no blocks). A sparse layer's index
+        pool takes the means of the scratch's K rows by the same row."""
         cfg = self.cfg
         nb, bs = self._alloc.num_blocks, self.block_size
         maxb = self._maxb
@@ -1723,13 +1783,25 @@ class ContinuousServer:
                 outp, outs = [], []
                 for i, (pl, sc) in enumerate(zip(pools, one)):
                     wrow = wrows[1 if cfg.window(i) else 0]
-                    if cfg.mixer(i) == "kda":
-                        # the slot's row of the state and the tail,
+                    if cfg.mixer(i) in RECURRENT_KINDS:
+                        # the slot's row of the state (and the tail),
                         # whole: nothing of the last occupant survives
                         outp.append(tuple(
                             jax.lax.dynamic_update_index_in_dim(
                                 p, c[0], slot, 0)
                             for p, c in zip(pl, sc)))
+                    elif cfg.mixer(i) == "sparse":
+                        # K and V as any pair; the index entries of the
+                        # same blocks, from the scratch's K rows, by
+                        # the same write row
+                        from ..ops.sparse_attention import index_blocks
+                        kp, vp = (
+                            scatter_seq_blocks(p, wrow, c[0].reshape(
+                                maxb, bs, *c.shape[2:]))
+                            for p, c in zip(pl[:2], sc))
+                        outp.append((kp, vp, pl[2].at[wrow].set(
+                            index_blocks(sc[0][0], cfg.sparse_spec, bs)))
+                            + tuple(pl[3:]))
                     elif scales is None:
                         outp.append(tuple(
                             scatter_seq_blocks(p, wrow, c[0].reshape(
@@ -1780,8 +1852,9 @@ class ContinuousServer:
                 # group's (nothing shares its blocks, none is forked)
                 # (a recurrent layer's state has no blocks at all)
                 pools = [pl if self.cfg.window(i)
-                         or self.cfg.mixer(i) == "kda"
-                         else tuple(p.at[dst].set(p[src]) for p in pl)
+                         or self.cfg.mixer(i) in RECURRENT_KINDS
+                         else tuple(p.at[dst].set(p[src])
+                                    for p in pl[:3]) + tuple(pl[3:])
                          for i, pl in enumerate(pools)]
                 if scales is not None:
                     scales = [(ks.at[dst].set(ks[src]),
@@ -2214,6 +2287,16 @@ class ContinuousServer:
             st["state_resets"] = self._state_resets
             st["state_prefix_refused"] = self._prefix_refused
             st["state_reprefills"] = self._reprefills
+        if "sparse" in self._kinds:
+            # the fourth kind of cached entry: the index of compressed
+            # keys, an entry every `sparse_stride` rows of a held block
+            st["index_rows"] = self._alloc.in_use * (
+                self.block_size // self.cfg.sparse_stride)
+            st["sparse_prefix_refused"] = self._prefix_refused
+            st["sparse_steps"] = self._sparse_steps
+            st["sparse_blocks_selected"] = self._sparse_blocks
+            st["sparse_rows_walked"] = self._sparse_rows_walked
+            st["sparse_rows_live"] = self._sparse_rows_live
         if "mla" in self._kinds:
             st["latent_blocks_in_use"] = self._alloc.in_use
             # what a step's latent walks read: live rows of the live
@@ -2301,7 +2384,8 @@ class ContinuousServer:
         kinds = [self.cfg.mixer(i) for i in range(self.cfg.n_layers)]
         bb = block_bytes(self.block_size, self.cfg.kv_heads,
                          self.cfg.head_dim, self._kv_acct_dtype(),
-                         layers=kinds.count("attn"))
+                         layers=kinds.count("attn")
+                         + kinds.count("sparse"))
         # a latent layer's block is one pool's rows (a recurrent
         # layer has none)
         bb += kinds.count("mla") * self.block_size * self.cfg.mla_row \
@@ -2643,12 +2727,15 @@ class ContinuousServer:
                      slot: int) -> _PendingPrefill:
         plen = len(req.prompt)
         matched, mbids, tier_ext = 0, [], []
-        if self._prefix_reuse and (self._win or self._recurrent):
+        sparse = "sparse" in self._kinds
+        if self._prefix_reuse and (self._win or self._recurrent
+                                   or sparse):
             # a prefix hit would hand the full layers their rows and
             # leave the window layers without the matched prefix's last
             # window, a recurrent layer without its state at the
-            # block's boundary: refused (and counted) until the tree
-            # keeps them
+            # block's boundary, a sparse layer's scratch without the
+            # rows its index is made of: refused (and counted) until
+            # the tree keeps them
             self._prefix_refused += 1
         elif self._prefix_reuse:
             # always leave >= 1 suffix token: admission needs the LAST
@@ -2692,7 +2779,7 @@ class ContinuousServer:
         if self._recurrent:
             # the request's state starts from zeros in its scratch and
             # the splice overwrites the slot's row whole: the reset
-            n_rec = sum(self.cfg.mixer(i) == "kda"
+            n_rec = sum(self.cfg.mixer(i) in RECURRENT_KINDS
                         for i in range(self.cfg.n_layers))
             with tracing.span("serving.state_reset", "serving",
                               rid=req.rid, slot=slot, layers=n_rec):
@@ -2701,6 +2788,13 @@ class ContinuousServer:
             return _PendingPrefill(req=req, slot=slot, caches=caches,
                                    done=0, seq=self._pf_seq, pt=pt,
                                    trow=trow, wrow=wrow, hold=1)
+        if sparse:
+            # nothing matched: an empty scratch (the gather's program
+            # reads K/V pairs alone)
+            return _PendingPrefill(req=req, slot=slot,
+                                   caches=self._fresh_scratch(),
+                                   done=0, seq=self._pf_seq, pt=pt,
+                                   trow=trow, wrow=wrow)
         if not self._win:
             # the matched rows, out of the shared blocks into the
             # request's scratch (the pools' kind: K/V or latent rows)
@@ -3312,10 +3406,12 @@ class ContinuousServer:
         state is a function of the tokens and cannot be rewound, so no
         snapshot of it is kept (no copy, no second buffer). The host
         holds every token the slot has landed (`_recover` flushed
-        first): the state, the conv tails and the latent rows of
+        first): the state (a conv tail too where the kind has one),
+        the latent or K/V rows and a sparse layer's index entries of
         prompt ++ tokens[:-1] are recomputed into a fresh scratch and
         spliced over the slot's row and blocks, and the slot goes on
-        from the host's frontier with nothing to replay."""
+        from the host's frontier with nothing to replay. One path for
+        every recurrent kind (`transformer.RECURRENT_KINDS`)."""
         seq = req.prompt + req.tokens[:-1]
         with tracing.span("serving.reprefill", "serving", rid=req.rid,
                           slot=slot, tokens=len(seq)):
@@ -3792,6 +3888,8 @@ class ContinuousServer:
                     self.params, self._caches, tok, pos, temp, keys)
             if ms is not None:
                 self._moe_buf.append(ms)
+            if self.paged and "sparse" in self._kinds:
+                self._sparse_account(pos[live])
             self._cur_dev = nxt
             self._rate.mark(float(len(live)))
             lanes = []
@@ -3830,6 +3928,25 @@ class ContinuousServer:
                 self._read_due = (retired
                                   or len(self._buf) >= self._max_async)
         return True
+
+    def _sparse_account(self, pos: np.ndarray) -> None:
+        """What one decode step's sparse layers choose and walk, a
+        layer and kv group, from the live slots' positions ALONE (the
+        device's selection is never read): a query at p reads every
+        block while p + 1 <= `sparse_dense_len`, else `sparse_topk` of
+        them, all whole but the last, which holds p % block + 1 rows.
+        Feeds `cache_stats()` `sparse_*` and the
+        /serving{...}/sparse/* counters."""
+        cfg = self.cfg
+        bs = cfg.sparse_block
+        blocks = pos // bs + 1
+        chosen = np.where(pos + 1 <= cfg.sparse_dense_len, blocks,
+                          np.minimum(blocks, cfg.sparse_topk))
+        self._sparse_steps += 1
+        self._sparse_blocks += int(chosen.sum())
+        self._sparse_rows_walked += int(
+            ((chosen - 1) * bs + pos % bs + 1).sum())
+        self._sparse_rows_live += int((pos + 1).sum())
 
     def run(self) -> Dict[int, List[int]]:
         """Drive step() until every submitted request finishes; returns
@@ -3872,9 +3989,30 @@ class ContinuousServer:
             raise ValueError("recurrent_state() needs a recurrent model, "
                              "a live slot and no step in flight "
                              "(flush() first)")
-        li = self.cfg.layer_mixer.index("kda")
+        li = next(i for i, k in enumerate(self.cfg.layer_mixer)
+                  if k in RECURRENT_KINDS)
         return (req.prompt + req.tokens[:-1],
                 np.asarray(self._pools[li][0][slot]))
+
+    def sparse_selection(self, slot: int):
+        """(tokens, ids, count) of a live slot on a model with sparse
+        layers: the token ids the LAST decode step's query had behind
+        it and was (prompt ++ every landed token but the last: the
+        query is the last of these, at position len - 1), and the
+        blocks the model's FIRST sparse layer chose for it, ids [n_kv,
+        K] int32 ascending (`count` [n_kv] of them real), read from the
+        device where the step left them. For a caller that checks the
+        selection against a recomputation; `flush()` first."""
+        req = self._slot_req[slot]
+        if "sparse" not in self._kinds or req is None or self._buf \
+                or len(req.tokens) < 2:
+            raise ValueError("sparse_selection() needs a model with "
+                             "sparse layers, a live slot that has decoded "
+                             "and no step in flight (flush() first)")
+        li = self.cfg.layer_mixer.index("sparse")
+        return (req.prompt + req.tokens[:-1],
+                np.asarray(self._pools[li][3][slot]),
+                np.asarray(self._pools[li][4][slot]))
 
     def live_positions(self) -> Dict[int, int]:
         """{slot: next write position} of every live slot: what the

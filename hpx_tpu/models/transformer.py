@@ -84,6 +84,11 @@ class RopeSpec:
         return jnp.asarray(inv, jnp.float32)
 
 
+# the mixer kinds that keep a per-slot state which is a function of the
+# tokens consumed (reset at admission, recomputed at a restore)
+RECURRENT_KINDS = ("kda", "lightning")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 256
@@ -155,8 +160,12 @@ class TransformerConfig:
     moe_held: Tuple[int, ...] = ()
     # the MIXER of layer i: "attn" (softmax attention over K/V pairs,
     # what the fields above describe), "kda" (a gated delta rule over a
-    # per-slot recurrent state, ops/kda.py) or "mla" (latent attention:
-    # one cached row of mla_rank + mla_rope_dim values a token).
+    # per-slot recurrent state, ops/kda.py), "mla" (latent attention:
+    # one cached row of mla_rank + mla_rope_dim values a token),
+    # "sparse" (softmax attention over K/V pairs of which the QUERY
+    # chooses the blocks it reads, by an index of compressed keys:
+    # ops/sparse_attention.py) or "lightning" (decayed linear attention
+    # over a per-slot recurrent state, ops/lightning.py).
     # Empty = every layer "attn".
     layer_mixer: Tuple[str, ...] = ()
     kda_heads: int = 0              # heads of kda_head_dim x kda_head_dim
@@ -179,6 +188,35 @@ class TransformerConfig:
     # score: its largest expert score) and picks its top_k inside them
     moe_n_group: int = 1
     moe_topk_group: int = 1
+    # a "sparse" layer's numbers (ops/sparse_attention.SparseSpec):
+    # compressed keys of sparse_kernel rows every sparse_stride, blocks
+    # of sparse_block rows, sparse_topk of them a query and kv group
+    # (sparse_init leading and those of the last sparse_local rows
+    # among them), every block up to sparse_dense_len rows
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init: int = 1
+    sparse_local: int = 2048
+    sparse_dense_len: int = 8192
+    # a "lightning" layer's heads: lightning_heads states of
+    # lightning_head_dim x lightning_head_dim a slot
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    # RMSNorm over each head of q and k (one learned scale a layer):
+    # the "sparse" and "lightning" mixers' (their parameters name it)
+    qk_norm: bool = False
+    # x = emb[token] * emb_scale; x += residual_scale * branch; the
+    # head reads RMSNorm(x) * logit_scale
+    emb_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    # the PUBLISHED index of layer i and the published depth, where a
+    # cut in depth keeps a run of layers (a lightning head's decay is a
+    # function of both); empty / 0 = i, n_layers
+    layer_published: Tuple[int, ...] = ()
+    published_layers: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -207,7 +245,25 @@ class TransformerConfig:
     def recurrent(self) -> bool:
         """Some layer keeps a per-slot state that is a function of the
         tokens consumed, not rows addressed by position."""
-        return "kda" in self.layer_mixer
+        return any(k in RECURRENT_KINDS for k in self.layer_mixer)
+
+    @property
+    def sparse_spec(self):
+        """A "sparse" layer's numbers as its ops take them."""
+        from ..ops.sparse_attention import SparseSpec
+        return SparseSpec(self.sparse_kernel, self.sparse_stride,
+                          self.sparse_block, self.sparse_topk,
+                          self.sparse_init, self.sparse_local,
+                          self.sparse_dense_len)
+
+    def lightning_decay(self, i: int):
+        """[lightning_heads] float32 log decay of layer i's heads, a
+        constant of (head, PUBLISHED layer, published depth)."""
+        from ..ops.lightning import lightning_log_decay
+        return lightning_log_decay(
+            self.lightning_heads,
+            self.layer_published[i] if self.layer_published else i,
+            self.published_layers or self.n_layers)
 
     @property
     def mla_row(self) -> int:
@@ -235,8 +291,9 @@ class TransformerConfig:
             raise NotImplementedError(
                 f"{body} ({module}) cannot compute this model: its "
                 f"caches hold K/V pairs, `layer_mixer` has {kinds}; "
-                "ContinuousServer(paged=True) holds the recurrent state "
-                "and the latent rows (models/serving.py _init_paged)")
+                "ContinuousServer(paged=True) holds the recurrent state, "
+                "the latent rows and a sparse layer's index "
+                "(models/serving.py _init_paged)")
 
     def only(self, body: str, module: str, *allowed: str) -> None:
         """Refuse, by mechanism and module, a model whose layers `body`
@@ -246,7 +303,8 @@ class TransformerConfig:
                   "layer_window", "layer_rope", "layer_sparse",
                   "moe_shared_d_ff", "moe_router", "moe_renorm",
                   "moe_bias", "moe_held", "layer_mixer", "moe_n_group",
-                  "moe_topk_group"):
+                  "moe_topk_group", "qk_norm", "emb_scale",
+                  "residual_scale", "logit_scale"):
             if f not in allowed and getattr(self, f) != getattr(
                     TransformerConfig, f):
                 raise NotImplementedError(
@@ -304,6 +362,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         """The leaves of a "kda" / "mla" mixer (its own `wo` among
         them); the small gate and decay parameters stay float32."""
         ks = jax.random.split(k, 12)
+        if kind in ("sparse", "lightning"):
+            return _init_gated_mixer(cfg, kind, ks, nrm)
         if kind == "kda":
             h, hd, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank
             return {"kda": {
@@ -396,6 +456,36 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         params["head"] = (jax.random.normal(keys[1], (cfg.vocab, d)) * s
                           ).astype(cfg.dtype)
     return params
+
+
+def _init_gated_mixer(cfg: TransformerConfig, kind: str, ks, nrm):
+    """The leaves of a "sparse" / "lightning" mixer: projections, the
+    q/k norm scales (`cfg.qk_norm`), an ELEMENTWISE output gate and, on
+    a lightning layer, the output norm's scale over all heads. Every
+    projection is a MATRIX [in, out] with the heads side by side in
+    its columns (a sparse layer's "wkv": k heads, then v heads): a
+    [D, H, hd] leaf is tiled over (H, hd) on the chip, and the chip's
+    compiler then copies it whole into the matmul's tiling in every
+    step (33 MB a leaf at 4096 x 32 x 128), as it transposes a fused
+    [D, 3 H hd] one (100 MB); the activation is what is reshaped."""
+    d = cfg.d_model
+    s = 1.0 / math.sqrt(d)
+    if kind == "sparse":
+        h, hd, nkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+        out = {"wq": nrm(ks[0], (d, h * hd), s),
+               "wkv": nrm(ks[1], (d, 2 * nkv * hd), s)}
+    else:
+        h, hd = cfg.lightning_heads, cfg.lightning_head_dim
+        out = {"wq": nrm(ks[0], (d, h * hd), s),
+               "wk": nrm(ks[4], (d, h * hd), s),
+               "wv": nrm(ks[5], (d, h * hd), s),
+               "onorm": jnp.ones((h * hd,), cfg.dtype)}
+    if cfg.qk_norm:
+        out.update(qnorm=jnp.ones((hd,), cfg.dtype),
+                   knorm=jnp.ones((hd,), cfg.dtype))
+    out.update(wg=nrm(ks[2], (d, h * hd), s),
+               wo=nrm(ks[3], (h * hd, d), 1.0 / math.sqrt(h * hd)))
+    return {kind: out}
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -528,8 +618,17 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
     head counts are read off the arrays, a "wgate" gates the heads, a
     "w3" makes the MLP SiLU-gated, a "moe" makes it sparse."""
     h = _norm(x, lp["ln1"], cfg)
+
+    def add(x, branch):         # the residual, under the model's scale
+        rs = cfg.residual_scale
+        return x + (branch if rs == 1.0 else branch * rs)
     if "kda" in lp:
         o, carry = _kda_mixer(h, lp["kda"], cfg, attend)
+    elif "sparse" in lp:
+        o, carry = _sparse_mixer(h, lp["sparse"], cfg, attend)
+    elif "lightning" in lp:
+        o, carry = _lightning_mixer(h, lp["lightning"], cfg, attend, pos,
+                                    cfg.rope_of(li))
     elif "mla" in lp:
         o, carry = _mla_mixer(h, lp["mla"], cfg, attend, pos,
                               cfg.rope_of(li))
@@ -546,10 +645,10 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
         o = jnp.einsum("bsnh,nhd->bsd", att, _dq(lp["wo"], att))
     if tp_axis:
         o = jax.lax.psum(o, tp_axis)       # Megatron row-parallel close
-    x = x + o
+    x = add(x, o)
     h = _norm(x, lp["ln2"], cfg)
     if "moe" in lp:
-        return x + moe(h), carry
+        return add(x, moe(h)), carry
     if "w3" in lp:
         h = (jax.nn.silu(h @ _dq(lp["w1"], h)) * (h @ _dq(lp["w3"], h))
              ) @ _dq(lp["w2"], h)
@@ -558,7 +657,67 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
             @ _dq(lp["w2"], h)
     if tp_axis:
         h = jax.lax.psum(h, tp_axis)
-    return x + h, carry
+    return add(x, h), carry
+
+
+def _head_rms(x, scale, eps: float):
+    """RMSNorm over the last axis in float32, back in x's type."""
+    xf = x.astype(jnp.float32)
+    return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                               + eps)).astype(x.dtype) * scale
+
+
+def _gated_out(h, o, m):
+    """W_o (sigmoid(W_g h) * o): the ELEMENTWISE output gate of the
+    "sparse" and "lightning" mixers. o [B, W, H * hd]."""
+    gate = jax.nn.sigmoid((h @ _dq(m["wg"], h)).astype(jnp.float32))
+    o = (o.astype(jnp.float32) * gate).astype(h.dtype)
+    return o @ _dq(m["wo"], o)
+
+
+def _sparse_mixer(h, m, cfg: TransformerConfig, attend):
+    """A learned-sparse attention mixer (InfLLM-v2) around the body's
+    cache. h [B, W, D] -> (y, carry). q = RMSNorm_head(W_q h), k =
+    RMSNorm_head(W_k h), v = W_v h, NO rotation; `attend(q, k, v)`
+    writes the rows (and the index of compressed keys) and attends the
+    blocks each query chooses (ops/sparse_attention.py); then the
+    elementwise sigmoid gate and W_o."""
+    b, w, _ = h.shape
+    hd = cfg.head_dim
+    q = (h @ _dq(m["wq"], h)).reshape(b, w, -1, hd)
+    k, v = jnp.moveaxis(
+        (h @ _dq(m["wkv"], h)).reshape(b, w, 2, -1, hd), 2, 0)
+    if "qnorm" in m:
+        q = _head_rms(q, m["qnorm"], cfg.norm_eps)
+        k = _head_rms(k, m["knorm"], cfg.norm_eps)
+    att, carry = attend(q, k, v)
+    return _gated_out(h, att.reshape(b, w, -1), m), carry
+
+
+def _lightning_mixer(h, m, cfg: TransformerConfig, attend, pos,
+                     rope: Optional[RopeSpec]):
+    """A lightning-attention mixer around the body's recurrent state.
+    h [B, W, D] -> (y, carry). q, k = RoPE(RMSNorm_head(W h)) at the
+    rows' positions, v = W_v h; the core (`attend(q, k, v)` =
+    ops/lightning.lightning_mix over the body's state, float32, q
+    scaled by d^-1/2) is S = lam S + k^T v, o = q S; then RMSNorm over
+    ALL heads' outputs, the elementwise sigmoid gate, W_o."""
+    f32 = jnp.float32
+    b, w, _ = h.shape
+    q, k, v = ((h @ _dq(m[n], h)).reshape(
+        b, w, -1, cfg.lightning_head_dim) for n in ("wq", "wk", "wv"))
+    if "qnorm" in m:
+        q = _head_rms(q, m["qnorm"], cfg.norm_eps)
+        k = _head_rms(k, m["knorm"], cfg.norm_eps)
+    if rope is not None:
+        q, k = _rope(q, pos, rope), _rope(k, pos, rope)
+    o, carry = attend(q.astype(f32) * q.shape[-1] ** -0.5, k.astype(f32),
+                      v.astype(f32))
+    o = o.reshape(b, w, -1)
+    if "onorm" in m:
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + cfg.norm_eps) * m["onorm"].astype(f32)
+    return _gated_out(h, o, m), carry
 
 
 def _kda_mixer(h, m, cfg: TransformerConfig, attend):
@@ -602,11 +761,7 @@ def _mla_mixer(h, m, cfg: TransformerConfig, attend, pos=None,
     [B, W, H, rank], which W_uv takes to the head's value dims ahead of
     W_o. One form for a decode step and a prefill chunk."""
     r, dn = cfg.mla_rank, cfg.mla_nope_dim
-
-    def rms(x, scale):
-        xf = x.astype(jnp.float32)
-        return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
-                                   + cfg.norm_eps)).astype(h.dtype) * scale
+    rms = functools.partial(_head_rms, eps=cfg.norm_eps)
     ckr = h @ _dq(m["wdkv"], h)                             # [B, W, r+dr]
     c = rms(ckr[..., :r], m["kvnorm"])
     if "wdq" in m:
@@ -704,7 +859,15 @@ def _cached_attention(q, kc, vc, qpos, window: int = 0):
 def _logits(params, x, cfg: TransformerConfig):
     """Final norm and the head (the embedding's transpose when tied)."""
     x = _norm(x, params["ln_f"], cfg)
+    if cfg.logit_scale != 1.0:
+        x = x * cfg.logit_scale
     return jnp.einsum("bsd,vd->bsv", x, params.get("head", params["emb"]))
+
+
+def _embed(params, toks, cfg: TransformerConfig):
+    """The tokens' embedding rows, times the model's `emb_scale`."""
+    x = params["emb"][toks]
+    return x if cfg.emb_scale == 1.0 else x * cfg.emb_scale
 
 
 def _block(x, lp, cfg: TransformerConfig, sp_size: int, dp_size: int):
@@ -1213,7 +1376,10 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
     (state, conv tail) and `valid` the count of the window's real
     columns (the rest is bucket padding, which a recurrent state must
     not consume; None = all); an "mla" layer's is (latent rows [B,
-    Smax, 1, R],)."""
+    Smax, 1, R],); a "sparse" layer's the K/V pair (its index is the
+    means of K's rows: nothing more is kept here), each row choosing
+    the blocks it attends; a "lightning" layer's (state,), `valid` as
+    for "kda"."""
     qpos = jnp.asarray(write_at) + jnp.arange(x.shape[1])
 
     def attend(q, k, v):
@@ -1230,6 +1396,22 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
         def attend(pre, g, beta):                           # noqa: F811
             return kda_mix(pre, g, beta, lp["kda"]["conv"], *kv,
                            valid=valid)
+    elif "sparse" in lp:
+        from ..ops.sparse_attention import chunk_attention
+
+        def attend(q, k, v):                                # noqa: F811
+            kc = jax.lax.dynamic_update_slice_in_dim(kv[0], k, write_at,
+                                                     axis=1)
+            vc = jax.lax.dynamic_update_slice_in_dim(kv[1], v, write_at,
+                                                     axis=1)
+            return chunk_attention(q, kc, vc, qpos, cfg.sparse_spec)[0], \
+                (kc, vc)
+    elif "lightning" in lp:
+        from ..ops.lightning import lightning_mix
+
+        def attend(q, k, v):                                # noqa: F811
+            return lightning_mix(q, k, v, cfg.lightning_decay(li), *kv,
+                                 valid=valid)
     elif "mla" in lp:
         def attend(q, row):                                 # noqa: F811
             lat = jax.lax.dynamic_update_slice_in_dim(
@@ -1288,7 +1470,7 @@ def _decode_window(params, caches, toks, pos0, cfg, tp_axis=None,
     side effects (returns (caches, None)). `valid`: how many of the W
     columns are real, for the layers whose state must not consume
     padding (see `_block_decode`)."""
-    x = params["emb"][toks]
+    x = _embed(params, toks, cfg)
     new_caches = []
     for li, (lp, kv) in enumerate(zip(params["layers"], caches)):
         x, kv = _block_decode(x, lp, kv, pos0, cfg, tp_axis=tp_axis,

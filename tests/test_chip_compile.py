@@ -514,6 +514,134 @@ def test_hybrid_server_step_program_copies_neither_state_nor_pool(
     assert _pool_ops(text, srv._pools[1][0]) == []
 
 
+# -- learned-sparse and lightning layers (the MiniCPM-SALA cell) ---------
+
+# 64 slots of up to 35,840 rows: tables of 560 blocks of 64 rows over a
+# pool of 24,576; 32 q / 2 kv x 128; a walk of at most 128 entries; 32
+# linear heads of 128 x 128
+_S_SLOTS, _S_NQ, _S_NKV, _S_HD, _S_BS, _S_MAXB, _S_NB = 64, 32, 2, 128, 64, \
+    560, 24576
+
+
+def _sala_spec():
+    from hpx_tpu.ops.sparse_attention import SparseSpec
+    return SparseSpec()
+
+
+def test_the_lightning_states_update_runs_in_place(sds):
+    """`hpx_lightning_step` over the cell's state: read once, written
+    once, the donated 134 MB of a layer neither copied nor re-laid."""
+    from hpx_tpu.ops import lightning
+    b, h, d = _S_SLOTS, 32, 128
+    vec = sds((b, h, d), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, v, g, s: lightning.lightning_step(
+            q, k, v, g, s, kernel="pallas", interpret=False),
+        donate_argnums=(4,)).lower(
+        vec, vec, vec, sds((h,), jnp.float32),
+        sds((b, h, d, d), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "hpx_lightning_step" in text
+    assert _copies_of(text, f"f32[{b},{h},{d},{d}]") == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        b * h * d * d * 4
+
+
+def test_the_sparse_walk_and_its_index_write_leave_the_pools_where_they_lie(
+        sds):
+    """A sparse layer's decode at the cell's shapes: the row's write,
+    the index entry's write (every axis ahead of head_dim indexed: the
+    layout rule), the selection and `hpx_paged_sparse` over pools left
+    in HBM. No pool-shaped copy around any of the three pools."""
+    from hpx_tpu.ops import sparse_attention as sa
+    spec = _sala_spec()
+    pool = sds((_S_NB, _S_NKV, _S_BS, _S_HD), jnp.bfloat16)
+    idx = sds((_S_NB, _S_BS // spec.stride * _S_NKV, _S_HD), jnp.float32)
+    new = sds((_S_SLOTS, _S_NKV, _S_HD), jnp.bfloat16)
+    text = jax.jit(
+        lambda q, kn, vn, kp, vp, ip, table, pos: sa.paged_sparse_decode(
+            q, kn, vn, kp, vp, ip, table, pos, spec),
+        donate_argnums=(3, 4, 5)).lower(
+        sds((_S_SLOTS, 1, _S_NQ, _S_HD), jnp.bfloat16), new, new, pool,
+        pool, idx, sds((_S_SLOTS, _S_MAXB), jnp.int32),
+        sds((_S_SLOTS,), jnp.int32)).compile().as_text()
+    assert "hpx_paged_sparse" in text
+    assert _pool_ops(text, pool) == []
+    assert [ln for ln in text.splitlines() if " copy(" in ln
+            and f"f32[{_S_NB}," in ln.split(" copy(")[0]] == []
+
+
+def _sala_server(monkeypatch):
+    from hpx_tpu.models.serving import ContinuousServer
+    from hpx_tpu.models.transformer import (RopeSpec, TransformerConfig,
+                                            init_params)
+    cfg = TransformerConfig(
+        vocab=512, d_model=256, n_heads=_S_NQ, head_dim=_S_HD,
+        n_kv_heads=_S_NKV, n_layers=2, d_ff=512, dtype=jnp.bfloat16,
+        norm="rmsnorm", mlp="swiglu", tied=False,
+        layer_mixer=("sparse", "lightning"),
+        layer_rope=(None, RopeSpec(10000.0)), lightning_heads=32,
+        lightning_head_dim=128, qk_norm=True, emb_scale=12.0,
+        residual_scale=0.2475, logit_scale=0.0625,
+        layer_published=(9, 10), published_layers=32)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    srv = ContinuousServer(params, cfg, paged=True, slots=_S_SLOTS,
+                           smax=_S_MAXB * _S_BS, num_blocks=1024,
+                           prefill_chunk=512)
+    assert srv._paged_kernel == "fused" and srv.block_size == _S_BS
+    return cfg, params, srv
+
+
+def test_sala_server_step_program_copies_neither_state_nor_pool(
+        sds, monkeypatch):
+    """The server's own `jit_step` for a sparse layer and a lightning
+    layer of the cell's mixer widths (a narrow d_model), built by
+    `_paged_step_prog` from parameter SHAPES: one Pallas call a mixer,
+    the state and the three pools donated and left where they lie."""
+    cfg, params, srv = _sala_server(monkeypatch)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    s = srv.slots
+    text = srv._paged_step_prog().lower(
+        on_chip(params), on_chip(srv._pools), None,
+        sds((s,), jnp.int32), sds((s,), jnp.int32),
+        (sds((s, srv._maxb), jnp.int32),),
+        sds((s,), jnp.float32), sds((s, 2), jnp.uint32)).compile().as_text()
+    for name in ("hpx_paged_sparse", "hpx_lightning_step"):
+        assert name in text
+    assert _copies_of(text, f"f32[{s},32,128,128]") == []
+    assert _pool_ops(text, srv._pools[0][0]) == []
+    assert [ln for ln in text.splitlines() if " copy(" in ln
+            and "f32[1024,8,128]" in ln.split(" copy(")[0]] == []
+
+
+def test_a_sparse_layers_prefill_chunk_builds_no_array_over_the_scratch(
+        sds, monkeypatch):
+    """`jit_chunk` at 512 rows over a scratch of 35,840: the chunk's
+    attention is walked in blocks of rows, so no array holds heads x
+    rows x smax scores (2.3 GB in float32 here); the largest the
+    selection builds is heads x rows x smax / 16."""
+    import re
+    cfg, params, srv = _sala_server(monkeypatch)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    scratch = on_chip(jax.eval_shape(srv._fresh_scratch))
+    compiled = srv._chunk_prog(512).lower(
+        on_chip(params), scratch, sds((1, 512), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile()
+    smax, rows, heads = srv.smax, 512, _S_NQ
+    sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"\b(?:f32|bf16|pred|s32)\[([0-9,]+)\]",
+                                    compiled.as_text())]
+    assert max(sizes) <= heads * rows * (smax // 16)
+    assert max(sizes) < heads * rows * smax // 8
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # -- flash attention (training forward/backward, ring chunk) -------------
 
 @pytest.mark.parametrize("n,nkv", [(8, 8), (16, 4)],

@@ -27,8 +27,19 @@ from hpx_tpu.utils.compilemon import count_compiles
 
 CFG = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
                             n_layers=2, d_ff=40)
+# a model whose layers choose their blocks (an index pool beside K/V)
+# and keep a linear state: its admission resets a state, its splice
+# writes an index, its step selects on the device
+SALA = tfm.TransformerConfig(
+    vocab=64, d_model=32, n_heads=4, head_dim=8, n_kv_heads=2, n_layers=2,
+    d_ff=40, norm="rmsnorm", mlp="swiglu", tied=False,
+    layer_mixer=("sparse", "lightning"),
+    layer_rope=(None, tfm.RopeSpec(10000.0)), sparse_kernel=4,
+    sparse_stride=2, sparse_block=8, sparse_topk=2, sparse_local=8,
+    sparse_dense_len=16, lightning_heads=4, lightning_head_dim=8,
+    qk_norm=True, emb_scale=12.0, residual_scale=0.25, logit_scale=0.5)
 EXECUTE = "PjRtCpuExecutable::Execute"
-MODES = ["paged", "dense"]
+MODES = ["paged", "dense", "sala"]
 # what each request of a workload asks for beside its prompt
 KINDS = {
     "greedy": [{}] * 5,
@@ -44,11 +55,16 @@ PLENS = [3, 21, 9, 12, 17, 5, 14]      # inline and chunked admissions
 
 @pytest.fixture(scope="module")
 def params():
-    return tfm.init_params(CFG, jax.random.PRNGKey(1))
+    return {"sala": tfm.init_params(SALA, jax.random.PRNGKey(2)),
+            None: tfm.init_params(CFG, jax.random.PRNGKey(1))}
 
 
 def _server(params, mode):
-    return ContinuousServer(params, CFG, slots=3, smax=64,
+    if mode == "sala":
+        return ContinuousServer(params[mode], SALA, slots=3, smax=64,
+                                prefill_chunk=8, prefill_buckets="4,8",
+                                paged=True)
+    return ContinuousServer(params[None], CFG, slots=3, smax=64,
                             prefill_chunk=8, prefill_buckets="4,8",
                             paged=(mode == "paged"), block_size=8)
 
