@@ -15,10 +15,11 @@ Topology::
         └── DecodeWorker × M    (paged ContinuousServer pools)
 
 The prefill worker computes prompt KV rows with the SAME bucketed
-chunk + probe programs a colocated server uses and ships raw
+chunk + probe programs a colocated server uses (the probe ONE layer
+deep, from the hidden row the last chunk hands back) and ships raw
 compute-dtype rows block-by-block as they finish (the final, partial
-block ships post-probe — the probe rewrites row plen-1). The decode
-worker splices received rows through its own `_paged_splice_prog`
+block ships post-probe — the probe rewrites the last layer's row
+plen-1). The decode worker splices received rows through its own `_paged_splice_prog`
 (`ContinuousServer.admit_prefilled`), so decode proceeds from KV
 bytes a colocated prefill would have produced — which is what makes
 failover REPLAY (not approximate): tokens are sha-identical to the
@@ -94,6 +95,8 @@ class _PrefillJob:
     emitted: int                   # rows already framed into segments
     temperature: float
     key: Any
+    row: Any = None                # the newest chunk's hidden row, where
+                                   # the probe starts
 
 
 class _WorkerRing:
@@ -143,9 +146,9 @@ class PrefillWorker(_WorkerRing):
     Emission discipline: full blocks of ``[0, ((plen-1)//bs)*bs)`` may
     ship as soon as their rows are chunked (KV rows are append-only —
     functions of (token, position) alone); the FINAL segment ships
-    only after the probe, which rewrites row plen-1 and yields the
-    seeding logits. ``start`` with ``prefix_rows`` resumes a transfer
-    whose original worker died: the scratch seeds from the
+    only after the probe, which rewrites the last layer's row plen-1
+    and picks the seed token. ``start`` with ``prefix_rows`` resumes a
+    transfer whose original worker died: the scratch seeds from the
     already-shipped prefix and only the suffix recomputes."""
 
     def __init__(self, params, cfg: TransformerConfig, smax: int = 512,
@@ -201,8 +204,8 @@ class PrefillWorker(_WorkerRing):
         with self._wspan("prefill.step", rid=rid):
             if job.done < plen:
                 n, width = eng._next_chunk(job.done, plen - job.done)
-                job.caches = eng._run_chunk(job.caches, job.prompt,
-                                            job.done, n, width)
+                job.caches, job.row = eng._run_chunk(
+                    job.caches, job.prompt, job.done, n, width)
                 job.done += n
             segs: List[KVSegment] = []
             # pre-probe emission cap: row plen-1 is rewritten by the
@@ -218,7 +221,7 @@ class PrefillWorker(_WorkerRing):
                 # row 0) inside its program; the engine's own lanes,
                 # which it also returns, serve nothing here
                 job.caches, *_, tok0 = eng._probe(
-                    job.caches, job.prompt[-1], plen - 1, 0,
+                    job.caches, job.row, plen - 1, 0,
                     job.temperature, job.key)
                 seed = int(tok0)
                 segs.append(self._emit(rid, job, job.emitted, plen,

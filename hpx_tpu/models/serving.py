@@ -56,7 +56,10 @@ batching changes THROUGHPUT, never content. Chunk padding preserves
 this bit-for-bit: per-token hidden states and K/V rows are independent
 of how the prompt is partitioned into windows (row-independent ops +
 exact-zero causal masking of pad rows), and the first sampled token
-comes from a 1-token logits probe of the last prompt position.
+comes from a one-row probe of the last prompt position that is ONE
+layer deep: every chunk hands back the hidden row of its last real
+column as it enters the last layer, and the probe takes the last
+chunk's row through that layer and the head.
 
 RESILIENCY (ROADMAP item 5): the step loop runs under a bounded
 `svc.resiliency.sync_replay`. Every live slot keeps a host-side
@@ -125,6 +128,7 @@ from .transformer import (
     _logits,
     _pick_row,
     _tree_key,
+    _window_tail,
 )
 
 __all__ = ["ContinuousServer", "DeadlineExceededError",
@@ -720,7 +724,9 @@ class _Request:
 class _PendingPrefill:
     """One in-flight chunked prefill: owns a reserved slot and a b=1
     scratch cache; `done` is the absolute prompt cursor (starts at the
-    radix-matched prefix length in paged mode)."""
+    radix-matched prefix length in paged mode). The chunks run to the
+    prompt's END on every model: a recurrent layer consumes the last
+    token once, in its chunk."""
     req: _Request
     slot: int
     caches: Any                    # b=1 [1, smax] scratch, per layer
@@ -733,14 +739,14 @@ class _PendingPrefill:
                                    # entries point at trash)
     wt: Optional[WindowTable] = None   # blocks held in the window group
     flow: Optional[int] = None     # tracing flow id chaining the chunks
-    hold: int = 0                  # prompt tokens the chunks leave to
-                                   # the probe (1 on a recurrent model:
-                                   # the probe is no idempotent rewrite
-                                   # there, it consumes the last token)
+    row: Any = None                # [1, 1, d_model], what the newest
+                                   # chunk handed back: its last real
+                                   # column ahead of the last layer,
+                                   # where the probe starts
 
     @property
     def remaining(self) -> int:
-        return len(self.req.prompt) - self.hold - self.done
+        return len(self.req.prompt) - self.done
 
 
 _now_ns = time.perf_counter_ns
@@ -809,9 +815,10 @@ class ContinuousServer:
     finished slots retire and queued requests admit between steps.
     Prompts prefill on a b=1 scratch cache in BUCKETED fixed-width
     chunks (pad-then-mask; widths from the ``hpx.serving.
-    prefill_buckets`` ladder), then a 1-token probe of the last prompt
-    position yields the seeding logits and the whole scratch splices
-    into the slot — so the program cache holds O(buckets) prefill
+    prefill_buckets`` ladder), then a one-row probe of the last prompt
+    position, ONE layer deep (it starts from the hidden row the last
+    chunk handed back), picks the seed token and the whole scratch
+    splices into the slot — so the program cache holds O(buckets) prefill
     programs regardless of the prompt-length mix. A prompt whose
     remaining tokens exceed ``hpx.serving.prefill_chunk`` becomes a
     PENDING prefill: it advances one chunk per step interleaved with
@@ -924,6 +931,8 @@ class ContinuousServer:
                             mesh)
             cache_sh = NamedSharding(mesh, P("dp", None, "tp", None))
         self.params = params
+        # what the probe reads: the last layer, final ln and the head
+        self._tail_params = {**params, "layers": params["layers"][-1:]}
         self._cache_sh = cache_sh
         # MoE decode state: the capacity-factor knob is an int PERCENT
         # (100 = GShard cf 1.0); 0 = auto = drop-free (cf = n_experts),
@@ -1523,64 +1532,71 @@ class ContinuousServer:
         frontier; they are never attended (causal mask) and the next
         chunk or the decode steps overwrite them before their
         positions ever go live. `n`: how many of the columns are real
-        (a recurrent layer's state consumes those alone)."""
+        (a recurrent layer's state consumes those alone). Returns the
+        scratch and ONE hidden row [1, 1, d_model]: column `n - 1` as
+        it enters the last layer (`_decode_window`), where the probe
+        starts if the chunk was its prompt's last. Every chunk returns
+        it: no chunk program carries a head or a pick."""
         cfg, smax = self.cfg, self.smax
         ck = ("cb_chunk", cfg, width, smax, self.mesh,
               _tree_key(self.params))
 
         def build():
             def chunk(params, caches, toks, pos0, n):
-                caches, _ = _decode_window(params, caches, toks, pos0,
-                                           cfg, need_logits=False,
-                                           valid=n)
-                return caches
+                return _decode_window(params, caches, toks, pos0, cfg,
+                                      need_logits=False, valid=n)
             return jax.jit(chunk, donate_argnums=(1,))
         return self._program(ck, build)
 
     def _probe_prog(self):
-        """Seed probe: rerun the LAST prompt token at its own position
-        (an idempotent K/V rewrite — same bytes) and pick the seed
-        token from its logits (`_seed_lane`), which never leave the
-        program: what comes back is the scratch, the three per-slot
-        vectors with the slot's lane set, and the token. One program
-        serves every prompt length, so the chunk programs never need a
-        logits variant per bucket. On a recurrent model the chunks
-        stop one token short (`_PendingPrefill.hold`) and the probe is
-        that token's one and only pass."""
+        """Seed probe, ONE layer deep: the hidden row the prompt's last
+        chunk handed back goes through the LAST layer at its own
+        position (an idempotent rewrite of that layer's cache row —
+        the same row to rounding) and through the head
+        (`_window_tail`), and `_seed_lane` picks the seed token from
+        its logits, which never leave the program: what comes back is
+        the last layer's scratch entry, the three per-slot vectors with
+        the slot's lane set, and the token. It reads one layer's
+        weights and the head, whatever the model's depth. One program
+        serves every prompt length, so no chunk program carries a head.
+        Where the last layer is of a recurrent kind the row comes from
+        BEHIND it (the layer consumed the token in its chunk) and the
+        probe is final ln, head and pick alone."""
         cfg, smax = self.cfg, self.smax
         ck = ("cb_probe", cfg, smax, self.mesh, _tree_key(self.params))
 
         def build():
             lane_sh = self._lane_sh()
 
-            def probe(params, caches, tok, pos, cur, temp, keys, slot,
+            def probe(params, row, kv, pos, cur, temp, keys, slot,
                       temperature, key):
-                caches, lg = _decode_window(params, caches, tok, pos,
-                                            cfg, need_logits=True)
-                out = _seed_lane(lg[0, -1], cur, temp, keys, slot,
+                kv, lg = _window_tail(params, row, kv, pos, cfg)
+                out = _seed_lane(lg[0], cur, temp, keys, slot,
                                  temperature, key, pos)
                 if lane_sh is not None:
                     # the placement the step hands its vector back in
                     out = tuple(jax.lax.with_sharding_constraint(v, sh)
                                 for v, sh in zip(out[:3], lane_sh)
                                 ) + out[3:]
-                return (caches,) + out
-            return jax.jit(probe, donate_argnums=(1,))
+                return (kv,) + out
+            return jax.jit(probe, donate_argnums=(2,))
         return self._program(ck, build)
 
-    def _probe(self, caches, tok: int, pos: int, slot: int = 0,
+    def _probe(self, caches, row, pos: int, slot: int = 0,
                temperature: float = 0.0, key=None):
-        """Dispatch the probe on `tok` at row `pos` of the b=1 scratch:
-        (scratch, feedback tokens, temperatures, keys, seed token) with
-        `slot`'s lanes set to the pick and the request's temperature
-        and key. Every operand is host NumPy or already on the device:
-        no eager program beside the named one."""
-        return self._probe_prog()(
-            self.params, caches, np.asarray([[tok]], np.int32),
-            np.int32(pos), self._feedback(), *self._lanes(),
-            np.int32(slot),
+        """Dispatch the probe on `row`, what the chunk that wrote row
+        `pos` of the b=1 scratch handed back: (scratch, feedback
+        tokens, temperatures, keys, seed token) with `slot`'s lanes set
+        to the pick and the request's temperature and key. The last
+        layer's entry of the scratch alone is an operand (donated);
+        the others stay where they lie. Every operand is host NumPy or
+        already on the device: no eager program beside the named one."""
+        kv, *out = self._probe_prog()(
+            self._tail_params, row, caches[-1], np.int32(pos),
+            self._feedback(), *self._lanes(), np.int32(slot),
             np.float32(temperature),
             self._no_key if key is None else key)
+        return [*caches[:-1], kv], *out
 
     def _lane_sh(self):
         """Placement of the per-slot vectors (1-d, and the keys' 2-d)
@@ -2675,24 +2691,22 @@ class ContinuousServer:
         reach pos0 + width, and a `dynamic_update_slice` whose window
         would pass the scratch's `smax` rows is CLAMPED and shifts the
         real rows: there the chunk is the widest bucket that fits and
-        the rest a further chunk; (1, 0) where none fits: one row,
-        through the probe's program (`_run_chunk`)."""
+        the rest a further chunk; where none fits, one row through the
+        chunk program at width 1."""
         n = min(self.prefill_chunk, remaining)
         width = self._bucket_width(n)
         room = self.smax - pos0
         if width > room:
             width = max((w for w in self.prefill_buckets if w <= room),
-                        default=0)
-            n = min(n, width) or 1
+                        default=1)
+            n = min(n, width)
         return n, width
 
     def _run_chunk(self, caches, seq: List[int], done: int, n: int,
                    width: int):
         """Dispatch the chunk `_next_chunk` planned, seq[done:done + n],
-        into the b=1 scratch `caches` (width 0: the probe's program,
-        its logits unread)."""
-        if not width:
-            return self._probe(caches, seq[done], done)[0]
+        into the b=1 scratch `caches`: (scratch, the hidden row the
+        probe starts from, `_chunk_prog`)."""
         toks = seq[done:done + n] + [0] * (width - n)
         return self._chunk_prog(width)(
             self.params, caches, np.asarray([toks], np.int32),
@@ -2787,7 +2801,7 @@ class ContinuousServer:
                 self._state_resets += 1
             return _PendingPrefill(req=req, slot=slot, caches=caches,
                                    done=0, seq=self._pf_seq, pt=pt,
-                                   trow=trow, wrow=wrow, hold=1)
+                                   trow=trow, wrow=wrow)
         if sparse:
             # nothing matched: an empty scratch (the gather's program
             # reads K/V pairs alone)
@@ -2838,12 +2852,12 @@ class ContinuousServer:
         n, width = self._next_chunk(p.done, p.remaining)
         with tracing.span("serving.prefill_chunk", "serving",
                           rid=req.rid, pos0=p.done, tokens=n,
-                          width=width or 1):
+                          width=width):
             if p.flow is not None:
                 tracing.flow_end(p.flow, "serving.prefill_chunks")
                 p.flow = None
-            p.caches = self._run_chunk(p.caches, req.prompt, p.done, n,
-                                       width)
+            p.caches, p.row = self._run_chunk(p.caches, req.prompt,
+                                              p.done, n, width)
             p.done += n
             self._chunks += 1
             self._chunk_rows += n
@@ -2851,8 +2865,9 @@ class ContinuousServer:
                 p.flow = tracing.flow_begin("serving.prefill_chunks")
 
     def _finish_prefill(self, p: _PendingPrefill) -> None:
-        """Prompt fully chunked: probe the last position, which picks
-        the seed token and sets the slot's lane of the per-slot
+        """Prompt fully chunked: probe the last position from the row
+        its last chunk handed back (one layer and the head), which
+        picks the seed token and sets the slot's lane of the per-slot
         vectors on the device (`_probe_prog`), splice the scratch into
         the slot (dense rows / paged blocks), go live. An admission
         enqueues its named programs and nothing else: every operand
@@ -2864,7 +2879,7 @@ class ContinuousServer:
         req, slot = p.req, p.slot
         plen = len(req.prompt)
         caches, self._cur_dev, self._temp_dev, self._keys_dev, tok0 = \
-            self._probe(p.caches, req.prompt[-1], plen - 1, slot,
+            self._probe(p.caches, p.row, plen - 1, slot,
                         req.temperature, req.key)
         if p.flow is not None:
             tracing.flow_end(p.flow, "serving.prefill_chunks")
@@ -2937,9 +2952,9 @@ class ContinuousServer:
     def _admit(self) -> None:
         """Fill free slots from the queue. A prompt whose remaining
         tokens fit one chunk prefills INLINE (admission latency = one
-        chunk + probe, and instant retires drain without decode
-        steps); a longer prompt reserves the slot as a PENDING prefill
-        and advances chunk-by-chunk in _prefill_tick, interleaved with
+        chunk + the one-layer probe, and instant retires drain without
+        decode steps); a longer prompt reserves the slot as a PENDING
+        prefill and advances chunk-by-chunk in _prefill_tick, interleaved with
         decode.
 
         A request that retires DURING admission (max_new == 1, or an
@@ -3105,10 +3120,6 @@ class ContinuousServer:
         done, plen = 0, len(prompt)
         while done < plen:
             n, width = self._next_chunk(done, plen - done)
-            if not width:
-                # no bucket fits under smax: the rows stay unwritten,
-                # which costs acceptance and never content
-                break
             toks = prompt[done:done + n] + [0] * (width - n)
             self._draft_caches = self._draft_chunk_prog(width)(
                 self._draft_params, self._draft_caches,
@@ -3435,7 +3446,7 @@ class ContinuousServer:
         done = 0
         while done < len(seq):
             n, width = self._next_chunk(done, len(seq) - done)
-            scratch = self._run_chunk(scratch, seq, done, n, width)
+            scratch, _ = self._run_chunk(scratch, seq, done, n, width)
             done += n
         return scratch
 

@@ -1465,21 +1465,48 @@ def _decode_window(params, caches, toks, pos0, cfg, tp_axis=None,
     W sequential steps — the speculative-verification / chunked-prefill
     fast path (every weight is read once per window instead of once per
     token, which is the whole memory-bandwidth case for speculative
-    decoding). need_logits=False is the cache-only prefill: skips the
-    final ln + [B, W, V] unembedding when the caller only wants the KV
-    side effects (returns (caches, None)). `valid`: how many of the W
-    columns are real, for the layers whose state must not consume
-    padding (see `_block_decode`)."""
+    decoding). `valid`: how many of the W columns are real, for the
+    layers whose state must not consume padding (see `_block_decode`).
+
+    need_logits=False is the cache-only prefill: no final ln, no [B, W,
+    V] unembedding, and the compiler drops the LAST layer behind its
+    cache write (its `wo` and FFN are no arguments of the program).
+    Returns (caches, row): the hidden row [B, 1, D] of the last real
+    column (`valid - 1`; the last where `valid` is None) as it ENTERS
+    the last layer, which `_window_tail` takes on to that column's
+    logits: taken ahead of the layer, it keeps nothing of it alive. A
+    last layer of a recurrent kind has consumed the column and cannot
+    run it again: there the row is the one BEHIND the layer, which then
+    runs whole."""
     x = _embed(params, toks, cfg)
     new_caches = []
     for li, (lp, kv) in enumerate(zip(params["layers"], caches)):
+        ahead = x
         x, kv = _block_decode(x, lp, kv, pos0, cfg, tp_axis=tp_axis,
                               ep_axis=ep_axis, ep_size=ep_size, li=li,
                               valid=valid)
         new_caches.append(kv)
-    if not need_logits:
-        return new_caches, None
-    return new_caches, _logits(params, x, cfg).astype(jnp.float32)
+    if need_logits:
+        return new_caches, _logits(params, x, cfg).astype(jnp.float32)
+    if cfg.mixer(cfg.n_layers - 1) not in RECURRENT_KINDS:
+        x = ahead
+    col = x.shape[1] - 1 if valid is None else valid - 1
+    return new_caches, jax.lax.dynamic_slice_in_dim(x, col, 1, axis=1)
+
+
+def _window_tail(params, row, kv, pos, cfg):
+    """The rest of the forward for the row a cache-only `_decode_window`
+    handed back, the column at position `pos`: the LAST layer on that
+    one row over its cache entry `kv` (an idempotent rewrite of the
+    entry's row `pos`: the window wrote it already), final ln and the
+    head. `params["layers"]` may hold the last layer alone. A last
+    layer of a recurrent kind is behind the row: ln and head alone, `kv`
+    as it came. Returns (kv, f32 logits [B, V])."""
+    last = cfg.n_layers - 1
+    if cfg.mixer(last) not in RECURRENT_KINDS:
+        row, kv = _block_decode(row, params["layers"][-1], kv, pos, cfg,
+                                li=last)
+    return kv, _logits(params, row, cfg)[:, 0].astype(jnp.float32)
 
 
 # CHUNK tokens per prefill window: large enough that every weight read
@@ -1512,7 +1539,7 @@ def _prefill_window(params, cfg, caches, prompt, tp_axis=None,
                                     ep_axis=ep_axis, ep_size=ep_size,
                                     need_logits=need_logits
                                     and e == plen)
-        if lg is not None:
+        if need_logits and e == plen:
             last = lg
     return caches, (last[:, -1] if need_logits else None)
 

@@ -626,6 +626,8 @@ class StepRecord(NamedTuple):
                             # enqueued eager, unnamed programs; none
                             # since the seed token's pick and the
                             # per-slot vectors moved into `cb_probe`
+                            # (the one-row probe of the last prompt
+                            # position, one layer deep since PR 44)
     held_ns: int            # inside dispatch calls of named programs
     waited_ns: int          # inside blocking device->host reads
     gap_ns: int             # the caller's, since the step before ended

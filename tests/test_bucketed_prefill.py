@@ -252,9 +252,9 @@ def test_a_prompt_that_ends_near_smax_matches_generate(params, matched):
     the scratch's smax rows the write would be CLAMPED and shift the
     real rows. The host takes the widest bucket that fits and sends the
     rest as a further chunk (within the narrowest bucket of the end:
-    a row at a time through the probe's program). After a radix match
-    of 20 tokens (blocks of 4) the chunks start off every chunk
-    boundary: 20, 36, 52 (room 12: the 8 bucket), 60 (room 4: rows)."""
+    a row at a time through the chunk program at width 1). After a
+    radix match of 20 tokens (blocks of 4) the chunks start off every
+    chunk boundary: 20, 36, 52 (room 12: the 8 bucket), 60 (room 4: rows)."""
     shared = _prompt(20, seed=20)
     srv = ContinuousServer(params, CFG, slots=1, smax=64, paged=True,
                            block_size=4, prefill_chunk=16,
@@ -272,7 +272,7 @@ def test_a_prompt_that_ends_near_smax_matches_generate(params, matched):
     assert srv._chunks - before == 4
     assert srv._next_chunk(48, 13) == (13, 16)
     assert srv._next_chunk(52, 9) == (8, 8)
-    assert srv._next_chunk(60, 1) == (1, 0)
+    assert srv._next_chunk(60, 1) == (1, 1)
     assert out[b] == _solo(params, p, 3)
 
 

@@ -642,6 +642,132 @@ def test_a_sparse_layers_prefill_chunk_builds_no_array_over_the_scratch(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# -- the one-layer probe (PR 44): chunks hand back a hidden row ----------
+
+# cell -> (driver under chipbench/drivers, the chunk's rows on the chip,
+# server sizes cut to what neither program reads: pools, slots).
+# StarCoder2-3B's 256 is the ridge rule's width there; DeepSeek-V2's
+# cell STATES 256
+_PROBE_CELLS = {
+    "starcoder2-3b": ("serving", 256, dict(num_blocks=512)),
+    "deepseek-v2": ("serving_latent", 256,
+                    dict(num_blocks=2048, radix_budget_blocks=64, slots=2)),
+}
+
+
+def _nbytes(tree):
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("cell", sorted(_PROBE_CELLS))
+def test_a_chunk_costs_what_it_cost_and_the_probe_reads_one_layer(
+        sds, monkeypatch, cell):
+    """`jit_chunk` and `jit_probe` at the cell's real size, built by the
+    server from parameter SHAPES. The chunk, which now also returns the
+    hidden row of its last real column, is the program it was (the
+    parent's form: the same window, the scratch alone returned): FLOPs
+    and argument bytes equal to 0.1 %, so the last layer's `wo` and FFN
+    are still no arguments of it, and no array over the vocabulary but
+    the embedding table. The probe's arguments are ONE layer's leaves,
+    final ln, the head, one scratch entry and the per-slot vectors, and
+    the head sees one row: no `[rows, vocab]` array in either."""
+    import importlib
+    import json
+    import re
+    from hpx_tpu.models import transformer as tfm
+    from hpx_tpu.models.serving import ContinuousServer
+    driver, rows, cut = _PROBE_CELLS[cell]
+    drv = importlib.import_module(f"chipbench.drivers.{driver}")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench/configs", cell + ".json")) as f:
+        conf = json.load(f)
+    cfg = drv.build_cfg(conf)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    srv = ContinuousServer(params, cfg, **{**conf["server"], **cut,
+                                           "prefill_chunk": rows})
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    scratch = on_chip(jax.eval_shape(srv._fresh_scratch))
+    chunk = (on_chip(params), scratch, sds((1, rows), jnp.int32),
+             sds((), jnp.int32), sds((), jnp.int32))
+    compiled = srv._chunk_prog(rows).lower(*chunk).compile()
+    parents = jax.jit(
+        lambda params, caches, toks, pos0, n: tfm._decode_window(
+            params, caches, toks, pos0, cfg, need_logits=False,
+            valid=n)[0], donate_argnums=(1,)).lower(*chunk).compile()
+
+    def flops(c):
+        cost = c.cost_analysis()
+        return (cost[0] if isinstance(cost, (list, tuple)) else cost)["flops"]
+
+    def args(c):
+        return c.memory_analysis().argument_size_in_bytes
+    assert abs(flops(compiled) / flops(parents) - 1) < 1e-3
+    assert abs(args(compiled) / args(parents) - 1) < 1e-3
+    # and under the whole tree by the last layer's dropped leaves
+    assert args(compiled) < _nbytes(params) + _nbytes(scratch)
+    text = compiled.as_text()
+    assert "jit_chunk" in text.splitlines()[0]      # the metrics' name
+    over_vocab = rf"\b\w+\[((?:\d+,)*{cfg.vocab})\]"
+    assert not re.findall(over_vocab, text)
+
+    s = srv.slots
+    tail = on_chip(srv._tail_params)
+    lane = (sds((s,), jnp.int32), sds((s,), jnp.float32),
+            sds((s, 2), jnp.uint32), sds((), jnp.int32),
+            sds((), jnp.float32), sds((2,), jnp.uint32))
+    probe = srv._probe_prog().lower(
+        tail, sds((1, 1, cfg.d_model), cfg.dtype), scratch[-1],
+        sds((), jnp.int32), *lane).compile()
+    read = {k: v for k, v in srv._tail_params.items()
+            if k != ("emb" if "head" in params else "head")}
+    want = _nbytes(read) + _nbytes(scratch[-1]) + 2 * cfg.d_model
+    assert want < _nbytes(params) / (cfg.n_layers / 2.5)
+    assert want <= args(probe) < want + 4096 * (len(lane) + 2)
+    text = probe.as_text()
+    assert "jit_probe" in text.splitlines()[0]
+    rows_over = set(re.findall(over_vocab, text))
+    assert rows_over and all(
+        int(np.prod([int(d) for d in dims.split(",")])) == cfg.vocab
+        for dims in rows_over), rows_over
+
+
+def test_a_cells_warm_up_compiles_one_chunk_a_width_and_one_probe():
+    """`chipbench`'s `Loop.warm()` (one tiny request a ladder width,
+    one prompt of `prefill_chunk + widths[0] + 1`) meets every program
+    of a cell: one chunk program a ladder width, ONE probe, step, splice
+    and gather, the parent's count (no chunk carries a head or a pick,
+    so none has a second variant); mixed traffic after it compiles
+    nothing."""
+    from chipbench.drivers.serving import Loop
+    from hpx_tpu.models import transformer as tfm
+    from hpx_tpu.models.serving import ContinuousServer
+    from hpx_tpu.utils.compilemon import count_compiles
+    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
+                                n_layers=2, d_ff=60)
+    srv = ContinuousServer(tfm.init_params(cfg, jax.random.PRNGKey(0)), cfg,
+                           slots=3, smax=96, paged=True, prefill_chunk=32)
+    loop = Loop.__new__(Loop)
+    loop.server = srv
+    with count_compiles() as c:
+        loop.warm()
+    mine = [k for k in tfm._PROGRAMS if cfg in k[1:2]]
+    widths = srv.prefill_buckets
+    assert sorted(k[2] for k in mine if k[0] == "cb_chunk") == list(widths)
+    assert sum(k[0] == "cb_probe" for k in mine) == 1
+    assert int(c) == srv._prog_misses == len(widths) + 1 + 3
+    r = np.random.RandomState(0)
+    with count_compiles() as window:
+        for plen in (3, 9, 31, 33, 50, 64, 70):
+            srv.submit([int(t) for t in r.randint(1, 64, plen)], max_new=5)
+        srv.run()
+    assert int(window) == 0 and not srv.failed
+
+
 # -- flash attention (training forward/backward, ring chunk) -------------
 
 @pytest.mark.parametrize("n,nkv", [(8, 8), (16, 4)],

@@ -350,10 +350,11 @@ def _admission_logits(srv, prompt):
     probe = srv._probe_prog()
     seen = []
     # the probe's logits never leave its program (it returns the seed
-    # token): the same window over the same scratch, before the probe
-    # donates it
-    logits = jax.jit(lambda params, caches, tok, pos: tfm._decode_window(
-        params, caches, tok, pos, srv.cfg, need_logits=True)[1][0, -1])
+    # token): the same tail (last layer's weights, the last chunk's
+    # hidden row, the last layer's scratch entry, the position) over
+    # the same entry, before the probe donates it
+    logits = jax.jit(lambda params, row, kv, pos: tfm._window_tail(
+        params, row, kv, pos, srv.cfg)[1][0])
 
     def spy(*a):
         seen.append(np.asarray(logits(*a[:4])))
@@ -398,9 +399,10 @@ def test_a_document_served_from_the_tree_gives_the_same_logits(
                                 paged_kernel=kernel, **kw)
     # the loader publishes the document's two blocks at its retirement.
     # One token more than the document, so that the probe's one-row
-    # pass (the last prompt token again, the same row to rounding but
-    # through a matmul of another shape) lands behind the blocks that
-    # are shared: both servers' document rows then come out of chunks
+    # pass of the last layer (the last prompt token again, the same row
+    # to rounding but through a matmul of another shape) lands behind
+    # the blocks that are shared: both servers' document rows then come
+    # out of chunks
     shared = server()
     shared.submit(doc + [7], max_new=1)
     shared.run()

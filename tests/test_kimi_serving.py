@@ -352,8 +352,8 @@ def test_a_reference_with_a_piece_left_out_disagrees(toy, leave_out):
 
 def test_two_requests_share_a_slot_one_after_the_other(toy):
     """One slot: the second request's state starts from zeros (the
-    reset), nothing of the first survives; a one-token prompt has no
-    chunk at all, only the probe."""
+    reset), nothing of the first survives; a one-token prompt is one
+    chunk of one real column, then the probe."""
     conf, cfg, params = toy
     srv = ContinuousServer(params, cfg, paged=True, slots=1, smax=144,
                            prefill_chunk=CHUNK)
@@ -571,5 +571,6 @@ def test_served_at_the_ceiling_width_gives_the_tokens_of_128(toy,
     assert (st["prefill_chunk"], st["prefill_chunk_source"]) == (
         serving._CHUNK_CEILING, "ridge")
     assert got == base and srv.failed == {}
-    # the probe takes the last token: 699: 512 128 64; 512: 512; 89: 128
-    assert (at128._chunks, srv._chunks) == (6 + 4 + 1, 3 + 1 + 1)
+    # the chunks run to the prompt's end: 700: 512 128 64; 513: 512 8;
+    # 90: 128
+    assert (at128._chunks, srv._chunks) == (6 + 5 + 1, 3 + 2 + 1)

@@ -1,8 +1,9 @@
 """Inside `ContinuousServer.step()` the only programs that reach the
 device are the ones `_program()` hands out (PR 40): the seed token is
 picked and the slot's lane of the per-slot vectors is set inside
-`cb_probe`, the vectors' host copies are NumPy, and every operand of a
-named program is host NumPy. Measured at the two real boundaries:
+`cb_probe` (one layer deep since PR 44: it starts from the hidden row
+the last chunk handed back), the vectors' host copies are NumPy, and
+every operand of a named program is host NumPy. Measured at the two real boundaries:
 
 * compiles: a fresh server driven through a mixed-length workload
   compiles exactly `srv._prog_misses` XLA modules, i.e. not one
@@ -18,9 +19,11 @@ out of the counts, as `test_compile_guard.py` does."""
 import glob
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hpx_tpu.models import serving
 from hpx_tpu.models import transformer as tfm
 from hpx_tpu.models.serving import ContinuousServer
 from hpx_tpu.utils.compilemon import count_compiles
@@ -97,6 +100,42 @@ def test_a_workload_compiles_its_named_programs_and_nothing_else(
     assert sorted(out) == rids and not srv.failed
     assert srv._prog_hits + srv._prog_misses > 10
     assert int(c) == srv._prog_misses
+
+
+def _matmuls(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("dot_general")
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_the_probe_holds_one_layers_matmuls(params, mode):
+    """`jit_probe` is ONE layer deep (PR 44): its jaxpr holds the
+    matmuls of the last layer on one row and the head's, whatever the
+    model's depth, where a one-row window through the whole model holds
+    every layer's; a last layer that is recurrent (the "sala" toy's)
+    ran in the chunk, and the probe is ln and head alone."""
+    srv = _server(params, mode)
+    cfg, weights = srv.cfg, srv.params
+    last = cfg.n_layers - 1
+    scratch = [serving._scratch_entry(cfg, srv.smax, i)
+               for i in range(cfg.n_layers)]
+    row = jnp.zeros((1, 1, cfg.d_model), cfg.dtype)
+    pos = np.int32(5)
+    probe = _matmuls(
+        srv._probe_prog()._prog, srv._tail_params, row, scratch[last], pos,
+        srv._feedback(), *srv._lanes(), np.int32(0), np.float32(0.0),
+        srv._no_key)
+    head = _matmuls(lambda x: tfm._logits(weights, x, cfg), row)
+    layer = _matmuls(
+        lambda x, kv: tfm._block_decode(x, weights["layers"][last], kv, pos,
+                                        cfg, li=last), row, scratch[last])
+    whole = _matmuls(
+        lambda kv: tfm._decode_window(weights, kv, jnp.zeros((1, 1), int),
+                                      pos, cfg), scratch)
+    assert head == 1 and layer >= 4 and whole > layer + head
+    recurrent = cfg.mixer(last) in tfm.RECURRENT_KINDS
+    assert recurrent == (mode == "sala")
+    assert probe == head + (0 if recurrent else layer)
+    assert len(srv._tail_params["layers"]) == 1
 
 
 def _step_programs(logdir):

@@ -251,7 +251,7 @@ def test_serving_programs_profiled(profiler):
     from hpx_tpu.models import transformer as tfm
     from hpx_tpu.models.serving import ContinuousServer
     cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4,
-                                head_dim=8, n_layers=2, d_ff=48)
+                                head_dim=8, n_layers=2, d_ff=44)
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     srv = ContinuousServer(params, cfg, slots=2, smax=64)
     srv.submit([3, 1, 4, 1, 5], max_new=6)
