@@ -15,6 +15,9 @@ Every server gets the serving counters::
                                                     (windowed RateCounter)
     /serving{locality#L/server#i}/prefill/chunks    prefill chunk dispatches
     /serving{locality#L/server#i}/prefill/pending   in-flight chunked prefills
+    /serving{locality#L/server#i}/prefill/admit-wait-steps  step() calls between
+                                a request's slot and its first token's
+                                program, summed over the admissions
     /serving{locality#L/server#i}/prefill/chunk-width    rows of a full chunk
     /serving{locality#L/server#i}/prefill/chunk-derived  1: the width follows
                                 the device's ridge; 0: an argument or the
@@ -95,7 +98,7 @@ Paged servers additionally export the cache counters::
                                                           V) a slot, layer and
                                                           step
 
-Models with recurrent ("kda", "lightning") layers add their per-slot
+Models with recurrent ("kda", "lightning", "mamba") layers add their per-slot
 state, models with latent-attention ("mla") layers their rows on the
 full group, models with sparse layers their index and what the decode
 steps' queries chose (from the positions: the device's choice is never
@@ -198,6 +201,8 @@ def register_server(srv) -> str:
         pc.CallbackCounter(_read(ref, lambda s: s._chunks)))
     put("serving", "prefill/pending",
         pc.CallbackCounter(_read(ref, lambda s: len(s._pending))))
+    put("serving", "prefill/admit-wait-steps",
+        pc.CallbackCounter(_read(ref, lambda s: s._admit_wait_steps)))
     put("serving", "prefill/chunk-width",
         pc.CallbackCounter(_read(ref, lambda s: s.prefill_chunk)))
     put("serving", "prefill/chunk-derived",

@@ -509,7 +509,8 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
     A layer's MIXER kind names what `pools` holds: K/V pools (above); a
     "kda" layer's per-slot (state [B, H, d, d] float32, conv tail [B,
     K - 1, 3 H d]), no table, no positions; a "lightning" layer's
-    per-slot (state,) alike; an "mla" layer's (latent pool [num_blocks,
+    per-slot (state,) and a "mamba" layer's (state [B, N, C] float32,
+    conv tail [B, (K - 1) C]) alike; an "mla" layer's (latent pool [num_blocks,
     1, block_size, R],) on the full group's table; a "sparse" layer's
     (K pool, V pool, index pool [num_blocks, block_size / stride * Nkv,
     H] float32, the last step's chosen block ids [B, Nkv, K] and their
@@ -540,6 +541,13 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
         def attend(q, k, v):                                # noqa: F811
             o, carry = lightning_mix(q, k, v, cfg.lightning_decay(li),
                                      *pools)
+            return o, (carry, None)
+    elif "mamba" in lp:
+        from ..ops.mamba import mamba_mix
+
+        def attend(u):                                      # noqa: F811
+            o, carry = mamba_mix(u, lp["mamba"], *pools,
+                                 eps=cfg.norm_eps)
             return o, (carry, None)
     elif "sparse" in lp:
         from ..ops.sparse_attention import paged_sparse_decode
@@ -615,7 +623,9 @@ def _scratch_entry(cfg: TransformerConfig, smax: int, i: int):
     kind: (k, v) [1, smax, n_kv, hd] (a "sparse" layer's too: its index
     is the means of k's rows); (latent rows [1, smax, 1, R],); or a
     recurrent layer's state of no tokens, zeros: (state [1, H, d, d]
-    float32, conv tail [1, K - 1, 3 H d]) or, "lightning", (state,)."""
+    float32, conv tail [1, K - 1, 3 H d]), "lightning" (state,), or
+    "mamba" (state [1, N, C] float32: the channels on the minor axis;
+    conv tail [1, (K - 1) C]: its rows side by side; ops/mamba.py)."""
     kind = cfg.mixer(i)
     if kind == "mla":
         return (jnp.zeros((1, smax, 1, cfg.mla_row), cfg.dtype),)
@@ -626,6 +636,10 @@ def _scratch_entry(cfg: TransformerConfig, smax: int, i: int):
     if kind == "lightning":
         h, d = cfg.lightning_heads, cfg.lightning_head_dim
         return (jnp.zeros((1, h, d, d), jnp.float32),)
+    if kind == "mamba":
+        c = cfg.mamba_d_inner
+        return (jnp.zeros((1, cfg.mamba_d_state, c), jnp.float32),
+                jnp.zeros((1, (cfg.mamba_d_conv - 1) * c), cfg.dtype))
     shape = (1, smax, cfg.kv_heads, cfg.head_dim)
     return (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
@@ -739,6 +753,7 @@ class _PendingPrefill:
                                    # entries point at trash)
     wt: Optional[WindowTable] = None   # blocks held in the window group
     flow: Optional[int] = None     # tracing flow id chaining the chunks
+    step0: int = 0                 # the step() call that gave the slot
     row: Any = None                # [1, 1, d_model], what the newest
                                    # chunk handed back: its last real
                                    # column ahead of the last layer,
@@ -880,8 +895,8 @@ class ContinuousServer:
         self._win = wins.pop() if wins else 0
         from ..core.config import runtime_config
         rc = runtime_config()
-        # the mixer kinds beside softmax attention: a "kda" or
-        # "lightning" layer keeps a per-slot recurrent state, an "mla"
+        # the mixer kinds beside softmax attention: a "kda", "lightning"
+        # or "mamba" layer keeps a per-slot recurrent state, an "mla"
         # layer latent rows, a "sparse" layer an index beside its K/V
         # pools; all live in the paged cache pytree only (`_init_paged`)
         self._recurrent = cfg.recurrent
@@ -992,7 +1007,8 @@ class ContinuousServer:
                 "recurrent state (and a sparse layer's index entry) "
                 "rolled back, and the latent and the sparse walk attend "
                 "one row a slot (models/serving.py _spec_step, "
-                "ops/kda.py, ops/lightning.py, ops/sparse_attention.py, "
+                "ops/kda.py, ops/lightning.py, ops/mamba.py, "
+                "ops/sparse_attention.py, "
                 "ops/paged_attention.paged_latent_attention)")
         if self._spec and self._win:
             raise NotImplementedError(
@@ -1135,6 +1151,9 @@ class ContinuousServer:
         # observability
         self._chunks = 0                # prefill chunk dispatches
         self._chunk_rows = 0            # prompt tokens they computed
+        # step() calls between a request's slot and its first token's
+        # program, summed over the admissions
+        self._admit_wait_steps = 0
         self._prog_hits = 0             # program-cache hits
         self._prog_misses = 0           # program-cache misses (compiles)
         self.ttft: Dict[int, float] = {}  # rid -> submit->seed seconds
@@ -2450,10 +2469,18 @@ class ContinuousServer:
         """The chunk width this server prefills at, where it came from
         (`arg` | `config` | `ridge`: derived from the device's ridge
         over the weights a chunk reads, `_ridge_chunk`) and the prompt
-        tokens a chunk dispatch has carried so far — the
+        tokens a chunk dispatch has carried so far; the chunked
+        prefills under way (`_prefill_tick` gives ONE of them ONE chunk
+        a step) and the step() calls that lay between a request's slot
+        and its first token's program, summed over the admissions (0
+        for a prompt that prefilled inline) — the
         /serving{...}/prefill/* counters."""
         return {"prefill_chunk": self.prefill_chunk,
                 "prefill_chunk_source": self._prefill_chunk_src,
+                "prefill_chunks": self._chunks,
+                "prefill_rows": self._chunk_rows,
+                "prefill_pending": len(self._pending),
+                "admit_wait_steps": self._admit_wait_steps,
                 "prefill_rows_per_chunk":
                     self._chunk_rows / self._chunks if self._chunks
                     else 0.0}
@@ -2733,6 +2760,7 @@ class ContinuousServer:
             p = _PendingPrefill(req=req, slot=slot,
                                 caches=self._fresh_scratch(),
                                 done=0, seq=self._pf_seq)
+        p.step0 = self._step_n
         self._pending[slot] = p
         self._admit_defers.pop(req.rid, None)   # admitted: ladder done
         return p
@@ -2878,6 +2906,7 @@ class ContinuousServer:
         drafts, a synchronous server)."""
         req, slot = p.req, p.slot
         plen = len(req.prompt)
+        self._admit_wait_steps += self._step_n - p.step0
         caches, self._cur_dev, self._temp_dev, self._keys_dev, tok0 = \
             self._probe(p.caches, p.row, plen - 1, slot,
                         req.temperature, req.key)
@@ -3991,8 +4020,10 @@ class ContinuousServer:
         """(tokens, state) of a live slot on a recurrent model: the
         token ids its recurrent state has consumed (prompt ++ every
         landed token but the last, which is fed next) and the float32
-        state [H, d, d] of the model's FIRST recurrent layer, read from
-        the device. For a caller that checks the state against a
+        state of the model's FIRST recurrent layer, read from the
+        device: [H, d, d], or a "mamba" layer's in the PUBLISHED
+        orientation [d_inner, d_state] (the cache keeps the channels on
+        the minor axis). For a caller that checks the state against a
         recomputation; call `flush()` first, so that no step is in
         flight and the host's frontier is the device's."""
         req = self._slot_req[slot]
@@ -4002,8 +4033,9 @@ class ContinuousServer:
                              "(flush() first)")
         li = next(i for i, k in enumerate(self.cfg.layer_mixer)
                   if k in RECURRENT_KINDS)
+        state = np.asarray(self._pools[li][0][slot])
         return (req.prompt + req.tokens[:-1],
-                np.asarray(self._pools[li][0][slot]))
+                state.T if self.cfg.mixer(li) == "mamba" else state)
 
     def sparse_selection(self, slot: int):
         """(tokens, ids, count) of a live slot on a model with sparse

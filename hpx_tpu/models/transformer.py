@@ -86,7 +86,7 @@ class RopeSpec:
 
 # the mixer kinds that keep a per-slot state which is a function of the
 # tokens consumed (reset at admission, recomputed at a restore)
-RECURRENT_KINDS = ("kda", "lightning")
+RECURRENT_KINDS = ("kda", "lightning", "mamba")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,8 +164,10 @@ class TransformerConfig:
     # one cached row of mla_rank + mla_rope_dim values a token),
     # "sparse" (softmax attention over K/V pairs of which the QUERY
     # chooses the blocks it reads, by an index of compressed keys:
-    # ops/sparse_attention.py) or "lightning" (decayed linear attention
-    # over a per-slot recurrent state, ops/lightning.py).
+    # ops/sparse_attention.py), "lightning" (decayed linear attention
+    # over a per-slot recurrent state, ops/lightning.py) or "mamba" (a
+    # selective scan: a diagonal state-space recurrence over a per-slot
+    # recurrent state behind a short convolution, ops/mamba.py).
     # Empty = every layer "attn".
     layer_mixer: Tuple[str, ...] = ()
     kda_heads: int = 0              # heads of kda_head_dim x kda_head_dim
@@ -204,6 +206,15 @@ class TransformerConfig:
     # lightning_head_dim x lightning_head_dim a slot
     lightning_heads: int = 0
     lightning_head_dim: int = 0
+    # a "mamba" layer's numbers: mamba_d_inner channels, each a state of
+    # mamba_d_state values; mamba_d_conv taps of the short convolution
+    # (with a bias where mamba_conv_bias); dt through a low rank of
+    # mamba_dt_rank
+    mamba_d_inner: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 0
+    mamba_conv_bias: bool = True
     # RMSNorm over each head of q and k (one learned scale a layer):
     # the "sparse" and "lightning" mixers' (their parameters name it)
     qk_norm: bool = False
@@ -364,6 +375,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         ks = jax.random.split(k, 12)
         if kind in ("sparse", "lightning"):
             return _init_gated_mixer(cfg, kind, ks, nrm)
+        if kind == "mamba":
+            return _init_mamba_mixer(cfg, ks, nrm)
         if kind == "kda":
             h, hd, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank
             return {"kda": {
@@ -488,6 +501,36 @@ def _init_gated_mixer(cfg: TransformerConfig, kind: str, ks, nrm):
     return {kind: out}
 
 
+def _init_mamba_mixer(cfg: TransformerConfig, ks, nrm):
+    """The leaves of a "mamba" mixer. Projections are MATRICES [in,
+    out] ("win": the u stream's columns, then the gate z's). "A_log" is
+    [d_state, d_inner], the published [d_inner, d_state] with the
+    channels on the minor axis, as the cached state lies
+    (ops/mamba.py); A_log = log(1..d_state) and dt_bias the inverse
+    softplus of a log-uniform step in [1e-3, 1e-1] (the published
+    initialisation: decays in the trained range); A_log, dt_bias and D
+    stay float32."""
+    d, c, n = cfg.d_model, cfg.mamba_d_inner, cfg.mamba_d_state
+    r, k = cfg.mamba_dt_rank, cfg.mamba_d_conv
+    step = jnp.exp(jax.random.uniform(
+        ks[5], (c,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    out = {"win": nrm(ks[0], (d, 2 * c), 1.0 / math.sqrt(d)),
+           "conv": nrm(ks[1], (k, c), 1.0 / math.sqrt(k)),
+           "wx": nrm(ks[2], (c, r + 2 * n), 1.0 / math.sqrt(c)),
+           "dt_norm": jnp.ones((r,), cfg.dtype),
+           "b_norm": jnp.ones((n,), cfg.dtype),
+           "c_norm": jnp.ones((n,), cfg.dtype),
+           "wdt": nrm(ks[3], (r, c), 1.0 / math.sqrt(r)),
+           "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+           "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+               1, n + 1, dtype=jnp.float32))[:, None], (n, c)),
+           "D": jnp.ones((c,), jnp.float32),
+           "wo": nrm(ks[4], (c, d), 1.0 / math.sqrt(c))}
+    if cfg.mamba_conv_bias:
+        out["conv_b"] = nrm(ks[6], (c,), 0.02)
+    return {"mamba": out}
+
+
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpecs: heads/ffn over tp; MoE experts over dp (the ep
     layout — see TransformerConfig); everything else replicated."""
@@ -609,7 +652,8 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
     x), whatever its kind. For attention the operands are (q, k, v)
     and the body's own cache write and attention read answer (dense
     cache, paged pools, the sp ring); for "kda" (pre, g, beta) and the
-    body's state and conv tail (`_kda_mixer`); for "mla" (q, row) and
+    body's state and conv tail (`_kda_mixer`), for "mamba" the u stream
+    and the same (`_mamba_mixer`); for "mla" (q, row) and
     the body's latent rows (`_mla_mixer`). `pos`: the positions of x's
     columns, [S] or [B, S]. `moe(h) -> out` is the body's sparse FFN
     (it closes its own collectives). What differs between LAYERS is in
@@ -629,6 +673,8 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
     elif "lightning" in lp:
         o, carry = _lightning_mixer(h, lp["lightning"], cfg, attend, pos,
                                     cfg.rope_of(li))
+    elif "mamba" in lp:
+        o, carry = _mamba_mixer(h, lp["mamba"], attend)
     elif "mla" in lp:
         o, carry = _mla_mixer(h, lp["mla"], cfg, attend, pos,
                               cfg.rope_of(li))
@@ -718,6 +764,20 @@ def _lightning_mixer(h, m, cfg: TransformerConfig, attend, pos,
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                               + cfg.norm_eps) * m["onorm"].astype(f32)
     return _gated_out(h, o, m), carry
+
+
+def _mamba_mixer(h, m, attend):
+    """A Mamba-1 mixer around the body's recurrent core. h [B, W, D] ->
+    (y [B, W, D], carry). Here: [u; z] = W_in h; after the core the
+    gate y * silu(z) in float32 and W_out. The core (`attend(u)` =
+    ops/mamba.mamba_mix over the body's state and conv tail:
+    convolution with its bias, SiLU, W_x, the three RMSNorms, W_dt,
+    softplus, the selective scan, the skip D u) has no positions."""
+    c = m["wo"].shape[0]
+    uz = h @ _dq(m["win"], h)
+    y, carry = attend(uz[..., :c])
+    y = (y * jax.nn.silu(uz[..., c:].astype(jnp.float32))).astype(h.dtype)
+    return y @ _dq(m["wo"], y), carry
 
 
 def _kda_mixer(h, m, cfg: TransformerConfig, attend):
@@ -1379,7 +1439,8 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
     Smax, 1, R],); a "sparse" layer's the K/V pair (its index is the
     means of K's rows: nothing more is kept here), each row choosing
     the blocks it attends; a "lightning" layer's (state,), `valid` as
-    for "kda"."""
+    for "kda"; a "mamba" layer's (state [B, N, C], conv tail [B, (K -
+    1) C]), `valid` alike."""
     qpos = jnp.asarray(write_at) + jnp.arange(x.shape[1])
 
     def attend(q, k, v):
@@ -1412,6 +1473,12 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
         def attend(q, k, v):                                # noqa: F811
             return lightning_mix(q, k, v, cfg.lightning_decay(li), *kv,
                                  valid=valid)
+    elif "mamba" in lp:
+        from ..ops.mamba import mamba_mix
+
+        def attend(u):                                      # noqa: F811
+            return mamba_mix(u, lp["mamba"], *kv, valid=valid,
+                             eps=cfg.norm_eps)
     elif "mla" in lp:
         def attend(q, row):                                 # noqa: F811
             lat = jax.lax.dynamic_update_slice_in_dim(

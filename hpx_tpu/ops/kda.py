@@ -50,19 +50,22 @@ _HEADS_PER_STEP = 8     # heads one grid step of hpx_kda_step updates
 
 
 def short_conv(pre: jax.Array, tail: jax.Array, w: jax.Array,
-               valid=None):
-    """Depthwise causal convolution over time, K taps a channel, no
-    bias. pre [B, W, C]: the window's pre-activation rows; tail [B,
-    K - 1, C]: the rows before it; w [K, C] (w[K - 1] multiplies the
-    current row). Returns (out [B, W, C] float32, the new tail: the
-    K - 1 rows ending at the window's last VALID row; `valid` None =
-    all W, else a scalar count, rows past it being padding)."""
+               valid=None, bias=None):
+    """Depthwise causal convolution over time, K taps a channel, plus
+    `bias` [C] where the layer has one. pre [B, W, C]: the window's
+    pre-activation rows; tail [B, K - 1, C]: the rows before it; w [K,
+    C] (w[K - 1] multiplies the current row). Returns (out [B, W, C]
+    float32, the new tail: the K - 1 rows ending at the window's last
+    VALID row; `valid` None = all W, else a scalar count, rows past it
+    being padding)."""
     k = w.shape[0]
     n = pre.shape[1]
     full = jnp.concatenate([tail, pre.astype(tail.dtype)], axis=1)
     wf = w.astype(jnp.float32)
     out = sum(full[:, j:j + n].astype(jnp.float32) * wf[j]
               for j in range(k))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     at = n if valid is None else valid
     return out, jax.lax.dynamic_slice_in_dim(full, at, k - 1, axis=1)
 

@@ -642,6 +642,121 @@ def test_a_sparse_layers_prefill_chunk_builds_no_array_over_the_scratch(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# -- selective-scan layers (the Jamba2-3B cell) ---------------------------
+
+# 256 slots; a Mamba layer's state 16 x 5120 float32 a slot (the channels
+# on the minor axis) and a flat conv tail of 3 x 5120; chunks of 512 rows
+# over the b=1 scratch; ONE K/V head of 128 under 20 query heads, pages
+# of 64 rows
+_M_SLOTS, _M_C, _M_N = 256, 5120, 16
+
+
+def test_the_mamba_states_update_runs_in_place(sds):
+    """`hpx_mamba_step` over the cell's state: read once, written once,
+    the donated 84 MB of a layer neither copied nor re-laid; u, dt and
+    y ride as [slots, C] rows, eight slots a grid step, with no copy
+    into a one-row tiling."""
+    from hpx_tpu.ops import mamba
+    b, c, n = _M_SLOTS, _M_C, _M_N
+    row, col = sds((b, c), jnp.float32), sds((b, n), jnp.float32)
+    compiled = jax.jit(
+        lambda u, dt, bm, cm, a, s: mamba.mamba_step(
+            u, dt, bm, cm, a, s, kernel="pallas", interpret=False),
+        donate_argnums=(5,)).lower(
+        row, row, col, col, sds((n, c), jnp.float32),
+        sds((b, n, c), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "hpx_mamba_step" in text
+    assert _copies_of(text, f"f32[{b},{n},{c}]") == []
+    assert _copies_of(text, f"f32[{b},{c}]") == []
+    assert _copies_of(text, f"f32[{b},1,{c}]") == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= b * n * c * 4
+
+
+@pytest.mark.parametrize("rows", [8, 512])
+def test_the_mamba_scan_compiles_at_the_cells_widths(sds, rows):
+    """`hpx_mamba_scan` over a chunk of the ladder's narrowest and
+    widest width: a true scan over the rows with a channel block's
+    state in registers, no temporary over rows x state."""
+    from hpx_tpu.ops import mamba
+    c, n = _M_C, _M_N
+    row, col = sds((1, rows, c), jnp.float32), sds((1, rows, n), jnp.float32)
+    compiled = jax.jit(
+        lambda u, dt, bm, cm, a, s, v: mamba.mamba_chunk(
+            u, dt, bm, cm, a, s, v, kernel="pallas", interpret=False),
+        donate_argnums=(5,)).lower(
+        row, row, col, col, sds((n, c), jnp.float32),
+        sds((1, n, c), jnp.float32), sds((), jnp.int32)).compile()
+    assert "hpx_mamba_scan" in compiled.as_text()
+    # the lane-spread B and C columns, and nothing of rows x N x C
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * rows * 2 * n * 128 * 4 + (1 << 20)
+
+
+def test_ssm_server_programs_copy_neither_state_nor_tail_nor_pool(
+        sds, monkeypatch):
+    """The server's own programs for a Mamba layer, an attention layer
+    over ONE K/V head and a Mamba LAST layer of the cell's mixer widths
+    (a narrow d_model), built from parameter SHAPES at 256 slots:
+    `jit_step` holds one Pallas call a mixer, the state, the flat tail
+    and the K/V pools donated and left where they lie; the splice
+    writes a slot's row of the state and the tail in place; `jit_probe`
+    behind the recurrent last layer reads ln and the tied head alone."""
+    from hpx_tpu.models.serving import ContinuousServer
+    from hpx_tpu.models.transformer import TransformerConfig, init_params
+    c, n, s = _M_C, _M_N, _M_SLOTS
+    cfg = TransformerConfig(
+        vocab=512, d_model=256, n_heads=20, head_dim=128, n_kv_heads=1,
+        n_layers=3, d_ff=512, dtype=jnp.bfloat16, norm="rmsnorm",
+        norm_eps=1e-6, mlp="swiglu", tied=True,
+        layer_mixer=("mamba", "attn", "mamba"), mamba_d_inner=c,
+        mamba_d_state=n, mamba_d_conv=4, mamba_dt_rank=160)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    srv = ContinuousServer(params, cfg, paged=True, slots=s, smax=1792,
+                           block_size=64, prefill_chunk=512)
+    assert srv._paged_kernel == "fused"
+    assert srv._alloc.num_blocks == s * 28 + 1
+    assert [a.shape for a in srv._pools[0]] == [(s, n, c), (s, 3 * c)]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    text = srv._paged_step_prog().lower(
+        on_chip(params), on_chip(srv._pools), None,
+        sds((s,), jnp.int32), sds((s,), jnp.int32),
+        (sds((s, srv._maxb), jnp.int32),),
+        sds((s,), jnp.float32), sds((s, 2), jnp.uint32)).compile().as_text()
+    for name in ("hpx_mamba_step", "hpx_paged_fused"):
+        assert name in text
+    assert _copies_of(text, f"f32[{s},{n},{c}]") == []
+    assert _copies_of(text, f"bf16[{s},{3 * c}]") == []
+    assert _copies_of(text, f"bf16[{s},3,{c}]") == []
+    assert _pool_ops(text, srv._pools[1][0]) == []
+    scratch = on_chip(jax.eval_shape(srv._fresh_scratch))
+    text = srv._paged_splice_prog().lower(
+        on_chip(srv._pools), None, scratch,
+        (sds((srv._maxb,), jnp.int32),), sds((), jnp.int32)
+    ).compile().as_text()
+    assert _copies_of(text, f"f32[{s},{n},{c}]") == []
+    assert _copies_of(text, f"bf16[{s},{3 * c}]") == []
+    lane = (sds((s,), jnp.int32), sds((s,), jnp.float32),
+            sds((s, 2), jnp.uint32), sds((), jnp.int32),
+            sds((), jnp.float32), sds((2,), jnp.uint32))
+    probe = srv._probe_prog().lower(
+        on_chip(srv._tail_params), sds((1, 1, cfg.d_model), cfg.dtype),
+        scratch[-1], sds((), jnp.int32), *lane).compile()
+    assert "jit_probe" in probe.as_text().splitlines()[0]
+    assert "dot" not in "".join(
+        ln for ln in probe.as_text().splitlines() if "5120" in ln
+        and (" dot(" in ln or " convolution(" in ln))
+    chunk = srv._chunk_prog(512).lower(
+        on_chip(params), scratch, sds((1, 512), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile()
+    assert "hpx_mamba_scan" in chunk.as_text()
+    assert "jit_chunk" in chunk.as_text().splitlines()[0]
+
+
 # -- the one-layer probe (PR 44): chunks hand back a hidden row ----------
 
 # cell -> (driver under chipbench/drivers, the chunk's rows on the chip,
@@ -660,6 +775,27 @@ def _nbytes(tree):
                for x in jax.tree.leaves(tree))
 
 
+def _cell_server(monkeypatch, cell, driver, rows, cut):
+    """(cfg, parameter SHAPES, server) of a benchmark cell at its real
+    widths and depth: the configuration's file through its driver's
+    `build_cfg`, the server's sizes cut by `cut`, chunks of `rows`;
+    `jax.default_backend` steered so that the kernels are the chip's."""
+    import importlib
+    import json
+    from hpx_tpu.models import transformer as tfm
+    from hpx_tpu.models.serving import ContinuousServer
+    drv = importlib.import_module(f"chipbench.drivers.{driver}")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench/configs", cell + ".json")) as f:
+        conf = json.load(f)
+    cfg = drv.build_cfg(conf)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return cfg, params, ContinuousServer(
+        params, cfg, **{**conf["server"], **cut, "prefill_chunk": rows})
+
+
 @pytest.mark.parametrize("cell", sorted(_PROBE_CELLS))
 def test_a_chunk_costs_what_it_cost_and_the_probe_reads_one_layer(
         sds, monkeypatch, cell):
@@ -672,22 +808,10 @@ def test_a_chunk_costs_what_it_cost_and_the_probe_reads_one_layer(
     the embedding table. The probe's arguments are ONE layer's leaves,
     final ln, the head, one scratch entry and the per-slot vectors, and
     the head sees one row: no `[rows, vocab]` array in either."""
-    import importlib
-    import json
     import re
     from hpx_tpu.models import transformer as tfm
-    from hpx_tpu.models.serving import ContinuousServer
     driver, rows, cut = _PROBE_CELLS[cell]
-    drv = importlib.import_module(f"chipbench.drivers.{driver}")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench/configs", cell + ".json")) as f:
-        conf = json.load(f)
-    cfg = drv.build_cfg(conf)
-    params = jax.eval_shape(
-        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    srv = ContinuousServer(params, cfg, **{**conf["server"], **cut,
-                                           "prefill_chunk": rows})
+    cfg, params, srv = _cell_server(monkeypatch, cell, driver, rows, cut)
 
     def on_chip(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
@@ -734,6 +858,68 @@ def test_a_chunk_costs_what_it_cost_and_the_probe_reads_one_layer(
     assert rows_over and all(
         int(np.prod([int(d) for d in dims.split(",")])) == cfg.vocab
         for dims in rows_over), rows_over
+
+
+# cell -> (driver, the chunk's rows, the server cut to what no program
+# reads, the operations `jit_step` / `jit_chunk` / `jit_probe` lower to
+# at the cell's real widths and depth). The counts are PR 44's
+# (91e4982), read by lowering that checkout and PR 45's side by side:
+# a third recurrent kind, a bias on `short_conv` and two host counters
+# left all fifteen programs as they were. A PR that changes one of
+# these programs changes its number here, knowingly.
+_OLD_CELLS = {
+    "starcoder2-3b": ("serving", 256, dict(num_blocks=512, slots=4),
+                      (9438, 6410, 805)),
+    "laguna-xs2": ("serving_mixed", 512, dict(num_blocks=1024, slots=4),
+                   (2818, 1398, 985)),
+    "kimi-linear-48b": ("serving_hybrid", 512,
+                        dict(num_blocks=1024, slots=2), (10204, 11782, 1040)),
+    "deepseek-v2": ("serving_latent", 256,
+                    dict(num_blocks=2048, radix_budget_blocks=64, slots=2),
+                    (3220, 2189, 1129)),
+    "minicpm-sala": ("serving_sparse", 512, dict(num_blocks=2048, slots=2),
+                     (3211, 2443, 1085)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_OLD_CELLS))
+def test_an_old_cells_programs_lower_to_the_operations_they_had(
+        sds, monkeypatch, cell):
+    """The step, chunk and probe programs of a cell the benchmark had
+    before the selective-scan kind, LOWERED for the described chip (not
+    compiled: ~5 s a cell) from parameter shapes at the cell's real
+    widths: each holds the operations it held at the parent commit, so
+    a new mixer kind costs the old cells no operand, no output and no
+    operation (PR 41 and PR 43 were refused for their `setup_s`)."""
+    import re
+    driver, rows, cut, want = _OLD_CELLS[cell]
+    cfg, params, srv = _cell_server(monkeypatch, cell, driver, rows, cut)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    def ops(lowered):
+        return len(re.findall(
+            r"^\s+(?:%\S+(?:, %\S+)* = )?\"?(?:stablehlo|func|chlo)\.",
+            lowered.as_text(), re.M))
+    s = srv.slots
+    tables = (sds((s, srv._maxb), jnp.int32),)
+    if srv._win:
+        tables += (sds((s, srv._ring), jnp.int32),)
+    lane = (sds((s,), jnp.int32), sds((s,), jnp.float32),
+            sds((s, 2), jnp.uint32))
+    step = srv._paged_step_prog().lower(
+        on_chip(params), on_chip(srv._pools), None, lane[0], lane[0],
+        tables, *lane[1:])
+    scratch = on_chip(jax.eval_shape(srv._fresh_scratch))
+    chunk = srv._chunk_prog(rows).lower(
+        on_chip(params), scratch, sds((1, rows), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32))
+    probe = srv._probe_prog().lower(
+        on_chip(srv._tail_params), sds((1, 1, cfg.d_model), cfg.dtype),
+        scratch[-1], sds((), jnp.int32), *lane, sds((), jnp.int32),
+        sds((), jnp.float32), sds((2,), jnp.uint32))
+    assert (ops(step), ops(chunk), ops(probe)) == want
 
 
 def test_a_cells_warm_up_compiles_one_chunk_a_width_and_one_probe():

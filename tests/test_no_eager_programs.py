@@ -41,8 +41,17 @@ SALA = tfm.TransformerConfig(
     sparse_stride=2, sparse_block=8, sparse_topk=2, sparse_local=8,
     sparse_dense_len=16, lightning_heads=4, lightning_head_dim=8,
     qk_norm=True, emb_scale=12.0, residual_scale=0.25, logit_scale=0.5)
+# a state-space hybrid: selective-scan layers (a state and a conv tail a
+# slot, reset at admission) beside attention over one K/V head, the
+# LAST layer recurrent, a tied head
+SSM = tfm.TransformerConfig(
+    vocab=64, d_model=32, n_heads=4, head_dim=8, n_kv_heads=1, n_layers=3,
+    d_ff=40, norm="rmsnorm", norm_eps=1e-6, mlp="swiglu", tied=True,
+    layer_mixer=("mamba", "attn", "mamba"), mamba_d_inner=64,
+    mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=4)
+MODELS = {"sala": SALA, "ssm": SSM}
 EXECUTE = "PjRtCpuExecutable::Execute"
-MODES = ["paged", "dense", "sala"]
+MODES = ["paged", "dense", "sala", "ssm"]
 # what each request of a workload asks for beside its prompt
 KINDS = {
     "greedy": [{}] * 5,
@@ -59,12 +68,13 @@ PLENS = [3, 21, 9, 12, 17, 5, 14]      # inline and chunked admissions
 @pytest.fixture(scope="module")
 def params():
     return {"sala": tfm.init_params(SALA, jax.random.PRNGKey(2)),
+            "ssm": tfm.init_params(SSM, jax.random.PRNGKey(3)),
             None: tfm.init_params(CFG, jax.random.PRNGKey(1))}
 
 
 def _server(params, mode):
-    if mode == "sala":
-        return ContinuousServer(params[mode], SALA, slots=3, smax=64,
+    if mode in MODELS:
+        return ContinuousServer(params[mode], MODELS[mode], slots=3, smax=64,
                                 prefill_chunk=8, prefill_buckets="4,8",
                                 paged=True)
     return ContinuousServer(params[None], CFG, slots=3, smax=64,
@@ -111,8 +121,8 @@ def test_the_probe_holds_one_layers_matmuls(params, mode):
     """`jit_probe` is ONE layer deep (PR 44): its jaxpr holds the
     matmuls of the last layer on one row and the head's, whatever the
     model's depth, where a one-row window through the whole model holds
-    every layer's; a last layer that is recurrent (the "sala" toy's)
-    ran in the chunk, and the probe is ln and head alone."""
+    every layer's; a last layer that is recurrent (the "sala" and the
+    "ssm" toys') ran in the chunk, and the probe is ln and head alone."""
     srv = _server(params, mode)
     cfg, weights = srv.cfg, srv.params
     last = cfg.n_layers - 1
@@ -133,7 +143,7 @@ def test_the_probe_holds_one_layers_matmuls(params, mode):
                                       pos, cfg), scratch)
     assert head == 1 and layer >= 4 and whole > layer + head
     recurrent = cfg.mixer(last) in tfm.RECURRENT_KINDS
-    assert recurrent == (mode == "sala")
+    assert recurrent == (mode in MODELS)
     assert probe == head + (0 if recurrent else layer)
     assert len(srv._tail_params["layers"]) == 1
 
@@ -178,3 +188,20 @@ def test_a_step_enqueues_its_dispatches_and_nothing_else(
     # a chunk one program, a decode step one
     assert sum(r.dispatches for r in recs) == (
         3 * len(rids) + srv._chunks + sum(r.eager_ns > 0 for r in recs))
+
+
+def test_importing_the_package_leaves_the_selective_scan_unimported():
+    """`ops/mamba.py` is imported where it is used (as `lightning.py`
+    and `sparse_attention.py` are): neither `import hpx_tpu` nor what
+    the stencil cell's driver imports pays for it."""
+    import subprocess
+    import sys
+    code = ("import sys, hpx_tpu, hpx_tpu.models.serving, "
+            "chipbench.drivers.hpx_dataflow; "
+            "print(sorted(m for m in sys.modules if m.endswith("
+            "('ops.mamba', 'ops.lightning', 'ops.sparse_attention'))))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env={**__import__("os").environ,
+                                         "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "[]"
