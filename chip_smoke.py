@@ -96,7 +96,8 @@ def phase_hpx(log2_n=24, fused_log2=19, fused_steps=1024, chain=8,
     import jax.numpy as jnp
 
     import hpx_tpu as hpx
-    from hpx_tpu.ops.stencil import heat_step_best, multistep
+    from hpx_tpu.models.stencil1d import heat_part
+    from hpx_tpu.ops.stencil import heat_step_best, multistep, takes_kernel
 
     n = 1 << log2_n
     rng = np.random.default_rng(seed)
@@ -143,6 +144,20 @@ def phase_hpx(log2_n=24, fused_log2=19, fused_steps=1024, chain=8,
           "hpx: dataflow heat chain differs from NumPy "
           f"(max err {np.abs(got - uh).max():.3g})")
 
+    # ... the dataflow node's body, one partition between two halo points
+    # that are not its own ends: exact float32 (0.25 is a power of two)
+    part = jax.jit(heat_part)
+    eh = np.concatenate([xh[:1] + 7, xh, xh[-1:] - 3])
+    halo_l, halo_r = jnp.asarray(eh[:1]), jnp.asarray(eh[-1:])
+    check(np.array_equal(
+        np.asarray(part(halo_l, x, halo_r, coef)),
+        xh + np.float32(0.25) * ((eh[:-2] - np.float32(2.0) * xh) + eh[2:])),
+        "hpx: heat_part differs from the float32 recurrence")
+    part_kernels = kernel_count(part, halo_l, x, halo_r, coef)
+    check(part_kernels == int(takes_kernel(n, x.dtype,
+                                           jax.default_backend())),
+          f"hpx: heat_part holds {part_kernels} kernels at {n} points")
+
     # ... and the fused in-VMEM multi-step kernel
     m = 1 << fused_log2
     vh = xh[:m]
@@ -162,6 +177,7 @@ def phase_hpx(log2_n=24, fused_log2=19, fused_steps=1024, chain=8,
                       else "python"),
         "kernels": {
             "heat_step_best": kernel_count(step, x),
+            "heat_part": part_kernels,
             "multistep": kernel_count(
                 jax.jit(lambda u: multistep(u, coef, fused_steps)),
                 jnp.asarray(vh)),

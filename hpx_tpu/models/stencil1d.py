@@ -12,7 +12,10 @@ on identical physics:
                     left, mid, right) — the future DAG throttled only by
                     dependencies. Partition updates are device dispatches;
                     halos are 1-element array slices; the host never
-                    blocks inside the loop.
+                    blocks inside the loop. A node's body (heat_part) is
+                    the blocked one-pass kernel of ops/stencil where
+                    `ops.stencil.takes_kernel` says the platform and the
+                    partition allow it, one XLA expression elsewhere.
   stencil_fused     TPU-first production path: T steps fused per dispatch
                     (ops/stencil.multistep — pallas in-VMEM when it fits).
 
@@ -33,7 +36,7 @@ from ..exec.tpu import TpuExecutor
 from ..futures.async_ import Launch
 from ..futures.dataflow import dataflow, unwrapping
 from ..futures.future import Future, make_ready_future
-from ..ops.stencil import heat_step, multistep
+from ..ops.stencil import heat_step, heat_step_halo, multistep, takes_kernel
 
 
 @dataclasses.dataclass
@@ -79,7 +82,15 @@ def heat_part(left: jax.Array, middle: jax.Array,
     left/right are whole neighbor partitions; shipping only the boundary
     element is the same optimization 1d_stencil_8 makes for the
     distributed case, and the right call for device memory traffic.
+
+    On a TPU a float32 partition of whole slabs goes through the blocked
+    kernel, which reads `middle` once and writes the result once; the
+    XLA expression below reads it three times. Both give the same
+    float32 result bit for bit.
     """
+    if middle.ndim == 1 and takes_kernel(middle.shape[0], middle.dtype,
+                                         jax.default_backend()):
+        return heat_step_halo(left, middle, right, coef)
     um = jnp.concatenate([left, middle, right])
     return um[1:-1] + coef * (um[:-2] - 2.0 * um[1:-1] + um[2:])
 

@@ -4,14 +4,21 @@ Reference analog: the `heat_part` inner loop of examples/1d_stencil/
 1d_stencil_4.cpp (u'[i] = u[i] + k*dt/dx^2 * (u[i-1] - 2u[i] + u[i+1]),
 periodic neighbors) — the Mcells/s hot loop of BASELINE config #2.
 
-TPU-first design: a single heat step is HBM-bandwidth-bound (read u, write
-u'). The win is fusing T steps per dispatch:
+A single heat step is HBM-bandwidth-bound (read u, write u'):
+  * heat_step: the XLA roll expression, any shape, any platform.
+  * heat_step_halo: ONE blocked Pallas kernel, slabs of (rows, 128)
+    streamed through a 1-D grid with each slab's two outside neighbours
+    as SMEM scalars: one stream in, one out. `stencil1d.heat_part` (the
+    dataflow node's body) and `pallas_heat_step` (the periodic ring,
+    the halo form fed from itself) both call it; `takes_kernel` says
+    for which platform, dtype and length.
+Fusing T steps per dispatch takes the traffic away instead:
   * pallas_multistep: whole array resident in VMEM, T updates without
     touching HBM in between — compute-bound instead of HBM-bound for
     arrays that fit VMEM (~<=2M f32).
   * xla_multistep: lax.fori_loop of the fused roll-expression under jit —
     works at any size, one HBM round-trip per step.
-Both are shape-static, branch-free, and VPU-friendly (8x128 lanes; arrays
+All are shape-static, branch-free, and VPU-friendly (8x128 lanes; arrays
 are laid out 2D (rows, 128)).
 """
 
@@ -22,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 LANES = 128
 
@@ -111,19 +119,17 @@ _VMEM_F32_LIMIT = 1 << 19
 def _pallas_blocked_kernel(u_ref, edges_ref, coef_ref, out_ref):
     """ONE heat step on a (R, 128) slab streamed from HBM.
 
-    Flattened-order neighbors in the (rows, 128) layout are lane shifts
+    Flattened-order neighbours in the (rows, 128) layout are lane shifts
     with a row carry, computed with the SLAB-periodic wrap (the slab's
-    first/last elements borrow from its own far edge). The 2 elements
-    per slab that wrap wrongly are patched IN-KERNEL from `edges_ref`
-    (SMEM: [grid, 2] true global neighbors, 8 bytes per slab gathered
-    once in XLA) — so ONE program streams one input + one output
-    (8 B/cell, the HBM roofline's assumption). The round-1..3 variant
-    patched them with a host-side scatter instead, which forced a
-    second full pass over `out` and capped the bench at ~61% of roof.
-    Separate halo-block INPUTS (vs these SMEM scalars) were measured to
-    stall the DMA pipeline (~15 points of roof); XLA's roll/concat
-    lowering of the same step materializes shifted copies (~4x
-    traffic)."""
+    first and last elements borrow from its own far edge). The two
+    elements a slab that wrap wrongly are patched in the kernel from
+    `edges_ref` (SMEM, [2 * grid]: the true left neighbour of each
+    slab's first element, then the true right neighbour of each slab's
+    last), so one program streams one input and one output: 8 bytes a
+    cell, what the HBM roofline assumes. The sum is associated as
+    `stencil1d.heat_part` and the plain float32 recurrence associate
+    it, (left - 2u) + right, so the kernel's result is theirs bit for
+    bit."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -137,49 +143,68 @@ def _pallas_blocked_kernel(u_ref, edges_ref, coef_ref, out_ref):
     carry_r = pltpu.roll(u[:, LANES - 1:], 1, axis=0)
     left = jnp.where(col == 0, carry_r, lane_r)
     first_cell = jnp.logical_and(row == 0, col == 0)
-    left = jnp.where(first_cell, edges_ref[i, 0], left)
+    left = jnp.where(first_cell, edges_ref[i], left)
 
     lane_l = pltpu.roll(u, LANES - 1, axis=1)
     carry_l = pltpu.roll(u[:, :1], u.shape[0] - 1, axis=0)
     right = jnp.where(col == LANES - 1, carry_l, lane_l)
     last_cell = jnp.logical_and(row == u.shape[0] - 1, col == LANES - 1)
-    right = jnp.where(last_cell, edges_ref[i, 1], right)
+    right = jnp.where(last_cell, edges_ref[pl.num_programs(0) + i], right)
 
-    out_ref[:] = u + coef * ((left + right) - 2.0 * u)
+    out_ref[:] = u + coef * ((left - 2.0 * u) + right)
 
 
-_BLOCK_ROWS = 2048           # 1 MB/slab: deep DMA pipeline; 8192 looked
-                             # ~5% faster in the r4 sweep but OOMs the
-                             # 16 MB scoped VMEM under some jit wrappings
-                             # (5 live slab temporaries x 4 MB)
+# Rows of 128 points a slab (a grid step): 2 MB in, 2 MB out, 14 MB of the
+# 16 MB of scoped VMEM with the pipeline's second buffers and the body's
+# temporaries. Measured inside jit(heat_part) at 2^27 points on a v5e
+# (PERF.md, PR 46): 1,024 rows 1.913 ms, 2,048 1.675, 4,096 1.650, 8,192
+# 1.655 (and 28 MB); a copy through the same BlockSpecs 1.643 at 4,096.
+_BLOCK_ROWS = 4096
+
+
+def _slab_rows(n: int) -> int:
+    """Rows a slab of an n-point array takes; 0 where the blocked kernel
+    cannot tile it (whole (8, 128) tiles, slabs that divide the rows)."""
+    if n <= 0 or n % (8 * LANES):
+        return 0
+    rows = n // LANES
+    r = min(_BLOCK_ROWS, rows)
+    return r if rows % r == 0 else 0
+
+
+def takes_kernel(nx: int, dtype, backend: str) -> bool:
+    """Whether a 1-D array of `nx` points of `dtype` on `backend` takes
+    the blocked kernel (`heat_step_halo`) or the XLA expression. The
+    kernel is Mosaic's: TPU only, float32, whole slabs."""
+    return (backend == "tpu" and jnp.dtype(dtype) == jnp.float32
+            and _slab_rows(nx) > 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pallas_heat_step(u: jax.Array, coef,
-                     interpret: bool = False) -> jax.Array:
-    """Single periodic heat step for arrays too big for VMEM: slabs
-    stream through a 1-D grid with the global-periodic seam neighbors
-    fed as per-slab SMEM scalars. Requires len(u) % 128 == 0 and
-    rows % block == 0 (the benchmark shapes; use heat_step_best for
-    automatic fallback)."""
+def heat_step_halo(left: jax.Array, u: jax.Array, right: jax.Array, coef,
+                   interpret: bool = False) -> jax.Array:
+    """One heat step of `u` whose outer neighbours are `left[-1]` and
+    `right[0]`: slabs of `_BLOCK_ROWS` rows stream through a 1-D grid,
+    each slab's two outside neighbours handed in as SMEM scalars, read
+    from `u`'s own slab seams and, at the two ends, from the halos.
+    `takes_kernel` says which shapes it accepts."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n = u.shape[0]
+    r = _slab_rows(n)
+    if not r:
+        raise ValueError(f"{n} points do not tile into slabs of "
+                         f"{_BLOCK_ROWS} rows of {LANES}")
     rows = n // LANES
-    r = min(_BLOCK_ROWS, rows)
-    assert n % LANES == 0 and rows % r == 0 and r % 8 == 0, (n, rows, r)
-    u2 = u.reshape(rows, LANES)
     grid = rows // r
+    # edges[i], edges[grid + i]: the left neighbour of slab i's first
+    # point and the right neighbour of its last, one gather at the seams
+    seams = np.arange(1, grid, dtype=np.int32) * (r * LANES)
+    edges = jnp.concatenate(
+        [left[-1:], u[np.concatenate([seams - 1, seams])], right[:1]])
 
-    # true global neighbors of each slab's first/last element — a tiny
-    # fused gather (2 scalars per slab)
-    import numpy as _np
-    starts = jnp.asarray(_np.arange(grid) * r * LANES, jnp.int32)
-    edges = jnp.stack([u[(starts - 1) % n],
-                       u[(starts + r * LANES) % n]], axis=1)
-
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _pallas_blocked_kernel,
         name="hpx_stencil_blocked",
         grid=(grid,),
@@ -189,22 +214,25 @@ def pallas_heat_step(u: jax.Array, coef,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((r, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(u2.shape, u2.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), u.dtype),
         interpret=interpret,
-    )(u2, edges, jnp.asarray([coef], dtype=u.dtype)).reshape(n)
-    return out
+    )(u.reshape(rows, LANES), edges,
+      jnp.asarray([coef], dtype=u.dtype)).reshape(n)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pallas_heat_step(u: jax.Array, coef,
+                     interpret: bool = False) -> jax.Array:
+    """One periodic heat step by the blocked kernel: the halo form fed
+    from the ring's own ends."""
+    return heat_step_halo(u[-1:], u, u[:1], coef, interpret=interpret)
 
 
 def heat_step_best(u: jax.Array, coef) -> jax.Array:
     """Best-available single step: the blocked pallas kernel on TPU
     when shapes allow, the XLA roll formulation otherwise."""
-    n = u.shape[0]
-    rows = n // LANES if n % LANES == 0 else 0
-    r = min(_BLOCK_ROWS, rows) if rows else 0
-    # == "tpu", not "not cpu": the kernel is Mosaic-only — a GPU backend
-    # must take the XLA path, not crash in pallas lowering (advisor r2)
-    if (jax.default_backend() == "tpu" and rows
-            and rows % r == 0 and r % 8 == 0):
+    if u.ndim == 1 and takes_kernel(u.shape[0], u.dtype,
+                                    jax.default_backend()):
         return pallas_heat_step(u, coef)
     return heat_step(u, coef)
 

@@ -995,6 +995,24 @@ def test_pallas_heat_step_compiles(sds):
     _kernel_text(lambda u: stencil.pallas_heat_step(u, np.float32(0.1)), u)
 
 
+def test_heat_part_kernel_streams_the_partition_once(sds):
+    """What `stencil1d.heat_part` hands its arguments to on a TPU (here
+    the backend is the CPU, so `heat_part` itself takes the XLA path), at
+    the benchmark's partition: the kernel is there, no XLA fusion makes
+    a partition-sized result beside it, and nothing partition-sized is
+    materialised (one stream in, one out)."""
+    import re
+    n = 1 << 27
+    assert stencil.takes_kernel(n, jnp.float32, "tpu")
+    compiled = jax.jit(stencil.heat_step_halo).lower(
+        sds((1,), jnp.float32), sds((n,), jnp.float32),
+        sds((1,), jnp.float32), sds((), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    assert not re.search(rf"= f32\[{n}\]\S* fusion\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 def test_pallas_multistep_compiles(sds):
     u = sds((1 << 19,), jnp.float32)
     _kernel_text(

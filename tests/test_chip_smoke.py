@@ -37,7 +37,8 @@ def test_exits_nonzero_and_names_the_platform_on_cpu():
 def test_phase_hpx_agrees_with_numpy():
     out = cs.phase_hpx(log2_n=14, fused_log2=12, fused_steps=16, chain=3)
     assert out["scheduler"] in ("native", "python")
-    assert set(out["kernels"]) == {"heat_step_best", "multistep"}
+    assert set(out["kernels"]) == {"heat_step_best", "heat_part",
+                                   "multistep"}
 
 
 def test_phase_serve_fused_interpret_equals_generate():

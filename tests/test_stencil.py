@@ -13,10 +13,12 @@ import pytest
 
 import hpx_tpu as hpx
 from hpx_tpu.models.stencil1d import (
-    StencilParams, gather_dataflow_result, init_domain, stencil_dataflow,
-    stencil_fused, stencil_serial,
+    StencilParams, gather_dataflow_result, heat_part, init_domain,
+    stencil_dataflow, stencil_fused, stencil_serial,
 )
-from hpx_tpu.ops.stencil import heat_step, pallas_multistep, xla_multistep
+from hpx_tpu.ops.stencil import (
+    heat_step, pallas_multistep, takes_kernel, xla_multistep,
+)
 from hpx_tpu.parallel import (
     make_mesh, shard_1d, sharded_heat_step, sharded_multistep,
 )
@@ -113,16 +115,65 @@ def test_conservation():
                                float(jnp.sum(init_domain(p))), rtol=1e-3)
 
 
-def test_pallas_heat_step_seams_interpret(monkeypatch):
-    """The blocked kernel's in-kernel seam patch (r4: per-slab SMEM edge
-    scalars replaced the host-side scatter): every slab-boundary element
-    must get its TRUE global-periodic neighbors. Small slabs force
-    multiple grid steps so all seam cases (interior + wraparound) hit."""
+@pytest.fixture
+def small_slabs(monkeypatch):
+    """Slabs of 8 rows, so a few thousand points take several grid
+    steps and every kind of seam (inside, both partition ends) is hit."""
     from hpx_tpu.ops import stencil as st
     monkeypatch.setattr(st, "_BLOCK_ROWS", 8)
+    jitted = (st.heat_step_halo, st.pallas_heat_step)
+    for f in jitted:        # a trace made at another slab height is stale
+        f.clear_cache()
+    yield st
+    for f in jitted:
+        f.clear_cache()
+
+
+@pytest.mark.parametrize("slabs", [1, 2, 5])
+@pytest.mark.parametrize("seed", [7, 2**31 + 5])
+def test_halo_kernel_is_heat_part_bit_for_bit(small_slabs, slabs, seed):
+    """The blocked kernel with the two halo points as scalars against
+    the XLA `heat_part` (what the CPU backend takes): the same float32
+    arithmetic in the same order, so equal bitwise, at every slab seam
+    and at both partition ends. The halos are NOT the ring's own ends,
+    so a kernel that wrapped the partition onto itself would differ."""
+    st = small_slabs
+    n, coef = 8 * 128 * slabs, jnp.float32(0.3)
+    assert st._slab_rows(n) == 8
+    rng = np.random.default_rng(seed)
+    u = jnp.asarray(rng.random(n, np.float32))
+    left = jnp.asarray(rng.random(1, np.float32) + 2.0)
+    right = jnp.asarray(rng.random(1, np.float32) - 3.0)
+    got = np.asarray(st.heat_step_halo(left, u, right, coef, interpret=True))
+    want = np.asarray(jax.jit(heat_part)(left, u, right, coef))
+    np.testing.assert_array_equal(got, want)
+    ring = np.asarray(st.pallas_heat_step(u, coef, interpret=True))
+    assert got[0] != ring[0] and got[-1] != ring[-1]
+    np.testing.assert_array_equal(got[1:-1], ring[1:-1])
+
+
+def test_pallas_heat_step_is_the_halo_form_fed_from_the_ring(small_slabs):
+    """`pallas_heat_step` is `heat_step_halo` with the ring's own ends
+    as halos: every slab-boundary element gets its true global-periodic
+    neighbours, and the result is `heat_step`'s."""
+    st = small_slabs
     n, coef = 8 * 128 * 4, jnp.float32(0.3)
     u = jnp.asarray(np.random.default_rng(7).random(n, np.float32))
     got = st.pallas_heat_step(u, coef, interpret=True)
-    want = heat_step(u, coef)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jax.jit(heat_step)(u, coef)))
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("nx,tiles", [
+    (64, False),                # under one (8, 128) tile
+    (1024, True),               # one tile, one slab
+    ((1 << 20) + 128, False),   # whole rows, not whole tiles
+    (1 << 27, True),            # the benchmark's partition
+])
+def test_takes_kernel(nx, tiles, backend):
+    """Which path a partition takes is a pure function of what the code
+    can observe: the platform, the dtype, the length."""
+    assert takes_kernel(nx, jnp.float32, backend) == \
+        (tiles and backend == "tpu")
+    assert not takes_kernel(nx, jnp.bfloat16, backend)
