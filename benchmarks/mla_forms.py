@@ -14,7 +14,16 @@ Two questions a cell cannot split, one line of JSON a reading:
    scores the 640-wide row and weighs its first 512 columns) against
    the EXPANDED form (K and V of every head materialised from the
    latent a block, then 192-wide scores and 128-wide values), both
-   walked in the same blocks under the same online softmax.
+   walked in the same blocks under the same online softmax. The
+   absorbed form walks the scratch a GROUP of heads at a time,
+   `LATENT_PAIRS_A_GROUP` (head, query) pairs at most: `--pairs
+   N[,N...]` times it at other sizes of a group, 0 = one walk of the
+   whole chunk (what the program did up to PR 46: past 16,384 pairs
+   its float32 accumulator leaves the chip's fast memory and a
+   256-wide chunk cost three 128-wide ones), and `row_groups` is the
+   same chunk cut by query rows instead (slower: PERF.md section 6,
+   PR 47). `--heads 32` is Kimi-Linear's shape (a scratch of 4,224
+   rows; give `--rows 3584 --widths 512`).
 
 Times as benchmarks/flash_tune.py `paged_measure` takes them: `n` calls
 inside ONE jitted loop, the slope over two `n`. Exits non-zero without
@@ -23,6 +32,7 @@ a TPU.
 Usage: python benchmarks/mla_forms.py [--kernel] [--chunk]
            [--entries 16,32,64] [--block 16]
            [--positions 1535,15231] [--rows 15360] [--widths 128,256]
+           [--pairs 0,4096,8192,16384,32768] [--heads 128]
 """
 
 import argparse
@@ -39,6 +49,7 @@ from bench import slope_time  # noqa: E402 — one timing discipline
 SHAPES = {"deepseek-v2": (64, 128, 1576, (1023, 8191, 15231, 25215)),
           "kimi-linear": (48, 32, 264, (511, 1535, 4223))}
 ROW, RANK, BS = 640, 512, 16
+CHUNK_SMAX = {128: 25216, 32: 4224}     # heads -> the cell's scratch rows
 
 
 def device_us(jax, step, x, operands, samples=3):
@@ -125,10 +136,10 @@ def expanded_chunk(jax, jnp, blk):
     return attend
 
 
-def chunk_lines(jax, jnp, rows, widths):
+def chunk_lines(jax, jnp, rows, widths, pairs, h=128):
     from hpx_tpu.models import transformer as tfm
-    h, dn, dr, dv = 128, 128, 64, 128
-    smax = 25216
+    dn, dr, dv = 128, 64, 128
+    smax = CHUNK_SMAX[h]
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
     lat = jax.random.normal(ks[0], (1, smax, ROW), jnp.bfloat16)
     wuk = (jax.random.normal(ks[1], (RANK, h, dn)) * RANK ** -0.5).astype(
@@ -136,31 +147,54 @@ def chunk_lines(jax, jnp, rows, widths):
     wuv = (jax.random.normal(ks[2], (RANK, h, dv)) * RANK ** -0.5).astype(
         jnp.bfloat16)
     blk = tfm.LATENT_ROWS_A_BLOCK
+    kept = tfm.LATENT_PAIRS_A_GROUP
     expanded = expanded_chunk(jax, jnp, blk)
-    for w in widths:
-        qpos = rows + jnp.arange(w)
-        q = (jax.random.normal(ks[3], (1, w, h, dn + dr)) * 0.3).astype(
-            jnp.bfloat16)
 
+    def absorbed_by(row_groups):
         def absorbed(qq, lat, wuk, wuv, qpos):
             qa = jnp.einsum("bqhn,rhn->bqhr", qq[..., :dn], wuk)
             qf = jnp.concatenate(
                 [qa, qq[..., dn:],
                  jnp.zeros(qa.shape[:-1] + (ROW - RANK - dr,), qq.dtype)], -1)
-            o = tfm._latent_attention(qf, lat, qpos, RANK, 0.1)
+            rs = qq.shape[1] // row_groups
+            o = jnp.concatenate(
+                [tfm._latent_attention(qf[:, i:i + rs], lat, qpos[i:i + rs],
+                                       RANK, 0.1)
+                 for i in range(0, qq.shape[1], rs)], 1)
             o = jnp.einsum("bqhr,rhv->bqhv", o, wuv)
             return jnp.pad(o, ((0, 0),) * 3 + ((0, dn + dr - dv),))
+        return absorbed
 
-        def expand(qq, lat, wuk, wuv, qpos):
-            o = expanded(qq, lat, wuk, wuv, qpos, 0.1)
-            return jnp.pad(jnp.moveaxis(o, 1, 2),
-                           ((0, 0),) * 3 + ((0, dn + dr - dv),))
-        for name, fn in (("absorbed", absorbed), ("expanded", expand)):
-            us = device_us(jax, fn, q, (lat, wuk, wuv, qpos))
-            print(json.dumps({"what": "prefill_chunk_attention",
-                              "form": name, "width": w, "rows": rows,
-                              "block_rows": blk, "heads": h,
-                              "us_per_layer": round(us, 1)}), flush=True)
+    def expand(qq, lat, wuk, wuv, qpos):
+        o = expanded(qq, lat, wuk, wuv, qpos, 0.1)
+        return jnp.pad(jnp.moveaxis(o, 1, 2),
+                       ((0, 0),) * 3 + ((0, dn + dr - dv),))
+    for w in widths:
+        qpos = rows + jnp.arange(w)
+        q = (jax.random.normal(ks[3], (1, w, h, dn + dr)) * 0.3).astype(
+            jnp.bfloat16)
+        line = {"what": "prefill_chunk_attention", "width": w, "rows": rows,
+                "block_rows": blk, "heads": h}
+        # past the kept constant also the same chunk cut by ROWS (one
+        # walk a group of rows: the constant is out of the way)
+        forms = [(p, 1) for p in pairs] + [
+            (0, rg) for rg in (2, 4) if w * h > kept]
+        timed = {}                      # (head groups, row groups) -> us
+        for p, rg in forms:
+            tfm.LATENT_PAIRS_A_GROUP = p or w * h
+            hg = tfm.latent_groups(1, w // rg, h)
+            if (hg, rg) not in timed:
+                timed[hg, rg] = device_us(jax, absorbed_by(rg), q,
+                                          (lat, wuk, wuv, qpos))
+            print(json.dumps({**line, "form": "absorbed",
+                              "pairs_a_group": p, "kept": p == kept,
+                              "groups": hg, "row_groups": rg,
+                              "us_per_layer": round(timed[hg, rg], 1)}),
+                  flush=True)
+        tfm.LATENT_PAIRS_A_GROUP = kept
+        us = device_us(jax, expand, q, (lat, wuk, wuv, qpos))
+        print(json.dumps({**line, "form": "expanded",
+                          "us_per_layer": round(us, 1)}), flush=True)
 
 
 def main() -> int:
@@ -172,6 +206,11 @@ def main() -> int:
     ap_.add_argument("--positions", default="")
     ap_.add_argument("--rows", type=int, default=15360)
     ap_.add_argument("--widths", default="128,256")
+    ap_.add_argument("--pairs", default="",
+                     help="sizes of a group; 0 = one walk (default: 0 "
+                          "and the program's constant)")
+    ap_.add_argument("--heads", type=int, default=128,
+                     choices=sorted(CHUNK_SMAX))
     args = ap_.parse_args()
     import jax
     import jax.numpy as jnp
@@ -187,8 +226,11 @@ def main() -> int:
                      args.block,
                      [int(p) for p in args.positions.split(",") if p])
     if args.chunk or both:
+        from hpx_tpu.models.transformer import LATENT_PAIRS_A_GROUP
         chunk_lines(jax, jnp, args.rows,
-                    [int(w) for w in args.widths.split(",")])
+                    [int(w) for w in args.widths.split(",")],
+                    [int(p) for p in args.pairs.split(",") if p]
+                    or [0, LATENT_PAIRS_A_GROUP], args.heads)
     return 0
 
 

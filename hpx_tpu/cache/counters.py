@@ -14,6 +14,9 @@ Every server gets the serving counters::
     /serving{locality#L/server#i}/tokens/rate       decode tokens/sec
                                                     (windowed RateCounter)
     /serving{locality#L/server#i}/prefill/chunks    prefill chunk dispatches
+    /serving{locality#L/server#i}/prefill/latent_groups  walks of the scratch
+                                those chunks' latent layers made (over chunks x
+                                latent layers: wide chunks walk in groups)
     /serving{locality#L/server#i}/prefill/pending   in-flight chunked prefills
     /serving{locality#L/server#i}/prefill/admit-wait-steps  step() calls between
                                 a request's slot and its first token's
@@ -199,6 +202,8 @@ def register_server(srv) -> str:
     put("serving", "tokens/rate", srv._rate)
     put("serving", "prefill/chunks",
         pc.CallbackCounter(_read(ref, lambda s: s._chunks)))
+    put("serving", "prefill/latent_groups",
+        pc.CallbackCounter(_read(ref, lambda s: s._latent_groups)))
     put("serving", "prefill/pending",
         pc.CallbackCounter(_read(ref, lambda s: len(s._pending))))
     put("serving", "prefill/admit-wait-steps",

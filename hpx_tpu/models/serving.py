@@ -129,6 +129,7 @@ from .transformer import (
     _pick_row,
     _tree_key,
     _window_tail,
+    latent_groups,
 )
 
 __all__ = ["ContinuousServer", "DeadlineExceededError",
@@ -1151,6 +1152,9 @@ class ContinuousServer:
         # observability
         self._chunks = 0                # prefill chunk dispatches
         self._chunk_rows = 0            # prompt tokens they computed
+        # walks of the scratch their latent layers made
+        # (`transformer.latent_groups` a chunk's width and layer)
+        self._latent_groups = 0
         # step() calls between a request's slot and its first token's
         # program, summed over the admissions
         self._admit_wait_steps = 0
@@ -2473,11 +2477,15 @@ class ContinuousServer:
         prefills under way (`_prefill_tick` gives ONE of them ONE chunk
         a step) and the step() calls that lay between a request's slot
         and its first token's program, summed over the admissions (0
-        for a prompt that prefilled inline) — the
-        /serving{...}/prefill/* counters."""
+        for a prompt that prefilled inline); `latent_groups`: the walks
+        of the scratch the chunks' latent layers made (`transformer.
+        latent_groups` of a chunk's width, a latent layer: over
+        `prefill_chunks` x the latent layers where chunks are cut into
+        groups of heads) — the /serving{...}/prefill/* counters."""
         return {"prefill_chunk": self.prefill_chunk,
                 "prefill_chunk_source": self._prefill_chunk_src,
                 "prefill_chunks": self._chunks,
+                "latent_groups": self._latent_groups,
                 "prefill_rows": self._chunk_rows,
                 "prefill_pending": len(self._pending),
                 "admit_wait_steps": self._admit_wait_steps,
@@ -2889,6 +2897,8 @@ class ContinuousServer:
             p.done += n
             self._chunks += 1
             self._chunk_rows += n
+            self._latent_groups += self.cfg.layer_mixer.count("mla") \
+                * latent_groups(1, width, self.cfg.n_heads)
             if p.remaining:
                 p.flow = tracing.flow_begin("serving.prefill_chunks")
 

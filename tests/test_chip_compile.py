@@ -471,6 +471,33 @@ def test_the_latent_rows_write_leaves_the_pool_where_it_lies(sds, cell):
         * _C_BS, 2)
 
 
+def test_a_wide_chunks_latent_accumulator_stays_in_fast_memory(sds):
+    """`_latent_attention` for a 256-wide chunk of DeepSeek-V2's 128
+    heads over the cell's scratch of 25,216 rows: walked in groups of
+    `LATENT_PAIRS_A_GROUP` pairs the compiler gives every float32
+    array of a block loop (running state, scores, the accumulator)
+    fast memory, `S(1)` in the compiled text, and the program's
+    temporaries are the joined output (17 MB). As ONE walk (up to PR
+    46) the accumulator `f32[1,128,256,512]` alone stays in HBM, 67 MB
+    of temporaries that every block reads and writes whole: a 256-wide
+    chunk cost three 128-wide ones on the chip."""
+    import re
+    from hpx_tpu.models import transformer as tfm
+    compiled = jax.jit(
+        lambda q, lat, qpos: tfm._latent_attention(q, lat, qpos, _K_RANK,
+                                                   0.1)).lower(
+        sds((1, 256, 128, _K_ROW), jnp.bfloat16),
+        sds((1, 25216, _K_ROW), jnp.bfloat16), sds((256,), jnp.int32)
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 40e6
+    loops = [ln for ln in compiled.as_text().splitlines()
+             if re.search(r" while\(", ln)]
+    assert len(loops) == tfm.latent_groups(1, 256, 128) == 2
+    carried = [a for ln in loops
+               for a in re.findall(rf"f32\[[\d,]*{_K_RANK}\]\{{[^}}]*\}}", ln)]
+    assert carried and all("S(1)" in a for a in carried), carried
+
+
 def test_hybrid_server_step_program_copies_neither_state_nor_pool(
         sds, monkeypatch):
     """The server's own `jit_step` for a KDA layer and an MLA layer of
@@ -866,7 +893,11 @@ def test_a_chunk_costs_what_it_cost_and_the_probe_reads_one_layer(
 # (91e4982), read by lowering that checkout and PR 45's side by side:
 # a third recurrent kind, a bias on `short_conv` and two host counters
 # left all fifteen programs as they were. A PR that changes one of
-# these programs changes its number here, knowingly.
+# these programs changes its number here, knowingly: PR 47 made
+# DeepSeek-V2's 256-wide chunk walk each latent layer's scratch in two
+# groups of 64 heads (2189 -> 2619: a second loop a layer and the
+# join); its step and probe, and Kimi's 512-wide chunk of 32 heads (one
+# group), stayed as they were.
 _OLD_CELLS = {
     "starcoder2-3b": ("serving", 256, dict(num_blocks=512, slots=4),
                       (9438, 6410, 805)),
@@ -876,7 +907,7 @@ _OLD_CELLS = {
                         dict(num_blocks=1024, slots=2), (10204, 11782, 1040)),
     "deepseek-v2": ("serving_latent", 256,
                     dict(num_blocks=2048, radix_budget_blocks=64, slots=2),
-                    (3220, 2189, 1129)),
+                    (3220, 2619, 1129)),
     "minicpm-sala": ("serving_sparse", 512, dict(num_blocks=2048, slots=2),
                      (3211, 2443, 1085)),
 }

@@ -275,22 +275,50 @@ def test_mla_with_a_rotary_part_absorbed_equals_expanded(toy):
     assert np.abs(np.asarray(plain) - want).max() > 1e-2
 
 
+# pairs a group over q [1, 8, 4, .]: None = the default (one group),
+# then two even groups of 2 heads, a ragged last group (3 + 1 heads),
+# one head a group; a group of fewer pairs than a head has is one head
+@pytest.mark.parametrize("pairs", [None, 16, 24, 8, 1])
 @pytest.mark.parametrize("rows,block", [(40, 512), (100, 16), (100, 48)])
-def test_a_chunks_latent_attention_is_blocked_and_bounded(rows, block,
+def test_a_chunks_latent_attention_is_blocked_and_bounded(rows, block, pairs,
                                                           monkeypatch):
     """`_latent_attention` walks the scratch in blocks up to the last
     query's position: equal to the whole-scratch softmax, whatever the
     rows past the chunk hold (a scratch's are finite: zeros, or what an
     earlier bucket's padding left), also where the scratch is no whole
-    number of blocks."""
+    number of blocks. Walked a GROUP of heads at a time it gives the
+    one walk's result TO THE BIT, for positions a chunk `[Q]` and a
+    row `[B, Q]`: held on quarters (every score's sum is then exact,
+    whatever order the backend's product takes it in: the CPU's sums a
+    score of 32 (head, query) rows in another order than one of 16 or
+    8, and the two differ by an ulp on arbitrary float32 data)."""
     monkeypatch.setattr(tfm, "LATENT_ROWS_A_BLOCK", block)
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
     q = jax.random.normal(ks[0], (1, 8, 4, 48))
     lat = jax.random.normal(ks[1], (1, rows, 48))
-    for pos0 in (0, 5, rows - 8):
-        qpos = pos0 + jnp.arange(8)
-        seen = lat.at[:, pos0 + 8:].set(1e4)
-        got = np.asarray(tfm._latent_attention(q, seen, qpos, 32, 0.2))
+    groups = {None: 1, 16: 2, 24: 2, 8: 4, 1: 4}[pairs]
+
+    def program():
+        # traced at its first call, under the constants of that moment
+        return jax.jit(lambda q, lat, qpos: tfm._latent_attention(
+            q, lat, qpos, 32, 0.2))
+
+    def quarters(x):
+        return jnp.round(x * 4) / 4
+    cases = [(pos0 + jnp.arange(8), lat.at[:, pos0 + 8:].set(1e4))
+             for pos0 in (0, 5, rows - 8)]
+    one_walk = program()
+    ones = [np.asarray(one_walk(quarters(q), quarters(seen), qpos))
+            for qpos, seen in cases]
+    if pairs is not None:
+        monkeypatch.setattr(tfm, "LATENT_PAIRS_A_GROUP", pairs)
+    assert tfm.latent_groups(1, 8, 4) == groups
+    grouped = program()
+    for (qpos, seen), one in zip(cases, ones):
+        for at in (qpos, qpos[None]):
+            np.testing.assert_array_equal(np.asarray(
+                grouped(quarters(q), quarters(seen), at)), one)
+        got = np.asarray(grouped(q, seen, qpos))
         s = np.einsum("bqhr,bkr->bhqk", q, lat) * 0.2
         s = np.where(np.arange(rows)[None, :] <= np.asarray(qpos)[:, None],
                      s, -np.inf)
@@ -298,6 +326,38 @@ def test_a_chunks_latent_attention_is_blocked_and_bounded(rows, block,
         want = np.einsum("bhqk,bkr->bqhr", p / p.sum(-1, keepdims=True),
                          lat[..., :32])
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+def _whiles(jaxpr):
+    """The `while` equations of a jaxpr, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [eqn] * (eqn.primitive.name == "while")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _whiles(sub)
+    return found
+
+
+@pytest.mark.parametrize("width,heads,loops", [
+    (128, 128, 1), (256, 128, 2), (512, 32, 1), (64, 128, 1)],
+    ids=["docqa128", "docqa256", "kimi512", "docqa64"])
+def test_the_groups_follow_from_the_querys_shape_alone(width, heads, loops):
+    """At the default constant a chunk of DeepSeek-V2's 128 heads walks
+    in two groups only at width 256, Kimi-Linear's widest (512 rows of
+    32 heads) in one: a `while` a group, each over the accumulator of
+    its heads, and with one group no slice and no concatenate."""
+    assert tfm.LATENT_PAIRS_A_GROUP == 16384
+    jaxpr = jax.make_jaxpr(
+        lambda q, lat, qpos: tfm._latent_attention(q, lat, qpos, 512, 0.1))(
+            jax.ShapeDtypeStruct((1, width, heads, 640), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 4224, 640), jnp.bfloat16),
+            jax.ShapeDtypeStruct((width,), jnp.int32)).jaxpr
+    found = _whiles(jaxpr)
+    assert len(found) == loops == tfm.latent_groups(1, width, heads)
+    assert all(e.outvars[-1].aval.shape == (1, heads // loops, width, 512)
+               for e in found)
+    joins = [e for e in jaxpr.eqns if e.primitive.name == "concatenate"]
+    assert len(joins) == (loops > 1)
 
 
 # -- the whole model: chunked prefill, then decode, on LOGITS -------------
@@ -533,6 +593,59 @@ def test_prefix_and_routing_counters_and_spans(toy):
     # a model without groups reports neither
     assert "routed_here" not in ContinuousServer(
         *_plain_moe(), paged=True, slots=1, smax=32).moe_stats()
+
+
+def test_a_chunk_past_the_constant_walks_in_groups_and_is_counted(
+        toy, monkeypatch):
+    """A 16-wide chunk of the toy's 4 heads is 64 pairs: under a
+    constant of 32 each of its three latent layers walks the scratch
+    twice (2 heads a group), an 8-wide chunk once; `prefill_stats()["latent_groups"]`
+    and the /serving{...}/prefill/latent_groups counter follow the
+    widths dispatched (no device read), the programs built under the
+    constant hold a `while` a group, and the tokens are the
+    reference's."""
+    conf, cfg, params = toy
+    monkeypatch.setattr(tfm, "LATENT_PAIRS_A_GROUP", 32)
+    had = set(tfm._PROGRAMS)
+    try:
+        # an smax no other test's programs were built for
+        srv = ContinuousServer(params, cfg, paged=True, slots=2, smax=80,
+                               block_size=16, prefill_chunk=16,
+                               prefix_reuse=False)
+        assert srv.prefill_buckets == (8, 16)
+        prompt = _prompt(21, 3)             # a 16-wide chunk, an 8-wide
+        rid = srv.submit(prompt, max_new=4)
+        out = srv.run()
+        st = srv.prefill_stats()
+        assert (st["prefill_chunks"], st["latent_groups"]) == (2, 3 * (2 + 1))
+        assert pc.query_counter(pc.counter_name(
+            "serving", "prefill/latent_groups",
+            srv.counter_instance)).value == 9
+        scratch = jax.eval_shape(srv._fresh_scratch)
+        for width, loops in ((8, 3), (16, 6)):
+            jaxpr = jax.make_jaxpr(srv._chunk_prog(width))(
+                params, scratch, np.zeros((1, width), np.int32),
+                np.int32(0), np.int32(width)).jaxpr
+            assert len(_whiles(jaxpr)) == loops
+        srv.submit(_prompt(7, 4), max_new=2)    # one 8-wide chunk more
+        srv.run()
+        assert srv.prefill_stats()["latent_groups"] == 9 + 3
+    finally:
+        for key in set(tfm._PROGRAMS) - had:
+            del tfm._PROGRAMS[key]
+    seq = list(prompt)
+    for t in out[rid]:
+        lg = _ref_logits(conf, params, seq)[-1]
+        assert lg.max() - lg[t] < 10 * TOL
+        seq.append(t)
+    # under the default constant every chunk of the toy is one group
+    monkeypatch.undo()
+    srv = ContinuousServer(params, cfg, paged=True, slots=2, smax=64,
+                           block_size=16, prefill_chunk=16,
+                           prefix_reuse=False)
+    srv.submit(prompt, max_new=1)
+    srv.run()
+    assert srv.prefill_stats()["latent_groups"] == 3 * 2
 
 
 def _plain_moe():
