@@ -4,7 +4,7 @@ reuse, and the performance counters that observe them.
 Host-side bookkeeping lives here (`BlockAllocator`, `PageTable`,
 `RadixCache`); the jit-side gather/scatter numerics live in
 `hpx_tpu/ops/paged_attention.py`; `models/serving.ContinuousServer`
-wires both together behind its `paged=True` flag. Knobs come from
+wires both together: they are its one cache. Knobs come from
 the `hpx.cache.*` config keys (`core/config.py`).
 """
 

@@ -7,7 +7,7 @@ preallocated pool of `[num_blocks, n_kv, block_size, head_dim]` rows
 per layer, and requests hold *block ids*, never rows. The allocator is
 pure host-side bookkeeping (free list + ref counts) so it is testable
 without jax; the device pools it indexes live with their owner
-(`models/serving.ContinuousServer(paged=True)`).
+(`models/serving.ContinuousServer`).
 
 Ref counting is what makes prefix sharing safe: a block chain published
 into the radix tree (`cache/radix.py`) and matched by three live

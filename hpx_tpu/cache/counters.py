@@ -69,7 +69,7 @@ MoE servers (``cfg.n_experts > 0``) add the expert-routing feed::
                                                       layer each) whose kept
                                                       groups include a held one
 
-Paged servers additionally export the cache counters::
+Every server also exports the counters of its cache::
 
     /cache{locality#L/server#i}/hit-rate                radix prefix hit rate
     /cache{locality#L/server#i}/blocks/in-use           pool blocks allocated
@@ -317,129 +317,128 @@ def register_server(srv) -> str:
         put("serving", "alerts/active",
             pc.CallbackCounter(_read(ref, lambda s: s._alerts.active())))
 
-    if getattr(srv, "paged", False):
-        put("cache", "hit-rate",
-            pc.CallbackCounter(_read(ref, lambda s: s._radix.hit_rate())))
-        put("cache", "blocks/in-use",
-            pc.CallbackCounter(_read(ref, lambda s: s._alloc.in_use)))
-        put("cache", "blocks/free",
-            pc.CallbackCounter(_read(ref, lambda s: s._alloc.free_count)))
-        put("cache", "blocks/shared",
+    put("cache", "hit-rate",
+        pc.CallbackCounter(_read(ref, lambda s: s._radix.hit_rate())))
+    put("cache", "blocks/in-use",
+        pc.CallbackCounter(_read(ref, lambda s: s._alloc.in_use)))
+    put("cache", "blocks/free",
+        pc.CallbackCounter(_read(ref, lambda s: s._alloc.free_count)))
+    put("cache", "blocks/shared",
+        pc.CallbackCounter(_read(
+            ref, lambda s: s._alloc.shared_count)))
+    put("cache", "blocks/radix-held",
+        pc.CallbackCounter(_read(ref, lambda s: s._radix.blocks_held)))
+    put("cache", "count/evictions",
+        pc.CallbackCounter(
+            _read(ref, lambda s: s._radix.total_evictions)))
+    put("cache", "prefill-tokens/saved",
+        pc.CallbackCounter(_read(ref, lambda s: s._prefill_saved)))
+    put("cache", "prefill-tokens/computed",
+        pc.CallbackCounter(_read(ref, lambda s: s._prefill_computed)))
+    # decode-attention HBM roofline feed: mapped blocks (and their
+    # dtype-aware bytes, int8/fp8 scale sidecars included) streamed
+    # per generated token — see ContinuousServer.hbm_read_stats
+    put("cache", "count/hbm-read-per-token",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["hbm_read_blocks_per_token"])))
+    put("cache", "bytes/hbm-read-per-token",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["hbm_read_bytes_per_token"])))
+    # how far the bounded `fused` walk goes (entries a live slot,
+    # and the share of the table's width)
+    put("cache", "count/walk-entries-per-slot",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["walk_entries_per_slot"])))
+    put("cache", "walk-share",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["walk_share"])))
+    # the kv heads that share one copy of an entry, and the copies
+    # a slot, layer and step then issues
+    put("cache", "count/heads-per-copy",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["heads_per_copy"])))
+    put("cache", "count/walk-copies-per-slot",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["walk_copies_per_slot"])))
+    if srv._win:
+        # the window block group (serving._init_paged)
+        put("cache", "window/blocks-in-use",
             pc.CallbackCounter(_read(
-                ref, lambda s: s._alloc.shared_count)))
-        put("cache", "blocks/radix-held",
-            pc.CallbackCounter(_read(ref, lambda s: s._radix.blocks_held)))
-        put("cache", "count/evictions",
-            pc.CallbackCounter(
-                _read(ref, lambda s: s._radix.total_evictions)))
-        put("cache", "prefill-tokens/saved",
-            pc.CallbackCounter(_read(ref, lambda s: s._prefill_saved)))
-        put("cache", "prefill-tokens/computed",
-            pc.CallbackCounter(_read(ref, lambda s: s._prefill_computed)))
-        # decode-attention HBM roofline feed: mapped blocks (and their
-        # dtype-aware bytes, int8/fp8 scale sidecars included) streamed
-        # per generated token — see ContinuousServer.hbm_read_stats
-        put("cache", "count/hbm-read-per-token",
-            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
-                               ["hbm_read_blocks_per_token"])))
-        put("cache", "bytes/hbm-read-per-token",
-            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
-                               ["hbm_read_bytes_per_token"])))
-        # how far the bounded `fused` walk goes (entries a live slot,
-        # and the share of the table's width)
-        put("cache", "count/walk-entries-per-slot",
-            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
-                               ["walk_entries_per_slot"])))
-        put("cache", "walk-share",
-            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
-                               ["walk_share"])))
-        # the kv heads that share one copy of an entry, and the copies
-        # a slot, layer and step then issues
-        put("cache", "count/heads-per-copy",
-            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
-                               ["heads_per_copy"])))
-        put("cache", "count/walk-copies-per-slot",
-            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
-                               ["walk_copies_per_slot"])))
-        if getattr(srv, "_win", 0):
-            # the window block group (serving._init_paged)
-            put("cache", "window/blocks-in-use",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._walloc.in_use)))
-            put("cache", "window/blocks-freed",
-                pc.CallbackCounter(_read(ref, lambda s: s._win_freed)))
-            put("cache", "window/prefix-refused",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._prefix_refused)))
-        if getattr(srv, "_recurrent", False):
-            # the per-slot recurrent state (serving._init_paged): no
-            # blocks, reset at admission, recomputed at a restore
-            put("cache", "state/bytes",
-                pc.CallbackCounter(_read(ref, lambda s: s._state_bytes)))
-            put("cache", "state/slots-live",
-                pc.CallbackCounter(_read(ref, lambda s: sum(
-                    r is not None for r in s._slot_req)
-                    + len(s._pending))))
-            put("cache", "state/resets",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._state_resets)))
-            put("serving", "state/prefix-refused",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._prefix_refused)))
-            put("serving", "state/reprefills",
-                pc.CallbackCounter(_read(ref, lambda s: s._reprefills)))
-        if "mla" in getattr(srv.cfg, "layer_mixer", ()):
-            # latent rows live on the full group's blocks
-            put("cache", "latent/blocks-in-use",
-                pc.CallbackCounter(_read(ref, lambda s: s._alloc.in_use)))
-            # rows a decode step's latent walks read, a latent layer
-            put("cache", "latent/rows-walked",
-                pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
-                                   ["latent_rows_walked_per_step"])))
-        if "sparse" in getattr(srv.cfg, "layer_mixer", ()):
-            # the index of compressed keys beside a sparse layer's K/V
-            # pools, and what the decode steps' selections read
-            put("cache", "index/rows",
-                pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
-                                   ["index_rows"])))
-            put("serving", "sparse/blocks-selected",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._sparse_blocks)))
-            put("serving", "sparse/rows-walked",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._sparse_rows_walked)))
-            put("serving", "sparse/rows-live",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._sparse_rows_live)))
-        if getattr(srv, "_tier", None) is not None:
-            # host-RAM demotion tier (cache/tier.py): occupancy,
-            # demote/promote/drop/decline totals, cumulative hit
-            # depth, and the promotion-latency histogram (with its
-            # derived pNN quantile counters) — /cache{...}/tier/*
-            put("cache", "tier/bytes-held",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._tier.stats()["tier_bytes_held"])))
-            put("cache", "tier/entries",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._tier.stats()["tier_entries"])))
-            put("cache", "tier/count/demoted",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._tier.total_demoted)))
-            put("cache", "tier/count/promoted",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._tier.total_promoted)))
-            put("cache", "tier/count/dropped",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._tier.total_dropped)))
-            put("cache", "tier/count/declined",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._tier.total_declined)))
-            put("cache", "tier/hit-depth-blocks",
-                pc.CallbackCounter(_read(
-                    ref, lambda s: s._tier.hit_depth_blocks)))
-            names.extend(register_histogram(
-                "cache", "tier/promote-latency-s", srv._tier_hist,
-                inst))
+                ref, lambda s: s._walloc.in_use)))
+        put("cache", "window/blocks-freed",
+            pc.CallbackCounter(_read(ref, lambda s: s._win_freed)))
+        put("cache", "window/prefix-refused",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._prefix_refused)))
+    if srv._recurrent:
+        # the per-slot recurrent state (serving._init_paged): no
+        # blocks, reset at admission, recomputed at a restore
+        put("cache", "state/bytes",
+            pc.CallbackCounter(_read(ref, lambda s: s._state_bytes)))
+        put("cache", "state/slots-live",
+            pc.CallbackCounter(_read(ref, lambda s: sum(
+                r is not None for r in s._slot_req)
+                + len(s._pending))))
+        put("cache", "state/resets",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._state_resets)))
+        put("serving", "state/prefix-refused",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._prefix_refused)))
+        put("serving", "state/reprefills",
+            pc.CallbackCounter(_read(ref, lambda s: s._reprefills)))
+    if "mla" in getattr(srv.cfg, "layer_mixer", ()):
+        # latent rows live on the full group's blocks
+        put("cache", "latent/blocks-in-use",
+            pc.CallbackCounter(_read(ref, lambda s: s._alloc.in_use)))
+        # rows a decode step's latent walks read, a latent layer
+        put("cache", "latent/rows-walked",
+            pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
+                               ["latent_rows_walked_per_step"])))
+    if "sparse" in getattr(srv.cfg, "layer_mixer", ()):
+        # the index of compressed keys beside a sparse layer's K/V
+        # pools, and what the decode steps' selections read
+        put("cache", "index/rows",
+            pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
+                               ["index_rows"])))
+        put("serving", "sparse/blocks-selected",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._sparse_blocks)))
+        put("serving", "sparse/rows-walked",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._sparse_rows_walked)))
+        put("serving", "sparse/rows-live",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._sparse_rows_live)))
+    if srv._tier is not None:
+        # host-RAM demotion tier (cache/tier.py): occupancy,
+        # demote/promote/drop/decline totals, cumulative hit
+        # depth, and the promotion-latency histogram (with its
+        # derived pNN quantile counters) — /cache{...}/tier/*
+        put("cache", "tier/bytes-held",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._tier.stats()["tier_bytes_held"])))
+        put("cache", "tier/entries",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._tier.stats()["tier_entries"])))
+        put("cache", "tier/count/demoted",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._tier.total_demoted)))
+        put("cache", "tier/count/promoted",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._tier.total_promoted)))
+        put("cache", "tier/count/dropped",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._tier.total_dropped)))
+        put("cache", "tier/count/declined",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._tier.total_declined)))
+        put("cache", "tier/hit-depth-blocks",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._tier.hit_depth_blocks)))
+        names.extend(register_histogram(
+            "cache", "tier/promote-latency-s", srv._tier_hist,
+            inst))
 
     with _lock:
         _servers[idx] = (ref, names)

@@ -285,8 +285,6 @@ declare("hpx.serving.moe.capacity_factor", "int", "0",
         "(100 = GShard cf 1.0; C = ceil(T*k*pct/100 / E)); 0 = auto = "
         "drop-free (cf = n_experts), the token-identity default. "
         "Lower trades overflow drops for smaller expert exchanges")
-declare("hpx.serving.mesh.paged", "bool", "1",
-        "sharded paged serving (0 restores the single-device refusal)")
 declare("hpx.serving.mesh.table_residency", "str", "sharded",
         "device block-table placement on mesh: sharded | replicated")
 declare("hpx.serving.fleet.prefill_workers", "int", "2",

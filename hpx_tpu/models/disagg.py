@@ -9,10 +9,10 @@ every worker death has a typed, deterministic failover.
 Topology::
 
     DisaggRouter (front end, admits by SLO class)
-        ├── PrefillWorker × N   (dense chunk programs, b=1 scratch)
+        ├── PrefillWorker × N   (chunk programs, b=1 scratch)
         │       │  KVSegments (cache/transfer: framed, checksummed,
         │       ▼   idempotent)
-        └── DecodeWorker × M    (paged ContinuousServer pools)
+        └── DecodeWorker × M    (ContinuousServer pools)
 
 The prefill worker computes prompt KV rows with the SAME bucketed
 chunk + probe programs a colocated server uses (the probe ONE layer
@@ -138,10 +138,11 @@ class _WorkerRing:
 
 
 class PrefillWorker(_WorkerRing):
-    """Computes prompt KV on a b=1 dense scratch with the colocated
-    server's OWN bucketed chunk/probe programs (an embedded dense
-    ``ContinuousServer`` is the program cache), emitting block-aligned
-    :class:`KVSegment`s as rows finish.
+    """Computes prompt KV on a b=1 contiguous scratch with the
+    colocated server's OWN bucketed chunk/probe programs (an embedded
+    one-slot ``ContinuousServer`` over the smallest pool it admits is
+    the program cache), emitting block-aligned :class:`KVSegment`s as
+    rows finish.
 
     Emission discipline: full blocks of ``[0, ((plen-1)//bs)*bs)`` may
     ship as soon as their rows are chunked (KV rows are append-only —
@@ -149,7 +150,11 @@ class PrefillWorker(_WorkerRing):
     only after the probe, which rewrites the last layer's row plen-1
     and picks the seed token. ``start`` with ``prefix_rows`` resumes a
     transfer whose original worker died: the scratch seeds from the
-    already-shipped prefix and only the suffix recomputes."""
+    already-shipped prefix and only the suffix recomputes.
+
+    ``server_kwargs`` reach the embedded server (``prefill_chunk``,
+    ``prefill_buckets``); its pool is never read here, so ``kv_dtype``
+    and ``paged_kernel`` have no effect on a worker's segments."""
 
     def __init__(self, params, cfg: TransformerConfig, smax: int = 512,
                  block_size: Optional[int] = None,
@@ -161,9 +166,12 @@ class PrefillWorker(_WorkerRing):
             from ..ops.attention_pallas import resolve_paged_block
             block_size = resolve_paged_block(cfg.head_dim)[0]
         self.block_size = int(block_size)
-        self._eng = ContinuousServer(params, cfg, slots=1, smax=smax,
-                                     paged=False, async_dispatch=False,
-                                     **server_kwargs)
+        # the scratch `start` builds and the segments are K/V pairs
+        cfg.kv_pairs_only("PrefillWorker", "models/disagg.py")
+        self._eng = ContinuousServer(
+            params, cfg, slots=1, smax=smax, block_size=self.block_size,
+            num_blocks=smax // self.block_size + 1,
+            async_dispatch=False, **server_kwargs)
         self._jobs: Dict[str, _PrefillJob] = {}
 
     def start(self, rid: str, prompt: List[int],
@@ -254,7 +262,7 @@ class PrefillWorker(_WorkerRing):
 
 
 class DecodeWorker(_WorkerRing):
-    """Paged ``ContinuousServer`` plus a :class:`TransferReceiver`:
+    """A ``ContinuousServer`` plus a :class:`TransferReceiver`:
     ingests segments (idempotently), admits completed transfers via
     ``admit_prefilled``, and pumps decode steps, translating between
     router-global request ids and local server rids."""
@@ -262,12 +270,12 @@ class DecodeWorker(_WorkerRing):
     def __init__(self, params, cfg: TransformerConfig, slots: int = 4,
                  smax: int = 512, mesh=None, **server_kwargs) -> None:
         # `mesh=` mirrors ContinuousServer(mesh=...) exactly: None is
-        # the single-device paged server, a (dp, tp) Mesh runs decode
+        # the single-device server, a (dp, tp) Mesh runs decode
         # + verify under shard_map (PR 10's sharded paged serving;
         # axis names in those bodies are hpxlint-HPX021-checked) —
         # one constructor for both, so a fleet mixes them freely
         self.srv = ContinuousServer(params, cfg, slots=slots,
-                                    smax=smax, paged=True, mesh=mesh,
+                                    smax=smax, mesh=mesh,
                                     **server_kwargs)
         self.recv = TransferReceiver()
         self._local_of: Dict[str, int] = {}
@@ -1020,7 +1028,7 @@ class DisaggRouter:
 
     def _degrade(self) -> None:
         """A worker role has no survivors: colocated fallback. Every
-        unfinished request restarts from its prompt on a LOCAL paged
+        unfinished request restarts from its prompt on a LOCAL
         server — slower, but the tokens are identical (the same
         differential contract every path here rides)."""
         if self._degraded:
@@ -1029,7 +1037,7 @@ class DisaggRouter:
         flight.record_fault("degrade", site="disagg")
         self._local = ContinuousServer(
             self.params, self.cfg, slots=self.slots, smax=self.smax,
-            paged=True, **self._srv_kwargs)
+            **self._srv_kwargs)
         self._qi.clear()
         self._qb.clear()
         for rid in sorted(self._reqs):

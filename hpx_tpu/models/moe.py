@@ -175,8 +175,8 @@ def _top_k_dispatch(gates: jax.Array, k: int, capacity: int,
 
 def moe_ffn(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
             axis: str = "", axis_size: int = 1,
-            token_mask: Any = None, return_stats: bool = False,
-            stats_sharding: Any = None) -> Tuple[jax.Array, ...]:
+            token_mask: Any = None,
+            return_stats: bool = False) -> Tuple[jax.Array, ...]:
     """MoE feed-forward on a [T, D] token block.
 
     axis: mesh axis the experts are sharded over ("" = single shard —
@@ -187,14 +187,6 @@ def moe_ffn(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
     also a psum-complete f32 stats vector [2 + E]:
     [claims routed, claims dropped over capacity,
     per-expert occupancy fraction of capacity].
-
-    stats_sharding (GSPMD callers only, never inside shard_map): a
-    replicated NamedSharding pinned onto the dispatch tensor for the
-    stats sums. Under expert-sharded weights the partitioner
-    propagates the e-sharded layout back into dispatch (which every
-    device computes in full from replicated gate weights) without
-    reslicing it, so an unpinned sum comes out multiplied by the
-    expert-shard count; the pin makes XLA close the sums correctly.
     """
     t, d = x.shape
     e = cfg.n_experts
@@ -256,15 +248,11 @@ def moe_ffn(x: jax.Array, params: Dict[str, Any], cfg: MoeConfig,
     if not return_stats:
         return out.astype(x.dtype), aux
     # every gate row claims exactly top_k slots (argmax always picks
-    # an expert), masked rows none — a static count, immune to the
-    # propagation hazard stats_sharding documents
+    # an expert), masked rows none — a static count
     claims = (jnp.float32(t * cfg.top_k) if token_mask is None
               else cfg.top_k * jnp.sum(token_mask.astype(jnp.float32)))
-    disp = dispatch
-    if stats_sharding is not None:
-        disp = jax.lax.with_sharding_constraint(disp, stats_sharding)
-    kept = jnp.sum(disp)
-    occ = jnp.sum(disp, axis=(0, 2)) / capacity            # [E]
+    kept = jnp.sum(dispatch)
+    occ = jnp.sum(dispatch, axis=(0, 2)) / capacity        # [E]
     if axis and p > 1:
         kept = jax.lax.psum(kept, axis)
         claims = jax.lax.psum(claims, axis)

@@ -4,9 +4,10 @@ Reference analog: none (HPX ships no serving runtime); this is the
 standard TPU serving-loop shape — a FIXED batch of decode slots, each
 at its OWN sequence position, stepping together in one jitted program.
 Requests admit into free slots between steps (their prompt prefills on
-the side in BUCKETED CHUNKS, then SPLICES into the slot's cache rows)
-and retire on eos/max_new, so short requests never wait for long ones
-and the chip never idles on a ragged batch. Static shapes throughout:
+the side in BUCKETED CHUNKS, then SPLICES into the blocks the slot's
+table maps) and retire on eos/max_new, so short requests never wait
+for long ones and the chip never idles on a ragged batch. Static shapes
+throughout:
 the per-row cache write is a batched scatter at the slot's position
 vector, the causal mask compares against per-row positions, and dead
 slots simply compute masked work (the XLA way — uniform work, no
@@ -45,7 +46,7 @@ Three throughput disciplines shape the hot loop:
   per sync instead of one. Acceptance compares drafts against the
   EXACT token the sequential step would pick (same ``_pick_row``
   key-fold contract), so spec output stays byte-identical, greedy and
-  sampled; the paged path rolls rejected window blocks back
+  sampled; a rejection rolls the window's blocks back
   (``PageTable.rollback``). Verify programs ride the prefill bucket
   ladder — still O(buckets) programs — and k adapts per slot on an
   acceptance EMA.
@@ -63,7 +64,7 @@ chunk's row through that layer and the head.
 
 RESILIENCY (ROADMAP item 5): the step loop runs under a bounded
 `svc.resiliency.sync_replay`. Every live slot keeps a host-side
-`SlotCheckpoint` (tokens, position, feedback token, paged block pins)
+`SlotCheckpoint` (tokens, position, feedback token, block pins)
 captured at flush boundaries every ``hpx.serving.ckpt_every`` tokens,
 at the frontier the HOST holds (the tokens landed; a step still in
 flight is ahead of it and is replayed); a step-level fault — injected
@@ -72,8 +73,8 @@ flushes the completed suffix (the step in flight included), rewinds live
 slots to their checkpoints and replays only the lost tail. The
 differential contract is what makes this sha-provable: replayed steps
 re-emit the SAME tokens, so a faulted run's outputs are byte-identical
-to the fault-free run. Paged restores re-enter from still-resident
-pinned blocks (no recompute); dense restores re-prefill prompt ++
+to the fault-free run. A restore re-enters from still-resident
+pinned blocks (no recompute); a recurrent model's re-prefills prompt ++
 emitted[:-1] through the bucketed chunk programs. Retry exhaustion,
 admission OOM that outlives ``hpx.serving.admit_retries``, and lapsed
 submit() deadlines shed requests with typed errors into `failed`.
@@ -304,7 +305,7 @@ def _ridge_chunk(params, cfg: TransformerConfig, ridge: float) -> int:
 
 
 def _resolve_kv_dtype(kv_dtype, rc) -> str:
-    """The pool dtype of a paged server: the constructor argument,
+    """The pool dtype of a server: the constructor argument,
     else ``hpx.cache.kv_dtype``; validated."""
     if kv_dtype is None:
         kv_dtype = rc.get("hpx.cache.kv_dtype", "bf16")
@@ -339,17 +340,15 @@ def _resolve_paged_kernel(paged_kernel, rc) -> str:
     return paged_kernel
 
 
-def _moe_rows(h, lp, cfg, moe_cf=None, moe_ep=None, moe_sink=None,
-              moe_ms=None):
+def _moe_rows(h, lp, cfg, moe_cf=None, moe_ep=None, moe_sink=None):
     """The sparse FFN of the serving bodies, over the token block h
     [B, W, D]. On one shard with no finite capacity set (`moe_cf`
     None or >= n_experts: the token-identity default) it is the
     drop-free `moe_ffn_serve`. `moe_ep` = (axis_name, axis_size)
     routes expert-parallel through `moe_ffn_decode` — only valid
-    inside a shard_map body; `moe_ms` is the replicated stats sharding
-    of the GSPMD bodies (see moe_ffn's stats_sharding); both, and a
-    finite `moe_cf`, take the GShard capacity dispatch. `moe_sink` (a
-    list) collects the per-layer psum-complete stats vector."""
+    inside a shard_map body; that, and a finite `moe_cf`, take the
+    GShard capacity dispatch. `moe_sink` (a list) collects the
+    per-layer psum-complete stats vector."""
     from .moe import moe_ffn, moe_ffn_decode, moe_ffn_serve
     from .transformer import _moe_cfg
     b, w, d = h.shape
@@ -359,18 +358,17 @@ def _moe_rows(h, lp, cfg, moe_cf=None, moe_ep=None, moe_sink=None,
     if moe_ep is not None:
         out, _aux, stats = moe_ffn_decode(h2, lp["moe"], mcfg,
                                           moe_ep[0], moe_ep[1])
-    elif moe_ms is None and cf >= cfg.n_experts:
+    elif cf >= cfg.n_experts:
         out, stats = moe_ffn_serve(h2, lp["moe"], mcfg)
     else:
         out, _aux, stats = moe_ffn(h2, lp["moe"], mcfg,
-                                   return_stats=True,
-                                   stats_sharding=moe_ms)
+                                   return_stats=True)
     if moe_sink is not None:
         moe_sink.append(stats)
     return out.reshape(b, w, d)
 
 
-def _moe_fold(sink, cfg=None):
+def _moe_fold(sink, cfg):
     """Fold the per-layer MoE stats vectors into ONE [2 + E] f32
     program output: routed / dropped-over-capacity claims SUM over
     layers, per-expert occupancy fractions AVERAGE over layers; what a
@@ -381,8 +379,7 @@ def _moe_fold(sink, cfg=None):
         return None
     from .moe import STATS_HERE
     s = jnp.sum(jnp.stack(sink), axis=0)
-    end = s.shape[0] - (STATS_HERE if cfg is not None
-                        and cfg.moe_n_group > 1 else 0)
+    end = s.shape[0] - (STATS_HERE if cfg.moe_n_group > 1 else 0)
     return jnp.concatenate([s[:2], s[2:end] / len(sink), s[end:]])
 
 
@@ -424,14 +421,14 @@ def _write_rows(k, v, write):
     return kv[:, 0], kv[:, 1], (table, pos)
 
 
-def _window_rows(x, lp, kv, pos0, cfg: TransformerConfig, li=0,
-                 moe_cf=None, moe_ep=None, moe_sink=None, moe_ms=None):
+def _window_rows(x, lp, kv, pos0, cfg: TransformerConfig, li=0):
     """Layer `li` for a W-token window per slot at PER-SLOT positions
-    over dense per-slot caches: `_layer` with those caches as its
-    mixer. x [B, W, D] (W = 1: one decode token a slot; W > 1: the
-    speculative verify window); kv: (k_cache, v_cache) [B, Smax, Nkv,
-    H]; pos0 [B] int32 — slot b's window row i lands at cache position
-    pos0[b] + i and attends positions <= pos0[b] + i.
+    over dense per-slot caches (the speculative DRAFT model's: the
+    server's own cache is the block pools, `_paged_window_rows`):
+    `_layer` with those caches as its mixer. x [B, W, D]; kv:
+    (k_cache, v_cache) [B, Smax, Nkv, H]; pos0 [B] int32 — slot b's
+    window row i lands at cache position pos0[b] + i and attends
+    positions <= pos0[b] + i.
 
     Same projections, same einsum contractions over the same smax
     rows, same -inf mask and f32 softmax at every W — so window column
@@ -452,54 +449,48 @@ def _window_rows(x, lp, kv, pos0, cfg: TransformerConfig, li=0,
             (kc, vc)
 
     return _layer(x, lp, cfg, li, posw, attend, None,
-                  lambda h: _moe_rows(h, lp, cfg, moe_cf, moe_ep,
-                                      moe_sink, moe_ms))
+                  lambda h: _moe_rows(h, lp, cfg))
 
 
-def _decode_window_rows(params, caches, toks, pos0, cfg, moe_cf=None,
-                        moe_ep=None, moe_ms=None):
+def _decode_window_rows(params, caches, toks, pos0, cfg):
     """W tokens per slot through every layer at per-slot positions
-    (W = 1: the decode step; else the speculative-verify forward);
-    toks [B, W] int32, pos0 [B] int32. Returns (caches, f32 logits
-    [B, W, V], mstats) — mstats is the folded MoE stats vector (None
-    for dense models)."""
+    over dense caches; toks [B, W] int32, pos0 [B] int32. Returns
+    (caches, f32 logits [B, W, V])."""
     x = _embed(params, toks, cfg)
     new_caches = []
-    sink = []
     for li, (lp, kv) in enumerate(zip(params["layers"], caches)):
-        x, kv = _window_rows(x, lp, kv, pos0, cfg, li, moe_cf, moe_ep,
-                             sink, moe_ms)
+        x, kv = _window_rows(x, lp, kv, pos0, cfg, li)
         new_caches.append(kv)
-    return (new_caches, _logits(params, x, cfg).astype(jnp.float32),
-            _moe_fold(sink))
+    return new_caches, _logits(params, x, cfg).astype(jnp.float32)
 
 
-def _decode_rows(params, caches, tok, pos, cfg, moe_cf=None,
-                 moe_ep=None, moe_ms=None):
+def _decode_rows(params, caches, tok, pos, cfg):
     """One token per slot: the W == 1 case of `_decode_window_rows`
-    (logits [B, V])."""
-    caches, logits, ms = _decode_window_rows(
-        params, caches, tok[:, None], pos, cfg, moe_cf, moe_ep, moe_ms)
-    return caches, logits[:, 0, :], ms
+    (logits [B, V]), the draft model's step."""
+    caches, logits = _decode_window_rows(
+        params, caches, tok[:, None], pos, cfg)
+    return caches, logits[:, 0, :]
 
 
 def _paged_window_rows(x, lp, pools, scales, table, pos0,
                        cfg: TransformerConfig, li=0, fused=False,
                        tp_axis=None, moe_cf=None, moe_ep=None,
                        moe_sink=None, write=None):
-    """`_window_rows` with the K/V rows living in a shared BLOCK POOL
-    instead of per-slot dense buffers: `_layer` with the pools as its
+    """Layer `li` for a W-token window per slot (W = 1: one decode
+    token a slot; W > 1: the speculative verify window) with the K/V
+    rows living in a shared BLOCK POOL: `_layer` with the pools as its
     mixer. x: [B, W, D]; pools: (k_pool, v_pool) each [num_blocks,
     Nkv, block_size, H]; scales: (k_scale, v_scale) [num_blocks, Nkv]
     f32 sidecars for quantized pools, or None; table: [B, max_blocks]
     int32 logical->physical block map OF THIS LAYER'S GROUP (a window
     layer's is its ring, `cfg.window(li)` names the width); pos0: [B]
-    int32. Projections/rope/ffn are byte-identical to the dense path;
-    only the cache write (scatter through the table) and read (gather
-    in logical order — same row values at the same logical indices, or
-    the fused Pallas table walk) differ, which is what keeps paged ==
-    dense token-exact, under speculation too (`ops.paged_attention`
-    holds both, W == 1 as `paged_decode_attention`).
+    int32. Projections/rope/ffn are byte-identical to `generate()`'s
+    dense caches; only the cache write (scatter through the table) and
+    read (gather in logical order — same row values at the same
+    logical indices, or the fused Pallas table walk) differ, which is
+    what keeps the server token-exact to it, under speculation too
+    (`ops.paged_attention` holds both, W == 1 as
+    `paged_decode_attention`).
 
     Under shard_map on a (dp, tp) mesh, `tp_axis` names the
     tensor-parallel axis: every shard sees its LOCAL kv-head slice of
@@ -584,9 +575,10 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
 def _paged_decode_window_rows(params, pools, scales, toks, tables, pos0,
                               cfg, fused=False, tp_axis=None,
                               moe_cf=None, moe_ep=None, dp=None):
-    """W tokens per slot over paged pools (W = 1: the decode step);
-    returns (pools, scales, f32 logits [B, W, V], mstats) — the
-    `_decode_window_rows` analog. `tables`: one [B, max_blocks] map a
+    """W tokens per slot over paged pools (W = 1: the decode step;
+    else the speculative-verify forward); returns (pools, scales, f32
+    logits [B, W, V], mstats) — mstats is the folded MoE stats vector
+    (None for dense models). `tables`: one [B, max_blocks] map a
     block GROUP, (full,) or (full, window ring); a layer reads its
     group's. `scales` is the per-layer list of (k_scale, v_scale)
     sidecars for quantized pools, or None (passed through untouched)."""
@@ -646,7 +638,7 @@ def _scratch_entry(cfg: TransformerConfig, smax: int, i: int):
 
 
 def _verify_tail(logits, toks, kvec, temp, keys, pos0, width):
-    """Shared device-side tail of both verify programs: pick the
+    """Device-side tail of the verify program: pick the
     target token at every window position with the SAME `_pick_row`
     the sequential step uses, then count the longest prefix of drafts
     agreeing with them.
@@ -685,7 +677,7 @@ class SlotCheckpoint:
     replay rewrites with the same bytes) every
     ``hpx.serving.ckpt_every`` emitted tokens.
 
-    ``pins`` (paged mode) hold ONE extra allocator reference per FULL
+    ``pins`` hold ONE extra allocator reference per FULL
     block below pos (rows [0, pos - pos % block_size)): the pin keeps
     eviction and slot-retire from recycling the block, and a full
     block is append-complete — this slot never writes it again, so
@@ -698,9 +690,8 @@ class SlotCheckpoint:
     so the slot's CURRENT table always holds them byte-exact. Restore
     rebuilds the PageTable from pins ++ the live table's frontier
     block; the replayed decode suffix re-enters from still-resident
-    KV. Dense mode pins nothing and restores by re-prefilling
-    prompt ++ tokens[:-1] (byte-identical: K/V rows are functions of
-    (token, position) alone)."""
+    KV. A recurrent model pins nothing and restores by re-prefilling
+    prompt ++ tokens[:-1] (`_restore_recurrent`)."""
 
     rid: int
     tokens: List[int]              # emitted tokens at capture (copy)
@@ -739,7 +730,7 @@ class _Request:
 class _PendingPrefill:
     """One in-flight chunked prefill: owns a reserved slot and a b=1
     scratch cache; `done` is the absolute prompt cursor (starts at the
-    radix-matched prefix length in paged mode). The chunks run to the
+    radix-matched prefix length). The chunks run to the
     prompt's END on every model: a recurrent layer consumes the last
     token once, in its chunk."""
     req: _Request
@@ -747,9 +738,9 @@ class _PendingPrefill:
     caches: Any                    # b=1 [1, smax] scratch, per layer
     done: int                      # prompt tokens already in scratch
     seq: int                       # admission order (FIFO tiebreak)
-    pt: Optional[PageTable] = None  # paged: blocks held for the request
-    trow: Any = None               # paged: host [maxb] table row
-    wrow: Any = None               # paged: splice WRITE rows, one a
+    pt: Optional[PageTable] = None  # blocks held for the request
+    trow: Any = None               # host [maxb] table row
+    wrow: Any = None               # splice WRITE rows, one a
                                    # block group (matched prefix
                                    # entries point at trash)
     wt: Optional[WindowTable] = None   # blocks held in the window group
@@ -827,15 +818,22 @@ class ContinuousServer:
         b = srv.submit([2, 7], max_new=8, eos_id=0)
         out = srv.run()            # {a: [tokens...], b: [tokens...]}
 
-    One jitted step decodes every live slot at its own position;
-    finished slots retire and queued requests admit between steps.
-    Prompts prefill on a b=1 scratch cache in BUCKETED fixed-width
-    chunks (pad-then-mask; widths from the ``hpx.serving.
-    prefill_buckets`` ladder), then a one-row probe of the last prompt
-    position, ONE layer deep (it starts from the hidden row the last
-    chunk handed back), picks the seed token and the whole scratch
-    splices into the slot — so the program cache holds O(buckets) prefill
-    programs regardless of the prompt-length mix. A prompt whose
+    The cache is ONE pool of fixed-size blocks a layer (`block_size`
+    rows each, `num_blocks` of them, shared by every slot) and a
+    per-slot TABLE that maps a request's positions onto the blocks it
+    holds (`cache/page_table`, `cache/block_allocator`); a retired
+    prompt's full blocks stay in a radix tree for the next request
+    that starts with them (``prefix_reuse``). One jitted step decodes
+    every live slot at its own position through its table; finished
+    slots retire and queued requests admit between steps. Prompts
+    prefill on a b=1 contiguous SCRATCH (the matched prefix gathered
+    into it) in BUCKETED fixed-width chunks (pad-then-mask; widths from
+    the ``hpx.serving.prefill_buckets`` ladder), then a one-row probe
+    of the last prompt position, ONE layer deep (it starts from the
+    hidden row the last chunk handed back), picks the seed token and
+    the scratch splices into the request's blocks — so the program
+    cache holds O(buckets) prefill programs regardless of the
+    prompt-length mix. A prompt whose
     remaining tokens exceed ``hpx.serving.prefill_chunk`` becomes a
     PENDING prefill: it advances one chunk per step interleaved with
     live decode (shortest-remaining-first across pendings), so admits
@@ -864,7 +862,7 @@ class ContinuousServer:
     ``spec_stats()`` and the ``/serving{...}/spec/*`` counters."""
 
     def __init__(self, params, cfg: TransformerConfig, slots: int = 4,
-                 smax: int = 512, mesh=None, paged: bool = False,
+                 smax: int = 512, mesh=None, paged: bool = True,
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  radix_budget_blocks: Optional[int] = None,
@@ -883,8 +881,13 @@ class ContinuousServer:
         self.slots = slots
         self.smax = smax
         self.mesh = mesh
-        self.paged = bool(paged)
-        nkv, hd = cfg.kv_heads, cfg.head_dim
+        if not paged:
+            # the keyword outlives its choice only for the callers that
+            # still pass `paged=True`
+            raise ValueError(
+                "ContinuousServer(paged=False): the dense server mode "
+                "is gone, the block pools are the one cache (drop the "
+                "argument); transformer.generate() is the dense oracle")
         # the window layers' width: they are ONE block group beside the
         # full layers' (cache/page_table.WindowTable), so one width
         wins = {cfg.window(i) for i in range(cfg.n_layers)} - {0}
@@ -899,40 +902,19 @@ class ContinuousServer:
         # the mixer kinds beside softmax attention: a "kda", "lightning"
         # or "mamba" layer keeps a per-slot recurrent state, an "mla"
         # layer latent rows, a "sparse" layer an index beside its K/V
-        # pools; all live in the paged cache pytree only (`_init_paged`)
+        # pools; all live in the cache pytree `_init_paged` builds
         self._recurrent = cfg.recurrent
         self._kinds = kinds = sorted(set(cfg.layer_mixer) - {"attn"})
-        for what, on in (
-                ("paged=False: the dense per-slot caches hold K/V "
-                 "pairs", not self.paged),
-                ("a (dp, tp) mesh: the state, the latent pool and the "
-                 "index pool have no placement", mesh is not None)):
-            if kinds and on:
-                raise NotImplementedError(
-                    f"{what}; a model with {kinds} mixers runs "
-                    "on ContinuousServer(paged=True) on one device "
-                    "(models/serving.py _init_paged)")
-        cache_sh = None
-        if self.paged and mesh is not None and \
-                not rc.get_bool("hpx.serving.mesh.paged", True):
-            # operational escape hatch back to the pre-sharded refusal
-            raise ValueError(
-                "sharded paged serving is disabled "
-                "(hpx.serving.mesh.paged=0): shard the dense path "
-                "(mesh=...) or run one paged server per replica")
+        if kinds and mesh is not None:
+            raise NotImplementedError(
+                "a (dp, tp) mesh: the state, the latent pool and the "
+                f"index pool have no placement; a model with {kinds} "
+                "mixers runs on ContinuousServer(paged=True) on one "
+                "device (models/serving.py _init_paged)")
         self._ep_axis, self._ep_size = None, 1
         if mesh is not None:
-            # GSPMD sharded serving: slots over dp, heads over tp. The
-            # dense step/prefill/splice programs are UNCHANGED —
-            # placement alone makes XLA partition them (einsum
-            # contractions over the tp-sharded head dim close with
-            # compiler-inserted all-reduces; expert einsums partition
-            # over the expert-sharded e dim). The PAGED decode/verify
-            # steps instead run under shard_map (block tables are
-            # per-dp-shard; the pool gather must stay shard-local),
-            # with explicit psums over tp and MoE token routing over
-            # the expert axis — see _paged_step_prog.
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            # sharded serving: slots over dp, heads over tp, the decode
+            # and verify steps under shard_map (`_paged_step_prog`)
             from .transformer import (_decode_ep, _decode_mesh_check,
                                       _decode_pspecs, _place)
             # the shared decode-mesh contract (axes, expert and
@@ -945,11 +927,9 @@ class ContinuousServer:
             self._ep_axis, self._ep_size = _decode_ep(cfg, mesh)
             params = _place(params, _decode_pspecs(params, cfg, mesh),
                             mesh)
-            cache_sh = NamedSharding(mesh, P("dp", None, "tp", None))
         self.params = params
         # what the probe reads: the last layer, final ln and the head
         self._tail_params = {**params, "layers": params["layers"][-1:]}
-        self._cache_sh = cache_sh
         # MoE decode state: the capacity-factor knob is an int PERCENT
         # (100 = GShard cf 1.0); 0 = auto = drop-free (cf = n_experts),
         # the token-identity default. Routed/dropped counts and
@@ -1076,36 +1056,21 @@ class ContinuousServer:
             self._draft_cfg = draft_cfg
             dn, dh = draft_cfg.kv_heads, draft_cfg.head_dim
 
+            # the draft's dense per-slot rows, allocated in their
+            # layout: on a mesh slots over dp and heads over tp
+            dsh = None
+            if mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                dsh = NamedSharding(mesh, P("dp", None, "tp", None))
+
             def dzeros():
-                if cache_sh is not None:
-                    return jnp.zeros((slots, smax, dn, dh),
-                                     draft_cfg.dtype, device=cache_sh)
-                return jnp.zeros((slots, smax, dn, dh),
-                                 draft_cfg.dtype)
+                return jnp.zeros((slots, smax, dn, dh), draft_cfg.dtype,
+                                 device=dsh)
             self._draft_caches = [(dzeros(), dzeros())
                                   for _ in range(draft_cfg.n_layers)]
 
-        if self.paged:
-            self._init_paged(block_size, num_blocks,
-                             radix_budget_blocks, prefix_reuse,
-                             paged_kernel, kv_dtype)
-            self._caches = None     # dense buffers never allocated
-        else:
-            if paged_kernel is not None or kv_dtype is not None:
-                raise ValueError(
-                    "paged_kernel / kv_dtype are paged-mode knobs; "
-                    "pass paged=True to use them")
-            def zeros():
-                # allocate DIRECTLY in the sharded layout: a full
-                # buffer on device 0 followed by a redistribute would
-                # peak at the unsharded size there — the exact OOM
-                # sharding avoids
-                if cache_sh is not None:
-                    return jnp.zeros((slots, smax, nkv, hd), cfg.dtype,
-                                     device=cache_sh)
-                return jnp.zeros((slots, smax, nkv, hd), cfg.dtype)
-            self._caches = [(zeros(), zeros())
-                            for _ in range(cfg.n_layers)]
+        self._init_paged(block_size, num_blocks, radix_budget_blocks,
+                         prefix_reuse, paged_kernel, kv_dtype)
         # windowed decode throughput, read by the serving counters
         from ..svc.performance_counters import RateCounter
         self._rate = RateCounter(window_s=5.0)
@@ -1401,9 +1366,9 @@ class ContinuousServer:
                     f"{self._table_residency!r}")
 
         def pzeros():
-            # allocate directly in the sharded layout (same OOM logic
-            # as the dense zeros(): never materialize the full pool on
-            # one device first)
+            # allocate directly in the sharded layout: a full pool on
+            # one device followed by a redistribute would peak at the
+            # unsharded size there, the very OOM sharding avoids
             dt = {"int8": jnp.int8,
                   "fp8": jnp.float8_e4m3fn}.get(self._kv_dtype,
                                                 cfg.dtype)
@@ -1497,55 +1462,17 @@ class ContinuousServer:
 
     def _dp(self):
         """(axis, size) of the data-parallel axis for the shard_map
-        paged bodies (`_dp_rows`); None on a single device."""
+        bodies (`_dp_rows`); None on a single device."""
         return None if self.mesh is None else ("dp",
                                                self.mesh.shape["dp"])
 
     def _moe_ep(self):
         """(axis, size) for expert-parallel routing inside the
-        shard_map paged bodies; None on a single shard — and for the
-        GSPMD dense programs, which partition the expert einsums from
-        placement alone and must never call collectives directly."""
+        shard_map bodies; None on a single shard."""
         if self.cfg.n_experts <= 0 or self._ep_axis is None \
                 or self._ep_size <= 1:
             return None
         return (self._ep_axis, self._ep_size)
-
-    def _step_prog(self):
-        cfg, slots, smax = self.cfg, self.slots, self.smax
-        ck = ("cb_step", cfg, slots, smax, self._moe_capacity_pct,
-              self.mesh, _tree_key(self.params))
-
-        def build():
-            cache_sh = self._cache_sh
-            moe_cf = self._moe_cf()
-            ms_sh = self._moe_stats_sh()
-
-            def step(params, caches, tok, pos, temp, keys):
-                if cache_sh is not None:
-                    caches = jax.tree.map(
-                        lambda c: jax.lax.with_sharding_constraint(
-                            c, cache_sh), caches)
-                caches, logits, ms = _decode_rows(
-                    params, caches, tok, pos, cfg, moe_cf,
-                    moe_ms=ms_sh)
-                nxt = jax.vmap(_pick_row)(logits, keys, temp, pos)
-                return caches, nxt, ms
-            return jax.jit(step, donate_argnums=(1,))
-        return self._program(ck, build)
-
-    def _moe_stats_sh(self):
-        """Replicated sharding for the MoE stats vector under GSPMD
-        dense programs. The partitioner propagates the expert-sharded
-        weight layout back into the (replicated-by-construction)
-        dispatch tensor without reslicing it, so the stats sums come
-        out multiplied by the expert-shard count; pinning the vector
-        replicated makes XLA close the sums correctly. The shard_map
-        paged programs psum explicitly and never need this."""
-        if self.mesh is None or self.cfg.n_experts <= 0:
-            return None
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        return NamedSharding(self.mesh, P())
 
     def _chunk_prog(self, width: int):
         """One bucketed prefill chunk: toks [1, width] (tail-padded
@@ -1623,7 +1550,7 @@ class ContinuousServer:
 
     def _lane_sh(self):
         """Placement of the per-slot vectors (1-d, and the keys' 2-d)
-        under the mesh: slots over dp, as the paged step's shard_map
+        under the mesh: slots over dp, as the step's shard_map
         takes and returns them. None on a single device."""
         if self.mesh is None:
             return None
@@ -1654,35 +1581,7 @@ class ContinuousServer:
             self._keys_dev = jax.device_put(self._key.copy(), sh[2])
         return self._temp_dev, self._keys_dev
 
-    def _splice_prog(self):
-        """Copy the b=1 scratch cache into one slot's rows — ALL smax
-        rows, so one program serves every prompt length (the garbage
-        rows past plen are exactly what the slot held before: never
-        read until decode overwrites them)."""
-        slots, smax = self.slots, self.smax
-        ck = ("cb_splice", self.cfg, slots, smax, self.mesh,
-              _tree_key(self.params))
-
-        def build():
-            cache_sh = self._cache_sh
-
-            def splice(caches, one, slot):
-                if cache_sh is not None:
-                    caches = jax.tree.map(
-                        lambda c: jax.lax.with_sharding_constraint(
-                            c, cache_sh), caches)
-                out = []
-                for (kc, vc), (k1, v1) in zip(caches, one):
-                    kc = jax.lax.dynamic_update_slice(
-                        kc, k1.astype(kc.dtype), (slot, 0, 0, 0))
-                    vc = jax.lax.dynamic_update_slice(
-                        vc, v1.astype(vc.dtype), (slot, 0, 0, 0))
-                    out.append((kc, vc))
-                return out
-            return jax.jit(splice, donate_argnums=(0,))
-        return self._program(ck, build)
-
-    # -- paged programs (models live in pools; tables map positions) -----
+    # -- cache programs (rows live in pools; tables map positions) --------
 
     def _paged_step_prog(self):
         cfg, slots, smax = self.cfg, self.slots, self.smax
@@ -1715,8 +1614,8 @@ class ContinuousServer:
                 return pools, scales, nxt, ms
             if self.mesh is None:
                 return self._jit_step(step)
-            # sharded paged decode runs under shard_map, NOT bare
-            # GSPMD: each dp shard steps ITS slots against its LOCAL
+            # sharded decode runs under shard_map, NOT bare GSPMD: each
+            # dp shard steps ITS slots against its LOCAL
             # pool replica (block tables are per-shard int32 into a
             # dp-replicated block axis — the gather can never cross
             # shards; every shard writes every slot's new rows, so the
@@ -1743,7 +1642,7 @@ class ContinuousServer:
         return jax.jit(step, donate_argnums=(1, 2))
 
     def _paged_shard_specs(self):
-        """Spec trees for the shard_map-wrapped paged programs:
+        """Spec trees for the shard_map-wrapped programs:
         (param pspecs, pool spec, scale spec). Pools replicate the
         block axis over dp and shard kv-heads over tp (the allocator's
         pool_pspec rule); the scale spec degrades to P() for bf16
@@ -1796,7 +1695,7 @@ class ContinuousServer:
     def _paged_splice_prog(self):
         """Write the request's padded block row back from the b=1
         scratch (chunked-prefill splice). One program for every
-        (matched, plen) combination: the WRITE row (`_start_paged`'s
+        (matched, plen) combination: the WRITE row (`_start_prefill`'s
         `wrow`) redirects radix-matched prefix entries to the trash
         block, so shared prefix blocks are never rewritten — for bf16
         the skipped write was an identity copy of the bytes the gather
@@ -1951,34 +1850,6 @@ class ContinuousServer:
 
     # -- speculative programs (verify windows + draft model) -------------
 
-    def _verify_prog(self, width: int):
-        """Dense window-verify: ONE forward over a width-W window at
-        per-slot positions, returning packed targets+acceptance. Keyed
-        per LADDER WIDTH (same ladder as the prefill chunks), so the
-        program cache stays O(buckets) however adaptive k wanders."""
-        cfg, slots, smax = self.cfg, self.slots, self.smax
-        ck = ("cb_verify", cfg, slots, smax, width,
-              self._moe_capacity_pct, self.mesh,
-              _tree_key(self.params))
-
-        def build():
-            cache_sh = self._cache_sh
-            moe_cf = self._moe_cf()
-            ms_sh = self._moe_stats_sh()
-
-            def verify(params, caches, toks, pos0, kvec, temp, keys):
-                if cache_sh is not None:
-                    caches = jax.tree.map(
-                        lambda c: jax.lax.with_sharding_constraint(
-                            c, cache_sh), caches)
-                caches, logits, ms = _decode_window_rows(
-                    params, caches, toks, pos0, cfg, moe_cf,
-                    moe_ms=ms_sh)
-                return caches, _verify_tail(
-                    logits, toks, kvec, temp, keys, pos0, width), ms
-            return jax.jit(verify, donate_argnums=(1,))
-        return self._program(ck, build)
-
     def _paged_verify_prog(self, width: int):
         cfg, slots, smax = self.cfg, self.slots, self.smax
         nb, bs = self._alloc.num_blocks, self.block_size
@@ -2033,8 +1904,8 @@ class ContinuousServer:
 
         def build():
             def step(params, caches, tok, pos):
-                caches, logits, _ms = _decode_rows(params, caches, tok,
-                                                   pos, dcfg)
+                caches, logits = _decode_rows(params, caches, tok, pos,
+                                              dcfg)
                 return caches, jnp.argmax(logits, axis=-1) \
                                   .astype(jnp.int32)
             return jax.jit(step, donate_argnums=(1,))
@@ -2067,7 +1938,7 @@ class ContinuousServer:
             return jax.jit(chunk, donate_argnums=(1,))
         return self._program(ck, build)
 
-    # -- paged host-side bookkeeping -------------------------------------
+    # -- host-side block bookkeeping -------------------------------------
 
     def _alloc_block(self) -> int:
         """allocator.alloc with the OOM→evict→retry discipline: a full
@@ -2165,7 +2036,7 @@ class ContinuousServer:
         return self._tables_arr
 
     def _release_slot(self, slot: int, req: "_Request") -> None:
-        """Paged retire: publish the request's FULL prompt blocks into
+        """Retire: publish the request's FULL prompt blocks into
         the radix tree (prefix reuse for future admits), then drop the
         request's references — shared blocks survive under the tree's
         ref, private ones return to the free list."""
@@ -2299,10 +2170,8 @@ class ContinuousServer:
         return n * bs
 
     def cache_stats(self) -> Dict[str, float]:
-        """Paged-mode observability snapshot (the same numbers the
+        """Cache observability snapshot (the same numbers the
         /cache{...} performance counters export)."""
-        if not self.paged:
-            raise ValueError("cache_stats() requires paged=True")
         st: Dict[str, float] = dict(self._alloc.stats())
         st.update(self._radix.stats())
         if self._tier is not None:
@@ -2415,8 +2284,6 @@ class ContinuousServer:
         scales are included: vs a bf16 compute dtype the quantized
         pools read ~0.5x, and vs tier-1's f32 compute dtype ~0.25x —
         the fp8 roofline ratio the acceptance gate pins at <= 0.30x."""
-        if not self.paged:
-            raise ValueError("hbm_read_stats() requires paged=True")
         live = sum(1 for pt in self._tables if pt is not None)
         blocks = occupancy(self._tables)
         per_tok = (blocks / live) if live else 0.0
@@ -2602,10 +2469,6 @@ class ContinuousServer:
         tokens match what a colocated submit() would produce. The rows
         stay a host array until a slot admits, so shedding a queued
         transfer can never leak pool blocks."""
-        if not self.paged:
-            raise ValueError(
-                "admit_prefilled() requires paged=True (the transfer "
-                "protocol ships block-granular KV)")
         self._only_kv_pairs("admit_prefilled()")
         if self._win:
             raise NotImplementedError(
@@ -2663,8 +2526,6 @@ class ContinuousServer:
         same contract as colocated prefix reuse on those pools. The
         match's block leases drop before returning (the caller gets
         BYTES, not references — nothing here can leak pool blocks)."""
-        if not self.paged:
-            raise ValueError("export_prefix_rows() requires paged=True")
         self._only_kv_pairs("export_prefix_rows()")
         matched, bids = self._radix.match(tokens)
         if not matched:
@@ -2758,23 +2619,10 @@ class ContinuousServer:
 
     def _start_prefill(self, req: "_Request",
                        slot: int) -> _PendingPrefill:
-        """Reserve `slot` and stand up the b=1 scratch cache (paged:
-        match the radix prefix, hold blocks for the whole prompt, and
-        gather them into the scratch)."""
+        """Reserve `slot` and stand up the b=1 scratch cache: match
+        the radix prefix, hold blocks for the whole prompt, and gather
+        the matched ones into the scratch."""
         self._pf_seq += 1
-        if self.paged:
-            p = self._start_paged(req, slot)
-        else:
-            p = _PendingPrefill(req=req, slot=slot,
-                                caches=self._fresh_scratch(),
-                                done=0, seq=self._pf_seq)
-        p.step0 = self._step_n
-        self._pending[slot] = p
-        self._admit_defers.pop(req.rid, None)   # admitted: ladder done
-        return p
-
-    def _start_paged(self, req: "_Request",
-                     slot: int) -> _PendingPrefill:
         plen = len(req.prompt)
         matched, mbids, tier_ext = 0, [], []
         sparse = "sparse" in self._kinds
@@ -2826,6 +2674,8 @@ class ContinuousServer:
         wnp = trow.copy()
         wnp[:matched // self.block_size] = self._trash
         wrow = (wnp,)
+        # an empty scratch, but where the gather brings the match
+        caches, wt = None, None
         if self._recurrent:
             # the request's state starts from zeros in its scratch and
             # the splice overwrites the slot's row whole: the reset
@@ -2835,44 +2685,37 @@ class ContinuousServer:
                               rid=req.rid, slot=slot, layers=n_rec):
                 caches = self._fresh_scratch()
                 self._state_resets += 1
-            return _PendingPrefill(req=req, slot=slot, caches=caches,
-                                   done=0, seq=self._pf_seq, pt=pt,
-                                   trow=trow, wrow=wrow)
-        if sparse:
-            # nothing matched: an empty scratch (the gather's program
-            # reads K/V pairs alone)
-            return _PendingPrefill(req=req, slot=slot,
-                                   caches=self._fresh_scratch(),
-                                   done=0, seq=self._pf_seq, pt=pt,
-                                   trow=trow, wrow=wrow)
-        if not self._win:
+        elif sparse:
+            pass        # the gather's program reads K/V pairs alone
+        elif not self._win:
             # the matched rows, out of the shared blocks into the
             # request's scratch (the pools' kind: K/V or latent rows)
             with tracing.span("serving.prefix_gather", "serving",
                               rid=req.rid, matched=matched, plen=plen):
                 caches = self._paged_gather_prog()(
                     self._pools, self._scales, trow, np.int32(matched))
-            return _PendingPrefill(req=req, slot=slot, caches=caches,
-                                   done=matched, seq=self._pf_seq, pt=pt,
-                                   trow=trow, wrow=wrow)
-        # window group: the blocks the FIRST decode step (at plen) can
-        # still see; the splice writes the scratch's rows of exactly
-        # those (nothing matched, so the scratch starts empty)
-        wt = WindowTable(self.block_size, self._win)
-        wt.base = wt.first_needed(plen)
-        try:
-            while wt.capacity < plen:
-                wt.append_block(self._walloc.alloc())
-        except CacheOOM:
-            self._free_window(wt)
-            for bid in pt.blocks:
-                self._alloc.decref(bid)
-            raise
-        wrow += (wt.as_linear_row(self._maxb, self._wtrash),)
-        return _PendingPrefill(req=req, slot=slot,
-                               caches=self._fresh_scratch(),
-                               done=0, seq=self._pf_seq, pt=pt,
-                               trow=trow, wrow=wrow, wt=wt)
+        else:
+            # window group: the blocks the FIRST decode step (at plen)
+            # can still see; the splice writes the scratch's rows of
+            # exactly those
+            wt = WindowTable(self.block_size, self._win)
+            wt.base = wt.first_needed(plen)
+            try:
+                while wt.capacity < plen:
+                    wt.append_block(self._walloc.alloc())
+            except CacheOOM:
+                self._free_window(wt)
+                for bid in pt.blocks:
+                    self._alloc.decref(bid)
+                raise
+            wrow += (wt.as_linear_row(self._maxb, self._wtrash),)
+        p = self._pending[slot] = _PendingPrefill(
+            req=req, slot=slot,
+            caches=self._fresh_scratch() if caches is None else caches,
+            done=matched, seq=self._pf_seq, pt=pt, trow=trow, wrow=wrow,
+            wt=wt, step0=self._step_n)
+        self._admit_defers.pop(req.rid, None)   # admitted: ladder done
+        return p
 
     def _advance_chunk(self, p: _PendingPrefill) -> None:
         """Run ONE bucketed chunk of p's prompt into its scratch.
@@ -2880,7 +2723,7 @@ class ContinuousServer:
         Fault site "prefill": the check fires BEFORE the chunk
         dispatch and before any host mutation, so a fault here leaves
         the pending internally consistent — recovery restarts it from
-        the prompt (`_restart_pending`; paged restarts re-match the
+        the prompt (`_restart_pending`; a restart re-matches the
         radix prefix, so already-resident blocks are not recomputed).
         """
         faultinject.check("prefill")
@@ -2907,7 +2750,7 @@ class ContinuousServer:
         its last chunk handed back (one layer and the head), which
         picks the seed token and sets the slot's lane of the per-slot
         vectors on the device (`_probe_prog`), splice the scratch into
-        the slot (dense rows / paged blocks), go live. An admission
+        the slot's blocks, go live. An admission
         enqueues its named programs and nothing else: every operand
         here is host NumPy. The host reads the seed token
         (`_land_seeds`) once this step's decode is enqueued — at once
@@ -2923,14 +2766,9 @@ class ContinuousServer:
         if p.flow is not None:
             tracing.flow_end(p.flow, "serving.prefill_chunks")
             p.flow = None
-        if self.paged:
-            self._pools, self._scales = self._paged_splice_prog()(
-                self._pools, self._scales, caches, p.wrow,
-                np.int32(slot))
-            self._tables[slot], self._wtables[slot] = p.pt, p.wt
-        else:
-            self._caches = self._splice_prog()(
-                self._caches, caches, np.int32(slot))
+        self._pools, self._scales = self._paged_splice_prog()(
+            self._pools, self._scales, caches, p.wrow, np.int32(slot))
+        self._tables[slot], self._wtables[slot] = p.pt, p.wt
         del self._pending[slot]
         req.sent = 1
         self._slot_req[slot] = req
@@ -3171,8 +3009,8 @@ class ContinuousServer:
         """Zero-model draft proposals per live slot: n-gram
         continuation mining over the slot's own history (prompt +
         generated so far), falling back to the radix tree's cached
-        continuations when the history has no recurring suffix (paged
-        mode with prefix reuse keeps whole retired prompts around —
+        continuations when the history has no recurring suffix
+        (prefix reuse keeps whole retired prompts around —
         `RadixCache.peek` reads them without taking leases)."""
         drafts: Dict[int, List[int]] = {}
         for s in live:
@@ -3180,7 +3018,7 @@ class ContinuousServer:
             k = kcap[s]
             hist = req.prompt + req.tokens
             d = _ngram_propose(hist, k, self._spec_ngram) if k else []
-            if not d and k and self.paged and self._prefix_reuse:
+            if not d and k and self._prefix_reuse:
                 d = self._radix.peek(hist, k)
             drafts[s] = d[:k]
         return drafts
@@ -3232,10 +3070,8 @@ class ContinuousServer:
         plus the bonus target token. Content is byte-identical to the
         sequential step loop (see `_verify_tail`); only the number of
         tokens per host sync changes. Rejection is cheap by
-        construction: dense scratch rows past the committed frontier
-        are dead under the causal mask, and paged tables just rewind
-        their cursor (`PageTable.rollback`) and drop window-extension
-        blocks."""
+        construction: the tables just rewind their cursor
+        (`PageTable.rollback`) and drop window-extension blocks."""
         self._flush()              # spec commits synchronously
         kcap: Dict[int, int] = {}
         for s in live:
@@ -3278,18 +3114,13 @@ class ContinuousServer:
             pos = np.array(self._pos, np.int32)
             kvec = np.array(kvec_host, np.int32)
             temp, keys = self._lanes()
-            if self.paged:
-                for s in live:
-                    self._ensure_window(s, self._pos[s],
-                                        self._pos[s] + kvec_host[s])
-                self._pools, self._scales, packed, ms = \
-                    self._paged_verify_prog(width)(
-                        self.params, self._pools, self._scales, toks,
-                        pos, self._tables_dev(), kvec, temp, keys)
-            else:
-                self._caches, packed, ms = self._verify_prog(width)(
-                    self.params, self._caches, toks, pos, kvec, temp,
-                    keys)
+            for s in live:
+                self._ensure_window(s, self._pos[s],
+                                    self._pos[s] + kvec_host[s])
+            self._pools, self._scales, packed, ms = \
+                self._paged_verify_prog(width)(
+                    self.params, self._pools, self._scales, toks, pos,
+                    self._tables_dev(), kvec, temp, keys)
             if ms is not None:
                 self._moe_buf.append(ms)
             # the speculative step's single designed host sync: one
@@ -3313,13 +3144,11 @@ class ContinuousServer:
             self._spec_accepted += min(acc, kvec_host[s])
             self._spec_adapt_k(s, min(acc, kvec_host[s]),
                                kvec_host[s])
-            if self.paged:
-                # rewind the table cursor past rejected draft rows;
-                # _release_slot (below, on retire) must see the
-                # post-rollback block list or it would double-release
-                pt = self._tables[s]
-                for bid in pt.rollback(self._pos[s]):
-                    self._alloc.decref(bid)
+            # rewind the table cursor past rejected draft rows;
+            # _release_slot (below, on retire) must see the
+            # post-rollback block list or it would double-release
+            for bid in self._tables[s].rollback(self._pos[s]):
+                self._alloc.decref(bid)
             self._maybe_retire(s)
         self._spec_steps += 1
         self._spec_emitted += emitted_total
@@ -3336,20 +3165,20 @@ class ContinuousServer:
         host holds: the tokens landed, and the position and feedback
         token that follow from them — `_pos` is ahead of it by the
         steps in flight (``req.sent - len(req.tokens)``, at most one
-        where a flush sweeps). Paged pins take one extra ref per FULL
+        where a flush sweeps). The pins take one extra ref per FULL
         block below pos — never the partial frontier block, whose pin
         would force a COW fork on the next token write (see
         SlotCheckpoint)."""
         req = self._slot_req[slot]
         pos = len(req.prompt) + len(req.tokens) - 1
         pins: List[int] = []
-        if self.paged and not self._recurrent:
+        if not self._recurrent:
             pt = self._tables[slot]
             pins = list(pt.blocks[:pos // self.block_size])
             for bid in pins:
                 self._alloc.incref(bid)
         wpins = None
-        wt = self._wtables[slot] if self.paged else None
+        wt = self._wtables[slot]
         if wt is not None:
             # `_ensure_block` frees one step behind the write, so the
             # frontier's window is still mapped with a step in flight
@@ -3393,14 +3222,12 @@ class ContinuousServer:
 
     def _restore_slot(self, slot: int) -> None:
         """Rewind one live slot to its last checkpoint; the decode
-        loop then replays ONLY the lost suffix. Paged: rebuild the
-        table from the pinned full blocks plus the live table's
-        frontier block — its rows [0, pos % bs) are byte-exact
-        because KV rows are append-only and COW forks copy every row
-        written so far. Dense: re-prefill prompt ++ tokens[:-1] through
-        the bucketed chunk programs (byte-identical rows by the
-        differential contract). Replayed tokens re-emit identically,
-        so a restored run's outputs match the fault-free run."""
+        loop then replays ONLY the lost suffix. Rebuild the table
+        from the pinned full blocks plus the live table's frontier
+        block — its rows [0, pos % bs) are byte-exact because KV rows
+        are append-only and COW forks copy every row written so far.
+        Replayed tokens re-emit identically, so a restored run's
+        outputs match the fault-free run."""
         ck = self._ckpt[slot]
         req = self._slot_req[slot]
         if self._recurrent:
@@ -3414,38 +3241,32 @@ class ContinuousServer:
             self._cur[slot] = ck.cur
             self._slot_k[slot] = ck.slot_k
             self._slot_acc[slot] = ck.slot_acc
-            if self.paged:
-                pt = self._tables[slot]
-                # pins cover the full blocks; the frontier block (if
-                # ck.pos is not block-aligned) rides over from the
-                # current table — it covered ck.pos at capture and
-                # tables only grow, so it is still there
-                keep = list(ck.pins)
-                if pt is not None and ck.pos % self.block_size:
-                    keep.append(pt.blocks[ck.pos // self.block_size])
-                npt = PageTable(self.block_size)
-                for bid in keep:
-                    self._alloc.incref(bid)   # the new table's refs
-                npt.extend_blocks(keep)
-                npt.tokens = ck.pos
-                if pt is not None:            # AFTER increfs: shared
-                    for bid in pt.blocks:     # bids must not hit 0
-                        self._alloc.decref(bid)
-                self._tables[slot] = npt
-                if ck.wpins is not None:
-                    # the window group as it stood at capture: rows
-                    # past ck.pos in its blocks are rewritten by the
-                    # replay before any query sees them
-                    for bid in ck.wpins[1]:
-                        self._walloc.incref(bid)
-                    self._free_window(self._wtables[slot])
-                    self._wtables[slot] = WindowTable(
-                        self.block_size, self._win, *ck.wpins)
-            else:
-                self._caches = self._splice_prog()(
-                    self._caches,
-                    self._reprefill(req.prompt + req.tokens[:-1]),
-                    np.int32(slot))
+            pt = self._tables[slot]
+            # pins cover the full blocks; the frontier block (if
+            # ck.pos is not block-aligned) rides over from the
+            # current table — it covered ck.pos at capture and
+            # tables only grow, so it is still there
+            keep = list(ck.pins)
+            if pt is not None and ck.pos % self.block_size:
+                keep.append(pt.blocks[ck.pos // self.block_size])
+            npt = PageTable(self.block_size)
+            for bid in keep:
+                self._alloc.incref(bid)   # the new table's refs
+            npt.extend_blocks(keep)
+            npt.tokens = ck.pos
+            if pt is not None:            # AFTER increfs: shared
+                for bid in pt.blocks:     # bids must not hit 0
+                    self._alloc.decref(bid)
+            self._tables[slot] = npt
+            if ck.wpins is not None:
+                # the window group as it stood at capture: rows
+                # past ck.pos in its blocks are rewritten by the
+                # replay before any query sees them
+                for bid in ck.wpins[1]:
+                    self._walloc.incref(bid)
+                self._free_window(self._wtables[slot])
+                self._wtables[slot] = WindowTable(
+                    self.block_size, self._win, *ck.wpins)
             if self._spec and self._draft_params is not None:
                 self._draft_prefill(slot, req.prompt
                                     + req.tokens[:-1])
@@ -3477,8 +3298,8 @@ class ContinuousServer:
         self._flt_restored += 1
 
     def _reprefill(self, seq: List[int]):
-        """The restore path that recomputes: a fresh b=1 scratch with
-        the cache state of `seq`, by re-running bucketed prefill over
+        """A recurrent model's restore recomputes: a fresh b=1 scratch
+        with the cache state of `seq`, by re-running bucketed prefill over
         the known tokens (the caller splices it). No probe: the
         restore point already knows the feedback token."""
         scratch = self._fresh_scratch()
@@ -3507,7 +3328,7 @@ class ContinuousServer:
     def _restart_pending(self, slot: int) -> None:
         """Faulted mid-chunked-prefill: drop the pending's scratch and
         blocks and start over from the prompt — `_start_prefill`
-        re-matches the radix prefix, so the paged restart recomputes
+        re-matches the radix prefix, so the restart recomputes
         only what was never resident. OOM on the restart requeues the
         request instead of failing recovery."""
         p = self._drop_pending(slot)
@@ -3560,8 +3381,7 @@ class ContinuousServer:
                 # shedding beats decoding from corrupt state
                 self._slot_req[s] = None
                 self._drop_ckpt(s)
-                if self.paged:
-                    self._release_slot(s, req)
+                self._release_slot(s, req)
                 self._shed_req(req, RequestShedError(
                     req.rid, "no checkpoint to restore from"))
         for s in list(self._pending):
@@ -3631,8 +3451,7 @@ class ContinuousServer:
                     continue
                 self._slot_req[s] = None
                 self._drop_ckpt(s)
-                if self.paged:
-                    self._release_slot(s, req)
+                self._release_slot(s, req)
                 self._shed_req(req, RequestShedError(req.rid, reason))
             for s in list(self._pending):
                 p = self._drop_pending(s)
@@ -3682,8 +3501,7 @@ class ContinuousServer:
             if self._slot_req[slot] is req:
                 self._slot_req[slot] = None
                 self._drop_ckpt(slot)
-                if self.paged:
-                    self._release_slot(slot, req)
+                self._release_slot(slot, req)
 
     def _flush(self, keep: int = 0) -> None:
         """Materialize the buffered steps' token vectors, all but the
@@ -3793,9 +3611,9 @@ class ContinuousServer:
                 self._moe_capacity_pct = (
                     self.cfg.n_experts * 100 if pct <= 0
                     else max(1, pct))
-            elif key == "hpx.cache.radix_budget_blocks" and self.paged:
+            elif key == "hpx.cache.radix_budget_blocks":
                 self._radix.budget_blocks = max(1, int(raw))
-            elif key == "hpx.cache.tier.host_budget_mb" and self.paged \
+            elif key == "hpx.cache.tier.host_budget_mb" \
                     and self._tier is not None:
                 # shrink applies on the next demotion's LRU sweep
                 self._tier.budget_bytes = max(1, int(raw)) << 20
@@ -3808,7 +3626,8 @@ class ContinuousServer:
         doc: Dict[str, Any] = {
             "kind": "server",
             "instance": self.counter_instance,
-            "paged": self.paged,
+            "cache": {"free_blocks": self._alloc.free_count,
+                      "num_blocks": self._alloc.num_blocks},
             "queue_depth": len(self._queue),
             "pending_prefills": len(self._pending),
             "live_slots": sum(1 for r in self._slot_req
@@ -3821,13 +3640,8 @@ class ContinuousServer:
         }
         if self._alerts is not None:
             doc["alerts"] = self._alerts.state()
-        if self.paged:
-            doc["cache"] = {
-                "free_blocks": self._alloc.free_count,
-                "num_blocks": self._alloc.num_blocks,
-            }
-            if self._tier is not None:
-                doc["tier"] = self._tier.stats()
+        if self._tier is not None:
+            doc["tier"] = self._tier.stats()
         return doc
 
     def step(self) -> bool:
@@ -3913,32 +3727,24 @@ class ContinuousServer:
             t0 = self._acct.work_clock()
             with tracing.span("serving.decode.operands", "serving",
                               live=len(live)):
-                # dense: dead slots re-write their own last position
-                # (harmless: never read — admission overwrites rows
-                # 0..plen first). Paged: dead slots' tables are
-                # all-trash, so their writes land in the reserved trash
-                # block instead of a recycled live block. Dead slots'
-                # feedback tokens are stale argmax/sample outputs —
-                # always valid ids.
+                # dead slots' tables are all-trash, so their writes
+                # land in the reserved trash block instead of a
+                # recycled live block. Dead slots' feedback tokens are
+                # stale argmax/sample outputs — always valid ids.
                 tok = self._feedback()
                 temp, keys = self._lanes()
                 pos = np.array(self._pos, np.int32)
-                if self.paged:
-                    for s in live:
-                        self._ensure_block(s, self._pos[s])
-                    tables = self._tables_dev()
+                for s in live:
+                    self._ensure_block(s, self._pos[s])
+                tables = self._tables_dev()
             self._acct.eager_ns += self._acct.work_clock() - t0
-            if self.paged:
-                self._pools, self._scales, nxt, ms = \
-                    self._paged_step_prog()(
-                        self.params, self._pools, self._scales, tok,
-                        pos, tables, temp, keys)
-            else:
-                self._caches, nxt, ms = self._step_prog()(
-                    self.params, self._caches, tok, pos, temp, keys)
+            self._pools, self._scales, nxt, ms = \
+                self._paged_step_prog()(
+                    self.params, self._pools, self._scales, tok, pos,
+                    tables, temp, keys)
             if ms is not None:
                 self._moe_buf.append(ms)
-            if self.paged and "sparse" in self._kinds:
+            if "sparse" in self._kinds:
                 self._sparse_account(pos[live])
             self._cur_dev = nxt
             self._rate.mark(float(len(live)))
@@ -3960,8 +3766,7 @@ class ContinuousServer:
                     # the read this asks for, in the next step()
                     self._slot_req[s] = None
                     self._drop_ckpt(s)
-                    if self.paged:
-                        self._release_slot(s, req)
+                    self._release_slot(s, req)
                     retired = True
             self._buf.append((nxt, lanes))
             # every program of this step is enqueued: now the reads.

@@ -5,7 +5,7 @@ This is the ROADMAP's "millions of users" topology: PR 8's
 disaggregated prefill/decode split and PR 10's mesh-sharded paged
 serving composed behind one front end. A :class:`FleetRouter` fronts
 *N* ``PrefillWorker``s and *M* ``DecodeWorker``s (each optionally
-constructed with ``mesh=`` so its paged server runs under
+constructed with ``mesh=`` so its server runs under
 ``shard_map``), and replaces the base router's least-loaded placement
 with **prefix-cache-aware** scoring — the AGAS move of treating
 workers as named, queryable localities:

@@ -383,7 +383,7 @@ HPX009_BAD = """\
 import numpy as np
 class ContinuousServer:
     def _spec_step(self, live):
-        packed = self._verify_prog(4)(None)
+        packed = self._paged_verify_prog(4)(None)
         vals = np.asarray(packed)
         return vals
 """

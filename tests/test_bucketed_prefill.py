@@ -3,8 +3,8 @@
 The contract: chunking a prompt into fixed-width padded windows and
 splicing the scratch cache changes WHICH programs run, never the
 bytes — every request still equals its solo transformer.generate()
-run, for prompt lengths straddling every bucket boundary, dense and
-paged, greedy and sampled, async dispatch on and off.  Plus the
+run, for prompt lengths straddling every bucket boundary, over blocks of
+16 rows and of 4, greedy and sampled, async dispatch on and off.  Plus the
 scheduling guarantees: the program cache stays O(buckets), and a
 short prompt admitted behind a long prompt's chunked prefill
 overtakes its tail chunks (ready-chunk ordering)."""
@@ -75,23 +75,28 @@ def test_resolve_buckets():
         _resolve_buckets(" , ", 8)
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("block_size", [None, 4], ids=["paged", "block4"])
 @pytest.mark.parametrize("async_dispatch", [True, False],
                          ids=["async", "sync"])
 @pytest.mark.parametrize("width", [CHUNK, 256, _CHUNK_CEILING],
                          ids=["stated-8", "derived-256", "ceiling"])
-def test_boundary_plens_match_generate(params, monkeypatch, paged,
+def test_boundary_plens_match_generate(params, monkeypatch, block_size,
                                        async_dispatch, width):
     """Every bucket-boundary prompt length, greedy AND sampled mixed in
     one batch, byte-identical to the solo run; at a derived width, one
-    under, at and one over that width and its half."""
+    under, at and one over that width and its half. Over the default
+    block of 16 rows, and over blocks of 4: a bucket's padded tail, the
+    chunk boundary and the splice's last block then fall on either
+    side of a block seam at every length."""
     if width == CHUNK:
-        srv = ContinuousServer(params, CFG, slots=3, smax=64, paged=paged,
+        srv = ContinuousServer(params, CFG, slots=3, smax=64,
+                               block_size=block_size,
                                prefill_chunk=CHUNK, prefill_buckets=LADDER,
                                async_dispatch=async_dispatch)
         plens = PLENS
     else:
-        srv = _derived_server(monkeypatch, params, width, paged=paged,
+        srv = _derived_server(monkeypatch, params, width,
+                              block_size=block_size,
                               async_dispatch=async_dispatch)
         plens = [w + d for w in (width // 2, width) for d in (-1, 0, 1)]
     want = {}

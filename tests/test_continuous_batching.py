@@ -90,16 +90,21 @@ KEYS = {"raw": jax.random.PRNGKey,              # uint32[2] on the device
 
 
 @pytest.mark.parametrize("staggered", [False, True])
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("block_size", [None, 2], ids=["paged", "block2"])
 @pytest.mark.parametrize("kind", sorted(KEYS))
-def test_per_request_sampling_matches_solo(params, kind, paged, staggered):
+def test_per_request_sampling_matches_solo(params, kind, block_size,
+                                           staggered):
     """Sampled requests reproduce their SOLO generate(temperature, key)
     tokens exactly, the seed token the probe's program picks included
     (the key folds match), mixed in one batch with greedy requests —
-    with a key of each kind, and admitted in one step or in different
-    steps beside slots that are already decoding."""
+    with a key of each kind, admitted in one step or in different
+    steps beside slots that are already decoding, and over the default
+    block (a request lives in one) or blocks of 2 rows (every prompt,
+    the probe's rewrite of row plen - 1 and every other decoded token
+    cross a block seam)."""
     k1, k2 = KEYS[kind](11), KEYS[kind](22)
-    srv = ContinuousServer(params, CFG, slots=3, smax=64, paged=paged)
+    srv = ContinuousServer(params, CFG, slots=3, smax=64,
+                           block_size=block_size)
     asks = [([3, 1, 4], dict(max_new=8, temperature=0.8, key=k1)),
             ([2, 7], dict(max_new=6)),                      # greedy
             ([5, 6, 7, 8], dict(max_new=7, temperature=1.3, key=k2)),

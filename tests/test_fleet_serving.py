@@ -80,7 +80,7 @@ def _check(out, rids, reqs, params):
 
 
 # ---------------------------------------------------------------------------
-# fault-free N x M identity: dense prefill -> mesh-sharded paged decode
+# fault-free N x M identity: one-slot prefill -> mesh-sharded decode
 # ---------------------------------------------------------------------------
 
 def test_fleet_mesh_decode_matches_generate(params, mesh,
@@ -356,7 +356,8 @@ def test_placement_spans_and_flow_arrows(params, fresh_digests):
 
 def test_decode_worker_mesh_passthrough(params, mesh):
     solo = DecodeWorker(params, CFG, slots=2, smax=64)
-    assert solo.srv.mesh is None and solo.srv.paged
+    assert solo.srv.mesh is None
+    assert solo.srv.block_size == solo.block_size()
     sharded = DecodeWorker(params, CFG, slots=2, smax=64, mesh=mesh)
     assert sharded.srv.mesh is mesh
     solo.close()

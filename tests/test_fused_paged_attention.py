@@ -6,7 +6,7 @@ oracle it is pinned against. The numerics contract (see the kernel's
 section comment): bitwise-equal scores and softmax, final logits within
 ~1 ulp (the PV contraction is the kernel's 2-D dot vs XLA's batched
 einsum), and therefore EXACT tokens — which the server-level tests here
-assert across dense/paged-gather/paged-fused, greedy/sampled,
+assert across generate()/gather/fused, greedy/sampled,
 speculative/non-speculative, bf16 and int8 KV.
 
 `fused_paged_online_attention` (paged_kernel="fused_online") carries a
@@ -922,7 +922,7 @@ def test_server_auto_block_size_honors_env(params, monkeypatch):
     assert srv.block_size == 8
 
 
-# -- server level: dense == gather == fused ---------------------------------
+# -- server level: generate() == gather == fused ------------------------------
 
 def _serve(params, reqs, **kw):
     srv = ContinuousServer(params, CFG, slots=3, smax=64, **kw)
@@ -931,21 +931,34 @@ def _serve(params, reqs, **kw):
     return srv.run(), srv
 
 
+def _generated(params, cfg, reqs):
+    """{rid: tokens} of each request alone through `generate()`: the
+    witness that shares no pool, table or paged kernel with a server."""
+    out = {}
+    for rid, r in enumerate(reqs):
+        r = dict(r)
+        toks = tfm.generate(params, cfg,
+                            jnp.asarray([r.pop("prompt")], jnp.int32),
+                            **r)
+        out[rid] = [int(t) for t in np.asarray(toks)[0]]
+    return out
+
+
 @pytest.mark.parametrize("reqs", [REQS, SAMPLED],
                          ids=["greedy", "sampled"])
-def test_server_fused_matches_dense_and_gather(params, reqs):
-    dense, _ = _serve(params, reqs)
+def test_server_fused_matches_generate_and_gather(params, reqs):
     gather, _ = _serve(params, reqs, paged=True, paged_kernel="gather")
     fused, srv = _serve(params, reqs, paged=True, paged_kernel="fused")
     assert srv._paged_kernel == "fused"
-    assert fused == gather == dense
+    assert fused == gather == _generated(params, CFG, reqs)
 
 
 @pytest.mark.parametrize("mode", ["greedy", "sampled", "spec1", "spec2"])
-def test_server_bounded_walk_matches_dense_and_gather(mode):
+def test_server_bounded_walk_matches_generate_and_gather(mode):
     """A model whose head is 128 wide serves `fused` through the
     bounded walk (decode W = 1; the speculative verify window W = k +
-    1): the same tokens as the dense and the gather servers."""
+    1): the same tokens as the gather server (without speculation) and
+    as `generate()`."""
     cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=2,
                                 head_dim=_HD, n_layers=2, d_ff=64)
     p128 = tfm.init_params(cfg, jax.random.PRNGKey(0))
@@ -959,7 +972,8 @@ def test_server_bounded_walk_matches_dense_and_gather(mode):
             srv.submit(**r)
         return srv.run()
     fused = serve(paged=True, paged_kernel="fused", **kw)
-    assert fused == serve(paged=True, paged_kernel="gather") == serve()
+    assert (fused == serve(paged=True, paged_kernel="gather")
+            == _generated(p128, cfg, reqs))
 
 
 @pytest.mark.parametrize("k", [1, 2])
@@ -973,17 +987,16 @@ def test_server_fused_spec_matches_nonspec(params, k):
 
 @pytest.mark.parametrize("reqs", [REQS, SAMPLED],
                          ids=["greedy", "sampled"])
-def test_server_fused_online_matches_dense_and_gather(params, reqs):
+def test_server_fused_online_matches_generate_and_gather(params, reqs):
     """The acceptance sweep's token gate: the online kernel's few-ulp
     logit drift never flips a token on this workload — greedy AND
-    sampled, against BOTH the dense and the paged-gather servers."""
-    dense, _ = _serve(params, reqs)
+    sampled, against BOTH `generate()` and the gather server."""
     gather, _ = _serve(params, reqs, paged=True, paged_kernel="gather")
     online, srv = _serve(params, reqs, paged=True,
                          paged_kernel="fused_online")
     assert srv._paged_kernel == "fused_online"
     assert srv._paged_fused == "online"
-    assert online == gather == dense
+    assert online == gather == _generated(params, CFG, reqs)
 
 
 @pytest.mark.parametrize("k", [1, 2])
@@ -1019,10 +1032,9 @@ def test_server_int8_greedy_matches_bf16(params):
     workload — the ISSUE's acceptance workload. (Not a general
     guarantee: quantization MAY flip near-ties on other inputs; here
     the margins dominate one quantization step.)"""
-    dense, _ = _serve(params, REQS)
     int8, srv = _serve(params, REQS, paged=True, kv_dtype="int8")
     assert srv._kv_dtype == "int8"
-    assert int8 == dense
+    assert int8 == _generated(params, CFG, REQS)
 
 
 def test_server_int8_halves_hbm_read_bytes(params):
@@ -1119,11 +1131,8 @@ def test_paged_kernel_knob_validation(params):
     with pytest.raises(ValueError, match="kv_dtype"):
         ContinuousServer(params, CFG, slots=2, smax=64, paged=True,
                          kv_dtype="fp8_e5m2")
-    # the knobs are paged-only
-    with pytest.raises(ValueError):
-        ContinuousServer(params, CFG, slots=2, smax=64,
-                         paged_kernel="fused")
-    with pytest.raises(ValueError):
-        ContinuousServer(params, CFG, slots=2, smax=64, kv_dtype="int8")
-    with pytest.raises(ValueError):
-        ContinuousServer(params, CFG, slots=2, smax=64, kv_dtype="fp8")
+    # the knobs are every server's: there is one cache
+    srv = ContinuousServer(params, CFG, slots=2, smax=64,
+                           paged_kernel="fused", kv_dtype="int8")
+    assert srv.hbm_read_stats()["paged_kernel"] == "fused"
+    assert srv.cache_stats()["kv_dtype"] == "int8"

@@ -324,14 +324,15 @@ def test_bodies_without_a_path_refuse_by_mechanism_and_module(toy):
         ContinuousServer(params, two, paged=True, slots=2, smax=64)
 
 
-def test_dense_server_and_generate_compute_the_same_model(toy):
-    """The non-paged bodies take the same definition: the dense server
-    and generate() serve the toy token for token like the paged one."""
+def test_the_server_and_generate_compute_the_same_model(toy):
+    """The dense body takes the same definition: generate() serves the
+    toy token for token like the server, over the default block (the
+    window is one block of 16 rows) and over blocks of 8 (it is two)."""
     _, cfg, params = toy
     prompts = [_prompt(26, 7), _prompt(5, 8)]
     outs = []
-    for paged in (False, True):
-        srv = ContinuousServer(params, cfg, paged=paged, slots=2,
+    for block in (None, 8):
+        srv = ContinuousServer(params, cfg, block_size=block, slots=2,
                                smax=128, prefill_chunk=CHUNK)
         rids = [srv.submit(p, max_new=30) for p in prompts]
         out = srv.run()

@@ -51,7 +51,10 @@ SSM = tfm.TransformerConfig(
     mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=4)
 MODELS = {"sala": SALA, "ssm": SSM}
 EXECUTE = "PjRtCpuExecutable::Execute"
-MODES = ["paged", "dense", "sala", "ssm"]
+# the K/V model over blocks of 8 rows (the chunk's width) and of 4 (a
+# chunk, and every admission but the shortest, spans blocks)
+BLOCKS = {"paged": 8, "block4": 4}
+MODES = [*BLOCKS, "sala", "ssm"]
 # what each request of a workload asks for beside its prompt
 KINDS = {
     "greedy": [{}] * 5,
@@ -75,11 +78,10 @@ def params():
 def _server(params, mode):
     if mode in MODELS:
         return ContinuousServer(params[mode], MODELS[mode], slots=3, smax=64,
-                                prefill_chunk=8, prefill_buckets="4,8",
-                                paged=True)
+                                prefill_chunk=8, prefill_buckets="4,8")
     return ContinuousServer(params[None], CFG, slots=3, smax=64,
                             prefill_chunk=8, prefill_buckets="4,8",
-                            paged=(mode == "paged"), block_size=8)
+                            block_size=BLOCKS[mode])
 
 
 def _submit(srv, kind, seed=0):

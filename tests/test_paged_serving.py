@@ -1,8 +1,9 @@
-"""Paged serving (ContinuousServer(paged=True)): the block-pool +
-radix-prefix-reuse decode path must be BYTE-IDENTICAL to the dense
-slot-cache path — same tokens for every request, greedy and sampled,
-with or without shared prefixes — while actually reusing cached
-prefix blocks (nonzero hit rate, prefill tokens saved)."""
+"""The server's cache (block pools + radix prefix reuse): whatever the
+block geometry, the pool's size, its dtype or the prefixes requests
+share, the tokens must be BYTE-IDENTICAL to `generate()`'s, which has
+no pool, no table and no tree — same tokens for every request, greedy
+and sampled — while actually reusing cached prefix blocks (nonzero hit
+rate, prefill tokens saved)."""
 
 import jax
 import jax.numpy as jnp
@@ -24,71 +25,71 @@ def params():
     return tfm.init_params(CFG, jax.random.PRNGKey(0))
 
 
-def _ref(params, cfg, prompt, max_new, eos_id=None):
+def _ref(params, cfg, prompt, max_new, eos_id=None, temperature=0.0,
+         key=None):
     out = tfm.generate(params, cfg,
                        jnp.asarray([prompt], jnp.int32),
-                       max_new=max_new, eos_id=eos_id)
+                       max_new=max_new, eos_id=eos_id,
+                       temperature=temperature, key=key)
     return [int(t) for t in np.asarray(out)[0]]
 
 
-def _run_both(params, cfg, reqs, smax=64, slots=3, **paged_kw):
-    """Submit the same mix to a dense and a paged server; returns
-    ({rid: tokens} dense, {rid: tokens} paged, paged server). rids
-    align because submission order is identical."""
-    dense = ContinuousServer(params, cfg, slots=slots, smax=smax)
-    paged = ContinuousServer(params, cfg, slots=slots, smax=smax,
-                             paged=True, **paged_kw)
-    for srv in (dense, paged):
-        for r in reqs:
-            srv.submit(**r)
-    return dense.run(), paged.run(), paged
+def _run_both(params, cfg, reqs, smax=64, slots=3, **server_kw):
+    """Each request alone through `generate()` (the oracle, which has no
+    pool, no table and no radix tree), and the same mix through one
+    server built with `server_kw`; returns ({rid: tokens} generate's,
+    {rid: tokens} the server's, the server). rids are the submission
+    order."""
+    srv = ContinuousServer(params, cfg, slots=slots, smax=smax,
+                           **server_kw)
+    for r in reqs:
+        srv.submit(**r)
+    ref = {rid: _ref(params, cfg, **r) for rid, r in enumerate(reqs)}
+    return ref, srv.run(), srv
 
 
 # -- equivalence -------------------------------------------------------------
 
-def test_greedy_matches_dense_and_generate(params):
+def test_greedy_matches_generate(params):
     reqs = [dict(prompt=[3, 1, 4], max_new=9),
             dict(prompt=[2, 7], max_new=5),
             dict(prompt=[5, 6, 7, 8, 9], max_new=12),
             dict(prompt=[1], max_new=7),
             dict(prompt=[9, 9, 2, 1], max_new=3),
             dict(prompt=[4, 4], max_new=10)]
-    outd, outp, _ = _run_both(params, CFG, reqs)
-    assert outd == outp
-    for rid, r in enumerate(reqs):
-        assert outp[rid] == _ref(params, CFG, r["prompt"], r["max_new"])
+    ref, out, _ = _run_both(params, CFG, reqs)
+    assert out == ref
 
 
-def test_sampled_matches_dense(params):
+def test_sampled_matches_generate(params):
     """temperature > 0: the per-(position, row) fold_in sampling
-    contract must survive the paged rewrite bit-for-bit."""
+    contract holds over the pool bit-for-bit."""
     reqs = [dict(prompt=[3, 1, 4], max_new=8, temperature=0.9,
                  key=jax.random.PRNGKey(7)),
             dict(prompt=[2, 7, 9], max_new=8, temperature=0.7,
                  key=jax.random.PRNGKey(8)),
             dict(prompt=[5, 5], max_new=6, temperature=1.3,
                  key=jax.random.PRNGKey(9))]
-    outd, outp, _ = _run_both(params, CFG, reqs, slots=2)
-    assert outd == outp
+    ref, out, _ = _run_both(params, CFG, reqs, slots=2)
+    assert out == ref
 
 
-def test_gqa_rope_matches_dense():
+def test_gqa_rope_matches_generate():
     params = tfm.init_params(GQA_ROPE, jax.random.PRNGKey(5))
     reqs = [dict(prompt=[3, 1, 4, 1, 5], max_new=7),
             dict(prompt=[2, 7], max_new=5),
             dict(prompt=[1, 2, 3], max_new=6)]
-    outd, outp, _ = _run_both(params, GQA_ROPE, reqs, smax=48, slots=2)
-    assert outd == outp
+    ref, out, _ = _run_both(params, GQA_ROPE, reqs, smax=48, slots=2)
+    assert out == ref
 
 
-def test_eos_matches_dense(params):
+def test_eos_matches_generate(params):
     probe = _ref(params, CFG, [3, 1, 4], 9)
     eos = probe[3]
     reqs = [dict(prompt=[3, 1, 4], max_new=9, eos_id=eos),
             dict(prompt=[2, 7], max_new=5)]
-    outd, outp, _ = _run_both(params, CFG, reqs, slots=2)
-    assert outd == outp
-    assert outp[0] == _ref(params, CFG, [3, 1, 4], 9, eos_id=eos)
+    ref, out, _ = _run_both(params, CFG, reqs, slots=2)
+    assert out == ref
 
 
 # -- prefix reuse ------------------------------------------------------------
@@ -96,13 +97,13 @@ def test_eos_matches_dense(params):
 def test_shared_prefix_hits_and_stays_identical(params):
     """Requests sharing a 2-block prefix: later admissions must match
     the published chain (saved prefill tokens) and still emit exactly
-    the dense tokens."""
+    `generate()`'s tokens."""
     pre = list(range(1, 33))                    # 32 = 2 blocks of 16
     reqs = [dict(prompt=pre + [40, 41], max_new=6),
             dict(prompt=pre + [50], max_new=6),
             dict(prompt=pre + [60, 61, 62], max_new=6)]
-    outd, outp, srv = _run_both(params, CFG, reqs, slots=2)
-    assert outd == outp
+    ref, out, srv = _run_both(params, CFG, reqs, slots=2)
+    assert out == ref
     st = srv.cache_stats()
     assert st["tokens_matched"] >= 32           # later reqs reused pre
     assert st["hit_rate"] > 0
@@ -117,8 +118,8 @@ def test_disjoint_prefixes_no_false_sharing(params):
     """Unrelated prompts must never match each other's chains — zero
     matched tokens, identical output."""
     reqs = [dict(prompt=[10 + i] * 20, max_new=5) for i in range(4)]
-    outd, outp, srv = _run_both(params, CFG, reqs, slots=2)
-    assert outd == outp
+    ref, out, srv = _run_both(params, CFG, reqs, slots=2)
+    assert out == ref
     assert srv.cache_stats()["tokens_matched"] == 0
 
 
@@ -126,9 +127,9 @@ def test_prefix_reuse_off_is_still_identical(params):
     pre = list(range(1, 33))
     reqs = [dict(prompt=pre + [40], max_new=5),
             dict(prompt=pre + [50], max_new=5)]
-    outd, outp, srv = _run_both(params, CFG, reqs, slots=2,
+    ref, out, srv = _run_both(params, CFG, reqs, slots=2,
                                 prefix_reuse=False)
-    assert outd == outp
+    assert out == ref
     assert srv.cache_stats()["tokens_matched"] == 0
     assert srv.cache_stats()["prefill_tokens_saved"] == 0
 
@@ -140,31 +141,15 @@ def test_oom_evicts_and_recovers(params):
     # 6 blocks leaves one spare for radix retention -> guaranteed OOM
     # churn across 6 sequential requests.
     reqs = [dict(prompt=[10 + i] * 20, max_new=5) for i in range(6)]
-    outd, outp, srv = _run_both(params, CFG, reqs, smax=32, slots=2,
+    ref, out, srv = _run_both(params, CFG, reqs, smax=32, slots=2,
                                 num_blocks=6)
-    assert outd == outp
+    assert out == ref
     st = srv.cache_stats()
     assert st["total_evictions"] > 0            # the retry path ran
     assert st["in_use"] <= 6
 
 
 # -- construction contracts --------------------------------------------------
-
-def test_paged_mesh_gate_restores_refusal(params):
-    """paged=True + mesh= is SUPPORTED now (see
-    test_sharded_paged_serving.py); hpx.serving.mesh.paged=0 is the
-    operational escape hatch back to the old single-device refusal —
-    it must fire before the mesh is even inspected."""
-    from hpx_tpu.core.config import runtime_config
-    rc = runtime_config()
-    rc.set("hpx.serving.mesh.paged", "0")
-    try:
-        with pytest.raises(ValueError, match="mesh.paged"):
-            ContinuousServer(params, CFG, slots=2, smax=64, paged=True,
-                             mesh=object())
-    finally:
-        rc.set("hpx.serving.mesh.paged", "1")
-
 
 def test_paged_rejects_misaligned_smax(params):
     with pytest.raises(ValueError, match="divisible"):
@@ -177,12 +162,6 @@ def test_paged_rejects_undersized_pool(params):
     with pytest.raises(ValueError, match="num_blocks"):
         ContinuousServer(params, CFG, slots=2, smax=64, paged=True,
                          num_blocks=4)
-
-
-def test_dense_rejects_cache_stats(params):
-    srv = ContinuousServer(params, CFG, slots=2, smax=64)
-    with pytest.raises(ValueError, match="paged=True"):
-        srv.cache_stats()
 
 
 # -- instant retirement (admission re-scan) ----------------------------------

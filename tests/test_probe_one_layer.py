@@ -59,10 +59,14 @@ CFGS = {
         layer_mixer=("sparse", "lightning"),
         layer_rope=(None, tfm.RopeSpec(10000.0)), **_SALA, **_BASE),
 }
-# (kind, paged): the dense server holds K/V pairs alone
-MODES = [("kv", False), ("kv", True), ("window", False), ("window", True),
-         ("latent", True), ("kda", True), ("sala", True),
-         ("sala_rec", True)]
+# (kind, block): the default block of 16 rows, and for the models whose
+# rows are K/V pairs addressed by position blocks of 4, so that the
+# chunk's padded tail (W = 8 over two blocks), the probe's rewrite of
+# row plen - 1 and the window group's ring all cross block seams (a
+# sparse layer's page is the model's own block)
+MODES = [("kv", None), ("kv", 4), ("window", None), ("window", 4),
+         ("latent", None), ("kda", None), ("sala", None),
+         ("sala_rec", None)]
 
 
 @pytest.fixture(scope="module")
@@ -76,9 +80,9 @@ def _prompt(n, seed=0):
     return [int(t) for t in r.randint(1, 64, n)]
 
 
-def _server(models, kind, paged, **kw):
+def _server(models, kind, block=None, **kw):
     return ContinuousServer(models[kind], CFGS[kind], slots=2, smax=SMAX,
-                            paged=paged, prefill_chunk=W,
+                            block_size=block, prefill_chunk=W,
                             prefill_buckets="4,8", **kw)
 
 
@@ -117,10 +121,10 @@ def _admit(srv, prompt, **ask):
 
 
 @pytest.mark.parametrize("plen", LENGTHS)
-@pytest.mark.parametrize("kind,paged", MODES)
-def test_first_token_is_the_last_positions_pick(models, kind, paged, plen):
+@pytest.mark.parametrize("kind,block", MODES)
+def test_first_token_is_the_last_positions_pick(models, kind, block, plen):
     prompt = _prompt(plen, seed=plen)
-    srv = _server(models, kind, paged)
+    srv = _server(models, kind, block)
     slot, tok0 = _admit(srv, prompt)
     caches, want = _oneshot(models, kind, prompt)
     assert tok0 == want
@@ -149,13 +153,13 @@ def test_first_token_is_the_last_positions_pick(models, kind, paged, plen):
 
 
 @pytest.mark.parametrize("plen", [1, W, 2 * W + 3])
-@pytest.mark.parametrize("kind,paged", [("kv", False), ("kv", True),
-                                        ("kda", True), ("sala", True),
-                                        ("sala_rec", True)])
-def test_a_sampled_first_token_is_the_same_draw(models, kind, paged, plen):
+@pytest.mark.parametrize("kind,block", [("kv", None), ("kv", 4),
+                                        ("kda", None), ("sala", None),
+                                        ("sala_rec", None)])
+def test_a_sampled_first_token_is_the_same_draw(models, kind, block, plen):
     prompt = _prompt(plen, seed=40 + plen)
     key = jax.random.PRNGKey(11 + plen)
-    srv = _server(models, kind, paged)
+    srv = _server(models, kind, block)
     _, tok0 = _admit(srv, prompt, temperature=0.9, key=key)
     assert tok0 == _oneshot(models, kind, prompt, 0.9, key)[1]
     if kind == "kv":
@@ -172,7 +176,7 @@ def test_a_match_that_leaves_one_token_probes_a_one_row_chunk(
     matched up to that token (`match(prompt[:-1])`): its only chunk has
     one real column, whose row the probe takes on."""
     doc = _prompt(doc_len, seed=7)
-    srv = _server(models, "latent", True, block_size=4)
+    srv = _server(models, "latent", 4)
     srv.submit(doc + [9], max_new=1)
     srv.run()
     before, saved = srv._chunks, srv.cache_stats()["prefill_tokens_saved"]
@@ -182,8 +186,7 @@ def test_a_match_that_leaves_one_token_probes_a_one_row_chunk(
     assert st["prefill_tokens_saved"] - saved == doc_len
     assert srv._chunks - before == 1
     assert tok0 == _oneshot(models, "latent", prompt)[1]
-    alone = _server(models, "latent", True, block_size=4,
-                    prefix_reuse=False)
+    alone = _server(models, "latent", 4, prefix_reuse=False)
     assert _admit(alone, prompt)[1] == tok0
 
 
@@ -223,7 +226,7 @@ def test_a_row_past_the_last_bucket_goes_through_a_width_one_chunk(models):
     bucket of the ladder fits: `_next_chunk` plans one row at width 1
     (the chunk program, not the probe's), and the first token is still
     the one-shot pick."""
-    srv = _server(models, "kv", True)
+    srv = _server(models, "kv")
     assert srv._next_chunk(SMAX - 1, 1) == (1, 1)
     assert srv._next_chunk(SMAX - 5, 5) == (4, 4)
     prompt = _prompt(SMAX - 1, seed=5)
@@ -237,7 +240,7 @@ def test_one_chunk_program_a_width_and_one_probe(models, monkeypatch):
     ladder width alone and return (scratch, one hidden row); the probe
     is one program, whose operands are the row and the LAST layer's
     entry of the scratch; `_PendingPrefill` holds no token back."""
-    srv = _server(models, "kv", True)
+    srv = _server(models, "kv")
     seen = []
     real = serving._cached_program
     monkeypatch.setattr(serving, "_cached_program",

@@ -13,12 +13,12 @@ the step's program).
   * a request with an `eos_id`, `max_new == 1`, a speculative server
     and `async_dispatch=False` keep the order read-then-dispatch
 
-each still token for token `generate()`'s output, over the dense
-server, the paged one, and the paged one with a window block group and
-experts (the Laguna toy of tests/test_laguna_serving.py); and, against
-the plain reference's own greedy continuation, over the paged server of
-a model with recurrent and latent-attention layers (the Kimi-Linear toy
-of tests/test_kimi_serving.py: `generate()` has no path for it).
+each still token for token `generate()`'s output, over blocks of 8 rows
+and of 4, and with a window block group and experts (the Laguna toy of
+tests/test_laguna_serving.py); and, against the plain reference's own
+greedy continuation, over the server of a model with recurrent and
+latent-attention layers (the Kimi-Linear toy of
+tests/test_kimi_serving.py: `generate()` has no path for it).
 """
 
 import json
@@ -46,8 +46,11 @@ PH, NAME, ARGS = 0, 1, 7
 READS = ("serving.first_token.wait", "serving.flush.wait",
          "serving.flush.moe_stats.wait")
 DISPATCH = "serving.dispatch"
-STEP_PROGS = ("cb_step", "pg_step")     # the decode step's, dense / paged
-MODES = ["dense", "paged", "mixed", "hybrid"]
+STEP_PROG = "pg_step"                   # the decode step's
+# the K/V toy over blocks of 8 rows and of 4 (a request's decode crosses
+# a seam, and `_ensure_block` extends its table, every fourth step),
+# the mixed and the hybrid toys over their own geometry
+MODES = ["paged", "block4", "mixed", "hybrid"]
 
 
 @pytest.fixture(scope="module")
@@ -64,7 +67,7 @@ def models():
                            "chipbench/tests/rehearse_hybrid.json")) as f:
         hconf = harness._merge(hconf, json.load(f)["config"])
     htoy = hybrid_drv.build_cfg(hconf)
-    return {"dense": (CFG, tfm.init_params(CFG, jax.random.PRNGKey(0))),
+    return {"kv": (CFG, tfm.init_params(CFG, jax.random.PRNGKey(0))),
             "mixed": (toy, drv.make_params(toy, 11)),
             "hybrid": (htoy, hybrid_drv.make_params(htoy, 11)),
             "hybrid_conf": hconf}
@@ -84,9 +87,9 @@ def ring():
 
 
 def _server(models, mode, **kw):
-    cfg, params = models[mode if mode in ("mixed", "hybrid") else "dense"]
-    base = {"dense": dict(smax=64),
-            "paged": dict(paged=True, smax=64, block_size=8),
+    cfg, params = models[mode if mode in ("mixed", "hybrid") else "kv"]
+    base = {"paged": dict(smax=64, block_size=8),
+            "block4": dict(smax=64, block_size=4),
             "mixed": dict(paged=True, smax=128, block_size=4,
                           prefill_chunk=8),
             "hybrid": dict(paged=True, smax=64, block_size=4,
@@ -114,7 +117,7 @@ def _generate(models, mode, prompt, max_new, eos_id=None):
             out.append(int(np.asarray(lg)[0, len(seq) - 1].argmax()))
             seq.append(out[-1])
         return out
-    cfg, params = models["mixed" if mode == "mixed" else "dense"]
+    cfg, params = models["mixed" if mode == "mixed" else "kv"]
     out = tfm.generate(params, cfg, jnp.asarray([prompt], jnp.int32),
                        max_new=max_new, eos_id=eos_id)
     return [int(t) for t in np.asarray(out)[0]]
@@ -134,7 +137,7 @@ def _names(events):
     `prog` is the decode step's (every other program's span: an
     admission's chunks, probe and splice, is left out)."""
     return [n for n, a in events if n in READS
-            or (n == DISPATCH and a.get("prog") in STEP_PROGS)]
+            or (n == DISPATCH and a.get("prog") == STEP_PROG)]
 
 
 def _behind(events, name):

@@ -67,7 +67,7 @@ def _serve(params, reqs=REQS, fi_kw=None, **srv_kw):
 
 # -- kill-mid-decode ---------------------------------------------------------
 
-def test_kill_mid_decode_dense_identical(params):
+def test_kill_mid_decode_default_block_identical(params):
     base, _ = _serve(params)
     got, srv = _serve(params, fi_kw=dict(
         schedule={"decode": {2, 5, 9}}))
@@ -201,19 +201,26 @@ def test_mixed_sites_identical(params):
 
 # -- recovery with a step in flight (reads lag one step) ---------------------
 
-@pytest.mark.parametrize("paged", [False, True])
+# blocks of 4 rows and of 2: a checkpoint's pins are the full blocks
+# below the host's frontier and its restore takes the frontier block
+# over from the live table, so at 2 rows every other capture lands ON a
+# seam (no frontier block to take over) and a restore spans many pins
+BLOCKS = pytest.mark.parametrize("block_size", [4, 2],
+                                 ids=["block4", "block2"])
+
+
+@BLOCKS
 @pytest.mark.parametrize("site", ["decode", "prefill"])
 def test_fault_while_a_step_is_buffered_identical(params, monkeypatch, site,
-                                                  paged):
+                                                  block_size):
     """The decode loop reads one step behind its newest dispatch, so a
     fault finds a step (and, right after an admission, a seed token)
     that the host has not read: `_recover` lands them all, restores
     from checkpoints taken at the host's frontier, and the replay
     emits the fault-free tokens. prefill_chunk=2 keeps a chunked
     prefill pending beside a live decode."""
-    kw = dict(prefill_chunk=2)
-    if paged:
-        kw.update(paged=True, block_size=4, num_blocks=64)
+    kw = dict(prefill_chunk=2, block_size=block_size,
+              num_blocks=256 // block_size)
     base, srv0 = _serve(params, **kw)
     buffered = []
     orig = ContinuousServer._recover
@@ -230,17 +237,16 @@ def test_fault_while_a_step_is_buffered_identical(params, monkeypatch, site,
     assert len(buffered) == 3 and max(buffered) >= 1
     assert srv.fault_stats()["restored_by_site"].get(site, 0) >= 1
     assert srv.failed == {} and srv._ckpt == {}
-    if paged:
-        assert srv._alloc.stats()["free"] == srv0._alloc.stats()["free"]
+    assert srv._alloc.stats()["free"] == srv0._alloc.stats()["free"]
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_checkpoints_advance_under_steady_decode(params, paged):
+@BLOCKS
+def test_checkpoints_advance_under_steady_decode(params, block_size):
     """One request, no retirement: the only reads are the lagged ones
     of a full buffer, each of which leaves a step in flight. The
     checkpoint still advances every ckpt_every tokens, at the frontier
     the host holds, and a fault restores from the newest."""
-    kw = dict(paged=True, block_size=4, num_blocks=64) if paged else {}
+    kw = dict(block_size=block_size, num_blocks=256 // block_size)
     prompt, max_new, every = [3, 1, 4, 1, 5], 30, 4
     want = _ref(params, CFG, prompt, max_new)
     srv = ContinuousServer(params, CFG, slots=1, smax=64, **kw)

@@ -4,15 +4,19 @@ the constant (for the block size: HPX_PAGED_BLOCK, the measured table
 `ops/paged_blocks.json`, then 16; for the chunk width: the device's
 ridge over the weights a chunk reads, 128 on a device of unknown
 ridge). And a live server takes a config write to one of its
-reloadable knobs at its next flush, never mid-step."""
+reloadable knobs at its next flush, never mid-step. And there is ONE
+kind of server: the block pools are its cache, `paged` has one value
+left, and nothing builds a dense server's programs."""
 
 import dataclasses
 
 import jax
+import numpy as np
 import pytest
 
 from hpx_tpu.core import config_schema
-from hpx_tpu.core.config import runtime_config
+from hpx_tpu.core.config import Configuration, runtime_config
+from hpx_tpu.core.errors import UndeclaredConfigKey
 from hpx_tpu.models import serving
 from hpx_tpu.models import transformer as tfm
 from hpx_tpu.models.serving import ContinuousServer
@@ -256,3 +260,69 @@ def test_reload_knobs_each_key(params, moe_params, knobs, key):
     assert got(srv) == applied
     srv.flush()
     assert got(srv) == clamped
+
+
+# -- one cache: `paged` is no choice ---------------------------------------
+
+def test_paged_false_is_refused_and_names_the_oracle(params):
+    """The keyword is accepted for the callers that still pass
+    `paged=True`; its other value names what took the dense server's
+    place as the reference."""
+    with pytest.raises(ValueError, match=r"dense server mode is gone"
+                       r".*generate\(\)"):
+        ContinuousServer(params, CFG, slots=2, smax=64, paged=False)
+    assert ContinuousServer(params, CFG, slots=2, smax=64,
+                            paged=True).block_size == 16
+
+
+def test_a_server_built_with_no_paged_argument_answers_cache_stats(params):
+    from hpx_tpu.svc import performance_counters as pc
+    srv = ContinuousServer(params, CFG, slots=2, smax=64)
+    rid = srv.submit([3, 1, 4, 1, 5], max_new=4)
+    assert len(srv.run()[rid]) == 4
+    st = srv.cache_stats()
+    assert st["num_blocks"] == 2 * 2 * (64 // 16) + 1
+    assert st["prefill_tokens_computed"] == 5
+    assert srv.hbm_read_stats()["block_size_source"] == "default"
+    # and its /cache{...} counters are registered like any server's
+    names = pc.discover_counters(
+        f"/cache{{locality#*/{srv.counter_instance}}}/*")
+    assert any(n.endswith("/blocks/in-use") for n in names)
+
+
+_RUNS = {
+    "plain": dict(),
+    "speculative": dict(spec=True, spec_k=3),
+    "mesh": dict(mesh=(1, 2)),
+}
+
+
+@pytest.mark.parametrize("run", sorted(_RUNS))
+def test_no_run_builds_a_dense_servers_program(params, run):
+    """The step, the splice and the verify of a plain, a speculative and
+    a two-device-mesh run are the pools' programs: no key of the
+    program cache names a dense server's."""
+    kw = dict(_RUNS[run])
+    if "mesh" in kw:
+        kw["mesh"] = jax.sharding.Mesh(
+            np.array(jax.devices()[:2]).reshape(kw["mesh"]), ("dp", "tp"))
+    srv = ContinuousServer(params, CFG, slots=2, smax=64, **kw)
+    rids = [srv.submit(p, max_new=6) for p in ([3, 1, 4], [2, 7, 1, 8, 2])]
+    out = srv.run()
+    assert sorted(out) == rids
+    names = {k[0] for k in tfm._PROGRAMS if isinstance(k, tuple)}
+    assert not {n for n in names
+                if n.startswith(("cb_step", "cb_splice", "cb_verify"))}
+    assert {"pg_step", "pg_splice"} <= names
+    assert ("pg_verify" in names) or run != "speculative"
+
+
+def test_the_mesh_hatch_is_no_config_key():
+    """`hpx.serving.mesh.paged` chose between two sharding rules; with
+    one left it is undeclared, and a strict configuration refuses it."""
+    assert config_schema.lookup("hpx.serving.mesh.paged") is None
+    assert config_schema.lookup(
+        "hpx.serving.mesh.table_residency") is not None
+    with pytest.raises(UndeclaredConfigKey, match="mesh.paged"):
+        Configuration(environ={}, strict=True).set(
+            "hpx.serving.mesh.paged", "0")

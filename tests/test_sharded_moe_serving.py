@@ -1,10 +1,10 @@
 """Expert-parallel MoE decode on the mesh: ContinuousServer with an
 MoE model and mesh=(dp, tp) must emit BYTE-IDENTICAL tokens to the
-single-device MoE server — greedy and sampled, dense and paged, spec
-on and off.  Experts shard over the "tp" axis (no dedicated "ep" axis
-in the default serving mesh); decode routing rides moe_ffn's tiled
-all_to_all with the drop-free auto capacity (cf = n_experts), so
-token identity is exact, not approximate.
+single-device MoE server — greedy and sampled, over blocks of 16 rows
+and of 4, spec on and off.  Experts shard over the "tp" axis (no
+dedicated "ep" axis in the default serving mesh); decode routing rides
+moe_ffn's tiled all_to_all with the drop-free auto capacity (cf =
+n_experts), so token identity is exact, not approximate.
 
 Also pinned here: the /serving{...}/moe/* counters advance from real
 decode stats, the capacity-factor knob re-keys at most the decode
@@ -61,27 +61,34 @@ def _run_both(params, mesh, reqs, **kw):
 
 # -- token identity ----------------------------------------------------------
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_greedy_matches_single_device(params, mesh, paged):
-    kw = dict(paged=True) if paged else {}
+# the default block of 16 rows, and blocks of 4: every dp shard then
+# writes rows of EVERY slot across block seams into its copy of the
+# pools (`_write_rows`), and a verify window spans blocks
+BLOCKS = pytest.mark.parametrize("block_size", [None, 4],
+                                 ids=["paged", "block4"])
+
+
+@BLOCKS
+def test_greedy_matches_single_device(params, mesh, block_size):
+    kw = dict(block_size=block_size)
     outs, outm, srv = _run_both(params, mesh, GREEDY, **kw)
     assert outs == outm
     assert srv._ep_axis == "tp" and srv._ep_size == 2
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_sampled_matches_single_device(params, mesh, paged):
-    kw = dict(paged=True) if paged else {}
+@BLOCKS
+def test_sampled_matches_single_device(params, mesh, block_size):
+    kw = dict(block_size=block_size)
     outs, outm, _ = _run_both(params, mesh, SAMPLED, **kw)
     assert outs == outm
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_spec_matches_single_device(params, mesh, paged):
+@BLOCKS
+def test_spec_matches_single_device(params, mesh, block_size):
     """Speculative decode over expert-parallel MoE: the verify window
     routes every draft position through the same drop-free exchange,
     so accepts match the solo server exactly."""
-    kw = dict(paged=True) if paged else {}
+    kw = dict(block_size=block_size)
     reqs = GREEDY[:3] + SAMPLED[:1]
     outs, outm, srv = _run_both(params, mesh, reqs, spec=True,
                                 spec_k=3, **kw)

@@ -5,9 +5,10 @@ while the block pool shards kv-heads over tp, replicates the block axis
 over dp, and the slot/page-table rows shard over dp (the shard_map
 step: block tables stay per-shard int32, no cross-shard gathers).
 
-Single-device paged == dense == generate() is already pinned by
-test_paged_serving / test_spec_serving, so equality against the solo
-paged server chains all the way back to the solo-generate() contract.
+Single-device server == generate() is already pinned by
+test_paged_serving / test_spec_serving (each request against its solo
+`generate()` run, greedy and sampled), so equality against the solo
+server chains all the way back to the solo-generate() contract.
 """
 
 import jax
@@ -113,7 +114,7 @@ def test_spec_matches_single_device(params, mesh):
 
 def test_spec_draft_model_matches_single_device(params, mesh):
     """Draft-model speculation: the draft shares the serving mesh
-    (dense caches over cache_sh) while the target runs the shard_map
+    (its dense rows: slots over dp, heads over tp) while the target runs the shard_map
     paged path."""
     dcfg = tfm.TransformerConfig(vocab=64, d_model=16, n_heads=2,
                                  head_dim=8, n_layers=1, d_ff=32)

@@ -1,10 +1,10 @@
 """Speculative decoding in ContinuousServer: the draft + window-verify
 path must be BYTE-IDENTICAL to both plain generate() and the
-non-speculative server — dense and paged, greedy and sampled, for every
-draft source — because acceptance compares draft tokens against the
-EXACT token the sequential step would have picked (same `_pick_row`
-contract, same fold_in key schedule). Throughput may vary with draft
-quality; tokens never do.
+non-speculative server — over blocks a verify window fits in and blocks
+it straddles, greedy and sampled, for every draft source — because
+acceptance compares draft tokens against the EXACT token the sequential
+step would have picked (same `_pick_row` contract, same fold_in key
+schedule). Throughput may vary with draft quality; tokens never do.
 
 Also pins the compile story: verify programs ride the prefill bucket
 ladder, so a spec workload builds O(buckets) programs, not O(distinct
@@ -67,12 +67,19 @@ def _serve(params, reqs, *, smax=64, slots=3, **kw):
 
 # -- equivalence sweep -------------------------------------------------------
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
+# the block geometry: the default block of 16 rows holds a whole request
+# here, so a window never leaves it; over blocks of 4 a window of up to
+# 5 tokens crosses a seam, `_ensure_window` extends the table under the
+# drafts and a rejection hands blocks back (`PageTable.rollback`)
+BLOCKS = pytest.mark.parametrize("block_size", [None, 4],
+                                 ids=["paged", "block4"])
+
+@BLOCKS
 @pytest.mark.parametrize("k", [1, 2, 4])
-def test_greedy_matches_nonspec_and_generate(params, paged, k):
-    base, _ = _serve(params, REQS, paged=paged)
-    spec, srv = _serve(params, REQS, paged=paged, spec=True, spec_k=k)
+def test_greedy_matches_nonspec_and_generate(params, block_size, k):
+    base, _ = _serve(params, REQS, block_size=block_size)
+    spec, srv = _serve(params, REQS, block_size=block_size, spec=True,
+                       spec_k=k)
     assert spec == base
     for rid, r in enumerate(REQS):
         assert spec[rid] == _ref(params, CFG, r["prompt"], r["max_new"])
@@ -82,43 +89,40 @@ def test_greedy_matches_nonspec_and_generate(params, paged, k):
     assert st["tokens_per_step"] >= 1.0
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
+@BLOCKS
 @pytest.mark.parametrize("k", [1, 2, 4])
-def test_sampled_matches_nonspec(params, paged, k):
+def test_sampled_matches_nonspec(params, block_size, k):
     """temperature > 0: acceptance still reduces to exact token match
     because `_sample_row` is deterministic given (key, pos, row)."""
-    base, _ = _serve(params, SAMPLED, slots=2, paged=paged)
-    spec, _ = _serve(params, SAMPLED, slots=2, paged=paged,
+    base, _ = _serve(params, SAMPLED, slots=2, block_size=block_size)
+    spec, _ = _serve(params, SAMPLED, slots=2, block_size=block_size,
                      spec=True, spec_k=k)
     assert spec == base
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_eos_inside_window(params, paged):
+@BLOCKS
+def test_eos_inside_window(params, block_size):
     """An eos accepted mid-window must truncate the emission exactly
     where the sequential server would have stopped."""
     probe = _ref(params, CFG, [3, 1, 4], 9)
     eos = probe[3]
     reqs = [dict(prompt=[3, 1, 4], max_new=9, eos_id=eos),
             dict(prompt=[2, 7], max_new=5)]
-    base, _ = _serve(params, reqs, slots=2, paged=paged)
-    spec, _ = _serve(params, reqs, slots=2, paged=paged,
+    base, _ = _serve(params, reqs, slots=2, block_size=block_size)
+    spec, _ = _serve(params, reqs, slots=2, block_size=block_size,
                      spec=True, spec_k=4)
     assert spec == base
     assert spec[0] == _ref(params, CFG, [3, 1, 4], 9, eos_id=eos)
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_rejection_at_first_token(params, draft_params, paged):
+@BLOCKS
+def test_rejection_at_first_token(params, draft_params, block_size):
     """A deliberately bad draft model (random tiny checkpoint): most
     windows reject at the first draft, yet output stays identical and
     every step still lands the sequential token."""
-    base, _ = _serve(params, REQS, paged=paged)
-    spec, srv = _serve(params, REQS, paged=paged, spec=True, spec_k=4,
-                       draft_params=draft_params, draft_cfg=DCFG)
+    base, _ = _serve(params, REQS, block_size=block_size)
+    spec, srv = _serve(params, REQS, block_size=block_size, spec=True,
+                       spec_k=4, draft_params=draft_params, draft_cfg=DCFG)
     assert spec == base
     st = srv.spec_stats()
     assert st["drafted"] > 0
@@ -126,15 +130,15 @@ def test_rejection_at_first_token(params, draft_params, paged):
     assert st["tokens_per_step"] >= 1.0     # but never below sequential
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
+@BLOCKS
 def test_draft_model_vs_prompt_lookup_same_tokens(params, draft_params,
-                                                  paged):
+                                                  block_size):
     """The two draft sources may accept wildly different fractions,
     but both must decode the exact same tokens."""
-    lookup, _ = _serve(params, REQS, paged=paged, spec=True, spec_k=3)
-    model, _ = _serve(params, REQS, paged=paged, spec=True, spec_k=3,
-                      draft_params=draft_params, draft_cfg=DCFG)
+    kw = dict(block_size=block_size, spec=True, spec_k=3)
+    lookup, _ = _serve(params, REQS, **kw)
+    model, _ = _serve(params, REQS, draft_params=draft_params,
+                      draft_cfg=DCFG, **kw)
     assert lookup == model
 
 

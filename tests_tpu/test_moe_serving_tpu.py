@@ -26,9 +26,9 @@ def _provenance(engine, **extra):
 
 
 class TestExpertParallelDecodeOnChip:
-    @pytest.mark.parametrize("paged", [False, True],
-                             ids=["dense", "paged"])
-    def test_mesh_matches_single_device(self, paged):
+    @pytest.mark.parametrize("block_size", [None, 4],
+                             ids=["paged", "block4"])
+    def test_mesh_matches_single_device(self, block_size):
         from hpx_tpu.models import transformer as tfm
         from hpx_tpu.models.serving import ContinuousServer
         if len(jax.devices()) < 4:
@@ -45,7 +45,7 @@ class TestExpertParallelDecodeOnChip:
                 dict(prompt=[5, 6, 7, 8, 9], max_new=12),
                 dict(prompt=[3, 1, 4], max_new=8, temperature=0.9,
                      key=jax.random.PRNGKey(7))]
-        kw = dict(paged=True) if paged else {}
+        kw = dict(block_size=block_size)
         outs = {}
         for name, m in (("single", None), ("mesh", mesh)):
             srv = ContinuousServer(params, cfg, slots=4, smax=64,
@@ -59,4 +59,4 @@ class TestExpertParallelDecodeOnChip:
                 assert srv._moe_dropped == 0     # auto = drop-free
         assert outs["single"] == outs["mesh"]
         _provenance("serving_moe_tpu_identity",
-                    paged=paged, identical=True)
+                    block_size=block_size, identical=True)
