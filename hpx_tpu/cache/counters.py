@@ -128,6 +128,18 @@ read)::
                                                         was refused (no state snapshot)
     /serving{locality#L/server#i}/state/reprefills      restores that recomputed a state
 
+Models with "eva" layers add their two grains of rows (one table a
+slot: its summary blocks, then its window's exact blocks)::
+
+    /cache{locality#L/server#i}/eva/exact-rows          exact rows of the live
+                                                        slots' windows under way
+    /cache{locality#L/server#i}/eva/summary-rows        summary rows the live slots'
+                                                        tables make visible
+    /cache{locality#L/server#i}/eva/rolls               windows completed, in prefill
+                                                        and in decode (cumulative)
+    /cache{locality#L/server#i}/eva/blocks-freed        exact blocks the rolls gave
+                                                        back, a window's at once
+
 Models with window layers add their second block group::
 
     /cache{locality#L/server#i}/window/blocks-in-use    window-group blocks held
@@ -410,6 +422,14 @@ def register_server(srv) -> str:
         put("serving", "sparse/rows-live",
             pc.CallbackCounter(_read(
                 ref, lambda s: s._sparse_rows_live)))
+    if "eva" in getattr(srv.cfg, "layer_mixer", ()):
+        # rows at two grains in one run a slot, and the rolls' clocks
+        for name, key in (("exact-rows", "eva_exact_rows"),
+                          ("summary-rows", "eva_summary_rows"),
+                          ("rolls", "eva_rolls"),
+                          ("blocks-freed", "eva_blocks_freed")):
+            put("cache", "eva/" + name, pc.CallbackCounter(_read(
+                ref, lambda s, key=key: s.cache_stats()[key])))
     if srv._tier is not None:
         # host-RAM demotion tier (cache/tier.py): occupancy,
         # demote/promote/drop/decline totals, cumulative hit

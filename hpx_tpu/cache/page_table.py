@@ -22,8 +22,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-__all__ = ["PageTable", "WindowTable", "device_table", "materialize",
-           "occupancy"]
+__all__ = ["PageTable", "TwoGrainTable", "WindowTable", "device_table",
+           "materialize", "occupancy"]
 
 _UIDS = itertools.count()
 
@@ -87,6 +87,10 @@ class PageTable:
     def blocks_for(self, tokens: int) -> int:
         """Blocks needed to cover `tokens` rows."""
         return -(-tokens // self.block_size)
+
+    def held(self, rows: int) -> int:
+        """Blocks of a request that has consumed `rows` positions."""
+        return self.blocks_for(rows)
 
     def block_of(self, pos: int) -> int:
         """Physical block id backing logical position `pos`."""
@@ -156,6 +160,94 @@ class WindowTable(PageTable):
         row = np.full((max_blocks,), pad, np.int32)
         row[self.base:self.base + len(self.blocks)] = self.blocks
         return row
+
+
+class TwoGrainTable(PageTable):
+    """Block map of one request on layers that keep rows at TWO grains
+    (ops/eva.py): the exact rows of the aligned window of `window`
+    positions the request is in, and one SUMMARY row every `chunk`
+    positions of every window behind it. `blocks` is ONE run: the
+    first `summary` blocks hold the summaries of the complete windows,
+    `per` blocks a window, the rest the window's exact rows in order.
+    A window's window / chunk summaries fill whole blocks (`per` is a
+    whole number), so logical row `row_of(pos)` of the run is gap-free
+    and the programs read this table like any other (`as_row`).
+
+    Two clocks: the exact run grows a block every `block_size`
+    positions and goes back ALL AT ONCE when the window completes
+    (`roll`); the summary run grows `per` blocks a window and is freed
+    at retirement only. The step that writes a window's last row
+    holds the window's `per` summary blocks behind the exact run
+    (`blocks_at`), where no live row lies yet; `roll` moves them up."""
+
+    def __init__(self, block_size: int, window: int, chunk: int) -> None:
+        super().__init__(block_size)
+        if window % chunk or (window // chunk) % block_size:
+            raise ValueError(
+                f"a window of {window} rows pooled every {chunk} gives "
+                f"{window / chunk:g} summaries, no whole number of "
+                f"blocks of {block_size}")
+        self.window, self.chunk = int(window), int(chunk)
+        self.per = window // chunk // block_size
+        self.summary = 0           # leading blocks that hold summaries
+
+    def row_of(self, pos: int) -> int:
+        """The logical row of position `pos` in the run."""
+        return (pos // self.window) * self.per * self.block_size \
+            + pos % self.window
+
+    def blocks_at(self, pos: int) -> int:
+        """Blocks the run needs for a WRITE at `pos`: the summaries
+        behind pos's window, the window's blocks up to pos's and, where
+        pos is the window's last row, the blocks its summaries take."""
+        n = self.row_of(pos) // self.block_size + 1
+        return n + (self.per if (pos + 1) % self.window == 0 else 0)
+
+    def roll(self) -> List[int]:
+        """The window is complete and its summaries lie in the last
+        `per` blocks: they join the summary run, and the exact run's
+        blocks come back for the caller to free."""
+        freed = self.blocks[self.summary:-self.per]
+        self.blocks = self.blocks[:self.summary] + self.blocks[-self.per:]
+        self.summary += self.per
+        self.version += 1
+        return freed
+
+    def held(self, rows: int) -> int:
+        """Blocks of a request that has consumed `rows` positions and
+        rolled every window it completed: `per` a complete window and
+        the exact rows of the window under way."""
+        return rows // self.window * self.per \
+            + self.blocks_for(rows % self.window)
+
+    def adopt(self, rows: int) -> None:
+        """Lay the run out for a request that has consumed `rows`
+        positions, over the `held(rows)` blocks the table lists."""
+        self.summary = rows // self.window * self.per
+        self.tokens = rows
+        self.version += 1
+
+    def write_rows(self, summary_width: int, pad: int):
+        """(summary blocks padded to `summary_width`, the window's
+        exact blocks padded to window / block_size): the write rows of
+        the prefill splice, whose scratch keeps the two grains apart."""
+        srow = np.full((summary_width,), pad, np.int32)
+        srow[:self.summary] = self.blocks[:self.summary]
+        erow = np.full((self.window // self.block_size,), pad, np.int32)
+        exact = self.blocks[self.summary:]
+        erow[:len(exact)] = exact
+        return srow, erow
+
+    @staticmethod
+    def max_blocks(block_size: int, window: int, chunk: int,
+                   smax: int) -> int:
+        """The most blocks a request of up to `smax` positions holds at
+        once: at its last position, or on the step that completes its
+        last whole window."""
+        t = TwoGrainTable(block_size, window, chunk)
+        last_whole = smax // window * window - 1
+        return max(t.blocks_at(smax - 1),
+                   t.blocks_at(last_whole) if last_whole > 0 else 0)
 
 
 def occupancy(tables: Sequence[Optional[PageTable]]) -> int:

@@ -98,7 +98,8 @@ import numpy as np
 
 from ..cache.block_allocator import BlockAllocator, CacheOOM, block_bytes
 from ..cache.ngram import propose as _ngram_propose
-from ..cache.page_table import (PageTable, WindowTable, materialize,
+from ..cache.page_table import (PageTable, TwoGrainTable, WindowTable,
+                                materialize,
                                 occupancy)
 from ..cache.radix import RadixCache
 from ..core.errors import Error, HpxError
@@ -127,6 +128,7 @@ from .transformer import (
     _embed,
     _layer,
     _logits,
+    _next_logits,
     _pick_row,
     _tree_key,
     _window_tail,
@@ -507,7 +509,10 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
     (K pool, V pool, index pool [num_blocks, block_size / stride * Nkv,
     H] float32, the last step's chosen block ids [B, Nkv, K] and their
     count [B, Nkv]) on the full group's table
-    (ops/sparse_attention.paged_sparse_decode)."""
+    (ops/sparse_attention.paged_sparse_decode); an "eva" layer's (K
+    pool, V pool) whose table lists a slot's summary blocks, then its
+    window's exact blocks: ONE gap-free run of rows, in which position
+    p is row `ops/eva.eva_row(p)` and every row up to it is attended."""
     w = x.shape[1]
     posw = pos0[:, None] + jnp.arange(w)[None, :]
     kw = {"fused": fused, "window": cfg.window(li)}
@@ -541,6 +546,22 @@ def _paged_window_rows(x, lp, pools, scales, table, pos0,
             o, carry = mamba_mix(u, lp["mamba"], *pools,
                                  eps=cfg.norm_eps)
             return o, (carry, None)
+    elif "eva" in lp:
+        from ..ops.eva import eva_row
+
+        def attend(q, k, v):                                # noqa: F811
+            if w != 1:
+                raise NotImplementedError(
+                    "a W-token window over a two-grain table (a column "
+                    "behind a window boundary needs summaries the step "
+                    "has not pooled: ops/eva.py, models/serving.py "
+                    "_eva_roll)")
+            # the walk that serves K/V pairs, at the position's row in
+            # the slot's one run [summaries | window]
+            row = eva_row(pos0, cfg.eva_chunk, cfg.eva_window)
+            out = paged_decode_attention(q, k[:, 0], v[:, 0], *pools,
+                                         table, row, fused=fused)
+            return out[0], (out[1:3], None)
     elif "sparse" in lp:
         from ..ops.sparse_attention import paged_sparse_decode
 
@@ -618,8 +639,17 @@ def _scratch_entry(cfg: TransformerConfig, smax: int, i: int):
     recurrent layer's state of no tokens, zeros: (state [1, H, d, d]
     float32, conv tail [1, K - 1, 3 H d]), "lightning" (state,), or
     "mamba" (state [1, N, C] float32: the channels on the minor axis;
-    conv tail [1, (K - 1) C]: its rows side by side; ops/mamba.py)."""
+    conv tail [1, (K - 1) C]: its rows side by side; ops/mamba.py); or
+    an "eva" layer's two grains apart (exact K, exact V [1, eva_window,
+    H, hd]: ONE window's rows as a ring; summary K, summary V [1,
+    summaries of the windows that can complete, H, hd]; ops/eva.py):
+    (eva_window + smax / eva_chunk) rows a layer where a K/V scratch
+    holds smax."""
     kind = cfg.mixer(i)
+    if kind == "eva":
+        from ..ops.eva import scratch_entry
+        return scratch_entry(smax, cfg.n_heads, cfg.head_dim,
+                             cfg.eva_chunk, cfg.eva_window, cfg.dtype)
     if kind == "mla":
         return (jnp.zeros((1, smax, 1, cfg.mla_row), cfg.dtype),)
     if kind == "kda":
@@ -905,12 +935,18 @@ class ContinuousServer:
         # pools; all live in the cache pytree `_init_paged` builds
         self._recurrent = cfg.recurrent
         self._kinds = kinds = sorted(set(cfg.layer_mixer) - {"attn"})
+        # an "eva" layer keeps K/V rows at two grains (ops/eva.py): one
+        # table a slot lists its summary blocks, then its window's
+        # (cache/page_table.TwoGrainTable)
+        self._eva = "eva" in kinds
+        # no snapshot can rewind these: a restore recomputes
+        self._recomputes = self._recurrent or self._eva
         if kinds and mesh is not None:
             raise NotImplementedError(
-                "a (dp, tp) mesh: the state, the latent pool and the "
-                f"index pool have no placement; a model with {kinds} "
-                "mixers runs on ContinuousServer(paged=True) on one "
-                "device (models/serving.py _init_paged)")
+                "a (dp, tp) mesh: the state, the latent pool, the index "
+                "pool and a two-grain table's roll have no placement; a "
+                f"model with {kinds} mixers runs on ContinuousServer on "
+                "one device (models/serving.py _init_paged)")
         self._ep_axis, self._ep_size = None, 1
         if mesh is not None:
             # sharded serving: slots over dp, heads over tp, the decode
@@ -986,10 +1022,12 @@ class ContinuousServer:
                 "speculative verify on a model with "
                 f"{kinds} mixers: a rejected draft needs the "
                 "recurrent state (and a sparse layer's index entry) "
-                "rolled back, and the latent and the sparse walk attend "
-                "one row a slot (models/serving.py _spec_step, "
+                "rolled back, the latent and the sparse walk attend "
+                "one row a slot, and a verify window over a two-grain "
+                "table would need its exact rows and a summary published "
+                "too early taken back (models/serving.py _spec_step, "
                 "ops/kda.py, ops/lightning.py, ops/mamba.py, "
-                "ops/sparse_attention.py, "
+                "ops/sparse_attention.py, ops/eva.py, "
                 "ops/paged_attention.paged_latent_attention)")
         if self._spec and self._win:
             raise NotImplementedError(
@@ -1252,6 +1290,20 @@ class ContinuousServer:
                 f"{-(-smax // bs) * bs})")
         self.block_size = bs
         self._maxb = smax // bs     # table width: blocks per sequence
+        if self._eva:
+            # one run a slot: its summary blocks, then its window's
+            chunk, win = cfg.eva_chunk, cfg.eva_window
+            if win % chunk or (win // chunk) % bs \
+                    or self.prefill_buckets[-1] > win - chunk:
+                raise NotImplementedError(
+                    f"eva layers of window {win} pooled every {chunk} "
+                    f"under block_size {bs} and prefill chunks of up to "
+                    f"{self.prefill_buckets[-1]} rows: a window's "
+                    "summaries must fill whole blocks (one gap-free run "
+                    "of rows, cache/page_table.TwoGrainTable) and a chunk "
+                    "must not wrap the scratch's ring onto the rows it "
+                    "pools (ops/eva.eva_window_attend)")
+            self._maxb = TwoGrainTable.max_blocks(bs, win, chunk, smax)
         if num_blocks is None:
             v = rc.get("hpx.cache.num_blocks", "auto")
             num_blocks = None if v in (None, "", "auto") else int(v)
@@ -1259,9 +1311,9 @@ class ContinuousServer:
             # worst-case live demand (every slot at smax) + the trash
             # block + equal headroom for radix retention, so prefix
             # chains persist before OOM-eviction starts recycling them
-            # (none on a recurrent model: prefix reuse is refused
-            # there, the headroom would hold nothing)
-            num_blocks = (1 if self._recurrent else 2) \
+            # (none on a model that refuses prefix reuse: the headroom
+            # would hold nothing)
+            num_blocks = (1 if self._recomputes else 2) \
                 * slots * self._maxb + 1
         if num_blocks < self._maxb + 1:
             raise ValueError(
@@ -1280,24 +1332,36 @@ class ContinuousServer:
         # the WINDOW block group: layers that see the last `window`
         # rows keep only those. A request's map there is a ring of
         # ceil(window / bs) + 2 columns whose blocks behind the window
-        # go back to this group's own allocator as decode advances;
+        # go back to this group's own allocator a block at a time as
+        # decode advances (a SLIDING window; an "eva" layer's ALIGNED
+        # window gives all its blocks back at once, to the full group's
+        # allocator: `_eva_roll`);
         # its pools hold slots x ring blocks, not slots x smax rows,
         # plus what a slot's checkpoint pins behind its window (the
         # tokens between two captures and in flight) and a trash block.
         self._ring = self._walloc = self._wtrash = None
         self._win_freed = self._prefix_refused = 0
         self._state_resets = self._reprefills = 0
+        # a two-grain table's clocks, from the host's positions alone:
+        # windows completed (in decode and in prefill), the exact
+        # blocks they gave back, and what the decode steps' walks read
+        # over what they had behind them (`_eva_account`)
+        self._eva_rolls = self._eva_freed = self._eva_pooled = 0
+        self._eva_attended = self._eva_behind = 0
         for what, on in (("a quantized hpx.cache.kv_dtype (a quantized "
-                          "latent row, or a sparse layer's quantized "
-                          "page, has no write or kernel)",
+                          "latent row, a sparse layer's quantized page "
+                          "or a summary pooled from quantized rows has "
+                          "no write or kernel)",
                           self._kv_dtype != "bf16"),
-                         ("the host tier (it demotes K/V pairs)",
+                         ("the host tier (it demotes K/V pairs of a "
+                          "prefix; a two-grain table shares none)",
                           rc.get_bool("hpx.cache.tier.enable", False))):
             if self._kinds and on:
                 raise NotImplementedError(
                     f"{what} on a model with {self._kinds} mixers "
                     "(models/serving.py _init_paged, ops/paged_attention"
-                    ".paged_latent_attention, ops/sparse_attention.py)")
+                    ".paged_latent_attention, ops/sparse_attention.py, "
+                    "ops/eva.py)")
         if self._win:
             for what, on in (("a (dp, tp) mesh", self.mesh is not None),
                              ("a quantized hpx.cache.kv_dtype",
@@ -1385,10 +1449,13 @@ class ContinuousServer:
             """Layer i's part of the cache pytree, by its mixer's kind:
             two K/V pools; ONE pool of latent rows on the full group's
             table; the per-slot recurrent state (and conv tail; no
-            blocks, no positions: `_fresh_scratch` is a slot's row); or
+            blocks, no positions: `_fresh_scratch` is a slot's row);
             two K/V pools, the INDEX pool of compressed keys on the
             same table (one float32 entry every `sparse_stride` rows
-            and kv head) and the last step's selection."""
+            and kv head) and the last step's selection; or an "eva"
+            layer's two K/V pools whose blocks hold rows of EITHER
+            grain (a window's exact rows, or summaries, never both in
+            one block): which, the slot's TwoGrainTable says."""
             kind = cfg.mixer(i)
             if kind == "mla":
                 return (jnp.zeros((num_blocks, 1, bs, cfg.mla_row),
@@ -1407,6 +1474,10 @@ class ContinuousServer:
             return (wzeros(), wzeros()) if cfg.window(i) \
                 else (pzeros(), pzeros())
         self._pools = [entry(i) for i in range(cfg.n_layers)]
+        # what a roll pools with: (phi, mu) of each "eva" layer
+        self._eva_params = self._eva and [
+            (lp["eva"]["phi"], lp["eva"]["mu"]) if "eva" in lp else None
+            for lp in self.params["layers"]]
         self._state_bytes = sum(
             a.nbytes for i, e in enumerate(self._pools)
             if cfg.mixer(i) in RECURRENT_KINDS for a in e)
@@ -1521,8 +1592,8 @@ class ContinuousServer:
             def probe(params, row, kv, pos, cur, temp, keys, slot,
                       temperature, key):
                 kv, lg = _window_tail(params, row, kv, pos, cfg)
-                out = _seed_lane(lg[0], cur, temp, keys, slot,
-                                 temperature, key, pos)
+                out = _seed_lane(_next_logits(lg[0], cfg), cur, temp,
+                                 keys, slot, temperature, key, pos)
                 if lane_sh is not None:
                     # the placement the step hands its vector back in
                     out = tuple(jax.lax.with_sharding_constraint(v, sh)
@@ -1603,7 +1674,8 @@ class ContinuousServer:
                 pools, scales, logits, ms = _paged_decode_rows(
                     params, pools, scales, tok, tables, pos, cfg,
                     fused, tp_axis, moe_cf, moe_ep, dp)
-                nxt = jax.vmap(_pick_row)(logits, keys, temp, pos)
+                nxt = jax.vmap(_pick_row)(_next_logits(logits, cfg), keys,
+                                          temp, pos)
                 if ms is not None and tp_axis is not None:
                     # fold the per-dp-group stats into one replicated
                     # vector: routed/dropped claims sum over groups,
@@ -1706,7 +1778,11 @@ class ContinuousServer:
         scatter_seq_blocks); int8 splices quantize whole blocks here
         (scatter_seq_blocks_q). `slot`: where a recurrent layer's
         scratch state lands (it has no blocks). A sparse layer's index
-        pool takes the means of the scratch's K rows by the same row."""
+        pool takes the means of the scratch's K rows by the same row.
+        An "eva" layer's scratch keeps its two grains apart and `wrows`
+        is (summary blocks, window blocks) of the slot's one table
+        (`TwoGrainTable.write_rows`): the summaries of the complete
+        windows and the ring's rows, each by its own row."""
         cfg = self.cfg
         nb, bs = self._alloc.num_blocks, self.block_size
         maxb = self._maxb
@@ -1721,7 +1797,15 @@ class ContinuousServer:
                 outp, outs = [], []
                 for i, (pl, sc) in enumerate(zip(pools, one)):
                     wrow = wrows[1 if cfg.window(i) else 0]
-                    if cfg.mixer(i) in RECURRENT_KINDS:
+                    if cfg.mixer(i) == "eva":
+                        (kp, vp), (ke, ve, ks, vs) = pl, sc
+                        for row, (k1, v1) in zip(
+                                wrows, ((ks, vs), (ke, ve))):
+                            kp, vp = (scatter_seq_blocks(
+                                p, row, c[0].reshape(-1, bs, *c.shape[2:]))
+                                for p, c in ((kp, k1), (vp, v1)))
+                        outp.append((kp, vp))
+                    elif cfg.mixer(i) in RECURRENT_KINDS:
                         # the slot's row of the state (and the tail),
                         # whole: nothing of the last occupant survives
                         outp.append(tuple(
@@ -1980,6 +2064,14 @@ class ContinuousServer:
         must not depend on the policy staying that way)."""
         pt = self._tables[slot]
         assert pt is not None
+        if self._eva:
+            # the write's row in the slot's one run; the step that
+            # completes a window also holds the blocks its summaries
+            # take (`_eva_roll`, once that step is dispatched). Nothing
+            # is shared, so nothing to fork.
+            while len(pt.blocks) < pt.blocks_at(pos):
+                pt.append_block(self._alloc_block())
+            return
         while pt.capacity <= pos:
             pt.append_block(self._alloc_block())
         self._cow_guard(pt, pos // self.block_size)
@@ -1998,6 +2090,58 @@ class ContinuousServer:
                     self._win_freed += len(freed)
                     for bid in freed:
                         self._walloc.decref(bid)
+
+    def _eva_roll_prog(self):
+        """A window's roll on the device: the exact rows the window
+        left in one slot's blocks are pooled into its summaries, a
+        layer at a time (`ops/eva.eva_roll_blocks`), which fill the
+        blocks held for them. The decode path's pooling: ONE program,
+        `jit_roll`, about every `eva_window` / live slots steps."""
+        cfg = self.cfg
+        ck = ("pg_roll", cfg, self._alloc.num_blocks, self.block_size,
+              _tree_key(self.params))
+
+        def build():
+            from ..ops.eva import eva_roll_blocks
+
+            def roll(pools, pv, exact, fresh):
+                return [pl if m is None else eva_roll_blocks(
+                    *pl, exact, fresh, *m, cfg.eva_chunk)
+                    for pl, m in zip(pools, pv)]
+            return jax.jit(roll, donate_argnums=(0,))
+        return self._program(ck, build)
+
+    def _eva_roll(self, slot: int) -> None:
+        """The step just dispatched wrote the last row of `slot`'s
+        window: pool the window into its summaries (enqueued behind
+        that step), publish them at the head of the slot's run and
+        give ALL the window's blocks back at once. Every block was
+        held before the step went out (`_ensure_block`): nothing here
+        can run out."""
+        pt, req = self._tables[slot], self._slot_req[slot]
+        win = self.cfg.eva_window
+        with tracing.span("serving.window_roll", "serving", rid=req.rid,
+                          slot=slot, window=self._pos[slot] // win - 1,
+                          blocks=win // self.block_size,
+                          rows=win // self.cfg.eva_chunk):
+            exact = pt.blocks[pt.summary:-pt.per]
+            self._pools = self._eva_roll_prog()(
+                self._pools, self._eva_params,
+                np.asarray(exact, np.int32),
+                np.asarray(pt.blocks[-pt.per:], np.int32))
+            for bid in pt.roll():
+                self._alloc.decref(bid)
+            self._eva_rolls += 1
+            self._eva_freed += len(exact)
+            self._eva_pooled += win // self.cfg.eva_chunk
+
+    def _eva_account(self, pos: np.ndarray) -> None:
+        """What one decode step's two-grain walks read, a layer, from
+        the live slots' positions ALONE: a query at p attends the
+        summaries of the windows behind its own and its window's rows
+        up to itself, where plain attention reads p + 1 rows."""
+        self._eva_attended += int((self._walk_row(pos) + 1).sum())
+        self._eva_behind += int((pos + 1).sum())
 
     def _ensure_window(self, slot: int, pos0: int, last: int) -> None:
         """`_ensure_block` generalized to a speculative verify window:
@@ -2045,7 +2189,7 @@ class ContinuousServer:
             return
         self._free_window(self._wtables[slot])
         self._wtables[slot] = None
-        if self._prefix_reuse and not (self._win or self._recurrent):
+        if self._prefix_reuse and not (self._win or self._recomputes):
             nfull = len(req.prompt) // self.block_size
             if nfull:
                 self._radix.insert(
@@ -2205,6 +2349,25 @@ class ContinuousServer:
             st["sparse_blocks_selected"] = self._sparse_blocks
             st["sparse_rows_walked"] = self._sparse_rows_walked
             st["sparse_rows_live"] = self._sparse_rows_live
+        if self._eva:
+            # the fifth kind of cached entry: rows at two grains in
+            # one run a slot; the live slots' rows, from their
+            # positions and tables alone
+            live = [s_ for s_ in range(self.slots)
+                    if self._slot_req[s_] is not None]
+            st["eva_summary_rows"] = self.block_size * sum(
+                self._tables[s_].summary for s_ in live)
+            st["eva_exact_rows"] = sum(
+                self._pos[s_] % self.cfg.eva_window for s_ in live)
+            st["eva_rolls"] = self._eva_rolls
+            # summaries written, in prefill (a chunk as it fills) and
+            # in decode (a window's at its roll), from positions alone
+            st["eva_chunks_pooled"] = self._eva_pooled
+            st["eva_blocks_freed"] = self._eva_freed
+            st["eva_rows_attended"] = self._eva_attended
+            st["eva_tokens_behind"] = self._eva_behind
+            st["eva_prefix_refused"] = self._prefix_refused
+            st["eva_reprefills"] = self._reprefills
         if "mla" in self._kinds:
             st["latent_blocks_in_use"] = self._alloc.in_use
             # what a step's latent walks read: live rows of the live
@@ -2250,7 +2413,7 @@ class ContinuousServer:
         nkv = cfg.kv_heads // (
             self.mesh.shape["tp"] if self.mesh is not None else 1)
         full = [i for i in range(cfg.n_layers)
-                if cfg.mixer(i) == "attn" and not cfg.window(i)]
+                if cfg.mixer(i) in ("attn", "eva") and not cfg.window(i)]
         if (self._paged_kernel != "fused" or self._kv_dtype != "bf16"
                 or cfg.head_dim % 128 or not full):
             return 0, nkv
@@ -2258,6 +2421,14 @@ class ContinuousServer:
         return walk_heads_per_copy(
             nkv, self._maxb * self.block_size, cfg.head_dim,
             cfg.heads(full[0]) // cfg.kv_heads, item, item), nkv
+
+    def _walk_row(self, pos):
+        """The row of the slot's table the walk of a step at `pos`
+        ends at: `pos`, or its row in a two-grain run."""
+        if not self._eva:
+            return pos
+        from ..ops.eva import eva_row
+        return eva_row(pos, self.cfg.eva_chunk, self.cfg.eva_window)
 
     def hbm_read_stats(self) -> Dict[str, Any]:
         """Modeled decode-attention HBM read cost per generated token,
@@ -2291,12 +2462,12 @@ class ContinuousServer:
         bb = block_bytes(self.block_size, self.cfg.kv_heads,
                          self.cfg.head_dim, self._kv_acct_dtype(),
                          layers=kinds.count("attn")
-                         + kinds.count("sparse"))
+                         + kinds.count("sparse") + kinds.count("eva"))
         # a latent layer's block is one pool's rows (a recurrent
         # layer has none)
         bb += kinds.count("mla") * self.block_size * self.cfg.mla_row \
             * jnp.dtype(self.cfg.dtype).itemsize
-        walks = [min(p // self.block_size + 1, self._maxb)
+        walks = [min(self._walk_row(p) // self.block_size + 1, self._maxb)
                  for p in self.live_positions().values()]
         walk = sum(walks) / len(walks) if walks else 0.0
         hg, nkv = self._walk_group()
@@ -2626,14 +2797,15 @@ class ContinuousServer:
         plen = len(req.prompt)
         matched, mbids, tier_ext = 0, [], []
         sparse = "sparse" in self._kinds
-        if self._prefix_reuse and (self._win or self._recurrent
+        if self._prefix_reuse and (self._win or self._recomputes
                                    or sparse):
             # a prefix hit would hand the full layers their rows and
             # leave the window layers without the matched prefix's last
             # window, a recurrent layer without its state at the
             # block's boundary, a sparse layer's scratch without the
-            # rows its index is made of: refused (and counted) until
-            # the tree keeps them
+            # rows its index is made of, an eva layer with blocks whose
+            # grain depends on where the request's window ends: refused
+            # (and counted) until the tree keeps them
             self._prefix_refused += 1
         elif self._prefix_reuse:
             # always leave >= 1 suffix token: admission needs the LAST
@@ -2655,10 +2827,12 @@ class ContinuousServer:
             # declined one re-prefills with entries left in the tier
             matched += self._promote_tier(req, matched, mbids,
                                           tier_ext)
-        pt = PageTable(self.block_size)
+        pt = TwoGrainTable(self.block_size, self.cfg.eva_window,
+                           self.cfg.eva_chunk) if self._eva \
+            else PageTable(self.block_size)
         pt.extend_blocks(mbids)
         try:
-            while pt.capacity < plen:
+            while len(pt.blocks) < pt.held(plen):
                 pt.append_block(self._alloc_block())
         except CacheOOM:
             for bid in pt.blocks:
@@ -2674,6 +2848,9 @@ class ContinuousServer:
         wnp = trow.copy()
         wnp[:matched // self.block_size] = self._trash
         wrow = (wnp,)
+        if self._eva:
+            pt.adopt(plen)
+            wrow = self._eva_wrows(pt)
         # an empty scratch, but where the gather brings the match
         caches, wt = None, None
         if self._recurrent:
@@ -2685,7 +2862,7 @@ class ContinuousServer:
                               rid=req.rid, slot=slot, layers=n_rec):
                 caches = self._fresh_scratch()
                 self._state_resets += 1
-        elif sparse:
+        elif sparse or self._eva:
             pass        # the gather's program reads K/V pairs alone
         elif not self._win:
             # the matched rows, out of the shared blocks into the
@@ -2717,6 +2894,16 @@ class ContinuousServer:
         self._admit_defers.pop(req.rid, None)   # admitted: ladder done
         return p
 
+    def _eva_wrows(self, pt: TwoGrainTable):
+        """The splice's write rows of a two-grain table: (summary
+        blocks, window blocks), each padded with the trash block to its
+        part of the scratch."""
+        from ..ops.eva import summary_rows
+        return pt.write_rows(
+            summary_rows(self.smax, self.cfg.eva_chunk,
+                         self.cfg.eva_window) // self.block_size,
+            self._trash)
+
     def _advance_chunk(self, p: _PendingPrefill) -> None:
         """Run ONE bucketed chunk of p's prompt into its scratch.
 
@@ -2737,6 +2924,21 @@ class ContinuousServer:
                 p.flow = None
             p.caches, p.row = self._run_chunk(p.caches, req.prompt,
                                               p.done, n, width)
+            if self._eva and (p.done + n) // self.cfg.eva_window \
+                    > p.done // self.cfg.eva_window:
+                # the chunk completed a window in the scratch: its
+                # summaries are pooled and seen by the rows behind the
+                # boundary (no block is held yet: none to free)
+                with tracing.span(
+                        "serving.window_roll", "serving", rid=req.rid,
+                        slot=p.slot, window=p.done // self.cfg.eva_window,
+                        blocks=0, rows=self.cfg.eva_window
+                        // self.cfg.eva_chunk):
+                    self._eva_rolls += 1
+            if self._eva:
+                # the chunks of `eva_chunk` rows this one completed
+                self._eva_pooled += (p.done + n) // self.cfg.eva_chunk \
+                    - p.done // self.cfg.eva_chunk
             p.done += n
             self._chunks += 1
             self._chunk_rows += n
@@ -3172,7 +3374,7 @@ class ContinuousServer:
         req = self._slot_req[slot]
         pos = len(req.prompt) + len(req.tokens) - 1
         pins: List[int] = []
-        if not self._recurrent:
+        if not self._recomputes:
             pt = self._tables[slot]
             pins = list(pt.blocks[:pos // self.block_size])
             for bid in pins:
@@ -3230,7 +3432,7 @@ class ContinuousServer:
         outputs match the fault-free run."""
         ck = self._ckpt[slot]
         req = self._slot_req[slot]
-        if self._recurrent:
+        if self._recomputes:
             return self._restore_recurrent(slot, req)
         with tracing.span("serving.restore", "serving", rid=req.rid,
                           slot=slot, pos=ck.pos,
@@ -3282,7 +3484,10 @@ class ContinuousServer:
         prompt ++ tokens[:-1] are recomputed into a fresh scratch and
         spliced over the slot's row and blocks, and the slot goes on
         from the host's frontier with nothing to replay. One path for
-        every recurrent kind (`transformer.RECURRENT_KINDS`)."""
+        every recurrent kind (`transformer.RECURRENT_KINDS`) and for a
+        two-grain table, whose rolls since cannot be taken back: its
+        run is laid out anew for the host's frontier (more blocks than
+        it holds now where the frontier lies ahead of a roll)."""
         seq = req.prompt + req.tokens[:-1]
         with tracing.span("serving.reprefill", "serving", rid=req.rid,
                           slot=slot, tokens=len(seq)):
@@ -3290,7 +3495,16 @@ class ContinuousServer:
             self._pos[slot] = len(seq)
             self._cur[slot] = req.tokens[-1]
             pt = self._tables[slot]
-            wrow = (pt.as_row(self._maxb, self._trash),)
+            if self._eva:
+                while len(pt.blocks) < pt.held(len(seq)):
+                    pt.append_block(self._alloc_block())
+                for bid in pt.rollback(pt.held(len(seq))
+                                       * self.block_size):
+                    self._alloc.decref(bid)
+                pt.adopt(len(seq))
+                wrow = self._eva_wrows(pt)
+            else:
+                wrow = (pt.as_row(self._maxb, self._trash),)
             self._pools, self._scales = self._paged_splice_prog()(
                 self._pools, self._scales, self._reprefill(seq), wrow,
                 np.int32(slot))
@@ -3746,6 +3960,8 @@ class ContinuousServer:
                 self._moe_buf.append(ms)
             if "sparse" in self._kinds:
                 self._sparse_account(pos[live])
+            if self._eva:
+                self._eva_account(pos[live])
             self._cur_dev = nxt
             self._rate.mark(float(len(live)))
             lanes = []
@@ -3769,6 +3985,11 @@ class ContinuousServer:
                     self._release_slot(s, req)
                     retired = True
             self._buf.append((nxt, lanes))
+            if self._eva:
+                for s in live:
+                    if self._slot_req[s] is not None \
+                            and self._pos[s] % self.cfg.eva_window == 0:
+                        self._eva_roll(s)
             # every program of this step is enqueued: now the reads.
             # The seed tokens of this step's admissions first, then —
             # one step late, so that this step runs meanwhile — what
@@ -3871,6 +4092,27 @@ class ContinuousServer:
         return (req.prompt + req.tokens[:-1],
                 np.asarray(self._pools[li][3][slot]),
                 np.asarray(self._pools[li][4][slot]))
+
+    def eva_summaries(self, slot: int):
+        """(tokens, k~, v~) of a live slot on a model with "eva"
+        layers: the token ids the slot has consumed (prompt ++ every
+        landed token but the last, which is fed next) and the summary
+        rows of the model's FIRST eva layer that the slot's table
+        makes visible, [visible, H, hd] each in chunk order, read from
+        the device (visible = eva_window / eva_chunk x the windows the
+        tokens complete). For a caller that checks the summaries
+        against a recomputation; `flush()` first."""
+        req = self._slot_req[slot]
+        if not self._eva or req is None or self._buf:
+            raise ValueError("eva_summaries() needs a model with eva "
+                             "layers, a live slot and no step in flight "
+                             "(flush() first)")
+        li = self.cfg.layer_mixer.index("eva")
+        pt = self._tables[slot]
+        bids = np.asarray([pt.blocks[:pt.summary]], np.int32)
+        return (req.prompt + req.tokens[:-1],
+                *(np.asarray(gather_block_kv(p, bids)[0])
+                  for p in self._pools[li]))
 
     def live_positions(self) -> Dict[int, int]:
         """{slot: next write position} of every live slot: what the

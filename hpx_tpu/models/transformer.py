@@ -167,7 +167,11 @@ class TransformerConfig:
     # ops/sparse_attention.py), "lightning" (decayed linear attention
     # over a per-slot recurrent state, ops/lightning.py) or "mamba" (a
     # selective scan: a diagonal state-space recurrence over a per-slot
-    # recurrent state behind a short convolution, ops/mamba.py).
+    # recurrent state behind a short convolution, ops/mamba.py) or "eva"
+    # (softmax attention over K/V rows at TWO grains: the exact rows of
+    # the query's own aligned window of `eva_window` positions, and one
+    # learned-pooled K/V row for every `eva_chunk` positions of every
+    # window behind it, under one softmax: ops/eva.py).
     # Empty = every layer "attn".
     layer_mixer: Tuple[str, ...] = ()
     kda_heads: int = 0              # heads of kda_head_dim x kda_head_dim
@@ -215,6 +219,17 @@ class TransformerConfig:
     mamba_d_conv: int = 4
     mamba_dt_rank: int = 0
     mamba_conv_bias: bool = True
+    # an "eva" layer's two grains (eva_chunk divides eva_window)
+    eva_chunk: int = 16
+    eva_window: int = 2048
+    # the head holds pred_heads x vocab rows: head m (rows vocab m ..
+    # vocab m + vocab - 1) predicts the token m + 1 ahead; decoding
+    # picks from head 0's
+    pred_heads: int = 1
+    # the head's product is taken in float32 (else in `dtype`)
+    logits_f32: bool = False
+    # RMSNorm's scale is 1 + the parameter (the parameter as published)
+    norm_unit_offset: bool = False
     # RMSNorm over each head of q and k (one learned scale a layer):
     # the "sparse" and "lightning" mixers' (their parameters name it)
     qk_norm: bool = False
@@ -302,9 +317,9 @@ class TransformerConfig:
             raise NotImplementedError(
                 f"{body} ({module}) cannot compute this model: its "
                 f"caches hold K/V pairs, `layer_mixer` has {kinds}; "
-                "ContinuousServer(paged=True) holds the recurrent state, "
-                "the latent rows and a sparse layer's index "
-                "(models/serving.py _init_paged)")
+                "ContinuousServer holds the recurrent state, the latent "
+                "rows, a sparse layer's index and an eva layer's two "
+                "grains of rows (models/serving.py _init_paged)")
 
     def only(self, body: str, module: str, *allowed: str) -> None:
         """Refuse, by mechanism and module, a model whose layers `body`
@@ -315,7 +330,8 @@ class TransformerConfig:
                   "moe_shared_d_ff", "moe_router", "moe_renorm",
                   "moe_bias", "moe_held", "layer_mixer", "moe_n_group",
                   "moe_topk_group", "qk_norm", "emb_scale",
-                  "residual_scale", "logit_scale"):
+                  "residual_scale", "logit_scale", "pred_heads",
+                  "logits_f32", "norm_unit_offset"):
             if f not in allowed and getattr(self, f) != getattr(
                     TransformerConfig, f):
                 raise NotImplementedError(
@@ -377,6 +393,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             return _init_gated_mixer(cfg, kind, ks, nrm)
         if kind == "mamba":
             return _init_mamba_mixer(cfg, ks, nrm)
+        if kind == "eva":
+            return _init_eva_mixer(cfg, ks, nrm)
         if kind == "kda":
             h, hd, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank
             return {"kda": {
@@ -411,12 +429,16 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             "wo": nrm(ks[4], (h, cfg.mla_v_dim, d),
                       1.0 / math.sqrt(h * cfg.mla_v_dim))}}
 
+    def scale1():
+        """A norm's parameter at the scale 1 it starts from."""
+        return (jnp.zeros if cfg.norm_unit_offset else jnp.ones)(
+            (d,), cfg.dtype)
+
     def layer(k, i):
         k1, k2, k3, k4 = jax.random.split(k, 4)
         if cfg.mixer(i) != "attn":
-            return ffn({"ln1": jnp.ones((d,), cfg.dtype),
-                        **mixer(k1, cfg.mixer(i)),
-                        "ln2": jnp.ones((d,), cfg.dtype)}, i, k3, k4)
+            return ffn({"ln1": scale1(), **mixer(k1, cfg.mixer(i)),
+                        "ln2": scale1()}, i, k3, k4)
         nh, nkv = cfg.heads(i), cfg.kv_heads
         if nh % nkv:
             raise ValueError(f"n_heads={nh} not a multiple of "
@@ -431,11 +453,11 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
                    "wkv": (jax.random.normal(kkv, (2, d, nkv, hd)) * s
                            ).astype(cfg.dtype)}
         out = {
-            "ln1": jnp.ones((d,), cfg.dtype),
+            "ln1": scale1(),
             **qkv,
             "wo": (jax.random.normal(k2, (nh, hd, d)) * s
                    ).astype(cfg.dtype),
-            "ln2": jnp.ones((d,), cfg.dtype),
+            "ln2": scale1(),
         }
         if cfg.attn_gate:
             out["wgate"] = (jax.random.normal(
@@ -462,12 +484,15 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     params = {
         "emb": (jax.random.normal(keys[0], (cfg.vocab, d)) * s
                 ).astype(cfg.dtype),
-        "ln_f": jnp.ones((d,), cfg.dtype),
+        "ln_f": scale1(),
         "layers": [layer(keys[2 + i], i) for i in range(cfg.n_layers)],
     }
+    if cfg.pred_heads > 1 and cfg.tied:
+        raise ValueError(f"pred_heads={cfg.pred_heads} needs an untied "
+                         "head of pred_heads x vocab rows (tied=False)")
     if not cfg.tied:
-        params["head"] = (jax.random.normal(keys[1], (cfg.vocab, d)) * s
-                          ).astype(cfg.dtype)
+        params["head"] = (jax.random.normal(
+            keys[1], (cfg.pred_heads * cfg.vocab, d)) * s).astype(cfg.dtype)
     return params
 
 
@@ -531,6 +556,26 @@ def _init_mamba_mixer(cfg: TransformerConfig, ks, nrm):
     return {"mamba": out}
 
 
+def _init_eva_mixer(cfg: TransformerConfig, ks, nrm):
+    """The leaves of an "eva" mixer: four projection MATRICES [in, out]
+    with the heads side by side in their columns (plain multi-head: as
+    many K/V heads as query heads) and the two learned vectors a head
+    that pool a chunk's rows into its summary, "phi" (the pooling
+    weights' query) and "mu" (added to the pooled key), float32. Drawn
+    at unit scale here so that random weights pool unevenly."""
+    d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
+    if cfg.kv_heads != h:
+        raise ValueError(f"an eva layer is plain multi-head: n_kv_heads="
+                         f"{cfg.kv_heads} != n_heads={h}")
+    s = 1.0 / math.sqrt(d)
+    return {"eva": {
+        "wq": nrm(ks[0], (d, h * hd), s), "wk": nrm(ks[1], (d, h * hd), s),
+        "wv": nrm(ks[2], (d, h * hd), s),
+        "phi": jax.random.normal(ks[3], (h, hd), jnp.float32),
+        "mu": jax.random.normal(ks[4], (h, hd), jnp.float32),
+        "wo": nrm(ks[5], (h * hd, d), 1.0 / math.sqrt(h * hd))}}
+
+
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpecs: heads/ffn over tp; MoE experts over dp (the ep
     layout — see TransformerConfig); everything else replicated."""
@@ -591,13 +636,17 @@ def _ln(x, scale):
 
 
 def _norm(x, scale, cfg: TransformerConfig):
-    """The model's norm: LayerNorm (scale, no bias) or RMSNorm."""
+    """The model's norm: LayerNorm (scale, no bias) or RMSNorm; under
+    `norm_unit_offset` the scale is 1 + the parameter."""
     if cfg.norm == "rmsnorm":
         xf = x.astype(jnp.float32)
         ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(ms + cfg.norm_eps)).astype(x.dtype) \
-            * scale
-    return _ln(x, scale)
+        y = xf * jax.lax.rsqrt(ms + cfg.norm_eps)
+        if cfg.norm_unit_offset:
+            # 1 + g in float32: in bfloat16 the sum would round g away
+            return (y * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
+        return y.astype(x.dtype) * scale
+    return _ln(x, scale + 1 if cfg.norm_unit_offset else scale)
 
 
 def _dq(w, like):
@@ -675,6 +724,9 @@ def _layer(x, lp, cfg: TransformerConfig, li: int, pos, attend,
                                     cfg.rope_of(li))
     elif "mamba" in lp:
         o, carry = _mamba_mixer(h, lp["mamba"], attend)
+    elif "eva" in lp:
+        o, carry = _eva_mixer(h, lp["eva"], cfg, attend, pos,
+                              cfg.rope_of(li))
     elif "mla" in lp:
         o, carry = _mla_mixer(h, lp["mla"], cfg, attend, pos,
                               cfg.rope_of(li))
@@ -764,6 +816,28 @@ def _lightning_mixer(h, m, cfg: TransformerConfig, attend, pos,
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                               + cfg.norm_eps) * m["onorm"].astype(f32)
     return _gated_out(h, o, m), carry
+
+
+def _eva_mixer(h, m, cfg: TransformerConfig, attend, pos,
+               rope: Optional[RopeSpec]):
+    """An EVA mixer around the body's two-grain cache. h [B, W, D] ->
+    (y, carry). q, k, v = W h a head; q and k rotated at the rows'
+    positions (a key is rotated BEFORE it is pooled, so a cached row,
+    exact or summary, never depends on who reads it); `attend(q, k, v)`
+    writes the exact rows, pools the chunks these rows complete
+    (ops/eva.eva_pool: learned softmax weights over a chunk's rows
+    against "phi", the weighted sums of K and V, "mu" added to the
+    key) and attends the query's own aligned window of exact rows
+    beside the summaries of every window behind it under ONE float32
+    softmax; then W_o."""
+    b, w, _ = h.shape
+    q, k, v = ((h @ _dq(m[n], h)).reshape(b, w, -1, cfg.head_dim)
+               for n in ("wq", "wk", "wv"))
+    if rope is not None:
+        q, k = _rope(q, pos, rope), _rope(k, pos, rope)
+    att, carry = attend(q, k, v)
+    att = att.reshape(b, w, -1)
+    return att @ _dq(m["wo"], att), carry
 
 
 def _mamba_mixer(h, m, attend):
@@ -951,11 +1025,22 @@ def _cached_attention(q, kc, vc, qpos, window: int = 0):
 
 
 def _logits(params, x, cfg: TransformerConfig):
-    """Final norm and the head (the embedding's transpose when tied)."""
+    """Final norm and the head (the embedding's transpose when tied):
+    [B, S, pred_heads x vocab], every prediction head's logits side by
+    side (`_next_logits` keeps the next token's)."""
     x = _norm(x, params["ln_f"], cfg)
     if cfg.logit_scale != 1.0:
         x = x * cfg.logit_scale
-    return jnp.einsum("bsd,vd->bsv", x, params.get("head", params["emb"]))
+    return jnp.einsum(
+        "bsd,vd->bsv", x, params.get("head", params["emb"]),
+        preferred_element_type=jnp.float32 if cfg.logits_f32 else None)
+
+
+def _next_logits(logits, cfg: TransformerConfig):
+    """Head 0's columns of `_logits`' rows: the NEXT token's logits,
+    what a decoder picks from. All of them where the model has one
+    prediction head."""
+    return logits if cfg.pred_heads == 1 else logits[..., :cfg.vocab]
 
 
 def _embed(params, toks, cfg: TransformerConfig):
@@ -1474,7 +1559,11 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
     means of K's rows: nothing more is kept here), each row choosing
     the blocks it attends; a "lightning" layer's (state,), `valid` as
     for "kda"; a "mamba" layer's (state [B, N, C], conv tail [B, (K -
-    1) C]), `valid` alike."""
+    1) C]), `valid` alike; an "eva" layer's (exact K, exact V [B,
+    eva_window, N, H]: a ring, row r the newest position = r mod the
+    window; summary K, summary V [B, chunks, N, H]), `valid` the
+    columns that may be WRITTEN (padding must not wrap onto live
+    rows)."""
     qpos = jnp.asarray(write_at) + jnp.arange(x.shape[1])
 
     def attend(q, k, v):
@@ -1513,6 +1602,13 @@ def _block_decode(x, lp, kv, write_at, cfg: TransformerConfig,
         def attend(u):                                      # noqa: F811
             return mamba_mix(u, lp["mamba"], *kv, valid=valid,
                              eps=cfg.norm_eps)
+    elif "eva" in lp:
+        from ..ops.eva import eva_window_attend
+
+        def attend(q, k, v):                                # noqa: F811
+            return eva_window_attend(
+                q, k, v, kv, write_at, valid, lp["eva"]["phi"],
+                lp["eva"]["mu"], cfg.eva_chunk, cfg.eva_window)
     elif "mla" in lp:
         def attend(q, row):                                 # noqa: F811
             lat = jax.lax.dynamic_update_slice_in_dim(

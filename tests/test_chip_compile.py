@@ -784,6 +784,66 @@ def test_ssm_server_programs_copy_neither_state_nor_tail_nor_pool(
     assert "jit_chunk" in chunk.as_text().splitlines()[0]
 
 
+def test_two_grain_server_programs_walk_once_and_copy_no_pool(
+        sds, monkeypatch):
+    """The server's own programs for EVA layers at the cell's widths
+    (32 K/V heads x 128, a group of ONE query head, windows of 2,048
+    pooled every 16; two layers of the eight), built from parameter
+    SHAPES at 24 slots: `jit_step` holds one `hpx_paged_fused` a layer
+    over the slot's one run of rows and leaves the pools where they
+    lie; the splice writes both grains and `jit_roll` a window's
+    summaries in place; `jit_chunk` at 512 columns over the two-grain
+    scratch compiles and keeps its temporaries under 1 GB."""
+    import json
+    from chipbench.drivers import serving_eva as drv
+    from hpx_tpu.models import transformer as tfm
+    from hpx_tpu.models.serving import ContinuousServer
+    from hpx_tpu.ops.eva import summary_rows
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root,
+                           "chipbench/configs/evabyte-6.5b.json")) as f:
+        conf = json.load(f)
+    cfg = drv.build_cfg({**conf, "num_hidden_layers": 2})
+    params = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    srv = ContinuousServer(params, cfg,
+                           **{**conf["server"], "num_blocks": 80})
+    s, nb = srv.slots, conf["server"]["num_blocks"]
+    assert srv._paged_kernel == "fused" and srv._maxb == 50
+    # a table entry is copied once for all 32 heads
+    assert srv._walk_group() == (32, 32)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    pools = [tuple(sds((nb,) + p.shape[1:], p.dtype) for p in pl)
+             for pl in srv._pools]
+    pool = f"bf16[{nb},32,64,128]"
+    text = srv._paged_step_prog().lower(
+        on_chip(params), pools, None, sds((s,), jnp.int32),
+        sds((s,), jnp.int32), (sds((s, srv._maxb), jnp.int32),),
+        sds((s,), jnp.float32), sds((s, 2), jnp.uint32)).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert "hpx_paged_fused" in text and _copies_of(text, pool) == []
+    scratch = on_chip(jax.eval_shape(srv._fresh_scratch))
+    assert [a.shape[1] for a in scratch[0]] == [2048, 2048, 1152, 1152]
+    text = srv._paged_splice_prog().lower(
+        pools, None, scratch,
+        (sds((summary_rows(srv.smax, 16, 2048) // 64,), jnp.int32),
+         sds((32,), jnp.int32)), sds((), jnp.int32)).compile().as_text()
+    assert _copies_of(text, pool) == []
+    roll = srv._eva_roll_prog().lower(
+        pools, on_chip(srv._eva_params), sds((32,), jnp.int32),
+        sds((2,), jnp.int32)).compile()
+    assert "jit_roll" in roll.as_text().splitlines()[0]
+    assert _copies_of(roll.as_text(), pool) == []
+    chunk = srv._chunk_prog(512).lower(
+        on_chip(params), scratch, sds((1, 512), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile()
+    assert "jit_chunk" in chunk.as_text().splitlines()[0]
+    assert chunk.memory_analysis().temp_size_in_bytes < 1e9
+
+
 # -- the one-layer probe (PR 44): chunks hand back a hidden row ----------
 
 # cell -> (driver under chipbench/drivers, the chunk's rows on the chip,

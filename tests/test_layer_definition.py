@@ -178,3 +178,57 @@ def test_a_default_config_describes_the_old_block():
     cfg.only("any body", "any module")      # nothing to refuse
     lp = tfm.init_params(cfg, jax.random.PRNGKey(0))["layers"][0]
     assert sorted(lp) == ["b1", "ln1", "ln2", "w1", "w2", "wo", "wqkv"]
+
+
+# -- what a layer's cache entry is, by its mixer's kind -----------------------
+
+_KIND = dict(vocab=64, d_model=32, n_heads=4, head_dim=8, n_layers=1,
+             d_ff=64, n_kv_heads=2)
+# kind -> (the fields that size its entry, the shapes of a b=1 scratch
+# entry at smax 64, what the dense-cache bodies' refusal must name)
+_KINDS = {
+    "attn": ({}, [(1, 64, 2, 8)] * 2, None),
+    "sparse": ({}, [(1, 64, 2, 8)] * 2, "sparse layer's index"),
+    "mla": (dict(mla_rank=24, mla_rope_dim=8), [(1, 64, 1, 128)],
+            "latent"),
+    "kda": (dict(kda_heads=2, kda_head_dim=8), [(1, 2, 8, 8), (1, 3, 48)],
+            "recurrent state"),
+    "lightning": (dict(lightning_heads=2, lightning_head_dim=8),
+                  [(1, 2, 8, 8)], "recurrent state"),
+    "mamba": (dict(mamba_d_inner=16, mamba_d_state=4),
+              [(1, 4, 16), (1, 48)], "recurrent state"),
+    # one window's exact rows as a ring, and one summary every chunk of
+    # the windows that can complete (64 // 16 = 4 of them, 16 // 4 each)
+    "eva": (dict(n_kv_heads=0, eva_chunk=4, eva_window=16),
+            [(1, 16, 4, 8)] * 2 + [(1, 16, 4, 8)] * 2, "two\\s+grains"),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_KINDS))
+def test_a_scratch_entry_has_its_kinds_shapes(kind):
+    fields, shapes, _ = _KINDS[kind]
+    cfg = tfm.TransformerConfig(**{**_KIND, **fields},
+                                layer_mixer=(kind,))
+    entry = jax.eval_shape(lambda: serving._scratch_entry(cfg, 64, 0))
+    assert [a.shape for a in entry] == shapes
+    if kind == "eva":       # (window + smax / chunk) rows where K/V has smax
+        entry = jax.eval_shape(lambda: serving._scratch_entry(
+            tfm.TransformerConfig(n_heads=32, head_dim=128, eva_chunk=16,
+                                  eva_window=2048, layer_mixer=("eva",)),
+            18688, 0))
+        assert [a.shape[1] for a in entry] == [2048, 2048, 1152, 1152]
+
+
+@pytest.mark.parametrize("kind", sorted(k for k in _KINDS if k != "attn"))
+def test_the_dense_cache_bodies_refuse_each_kind_by_mechanism(kind):
+    fields, _, names = _KINDS[kind]
+    cfg = tfm.TransformerConfig(**{**_KIND, **fields},
+                                layer_mixer=(kind,))
+    with pytest.raises(NotImplementedError) as e:
+        cfg.kv_pairs_only("generate: the dense K/V caches",
+                          "models/transformer.py")
+    text = str(e.value)
+    assert "models/transformer.py" in text and kind in text
+    assert "models/serving.py _init_paged" in text
+    import re
+    assert re.search(names, text)

@@ -326,3 +326,60 @@ def test_the_mesh_hatch_is_no_config_key():
     with pytest.raises(UndeclaredConfigKey, match="mesh.paged"):
         Configuration(environ={}, strict=True).set(
             "hpx.serving.mesh.paged", "0")
+
+
+# -- a two-grain table's block counts, reckoned without a device -------------
+
+def _eva_cell():
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root,
+                           "chipbench/configs/evabyte-6.5b.json")) as f:
+        return json.load(f)
+
+
+# position of a write -> (summary blocks behind it, its window's exact
+# blocks up to it, the blocks held for the summaries it completes) at
+# the cell's geometry: windows of 2,048, chunks of 16, blocks of 64
+_EVA_BLOCKS = {
+    0: (0, 1, 0), 63: (0, 1, 0), 64: (0, 2, 0), 2046: (0, 32, 0),
+    2047: (0, 32, 2), 2048: (2, 1, 0), 4095: (2, 32, 2),
+    16384: (16, 1, 0), 18431: (16, 32, 2), 18432: (18, 1, 0),
+    18687: (18, 4, 0),
+}
+
+
+@pytest.mark.parametrize("pos", sorted(_EVA_BLOCKS))
+def test_two_grain_block_counts_at_the_cells_geometry(pos):
+    from hpx_tpu.cache.page_table import TwoGrainTable
+    conf = _eva_cell()
+    srv = conf["server"]
+    t = TwoGrainTable(srv["block_size"], conf["window_size"],
+                      conf["chunk_size"])
+    assert t.per == 2
+    summary, exact, fresh = _EVA_BLOCKS[pos]
+    assert t.blocks_at(pos) == summary + exact + fresh
+    # once the step is out and its window rolled: what the slot holds
+    assert t.held(pos + 1) == (pos + 1) // 2048 * 2 \
+        + -(-((pos + 1) % 2048) // 64)
+    assert t.blocks_at(pos) <= TwoGrainTable.max_blocks(
+        64, 2048, 16, srv["smax"]) == 50
+    assert srv["num_blocks"] == srv["slots"] * 50 + 1
+
+
+def test_a_two_grain_server_sizes_its_table_and_pool_from_the_run():
+    cfg = tfm.TransformerConfig(
+        vocab=64, d_model=32, n_heads=4, head_dim=8, n_layers=2, d_ff=64,
+        norm="rmsnorm", mlp="swiglu", tied=False, rope=True,
+        layer_mixer=("eva", "eva"), eva_chunk=4, eva_window=16)
+    srv = ContinuousServer(tfm.init_params(cfg, jax.random.PRNGKey(0)),
+                           cfg, slots=3, smax=96, block_size=4,
+                           prefill_chunk=8)
+    # 5 windows behind the last + its 4 blocks + the block a roll holds
+    assert srv._maxb == 10
+    # nothing is shared (prefix reuse is refused): no radix headroom
+    assert srv._alloc.num_blocks == 3 * 10 + 1
+    assert [tuple(a.shape for a in e) for e in srv._pools] == \
+        [((31, 4, 4, 8),) * 2] * 2
+    assert srv.hbm_read_stats()["walk_entries_per_slot"] == 0.0
