@@ -19,7 +19,8 @@ a hard error, never a silent fall-through to bf16 byte accounting.
 
 Usage: python benchmarks/flash_tune.py [--quick] [--paged]
                                        [--positions P[,P...]
-                                        [--heads [B x]QxKV] [--smax S]]
+                                        [--heads [B x]QxKV] [--smax S]
+                                        [--block N]]
   --quick: S in {2k, 4k} only and fewer samples (smoke/dev loops).
   --paged: tune the paged decode kernel instead of flash forward.
   --positions 15,511,2047: with --paged, no sweep and nothing banked:
@@ -28,7 +29,8 @@ Usage: python benchmarks/flash_tune.py [--quick] [--paged]
     trash block as the server lays it out: 32 slots, 24 q / 2 kv heads
     (the StarCoder2-3B cell's shape, `--heads 32x24x2`). [--heads
     [B x]QxKV] [--smax S] give another cell's: 32x48x8 and 4864 (304
-    entries) are Laguna's full layers. Times are the DEVICE's
+    entries) are Laguna's full layers, 24x32x32, 3200 and `--block 64`
+    (50 entries of 64 rows) EvaByte's. Times are the DEVICE's
     (`paged_measure`), so a call under the host's dispatch latency reads
     as what it is.
 """
@@ -232,9 +234,9 @@ def paged_measure(jax, jnp, S, bs, kvd, kern, samples=3, **layout):
     return hbm / per / 1e9, per * 1e6, (pers[-1] - pers[0]) / per
 
 
-def paged_positions(jax, jnp, positions, heads, S) -> int:
+def paged_positions(jax, jnp, positions, heads, S, bs=16) -> int:
     """`--positions`: one line per position, nothing banked."""
-    bs, kvd, kern = 16, "bf16", "fused"
+    kvd, kern = "bf16", "fused"
     pools = _paged_pools(jnp, S, bs, kvd, heads)
     for p in positions:
         gbs, us, spread = paged_measure(jax, jnp, S, bs, kvd, kern,
@@ -309,7 +311,8 @@ def main() -> int:
         heads = tuple(map(int, (_arg("--heads") or "24x2").split("x")))
         return paged_positions(
             jax, jnp, [int(p) for p in _arg("--positions").split(",")],
-            (32,) * (3 - len(heads)) + heads, int(_arg("--smax") or 2048))
+            (32,) * (3 - len(heads)) + heads, int(_arg("--smax") or 2048),
+            int(_arg("--block") or 16))
     if "--paged" in sys.argv:
         return paged_main(jax, jnp, quick)
 

@@ -100,6 +100,14 @@ Every server also exports the counters of its cache::
     /cache{locality#L/server#i}/count/walk-copies-per-slot  DMA descriptors (K and
                                                           V) a slot, layer and
                                                           step
+    /cache{locality#L/server#i}/count/walk-bank-sets    sets of banks the walk's
+                                                          copies land in (2: a
+                                                          grid step copies the
+                                                          next one's entries; 0:
+                                                          the grid walk)
+    /cache{locality#L/server#i}/walk-steps-prefetched-share  grid steps of a
+                                                          layer's call whose copies
+                                                          the step before started
 
 Models with recurrent ("kda", "lightning", "mamba") layers add their per-slot
 state, models with latent-attention ("mla") layers their rows on the
@@ -372,6 +380,14 @@ def register_server(srv) -> str:
     put("cache", "count/walk-copies-per-slot",
         pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
                            ["walk_copies_per_slot"])))
+    # the sets of banks those copies land in, and the grid steps
+    # whose copies the step before them started
+    put("cache", "count/walk-bank-sets",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["walk_bank_sets"])))
+    put("cache", "walk-steps-prefetched-share",
+        pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                           ["walk_steps_prefetched_share"])))
     if srv._win:
         # the window block group (serving._init_paged)
         put("cache", "window/blocks-in-use",

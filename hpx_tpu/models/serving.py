@@ -2447,7 +2447,12 @@ class ContinuousServer:
         copies an entry ONCE for the `heads_per_copy` kv heads a grid
         step owns (`walk_copies_per_slot` = `walk_entries_per_slot` x 2
         pools x n_kv / `heads_per_copy`: the DMA descriptors a slot,
-        layer and step; both 0 where the calls keep the grid walk),
+        layer and step; both 0 where the calls keep the grid walk)
+        into the set of banks the grid step before is not reading
+        (`walk_bank_sets` 2, 0 on the grid walk;
+        `walk_steps_prefetched_share` = (G - 1) / G, G = slots x n_kv /
+        `heads_per_copy` of the call: the grid steps of a layer's call
+        whose copies were in flight during the step before),
         every other fused call visits all max_blocks entries, whose
         tail aliases the single resident trash block — occupancy is
         the honest per-slot traffic either way. bytes/token uses
@@ -2471,6 +2476,9 @@ class ContinuousServer:
                  for p in self.live_positions().values()]
         walk = sum(walks) / len(walks) if walks else 0.0
         hg, nkv = self._walk_group()
+        # grid steps of one layer's call (the shard's under a mesh)
+        dp = self.mesh.shape["dp"] if self.mesh is not None else 1
+        grid = self.slots // dp * nkv // hg if hg else 0
         return {
             "hbm_read_blocks_per_token": per_tok,
             "hbm_read_bytes_per_token": per_tok * bb,
@@ -2482,6 +2490,10 @@ class ContinuousServer:
             # copies (K and V) a slot, layer and step then issues
             "heads_per_copy": hg,
             "walk_copies_per_slot": walk * 2 * nkv / hg if hg else 0.0,
+            # the sets of banks the walk lands in, and the share of a
+            # call's grid steps whose copies the step before started
+            "walk_bank_sets": 2 if hg else 0,
+            "walk_steps_prefetched_share": (grid - 1) / grid if hg else 0.0,
             # where this server's block_size came from: arg | config |
             # env | seed (paged_blocks.json) | default
             "block_size_source": self._block_size_src,

@@ -903,6 +903,36 @@ def flash_attention_chunk(q, k, v, acc, m, l, d,
 # same bytes. Starts, waits and table reads fall by `hg`; the bytes
 # stay; the semaphore rule stays word for word.
 #
+# TWO SETS OF BANKS (PR 50). A grid step ran its copies and then its
+# finish, and because the kernel issues its own copies nothing of
+# Pallas' pipeline overlapped them across grid steps either: on EvaByte
+# (32 heads over 3,200 bank rows) 33 us of copies and 21 us of finish a
+# step, one after the other, the kernel at 55 % of its bytes. So the
+# banks are (2, hg, S, hd) a pool and grid step n, which owns set
+# n % 2, STARTS THE COPIES OF STEP n + 1 INTO THE OTHER SET before it
+# waits for its own and runs its finish: the next step's (slot, group)
+# follows from n + 1, its `_walk_entries` and its table entries are
+# scalar-prefetched, so they are readable a step early. The call's
+# first step starts its own copies too; the last starts none (a trip
+# count of 0, not a branch); a step's waits rebuild the descriptors the
+# step before started. The semaphore rule holds PER SET: DMA semaphores
+# are (set, pool), the next step's copies signal the OTHER set's, and
+# nothing is read from a set's banks before every wait of that set's
+# copies has returned. A set is written again only by the step AFTER
+# the one that read it, which starts no copy before its own body
+# begins. The chain needs the grid IN ORDER, so both axes are
+# "arbitrary"; a v5e chip has one TensorCore and loses nothing, a
+# two-core part would want the slot axis split by hand (no cell and no
+# mesh test runs one). `walk_heads_per_copy` counts both sets under the
+# same budget, so `hg` halves where one set filled it: EvaByte 32 -> 16
+# (two sets of 16 heads' banks are the bytes one set of 32 was; a slot
+# is two grid steps and an entry two 256 KB copies a pool, still paced
+# by bytes); StarCoder2-3B's 2 and Laguna's 8 stay. A bank too long for
+# two sets at one head (smax past 126,976 in bfloat16) is the
+# compiler's to refuse, as one too long for one set (229,376) was. The
+# finish is untouched: the same ops in the same order under the same
+# masks, so the rows are bit for bit what one set gave.
+#
 # Every other call keeps the grid walk, for reasons
 # that conflict with this path's: a one-byte pool would need a staging
 # bank and a per-entry dequantization between landing and banking
@@ -1051,71 +1081,93 @@ def _paged_live_kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     """One (slot b, group of `hg` kv heads) grid step: the slot's whole
     walk, bounded by its live length (`_paged_kernel`'s result for
     unquantized pools without its dead grid steps), each table entry
-    copied ONCE for all the group's heads.
+    copied ONCE for all the group's heads, and copied WHILE THE GRID
+    STEP BEFORE ran its finish.
 
     q_ref / o_ref: (hg, Wg, hd), a head's rows as in `_paged_kernel`;
-    k_hbm / v_hbm: the POOLS, left in HBM; k_s / v_s: (hg, S, hd), a
-    bank a head. The first `_walk_entries` table entries are copied into
-    the banks (entry j, heads h0 .. h0 + hg, which lie back to back in
-    the pool, to rows j*bs .. of every head's bank: one descriptor a
-    pool), all in flight at once on
-    ONE DMA semaphore a pool. Such a semaphore counts bytes landed from
-    ANY copy that signals it, so a wait that returns says nothing of
-    ITS copy: NOTHING IS READ FROM A BANK BEFORE EVERY WAIT OF BOTH
-    LOOPS' COPIES HAS RETURNED. Then `_paged_kernel`'s finish, op for
-    op, head by head over that head's (S, hd) bank. Rows past the walk
-    hold whatever VMEM held (the previous grid
-    step's rows, NaN for all we know): their scores are masked to -inf
-    as every dead row's are (a select, so a NaN score goes too), and
-    V's are SELECTED to zero ahead of the product, because 0 x NaN is
-    NaN."""
-    b = pl.program_id(0)
-    heads = pl.ds(pl.multiple_of(pl.program_id(1) * hg, hg), hg)
+    k_hbm / v_hbm: the POOLS, left in HBM; k_s / v_s: (2, hg, S, hd),
+    TWO SETS of a bank a head. The grid runs in order, and grid step n
+    (= b * groups + g) owns set n % 2. The first `_walk_entries` table
+    entries of a step are copied into its set (entry j, heads h0 .. h0
+    + hg, which lie back to back in the pool, to rows j*bs .. of every
+    head's bank: one descriptor a pool), all in flight at once on ONE
+    DMA semaphore a set and pool: started by the step BEFORE (the
+    call's first step starts its own), which then waits for its own
+    and runs its finish while they land; the last step starts none.
+    Such a semaphore counts bytes landed from ANY copy that signals it,
+    so a wait that returns says nothing of ITS copy: NOTHING IS READ
+    FROM A SET'S BANKS BEFORE EVERY WAIT OF THAT SET'S COPIES HAS
+    RETURNED, and the next step's copies signal the OTHER set's
+    semaphores. Then `_paged_kernel`'s finish, op for op, head by head
+    over that head's (S, hd) bank. Rows past the walk hold whatever
+    VMEM held (the rows of the step before last, NaN for all we know):
+    their scores are masked to -inf as every dead row's are (a select,
+    so a NaN score goes too), and V's are SELECTED to zero ahead of the
+    product, because 0 x NaN is NaN."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    nslot, ngrp = pl.num_programs(0), pl.num_programs(1)
+    n = b * ngrp + g
+    mine = n % 2
+
+    def walk(slot):
+        return _walk_entries(pos_ref[slot], w, block_size, nblk)
+
+    def each(slot, grp, bank, count, what):
+        """`what` (start or wait) of the copies of (slot, grp)'s first
+        `count` entries into set `bank`: a wait rebuilds the descriptor
+        its start was given."""
+        heads = pl.ds(pl.multiple_of(grp * hg, hg), hg)
+
+        def body(j, carry):
+            rows = pl.ds(pl.multiple_of(j * block_size, block_size),
+                         block_size)
+            blk = table_ref[slot, j]
+            what(pltpu.make_async_copy(k_hbm.at[blk, heads],
+                                       k_s.at[bank, :, rows, :],
+                                       sem.at[bank, 0]))
+            what(pltpu.make_async_copy(v_hbm.at[blk, heads],
+                                       v_s.at[bank, :, rows, :],
+                                       sem.at[bank, 1]))
+            return carry
+        jax.lax.fori_loop(0, count, body, 0)
+
+    def start(cp):
+        cp.start()
+
+    def wait(cp):
+        cp.wait()
+
     pos0 = pos_ref[b]
-    n_live = _walk_entries(pos0, w, block_size, nblk)
-
-    def copies(j):
-        rows = pl.ds(pl.multiple_of(j * block_size, block_size),
-                     block_size)
-        blk = table_ref[b, j]
-        return (pltpu.make_async_copy(k_hbm.at[blk, heads],
-                                      k_s.at[:, rows, :], sem.at[0]),
-                pltpu.make_async_copy(v_hbm.at[blk, heads],
-                                      v_s.at[:, rows, :], sem.at[1]))
-
-    def start(j, carry):
-        for c in copies(j):
-            c.start()
-        return carry
-
-    def wait(j, carry):
-        for c in copies(j):
-            c.wait()
-        return carry
-
-    jax.lax.fori_loop(0, n_live, start, 0)
-    jax.lax.fori_loop(0, n_live, wait, 0)
+    n_live = walk(b)
+    # the step after this one: the slot's next group, else the next slot
+    last = g == ngrp - 1
+    b_next = jnp.minimum(jnp.where(last, b + 1, b), nslot - 1)
+    g_next = jnp.where(last, 0, g + 1)
+    each(b, g, mine, jnp.where(n == 0, n_live, 0), start)  # none before it
+    each(b_next, g_next, 1 - mine,                 # into the OTHER set
+         jnp.where(n == nslot * ngrp - 1, 0, walk(b_next)), start)
+    each(b, g, mine, n_live, wait)
 
     # the horizon is the slot's, the same for every head of the group
-    sshape = (q_ref.shape[1], k_s.shape[1])
+    sshape = (q_ref.shape[1], k_s.shape[2])
     kpos = jax.lax.broadcasted_iota(jnp.int32, sshape, 1)
     wrow = jax.lax.broadcasted_iota(jnp.int32, sshape, 0) // group
     if window:
         kpos = _ring_kpos(kpos // block_size, kpos % block_size,
                           pos0 + wrow, block_size, nblk)
     live = _live(kpos, pos0 + wrow, window)        # per-window-row horizon
-    vrow = jax.lax.broadcasted_iota(jnp.int32, k_s.shape[1:], 0)
+    vrow = jax.lax.broadcasted_iota(jnp.int32, k_s.shape[2:], 0)
     vlive = vrow < n_live * block_size
 
     for i in range(hg):                            # a head's finish
         q = q_ref[i]                               # (Wg, hd)
         s = jax.lax.dot_general(
-            q, k_s[i].astype(q.dtype), (((1,), (1,)), ((), ())),
+            q, k_s[mine, i].astype(q.dtype), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32).astype(q.dtype)
         sf = (s / math.sqrt(q.shape[-1])).astype(jnp.float32)
         sf = jnp.where(live, sf, -jnp.inf)
         p = jax.nn.softmax(sf, axis=-1)            # oracle op order
-        v = jnp.where(vlive, v_s[i], 0)
+        v = jnp.where(vlive, v_s[mine, i], 0)
         att = jax.lax.dot_general(
             p.astype(o_ref.dtype), v.astype(o_ref.dtype),
             (((1,), (0,)), ((), ())),
@@ -1305,20 +1357,22 @@ def fused_paged_online_attention(q: jax.Array, k_pool: jax.Array,
                              window=window)
 
 
-# VMEM the bounded walk may plan with: the two banks of a grid step's
-# heads and one head's finish. v5e has 128 MiB; half is left to the
-# compiler (the q / o blocks it double-buffers, what it spills).
+# VMEM the bounded walk may plan with: the two SETS of K and V banks of
+# a grid step's heads and one head's finish. v5e has 128 MiB; half is
+# left to the compiler (the q / o blocks it double-buffers, what it
+# spills).
 _WALK_VMEM_BUDGET = 64 << 20
 
 
 def _walk_vmem_bytes(hg: int, seq: int, hd: int, wg: int,
                      pool_itemsize: int, q_itemsize: int) -> int:
-    """What a grid step of `_paged_live_kernel` holds in VMEM with `hg`
-    heads a group: the K and V banks of all of them, and ONE head's
-    finish (its K rows as loaded and cast, its V rows selected and
-    cast, and the score rows, `wg` = W * group padded to 8 sublanes,
-    through mask and softmax in float32)."""
-    banks = 2 * hg * seq * hd * pool_itemsize
+    """What `_paged_live_kernel` holds in VMEM with `hg` heads a group:
+    the K and V banks of all of them TWICE (this grid step's set and
+    the one the next step's copies land in), and ONE head's finish (its
+    K rows as loaded and cast, its V rows selected and cast, and the
+    score rows, `wg` = W * group padded to 8 sublanes, through mask and
+    softmax in float32)."""
+    banks = 2 * 2 * hg * seq * hd * pool_itemsize
     finish = 2 * seq * hd * (pool_itemsize + q_itemsize) \
         + 6 * (wg + -wg % 8) * seq * 4
     return banks + finish
@@ -1343,10 +1397,12 @@ def walk_heads_per_copy(nkv: int, seq: int, hd: int, wg: int,
 def _live_walk_call(qk, k_pool, v_pool, table, pos0, *, w: int,
                     group: int, window: int, interpret: bool) -> jax.Array:
     """Launch `_paged_live_kernel`: qk [B, n_kv, Wg_pad, hd] in, the
-    same out. Grid (slot, n_kv // hg), both parallel, `hg` =
+    same out. Grid (slot, n_kv // hg), run IN ORDER (a step starts the
+    next step's copies: "arbitrary" on both axes), `hg` =
     `walk_heads_per_copy` of the operands' shapes; the pools stay in HBM
-    for the kernel's own copies; table and pos0 scalar-prefetched; the
-    two (hg, S, hd) banks in the pools' dtype and a DMA semaphore a
+    for the kernel's own copies; table and pos0 scalar-prefetched (so a
+    step reads the next slot's a step early); two sets of (hg, S, hd)
+    banks a pool in the pools' dtype and a DMA semaphore a set and
     pool; the VMEM limit stated from those bytes."""
     b, nkv, wg_pad, hd = qk.shape
     bs = k_pool.shape[2]
@@ -1366,14 +1422,15 @@ def _live_walk_call(qk, k_pool, v_pool, table, pos0, *, w: int,
             grid=(b, nkv // hg),
             in_specs=[q_spec, pool_spec, pool_spec],
             out_specs=[q_spec],
-            scratch_shapes=[pltpu.VMEM((hg, maxb * bs, hd), k_pool.dtype),
-                            pltpu.VMEM((hg, maxb * bs, hd), v_pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
+            scratch_shapes=[
+                pltpu.VMEM((2, hg, maxb * bs, hd), k_pool.dtype),
+                pltpu.VMEM((2, hg, maxb * bs, hd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2))],      # (set, pool)
         ),
         out_shape=[_sds((b, nkv, wg_pad, hd), qk.dtype, qk, k_pool,
                         v_pool)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=int(max(
                 _walk_vmem_bytes(hg, *sizes) + (8 << 20), 32 << 20))),
         interpret=interpret,

@@ -118,17 +118,22 @@ def test_paged_attention_window_and_hd64_compile(sds, kernel, kv, nkv,
 # the benchmark's two serving cells at their real widths: 32 slots;
 # StarCoder2-3B 24 q / 2 kv over 128 entries of 8,193 blocks; Laguna's
 # full layers 48 q / 8 kv over 304 entries of 19,457 blocks, its window
-# layers 64 q / 8 kv over a ring of 34 in 1,217 blocks
+# layers 64 q / 8 kv over a ring of 34 in 1,217 blocks; EvaByte 32 q /
+# 32 kv over a two-grain run of 50 entries of 1,201 blocks of 64 rows
+# (24 slots in its cell; a slot more or less changes no kernel)
 _CELL_WIDTHS = {"sc2-3b": (24, 2, 128, 8193, 0),
                 "laguna-full": (48, 8, 304, 19457, 0),
-                "laguna-window": (64, 8, 34, 1217, 512)}
+                "laguna-window": (64, 8, 34, 1217, 512),
+                "evabyte": (32, 32, 50, 1201, 0)}
+_CELL_BLOCK = {"evabyte": 64}
 
 
 def _cell_case(make, cell, w=1, hd=128, **pool_kw):
     """(`fused` call, pool, argument shapes) of one of `_CELL_WIDTHS`,
     32 slots; `make(shape, dtype)` places a shape."""
     nq, nkv, maxb, nb, window = _CELL_WIDTHS[cell]
-    pool = make((nb, nkv, 16, hd), jnp.bfloat16, **pool_kw)
+    pool = make((nb, nkv, _CELL_BLOCK.get(cell, 16), hd), jnp.bfloat16,
+                **pool_kw)
 
     def call(q, kp, vp, table, pos):
         return ap.fused_paged_attention(q, kp, vp, table, pos,
@@ -151,8 +156,11 @@ def _walk_grids(fn, *shapes) -> list:
     return list(walk(jax.make_jaxpr(fn)(*shapes).jaxpr))
 
 
-# heads a copy carries at the cells' widths (W = 1 and 4 alike)
-_CELL_HG = {"sc2-3b": 2, "laguna-full": 8, "laguna-window": 8}
+# heads a copy carries at the cells' widths (W = 1 and 4 alike), two
+# sets of banks counted: EvaByte's 32 heads x 3,200 rows are 26 MB a
+# pool, so a slot is two grid steps of 16
+_CELL_HG = {"sc2-3b": 2, "laguna-full": 8, "laguna-window": 8,
+            "evabyte": 16}
 
 
 @pytest.mark.parametrize("w", [1, 4], ids=["decode", "window4"])
@@ -160,12 +168,18 @@ _CELL_HG = {"sc2-3b": 2, "laguna-full": 8, "laguna-window": 8}
 def test_bounded_walk_compiles_at_the_cells_widths(sds, cell, w):
     """`_paged_live_kernel` (pools left in HBM, a data-dependent loop
     of block copies in one grid step a (slot, group of kv heads), one
-    copy an entry for the whole group) at the two
+    copy an entry for the whole group, into the set of banks the step
+    before is not reading) at the K/V
     serving cells' widths, full table and ring, under both names, with
-    nothing pool-shaped copied around it."""
+    nothing pool-shaped copied around it, and a stated VMEM limit
+    (both sets, a head's finish, 8 MB) inside the chip's 128 MiB."""
     call, pool, shapes = _cell_case(sds, cell, w)
-    nkv = _CELL_WIDTHS[cell][1]
-    assert _walk_grids(call, *shapes) == [(32, nkv // _CELL_HG[cell])]
+    nq, nkv, maxb = _CELL_WIDTHS[cell][:3]
+    hg = _CELL_HG[cell]
+    assert _walk_grids(call, *shapes) == [(32, nkv // hg)]
+    assert ap._walk_vmem_bytes(
+        hg, maxb * pool.shape[2], 128, w * nq // nkv, 2, 2) + (8 << 20) \
+        < 128 << 20
     text = _kernel_text(call, *shapes)
     assert ("hpx_paged_fused_win" in text) == (cell == "laguna-window")
     assert "hpx_paged_fused" in text
@@ -202,21 +216,22 @@ def test_bounded_walk_compiles_under_the_serving_mesh(topo, cell):
 
 
 @pytest.mark.parametrize("nkv,smax,dtype,hg", [
-    (8, 4864, jnp.float32, 8),       # 8 KB a head and entry, 40 MB of banks
-    (8, 9728, jnp.float32, 4),
-    (8, 16384, jnp.bfloat16, 4),
-    (6, 16384, jnp.bfloat16, 3),
+    (8, 4864, jnp.float32, 4),       # 8 KB a head and entry, 2 x 20 MB
+    (8, 9728, jnp.float32, 2),
+    (8, 16384, jnp.bfloat16, 2),
+    (6, 16384, jnp.bfloat16, 2),
     (1, 28672, jnp.bfloat16, 1),     # ROADMAP A11: must not move down
-    (8, 28672, jnp.bfloat16, 2),
-    (1, 131072, jnp.bfloat16, 1),    # the stated VMEM limit's reach
+    (8, 28672, jnp.bfloat16, 1),
+    (1, 114688, jnp.bfloat16, 1),    # the stated VMEM limit's reach: two
+                                     # sets compile through 126,976
 ], ids=lambda v: getattr(v, "__name__", str(v)))
 def test_bounded_walk_compiles_at_every_group_the_rule_picks(
         sds, nkv, smax, dtype, hg):
     """The launch states its VMEM limit from the banks it allocates, so
     whatever group `walk_heads_per_copy` picks compiles: float32 pools,
-    a group that is no power of two, and the long tables at which only
-    one head fits (smax 28,672 compiled before this path grouped heads,
-    and still does)."""
+    a group that two sets of banks halve, and the long tables at which
+    only one head fits (smax 28,672 compiled before this path grouped
+    heads, and still does with two sets of its banks)."""
     maxb, b, g = smax // 16, 8, 3
     item = jnp.dtype(dtype).itemsize
     assert ap.walk_heads_per_copy(nkv, smax, 128, g, item, item) == hg
@@ -811,8 +826,9 @@ def test_two_grain_server_programs_walk_once_and_copy_no_pool(
                            **{**conf["server"], "num_blocks": 80})
     s, nb = srv.slots, conf["server"]["num_blocks"]
     assert srv._paged_kernel == "fused" and srv._maxb == 50
-    # a table entry is copied once for all 32 heads
-    assert srv._walk_group() == (32, 32)
+    # a table entry is copied once for 16 of the 32 heads: two sets of
+    # 16 heads' banks are the bytes one set of 32 is
+    assert srv._walk_group() == (16, 32)
 
     def on_chip(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)

@@ -457,8 +457,21 @@ def test_the_bounded_walk_serves_both_grains(kernel):
     params = drv.make_params(cfg, 5)
     srv = ContinuousServer(params, cfg, slots=2, smax=64, block_size=4,
                            prefill_chunk=8, paged_kernel=kernel)
+    # what the rule gives for the toy's shapes: both heads in one grid
+    # step, so two steps a layer's call, the second copied during the
+    # first; the gather form has no bank to copy into
+    st = srv.hbm_read_stats()
+    fused = kernel == "fused"
+    assert st["heads_per_copy"] == (2 if fused else 0)
+    assert st["walk_bank_sets"] == (2 if fused else 0)
+    assert st["walk_steps_prefetched_share"] == (0.5 if fused else 0.0)
     prompt = _prompt(14, seed=1)
     rid = srv.submit(prompt, max_new=22)
+    while not srv.live_positions():
+        srv.step()
+    st = srv.hbm_read_stats()
+    assert st["walk_copies_per_slot"] == (
+        st["walk_entries_per_slot"] * 2 if fused else 0.0)
     out = srv.run()[rid]
     conf = _conf(num_pred_heads=2)
     best = np.asarray(ref.logits(params, conf, prompt + out[:-1]))[

@@ -415,12 +415,22 @@ def test_bounded_walk_on_a_ring_matches_gather(lap, dtype):
     _assert_close_to_oracle(af, ag, dtype)
 
 
-def _paged_grid(fn, *args):
-    """The grid of the one `pallas_call` in fn's jaxpr."""
+def _paged_launch(fn, *args):
+    """The one `pallas_call` in fn's jaxpr: (grid, the scratch refs'
+    avals, the chip's compiler parameters)."""
     calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
-    return tuple(calls[0].params["grid_mapping"].grid)
+    par = calls[0].params
+    gm = par["grid_mapping"]
+    scratch = [v.aval for v in
+               par["jaxpr"].invars[-gm.num_scratch_operands:]]
+    return tuple(gm.grid), scratch, par["compiler_params"]["mosaic_tpu"]
+
+
+def _paged_grid(fn, *args):
+    """The grid of the one `pallas_call` in fn's jaxpr."""
+    return _paged_launch(fn, *args)[0]
 
 
 def _walk_sizes(nkv, maxb, bs, hd, wg, dtype):
@@ -472,32 +482,37 @@ def test_which_calls_take_the_bounded_walk(kernel, pool, hd, w, window,
 
 
 # the cells' calls and the corners of the rule: (n_kv, table entries,
-# W * group, pool dtype) -> heads a copy. Block 16, head 128.
+# W * group, pool dtype, block) -> heads a copy, TWO sets of banks
+# counted (PR 50). Head 128.
 _HG_CASES = [
-    ((2, 128, 12, jnp.bfloat16), 2),      # StarCoder2-3B: 2 MB of banks
-    ((8, 304, 6, jnp.bfloat16), 8),       # Laguna's full layers: 20 MB
-    ((8, 34, 8, jnp.bfloat16), 8),        # Laguna's ring: 2.2 MB
-    ((1, 128, 24, jnp.bfloat16), 1),      # one kv head (tp = n_kv)
-    ((4, 304, 6, jnp.bfloat16), 4),       # Laguna's shard on tp = 2
-    ((8, 304, 6, jnp.float32), 8),        # float32 pools: 40 MB
-    ((8, 608, 6, jnp.float32), 4),        # and twice the rows
-    ((8, 1024, 6, jnp.bfloat16), 4),      # smax 16,384
-    ((6, 1024, 8, jnp.bfloat16), 3),      # a divisor, not a power of two
-    ((2, 1792, 12, jnp.bfloat16), 1),     # smax 28,672: one head fits
-    ((8, 1792, 6, jnp.bfloat16), 2),
-    ((8, 4096, 6, jnp.bfloat16), 1),      # nothing fits: still 1
+    ((2, 128, 12, jnp.bfloat16, 16), 2),  # StarCoder2-3B: 2 x 2 MB of banks
+    ((8, 304, 6, jnp.bfloat16, 16), 8),   # Laguna's full layers: 2 x 20 MB
+    ((8, 34, 8, jnp.bfloat16, 16), 8),    # Laguna's ring: 2 x 2.2 MB
+    ((1, 128, 24, jnp.bfloat16, 16), 1),  # one kv head (tp = n_kv)
+    ((4, 304, 6, jnp.bfloat16, 16), 4),   # Laguna's shard on tp = 2
+    ((8, 304, 6, jnp.float32, 16), 4),    # float32 pools: 2 x 40 MB is over
+    ((8, 608, 6, jnp.float32, 16), 2),    # and twice the rows
+    ((8, 1024, 6, jnp.bfloat16, 16), 2),  # smax 16,384
+    ((6, 1024, 8, jnp.bfloat16, 16), 2),  # a divisor, not the largest fit
+    ((2, 1792, 12, jnp.bfloat16, 16), 1),     # smax 28,672: one head fits
+    ((8, 1792, 6, jnp.bfloat16, 16), 1),
+    ((8, 4096, 6, jnp.bfloat16, 16), 1),  # nothing fits: still 1
+    # EvaByte: 32 kv heads over 50 entries of 64 rows; one set of 32
+    # heads' banks is 52 MB, so two sets hold 16 and a slot is two steps
+    ((32, 50, 1, jnp.bfloat16, 64), 16),
+    ((32, 50, 4, jnp.bfloat16, 64), 16),  # its verify window alike
 ]
 
 
 @pytest.mark.parametrize("shape,want", _HG_CASES, ids=[
-    f"nkv{c[0][0]}-maxb{c[0][1]}-{jnp.dtype(c[0][3]).name}"
+    f"nkv{c[0][0]}-maxb{c[0][1]}-wg{c[0][2]}-{jnp.dtype(c[0][3]).name}"
     for c in _HG_CASES])
 def test_heads_per_copy_follows_the_banks(shape, want):
-    """`hg` is the largest divisor of the call's n_kv whose banks and
-    one head's finish fit the stated VMEM budget, and 1 where none
-    does; the grid the launch pins is (B, n_kv // hg)."""
-    nkv, maxb, wg, dtype = shape
-    sizes = _walk_sizes(nkv, maxb, 16, _HD, wg, dtype)
+    """`hg` is the largest divisor of the call's n_kv whose two sets of
+    banks and one head's finish fit the stated VMEM budget, and 1 where
+    none does; the grid the launch pins is (B, n_kv // hg)."""
+    nkv, maxb, wg, dtype, bs = shape
+    sizes = _walk_sizes(nkv, maxb, bs, _HD, wg, dtype)
     hg = ap.walk_heads_per_copy(*sizes)
     assert hg == want and nkv % hg == 0
     assert hg == 1 or ap._walk_vmem_bytes(hg, *sizes[1:]) \
@@ -507,12 +522,49 @@ def test_heads_per_copy_follows_the_banks(shape, want):
             > ap._WALK_VMEM_BUDGET
     if maxb <= 304:
         q = jax.ShapeDtypeStruct((2, 1, nkv * wg, _HD), dtype)
-        pool = jax.ShapeDtypeStruct((5, nkv, 16, _HD), dtype)
+        pool = jax.ShapeDtypeStruct((5, nkv, bs, _HD), dtype)
         grid = _paged_grid(
             lambda q, kp, vp: ap.fused_paged_attention(
                 q, kp, vp, jnp.zeros((2, maxb), jnp.int32),
                 jnp.zeros((2,), jnp.int32), interpret=True), q, pool, pool)
         assert grid == (2, nkv // hg)
+
+
+def test_the_banks_are_counted_twice():
+    """`_walk_vmem_bytes` holds two sets of K and V banks and one
+    head's finish: a head more costs four banks."""
+    one, two = (ap._walk_vmem_bytes(hg, 3200, _HD, 8, 2, 2)
+                for hg in (1, 2))
+    assert two - one == 2 * 2 * 3200 * _HD * 2
+
+
+@pytest.mark.parametrize("nkv,maxb,bs,window,hg", [
+    (2, 8, 16, 0, 2), (8, 34, 16, 512, 8), (32, 50, 64, 0, 16)],
+    ids=["two-heads", "ring", "evabyte"])
+def test_the_launch_pins_two_sets_and_a_semaphore_pair_a_set(
+        nkv, maxb, bs, window, hg):
+    """What `_live_walk_call` hands the compiler: the grid (B, n_kv //
+    hg) as before, run IN ORDER (a step starts the next step's copies),
+    two sets of (hg, S, hd) banks a pool, DMA semaphores (set, pool):
+    no set shares a semaphore with the other, and the stated VMEM limit
+    covers both sets."""
+    B = 3
+    q = jax.ShapeDtypeStruct((B, 1, nkv, _HD), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((5, nkv, bs, _HD), jnp.bfloat16)
+    grid, scratch, params = _paged_launch(
+        lambda q, kp, vp: ap.fused_paged_attention(
+            q, kp, vp, jnp.zeros((B, maxb), jnp.int32),
+            jnp.zeros((B,), jnp.int32), interpret=True, window=window),
+        q, pool, pool)
+    assert grid == (B, nkv // hg)
+    k_s, v_s, sem = scratch
+    assert k_s.shape == v_s.shape == (2, hg, maxb * bs, _HD)
+    assert k_s.dtype == v_s.dtype == jnp.bfloat16
+    assert sem.shape == (2, 2) and "dma_sem" in str(sem.dtype)
+    assert "semaphore" in str(sem.memory_space)
+    assert tuple(params.dimension_semantics) == ("arbitrary", "arbitrary")
+    banks = k_s.size * 2 + v_s.size * 2
+    assert banks < params.vmem_limit_bytes < 128 << 20
 
 
 def _forced(monkeypatch, hg):
@@ -588,6 +640,100 @@ def test_a_bank_too_long_for_a_group_walks_one_head(dtype, monkeypatch):
     assert (single == grouped).all()
 
 
+# -- two sets of banks (PR 50): a grid step starts the NEXT step's copies ------
+#
+# Grid step n lands in set n % 2 and was started by step n - 1. A call
+# of ONE slot with every kv head in one group is one grid step: it
+# starts its own copies, waits, finishes, which is the kernel as it was
+# before the second set, op for op. So "the slot alone" is the bitwise
+# oracle here, and the gather form stays the ulp-tight one it always
+# was (this file's docstring: the final contraction differs).
+
+def _neighbours_case(dtype, w, nkv=4):
+    """Five slots whose walks differ as far as they can: position 0
+    beside a full table beside a one-entry slot, a ragged one, and a
+    second slot at position 0 at the call's end."""
+    bs, maxb, B = 16, 6, 5
+    kp, vp, table, _, q, kn, vn = _paged_state(
+        bs, maxb, B=B, nkv=nkv, nq=2 * nkv, hd=_HD, w=w, dtype=dtype,
+        seed=61)
+    pos = jnp.asarray([0, maxb * bs - w, bs - w, 2 * bs + 3, 0], jnp.int32)
+    return q, kp, vp, table, pos, kn, vn
+
+
+def _walk(q, kp, vp, table, pos, window):
+    return np.asarray(ap.fused_paged_attention(
+        q, kp, vp, table, pos, interpret=True, window=window), np.float32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
+@pytest.mark.parametrize("w", [1, 4])
+@pytest.mark.parametrize("hg", [1, 2, 4], ids=["hg1", "hg2", "hg-nkv"])
+def test_neighbours_that_differ_most_give_the_slot_alone(hg, w, window,
+                                                         dtype, monkeypatch):
+    """Every slot of the batch, at one head a grid step (four steps a
+    slot, each copied during the head before), two, and all four (one
+    step a slot, copied during the slot before): bit for bit the call
+    of that slot ALONE in one grid step, which starts, waits and
+    finishes its own copies as the kernel did before the second set.
+    A copy that landed in the set being read, or rows taken from the
+    set of the step before, would show in the short slot beside the
+    full one. And the batch agrees with the gather form as it always
+    has."""
+    q, kp, vp, table, pos, kn, vn = _neighbours_case(dtype, w)
+    _forced(monkeypatch, hg)
+    batch = _walk(q, kp, vp, table, pos, window)
+    assert np.isfinite(batch).all()
+    _forced(monkeypatch, 4)
+    for b in range(q.shape[0]):
+        alone = _walk(q[b:b + 1], kp, vp, table[b:b + 1], pos[b:b + 1],
+                      window)
+        assert (alone[0] == batch[b]).all(), b
+    if w > 1 and window:
+        return      # no oracle: a verify window over a ring is refused
+    attend = paged_decode_attention if w == 1 else paged_window_attention
+    _forced(monkeypatch, hg)
+    ag = attend(q, kn, vn, kp, vp, table, pos, window=window)[0]
+    af = attend(q, kn, vn, kp, vp, table, pos, window=window, fused=True,
+                interpret=True)[0]
+    _assert_close_to_oracle(af, ag, dtype)
+
+
+@pytest.mark.parametrize("order", [[4, 3, 2, 1, 0], [1, 0, 2, 4, 3],
+                                   [2, 4, 0, 3, 1]],
+                         ids=["reversed", "swapped", "shuffled"])
+@pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
+@pytest.mark.parametrize("hg", [1, 4, None], ids=["hg1", "hg-nkv", "rule"])
+def test_a_slots_rows_do_not_move_with_its_neighbours(hg, window, order,
+                                                      monkeypatch):
+    """The same five slots in another order: every slot's rows are the
+    same bits whichever slot ran before it (whose set it does not read)
+    and after it (whose copies it starts into the other set)."""
+    q, kp, vp, table, pos, _, _ = _neighbours_case(jnp.bfloat16, 1)
+    _forced(monkeypatch, hg)
+    base = _walk(q, kp, vp, table, pos, window)
+    o = np.asarray(order)
+    moved = _walk(q[o], kp, vp, table[o], pos[o], window)
+    assert (moved == base[o]).all()
+
+
+def test_a_call_of_one_grid_step_prefetches_nothing(monkeypatch):
+    """One slot, one group: the first step of a call is also its last,
+    so it starts its own copies and no other (the next step's trip
+    count is 0: no read past the table or the positions)."""
+    q, kp, vp, table, pos, _, _ = _neighbours_case(jnp.float32, 1)
+    _forced(monkeypatch, 4)
+    grid = _paged_grid(
+        lambda q, kp, vp: ap.fused_paged_attention(
+            q, kp, vp, table[1:2], pos[1:2], interpret=True),
+        q[1:2], kp, vp)
+    assert grid == (1, 1)
+    alone = _walk(q[1:2], kp, vp, table[1:2], pos[1:2], 0)
+    assert (alone[0] == _walk(q, kp, vp, table, pos, 0)[1]).all()
+
+
 def test_server_walk_share_follows_the_positions(params):
     """`hbm_read_stats()` says how far the next step's bounded walk
     goes: per live slot p // block + 1 entries of the table's width."""
@@ -596,6 +742,9 @@ def test_server_walk_share_follows_the_positions(params):
                            paged_kernel="fused", block_size=8)
     st = srv.hbm_read_stats()
     assert st["walk_share"] == 0.0 == st["walk_entries_per_slot"]
+    # a head of 8 keeps the grid walk: no set of banks, nothing ahead
+    assert st["walk_bank_sets"] == 0
+    assert st["walk_steps_prefetched_share"] == 0.0
     for r in REQS[:3]:
         srv.submit(**r)
     seen = 0
@@ -628,7 +777,9 @@ def test_server_counts_the_heads_a_copy_carries(kernel, kv_dtype, hd, nkv,
     """`hbm_read_stats()` carries the group of the full group's decode
     call and the copies a slot, layer and step issues (entries x 2
     pools x n_kv / hg), 0 where the server's calls keep the grid walk;
-    the registry carries both."""
+    the sets of banks those copies land in (2, or 0) and the share of a
+    call's G = slots x n_kv / hg grid steps whose copies the step
+    before started, (G - 1) / G; the registry carries all four."""
     from hpx_tpu.svc import performance_counters as pc
     cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=4,
                                 n_kv_heads=nkv, head_dim=hd, n_layers=2,
@@ -639,6 +790,8 @@ def test_server_counts_the_heads_a_copy_carries(kernel, kv_dtype, hd, nkv,
     st = srv.hbm_read_stats()
     assert st["heads_per_copy"] == want
     assert st["walk_copies_per_slot"] == 0.0
+    steps = 3 * nkv // want if want else 0     # G of a layer's call
+    ahead = (steps - 1) / steps if want else 0.0
     for r in REQS[:3]:
         srv.submit(**r)
     seen = 0
@@ -649,9 +802,14 @@ def test_server_counts_the_heads_a_copy_carries(kernel, kv_dtype, hd, nkv,
         assert st["heads_per_copy"] == want
         assert st["walk_copies_per_slot"] == pytest.approx(
             st["walk_entries_per_slot"] * 2 * nkv / want if want else 0.0)
+        assert st["walk_bank_sets"] == (2 if want else 0)
+        assert st["walk_steps_prefetched_share"] == pytest.approx(ahead)
         for name, key in (("count/heads-per-copy", "heads_per_copy"),
                           ("count/walk-copies-per-slot",
-                           "walk_copies_per_slot")):
+                           "walk_copies_per_slot"),
+                          ("count/walk-bank-sets", "walk_bank_sets"),
+                          ("walk-steps-prefetched-share",
+                           "walk_steps_prefetched_share")):
             assert pc.query_counter(pc.counter_name(
                 "cache", name, srv.counter_instance)).value == st[key]
         seen += 1

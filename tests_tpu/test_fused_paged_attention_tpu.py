@@ -16,7 +16,11 @@ landed before the banks are read, and that the rows of the VMEM banks
 the PREVIOUS grid step left behind a short walk stay out of the
 result. Since PR 35 a grid step owns a GROUP of kv heads and copies a
 table entry once for all of them (`TestHeadGroups`: every group size
-bit for bit one head's result, at both serving cells' shapes)."""
+bit for bit one head's result, at both serving cells' shapes). Since
+PR 50 a grid step starts the NEXT step's copies into a second set of
+banks before it waits for its own (`TestTwoSets`: only the chip runs
+the copies beside the finish, so only here can a copy that lands in
+the set being read, or a wait on the other set's semaphore, show)."""
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +46,24 @@ def _table(b, maxb, nb, seed=1):
     rng = np.random.default_rng(seed)
     ids = rng.permutation(nb)[:b * maxb].reshape(b, maxb)
     return jnp.asarray(ids, jnp.int32)
+
+
+def _gather_oracle(q, kp, vp, table, pos, nkv):
+    """Attention over the gathered rows of the same pools (nothing
+    written this step): q [B, W, n_q, hd], window row w sees rows <=
+    pos + w."""
+    from hpx_tpu.ops.paged_attention import gather_block_kv
+    b, w, nq, hd = q.shape
+    kc = gather_block_kv(kp, table, None, q.dtype)
+    vc = gather_block_kv(vp, table, None, q.dtype)
+    qg = q.reshape(b, w, nkv, nq // nkv, hd)
+    s = (jnp.einsum("bqngh,bknh->bngqk", qg, kc)
+         / np.sqrt(hd)).astype(jnp.float32)
+    live = (jnp.arange(kc.shape[1])[None, None, :]
+            <= pos[:, None, None] + jnp.arange(w)[None, :, None])
+    s = jnp.where(live[:, None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(q.shape)
 
 
 def _close(a, b, tol):
@@ -258,7 +280,7 @@ class TestHeadGroups:
         (8, 48, 304, 0, 1, jnp.bfloat16, 8),       # Laguna's full layers
         (8, 64, 34, 512, 1, jnp.bfloat16, 8),      # Laguna's ring
         (8, 48, 304, 0, 4, jnp.bfloat16, 8),       # a verify window
-        (8, 16, 304, 0, 1, jnp.float32, 8),        # 8 KB a head and entry
+        (8, 16, 304, 0, 1, jnp.float32, 4),        # 8 KB a head and entry
         (6, 12, 64, 0, 1, jnp.bfloat16, 6),        # no power of two
     ], ids=["sc2-3b", "laguna-full", "laguna-window", "laguna-full-w4",
             "f32", "nkv6"])
@@ -269,7 +291,6 @@ class TestHeadGroups:
         gives (PR 31's kernel), which stays as close to the gather
         oracle as it was."""
         from hpx_tpu.ops import attention_pallas as ap
-        from hpx_tpu.ops.paged_attention import gather_block_kv
         q, kp, vp, table, pos = self._case(nkv, nq, maxb, w, dtype, 40)
         item = jnp.dtype(dtype).itemsize
         assert ap.walk_heads_per_copy(nkv, maxb * 16, 128, w * nq // nkv,
@@ -291,21 +312,9 @@ class TestHeadGroups:
             for _ in range(2):      # a race would not show every time
                 assert (run(force) == one).all(), force
         if not window:
-            # the oracle over the same pools (nothing written this step)
-            g = nq // nkv
-            kc = gather_block_kv(kp, table, None, q.dtype)
-            vc = gather_block_kv(vp, table, None, q.dtype)
-            qg = q.reshape(8, w, nkv, g, 128)
-            s = (jnp.einsum("bqngh,bknh->bngqk", qg, kc)
-                 / np.sqrt(128)).astype(jnp.float32)
-            live = (jnp.arange(kc.shape[1])[None, None, :]
-                    <= pos[:, None, None] + jnp.arange(w)[None, :, None])
-            s = jnp.where(live[:, None, None], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-            want = jnp.einsum("bngqk,bknh->bqngh", p, vc).reshape(q.shape)
             # float32 too: at the default precision the chip multiplies
             # the oracle's einsums in bfloat16 passes
-            _close(one, want, 3e-2)
+            _close(one, _gather_oracle(q, kp, vp, table, pos, nkv), 3e-2)
 
     @pytest.mark.parametrize("head", [0, 5, 7])
     @pytest.mark.parametrize("window", [0, 40], ids=["full", "ring"])
@@ -343,3 +352,49 @@ class TestHeadGroups:
         assert np.isnan(bad[mine]).all()     # the poison was in the bank
         assert np.isfinite(bad[~mine]).all()
         assert (bad[~mine] == good[~mine]).all()
+
+
+class TestTwoSets:
+    """What only the chip can show of the two sets of banks (PR 50):
+    the next grid step's copies land WHILE this step's finish reads its
+    own set, each set on its own pair of semaphores."""
+
+    @pytest.mark.parametrize("nkv,nq,maxb,bs,window,hg", [
+        (32, 32, 50, 64, 0, 16),       # evabyte.doc-closed: 2 steps a slot
+        (8, 48, 304, 16, 0, 8),        # Laguna's full layers: 1 a slot
+        (8, 64, 34, 16, 512, 8),       # Laguna's ring
+        (2, 24, 128, 16, 0, 2),        # sc2-3b.gen-closed
+    ], ids=["evabyte", "laguna-full", "laguna-window", "sc2-3b"])
+    def test_neighbours_do_not_move_a_slots_rows(self, nkv, nq, maxb, bs,
+                                                 window, hg):
+        """Slots whose walks differ as far as they can (position 0, a
+        full table, one entry, ragged ones between) in three orders:
+        a slot's rows are bit for bit the same whatever ran before and
+        after it, run after run, and stay as close to the gather oracle
+        as they were."""
+        from hpx_tpu.ops import attention_pallas as ap
+        B, hd = 6, 128
+        nb = B * maxb + 1
+        kp, vp = _pools(nb, bs, nkv, hd, seed=60)
+        table = _table(B, maxb, nb, seed=61)
+        top = maxb * bs - 1
+        pos = jnp.asarray([0, top, bs - 1, top // 2, bs, top - bs],
+                          jnp.int32)
+        rng = np.random.default_rng(62)
+        q = jnp.asarray(rng.standard_normal((B, 1, nq, hd), np.float32),
+                        jnp.bfloat16)
+        assert ap.walk_heads_per_copy(nkv, maxb * bs, hd, nq // nkv,
+                                      2, 2) == hg
+        f = jax.jit(lambda q, kp, vp, table, pos: ap.fused_paged_attention(
+            q, kp, vp, table, pos, window=window))
+        one = np.asarray(f(q, kp, vp, table, pos), np.float32)
+        assert np.isfinite(one).all()
+        for order in ([5, 4, 3, 2, 1, 0], [1, 0, 2, 5, 3, 4],
+                      [0, 1, 2, 3, 4, 5]):
+            o = np.asarray(order)
+            for _ in range(2):      # a race would not show every time
+                got = np.asarray(f(q[o], kp, vp, table[o], pos[o]),
+                                 np.float32)
+                assert (got == one[o]).all(), order
+        if not window:
+            _close(one, _gather_oracle(q, kp, vp, table, pos, nkv), 3e-2)
