@@ -8,7 +8,17 @@ Two questions a cell cannot split, one line of JSON a reading:
    1,576 entries) and under Kimi-Linear's 32 (48 slots, 264 entries).
    With `--entries N[,N...]` at other sizes of a fold
    (`LATENT_WALK_ENTRIES` table entries a buffer), with `--block 64`
-   over pools of another block size (the same rows).
+   over pools of another block size (the same rows), with `--run
+   N[,N...]` at other sizes of a coalesced copy (`LATENT_RUN` table
+   entries ONE descriptor carries where they name neighbours; 1 = a
+   copy an entry, the kernel up to PR 50), and with `--table` over
+   tables of another shape: `run` (every slot's blocks consecutive,
+   the default), `shuffled` (a permutation of the pool: no group is a
+   run) or `mixed:<pct>` (<pct> % of the aligned groups of 32 entries
+   stay where `run` has them, the other entries are shuffled among
+   themselves); `run_pct` is the share of the live entries that a
+   coalesced copy carried (a slot's first and last folds are single
+   copies whatever its table holds).
 2. A prefill chunk of W queries over `--rows` cached rows: the ABSORBED
    form the program keeps (`transformer._latent_attention`: every head
    scores the 640-wide row and weighs its first 512 columns) against
@@ -30,7 +40,8 @@ inside ONE jitted loop, the slope over two `n`. Exits non-zero without
 a TPU.
 
 Usage: python benchmarks/mla_forms.py [--kernel] [--chunk]
-           [--entries 16,32,64] [--block 16]
+           [--entries 16,32,64] [--block 16] [--run 1,4,8,32]
+           [--table run|shuffled|mixed:<pct>]
            [--positions 1535,15231] [--rows 15360] [--widths 128,256]
            [--pairs 0,4096,8192,16384,32768] [--heads 128]
 """
@@ -68,7 +79,29 @@ def device_us(jax, step, x, operands, samples=3):
     return pers[(samples - 1) // 2] * 1e6
 
 
-def kernel_lines(jax, jnp, entries, block=BS, positions=None):
+def make_table(kind: str, b: int, maxb: int):
+    """The [b, maxb] table of `--table kind` over a pool of b * maxb + 1
+    blocks (block 0 is no slot's), the same at every call."""
+    import numpy as np
+    table = 1 + np.arange(b * maxb, dtype=np.int32)
+    rng = np.random.default_rng(0)
+    if kind == "shuffled":
+        table = rng.permutation(table)
+    elif kind.startswith("mixed:"):
+        grain = 32                      # a fold of the kept size, a row's
+        first = (np.arange(b)[:, None] * maxb
+                 + np.arange(maxb // grain)[None] * grain).ravel()
+        moved = rng.permutation(first)[
+            :round(len(first) * (1 - float(kind[6:]) / 100))]
+        at = (moved[:, None] + np.arange(grain)).ravel()
+        table[at] = rng.permutation(table[at])
+    elif kind != "run":
+        raise SystemExit(f"mla_forms: unknown --table {kind!r}")
+    return table.reshape(b, maxb)
+
+
+def kernel_lines(jax, jnp, entries, block=BS, positions=None, runs=(),
+                 kind="run"):
     from hpx_tpu.ops import attention_pallas as ap
     for name, (b, h, maxb16, own) in SHAPES.items():
         maxb = maxb16 * BS // block
@@ -77,9 +110,12 @@ def kernel_lines(jax, jnp, entries, block=BS, positions=None):
         pool = jax.random.normal(ks[0], (nb, 1, block, ROW), jnp.bfloat16)
         q = (jax.random.normal(ks[1], (b, h, ROW)) * 0.3).astype(
             jnp.bfloat16)
-        table = (1 + jnp.arange(b * maxb, dtype=jnp.int32)).reshape(b, maxb)
-        for fold in entries:
-            ap.LATENT_WALK_ENTRIES = fold
+        table_np = make_table(kind, b, maxb)
+        table = jnp.asarray(table_np)
+        for fold, run in ((f, r) for f in entries
+                          for r in runs or (ap.LATENT_RUN,)):
+            ap.LATENT_WALK_ENTRIES, ap.LATENT_RUN = fold, run
+            fold, run = ap.latent_walk_sizes(maxb)  # what the launch takes
             for p in positions or own:
                 if p >= maxb * block:
                     continue
@@ -91,10 +127,16 @@ def kernel_lines(jax, jnp, entries, block=BS, positions=None):
                     return jnp.pad(o, ((0, 0), (0, 0), (0, ROW - RANK)))
                 us = device_us(jax, step, q, (pool, table, pos))
                 rows = b * (p + 1)
+                live = p // block + 1
                 print(json.dumps({
                     "what": "hpx_mla_paged", "shape": name, "slots": b,
                     "heads": h, "position": p, "block_size": block,
-                    "fold_entries": fold, "us_per_call": round(us, 1),
+                    "fold_entries": fold, "run_entries": run,
+                    "table": kind, "run_pct": round(
+                        100 * ap.latent_entries_coalesced(
+                            table_np, live, fold, run).sum()
+                        / (b * live), 1),
+                    "us_per_call": round(us, 1),
                     "live_gb_per_s": round(rows * 1152 / us / 1e3, 1),
                     "live_tflop_per_s": round(
                         rows * h * 2 * 1088 / us / 1e6, 1)}), flush=True)
@@ -203,6 +245,11 @@ def main() -> int:
     ap_.add_argument("--chunk", action="store_true")
     ap_.add_argument("--entries", default="32")
     ap_.add_argument("--block", type=int, default=BS)
+    ap_.add_argument("--run", default="",
+                     help="entries a coalesced copy carries; 1 = a copy "
+                          "an entry (default: the kernel's constant)")
+    ap_.add_argument("--table", default="run",
+                     help="run | shuffled | mixed:<pct>")
     ap_.add_argument("--positions", default="")
     ap_.add_argument("--rows", type=int, default=15360)
     ap_.add_argument("--widths", default="128,256")
@@ -224,7 +271,9 @@ def main() -> int:
     if args.kernel or both:
         kernel_lines(jax, jnp, [int(e) for e in args.entries.split(",")],
                      args.block,
-                     [int(p) for p in args.positions.split(",") if p])
+                     [int(p) for p in args.positions.split(",") if p],
+                     [int(r) for r in args.run.split(",") if r],
+                     args.table)
     if args.chunk or both:
         from hpx_tpu.models.transformer import LATENT_PAIRS_A_GROUP
         chunk_lines(jax, jnp, args.rows,

@@ -132,6 +132,12 @@ read)::
     /cache{locality#L/server#i}/latent/blocks-in-use    blocks of latent rows held
     /cache{locality#L/server#i}/latent/rows-walked      rows a decode step's latent
                                                         walks read, a latent layer
+    /cache{locality#L/server#i}/latent/run-pct          of the table entries those
+                                                        walks cover, the share copied
+                                                        with their group's neighbours
+                                                        in one descriptor
+    /cache{locality#L/server#i}/latent/entries-walked   the decode steps' so far, ...
+    /cache{locality#L/server#i}/latent/entries-coalesced ... and those so copied
     /serving{locality#L/server#i}/state/prefix-refused  admissions whose prefix match
                                                         was refused (no state snapshot)
     /serving{locality#L/server#i}/state/reprefills      restores that recomputed a state
@@ -423,6 +429,18 @@ def register_server(srv) -> str:
         put("cache", "latent/rows-walked",
             pc.CallbackCounter(_read(ref, lambda s: s.cache_stats()
                                ["latent_rows_walked_per_step"])))
+        # of the table entries the next step's latent walks cover, the
+        # share the kernel copies with their group's neighbours in one
+        # descriptor; and the entries walked / so copied so far
+        put("cache", "latent/run-pct",
+            pc.CallbackCounter(_read(ref, lambda s: s.hbm_read_stats()
+                               ["latent_run_pct"])))
+        put("cache", "latent/entries-walked",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._latent_walked)))
+        put("cache", "latent/entries-coalesced",
+            pc.CallbackCounter(_read(
+                ref, lambda s: s._latent_coalesced)))
     if "sparse" in getattr(srv.cfg, "layer_mixer", ()):
         # the index of compressed keys beside a sparse layer's K/V
         # pools, and what the decode steps' selections read

@@ -105,12 +105,15 @@ from ..cache.radix import RadixCache
 from ..core.errors import Error, HpxError
 from ..svc import faultinject, flight, progprof, tracing
 from ..svc.resiliency import sync_replay
-from ..ops.attention_pallas import (resolve_paged_block,
+from ..ops.attention_pallas import (latent_groups_coalesced,
+                                     latent_walk_sizes,
+                                     resolve_paged_block,
                                      walk_heads_per_copy)
 from ..ops.kda import kda_mix
 from ..ops.paged_attention import (
     block_rows,
     gather_block_kv,
+    latent_takes_kernel,
     paged_decode_attention,
     paged_latent_attention,
     paged_window_attention,
@@ -1348,6 +1351,13 @@ class ContinuousServer:
         # over what they had behind them (`_eva_account`)
         self._eva_rolls = self._eva_freed = self._eva_pooled = 0
         self._eva_attended = self._eva_behind = 0
+        # the table entries the decode steps' latent walks covered, a
+        # latent layer each, and those of them ONE copy carried with
+        # its group's neighbours (`_latent_entries`); a slot's running
+        # count of coalesced groups, by (table uid, version, run)
+        self._latent_walked = self._latent_coalesced = 0
+        self._run_cum: Dict[int, Tuple[Tuple[int, int, int],
+                                       np.ndarray]] = {}
         for what, on in (("a quantized hpx.cache.kv_dtype (a quantized "
                           "latent row, a sparse layer's quantized page "
                           "or a summary pooled from quantized rows has "
@@ -2375,6 +2385,12 @@ class ContinuousServer:
             st["latent_rows_walked_per_step"] = sum(
                 self._pos[s_] + 1 for s_ in range(self.slots)
                 if self._slot_req[s_] is not None)
+            # the table entries the decode steps' latent walks have
+            # covered so far, a latent layer each, and those of them
+            # that lay in a coalesced copy (`latent_run_pct` of a span
+            # of steps: the growth of the second over the first's)
+            st["latent_entries_walked"] = self._latent_walked
+            st["latent_entries_coalesced"] = self._latent_coalesced
         st.update(self.hbm_read_stats())
         st.update(self.prefill_stats())
         if self.mesh is not None:
@@ -2422,6 +2438,40 @@ class ContinuousServer:
             nkv, self._maxb * self.block_size, cfg.head_dim,
             cfg.heads(full[0]) // cfg.kv_heads, item, item), nkv
 
+    def _latent_entries(self, positions: Dict[int, int]
+                        ) -> Tuple[int, int]:
+        """(walked, coalesced): the table entries one latent layer's
+        walk of a decode step at `positions` ({slot: position}) covers,
+        and those of them `hpx_mla_paged` copies with their aligned
+        group's neighbours in ONE descriptor (`attention_pallas.
+        latent_groups_coalesced`, the kernel's own rule, over the
+        slots' host tables: a slot's groups are read again only when
+        its table has mutated). (0, 0) where no latent layer takes the
+        kernel (none, the gather form, a rank that is no whole lanes);
+        coalesced 0 where a fold is no whole number of groups. A slot's
+        FIRST and LAST folds are single copies whatever its table
+        holds."""
+        if "mla" not in self._kinds or not latent_takes_kernel(
+                self._paged_fused, self.cfg.mla_rank):
+            return 0, 0
+        fold, run = latent_walk_sizes(self._maxb)
+        walked = coalesced = 0
+        for s, p in positions.items():
+            pt = self._tables[s]
+            n = min(p // self.block_size + 1, self._maxb)
+            walked += n
+            key = (pt.uid, pt.version, run)
+            hit = self._run_cum.get(s)
+            if hit is None or hit[0] != key:
+                hit = self._run_cum[s] = (key, np.concatenate((
+                    [0], np.cumsum(latent_groups_coalesced(pt.blocks, run)))))
+            cum = hit[1]
+            # the groups of the folds between the slot's first and last
+            ahead = min((-(-n // fold) - 1) * fold // run, len(cum) - 1)
+            first = min(fold // run, ahead)
+            coalesced += run * int(cum[ahead] - cum[first])
+        return walked, coalesced
+
     def _walk_row(self, pos):
         """The row of the slot's table the walk of a step at `pos`
         ends at: `pos`, or its row in a two-grain run."""
@@ -2452,10 +2502,20 @@ class ContinuousServer:
         (`walk_bank_sets` 2, 0 on the grid walk;
         `walk_steps_prefetched_share` = (G - 1) / G, G = slots x n_kv /
         `heads_per_copy` of the call: the grid steps of a layer's call
-        whose copies were in flight during the step before),
-        every other fused call visits all max_blocks entries, whose
-        tail aliases the single resident trash block — occupancy is
-        the honest per-slot traffic either way. bytes/token uses
+        whose copies were in flight during the step before). The
+        latent walk (`hpx_mla_paged`) copies an aligned group of
+        `attention_pallas.LATENT_RUN` table entries that name
+        neighbours in ONE descriptor: `latent_run_pct` is the share of
+        the entries the next step's latent walks cover that lie in such
+        a group (`_latent_entries`; what the SHAPE of the live tables
+        gives the kernel: high for long prompts allocated in one loop
+        on a fresh free list, a slot's first and last folds apart,
+        which are never coalesced; less as slots grow side by side or
+        a churned list hands out ids out of order; 0 where no latent
+        layer takes the kernel). Every other fused call visits all max_blocks
+        entries, whose tail aliases the single resident trash block —
+        occupancy is the honest per-slot traffic either way.
+        bytes/token uses
         `cache.block_allocator.block_bytes`, so the int8/fp8 sidecar
         scales are included: vs a bf16 compute dtype the quantized
         pools read ~0.5x, and vs tier-1's f32 compute dtype ~0.25x —
@@ -2472,10 +2532,12 @@ class ContinuousServer:
         # layer has none)
         bb += kinds.count("mla") * self.block_size * self.cfg.mla_row \
             * jnp.dtype(self.cfg.dtype).itemsize
+        positions = self.live_positions()
         walks = [min(self._walk_row(p) // self.block_size + 1, self._maxb)
-                 for p in self.live_positions().values()]
+                 for p in positions.values()]
         walk = sum(walks) / len(walks) if walks else 0.0
         hg, nkv = self._walk_group()
+        lat_walked, lat_coalesced = self._latent_entries(positions)
         # grid steps of one layer's call (the shard's under a mesh)
         dp = self.mesh.shape["dp"] if self.mesh is not None else 1
         grid = self.slots // dp * nkv // hg if hg else 0
@@ -2494,6 +2556,12 @@ class ContinuousServer:
             # call's grid steps whose copies the step before started
             "walk_bank_sets": 2 if hg else 0,
             "walk_steps_prefetched_share": (grid - 1) / grid if hg else 0.0,
+            # of the table entries the next decode step's latent walks
+            # cover, the share `hpx_mla_paged` copies with their
+            # group's neighbours in one descriptor (0 where no latent
+            # layer takes the kernel): what the tables' SHAPE gives it
+            "latent_run_pct": 100.0 * lat_coalesced / lat_walked
+            if lat_walked else 0.0,
             # where this server's block_size came from: arg | config |
             # env | seed (paged_blocks.json) | default
             "block_size_source": self._block_size_src,
@@ -3895,7 +3963,8 @@ class ContinuousServer:
             self._acct.end(
                 self._step_n, self.slots - self._slot_req.count(None),
                 self._admits, self._chunks, self._prog_misses,
-                self._reads_draining, self._reads_overlapped)
+                self._reads_draining, self._reads_overlapped,
+                self._latent_walked, self._latent_coalesced)
 
     def _step_span(self) -> bool:
         """step()'s body, inside its `serving.step` span."""
@@ -3974,6 +4043,11 @@ class ContinuousServer:
                 self._sparse_account(pos[live])
             if self._eva:
                 self._eva_account(pos[live])
+            if "mla" in self._kinds:
+                walked, coalesced = self._latent_entries(
+                    self.live_positions())
+                self._latent_walked += walked
+                self._latent_coalesced += coalesced
             self._cur_dev = nxt
             self._rate.mark(float(len(live)))
             lanes = []

@@ -1442,26 +1442,90 @@ def _live_walk_call(qk, k_pool, v_pool, table, pos0, *, w: int,
 # rows = 512 rows a fold (a 640-wide bfloat16 buffer is 655 KB)
 LATENT_WALK_ENTRIES = 32
 
+# table entries ONE copy of the latent walk carries where the table
+# names neighbours: a whole fold. An aligned group of 32 entries t,
+# t + 1, .., t + 31 is 512 rows = 655 KB of contiguous pool, one
+# descriptor where it was 32 of 20 KB. Swept on the chip (PERF.md PR
+# 51, device clock, 64 slots x 128 heads at position 15,231): groups of
+# 4 / 8 / 16 / 32 read 3,658 / 3,601 / 2,861 / 2,880 us a call over
+# tables that are runs where a copy an entry reads 3,667: eight or four
+# compare-and-branches a fold break the straight-line issue of its
+# copies and cost what the descriptors they save cost; two or one
+# pay. Kept: the fold itself. 1 = every entry its own copy
+LATENT_RUN = 32
+
+
+def latent_walk_sizes(maxb: int) -> Tuple[int, int]:
+    """(fold, run) of `hpx_mla_paged` over a table `maxb` wide: the
+    entries a buffer holds, and the entries a coalesced copy carries:
+    `LATENT_RUN` where a fold is whole groups of it, else 1 (every
+    entry its own copy). The one place the kernel's launch and the
+    host's `latent_run_pct` ask."""
+    fold = min(LATENT_WALK_ENTRIES, maxb)
+    return fold, LATENT_RUN if fold % LATENT_RUN == 0 else 1
+
+
+def latent_groups_coalesced(table, run: int):
+    """Which aligned groups of `run` entries of each row of a `[..,
+    maxb]` table `hpx_mla_paged` copies in ONE descriptor where their
+    fold lies between a slot's first and last: those whose ids are t,
+    t + 1, .., t + run - 1 (none at `run` 1: every entry is then its
+    own copy). Host NumPy, bool `[.., maxb // run]`: the kernel's own
+    rule over a table's groups, whatever their fold; the feed of
+    `latent_run_pct`."""
+    import numpy as np
+    table = np.array(table)             # host ids, never a device array
+    groups = table.shape[-1] // run
+    ids = table[..., :groups * run].reshape(table.shape[:-1] + (groups, run))
+    return (np.diff(ids, axis=-1) == 1).all(-1) & (run > 1)
+
+
+def latent_entries_coalesced(table, n_live, fold: int, run: int):
+    """Of the first `n_live` entries of each row of a table, how many
+    lie in a coalesced copy: `run` for every group of
+    `latent_groups_coalesced` in the folds BETWEEN the slot's first
+    and its last (those two are single copies as they ever were,
+    whatever the table holds)."""
+    import numpy as np
+    runs = latent_groups_coalesced(table, run)
+    ahead = (-(-np.array(n_live) // fold) - 1) * fold
+    at = np.arange(runs.shape[-1]) * run
+    return run * (runs & (at >= fold) & (at < ahead[..., None])).sum(-1)
+
 
 def _latent_kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, bank,
                    acc_s, m_s, l_s, sem, *, block_size: int, nblk: int,
-                   chunk: int, rank: int, scale: float):
+                   chunk: int, run: int, rank: int, scale: float):
     """One slot's absorbed latent attention (MLA decode): every query
     head over ONE cached head of latent rows whose value is its own
     first `rank` columns. q_ref (H, R), o_ref (H, rank); pool_hbm: the
     latent pool [num_blocks, 1, block_size, R], left in HBM.
 
     The slot's live table entries (`_walk_entries`: none past `pos`)
-    are walked `chunk` at a time through TWO buffers, `bank` (2, chunk
-    * block_size, R): while one buffer's rows are scored and folded
-    into the running softmax (`acc_s` (H, rank), `m_s`, `l_s`, float32:
-    the flash carry), the next `chunk` entries land in the other. VMEM
+    are walked `chunk` at a time through TWO buffers, `bank` (2, chunk,
+    block_size, R): while one buffer's rows are scored and folded into
+    the running softmax (`acc_s` (H, rank), `m_s`, `l_s`, float32: the
+    flash carry), the next `chunk` entries land in the other. VMEM
     holds two buffers and the carry whatever the table's width, and no
-    row past the last live entry is copied, scored or weighed. A
-    buffer's copies signal that buffer's OWN semaphore, and a buffer is
-    read only after every wait of its copies has returned (a DMA
-    semaphore counts bytes landed from any copy that signals it). Only
-    the LAST fold has rows past `pos` (the tail of the last live block,
+    row past the last live entry is copied, scored or weighed.
+
+    The whole folds BETWEEN a slot's first and its last (the ones whose
+    copies land while the fold before is scored) are taken in aligned
+    GROUPS of `run`: a group whose entries are t, t + 1, .., t + run - 1
+    (read off the scalar-prefetched table: `run` - 1 compares and one
+    branch) is ONE copy of `run` consecutive pool blocks, any other
+    group `run` copies of one block each; the same rows at the same
+    buffer offsets either way, so the output does not depend on which
+    was taken. The slot's FIRST fold (nothing runs beside its copies:
+    they go out at once) and its LAST (a count of the slot's own) are
+    an entry a copy, from a loop, as they ever were.
+
+    A buffer's copies signal that buffer's OWN semaphore, and a buffer
+    is read only after every wait of its copies has returned. A DMA
+    semaphore counts bytes landed from any copy that signals it, so
+    the waits do not ask how a group was started: one wait of a
+    group's bytes a group, one of a block's a single entry. Only the
+    LAST fold has rows past `pos` (the tail of the last live block,
     and what the buffer held before): there the scores are masked and
     the value rows selected to zero, because 0 x NaN is NaN."""
     b = pl.program_id(0)
@@ -1471,47 +1535,75 @@ def _latent_kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, bank,
     tail = n_live - (n_fold - 1) * chunk        # the last fold's entries
     rows = chunk * block_size
 
-    def copy(c, buf, j):
-        at = pl.ds(pl.multiple_of(j * block_size, block_size), block_size)
+    def single(buf, j, block=0):
+        """Entry j of a buffer alone, from pool block `block`."""
         return pltpu.make_async_copy(
-            pool_hbm.at[table_ref[b, c * chunk + j], 0],
-            bank.at[buf, at, :], sem.at[buf])
+            pool_hbm.at[block, 0], bank.at[buf, j], sem.at[buf])
 
-    def each(c, buf, count, what):
-        """`what` of fold c's first `count` copies: straight-line code
-        where the count is static (a whole fold: on the chip 20% under
-        the same descriptors issued from a loop, PERF.md PR 37), a loop
-        where it is the slot's own (the last fold's)."""
+    def group(buf, g, block=0):
+        """Group g of a buffer in one copy: `run` pool blocks from
+        `block` on."""
+        return pltpu.make_async_copy(
+            pool_hbm.at[pl.ds(block, run), 0],
+            bank.at[buf, pl.ds(g * run, run)], sem.at[buf])
+
+    def start_group(c, buf, g):
+        ids = [table_ref[b, c * chunk + g * run + k] for k in range(run)]
+        if run == 1:
+            single(buf, g, ids[0]).start()
+            return
+        neighbours = functools.reduce(
+            jnp.logical_and, [ids[k] == ids[0] + k for k in range(1, run)])
+
+        @pl.when(neighbours)
+        def _():
+            group(buf, g, ids[0]).start()
+
+        @pl.when(jnp.logical_not(neighbours))
+        def _():
+            for k in range(run):
+                single(buf, g * run + k, ids[k]).start()
+
+    def each(c, buf, count, start: bool):
+        """Start, or wait for, fold c's first `count` entries. A whole
+        fold (a static count) in groups, straight-line code (on the
+        chip 20% under the same descriptors issued from a loop,
+        PERF.md PR 37); a count of the slot's own (its first fold's
+        start, its last fold's start and wait) an entry a copy, from
+        a loop."""
         if isinstance(count, int):
-            for j in range(count):
-                what(copy(c, buf, j))
+            assert count % run == 0
+            for g in range(count // run):
+                if start:
+                    start_group(c, buf, g)
+                else:
+                    group(buf, g).wait()
             return
 
         def body(j, carry):
-            what(copy(c, buf, j))
+            if start:
+                single(buf, j, table_ref[b, c * chunk + j]).start()
+            else:
+                single(buf, j).wait()
             return carry
         jax.lax.fori_loop(0, count, body, 0)
-
-    def start(cp):
-        cp.start()
-
-    def wait(cp):
-        cp.wait()
 
     acc_s[...] = jnp.zeros_like(acc_s)
     m_s[...] = jnp.full_like(m_s, _NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
     q = q_ref[...]
-    each(0, 0, jnp.minimum(chunk, n_live), start)
+    # nothing runs beside the slot's first fold: its copies go out at
+    # once, an entry a copy, whatever the table holds
+    each(0, 0, jnp.minimum(chunk, n_live), True)
 
     def fold(c, nxt, count, last: bool):
         """Fold c: start the `nxt` entries of fold c + 1 into the other
         buffer, wait for this fold's `count`, score and fold them in."""
         buf = c % 2
         if nxt is not None:
-            each(c + 1, 1 - buf, nxt, start)
-        each(c, buf, count, wait)
-        lat = bank[buf]
+            each(c + 1, 1 - buf, nxt, True)
+        each(c, buf, count, False)
+        lat = bank[buf].reshape(rows, bank.shape[-1])
         s = jax.lax.dot_general(
             q, lat.astype(q.dtype), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # (H, rows)
@@ -1582,10 +1674,10 @@ def fused_latent_attention(q: jax.Array, pool: jax.Array,
     b, h, r = q.shape
     bs = pool.shape[2]
     maxb = table.shape[1]
-    chunk = min(LATENT_WALK_ENTRIES, maxb)
+    chunk, run = latent_walk_sizes(maxb)
     return pl.pallas_call(
         functools.partial(_latent_kernel, block_size=bs, nblk=maxb,
-                          chunk=chunk, rank=rank, scale=scale),
+                          chunk=chunk, run=run, rank=rank, scale=scale),
         name="hpx_mla_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -1594,7 +1686,7 @@ def fused_latent_attention(q: jax.Array, pool: jax.Array,
                       pl.BlockSpec(memory_space=pltpu.HBM)],
             out_specs=[pl.BlockSpec((None, h, rank),
                                     lambda bb, *_: (bb, 0, 0))],
-            scratch_shapes=[pltpu.VMEM((2, chunk * bs, r), pool.dtype),
+            scratch_shapes=[pltpu.VMEM((2, chunk, bs, r), pool.dtype),
                             pltpu.VMEM((h, rank), jnp.float32),
                             pltpu.VMEM((h, 128), jnp.float32),
                             pltpu.VMEM((h, 128), jnp.float32),

@@ -88,6 +88,7 @@ from .attention_pallas import (_live, _ring_kpos, fused_latent_attention,
 __all__ = [
     "block_rows",
     "gather_block_kv",
+    "latent_takes_kernel",
     "paged_decode_attention",
     "paged_latent_attention",
     "paged_window_attention",
@@ -462,6 +463,13 @@ def paged_window_attention(q: jax.Array, k_new: jax.Array,
     return att, k_pool, v_pool
 
 
+def latent_takes_kernel(fused, rank: int) -> bool:
+    """Whether `paged_latent_attention` takes `hpx_mla_paged`: asked
+    for (`fused`: any of the fused kernels' names) and the value slice
+    is whole lanes. Decided HERE, from the operands."""
+    return bool(fused) and rank % 128 == 0
+
+
 def paged_latent_attention(q: jax.Array, row_new: jax.Array,
                            pool: jax.Array, table: jax.Array,
                            pos: jax.Array, *, rank: int, scale: float,
@@ -480,13 +488,13 @@ def paged_latent_attention(q: jax.Array, row_new: jax.Array,
 
     `fused` (any of the fused kernels' names) takes `hpx_mla_paged`,
     the table walk bounded by the slot's live length, where the value
-    slice is whole lanes (rank % 128 == 0: decided HERE, from the
-    operands); every other call the gather form below, the kernel's
+    slice is whole lanes (`latent_takes_kernel`: rank % 128 == 0);
+    every other call the gather form below, the kernel's
     oracle: the same float32 scores, mask, softmax and the
     probabilities rounded to the rows' type ahead of the value
     product."""
     pool = scatter_token(pool, table, pos, row_new[:, None, :])
-    if fused and rank % 128 == 0:
+    if latent_takes_kernel(fused, rank):
         return fused_latent_attention(q, pool, table, pos, rank=rank,
                                       scale=scale,
                                       interpret=interpret), pool

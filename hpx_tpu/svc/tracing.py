@@ -646,11 +646,22 @@ class StepRecord(NamedTuple):
     chunks: int
     live: int               # live slots when the step ended
     compiles: int           # program-cache misses inside the step
+    latent_entries: int     # table entries the decode step's latent
+                            # walks covered, a latent layer each, ...
+    latent_coalesced: int   # ... of them copied with their group's
+                            # neighbours in one descriptor
     cpu_thread_ns: int      # time.thread_time_ns
     cpu_process_ns: int     # time.process_time_ns: every thread's
     gc_ns: int              # collector pauses
     gc_full: int            # ... of which full (generation 2) runs
     slow: str               # "", "step" or "block"
+
+    @property
+    def latent_run_pct(self) -> float:
+        """The share of the latent walks' table entries that lay in a
+        coalesced copy (0 where the step walked none)."""
+        return 100.0 * self.latent_coalesced / self.latent_entries \
+            if self.latent_entries else 0.0
 
     def blame(self) -> str:
         """The part of a slow step to look at first: `held` (in
@@ -741,7 +752,7 @@ class StepAccount:
         self._lead = lead
         if self._end is None:       # the first step: nothing before it
             self._seen = (time.thread_time_ns(), time.process_time_ns(),
-                          _GC.ns, _GC.full, 0, 0, 0, 0, 0)
+                          _GC.ns, _GC.full, 0, 0, 0, 0, 0, 0, 0)
             self._end = _now_ns()
         # what the choke points saw since end(): the server's own reads
         # and dispatches BETWEEN steps (a caller's `flush()`), not the
@@ -763,20 +774,22 @@ class StepAccount:
             self.top_prog, self.top_ns = prog, ns
 
     def end(self, n: int, live: int, admits: int = 0, chunks: int = 0,
-            compiles: int = 0, draining: int = 0, overlapped: int = 0
-            ) -> StepRecord:
-        """Close the step's record. `admits` .. `overlapped`: the
+            compiles: int = 0, draining: int = 0, overlapped: int = 0,
+            latent: int = 0, coalesced: int = 0) -> StepRecord:
+        """Close the step's record. `admits` .. `coalesced`: the
         server's running totals of admissions, chunks, program-cache
-        misses and reads (draining, overlapped); like the process's
+        misses, reads (draining, overlapped) and the latent walks'
+        table entries (all, coalesced); like the process's
         clocks they are read once, here, and a record holds their
         growth since the record before."""
         now = _now_ns()
         wall, gap = now - self._t0, self._t0 - self._end - self._between
         cpu, cpu_all, gc_ns, gc_full, admits0, chunks0, compiles0, \
-            draining0, overlapped0 = self._seen
+            draining0, overlapped0, latent0, coalesced0 = self._seen
         self._seen = seen = (
             time.thread_time_ns(), time.process_time_ns(), _GC.ns,
-            _GC.full, admits, chunks, compiles, draining, overlapped)
+            _GC.full, admits, chunks, compiles, draining, overlapped,
+            latent, coalesced)
         self._end = now
         chunks -= chunks0
         compiles -= compiles0
@@ -797,7 +810,8 @@ class StepAccount:
             self.eager_ns, self.held_ns, self.waited_ns, gap,
             self.top_prog, self.top_ns, self.dispatches, draining,
             overlapped, self._lead, owed, admits - admits0, chunks, live,
-            compiles, seen[0] - cpu, seen[1] - cpu_all, seen[2] - gc_ns,
+            compiles, latent - latent0, coalesced - coalesced0,
+            seen[0] - cpu, seen[1] - cpu_all, seen[2] - gc_ns,
             seen[3] - gc_full, slow)
         self._ring.append(rec)
         self.held_ns = self.waited_ns = 0

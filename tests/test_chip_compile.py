@@ -484,6 +484,17 @@ def test_the_latent_rows_write_leaves_the_pool_where_it_lies(sds, cell):
     assert need <= 16 << 20 and need == ap.latent_vmem_bytes(
         heads, _K_ROW, _K_RANK, min(ap.LATENT_WALK_ENTRIES, 4 * maxb)
         * _C_BS, 2)
+    # with the copies coalesced (PR 51: a fold of `LATENT_RUN` entries
+    # that name neighbours lands as one descriptor in a buffer that is
+    # now (2, entries, block, R)) the kernel asks the scoped VMEM it
+    # asked before, and takes the four operands it took: table,
+    # positions, q, pool
+    assert ap.latent_walk_sizes(maxb) == (32, ap.LATENT_RUN) == (32, 32)
+    call = next(ln for ln in text.splitlines()
+                if "custom-call(" in ln and "hpx_mla_paged" in ln)
+    assert need == 16 << 20 and f'"size":"{need}"' in call
+    operands = call.split("custom-call(")[1].split(")")[0]
+    assert len(operands.split(",")) == 4, operands
 
 
 def test_a_wide_chunks_latent_accumulator_stays_in_fast_memory(sds):
@@ -544,11 +555,22 @@ def test_hybrid_server_step_program_copies_neither_state_nor_pool(
     def on_chip(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
     s = srv.slots
-    text = srv._paged_step_prog().lower(
+    # the operands `jit_step` has had since PR 40, operand for operand
+    # (parameters, pools, scales, tokens, positions, tables, the two
+    # sampling lanes): whether a table's groups are runs is read off
+    # the table INSIDE the kernel, so no program gains an input or an
+    # output a ladder width (PR 43's lesson)
+    lowered = srv._paged_step_prog().lower(
         on_chip(params), on_chip(srv._pools), None,
         sds((s,), jnp.int32), sds((s,), jnp.int32),
         (sds((s, srv._maxb), jnp.int32),),
-        sds((s,), jnp.float32), sds((s, 2), jnp.uint32)).compile().as_text()
+        sds((s,), jnp.float32), sds((s, 2), jnp.uint32))
+    pools_out, scales_out, tokens_out, moe_out = lowered.out_info
+    assert scales_out is None and tokens_out.shape == (s,)
+    assert jax.tree.map(lambda x: x.shape, pools_out) == jax.tree.map(
+        lambda x: x.shape, srv._pools)
+    assert len(jax.tree.leaves(moe_out)) == 1
+    text = lowered.compile().as_text()
     for name in ("hpx_kda_step", "hpx_mla_paged", "hpx_moe_gmm"):
         assert name in text
     assert _copies_of(

@@ -77,3 +77,82 @@ def test_mla_paged_kernel_equals_the_gather_form_at_the_cells_shape():
     # bfloat16 outputs of float32 sums taken in another order
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
     assert np.abs(got - want).mean() < 2e-3
+
+
+class TestRunCopies:
+    """What only the chip can show of the latent walk's coalesced
+    copies (PR 51): a fold of 32 neighbours lands as ONE 655 KB
+    descriptor on the buffer's own semaphore, and the waits count a
+    group's bytes whichever way its copies were started."""
+
+    @pytest.mark.parametrize("kind", ["run", "permuted", "broken@1",
+                                      "broken@2", "broken@3", "mixed"])
+    @pytest.mark.parametrize("b,h,maxb,nb", [
+        (64, 128, 1576, 20000), (48, 32, 264, 12673)],
+        ids=["deepseek-v2", "kimi"])
+    def test_grouped_copies_give_the_single_copy_forms_bits(
+            self, b, h, maxb, nb, kind, monkeypatch):
+        """At the two cells' shapes, over tables that are one run a
+        slot, a permutation with no two neighbours, runs broken at every
+        offset of a group of four, and a mix of all of them fold by
+        fold: the kernel in groups of `LATENT_RUN` is the kernel a copy
+        an entry TO THE BIT, run after run, at positions from 0 to the
+        whole table."""
+        from hpx_tpu.ops import attention_pallas as ap
+        r, rank, bs = 640, 512, 16
+        fold, run = ap.latent_walk_sizes(maxb)
+        assert run == ap.LATENT_RUN > 1
+        rng = np.random.default_rng(70)
+        ks = jax.random.split(jax.random.PRNGKey(71), 2)
+        pool = jax.random.normal(ks[0], (nb, 1, bs, r), jnp.bfloat16)
+        q = (jax.random.normal(ks[1], (b, h, r)) * 0.3).astype(jnp.bfloat16)
+        # a slot's region of the pool: `maxb` ids from its base on (the
+        # regions of DeepSeek-V2's 64 slots overlap: 20,000 blocks)
+        base = rng.integers(1, nb - maxb, b)
+        ids = base[:, None] + np.arange(maxb)[None]
+
+        def permuted(x):                # no entry's successor its id's
+            half = maxb // 2
+            return np.stack([x[:, half:2 * half], x[:, :half]], -1).reshape(
+                b, -1)[:, ::-1]
+
+        def broken(x, k):               # every group of 4 jumps at k
+            g = x.reshape(b, -1, 4).copy()
+            g[:, :, k:] = g[:, ::-1, k:]
+            return g.reshape(b, -1)
+        forms = {"run": ids, "permuted": permuted(ids),
+                 **{f"broken@{k}": broken(ids, k) for k in (1, 2, 3)}}
+        if kind == "mixed":             # a slot's folds each their own
+            table = ids.copy()
+            whole = maxb // run * run
+            pick = rng.integers(0, len(forms), (b, whole // run))
+            for i, form in enumerate(forms.values()):
+                here = np.repeat(pick == i, run, axis=1)
+                table[:, :whole][here] = form[:, :whole][here]
+        else:
+            table = forms[kind]
+        top = maxb * bs - 1
+        pos = rng.integers(0, top + 1, b)
+        pos[:6] = [0, top, bs - 1, run * bs - 1, run * bs, top - bs]
+        n_live = pos // bs + 1
+        share = ap.latent_entries_coalesced(table, n_live, fold,
+                                            run).sum() / n_live.sum()
+        assert {"run": share > 0.6, "mixed": 0.05 < share < 0.5}.get(
+            kind, share == 0.0), share
+        table, pos = jnp.asarray(table, jnp.int32), jnp.asarray(pos,
+                                                                jnp.int32)
+
+        def form():                     # traced under today's constant
+            return jax.jit(lambda q, pool, table, pos:
+                           ap.fused_latent_attention(
+                               q, pool, table, pos, rank=rank,
+                               scale=192 ** -0.5))
+        grouped = form()
+        got = np.asarray(grouped(q, pool, table, pos), np.float32)
+        monkeypatch.setattr(ap, "LATENT_RUN", 1)
+        one = np.asarray(form()(q, pool, table, pos), np.float32)
+        assert np.isfinite(one).all()
+        assert (got == one).all()
+        for _ in range(3):              # a race would not show every time
+            assert (np.asarray(grouped(q, pool, table, pos),
+                               np.float32) == one).all()
